@@ -228,10 +228,9 @@ def _phase_quantiles(qs: List[dict]) -> Dict[str, dict]:
 
 
 def _last_bench_errors(events: List[dict]) -> Dict[str, dict]:
-    """Most recent ``bench_error`` record per metric — the relay-wedge
-    trail bench.py leaves when a probe fails (today that failure lives
-    only in the BENCH_*.json tail string; here the roll-up surfaces
-    it next to the successful runs)."""
+    """Most recent ``bench_error`` record per metric — the trail
+    bench.py leaves when a probe or measurement fails; the roll-up
+    surfaces it next to the successful runs."""
     out: Dict[str, dict] = {}
     for e in events:
         if e.get("kind") != "bench_error":
@@ -239,8 +238,6 @@ def _last_bench_errors(events: List[dict]) -> Dict[str, dict]:
         out[str(e.get("metric") or "?")] = {
             "ts": e.get("ts"),
             "error": str(e.get("error") or "")[:300],
-            "attempts": e.get("attempts"),
-            "last_known_good": e.get("last_known_good"),
         }
     return out
 
@@ -658,11 +655,7 @@ def render_summary(events: List[dict]) -> str:
         + (f" spans={s['span_count']}" if s.get("span_count") else ""),
     ]
     for metric, err in sorted((s.get("bench_errors") or {}).items()):
-        lkg = err.get("last_known_good") or {}
-        lines.append(
-            f"LAST BENCH ERROR [{metric}]: {err['error']}"
-            + (f" (last known good: {lkg.get('tflops', lkg)})"
-               if lkg else ""))
+        lines.append(f"LAST BENCH ERROR [{metric}]: {err['error']}")
     pq = s.get("phase_quantiles") or {}
     if pq:
         lines.append("")

@@ -19,8 +19,8 @@ Three cost tiers, strictly ordered:
 - **Flight recorder only** (``config.obs_flight_recorder > 0``,
   obs off): spans are timed and appended to a bounded in-memory ring —
   no file I/O, no event assembly — so a field failure can dump the last
-  N records as a post-mortem artifact (the BENCH_r05 null-row lesson:
-  today a relay-wedge failure leaves one error string).
+  N records as a post-mortem artifact (a failed capture used to
+  leave one error string and nothing else).
 - **Full** (``obs_level != "off"``): span records ALSO append to the
   JSONL event log (``kind: "span"``), where ``history`` and the chrome
   exporter read them back.

@@ -88,10 +88,10 @@ REGISTRY: Dict[str, KernelSpec] = {}
 #: dispatches, so nothing may consult the registry).
 _LOOKUPS = {"count": 0}
 
-#: VMEM budget the grouped variants may spend on ONE (a, b) block pair
-#: (double-buffered by Mosaic); bounds G at big block sizes so a
-#: bs=512 group never blows the 16 MiB core budget.
-VMEM_PAIR_BUDGET_BYTES = 8 * 1024 * 1024
+#: Mosaic's default scoped-VMEM limit for one kernel on a v5e core —
+#: the "limit 16.00M" of its RESOURCE_EXHAUSTED refusal. Every grouped
+#: schedule's per-step footprint (``dot_step_vmem_bytes``) is held to it.
+VMEM_SCOPED_LIMIT_BYTES = 16 * 1024 * 1024
 
 
 def register_kernel(spec: KernelSpec) -> None:
@@ -106,12 +106,42 @@ def get_kernel(kernel_id: str) -> KernelSpec:
     return REGISTRY[kernel_id]
 
 
-def grouped_factor(bs: int, requested: int) -> int:
-    """Effective pair-group G for a grouped variant at this block size:
-    the requested factor clamped so a double-buffered (bs, G·bs) +
-    (G·bs, bs) f32 block pair fits VMEM_PAIR_BUDGET_BYTES."""
-    cap = int(VMEM_PAIR_BUDGET_BYTES // max(2 * bs * bs * 4, 1))
-    return max(1, min(requested, cap))
+def _itemsize(dtype) -> int:
+    return 4 if dtype is None else jnp.dtype(dtype).itemsize
+
+
+def dot_step_vmem_bytes(lhs: int, rhs: int, out: int, itemsize: int,
+                        acc: bool) -> int:
+    """Scoped VMEM Mosaic allocates for ONE grid step of an
+    ``out (+)= lhs @ rhs`` kernel (operand sizes in ELEMENTS), counted
+    the way the chip's compiler counts it (checked against v5e compiles
+    in tests/test_chip_compile.py): both inputs and the out block are
+    double-buffered, the dot result is an f32 temporary, ``acc`` adds
+    the f32 accumulator scratch. f32 payloads contract at HIGHEST
+    precision, whose bf16 splits of both operands take temporaries of
+    2x the operand bytes; bf16 operands of a width that is not a power
+    of two are re-laid out through temporaries measured at up to 0.37x
+    the operand bytes — budgeted as half. Linear in every size."""
+    io = 2 * (lhs + rhs + out) * itemsize
+    tmp = out * 4 * (2 if acc else 1)
+    if itemsize >= 4:
+        tmp += 2 * (lhs + rhs) * 4
+    else:
+        tmp += (lhs + rhs) * itemsize // 2
+    return io + tmp
+
+
+def grouped_factor(bs: int, requested: int, itemsize: int = 4) -> int:
+    """Effective pair-group G for a grouped variant at this block size
+    and payload width: the requested factor clamped so one
+    (bs, G·bs)x(G·bs, bs) accumulate step fits the scoped-VMEM limit.
+    ``itemsize`` defaults to f32, the widest payload — a G feasible
+    there is feasible for every narrower dtype."""
+    tile = bs * bs
+    fixed = dot_step_vmem_bytes(0, 0, tile, itemsize, acc=True)
+    per_g = dot_step_vmem_bytes(tile, tile, 0, itemsize, acc=True)
+    cap = (VMEM_SCOPED_LIMIT_BYTES - fixed) // max(per_g, 1)
+    return int(max(1, min(requested, cap)))
 
 
 def _pallas_eligible(bs: int, npairs: int) -> bool:
@@ -123,12 +153,16 @@ def _pallas_eligible(bs: int, npairs: int) -> bool:
 
 
 def admissible(kernel_id: str, bs: int, npairs: int,
-               config: Optional[MatrelConfig] = None) -> bool:
+               config: Optional[MatrelConfig] = None,
+               dtype=None) -> bool:
     """Can this kernel RUN for a (bs, npairs) SpGEMM under this config?
     Pallas entries need the pallas gate (real TPU or interpret mode)
     and the 8-sublane block rule (the pallas_spmm lesson, soak seed
     50114); grouped entries additionally need a VMEM-feasible G >= 2
-    (G == 1 would be the generic schedule with extra padding)."""
+    (G == 1 would be the generic schedule with extra padding).
+    ``dtype`` is the payload dtype the kernel will run at; None (the
+    planner, which stamps before operands are materialised) counts
+    f32, the widest."""
     spec = REGISTRY.get(kernel_id)
     if spec is None:
         return False
@@ -138,7 +172,8 @@ def admissible(kernel_id: str, bs: int, npairs: int,
             return False
         if not _pallas_eligible(bs, npairs):
             return False
-        if spec.group > 1 and grouped_factor(bs, spec.group) < 2:
+        if spec.group > 1 and grouped_factor(
+                bs, spec.group, _itemsize(dtype)) < 2:
             return False
     return True
 
@@ -501,7 +536,8 @@ def _grouped_call(bs, G, n_groups, n_out, out_dtype, interpret,
     )
 
 
-def _adaptive_group(counts: np.ndarray, requested: int, bs: int) -> int:
+def _adaptive_group(counts: np.ndarray, requested: int, bs: int,
+                    itemsize: int) -> int:
     """Effective G for one grouped schedule: the MEDIAN slot-run
     length, clamped by the spec's request and the VMEM budget. A fixed
     G pads every short run to the group width (measured 17× SLOWER
@@ -512,7 +548,7 @@ def _adaptive_group(counts: np.ndarray, requested: int, bs: int) -> int:
     if counts.size == 0:
         return 2
     med = int(np.median(counts[counts > 0])) if np.any(counts > 0) else 1
-    return max(2, min(requested, grouped_factor(bs, requested),
+    return max(2, min(grouped_factor(bs, requested, itemsize),
                       max(med, 2)))
 
 
@@ -521,7 +557,7 @@ def _build_grouped(A, B, bs, pairs, n_out, out_dtype, interpret, G):
     from matrel_tpu.ops import spgemm as spgemm_lib
     slot, pa, pb = pairs
     counts = np.bincount(np.asarray(slot, np.int64), minlength=n_out)
-    G = _adaptive_group(counts, G, bs)
+    G = _adaptive_group(counts, G, bs, _itemsize(out_dtype))
     src, group_slot = _grouped_tables(np.asarray(slot, np.int64), n_out,
                                       G, int(np.asarray(pa).size))
     ga, gb = _bake_grouped(spgemm_lib._edge_masked(A),
@@ -547,6 +583,37 @@ def _build_grouped(A, B, bs, pairs, n_out, out_dtype, interpret, G):
     return run
 
 
+def _band_call(bs, wa, rc, gr, nchunks, out_dtype, interpret):
+    """The pallas_call of the band schedule: one step contracts a
+    (bs, wa·bs) A strip with a (wa·bs, rc·bs) B chunk."""
+    from jax.experimental import pallas as pl
+    from matrel_tpu.utils import compat
+
+    prec = _pallas_precision(out_dtype)
+
+    def kern(a_ref, b_ref, out_ref):
+        out_ref[0] = jax.lax.dot(
+            a_ref[0], b_ref[0], precision=prec,
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+    return pl.pallas_call(
+        kern,
+        grid=(gr, nchunks),
+        in_specs=[
+            pl.BlockSpec((1, bs, wa * bs), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, wa * bs, rc * bs),
+                         lambda i, j: (i * nchunks + j, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, bs, rc * bs),
+                               lambda i, j: (i * nchunks + j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((gr * nchunks, bs, rc * bs),
+                                       out_dtype),
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )
+
+
 def _build_band(A, B, bs, pairs, n_out, out_dtype, interpret, wmax,
                 out_rows, out_cols):
     """Band builder — WALK THE DIAGONAL: per A block-row the k-band
@@ -562,8 +629,6 @@ def _build_band(A, B, bs, pairs, n_out, out_dtype, interpret, wmax,
     to the hub width) — which is why it is the row_band
     specialization, not the default. Rows whose bands exceed the
     VMEM-feasible width fall back to the grouped schedule."""
-    from jax.experimental import pallas as pl
-    from matrel_tpu.utils import compat
     from matrel_tpu.ops import spgemm as spgemm_lib
 
     out_rows = np.asarray(out_rows, np.int64)
@@ -588,12 +653,15 @@ def _build_band(A, B, bs, pairs, n_out, out_dtype, interpret, wmax,
     wa = int(max((kmax - kmin + 1)[live].max(initial=1), 1))
     rr = int(max((cmax - cmin + 1)[live &
                                    (cmax >= 0)].max(initial=1), 1))
-    # VMEM feasibility: the A strip + one B chunk + the out chunk,
-    # f32, double-buffered by Mosaic — chunk the output band when it
-    # does not fit, fall back entirely when even Rc = 1 does not
-    budget = VMEM_PAIR_BUDGET_BYTES // 4
-    rc = int(min(rr, max(budget // max(wa * bs * bs, 1) - 1, 0)))
-    if rc < 1 or wa > grouped_factor(bs, max(wmax, 2)) * 2:
+    # VMEM feasibility: the A strip + one B chunk + the out chunk as
+    # Mosaic counts one step — chunk the output band when it does not
+    # fit, fall back entirely when even Rc = 1 does not
+    isz = _itemsize(out_dtype)
+    tile = bs * bs
+    fixed = dot_step_vmem_bytes(wa * tile, 0, 0, isz, acc=False)
+    per_rc = dot_step_vmem_bytes(0, wa * tile, tile, isz, acc=False)
+    rc = int(min(rr, max(VMEM_SCOPED_LIMIT_BYTES - fixed, 0) // per_rc))
+    if rc < 1 or wa > grouped_factor(bs, max(wmax, 2), isz) * 2:
         return _build_grouped(A, B, bs, pairs, n_out, out_dtype,
                               interpret, wmax)
     nchunks = -(-rr // rc)
@@ -654,29 +722,7 @@ def _build_band(A, B, bs, pairs, n_out, out_dtype, interpret, wmax,
                + (out_cols - np.clip(cmin, 0, None)[out_rows]))
         sel_dev = jnp.asarray(sel)
 
-    prec = _pallas_precision(out_dtype)
-
-    def kern(a_ref, b_ref, out_ref):
-        out_ref[0] = jax.lax.dot(
-            a_ref[0], b_ref[0], precision=prec,
-            preferred_element_type=jnp.float32).astype(out_ref.dtype)
-
-    kernel = pl.pallas_call(
-        kern,
-        grid=(gr, nchunks),
-        in_specs=[
-            pl.BlockSpec((1, bs, wa * bs), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, wa * bs, rc * bs),
-                         lambda i, j: (i * nchunks + j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bs, rc * bs),
-                               lambda i, j: (i * nchunks + j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((gr * nchunks, bs, rc * bs),
-                                       out_dtype),
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-    )
+    kernel = _band_call(bs, wa, rc, gr, nchunks, out_dtype, interpret)
 
     @jax.jit  # matlint: disable=ML010 registry runner — the sanctioned kernel seam's own dispatch program
     def _run(a, b, sel_):
@@ -714,7 +760,8 @@ def _build_bucketed(A, B, bs, pairs, n_out, out_dtype, interpret,
     for slots_sel, G in ((light_slots, g_light), (heavy_slots, g_heavy)):
         if slots_sel.size == 0:
             continue
-        G = _adaptive_group(counts[slots_sel], G, bs)
+        G = _adaptive_group(counts[slots_sel], G, bs,
+                            _itemsize(out_dtype))
         # compact this bucket's pairs onto local slot ids (slot-sorted
         # order is preserved, so the grouped tables stay run-coherent)
         local_of = np.full(n_out, -1, np.int64)
@@ -772,11 +819,12 @@ def build_runner(kernel_id: str, A, B, cfg: MatrelConfig,
     if kernel_id == "pallas_generic":
         return _build_pallas_generic(bs, npairs, n_out, out_dtype,
                                      interpret)
-    G = grouped_factor(bs, spec.group)
+    isz = _itemsize(out_dtype)
+    G = grouped_factor(bs, spec.group, isz)
     if spec.bucket_split > 0:
         return _build_bucketed(A, B, bs, pairs3, n_out, out_dtype,
                                interpret,
-                               g_light=max(2, grouped_factor(bs, 2)),
+                               g_light=max(2, grouped_factor(bs, 2, isz)),
                                g_heavy=G,
                                split=spec.bucket_split)
     if kernel_id == "pallas_band":

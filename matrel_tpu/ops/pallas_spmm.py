@@ -87,6 +87,38 @@ def pallas_eligible(S, pm: int) -> bool:
             and (tm % 128 == 0 or tm == pm))
 
 
+def spmm_call(bs: int, tm: int, m_tiles: int, nnzb: int, gr: int,
+              pm: int, out_dtype, interpret: bool = False):
+    """The pallas_call of one SpMM, from shapes alone (make_spmm binds
+    it to a matrix; tests/test_chip_compile.py compiles it for a
+    described chip): ``kernel(rows, cols, payload[nnzb,bs,bs],
+    dblocks[gc,bs,pm]) -> [gr*bs, pm]``."""
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,           # block_rows, block_cols
+        grid=(m_tiles, nnzb),
+        in_specs=[
+            pl.BlockSpec((1, bs, bs), lambda j, i, brows, bcols: (i, 0, 0)),
+            pl.BlockSpec((1, bs, tm), lambda j, i, brows, bcols: (bcols[i], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((bs, tm), lambda j, i, brows, bcols: (brows[i], j)),
+        scratch_shapes=[pltpu.VMEM((bs, tm), jnp.float32)],
+    )
+    # bf16 payloads run the MXU's native single pass; asking Mosaic for
+    # fp32 contract precision on bf16 operands is both pointless (inputs
+    # carry bf16 information) and rejected ("Bad lhs type"). f32 payloads
+    # keep full-f32 MXU passes.
+    precision = (jax.lax.Precision.DEFAULT if out_dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+    return pl.pallas_call(  # matlint: disable=ML009 legacy SpMM kernel, unported to the registry this round (block-sparse x DENSE path; registry covers S x S)
+        _make_kernel(precision, nnzb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((gr * bs, pm), out_dtype),
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )
+
+
 def make_spmm(S, pm, out_pshape, d_spec, out_sharding, cfg: MatrelConfig,
               interpret: bool = False):
     """Build a jitted SpMM runner bound to S's static tile metadata."""
@@ -116,32 +148,8 @@ def make_spmm(S, pm, out_pshape, d_spec, out_sharding, cfg: MatrelConfig,
     tm = _pick_tm(pm)
     m_tiles = pm // tm
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,           # block_rows, block_cols
-        grid=(m_tiles, nnzb),
-        in_specs=[
-            pl.BlockSpec((1, bs, bs), lambda j, i, brows, bcols: (i, 0, 0)),
-            pl.BlockSpec((1, bs, tm), lambda j, i, brows, bcols: (bcols[i], 0, j)),
-        ],
-        out_specs=pl.BlockSpec((bs, tm), lambda j, i, brows, bcols: (brows[i], j)),
-        scratch_shapes=[pltpu.VMEM((bs, tm), jnp.float32)],
-    )
-
     out_dtype = S.blocks.dtype
-    # bf16 payloads run the MXU's native single pass; asking Mosaic for
-    # fp32 contract precision on bf16 operands is both pointless (inputs
-    # carry bf16 information) and rejected ("Bad lhs type"). f32 payloads
-    # keep full-f32 MXU passes.
-    precision = (jax.lax.Precision.DEFAULT if out_dtype == jnp.bfloat16
-                 else jax.lax.Precision.HIGHEST)
-    kernel = pl.pallas_call(  # matlint: disable=ML009 legacy SpMM kernel, unported to the registry this round (block-sparse x DENSE path; registry covers S x S)
-        _make_kernel(precision, nnzb),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((gr * bs, pm), out_dtype),
-        compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )
+    kernel = spmm_call(bs, tm, m_tiles, nnzb, gr, pm, out_dtype, interpret)
 
     # The tile stack is static per matrix: permute it into kernel order
     # ONCE at build time. Doing this inside `run` cost ~2 ms/call at
@@ -203,4 +211,8 @@ def make_spmm(S, pm, out_pshape, d_spec, out_sharding, cfg: MatrelConfig,
         del brows, bcols  # baked into the prepared payload at build
         return _run(payload_prepared, rows_d, cols_d, dd)
 
+    # the jitted program and its baked arguments, so a caller can look
+    # at what was compiled (chip_smoke.py reads the HLO) without
+    # closing over the payload as a constant
+    run.jitted, run.baked_args = _run, (payload_prepared, rows_d, cols_d)
     return run

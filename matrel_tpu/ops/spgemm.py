@@ -200,7 +200,8 @@ def _tiles_runner(A, B, cfg, interpret, pairs, n_out, out_dtype,
                                   side=max(A.shape[0], A.shape[1],
                                            B.shape[1]),
                                   mesh=A.mesh)
-    elif not kr.admissible(kid, A.block_size, npairs, cfg):
+    elif not kr.admissible(kid, A.block_size, npairs, cfg,
+                           dtype=out_dtype):
         kid = kr.legacy_default(A.block_size, npairs, cfg)
     key = (id(A), id(B), npairs, n_out, str(out_dtype), kid,
            interpret, cfg.matmul_precision)

@@ -29,6 +29,14 @@ import jax.numpy as jnp
 
 _log = logging.getLogger("matrel_tpu.autotune")
 
+
+def _log_dropped(variant, err) -> None:
+    """A candidate that fails to build, compile or run drops out of the
+    measured table — said out loud (a refused Pallas kernel used to
+    vanish without a word)."""
+    _log.warning("autotune: %s dropped from the measurement: %s: %s",
+                 variant, type(err).__name__, str(err)[:300])
+
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib, padding
 from matrel_tpu.core.blockmatrix import BlockMatrix
@@ -317,8 +325,9 @@ def autotune_matmul(n: int, k: int, m: int,
             continue
         try:
             t = measure_strategy(s, A, B, cfg)
-        except Exception:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a strategy failing to compile
-            continue       # on this backend just drops out of the table
+        except Exception as e:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a strategy failing to compile
+            _log_dropped(s, e)       # on this backend just drops out of the table
+            continue
         if t > 0.0:        # non-positive median = noise, not a time
             results[s] = t
     # _pick_winner owns the one-variant and tie gates (advisor r4):
@@ -520,8 +529,9 @@ def lookup_or_measure_spmv(plan, mesh,
             continue
         try:
             t = measure_spmv_variant(v, plan, mesh, cfg)
-        except Exception:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a variant failing to compile
-            continue       # on this backend drops out of the table
+        except Exception as e:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a variant failing to compile
+            _log_dropped(v, e)       # on this backend drops out of the table
+            continue
         if t > 0.0:
             results[v] = t
     # a one-variant "comparison" proves nothing, and which variants are
@@ -644,7 +654,8 @@ def lookup_or_measure_spgemm(side: int, structure: str, bs: int, mesh,
             continue
         try:
             t = measure_spgemm_kernel(kid, A, B, cfg)
-        except Exception:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a kernel failing to compile on this backend drops out of the table
+        except Exception as e:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a kernel failing to compile on this backend drops out of the table
+            _log_dropped(kid, e)
             continue
         if t > 0.0:
             results[kid] = t
@@ -729,7 +740,8 @@ def measure_fusion_region(region, root_tree, mesh,
                 ts.append(time.perf_counter() - t0)
             ts.sort()
             t = ts[len(ts) // 2]
-        except Exception:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a region variant failing to build/compile drops out of the table
+        except Exception as e:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a region variant failing to build/compile drops out of the table
+            _log_dropped(name, e)
             continue
         if t > 0.0:
             results[name] = t
@@ -885,7 +897,8 @@ def lookup_or_measure_reshard(plan, mesh,
     for v in RESHARD_VARIANTS:
         try:
             t = measure_reshard_variant(v, plan, mesh, cfg)
-        except Exception:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a variant failing to compile on this backend drops out of the table
+        except Exception as e:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a variant failing to compile on this backend drops out of the table
+            _log_dropped(v, e)
             continue
         if t > 0.0:
             results[v] = t
@@ -957,7 +970,8 @@ def lookup_or_measure_ivm(rule: str, side: int, mesh,
     for name, fn in (("patch", patch_s), ("recompute", full_s)):
         try:
             t = float(fn())
-        except Exception:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a variant failing on this backend drops out of the table
+        except Exception as e:  # noqa: BLE001  # matlint: disable=ML007 measurement loop — a variant failing on this backend drops out of the table
+            _log_dropped(name, e)
             continue
         if t > 0.0:
             results[name] = t

@@ -210,11 +210,7 @@ def matmul_summa(a: jax.Array, b: jax.Array, mesh: Mesh,
         acc0 = jnp.zeros((ab.shape[0], bb.shape[1]), dtype=out_dtype)
         # mark the fresh accumulator as varying over the mesh axes so the
         # fori_loop carry types line up with the per-device dot results
-        pcast = getattr(jax.lax, "pcast", None)
-        if pcast is not None:
-            acc0 = pcast(acc0, (x, y), to="varying")
-        else:
-            acc0 = compat.pvary(acc0, (x, y))
+        acc0 = compat.pvary(acc0, (x, y))
         if g == 1:
             return _local_dot(ab, bb, prec, out_dtype)
         _, _, acc = jax.lax.fori_loop(0, g, step, (ab, bb, acc0))

@@ -26,7 +26,8 @@ import numpy as np
 from jax.sharding import Mesh
 
 from matrel_tpu import executor as executor_lib
-from matrel_tpu.config import MatrelConfig, default_config, normalize_sla
+from matrel_tpu.config import (MatrelConfig, configure_compile_cache,
+                               default_config, normalize_sla)
 from matrel_tpu.core import mesh as mesh_lib
 from matrel_tpu.core.blockmatrix import BlockMatrix
 from matrel_tpu.ir.expr import MatExpr, as_expr
@@ -63,6 +64,7 @@ class MatrelSession:
     def __init__(self, mesh: Optional[Mesh] = None,
                  config: Optional[MatrelConfig] = None):
         self.config = config or default_config()
+        configure_compile_cache()
         # concurrency sanitizer (utils/lockdep.py;
         # docs/CONCURRENCY.md): armed BEFORE any of this session's
         # locks construct, so they all come back instrumented. Off

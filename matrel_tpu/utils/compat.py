@@ -1,47 +1,28 @@
-"""Version compatibility shims for the jax API surface.
-
-The codebase targets the modern jax API (``jax.shard_map`` with its
-``check_vma`` flag); container images pin older releases where the same
-function lives at ``jax.experimental.shard_map.shard_map`` and the flag
-is spelled ``check_rep``. Every shard_map call site imports from here so
-the version split lives in exactly one place.
-"""
+"""The jax API surface the codebase leans on, in one place: every
+shard_map call site, varying-axes cast and Pallas TPU compiler-params
+construction imports from here, so an API move lands in one file."""
 
 from __future__ import annotations
 
-try:                                   # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map
-    _LEGACY = False
-except ImportError:                    # jax 0.4.x: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _LEGACY = True
+import jax
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=None, **kw):
-    """jax.shard_map across versions. ``check_vma`` (the modern name for
-    the per-output varying-manual-axes check) maps onto the legacy
-    ``check_rep`` flag — same meaning, inverted era."""
+    """``jax.shard_map`` with ``check_vma`` (the per-output
+    varying-manual-axes check) passed only when the caller set it."""
     if check_vma is not None:
-        kw["check_rep" if _LEGACY else "check_vma"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
+        kw["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def pvary(x, axes):
-    """jax.lax.pvary across versions: on legacy jax the varying-axes
-    type system doesn't exist, so marking a value varying is the
-    identity (check_rep handles replication checking instead)."""
-    import jax
-    fn = getattr(jax.lax, "pvary", None)
-    if fn is None:
-        return x
-    return fn(x, axes)
+    """Mark a replicated value as varying over ``axes`` inside a
+    shard_map (``jax.lax.pcast``; ``pvary`` is deprecated)."""
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def tpu_compiler_params(**kw):
-    """pallas tpu CompilerParams across the rename
-    (``TPUCompilerParams`` on legacy jax)."""
+    """Pallas TPU ``CompilerParams``."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
+    return pltpu.CompilerParams(**kw)

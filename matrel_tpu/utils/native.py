@@ -55,7 +55,7 @@ def _build() -> bool:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         return True
     except (OSError, subprocess.SubprocessError) as e:
-        log.debug("native build failed: %s", e)
+        log.warning("native build failed (numpy fallbacks run): %s", e)
         return False
 
 

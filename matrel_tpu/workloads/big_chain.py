@@ -149,9 +149,7 @@ def _vma_zeros(shape, dt, vma_axes):
     shard_map need this or the fori carry types mismatch)."""
     z = jnp.zeros(shape, dtype=dt)
     if vma_axes:
-        pcast = getattr(jax.lax, "pcast", None)
-        z = (pcast(z, vma_axes, to="varying") if pcast is not None
-             else compat.pvary(z, vma_axes))
+        z = compat.pvary(z, vma_axes)
     return z
 
 
@@ -259,9 +257,7 @@ def streaming_chain_sharded(n: int,
             return panel_body(idx * per_dev + j, acc)
 
         acc0 = jnp.zeros((), jnp.float32)
-        pcast = getattr(jax.lax, "pcast", None)
-        acc0 = (pcast(acc0, axes, to="varying") if pcast is not None
-                else compat.pvary(acc0, axes))
+        acc0 = compat.pvary(acc0, axes)
         local = jax.lax.fori_loop(0, per_dev, body, acc0)
         return jax.lax.psum(local, axes)
 

@@ -1,13 +1,13 @@
-"""Fire-drill for the staged relay-recovery batch (VERDICT r5 Next #2).
+"""Fire-drill for the staged capture batch (VERDICT r5 Next #2).
 
 `tools/tpu_batch.sh --dry` must run the WHOLE staged capture sequence
 end-to-end on the CPU backend with rc 0, each step emitting its
 expected parseable artifact, and every write redirected away from the
 repo's committed capture history. The round-6 introduction of this
-drill immediately caught two staged tools that would have crashed in a
-real relay window (gram_sym_full / autotune_capture missing their
-sys.path setup) — which is precisely the failure mode the VERDICT said
-the first relay window must not be spent debugging.
+drill immediately caught two staged tools that would have crashed on
+their first chip run (gram_sym_full / autotune_capture missing their
+sys.path setup) — precisely the failure chip time must not be spent
+debugging.
 
 One subprocess run shared by every assertion: the batch takes ~30 s on
 the CI host and the point is the INTEGRATED sequence.
@@ -89,7 +89,7 @@ def test_spgemm_row_artifact(dry_batch):
 
 def test_sparse_kernels_row_artifact(dry_batch):
     _, records, _ = dry_batch
-    # twice in the dry batch, like its sibling rows: the wedge-safe
+    # twice in the dry batch, like its sibling rows: the probed
     # bench.py --sparse-kernels step AND bench_all's dry-enabled row
     recs = [r for r in records
             if r.get("metric") == "sparse_kernel_sweep"
@@ -122,7 +122,7 @@ def test_sparse_kernels_row_artifact(dry_batch):
 
 def test_fusion_row_artifact(dry_batch):
     _, records, _ = dry_batch
-    # twice in the dry batch, like its sibling rows: the wedge-safe
+    # twice in the dry batch, like its sibling rows: the probed
     # bench.py --fusion step AND bench_all's dry-enabled row
     recs = [r for r in records
             if r.get("metric") == "fusion_region_sweep"
@@ -151,7 +151,7 @@ def test_fusion_row_artifact(dry_batch):
 
 def test_traffic_row_artifact(dry_batch):
     _, records, _ = dry_batch
-    # twice in the dry batch, like its sibling rows: the wedge-safe
+    # twice in the dry batch, like its sibling rows: the probed
     # tools/traffic.py step AND bench_all's dry-enabled row
     recs = [r for r in records
             if r.get("metric") == "traffic_overload_harness"
@@ -230,7 +230,7 @@ def test_serve_row_artifact(dry_batch):
 
 def test_cse_row_artifact(dry_batch):
     _, records, _ = dry_batch
-    # twice in the dry batch, like its sibling rows: the wedge-safe
+    # twice in the dry batch, like its sibling rows: the probed
     # bench.py --cse step AND bench_all's dry-enabled row
     recs = [r for r in records
             if r.get("metric") == "cse_shared_interior_batch"
@@ -258,7 +258,7 @@ def test_cse_row_artifact(dry_batch):
 
 def test_fleet_row_artifact(dry_batch):
     _, records, _ = dry_batch
-    # twice in the dry batch, like its sibling rows: the wedge-safe
+    # twice in the dry batch, like its sibling rows: the probed
     # bench.py --fleet step AND bench_all's dry-enabled row
     recs = [r for r in records
             if r.get("metric") == "fleet_scaleout_qps"
@@ -564,7 +564,7 @@ def test_artifacts_redirected_out_of_repo(dry_batch):
     _, _, art = dry_batch
     # every side-effect landed in the dry dir, not the capture history
     for name in ("events.jsonl", "progress.jsonl", "soaklog.jsonl",
-                 "bench_last_good.json", "cpu_baseline.json",
+                 "cpu_baseline.json",
                  "autotune_dry.json", "spk_autotune.json",
                  "flight.json", "drift.json"):
         assert (art / name).exists(), f"{name} not redirected"

@@ -1,0 +1,192 @@
+"""The main path's Pallas kernels, compiled by the chip's own compiler
+for a DESCRIBED v5e 2x2 (nothing attached, no chip time): what
+interpret mode cannot show — VMEM refusals, tiling, partitioning.
+Shapes are the ones chip_smoke.py's plans produce at README scale
+(PageRank 1M nodes / 10M edges: 1954 blocks of 512 rows, 5376 slots;
+SpMM 100 352^2 at 1% of 512^2 tiles, 512 dense columns).
+
+One file on purpose: the worker that describes the topology holds the
+TPU library until it exits, so a second file would skip on another
+worker. The topology is described inside a fixture, never at import.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from matrel_tpu.config import MatrelConfig
+from matrel_tpu.ops import kernel_registry as kr
+from matrel_tpu.ops import pallas_spmm, pallas_spmv as pc
+from matrel_tpu.ops import spmv as spmv_lib
+
+# README-scale PageRank plan (tools: build_spmv_plan on 1M/10M uniform)
+NB, CAP, BLOCK = 1954, 5376, 512
+N_NODES = 1_000_000
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe is a skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device compile is written to the persistent cache but
+    # can never be read back without a chip: keep it off for this file
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    """Compile for the described chip; the text must hold the kernel."""
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _refusal(fn, *args):
+    """None when the compile passes, else the compiler's VMEM refusal."""
+    try:
+        fn.lower(*args).compile()
+        return None
+    except Exception as e:  # noqa: BLE001 — the refusal text is the result
+        m = re.search(r"Scoped allocation with size \S+ and limit \S+"
+                      r"|Ran out of memory in memory space vmem", str(e))
+        assert m, f"refused for another reason than VMEM: {e}"
+        return m.group(0)
+
+
+def _compact_table_shapes(nb, sharding):
+    shp = (nb, CAP // pc.LANE, pc.LANE)
+    return tuple(_sds(sharding, shp, dt)
+                 for dt in (jnp.int32, jnp.int8, jnp.int32, jnp.float32))
+
+
+def test_compact_spmv(one_chip):
+    static = (N_NODES, N_NODES, BLOCK, spmv_lib.LO)
+    x = _sds(one_chip, (N_NODES,), jnp.float32)
+    _compile(pc._compact_jitted, static,
+             _compact_table_shapes(NB, one_chip), (), x, 3, False)
+
+
+def test_compact_spmv_k_wide(one_chip):
+    static = (N_NODES, N_NODES, BLOCK, spmv_lib.LO)
+    X = _sds(one_chip, (N_NODES, pc._COL_CHUNK), jnp.float32)
+    _compile(pc._compact_matmat_jitted, static,
+             _compact_table_shapes(NB, one_chip), (), X, 3, False)
+
+
+def test_compact_spmv_sharded_2x2(topo):
+    mesh = Mesh(np.asarray(topo.devices, dtype=object).reshape(2, 2),
+                ("x", "y"))
+    axes = tuple(mesh.axis_names)
+    nb_pad = -(-NB // mesh.size) * mesh.size
+    tables = _compact_table_shapes(
+        nb_pad, NamedSharding(mesh, P(axes, None, None)))
+    x = _sds(NamedSharding(mesh, P()), (N_NODES,), jnp.float32)
+    run = pc._compact_sharded_runner(
+        (N_NODES, N_NODES, BLOCK, spmv_lib.LO), mesh, 3, 0, False)
+    text = _compile(run, *tables, x).as_text()
+    assert "all-gather" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_spmm(one_chip, dtype):
+    bs, gr, pm = 512, 196, 512           # 100 352 = 196 * 512
+    nnzb = gr * gr // 100 + gr           # 1% of tiles + the row padding
+    tm = pallas_spmm._pick_tm(pm)
+    kernel = pallas_spmm.spmm_call(bs, tm, pm // tm, nnzb, gr, pm, dtype)
+    _compile(jax.jit(kernel), _sds(one_chip, (nnzb,), jnp.int32),
+             _sds(one_chip, (nnzb,), jnp.int32),
+             _sds(one_chip, (nnzb, bs, bs), dtype),
+             _sds(one_chip, (gr, bs, pm), dtype))
+
+
+def _pair_args(one_chip, bs, dtype, npairs, n_tiles):
+    tiles = _sds(one_chip, (n_tiles, bs, bs), dtype)
+    table = _sds(one_chip, (npairs,), jnp.int32)
+    return tiles, tiles, table, table, table
+
+
+@pytest.mark.parametrize("bs", [256, 512])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_spgemm_pallas_generic(one_chip, bs, dtype):
+    npairs, n_out = 384, 128
+    run = kr._build_pallas_generic(bs, npairs, n_out, dtype, False)
+    _compile(run, *_pair_args(one_chip, bs, dtype, npairs, 256))
+
+
+def _grouped(one_chip, bs, dtype, G, n_groups=64, n_out=16):
+    kernel = kr._grouped_call(bs, G, n_groups, n_out, dtype, False)
+    return (jax.jit(kernel), _sds(one_chip, (n_groups,), jnp.int32),
+            _sds(one_chip, (n_groups, bs, G * bs), dtype),
+            _sds(one_chip, (n_groups, G * bs, bs), dtype))
+
+
+GROUPED_KERNELS = [k for k, s in kr.REGISTRY.items() if s.group > 1]
+
+
+@pytest.mark.parametrize("kernel_id", GROUPED_KERNELS)
+@pytest.mark.parametrize("bs", [256, 512])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_grouped_admissible_iff_compiles(one_chip, kernel_id, bs, dtype):
+    """admissible() is true exactly where the chip's compiler takes the
+    grouped schedule: an admitted kernel compiles at the widest G its
+    builder may pick, and a refused one is refused even at G = 2 (the
+    narrowest grouped schedule) — no shape admitted and refused."""
+    cfg = MatrelConfig(pallas_interpret=True)    # the Pallas gate, on CPU
+    isz = jnp.dtype(dtype).itemsize
+    spec = kr.get_kernel(kernel_id)
+    G = kr.grouped_factor(bs, spec.group, isz)
+    if kr.admissible(kernel_id, bs, 64, cfg, dtype=dtype):
+        assert G >= 2
+        assert _refusal(*_grouped(one_chip, bs, dtype, G)) is None
+        # and the clamp is not slack: twice the group is refused
+        if G < spec.group:
+            assert _refusal(*_grouped(one_chip, bs, dtype, 2 * G))
+    else:
+        assert G == 1
+        assert _refusal(*_grouped(one_chip, bs, dtype, 2))
+
+
+@pytest.mark.parametrize("bs,dtype,wa,rc,fits", [
+    (256, jnp.float32, 3, 3, True), (256, jnp.float32, 5, 3, False),
+    (512, jnp.bfloat16, 3, 2, True), (512, jnp.bfloat16, 3, 3, False),
+    (512, jnp.float32, 1, 1, True), (512, jnp.float32, 2, 1, False),
+])
+def test_band_budget_matches_compiler(one_chip, bs, dtype, wa, rc, fits):
+    """The band builder's chunk arithmetic (dot_step_vmem_bytes, no
+    accumulator) against the compiler on both sides of the limit."""
+    tile = bs * bs
+    need = kr.dot_step_vmem_bytes(wa * tile, wa * rc * tile, rc * tile,
+                                  jnp.dtype(dtype).itemsize, acc=False)
+    assert (need <= kr.VMEM_SCOPED_LIMIT_BYTES) == fits
+    gr, nch = 16, 2
+    kernel = kr._band_call(bs, wa, rc, gr, nch, dtype, False)
+    refused = _refusal(
+        jax.jit(kernel), _sds(one_chip, (gr, bs, wa * bs), dtype),
+        _sds(one_chip, (gr * nch, wa * bs, rc * bs), dtype))
+    assert (refused is None) == fits

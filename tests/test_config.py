@@ -108,3 +108,38 @@ class TestAxisCostWeights:
         assert MatrelConfig.from_env().axis_cost_weights == (1.0, 8.0)
         monkeypatch.setenv("MATREL_AXIS_COST_WEIGHTS", "1.5x32")
         assert MatrelConfig.from_env().axis_cost_weights == (1.5, 32.0)
+
+
+class TestDeviceStory:
+    """One helper says "is this a TPU"; one function places the
+    persistent compile cache (PR 22)."""
+
+    def test_on_tpu_is_false_on_the_cpu(self):
+        from matrel_tpu.config import on_tpu
+        assert on_tpu() is False
+        assert pallas_enabled(MatrelConfig()) is False
+
+    @pytest.mark.parametrize("env_dir", [None, "/some/where/else"])
+    def test_compile_cache_dir(self, monkeypatch, env_dir):
+        """JAX_COMPILATION_CACHE_DIR set: it is used and no directory is
+        set in code; unset: the fixed <checkout>/.jax_cache."""
+        import os
+        import jax
+        import matrel_tpu
+        from matrel_tpu.config import configure_compile_cache
+        updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.append((k, v)))
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(matrel_tpu.__file__))), ".jax_cache")
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+            want = env_dir
+        assert configure_compile_cache() == want
+        if env_dir is not None:
+            assert updates == []
+        else:
+            assert all(u == ("jax_compilation_cache_dir", want)
+                       for u in updates)

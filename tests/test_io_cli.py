@@ -520,7 +520,9 @@ class TestCLI:
         r = self._run("info")
         assert r.returncode == 0, r.stderr
         out = json.loads(r.stdout)
-        assert out["backend"] == "cpu" and "mesh" in out
+        assert out["platform"] == "cpu" and "mesh" in out
+        assert out["device_kind"] and out["device_count"] >= 1
+        assert out["compile_cache_dir"]
 
     def test_pagerank_cli(self, tmp_path, capsys):
         import json

@@ -397,7 +397,7 @@ class TestSuppression:
 class TestML008DevicePut:
     SRC = """
         import jax
-        def relay(x, sh):
+        def place(x, sh):
             return jax.device_put(x, sh)
     """
 

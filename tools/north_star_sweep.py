@@ -10,7 +10,7 @@ appends the outcome to PROGRESS.jsonl. Per the VERDICT's stop rule: if
 the top two schedules tie (<1% apart), the written negative result
 stands and the sweep should not be re-run.
 
-Wedge-safe: probes the backend first via bench.py's harness.
+Probes the backend first via bench.py's harness.
 """
 
 from __future__ import annotations
