@@ -344,13 +344,6 @@ def pagerank_graph(sizes, seed):
             rng.integers(0, n, m).astype(np.int32), n, m)
 
 
-def _runner_misses(pr):
-    return {name: getattr(pr, name).cache_info().misses
-            for name in ("_compact_runner_loop", "_compact_sharded_loop",
-                         "_onehot_runner", "_onehot_sharded_runner",
-                         "_edges_runner")}
-
-
 def phase_pagerank(sizes, seed, mesh=None):
     """Configuration 5 through the edge-list entry. ``mesh`` set runs
     the sharded compact executor (the --chips 4 phase)."""
@@ -365,16 +358,16 @@ def phase_pagerank(sizes, seed, mesh=None):
     # "auto" is what a user gets; off the TPU auto is the segment-sum
     # path by design, so a rehearsal names the executor explicitly
     impl = "auto" if _on_tpu() else "onehot"
-    before = _runner_misses(pr)
+    before = pr.path_counts()
     t0 = time.perf_counter()
     r = jax.block_until_ready(
         pr.pagerank_edges(src, dst, n, rounds=rounds, mesh=mesh, impl=impl))
     first = time.perf_counter() - t0
-    ran = [k for k, v in _runner_misses(pr).items() if v > before[k]]
     r, warm = timed(lambda: pr.pagerank_edges(src, dst, n, rounds=rounds,
                                               mesh=mesh, impl=impl))
-    want_runner = ("_compact_sharded_loop" if mesh is not None
-                   else "_compact_runner_loop")
+    # which executor answered, from the workload's public counts
+    ran = [k for k, v in pr.path_counts().items() if v > before[k]]
+    want_runner = "compact_sharded" if mesh is not None else "compact"
     has_kernel, plan_info, resident = False, None, None
     if ran == [want_runner]:
         (prepared, _), = [v for v in pr._PLAN_CACHE.values()]
@@ -518,8 +511,7 @@ def phase_mesh_strategies(sizes, seed, mesh, cfg):
         e = A.multiply(B)
         out, first, warm = first_and_warm(lambda: sess.compute(e).data)
         plan = sess.compile(e)
-        chosen = sorted({nd.attrs.get("strategy") for nd in _walk(
-            plan.optimized) if nd.kind == "matmul"})
+        chosen = sorted(plan.meta["executors"])
         spans = len(out.sharding.device_set)
         err = float(diff(jax.device_put(out, dev0), ref)) / ref_max
         emit(query=f"mesh.matmul.{strategy}", shapes={"A": [n, n],
@@ -531,12 +523,6 @@ def phase_mesh_strategies(sizes, seed, mesh, cfg):
              max_err=err, check="one-device product, compared on device",
              **{"pass": chosen == [strategy] and spans == mesh.size
                 and err <= 2e-2})
-
-
-def _walk(node):
-    yield node
-    for c in node.children:
-        yield from _walk(c)
 
 
 def phase_fleet(sizes, seed, mesh, cfg):

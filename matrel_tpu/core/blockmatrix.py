@@ -30,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib
+from matrel_tpu.obs import trace as trace_lib
 
 Array = jax.Array
 
@@ -271,8 +272,10 @@ class BlockMatrix:
 
     def to_numpy(self) -> np.ndarray:
         """Gather to host, dropping padding."""
-        full = np.asarray(jax.device_get(self.data))
-        return full[: self.shape[0], : self.shape[1]]
+        with trace_lib.entry("fetch") as sp:
+            full = np.asarray(jax.device_get(self.data))
+            sp.set(bytes=full.nbytes)
+            return full[: self.shape[0], : self.shape[1]]
 
     def block_until_ready(self) -> "BlockMatrix":
         self.data.block_until_ready()

@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib
+from matrel_tpu.obs import trace as trace_lib
 
 Array = jax.Array
 
@@ -197,13 +198,15 @@ class BlockSparseMatrix:
     def to_numpy(self) -> np.ndarray:
         gr, gc = self.grid
         bs = self.block_size
-        out = np.zeros((gr * bs, gc * bs), dtype=self.blocks.dtype)
-        br = np.asarray(self.block_rows)
-        bc = np.asarray(self.block_cols)
-        blocks = np.asarray(self.blocks)
-        for i in range(self.nnzb):
-            out[br[i] * bs:(br[i] + 1) * bs, bc[i] * bs:(bc[i] + 1) * bs] = blocks[i]
-        return out[: self.shape[0], : self.shape[1]]
+        with trace_lib.entry("fetch") as sp:
+            out = np.zeros((gr * bs, gc * bs), dtype=self.blocks.dtype)
+            br = np.asarray(self.block_rows)
+            bc = np.asarray(self.block_cols)
+            blocks = np.asarray(self.blocks)
+            sp.set(bytes=blocks.nbytes + br.nbytes + bc.nbytes)
+            for i in range(self.nnzb):
+                out[br[i] * bs:(br[i] + 1) * bs, bc[i] * bs:(bc[i] + 1) * bs] = blocks[i]
+            return out[: self.shape[0], : self.shape[1]]
 
     def to_dense(self, config: Optional[MatrelConfig] = None):
         """Scatter tiles into a dense BlockMatrix (device-side)."""

@@ -101,6 +101,15 @@ class Lowerer:
         # plan is garbage-collected; the held reference both prevents
         # that collection and proves the match.
         self.spmv_choice: Dict[int, Tuple[object, str]] = {}
+        # which executor each matmul lowered to, in lowering order and
+        # without repeats: a strategy name, a registry kernel id,
+        # "pallas_spmm" / "pallas_spmv", or "xla" — complete once the
+        # lowered function has been traced (plan.meta["executors"])
+        self.executors: List[str] = []
+
+    def _ran(self, executor: str) -> None:
+        if executor not in self.executors:
+            self.executors.append(executor)
 
     def _spmv_forced(self, plan) -> Optional[str]:
         """The measured executor variant forced for THIS plan object, or
@@ -137,14 +146,17 @@ class Lowerer:
                     return memo[node.uid]
                 # annotate() per physical operator: the profiler-timeline
                 # visibility the reference gets from Spark stage names
-                # (SURVEY.md §5 "Tracing / profiling"). EVERY node
-                # lowering dispatch must go through this one wrapped
-                # call — tests/test_obs.py structurally enforces it, so
-                # new ops can't silently skip instrumentation. A fused
-                # region (ir/fusion.py stamp, config.fusion_enable) is
-                # ONE dispatch: the whole member set lowers under this
-                # single frame — that per-edge dispatch collapse is the
-                # point of the fusion pass.
+                # (SURVEY.md §5 "Tracing / profiling") — the label rides
+                # the HLO's op_name and so the ``tf_op`` stat of the
+                # operation's event metadata in a trace (PERF.md, PR
+                # 25). EVERY node lowering dispatch must go through
+                # this one wrapped call — tests/test_obs.py structurally
+                # enforces it, so new ops can't silently skip
+                # instrumentation. A fused region (ir/fusion.py stamp,
+                # config.fusion_enable) is ONE dispatch: the whole
+                # member set lowers under this single frame — that
+                # per-edge dispatch collapse is the point of the fusion
+                # pass.
                 sig = (node.attrs.get("fused_region")
                        if self.config.fusion_enable else None)
                 if sig is not None:
@@ -430,6 +442,7 @@ class Lowerer:
             # measured: the expanded XLA one-hot path beats the compact
             # Pallas scatter for this plan shape class on this backend
             use_pallas = False
+        self._ran("pallas_spmv" if use_pallas else "xla")
         if use_pallas:
             from matrel_tpu.ops import pallas_spmv as pc
             interp = pallas_interpret_mode(self.config)
@@ -563,6 +576,7 @@ class Lowerer:
         if kid is None:
             kid, _, _ = spgemm_kernel_choice(node, self.config,
                                              self.mesh)
+        self._ran(kid)
         return spgemm_lib.apply_dense(
             SA, SB, self.config, kernel=kid, epilogue=epilogue,
             epilogue_elementwise=epilogue_elementwise)
@@ -597,6 +611,7 @@ class Lowerer:
             plan = _coo_dispatch_plan(node)
             if plan is None:
                 blk = A.to_block(self.mesh, self.config).data
+                self._ran("xla")
                 return strategies.run_matmul("xla", blk, ev(r), self.mesh,
                                              self.config,
                                              epilogue=epilogue)
@@ -611,6 +626,7 @@ class Lowerer:
             plan = _coo_dispatch_plan(node)
             if plan is None:
                 blk = S.to_block(self.mesh, self.config).data
+                self._ran("xla")
                 return strategies.run_matmul("xla", ev(l), blk, self.mesh,
                                              self.config,
                                              epilogue=epilogue)
@@ -621,7 +637,8 @@ class Lowerer:
         if l.kind == "sparse_leaf":
             from matrel_tpu.ops import spmm as spmm_lib
             return spmm_lib.apply(l.attrs["matrix"], ev(r), r.shape,
-                                  self.config, epilogue=epilogue)
+                                  self.config, epilogue=epilogue,
+                                  ran=self._ran)
         if r.kind == "sparse_leaf" and l.kind != "sparse_leaf":
             # A·S = (Sᵀ·Aᵀ)ᵀ — transpose the tile stack once, EAGERLY:
             # this code runs inside the executor's trace, and a traced
@@ -636,7 +653,7 @@ class Lowerer:
                 S._transposed_memo = st
             at = ev(l).T
             out = spmm_lib.apply(st, at, (l.shape[1], l.shape[0]),
-                                 self.config)
+                                 self.config, ran=self._ran)
             return fin(out.T)
         gram = None
         if l.kind == "transpose" and self._same_operand(l.children[0], r):
@@ -664,6 +681,7 @@ class Lowerer:
                 # materialised either.
                 from matrel_tpu.ops.gram import symmetric_gram
                 strategy = node.attrs.get("strategy", "xla")
+                self._ran(strategy)
                 if side == "AtA":
                     mm = lambda p, q: strategies.run_matmul(
                         strategy, p.T, q, self.mesh, self.config)
@@ -673,6 +691,7 @@ class Lowerer:
                 return fin(symmetric_gram(x, mm).astype(jnp.float32))
         a, b = ev(node.children[0]), ev(node.children[1])
         strategy = node.attrs.get("strategy", "xla")
+        self._ran(strategy)
         if self.config.reshard_peak_budget_bytes > 0:
             # staged reshard lowering (parallel/reshard.py): re-lay
             # each operand to the layout the strategy consumes through
@@ -1357,9 +1376,11 @@ def compile_exprs(exprs, mesh: Optional[Mesh] = None,
     fn = low.lower_multi(opts, leaf_order)
     with trace_lib.phase("plan.trace") as sp_tr:
         fn, extra = _hoist_large_consts(fn, _example_avals(leaf_order))
+    fn.__name__ = "matrel_plan_multi"
     meta = {"optimize_ms": round(sp_opt.dur_ms, 3),
             "trace_ms": round(sp_tr.dur_ms, 3),
-            "rule_hits": rule_hits}
+            "rule_hits": rule_hits,
+            "executors": low.executors or ["xla"]}
     if verify_diags is not None:
         meta["diagnostics"] = verify_diags
     prec_meta = _precision_meta(opts, cfg)
@@ -1601,10 +1622,13 @@ def compile_expr(expr: MatExpr, mesh: Optional[Mesh] = None,
     fn = low.lower(opt, leaf_order)
     with trace_lib.phase("plan.trace") as sp_tr:
         fn, extra = _hoist_large_consts(fn, _example_avals(leaf_order))
+    # a stable name for the profiler's ``XLA Modules`` line
+    fn.__name__ = f"matrel_plan_{opt.kind}"
     jitted = jax.jit(fn)
     meta = {"optimize_ms": round(sp_opt.dur_ms, 3),
             "trace_ms": round(sp_tr.dur_ms, 3),
-            "rule_hits": rule_hits}
+            "rule_hits": rule_hits,
+            "executors": low.executors or ["xla"]}
     if verify_diags is not None:
         meta["diagnostics"] = verify_diags
     prec_meta = _precision_meta((opt,), cfg)

@@ -7,7 +7,7 @@ surfaces). This package is the TPU rebuild's equivalent, three layers:
 
 - :mod:`matrel_tpu.obs.metrics` — process-wide metrics registry
   (counters / gauges / timing histograms; thread-safe, zero-dep), the
-  accumulator analogue. ``utils/profiling.StepTimer`` is a view over it.
+  accumulator analogue.
 - :mod:`matrel_tpu.obs.events` — structured JSONL event log, the Spark
   event-log analogue: ``MatrelSession`` emits one record per query run
   (optimize/compile/execute phases, rewrite-rule hits, plan-cache
@@ -23,11 +23,16 @@ surfaces). This package is the TPU rebuild's equivalent, three layers:
 Tier 2 (round 9) adds the runtime-behaviour surfaces on top:
 
 - :mod:`matrel_tpu.obs.trace` — structured tracing spans (parent-linked
-  ``span`` records through admission → plan → verify → trace →
-  execute; ``python -m matrel_tpu trace --export chrome`` renders them
-  as a Perfetto timeline) and the bounded in-memory flight recorder
+  ``span`` records through sql → compute (plan → compile → dispatch) →
+  fetch, the serve admission path and PageRank's host side;
+  ``python -m matrel_tpu trace --export chrome`` renders them as a
+  Perfetto timeline) and the bounded in-memory flight recorder
   (``config.obs_flight_recorder``) dumped as a post-mortem artifact on
-  verification/compile/serve failures.
+  verification/compile/serve failures. Four tiers: inactive, profiler
+  session (any running ``jax.profiler`` trace makes the spans
+  ``TraceAnnotation``s on the profiler's own clock, plus a ring read
+  with ``trace.profile_spans()`` — no config change), flight recorder,
+  full.
 - :mod:`matrel_tpu.obs.drift` — the cost-model drift auditor
   (``history --drift``): estimated bytes/FLOPs joined to measured
   per-op times, calibration ratios persisted per (strategy,
@@ -54,17 +59,17 @@ gets from Spark's live UI + metrics sink:
 Instrumentation is off-hot-path by contract: event assembly happens
 outside jitted code, per-op timing only under ``analyze=True``, and with
 ``config.obs_level == "off"`` (the default) plus the flight recorder
-off, the query path takes zero extra syncs, appends zero events and
-creates zero span objects.
+off and no profiler session running, the query path takes zero extra
+syncs, appends zero events and creates zero span objects.
 """
 
 from matrel_tpu.obs.events import EventLog, SCHEMA_VERSION, read_events
 from matrel_tpu.obs.metrics import MetricsRegistry, REGISTRY
 from matrel_tpu.obs.trace import (FlightRecorder, Span, Tracer,
-                                  chrome_trace, span)
+                                  chrome_trace, profile_spans, span)
 
 __all__ = [
     "EventLog", "FlightRecorder", "MetricsRegistry", "REGISTRY",
-    "SCHEMA_VERSION", "Span", "Tracer", "chrome_trace", "read_events",
-    "span",
+    "SCHEMA_VERSION", "Span", "Tracer", "chrome_trace", "profile_spans",
+    "read_events", "span",
 ]

@@ -5,7 +5,7 @@ The accumulator/metrics-system analogue of the reference (Spark
 accumulators + the metrics registry the UI reads). Thread-safe and
 dependency-free: the session, planner and executor record into the
 process registry; ``snapshot()`` is the read surface (the event log
-embeds slices of it, ``StepTimer.table()`` renders from it, the live
+embeds slices of it, the live
 metrics endpoint — obs/export.py — serves it).
 
 Design constraints, in order: recording must be cheap (a lock + a few
@@ -357,6 +357,5 @@ class MetricsRegistry:
             self._histograms.clear()
 
 
-#: Process-wide default registry — what the session and StepTimer use
-#: unless handed a private one.
+#: Process-wide default registry — what the session uses.
 REGISTRY = MetricsRegistry()

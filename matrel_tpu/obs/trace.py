@@ -1,33 +1,43 @@
 """Structured tracing spans + the flight recorder — obs tier 2.
 
 PR 1's event log says WHAT each query decided (strategies, estimated
-bytes, cache outcomes); this module says WHERE THE TIME WENT: a
-``span()`` context threaded through admission → plan → verify → trace →
-execute, emitting parent-linked records into the same schema-versioned
-event log, renderable as a Chrome/Perfetto timeline
-(``python -m matrel_tpu trace --export chrome``) so serve-pipeline
-overlap and admission-queue bubbles become visible.
+bytes, cache outcomes); this module says WHERE THE TIME WENT: one
+``span()`` context threaded through sql → compute (plan → compile →
+dispatch) → fetch, the serve admission path and PageRank's host side,
+emitting parent-linked records that share a ``qid`` per query.
 
-Three cost tiers, strictly ordered:
+Four cost tiers, strictly ordered:
 
-- **Inactive** (``obs_level="off"``, flight recorder off — the bench
-  default): :func:`span` returns a shared no-op singleton — no
-  allocation, no clock reads, no stack bookkeeping. ``phase()`` (the
-  executor's compile-phase form) still reads the clock because its
-  durations feed ``plan.meta`` regardless of observability, exactly as
-  the pre-span ``time.perf_counter()`` pairs did.
+- **Inactive** (no profiler session, ``obs_level="off"``, flight
+  recorder off — the bench default): :func:`span` returns a shared
+  no-op singleton — no allocation, no clock reads, no stack
+  bookkeeping. ``phase()`` (the executor's compile-phase form) still
+  reads the clock because its durations feed ``plan.meta`` regardless
+  of observability.
+- **Profiler session** (a ``jax.profiler`` trace is running —
+  ``start_trace`` around traffic, or ``start_server`` + a capture; no
+  config change): every span is also a
+  ``jax.profiler.TraceAnnotation("matrel.<name>", qid=...)`` on the
+  host plane of the profiler's own trace — the device's clock by
+  construction — and one record ``{name, start_ns, end_ns, span_id,
+  parent_id, qid, tid, attrs}`` in a process-wide bounded ring
+  (:func:`profile_spans`; ``time.time_ns()``, the profiler's host
+  clock). No span adds a device sync.
 - **Flight recorder only** (``config.obs_flight_recorder > 0``,
   obs off): spans are timed and appended to a bounded in-memory ring —
   no file I/O, no event assembly — so a field failure can dump the last
-  N records as a post-mortem artifact (a failed capture used to
-  leave one error string and nothing else).
+  N records as a post-mortem artifact.
 - **Full** (``obs_level != "off"``): span records ALSO append to the
   JSONL event log (``kind: "span"``), where ``history`` and the chrome
-  exporter read them back.
+  exporter (``python -m matrel_tpu trace --export chrome``) read them.
 
-Activation is per-thread (``activate()``): the session activates its
-tracer around each query/batch, and the serve admission worker
-activates it in its own thread, so parent links never cross threads.
+Activation is per-thread and per ENTRY CALL (``entry()``): the session
+opens its sql/compute/batch span with its tracer, ``to_numpy`` and
+``pagerank_edges`` keep the thread's, and the serve admission worker
+opens its own in its own thread, so parent links never cross threads.
+The entry span asks the profiler once whether a session runs
+(``TraceAnnotation.is_enabled()``) and hands the answer down the
+thread with the tracer; a span below it asks nothing.
 """
 
 from __future__ import annotations
@@ -36,6 +46,7 @@ import collections
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import List, Optional
@@ -44,51 +55,61 @@ from matrel_tpu.obs.events import SCHEMA_VERSION
 from matrel_tpu.utils import lockdep
 
 _SPAN_SEQ = itertools.count(1)
+_QID_SEQ = itertools.count(1)
 
-_tls = threading.local()
+#: Prefix of a span's name on the profiler's host plane and in the
+#: :func:`profile_spans` ring (beside the benchmark's ``bench.*``).
+PROFILE_PREFIX = "matrel."
 
-
-def _span_stack() -> list:
-    s = getattr(_tls, "stack", None)
-    if s is None:
-        s = _tls.stack = []
-    return s
-
-
-def active_tracer() -> Optional["Tracer"]:
-    return getattr(_tls, "tracer", None)
+_time_ns = time.time_ns
+_perf_counter = time.perf_counter
+_get_ident = threading.get_ident
+_KEEP = object()
+_trace_annotation = None
 
 
-class _Activation:
-    """Context manager installing a tracer for the current thread.
-    ``activate(None)`` is a sanctioned no-op (the session passes its
-    tracer straight through; sessions without one pay two attribute
-    writes per query)."""
+class _State:
+    """One thread's tracing state, installed by its open entry span."""
 
-    __slots__ = ("tracer", "_prev")
+    __slots__ = ("live", "tracer", "profiled", "current")
 
-    def __init__(self, tracer: Optional["Tracer"]):
-        self.tracer = tracer
-        self._prev = None
-
-    def __enter__(self):
-        self._prev = getattr(_tls, "tracer", None)
-        _tls.tracer = self.tracer
-        return self.tracer
-
-    def __exit__(self, *exc):
-        _tls.tracer = self._prev
-        return False
+    def __init__(self):
+        self.live = False       # tracer is not None or profiled
+        self.tracer = None      # the entry call's Tracer
+        self.profiled = False   # the entry call found a profiler session
+        self.current = None     # the innermost open live Span
 
 
-def activate(tracer: Optional["Tracer"]) -> _Activation:
-    return _Activation(tracer)
+class _ThreadState(threading.local):
+    """Holds the thread's :class:`_State` (made on the thread's first
+    read): one thread-local lookup per span, plain attribute reads
+    after it."""
+
+    def __init__(self):
+        self.st = _State()
+
+
+_tls = _ThreadState()
+
+
+def _profiler_on() -> bool:
+    """Whether a ``jax.profiler`` session is running (~0.1 us). jax is
+    looked up, never imported: a process that has not imported it (the
+    bench parents) has no session."""
+    global _trace_annotation
+    ta = _trace_annotation
+    if ta is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return False
+        ta = _trace_annotation = jax.profiler.TraceAnnotation
+    return ta.is_enabled()
 
 
 class _NoopSpan:
     """The inactive-path singleton: enters/exits without touching the
-    clock or the span stack. ``dur_ms`` stays None — callers that need
-    a duration unconditionally use :func:`phase` instead."""
+    clock or the thread's state. ``dur_ms`` stays None — callers that
+    need a duration unconditionally use :func:`phase` instead."""
 
     __slots__ = ()
     dur_ms = None
@@ -110,46 +131,81 @@ _NOOP = _NoopSpan()
 
 
 class Span:
-    """One timed scope. Parent-linked through the per-thread stack;
-    emitted through the owning tracer at exit (when there is one)."""
+    """One timed scope. Parent-linked through the thread's innermost
+    open span; emitted at exit through the owning tracer (when there is
+    one) and, under a profiler session, as a ``TraceAnnotation`` and a
+    record of the :func:`profile_spans` ring. A root span draws the
+    ``qid`` its descendants carry. An ENTRY span (:func:`entry`) also
+    installs its tracer and the profiler's answer for the thread while
+    it is open."""
 
-    __slots__ = ("name", "attrs", "tracer", "span_id", "parent_id",
-                 "t0", "t0_epoch", "dur_ms")
+    __slots__ = ("name", "attrs", "tracer", "profiled", "span_id",
+                 "parent_id", "qid", "t0", "start_ns", "dur_ms",
+                 "_annotation", "_profile_name", "_parent", "_outer",
+                 "_st")
 
     def __init__(self, name: str, tracer: Optional["Tracer"],
-                 attrs: dict):
+                 attrs: dict, profiled: bool, is_entry: bool,
+                 st: _State):
         self.name = name
         self.tracer = tracer
+        self.profiled = profiled
         self.attrs = attrs
+        self._st = st
         self.span_id = None
         self.parent_id = None
-        self.t0 = None
-        self.t0_epoch = None
+        self.qid = None
         self.dur_ms = None
+        self._outer = () if is_entry else None
 
     def __enter__(self):
-        if self.tracer is not None:
+        tracer, profiled = self.tracer, self.profiled
+        if tracer is not None or profiled:
+            tls = self._st
+            if self._outer is not None:
+                self._outer = (tls.live, tls.tracer, tls.profiled)
+                tls.live, tls.tracer, tls.profiled = True, tracer, profiled
+            parent = self._parent = tls.current
+            tls.current = self
             self.span_id = next(_SPAN_SEQ)
-            stack = _span_stack()
-            self.parent_id = stack[-1] if stack else None
-            stack.append(self.span_id)
-        self.t0_epoch = time.time()
-        self.t0 = time.perf_counter()
+            if parent is None:
+                self.qid = next(_QID_SEQ)
+            else:
+                self.parent_id = parent.span_id
+                self.qid = parent.qid
+            if profiled:
+                name = self._profile_name = PROFILE_PREFIX + self.name
+                self._annotation = _trace_annotation(name, qid=self.qid)
+                self._annotation.__enter__()
+        self.start_ns = _time_ns()
+        self.t0 = _perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self.dur_ms = (time.perf_counter() - self.t0) * 1e3
+        self.dur_ms = (_perf_counter() - self.t0) * 1e3
+        if self.span_id is None:
+            return False
+        tls = self._st
+        tls.current = self._parent
+        if self._outer is not None:
+            tls.live, tls.tracer, tls.profiled = self._outer
+        tid = _get_ident()
+        if self.profiled:
+            end_ns = _time_ns()
+            self._annotation.__exit__(exc_type, exc, tb)
+            # _RECORD_KEYS' order; profile_spans() makes the dict
+            _ring_append((self._profile_name, self.start_ns, end_ns,
+                          self.span_id, self.parent_id, self.qid, tid,
+                          self.attrs))
         if self.tracer is not None:
-            stack = _span_stack()
-            if stack and stack[-1] == self.span_id:
-                stack.pop()
             rec = {"name": self.name,
                    "span_id": self.span_id,
                    "parent_id": self.parent_id,
-                   "t0": round(self.t0_epoch, 6),
+                   "qid": self.qid,
+                   "t0": round(self.start_ns * 1e-9, 6),
                    "dur_ms": round(self.dur_ms, 3),
                    "pid": os.getpid(),
-                   "tid": threading.get_ident()}
+                   "tid": tid}
             if exc_type is not None:
                 # the error rides the span so a flight-recorder dump
                 # shows WHICH scope died, not just that something did
@@ -167,25 +223,47 @@ class Span:
     def elapsed_ms(self) -> float:
         """Wall milliseconds since enter — readable BEFORE exit (the
         serve batch reports its wall while still inside the span)."""
-        return (time.perf_counter() - self.t0) * 1e3
+        return (_perf_counter() - self.t0) * 1e3
+
+
+def entry(name: str, tracer=_KEEP, **attrs):
+    """The span an ENTRY call (``session.sql``, ``compute``, a batch,
+    the admission worker, ``to_numpy``, ``pagerank_edges``) wraps
+    itself in. It asks the profiler, once, whether a session runs, and
+    while it is open the thread's spans go to ``tracer`` (no argument:
+    the thread's own) and to the profiler's trace accordingly; a
+    :func:`span` below it asks nothing. With neither a tracer nor a
+    session — the default deployment — it is the no-op singleton: no
+    object, no clock read, no thread-local write."""
+    profiled = _profiler_on()
+    if tracer is None and not profiled:
+        return _NOOP
+    st = _tls.st
+    if tracer is _KEEP:
+        tracer = st.tracer
+        if tracer is None and not profiled:
+            return _NOOP
+    return Span(name, tracer, attrs, profiled, True, st)
 
 
 def span(name: str, **attrs):
-    """A span that costs NOTHING when no tracer is active for this
-    thread (the obs-off / recorder-off contract). Use everywhere the
-    duration is purely observational."""
-    tr = active_tracer()
-    if tr is None:
+    """A span that costs NOTHING when the entry call above it found
+    neither a tracer nor a profiler session (the obs-off / recorder-off
+    / profiler-off contract). Use everywhere the duration is purely
+    observational."""
+    st = _tls.st
+    if not st.live:
         return _NOOP
-    return Span(name, tr, attrs)
+    return Span(name, st.tracer, attrs, st.profiled, False, st)
 
 
 def phase(name: str, **attrs) -> Span:
     """A span that ALWAYS times (``dur_ms`` readable after exit) and
-    emits only when a tracer is active — for the executor's compile
+    emits only under a live entry span — for the executor's compile
     phases, whose durations feed ``plan.meta`` regardless of
     observability (the pre-span behaviour, one mechanism)."""
-    return Span(name, active_tracer(), attrs)
+    st = _tls.st
+    return Span(name, st.tracer, attrs, st.profiled, False, st)
 
 
 class Tracer:
@@ -252,6 +330,29 @@ class FlightRecorder:
             json.dump(artifact, f, default=repr)
         os.replace(tmp, path)
         return path
+
+
+#: Spans recorded while a profiler session ran, process-wide: a traced
+#: window of some thousand queries fits; an older record falls out.
+PROFILE_RING_CAPACITY = 16384
+_PROFILE_RING = FlightRecorder(PROFILE_RING_CAPACITY)
+# A span's exit appends a tuple straight to the ring's deque (one C
+# call, atomic under the interpreter's lock like the snapshot's copy):
+# the recorder's own lock and the dict cost a live span a tenth of its
+# time, and the reader can pay for the dict.
+_ring_append = _PROFILE_RING._buf.append
+_RECORD_KEYS = ("name", "start_ns", "end_ns", "span_id", "parent_id",
+                "qid", "tid", "attrs")
+
+
+def profile_spans() -> List[dict]:
+    """Snapshot of the profiler tier's ring, oldest first: one record
+    ``{name, start_ns, end_ns, span_id, parent_id, qid, tid, attrs}`` per
+    span that ended while a ``jax.profiler`` session ran. ``name`` is
+    the host-plane event's (``matrel.<name>``); the times are
+    ``time.time_ns()``, which is the trace's host clock plus the
+    session's start."""
+    return [dict(zip(_RECORD_KEYS, r)) for r in _PROFILE_RING.snapshot()]
 
 
 #: Default flight-recorder artifact name (cwd-relative, like the event

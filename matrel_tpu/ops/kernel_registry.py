@@ -382,6 +382,7 @@ def _build_pallas_generic(bs, npairs, n_out, out_dtype, interpret):
     )
     kernel = pl.pallas_call(
         _make_pair_kernel(prec, npairs),
+        name="matrel_pallas_generic",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_out, bs, bs), out_dtype),
         compiler_params=compat.tpu_compiler_params(
@@ -528,6 +529,7 @@ def _grouped_call(bs, G, n_groups, n_out, out_dtype, interpret,
     )
     return pl.pallas_call(
         _make_grouped_kernel(prec, n_groups),
+        name="matrel_pallas_grouped",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((out_n, bs, bs), out_dtype),
         compiler_params=compat.tpu_compiler_params(
@@ -598,6 +600,7 @@ def _band_call(bs, wa, rc, gr, nchunks, out_dtype, interpret):
 
     return pl.pallas_call(
         kern,
+        name="matrel_pallas_band",
         grid=(gr, nchunks),
         in_specs=[
             pl.BlockSpec((1, bs, wa * bs), lambda i, j: (i, 0, 0)),

@@ -111,6 +111,7 @@ def spmm_call(bs: int, tm: int, m_tiles: int, nnzb: int, gr: int,
                  else jax.lax.Precision.HIGHEST)
     return pl.pallas_call(  # matlint: disable=ML009 legacy SpMM kernel, unported to the registry this round (block-sparse x DENSE path; registry covers S x S)
         _make_kernel(precision, nnzb),
+        name="matrel_spmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((gr * bs, pm), out_dtype),
         compiler_params=compat.tpu_compiler_params(

@@ -86,6 +86,7 @@ def _compact_runner(nb: int, cap: int, block: int, lo: int, passes: int,
     cr = cap // LANE
     scatter = pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
         _make_scatter_kernel(hi_n, lo, passes),
+        name="matrel_spmv_scatter",
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1, cr, LANE), lambda b: (b, 0, 0)),
@@ -370,6 +371,7 @@ def _compact_runner_k(nb: int, cap: int, block: int, lo: int,
     cr = cap // LANE
     return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
         _make_scatter_kernel_k(hi_n, lo, passes, k),
+        name="matrel_spmv_scatter",
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1, cr, LANE), lambda b: (b, 0, 0)),

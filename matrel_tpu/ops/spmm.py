@@ -86,6 +86,7 @@ def _cached_runner(S, pm, out_pshape, d_spec, out_sharding, cfg, interpret,
                                         out_sharding, cfg, interpret=interpret)
         else:
             run = _xla_spmm(S, pm, out_pshape, d_spec, out_sharding, cfg)
+        run.executor = "pallas_spmm" if use_pallas else "xla"
         _RUNNER_CACHE[key] = run
         if id(S) not in _FINALIZER_IDS:
             _FINALIZER_IDS.add(id(S))
@@ -104,7 +105,7 @@ def _dense_spec(pm: int, mesh) -> P:
 def apply(S: BlockSparseMatrix, dd: jax.Array,
           d_shape: Tuple[int, int],
           config: Optional[MatrelConfig] = None,
-          interpret=None, epilogue=None) -> jax.Array:
+          interpret=None, epilogue=None, ran=None) -> jax.Array:
     """Trace-compatible SpMM: S (static metadata) × dense padded array
     ``dd`` of logical shape ``d_shape``. Returns the padded product with
     canonical output sharding.
@@ -115,7 +116,9 @@ def apply(S: BlockSparseMatrix, dd: jax.Array,
     compiles as the SpMM's epilogue instead of its own dispatch. The
     runner itself is epilogue-agnostic (one cached kernel per matrix,
     never forked per epilogue); None keeps the historical path
-    bit-identically."""
+    bit-identically. ``ran`` is told which runner the product goes to
+    (``"pallas_spmm"`` or ``"xla"`` — the executor's
+    ``plan.meta["executors"]``)."""
     cfg = config or default_config()
     n, k = S.shape
     k2, m = d_shape
@@ -130,6 +133,8 @@ def apply(S: BlockSparseMatrix, dd: jax.Array,
     d_spec = _dense_spec(pm, mesh)
     run = _cached_runner(S, pm, out_pshape, d_spec, out_sharding, cfg,
                          interpret, explicit_interpret)
+    if ran is not None:
+        ran(run.executor)
     out = run(S.blocks, S.block_rows, S.block_cols, dd)
     return out if epilogue is None else epilogue(out)
 

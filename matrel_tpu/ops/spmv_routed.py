@@ -310,6 +310,7 @@ def _routed_runner(g_s: int, g_d: int, cap: int, passes: int,
 
     gather = pl.pallas_call(  # matlint: disable=ML009 legacy routed-SpMV reference kernel, unported to the registry this round (kept as a reference formulation)
         _make_gather_kernel(passes),
+        name="matrel_spmv_routed_gather",
         grid=(g_s, g_d),
         in_specs=[
             pl.BlockSpec(cell, lambda gs, gd: (gs, gd, 0, 0)),
@@ -327,6 +328,7 @@ def _routed_runner(g_s: int, g_d: int, cap: int, passes: int,
     # source-major tables directly — the shuffle is this index map
     scatter = pl.pallas_call(  # matlint: disable=ML009 legacy routed-SpMV reference kernel, unported to the registry this round (kept as a reference formulation)
         _make_scatter_kernel(g_s, passes),
+        name="matrel_spmv_routed_scatter",
         grid=(g_d, g_s),
         in_specs=[
             pl.BlockSpec(cell, lambda gd, gs: (gs, gd, 0, 0)),

@@ -518,19 +518,16 @@ class ServePipeline:
             # admission fault exercises the same bisection/re-admission
             # path as any other batch failure (free when off)
             faults_lib.check("serve_admit", self.session.config)
-            # worker-thread tracer activation: the admission
+            # the worker thread's entry span: the admission
             # span is the serve trail's root — run_many's
             # batch/plan/execute spans parent-link under it,
             # so a chrome export shows queue bubbles next to
             # compile/execute overlap
-            with trace_lib.activate(
-                    getattr(self.session, "_tracer", None)), \
-                    trace_lib.span(
-                        "serve.admit", batch=len(batch),
-                        inflight=len(self._inflight),
-                        bisect_depth=depth,
-                        max_wait_ms=(max(waits_ms)
-                                     if waits_ms else 0.0)):
+            with trace_lib.entry(
+                    "serve.admit", getattr(self.session, "_tracer", None),
+                    batch=len(batch), inflight=len(self._inflight),
+                    bisect_depth=depth,
+                    max_wait_ms=max(waits_ms) if waits_ms else 0.0):
                 outs = self.session.run_many(
                     [it[0] for it in batch],
                     precision=sla,

@@ -460,27 +460,20 @@ class MatrelSession:
         so a degraded plan never shares a cache slot with the stamped
         original (the axisw/prec prefix idiom)."""
         sla = sla if sla is not None else self.config.precision_sla
-        # fault site "compile" (resilience/faults.py): free when off
-        faults_lib.check("compile", self.config)
-        key, pins = _plan_key(e)
-        key = (degrade_lib.key_prefix(rung) + self._axisw_prefix()
-               + self._coeff_prefix() + _prec_prefix(sla) + key)
-        with self._compile_lock:
-            plan = self._plan_cache.get(key)
-            if plan is not None:
-                self._plan_cache.move_to_end(key)
-                return plan, True, key
-            try:
-                plan = executor_lib.compile_expr(
-                    e, self.mesh,
-                    degrade_lib.apply_rung(self._sla_config(sla), rung))
-            except Exception as ex:
-                # post-mortem trail BEFORE the error propagates: a
-                # VerificationError / compile failure in the field
-                # leaves the flight-recorder artifact, not just the
-                # exception string (no-op when the recorder is off)
-                self._flight_auto_dump(ex)
-                raise
+        with trace_lib.span("plan") as sp:
+            # fault site "compile" (resilience/faults.py): free when off
+            faults_lib.check("compile", self.config)
+            key, pins = _plan_key(e)
+            key = (degrade_lib.key_prefix(rung) + self._axisw_prefix()
+                   + self._coeff_prefix() + _prec_prefix(sla) + key)
+            plan = self._plan_probe(key, sp)
+        if plan is not None:
+            return plan, True, key
+
+        def build():
+            plan = executor_lib.compile_expr(
+                e, self.mesh,
+                degrade_lib.apply_rung(self._sla_config(sla), rung))
             # pin every id()-keyed object on the cached plan: a garbage-
             # collected object's address can be REUSED by CPython, and a
             # later distinct object at the recycled address would falsely
@@ -489,6 +482,40 @@ class MatrelSession:
             # reachable from the expr, so its old value is pinned
             # explicitly via the collected pins list.
             plan._cache_pin = (e, pins)
+            return plan
+
+        plan, hit = self._plan_build(key, build, rung)
+        return plan, hit, key
+
+    def _plan_probe(self, key: str, sp):
+        """The plan cache's answer for ``key`` (None: a miss), noted on
+        the ``plan`` span as ``hit``."""
+        with self._compile_lock:
+            plan = self._plan_cache.get(key)
+            if plan is not None:
+                self._plan_cache.move_to_end(key)
+        sp.set(hit=plan is not None)
+        return plan
+
+    def _plan_build(self, key: str, build, rung: int):
+        """(plan, hit) after a probe missed: compile under the lock and
+        the ``compile`` span, insert, evict. ``hit`` is True when a
+        racing caller compiled the same key between probe and lock."""
+        with self._compile_lock:
+            plan = self._plan_cache.get(key)
+            if plan is not None:
+                return plan, True
+            with trace_lib.span("compile") as sp:
+                try:
+                    plan = build()
+                except Exception as ex:
+                    # post-mortem trail BEFORE the error propagates: a
+                    # VerificationError / compile failure in the field
+                    # leaves the flight-recorder artifact, not just the
+                    # exception string (no-op when the recorder is off)
+                    self._flight_auto_dump(ex)
+                    raise
+                sp.set(executors=plan.meta.get("executors"))
             if rung:
                 # the rung rides the plan so obs events / explain say
                 # WHICH ladder step produced this attempt's plan
@@ -496,7 +523,7 @@ class MatrelSession:
             self._plan_cache[key] = plan
             self._plan_cache_bytes += _plan_bytes(plan)
             self._evict_plans()
-            return plan, False, key
+            return plan, False
 
     def _axisw_prefix(self) -> str:
         """Topology weights change which strategies get stamped, so
@@ -548,41 +575,36 @@ class MatrelSession:
         its root-key order (``_root_keys``) so callers can map outputs
         back to their own root order."""
         sla = sla if sla is not None else self.config.precision_sla
-        # fault site "compile": the MultiPlan twin shares the site
-        faults_lib.check("compile", self.config)
-        keyed = []
-        pins_all: list = []
-        for e in roots:
-            k, p = _plan_key(e)
-            keyed.append(k)
-            pins_all.extend(p)
-        uniq: "OrderedDict[str, MatExpr]" = OrderedDict()
-        for k, e in zip(keyed, roots):
-            uniq.setdefault(k, e)
-        skeys = sorted(uniq)
-        mkey = ("multi:" + degrade_lib.key_prefix(rung)
-                + self._axisw_prefix() + self._coeff_prefix()
-                + _prec_prefix(sla) + "||".join(skeys))
-        with self._compile_lock:
-            plan = self._plan_cache.get(mkey)
-            if plan is not None:
-                self._plan_cache.move_to_end(mkey)
-                return plan, True, keyed
-            try:
-                plan = executor_lib.compile_exprs(
-                    [uniq[k] for k in skeys], self.mesh,
-                    degrade_lib.apply_rung(self._sla_config(sla), rung))
-            except Exception as ex:
-                self._flight_auto_dump(ex)   # same trail as the
-                raise                        # single-plan entry
-            if rung:
-                plan.meta["degrade"] = degrade_lib.rung_meta(rung)
+        with trace_lib.span("plan", roots=len(roots)) as sp:
+            # fault site "compile": the MultiPlan twin shares the site
+            faults_lib.check("compile", self.config)
+            keyed = []
+            pins_all: list = []
+            for e in roots:
+                k, p = _plan_key(e)
+                keyed.append(k)
+                pins_all.extend(p)
+            uniq: "OrderedDict[str, MatExpr]" = OrderedDict()
+            for k, e in zip(keyed, roots):
+                uniq.setdefault(k, e)
+            skeys = sorted(uniq)
+            mkey = ("multi:" + degrade_lib.key_prefix(rung)
+                    + self._axisw_prefix() + self._coeff_prefix()
+                    + _prec_prefix(sla) + "||".join(skeys))
+            plan = self._plan_probe(mkey, sp)
+        if plan is not None:
+            return plan, True, keyed
+
+        def build():
+            plan = executor_lib.compile_exprs(
+                [uniq[k] for k in skeys], self.mesh,
+                degrade_lib.apply_rung(self._sla_config(sla), rung))
             plan._cache_pin = (tuple(uniq[k] for k in skeys), pins_all)
             plan._root_keys = tuple(skeys)
-            self._plan_cache[mkey] = plan
-            self._plan_cache_bytes += _plan_bytes(plan)
-            self._evict_plans()
-            return plan, False, keyed
+            return plan
+
+        plan, hit = self._plan_build(mkey, build, rung)
+        return plan, hit, keyed
 
     def _evict_plans(self) -> None:
         """Drop least-recently-used plans past the config bounds. The
@@ -606,6 +628,16 @@ class MatrelSession:
         return {"plans": len(self._plan_cache),
                 "hoisted_bytes": self._plan_cache_bytes,
                 "evicted": self._plan_cache_evicted}
+
+    def plan_executors(self) -> List[List[str]]:
+        """Which executors each cached plan runs
+        (``plan.meta["executors"]``), least recently used first. Kept
+        out of ``plan_cache_info()``, which rides every obs-on query
+        record and metrics snapshot: this one is per plan. Takes no
+        lock — a compile in another thread holds ``_compile_lock`` for
+        seconds; the copy of the values is one C call."""
+        return [list(p.meta.get("executors") or ())
+                for p in list(self._plan_cache.values())]
 
     def _replan_warm(self, classes) -> dict:
         """Proactively recompile cached plans whose matmul decisions
@@ -1591,14 +1623,16 @@ class MatrelSession:
         # HeldAcrossDispatch diagnostic — the PR 8 drain-wedge class
         # caught at runtime. One flag check when off.
         lockdep.note_dispatch("session.dispatch")
-        if self._exec_lock is None:
-            return plan.run(bindings=bindings)
-        with self._exec_lock:
-            out = plan.run(bindings=bindings)
-            for o in (out if isinstance(out, (list, tuple))
-                      else (out,)):
-                o.data.block_until_ready()
-            return out
+        with trace_lib.span("dispatch",
+                            executors=plan.meta.get("executors")):
+            if self._exec_lock is None:
+                return plan.run(bindings=bindings)
+            with self._exec_lock:
+                out = plan.run(bindings=bindings)
+                for o in (out if isinstance(out, (list, tuple))
+                          else (out,)):
+                    o.data.block_until_ready()
+                return out
 
     def _emit_placement_event(self, record: dict) -> None:
         """One ``placement`` record per fleet-routed submission
@@ -1707,21 +1741,24 @@ class MatrelSession:
         if pol is not None:
             return self._compute_resilient(e, rc, sla, pol,
                                            tenant=tenant)
-        if (not rc and not self._obs_enabled()
-                and self._tracer is None and not self._cse_on()):
-            # the production path: zero event assembly, zero extra
-            # device syncs, zero span objects, zero cache-key walks
-            # beyond the plan cache's own (the obs_level="off" /
-            # result_cache_max_bytes=0 / flight-recorder-off /
-            # cse-off contract bench.py relies on; with cse_enable a
-            # single query must still reach the template probe/insert
-            # seam in _compute_observed)
-            return self._arbitrated_run(
-                self._compile_entry(e, sla=sla)[0])
-        # per-thread tracer activation: executor compile phases and
-        # every span below parent-link into this query's trail
-        with trace_lib.activate(self._tracer), \
-                trace_lib.span("query", root_kind=e.kind):
+        fast = (not rc and not self._obs_enabled()
+                and self._tracer is None and not self._cse_on())
+        # the entry span: executor compile phases and every span below
+        # parent-link into this query's trail. One span set for the
+        # three ways through compute; with no tracer and no profiler
+        # session it is the no-op singleton
+        with trace_lib.entry("compute", self._tracer, root_kind=e.kind,
+                             path="fast" if fast else "observed"):
+            if fast:
+                # the production path: zero event assembly, zero extra
+                # device syncs, zero span objects, zero cache-key walks
+                # beyond the plan cache's own (the obs_level="off" /
+                # result_cache_max_bytes=0 / flight-recorder-off /
+                # cse-off contract bench.py relies on; with cse_enable
+                # a single query must still reach the template
+                # probe/insert seam in _compute_observed)
+                return self._arbitrated_run(
+                    self._compile_entry(e, sla=sla)[0])
             return self._compute_observed(e, rc, sla, tenant=tenant)
 
     def _compute_observed(self, e: MatExpr, rc: bool,
@@ -1754,20 +1791,18 @@ class MatrelSession:
                                        ent=ent)
                 return ent.result
         bindings = cache_label = None
-        with trace_lib.span("plan"):
-            # plan-template probe (serve/mqo.py): a structurally
-            # identical query modulo dense-leaf bindings rebinds into
-            # the cached template's program — zero optimize/trace
-            tpl = (self._template_probe(e, sla, rung)
-                   if self._cse_on() else None)
-            if tpl is not None:
-                plan, pkey, bindings = tpl
-                hit, cache_label = True, "template_hit"
-            else:
-                plan, hit, pkey = self._compile_entry(e, sla=sla,
-                                                      rung=rung)
-                if self._cse_on() and not hit:
-                    self._template_insert(e, plan, sla, rung)
+        # plan-template probe (serve/mqo.py): a structurally
+        # identical query modulo dense-leaf bindings rebinds into
+        # the cached template's program — zero optimize/trace
+        tpl = (self._template_probe(e, sla, rung)
+               if self._cse_on() else None)
+        if tpl is not None:
+            plan, pkey, bindings = tpl
+            hit, cache_label = True, "template_hit"
+        else:
+            plan, hit, pkey = self._compile_entry(e, sla=sla, rung=rung)
+            if self._cse_on() and not hit:
+                self._template_insert(e, plan, sla, rung)
         # fault site "execute": the host-side dispatch point — the main
         # retryable site (per attempt, unlike the trace-time sites)
         faults_lib.check("execute", self.config)
@@ -1776,10 +1811,10 @@ class MatrelSession:
                                      bindings=bindings,
                                      cache_label=cache_label)
         else:
-            # flight-recorder-only tier: the span marks DISPATCH (JAX
-            # async — deliberately no added sync; always-cheap)
-            with trace_lib.span("query.execute"):
-                out = self._arbitrated_run(plan, bindings=bindings)
+            # profiler / flight-recorder tiers: the ``dispatch`` span
+            # inside marks DISPATCH (JAX async — deliberately no added
+            # sync; always-cheap)
+            out = self._arbitrated_run(plan, bindings=bindings)
         summary = None
         if self._prov is not None:
             # capture BEFORE the cache insert so the new CacheEntry's
@@ -1814,9 +1849,9 @@ class MatrelSession:
         while True:
             deadline.raise_if_expired()
             try:
-                with trace_lib.activate(self._tracer), \
-                        trace_lib.span("query", root_kind=e.kind,
-                                       attempt=attempt, rung=rung):
+                with trace_lib.entry("compute", self._tracer,
+                                     root_kind=e.kind, path="resilient",
+                                     attempt=attempt, rung=rung):
                     out = self._compute_observed(
                         e, rc and rung < degrade_lib.RC_BYPASS_RUNG,
                         sla, rung=rung, tenant=tenant)
@@ -1928,8 +1963,8 @@ class MatrelSession:
                                             _brownout_rung=_brownout_rung)
         rc = self._rc_enabled()
         obs = self._obs_enabled()
-        with trace_lib.activate(self._tracer), \
-                trace_lib.span("serve.batch", size=len(es)) as sp_batch:
+        with trace_lib.entry("serve.batch", self._tracer,
+                             size=len(es)) as sp_batch:
             return self._run_many_observed(es, rc, obs, sp_batch,
                                            _queue_wait_ms,
                                            _inflight_depth, sla,
@@ -1954,10 +1989,9 @@ class MatrelSession:
                   and rung < degrade_lib.RC_BYPASS_RUNG)
             obs = self._obs_enabled()
             try:
-                with trace_lib.activate(self._tracer), \
-                        trace_lib.span("serve.batch", size=len(es),
-                                       attempt=attempt,
-                                       rung=rung) as sp_batch:
+                with trace_lib.entry("serve.batch", self._tracer,
+                                     size=len(es), attempt=attempt,
+                                     rung=rung) as sp_batch:
                     outs = self._run_many_observed(
                         es, rc, obs, sp_batch, _queue_wait_ms,
                         _inflight_depth, sla, rung=rung,
@@ -1987,6 +2021,10 @@ class MatrelSession:
                            _brownout_rung: Optional[int] = None
                            ) -> List[BlockMatrix]:
         sla = sla if sla is not None else self.config.precision_sla
+        if _queue_wait_ms is not None:
+            # per-query admission wait, readable on the span with obs
+            # off (a profiler session, the flight recorder)
+            sp_batch.set(queue_wait_ms=_queue_wait_ms)
 
         def _tenant_of(i):
             return (_tenants[i] if _tenants is not None
@@ -2029,20 +2067,18 @@ class MatrelSession:
                 pend, cse_hoisted = self._cse_hoist_batch(pend, sla,
                                                           rung, rc)
             bindings = None
-            with trace_lib.span("plan", roots=len(pend)):
-                tpl = (self._template_probe_multi(
-                    [e for _, e in pend], sla, rung)
-                    if self._cse_on() else None)
-                if tpl is not None:
-                    plan, keys, pos, bindings = tpl
-                    plan_hit = tpl_hit = True
-                else:
-                    plan, plan_hit, keys = self._compile_multi_entry(
-                        [e for _, e in pend], sla=sla, rung=rung)
-                    pos = {k: j
-                           for j, k in enumerate(plan._root_keys)}
-                    if self._cse_on() and not plan_hit:
-                        self._template_insert_multi(plan, sla, rung)
+            tpl = (self._template_probe_multi(
+                [e for _, e in pend], sla, rung)
+                if self._cse_on() else None)
+            if tpl is not None:
+                plan, keys, pos, bindings = tpl
+                plan_hit = tpl_hit = True
+            else:
+                plan, plan_hit, keys = self._compile_multi_entry(
+                    [e for _, e in pend], sla=sla, rung=rung)
+                pos = {k: j for j, k in enumerate(plan._root_keys)}
+                if self._cse_on() and not plan_hit:
+                    self._template_insert_multi(plan, sla, rung)
             # fault site "execute" — per batch attempt (host side)
             faults_lib.check("execute", self.config)
             # the batch's execute span: under obs the sync happens
@@ -2344,7 +2380,8 @@ class MatrelSession:
         """SQL-ish entry point over registered matrix tables (the reference's
         SQL surface, SURVEY.md §2 'SQL entry point'). See sql.py."""
         from matrel_tpu.sql import parse_sql
-        return parse_sql(query, self)
+        with trace_lib.entry("sql", self._tracer, chars=len(query)):
+            return parse_sql(query, self)
 
     def explain_sql(self, query: str, analyze: bool = False) -> str:
         """Optimized-plan text for a SQL query — the EXPLAIN analogue

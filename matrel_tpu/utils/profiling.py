@@ -1,94 +1,17 @@
-"""Tracing / profiling — the Spark-UI/SparkListener analogue
-(SURVEY.md §5 "Tracing / profiling").
-
-The reference gets stage/task timelines from the Spark UI for free; here:
-  - ``trace(dir)``: jax.profiler context writing TensorBoard/Perfetto traces
-  - ``annotate``: named_scope so each physical operator is visible in XLA
-    traces (the executor wraps every node lowering — structurally
-    enforced by tests/test_obs.py)
-  - ``StepTimer``: wall-clock per-step table with device sync — since the
-    obs/ subsystem landed, a thin VIEW over a
-    :class:`matrel_tpu.obs.metrics.MetricsRegistry` (timings record as
-    histograms, ``count`` as counters), so ad-hoc timer use and the
-    session's query metrics share one aggregation surface instead of the
-    old private dicts.
+"""Named scopes per physical operator (SURVEY.md §5 "Tracing /
+profiling"): the executor wraps every node lowering in ``annotate``
+(structurally enforced by tests/test_obs.py), so the operator's label
+rides the HLO's ``op_name`` and, in a profiler trace, the ``tf_op``
+stat of each device operation's event metadata
+(``jit(matrel_plan_agg)/matrel.agg/reduce_sum``) — what TensorBoard /
+xprof show beside an op. Timing lives in ``matrel_tpu/obs/``.
 """
 
 from __future__ import annotations
 
-import contextlib
-import time
-from typing import Iterator, Optional
-
 import jax
-
-from matrel_tpu.obs.metrics import MetricsRegistry
-
-
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    """Capture an XLA profiler trace (view in TensorBoard/Perfetto)."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 def annotate(name: str):
     """Named scope that shows up in profiler timelines per operator."""
     return jax.named_scope(name)
-
-
-class StepTimer:
-    """Per-step wall-clock accounting with explicit device sync, backed
-    by a metrics registry (private by default — back-compat with the
-    original free-standing timer; pass the process
-    :data:`matrel_tpu.obs.metrics.REGISTRY` to aggregate with the
-    session's query metrics).
-
-    Usage:
-        t = StepTimer()
-        with t.step("matmul"):
-            out = plan.run(); out.block_until_ready()
-        print(t.table())
-    """
-
-    def __init__(self, registry: Optional[MetricsRegistry] = None):
-        self.registry = registry or MetricsRegistry()
-        self._steps: list = []      # insertion order for table()
-        self._counts: list = []
-
-    @contextlib.contextmanager
-    def step(self, name: str, sync: Optional[jax.Array] = None):
-        t0 = time.perf_counter()
-        yield
-        if sync is not None:
-            sync.block_until_ready()
-        if name not in self._steps:
-            self._steps.append(name)
-        self.registry.histogram(f"step.{name}").observe(
-            time.perf_counter() - t0)
-
-    def count(self, name: str, value: float = 1.0) -> None:
-        """Accumulator-style counter (the reference counts e.g. nnz
-        processed via Spark accumulators)."""
-        if name not in self._counts:
-            self._counts.append(name)
-        self.registry.counter(name).inc(value)
-
-    @property
-    def counters(self) -> dict:
-        """Name → accumulated value (the pre-obs dict surface)."""
-        return {n: self.registry.counter(n).value for n in self._counts}
-
-    def table(self) -> str:
-        lines = [f"{'step':<28}{'count':>6}{'total_s':>10}{'mean_ms':>10}"]
-        for name in self._steps:
-            h = self.registry.histogram(f"step.{name}")
-            lines.append(f"{name:<28}{h.count:>6}{h.total:>10.3f}"
-                         f"{1e3 * h.mean:>10.2f}")
-        for name in self._counts:
-            v = self.registry.counter(name).value
-            lines.append(f"{name:<28}{'-':>6}{v:>10.0f}{'':>10}")
-        return "\n".join(lines)

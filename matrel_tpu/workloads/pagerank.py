@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from matrel_tpu.config import MatrelConfig, on_tpu
 from matrel_tpu.core.blockmatrix import BlockMatrix
+from matrel_tpu.obs import trace as trace_lib
 
 log = logging.getLogger("matrel_tpu.pagerank")
 
@@ -83,6 +84,32 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
     """
     if impl not in ("auto", "segment", "onehot"):
         raise ValueError(f"unknown impl {impl!r}")
+    with trace_lib.entry("pagerank") as sp:
+        out, path = _pagerank_edges(src, dst, n, rounds, alpha, mesh,
+                                    impl, weights, passes)
+        _PATH_COUNTS[path] += 1
+        sp.set(impl=path)
+        return out
+
+
+# How many pagerank_edges calls each executor answered, process-wide.
+_PATH_COUNTS = dict.fromkeys(
+    ("compact", "compact_sharded", "onehot", "onehot_sharded", "segment"),
+    0)
+
+
+def path_counts() -> dict:
+    """Calls of :func:`pagerank_edges` by the executor that answered:
+    ``compact`` / ``compact_sharded`` (compact tables through the Pallas
+    SpMV), ``onehot`` / ``onehot_sharded`` (expanded tables, XLA),
+    ``segment`` (gather + segment-sum) — the ``impl`` the
+    ``matrel.pagerank`` span carries. A copy."""
+    return dict(_PATH_COUNTS)
+
+
+def _pagerank_edges(src, dst, n, rounds, alpha, mesh, impl, weights,
+                    passes):
+    """(ranks, the executor that ran) — pagerank_edges under its span."""
     if impl == "onehot":
         # explicit choice: any backend; with mesh= the sharded variant
         # (plan tables row-decomposed over every device)
@@ -94,15 +121,18 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
         if mesh is not None:
             from matrel_tpu.config import pallas_enabled
             if pallas_enabled():
+                path = "compact_sharded"
                 out = _pagerank_compact_sharded(
                     src, dst, n, rounds, alpha, mesh, max_slots=None,
                     weights=weights, passes=passes)
             else:
+                path = "onehot_sharded"
                 out = _pagerank_onehot_sharded(src, dst, n, rounds,
                                                alpha, mesh,
                                                max_slots=None,
                                                weights=weights)
         else:
+            path = _single_device_path()
             out = _pagerank_onehot(src, dst, n, rounds, alpha,
                                    weights=weights, passes=passes)
         if out is None:
@@ -110,7 +140,7 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
                 "impl='onehot' requested but the graph's degree "
                 "distribution is too heavy-tailed for the one-hot plan "
                 "(build_spmv_plan refused); use impl='segment' or 'auto'")
-        return out
+        return out, path
     if impl == "auto":
         # The one-hot MXU matvec (ops/spmv.py) beats segment_sum ~5× on
         # TPU; on CPU the extra one-hot FLOPs lose, so auto keeps the
@@ -126,21 +156,24 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
             if mesh is not None:
                 from matrel_tpu.config import pallas_enabled
                 if pallas_enabled():
+                    path = "compact_sharded"
                     out = _pagerank_compact_sharded(
                         src, dst, n, rounds, alpha, mesh,
                         max_slots=_auto_max_slots() * mesh.size,
                         weights=weights, passes=passes)
                 else:
+                    path = "onehot_sharded"
                     out = _pagerank_onehot_sharded(
                         src, dst, n, rounds, alpha, mesh,
                         max_slots=_PLAN_CACHE_MAX_SLOTS * mesh.size,
                         weights=weights)
             else:
+                path = _single_device_path()
                 out = _pagerank_onehot(src, dst, n, rounds, alpha,
                                        max_slots=_auto_max_slots(),
                                        weights=weights, passes=passes)
             if out is not None:
-                return out
+                return out, path
             log.warning(
                 "pagerank_edges: the one-hot plan refused this graph "
                 "(degree tail cannot be padded, or the plan exceeds "
@@ -152,7 +185,21 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
          else jnp.asarray(weights, jnp.float32))
     prepare, run = _edges_runner(int(n), int(rounds), float(alpha))
     src, dst, w = prepare(src, dst, w)
-    return run(src, dst, w)
+    return _dispatch(run, src, dst, w), "segment"
+
+
+def _single_device_path() -> str:
+    """What _pagerank_onehot runs: the compact tables through the
+    Pallas SpMV where Pallas is on, the expanded tables otherwise."""
+    from matrel_tpu.config import pallas_enabled
+    return "compact" if pallas_enabled() else "onehot"
+
+
+def _dispatch(run, *args):
+    """The call of a jitted round loop, until it returns (the device
+    runs on)."""
+    with trace_lib.span("pagerank.dispatch"):
+        return run(*args)
 
 
 def prepare_pagerank_onehot(src, dst, n: int, max_slots: int = None,
@@ -202,7 +249,7 @@ def run_pagerank_onehot(prepared, rounds: int = 30,
     run = _onehot_runner(plan.n_rows, int(rounds), float(alpha),
                          (plan.n_rows, plan.n_cols, plan.block),
                          len(plan.arrays()))
-    return run(plan.arrays(), dangling)
+    return _dispatch(run, plan.arrays(), dangling)
 
 
 def run_pagerank_compact(prepared, rounds: int = 30, alpha: float = 0.85,
@@ -228,7 +275,7 @@ def run_pagerank_compact(prepared, rounds: int = 30, alpha: float = 0.85,
                                (plan.n_rows, plan.n_cols, plan.block,
                                 spmv_lib.LO),
                                len(ov), int(passes), bool(interpret))
-    return run(tables, ov, dangling)
+    return _dispatch(run, tables, ov, dangling)
 
 
 # Prepared-plan cache for the auto path: repeated pagerank_edges calls on
@@ -260,36 +307,42 @@ def _cache_get_or_insert(key, build, per_dev_slots_of):
     """Byte-aware cache: values are (prepared, per_dev_slots). ``build``
     runs on a miss (may return None = refused); oversized results are
     returned uncached."""
-    hit = _PLAN_CACHE.get(key)
-    if hit is not None:
-        return hit[0]
-    prepared = build()
-    if prepared is None:
-        return None
-    cost = per_dev_slots_of(prepared)
-    if cost <= _PLAN_CACHE_MAX_SLOTS:
-        total = sum(c for _, c in _PLAN_CACHE.values())
-        while _PLAN_CACHE and total + cost > _PLAN_CACHE_MAX_SLOTS:
-            total -= _PLAN_CACHE.pop(next(iter(_PLAN_CACHE)))[1]
-        _PLAN_CACHE[key] = (prepared, cost)
-    return prepared
+    with trace_lib.span("pagerank.plan") as sp:
+        hit = _PLAN_CACHE.get(key)
+        sp.set(hit=hit is not None)
+        if hit is not None:
+            return hit[0]
+        prepared = build()
+        if prepared is None:
+            return None
+        cost = per_dev_slots_of(prepared)
+        if cost <= _PLAN_CACHE_MAX_SLOTS:
+            total = sum(c for _, c in _PLAN_CACHE.values())
+            while _PLAN_CACHE and total + cost > _PLAN_CACHE_MAX_SLOTS:
+                total -= _PLAN_CACHE.pop(next(iter(_PLAN_CACHE)))[1]
+            _PLAN_CACHE[key] = (prepared, cost)
+        return prepared
 
 
 def _graph_fingerprint(src, dst, n: int, weights=None) -> tuple:
     import hashlib
     h = hashlib.blake2b(digest_size=16)
     sizes = []
-    for a in (src, dst):
-        # canonicalize to int32 (node ids fit by construction) so the
-        # same graph hashes identically whatever index dtype it arrives
-        # in; no copy when it already is int32
-        a = np.ascontiguousarray(np.asarray(a, dtype=np.int32))
-        h.update(a.tobytes())
-        sizes.append(a.shape[0])
-    if weights is not None:
-        h.update(np.ascontiguousarray(
-            np.asarray(weights, dtype=np.float32)).tobytes())
-    return (n, tuple(sizes), weights is not None, h.hexdigest())
+    with trace_lib.span("pagerank.fingerprint") as sp:
+        for a in (src, dst):
+            # canonicalize to int32 (node ids fit by construction) so
+            # the same graph hashes identically whatever index dtype it
+            # arrives in; no copy when it already is int32
+            a = np.ascontiguousarray(np.asarray(a, dtype=np.int32))
+            h.update(a.tobytes())
+            sizes.append(a.shape[0])
+        hashed = 4 * sum(sizes)
+        if weights is not None:
+            w = np.ascontiguousarray(np.asarray(weights, dtype=np.float32))
+            h.update(w.tobytes())
+            hashed += w.nbytes
+        sp.set(bytes=hashed)
+        return (n, tuple(sizes), weights is not None, h.hexdigest())
 
 
 def _plan_slots(prepared) -> int:
@@ -312,14 +365,21 @@ def _auto_max_slots() -> int:
 def _pagerank_onehot(src, dst, n: int, rounds: int, alpha: float,
                      max_slots: int = None, weights=None,
                      passes: int = 3):
+    from matrel_tpu.config import pallas_enabled
+
+    def build():
+        prepared = prepare_pagerank_onehot(src, dst, n,
+                                           max_slots=max_slots,
+                                           weights=weights)
+        if prepared is not None and pallas_enabled():
+            from matrel_tpu.ops import pallas_spmv as pc
+            pc.compact_tables(prepared[0])      # upload now, memoised
+        return prepared
+
     prepared = _cache_get_or_insert(
-        _graph_fingerprint(src, dst, n, weights),
-        lambda: prepare_pagerank_onehot(src, dst, n, max_slots=max_slots,
-                                        weights=weights),
-        _plan_slots)
+        _graph_fingerprint(src, dst, n, weights), build, _plan_slots)
     if prepared is None:
         return None
-    from matrel_tpu.config import pallas_enabled
     if pallas_enabled():
         # compact-table Pallas executor: faster and ~17× less HBM than
         # the expanded tables (BASELINE row 5). passes=3 (default) is
@@ -364,7 +424,7 @@ def _pagerank_compact_sharded(src, dst, n: int, rounds: int, alpha: float,
         int(n), int(rounds), float(alpha),
         (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO),
         len(ov), int(passes), bool(interpret), mesh)
-    return run(*tables, jnp.asarray(dangling), *ov)
+    return _dispatch(run, *tables, jnp.asarray(dangling), *ov)
 
 
 @functools.lru_cache(maxsize=32)
@@ -378,7 +438,8 @@ def _compact_sharded_loop(n: int, rounds: int, alpha: float, plan_static,
     axes = tuple(mesh.axis_names)
     in_specs = pc.compact_sharded_specs(axes, n_ov)
 
-    def kernel(src8, lane, off, val, dangling, *ov):
+    def matrel_pagerank_compact_sharded(src8, lane, off, val, dangling,
+                                        *ov):
         def matvec(r):
             return pc.compact_sharded_apply(
                 plan_static, (src8, lane, off, val), ov, r, axes,
@@ -389,8 +450,9 @@ def _compact_sharded_loop(n: int, rounds: int, alpha: float, plan_static,
         r0 = compat.pvary(r0, axes)
         return jax.lax.fori_loop(0, rounds, body, r0)
 
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=in_specs,  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
-                             out_specs=P(), check_vma=False))
+    return jax.jit(shard_map(matrel_pagerank_compact_sharded, mesh=mesh,  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
+                             in_specs=in_specs, out_specs=P(),
+                             check_vma=False))
 
 
 def _pagerank_onehot_sharded(src, dst, n: int, rounds: int, alpha: float,
@@ -421,7 +483,7 @@ def _pagerank_onehot_sharded(src, dst, n: int, rounds: int, alpha: float,
     run = _onehot_sharded_runner(int(n), int(rounds), float(alpha),
                                  (plan.n_rows, plan.n_cols, plan.block),
                                  len(plan.arrays()), mesh)
-    return run(*plan.arrays(), dangling)
+    return _dispatch(run, *plan.arrays(), dangling)
 
 
 @functools.lru_cache(maxsize=32)
@@ -435,7 +497,7 @@ def _onehot_sharded_runner(n: int, rounds: int, alpha: float, plan_static,
     in_specs = spmv_lib.sharded_table_specs(axes, n_arrays)
     in_specs = in_specs + (P(),)          # dangling, replicated
 
-    def kernel(src8, sel, oh_hi, oh_lo, *rest):
+    def matrel_pagerank_onehot_sharded(src8, sel, oh_hi, oh_lo, *rest):
         ov, dangling = rest[:-1], rest[-1]
         arrays = (src8, sel, oh_hi, oh_lo) + ov
 
@@ -449,8 +511,9 @@ def _onehot_sharded_runner(n: int, rounds: int, alpha: float, plan_static,
 
     # check_vma=False: see _sharded_spmv_runner — the all_gathered carry
     # is value-identical per device but typed varying
-    return jax.jit(shard_map(kernel, mesh=mesh, in_specs=in_specs,  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
-                             out_specs=P(), check_vma=False))
+    return jax.jit(shard_map(matrel_pagerank_onehot_sharded, mesh=mesh,  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
+                             in_specs=in_specs, out_specs=P(),
+                             check_vma=False))
 
 
 def _power_body(matvec, n: int, alpha: float, dangling):
@@ -476,13 +539,13 @@ def _onehot_runner(n: int, rounds: int, alpha: float, plan_static,
     from matrel_tpu.ops import spmv as spmv_lib
 
     @jax.jit  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
-    def run(arrays, dangling):
+    def matrel_pagerank_onehot(arrays, dangling):
         body = _power_body(
             lambda r: spmv_lib.spmv_apply(plan_static, arrays, r),
             n, alpha, dangling)
         return jax.lax.fori_loop(0, rounds, body, _r0(n))
 
-    return run
+    return matrel_pagerank_onehot
 
 
 @functools.lru_cache(maxsize=32)
@@ -491,14 +554,14 @@ def _compact_runner_loop(n: int, rounds: int, alpha: float, plan_static,
     from matrel_tpu.ops import pallas_spmv as pc
 
     @jax.jit  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
-    def run(tables, ov, dangling):
+    def matrel_pagerank_compact(tables, ov, dangling):
         body = _power_body(
             lambda r: pc.compact_apply(plan_static, tables, ov, r,
                                        passes, interpret),
             n, alpha, dangling)
         return jax.lax.fori_loop(0, rounds, body, _r0(n))
 
-    return run
+    return matrel_pagerank_compact
 
 
 @functools.lru_cache(maxsize=32)
@@ -514,7 +577,7 @@ def _edges_runner(n: int, rounds: int, alpha: float):
         return s[order], d[order], w[order]
 
     @jax.jit  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
-    def run(s, d, w):
+    def matrel_pagerank_segment(s, d, w):
         outdeg = jax.ops.segment_sum(w, s, num_segments=n)
         inv_deg = jnp.where(outdeg > 0,
                             1.0 / jnp.maximum(outdeg, 1e-30), 0.0)
@@ -528,7 +591,7 @@ def _edges_runner(n: int, rounds: int, alpha: float):
         body = _power_body(matvec, n, alpha, dangling)
         return jax.lax.fori_loop(0, rounds, body, _r0(n))
 
-    return prepare, run
+    return prepare, matrel_pagerank_segment
 
 
 def pagerank_csr(src, dst, n: int, rounds: int = 30, alpha: float = 0.85,

@@ -212,7 +212,7 @@ class TestML005ResultCacheKeying:
 
 class TestML006RawTiming:
     """Raw wall-clock timing in library modules (ISSUE 6): timing
-    belongs in spans/StepTimer so the measurement lands in the event
+    belongs in spans so the measurement lands in the event
     log where history / the chrome exporter / the drift auditor can
     read it — a bare perf_counter pair dies in a local variable."""
 
@@ -241,16 +241,15 @@ class TestML006RawTiming:
         got = _lint(tmp_path, src, "matrel_tpu/serve/pipeline.py")
         assert _rules(got) == ["ML006"] and len(got) == 2
 
-    def test_obs_and_profiling_and_autotune_exempt(self, tmp_path):
+    def test_obs_and_autotune_exempt(self, tmp_path):
         src = """
             import time
             def measure():
                 return time.perf_counter()
         """
-        # the sanctioned timing homes: the obs layer itself, the
-        # StepTimer module, and the autotune measurement subsystem
+        # the sanctioned timing homes: the obs layer itself and the
+        # autotune measurement subsystem
         for rel in ("matrel_tpu/obs/trace.py",
-                    "matrel_tpu/utils/profiling.py",
                     "matrel_tpu/parallel/autotune.py"):
             assert _lint(tmp_path, src, rel) == []
 
@@ -539,7 +538,7 @@ class TestML010JitSeam:
                 return x + 1
         """
         assert _lint(tmp_path, src,
-                     "matrel_tpu/utils/profiling.py") == []
+                     "matrel_tpu/utils/compat.py") == []
         assert _lint(tmp_path, src, "tools/some_probe.py") == []
         assert _lint(tmp_path, src, "bench.py") == []
 

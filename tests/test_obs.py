@@ -594,6 +594,28 @@ class TestInstrumentationGuard:
                 f"executor.py line {i + 1}: lowering dispatch not "
                 f"wrapped in annotate()")
 
+    def test_programs_carry_stable_names(self, mesh8, chain3):
+        """What a profiler trace names the program's device work by:
+        the jitted plan (``XLA Modules``: ``jit_matrel_plan_<root
+        kind>``) and the PageRank round loops carry stable names, not
+        a closure's (the Pallas kernels' names are guarded per call
+        site in TestProfilerTier)."""
+        from matrel_tpu.workloads import pagerank as pr
+        sess = MatrelSession(mesh=mesh8)
+        plan = sess.compile(chain3)
+        assert plan.jitted.__name__ == "matrel_plan_matmul"
+        multi = sess._compile_multi_entry([chain3, chain3.t()])[0]
+        assert multi.jitted.__name__ == "matrel_plan_multi"
+        static = (64, 64, 64, 8)
+        names = {
+            pr._compact_runner_loop(64, 2, 0.85, static, 0, 3,
+                                    True).__name__,
+            pr._onehot_runner(64, 2, 0.85, static[:3], 4).__name__,
+            pr._edges_runner(64, 2, 0.85)[1].__name__}
+        assert names == {"matrel_pagerank_compact",
+                         "matrel_pagerank_onehot",
+                         "matrel_pagerank_segment"}
+
     def test_bench_emits_bench_event(self, tmp_path, monkeypatch):
         """bench.py main() appends a `bench` record to the shared log."""
         import bench
@@ -637,14 +659,16 @@ class TestTracingSpans:
         spans = [e for e in read_events(sess.config.obs_event_log)
                  if e["kind"] == "span"]
         names = {s["name"] for s in spans}
-        assert {"query", "plan", "plan.optimize", "plan.verify",
-                "plan.trace", "query.execute"} <= names
+        assert {"compute", "plan", "compile", "plan.optimize",
+                "plan.verify", "plan.trace", "dispatch",
+                "query.execute"} <= names
         by_id = {s["span_id"]: s for s in spans}
         # every compile phase parent-links (transitively) to the query
         # root span — the chrome exporter's nesting source of truth
-        root = next(s for s in spans if s["name"] == "query")
+        root = next(s for s in spans if s["name"] == "compute")
         assert root["parent_id"] is None
-        for name in ("plan.optimize", "query.execute"):
+        assert root["attrs"]["path"] == "observed"
+        for name in ("plan.optimize", "query.execute", "dispatch"):
             s = next(x for x in spans if x["name"] == name)
             seen = set()
             while s["parent_id"] is not None:
@@ -652,7 +676,17 @@ class TestTracingSpans:
                 assert s["span_id"] not in seen
                 seen.add(s["span_id"])
                 s = by_id[s["parent_id"]]
-            assert s["name"] == "query"
+            assert s["name"] == "compute"
+            assert s["qid"] == root["qid"]
+        # the compile phases nest in the miss's compile span, beside
+        # the plan-cache probe that missed
+        compile_ = next(s for s in spans if s["name"] == "compile")
+        assert by_id[next(s for s in spans if s["name"] == "plan.trace")[
+            "parent_id"]] is compile_
+        assert next(s for s in spans
+                    if s["name"] == "plan")["attrs"] == {"hit": False}
+        assert compile_["attrs"]["executors"] \
+            == sess.compile(chain3).meta["executors"]
         for s in spans:
             assert s["schema"] == SCHEMA_VERSION
             assert isinstance(s["dur_ms"], (int, float))
@@ -758,7 +792,7 @@ class TestFlightRecorder:
         assert len(sess._flight) > 0
         names = {r["name"] for r in sess._flight.snapshot()
                  if r.get("kind") == "span"}
-        assert {"query", "plan.optimize", "query.execute"} <= names
+        assert {"compute", "plan.optimize", "dispatch"} <= names
 
     def test_ring_is_bounded(self, mesh8, tmp_path, chain3):
         sess = _session(mesh8, tmp_path, level="off",
@@ -878,6 +912,249 @@ class TestObsOffServePath:
         outs = sess.run_many(stream)           # repeated traffic:
         assert len(outs) == 2                  # rc/plan-cache hits only
         assert emits == []
+
+
+def _pallas_call_sites():
+    """(file, line, call node) of every ``pallas_call`` in the package."""
+    import ast
+    import glob
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "matrel_tpu")
+    sites = []
+    for path in sorted(glob.glob(os.path.join(root, "**", "*.py"),
+                                 recursive=True)):
+        with open(path) as f:
+            tree = ast.parse(f.read())
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                sites.append((os.path.relpath(path, root), node.lineno,
+                              node))
+    return sites
+
+
+_PALLAS_SITES = _pallas_call_sites()
+
+
+class TestProfilerTier:
+    """The third activation tier: a running ``jax.profiler`` session
+    makes every span live on the default-config path — a
+    ``TraceAnnotation`` on the profiler's host plane and a record in
+    the process-wide ring — and with none running the path constructs
+    nothing."""
+
+    @staticmethod
+    def _trace(tmp_path):
+        import contextlib
+        import jax
+
+        @contextlib.contextmanager
+        def session():
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(str(tmp_path / "prof"),
+                                     profiler_options=opts)
+            try:
+                yield
+            finally:
+                jax.profiler.stop_trace()
+        return session()
+
+    @staticmethod
+    def _sql_session(mesh8, rng):
+        sess = MatrelSession(mesh=mesh8)        # the default config
+        assert sess._tracer is None and sess._flight is None
+        for name in ("A", "B"):
+            sess.register(name, BlockMatrix.from_numpy(
+                rng.standard_normal((32, 32)).astype(np.float32),
+                mesh=mesh8))
+        return sess
+
+    def test_fast_path_spans_nest_and_share_qid(self, mesh8, rng,
+                                                tmp_path):
+        from matrel_tpu.obs import trace as trace_lib
+        sess = self._sql_session(mesh8, rng)
+        q = "rowsum(A * B)"
+        want = sess.compute(sess.sql(q)).to_numpy()     # warm
+        before = len(trace_lib.profile_spans())
+        with self._trace(tmp_path):
+            got = sess.compute(sess.sql(q)).to_numpy()
+        np.testing.assert_array_equal(got, want)
+        recs = trace_lib.profile_spans()[before:]
+        by_name = {r["name"]: r for r in recs}
+        assert set(by_name) == {"matrel.sql", "matrel.compute",
+                                "matrel.plan", "matrel.dispatch",
+                                "matrel.fetch"}
+        comp = by_name["matrel.compute"]
+        assert comp["parent_id"] is None
+        assert comp["attrs"] == {"root_kind": "agg", "path": "fast"}
+        for child in ("matrel.plan", "matrel.dispatch"):
+            c = by_name[child]
+            assert c["parent_id"] == comp["span_id"]
+            assert c["qid"] == comp["qid"]
+            assert comp["start_ns"] <= c["start_ns"] <= c["end_ns"] \
+                <= comp["end_ns"]
+        assert by_name["matrel.plan"]["attrs"] == {"hit": True}
+        assert by_name["matrel.dispatch"]["attrs"]["executors"] \
+            == sess.compile(sess.sql(q)).meta["executors"]
+        assert by_name["matrel.sql"]["attrs"] == {"chars": len(q)}
+        assert by_name["matrel.fetch"]["attrs"] == {"bytes": got.nbytes}
+        # roots of their own: three entry calls, three qids
+        assert len({by_name[n]["qid"] for n in (
+            "matrel.sql", "matrel.compute", "matrel.fetch")}) == 3
+        # the same spans lie on the profiler's host plane, with the qid
+        from jax.profiler import ProfileData
+        import glob
+        [path] = glob.glob(str(tmp_path / "prof" / "plugins" / "profile"
+                               / "*" / "*.xplane.pb"))
+        events = [(ev.name, dict(ev.stats))
+                  for plane in ProfileData.from_file(path).planes
+                  for line in plane.lines for ev in line.events
+                  if ev.name.startswith("matrel.")]
+        assert sorted(n for n, _ in events) == sorted(by_name)
+        assert {n: st["qid"] for n, st in events} \
+            == {n: r["qid"] for n, r in by_name.items()}
+        # and with the session over, the path is dark again
+        sess.compute(sess.sql(q)).to_numpy()
+        assert len(trace_lib.profile_spans()) == before + 5
+
+    @pytest.mark.parametrize("entry", ["sql_compute_fetch", "run_many",
+                                       "pagerank_edges"])
+    def test_profiler_off_constructs_nothing(self, entry, mesh8, rng,
+                                             monkeypatch):
+        """The structural twin of
+        test_repeated_serve_path_creates_no_spans for the default
+        deployment: no Span, no TraceAnnotation, nothing in the ring."""
+        import jax
+        from matrel_tpu.obs import trace as trace_lib
+        from matrel_tpu.workloads import pagerank as pr
+        sess = self._sql_session(mesh8, rng)
+        src = rng.integers(0, 200, 1500).astype(np.int32)
+        dst = rng.integers(0, 200, 1500).astype(np.int32)
+
+        def run():
+            if entry == "sql_compute_fetch":
+                return sess.compute(sess.sql("rowsum(A * B)")).to_numpy()
+            if entry == "run_many":
+                return sess.run_many([sess.table("A").expr().t(),
+                                      sess.sql("A * B")])[1].to_numpy()
+            return np.asarray(pr.pagerank_edges(src, dst, 200, rounds=3,
+                                                impl="onehot"))
+
+        want = run()                                    # warm
+        before = len(trace_lib.profile_spans())
+
+        def poisoned(*a, **k):
+            raise AssertionError("constructed with no profiler running")
+
+        real = jax.profiler.TraceAnnotation.__init__
+
+        def no_program_annotation(self, name, **kw):
+            # jax annotates its own calls whether a session runs or not
+            if name.startswith(trace_lib.PROFILE_PREFIX):
+                poisoned()
+            real(self, name, **kw)
+
+        monkeypatch.setattr(trace_lib.Span, "__init__", poisoned)
+        monkeypatch.setattr(jax.profiler.TraceAnnotation, "__init__",
+                            no_program_annotation)
+        np.testing.assert_array_equal(run(), want)
+        assert len(trace_lib.profile_spans()) == before
+
+    def test_pagerank_spans_and_path_counts(self, rng, tmp_path):
+        from matrel_tpu.obs import trace as trace_lib
+        from matrel_tpu.workloads import pagerank as pr
+        src = rng.integers(0, 300, 2000).astype(np.int32)
+        dst = rng.integers(0, 300, 2000).astype(np.int32)
+        counts = pr.path_counts()
+        before = len(trace_lib.profile_spans())
+        with self._trace(tmp_path):
+            for _ in range(2):
+                pr.pagerank_edges(src, dst, 300, rounds=3, impl="onehot")
+            pr.pagerank_edges(src, dst, 300, rounds=3, impl="segment")
+        recs = trace_lib.profile_spans()[before:]
+        roots = [r for r in recs if r["name"] == "matrel.pagerank"]
+        assert [r["attrs"]["impl"] for r in roots] \
+            == ["onehot", "onehot", "segment"]
+        after = pr.path_counts()
+        assert after["onehot"] == counts["onehot"] + 2
+        assert after["segment"] == counts["segment"] + 1
+        first, second, seg = (
+            [r for r in recs if r["qid"] == root["qid"]
+             and r is not root] for root in roots)
+        for kids, hit in ((first, False), (second, True)):
+            names = [r["name"] for r in sorted(
+                kids, key=lambda r: r["start_ns"])]
+            assert names == ["matrel.pagerank.fingerprint",
+                             "matrel.pagerank.plan",
+                             "matrel.pagerank.dispatch"]
+            by = {r["name"]: r for r in kids}
+            assert by["matrel.pagerank.plan"]["attrs"] == {"hit": hit}
+            assert by["matrel.pagerank.fingerprint"]["attrs"] \
+                == {"bytes": 2 * 4 * 2000}
+        assert [r["name"] for r in seg] == ["matrel.pagerank.dispatch"]
+
+    def test_serve_batch_span_carries_queue_wait(self, mesh8, tmp_path,
+                                                 chain3):
+        """``queue_wait_ms`` per query rides the ``serve.batch`` span,
+        so it is readable with obs off (here: the flight recorder)."""
+        sess = _session(mesh8, tmp_path, level="off",
+                        obs_flight_recorder=64)
+        sess.submit(chain3).result(timeout=120)
+        sess.serve_close()
+        batch = next(r for r in sess._flight.snapshot()
+                     if r.get("name") == "serve.batch")
+        [wait] = batch["attrs"]["queue_wait_ms"]
+        assert wait >= 0.0
+
+    @pytest.mark.parametrize("kind", ["xla", "pallas_spmm"])
+    def test_plan_meta_executors(self, kind, mesh8, rng):
+        """Which executor a plan runs, publicly: ``plan.meta`` and
+        ``plan_executors()`` (``plan_cache_info()`` rides every obs-on
+        record and stays a len and two ints)."""
+        from matrel_tpu.core.sparse import BlockSparseMatrix
+        if kind == "xla":
+            sess = MatrelSession(mesh=mesh8, config=MatrelConfig(
+                strategy_override="xla"))
+            a = BlockMatrix.from_numpy(
+                rng.standard_normal((32, 32)).astype(np.float32),
+                mesh=mesh8)
+            e = a.expr() @ a.expr()
+        else:
+            import jax
+            from matrel_tpu.core import mesh as mesh_lib
+            one = mesh_lib.make_mesh((1, 1), devices=jax.devices()[:1])
+            cfg = MatrelConfig(pallas_interpret=True)
+            sess = MatrelSession(mesh=one, config=cfg)
+            S = BlockSparseMatrix.random((256, 256), block_density=0.5,
+                                         block_size=128, mesh=one,
+                                         seed=3, config=cfg)
+            D = BlockMatrix.random((256, 128), mesh=one, seed=4,
+                                   config=cfg)
+            e = S.multiply(D)
+        plan = sess.compile(e)
+        assert plan.meta["executors"] == [kind]
+        assert sess.plan_executors() == [[kind]]
+        assert "executors" not in sess.plan_cache_info()
+
+    @pytest.mark.parametrize(
+        "site", _PALLAS_SITES,
+        ids=[f"{f}:{ln}" for f, ln, _ in _PALLAS_SITES])
+    def test_every_pallas_call_has_a_stable_name(self, site):
+        """A kernel's name in the device trace is what its
+        ``pallas_call`` is given, not a closure's: a literal starting
+        ``matrel_`` at every call site."""
+        import ast
+        _, _, call = site
+        name = next((kw.value for kw in call.keywords
+                     if kw.arg == "name"), None)
+        assert isinstance(name, ast.Constant), "no literal name="
+        assert isinstance(name.value, str) \
+            and name.value.startswith("matrel_")
+
+    def test_all_pallas_call_sites_were_found(self):
+        assert len(_PALLAS_SITES) == 8
 
 
 class TestAnalyzeEvent:

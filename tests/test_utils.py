@@ -1,12 +1,11 @@
 """Aux subsystem tests: checkpoint round-trip + GC, resilient driver loop
-with injected failure, step timer (SURVEY.md §5)."""
+with injected failure (SURVEY.md §5)."""
 
 import numpy as np
 import pytest
 
 from matrel_tpu.core.blockmatrix import BlockMatrix
 from matrel_tpu.utils.checkpoint import CheckpointManager
-from matrel_tpu.utils.profiling import StepTimer
 from matrel_tpu.utils import resilience
 
 
@@ -88,12 +87,3 @@ class TestResilience:
         with pytest.raises(ValueError):
             resilience.run_resilient(body, cm, mesh8, {"A": bm}, num_steps=2)
 
-
-def test_step_timer():
-    t = StepTimer()
-    with t.step("work"):
-        sum(range(1000))
-    t.count("nnz", 42)
-    t.count("nnz", 8)
-    out = t.table()
-    assert "work" in out and "nnz" in out and "50" in out

@@ -351,10 +351,10 @@ class SpecKeyedCacheRule(Rule):
 class RawTimingRule(Rule):
     """ML006: raw ``time.perf_counter()``/``time.time()``/
     ``time.monotonic()`` calls in library modules outside
-    ``matrel_tpu/obs/`` and ``utils/profiling.py``.
+    ``matrel_tpu/obs/``.
 
     Timing that matters belongs in the observability layer: a span
-    (``obs.trace.span``/``phase``) or a ``StepTimer`` step, so the
+    (``obs.trace.span``/``phase``), so the
     measurement lands in the event log where ``history``, the chrome
     exporter and the drift auditor can read it — a bare perf_counter
     pair produces a number that dies in a local variable (or worse, a
@@ -379,8 +379,7 @@ class RawTimingRule(Rule):
         # the event log as retry/degrade records
         return (relpath.startswith("matrel_tpu/")
                 and not relpath.startswith("matrel_tpu/obs/")
-                and relpath not in ("matrel_tpu/utils/profiling.py",
-                                    "matrel_tpu/parallel/autotune.py",
+                and relpath not in ("matrel_tpu/parallel/autotune.py",
                                     "matrel_tpu/resilience/retry.py"))
 
     def check(self, tree, relpath):
@@ -392,8 +391,8 @@ class RawTimingRule(Rule):
                 yield Finding(
                     relpath, node.lineno, self.id,
                     f"raw `{name}()` timing in library code — route "
-                    "through obs.trace.span()/phase() or StepTimer so "
-                    "the measurement lands in the event log")
+                    "through obs.trace.span()/phase() so the "
+                    "measurement lands in the event log")
 
 
 class BroadSwallowRule(Rule):
@@ -591,7 +590,7 @@ class JitSeamRule(Rule):
     cannot count, and the fused-vs-staged measurement cannot sweep —
     the ML009 "one seam" argument applied to programs instead of
     kernels. Scope: the package minus executor.py (the seam) and
-    utils/ (host-side tooling/profiling helpers); harness scripts
+    utils/ (host-side tooling helpers); harness scripts
     (bench/tools/tests) are out of scope — they ARE measurement.
     The pre-existing legitimate sites (workload runner caches, ops
     table builders, autotune probes, core constructors) carry
