@@ -370,8 +370,8 @@ def phase_pagerank(sizes, seed, mesh=None):
     want_runner = "compact_sharded" if mesh is not None else "compact"
     has_kernel, plan_info, resident = False, None, None
     if ran == [want_runner]:
-        (prepared, _), = [v for v in pr._PLAN_CACHE.values()]
-        plan, dangling = prepared
+        (cached,) = pr._PLAN_CACHE
+        plan, dangling = cached.prepared
         nb, cap = np.asarray(plan.src8).shape
         plan_info = {"blocks": int(nb), "slots_per_block": int(cap),
                      "block_rows": int(plan.block), "lo": spmv_lib.LO,

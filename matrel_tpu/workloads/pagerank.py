@@ -11,7 +11,8 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Optional
+import weakref
+from typing import NamedTuple, Optional
 
 import jax
 
@@ -78,9 +79,15 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
 
         contrib[j] = Σ_{(i,j)∈E} r[i] / outdeg[i]
 
-    Edges are device-resident int32 arrays (10M edges = 80 MB); the whole
-    30-round loop is one jitted fori_loop, no host round trips. Edge arrays
-    may be sharded over the mesh (segment_sum psums over ICI).
+    The whole 30-round loop is one jitted fori_loop, no host round trips.
+    On the segment path the edges are device-resident int32 arrays (10M
+    edges = 80 MB) and may be sharded over the mesh (segment_sum psums
+    over ICI). The one-hot and compact paths build their plan on the
+    host, once per graph: a later call on the same graph finds it by
+    comparing the edge arrays with the copy the plan keeps (numpy
+    arrays: ~8-15 ms at 10M edges, so an in-place edit is seen), or by
+    identity when it is handed the same ``jax.Array`` objects (no pull
+    to the host).
     """
     if impl not in ("auto", "segment", "onehot"):
         raise ValueError(f"unknown impl {impl!r}")
@@ -280,19 +287,45 @@ def run_pagerank_compact(prepared, rounds: int = 30, alpha: float = 0.85,
 
 # Prepared-plan cache for the auto path: repeated pagerank_edges calls on
 # the same graph (alpha/round sweeps) must not repay the host sort + table
-# transfer. Keyed by a FULL content hash (blake2b runs ~1 GB/s, so a 10M-
-# edge probe costs ~0.2 s against ~1 s of saved 30-round compute — and a
-# sampled key would silently serve a stale plan after small graph edits).
-# Callers holding device-resident edge arrays should use
-# prepare_pagerank_onehot/run_pagerank_onehot directly: a cache probe
-# pulls the arrays to host. Eviction is byte-aware in PER-DEVICE slots
+# transfer. An entry recognises its graph by COMPARISON with a private
+# int32 (weights: float32) copy of the arrays it was built from, taken
+# once, when the plan is cached. Equality, not a digest or a sample: a
+# graph edited in one edge, in place or in a new array, is rebuilt, and
+# an equal graph in another array or index dtype hits. A probe walks the
+# entries of the same (n, sizes, weighted, mesh…) key and compares in
+# chunks, with an early exit and no temporary beyond a chunk whatever the
+# dtype or strides: a repeated 10M-edge call reads 80 MB against 80 MB
+# (7.9 ms on the v5e's host, PERF.md §6 PR 26; no copy, no hash — against
+# ~0.75 s of 30-round compute), and a different graph of the same size
+# fails in its first chunk. A
+# numpy array is mutable and is always compared; a jax.Array is not, so
+# the very object an entry was built from (held weakly) hits by identity
+# with no device→host pull — any other jax.Array is pulled to be
+# compared. Host memory: the copies are 8 B an edge (12 with weights) per
+# cached graph, beside the ~13 B a slot of compact host tables its plan
+# already keeps; a plan has about a slot an edge or more, so the budget
+# below holds all single-device entries' copies to ~192 MB (288
+# weighted), and a sharded plan's grow with the mesh as its tables do.
+# Eviction is byte-aware in PER-DEVICE slots
 # (expanded one-hot tables are ~224 B per padded slot — the compact
 # executor's ~30 B/slot plans cost far less, so this budget is the
 # conservative worst case across both executors; sharded plans
 # spread theirs over mesh.size devices): pinning several multi-GB plans
 # would OOM a 16 GB chip, and plans above the budget run uncached.
-_PLAN_CACHE: dict = {}
+_PLAN_CACHE: list = []               # _CachedPlan, oldest first
 _PLAN_CACHE_MAX_SLOTS = 24_000_000   # ≈5.4 GB of expanded tables/device
+# elements a comparison step: its temporary (a 64 KB mask) stays in the
+# heap and the cache, a first-chunk miss costs ~0.1 ms, and from 64K
+# elements up the whole compare runs at memory speed
+_PROBE_CHUNK = 1 << 16
+
+
+class _CachedPlan(NamedTuple):
+    key: tuple          # (n, sizes, weighted) + the caller's tail
+    kept: tuple         # the canonical copies: src, dst[, weights]
+    refs: tuple         # per array: a weakref to the jax.Array, or None
+    prepared: tuple
+    cost: int           # per-device slots
 
 
 def _host_fetchable(a) -> bool:
@@ -303,46 +336,79 @@ def _host_fetchable(a) -> bool:
     return True
 
 
-def _cache_get_or_insert(key, build, per_dev_slots_of):
-    """Byte-aware cache: values are (prepared, per_dev_slots). ``build``
-    runs on a miss (may return None = refused); oversized results are
-    returned uncached."""
+def _same_contents(a, kept) -> tuple:
+    """(whether ``a`` holds what ``kept`` holds, the bytes of ``kept``
+    read to find out). Indices compare under numpy's promotion, so an
+    id beyond int32 equals nothing; weights compare as the float32 the
+    plan is built from."""
+    a = np.asarray(a)
+    seen = 0
+    for i in range(0, kept.shape[0], _PROBE_CHUNK):
+        ours, theirs = kept[i:i + _PROBE_CHUNK], a[i:i + _PROBE_CHUNK]
+        if ours.dtype.kind == "f":
+            theirs = theirs.astype(ours.dtype, copy=False)
+        seen += ours.nbytes
+        if not np.array_equal(ours, theirs):
+            return False, seen
+    return True, seen
+
+
+def _recognise(arrays, key):
+    """The entry of ``_PLAN_CACHE`` built from this graph, if any, and
+    how it was known: ``identity`` (every array is the jax.Array the
+    entry was built from), ``compare`` (by content) or ``new``."""
+    with trace_lib.span("pagerank.fingerprint") as sp:
+        examined = 0
+        for entry in _PLAN_CACHE:
+            if entry.key != key:
+                continue
+            how = "identity"
+            for a, kept, ref in zip(arrays, entry.kept, entry.refs):
+                if ref is not None and ref() is a:
+                    continue
+                how = "compare"
+                same, seen = _same_contents(a, kept)
+                examined += seen
+                if not same:
+                    break
+            else:
+                sp.set(bytes=examined, how=how)
+                return entry
+        sp.set(bytes=examined, how="new")
+        return None
+
+
+def _cached_plan(src, dst, n: int, weights, tail: tuple, build,
+                 per_dev_slots_of):
+    """The prepared plan of this graph for the caller ``tail`` names:
+    the cached one, or ``build()``'s (None = refused), cached with its
+    cost in per-device slots unless that exceeds the budget."""
+    arrays = tuple(a if isinstance(a, (jax.Array, np.ndarray))
+                   else np.asarray(a)
+                   for a in (src, dst, weights) if a is not None)
+    key = (n, tuple(a.shape[0] for a in arrays[:2]),
+           weights is not None) + tail
+    entry = _recognise(arrays, key)
     with trace_lib.span("pagerank.plan") as sp:
-        hit = _PLAN_CACHE.get(key)
-        sp.set(hit=hit is not None)
-        if hit is not None:
-            return hit[0]
+        sp.set(hit=entry is not None)
+        if entry is not None:
+            return entry.prepared
         prepared = build()
         if prepared is None:
             return None
         cost = per_dev_slots_of(prepared)
         if cost <= _PLAN_CACHE_MAX_SLOTS:
-            total = sum(c for _, c in _PLAN_CACHE.values())
+            total = sum(e.cost for e in _PLAN_CACHE)
             while _PLAN_CACHE and total + cost > _PLAN_CACHE_MAX_SLOTS:
-                total -= _PLAN_CACHE.pop(next(iter(_PLAN_CACHE)))[1]
-            _PLAN_CACHE[key] = (prepared, cost)
+                total -= _PLAN_CACHE.pop(0).cost
+            # the build accepted the ids (all in [0, n)), so int32 holds
+            kept = tuple(np.array(a, dtype=t) for a, t in zip(
+                arrays, (np.int32, np.int32, np.float32)))
+            refs = tuple(weakref.ref(a) if isinstance(a, jax.Array)
+                         else None for a in arrays)
+            _PLAN_CACHE.append(_CachedPlan(key, kept, refs, prepared,
+                                           cost))
         return prepared
-
-
-def _graph_fingerprint(src, dst, n: int, weights=None) -> tuple:
-    import hashlib
-    h = hashlib.blake2b(digest_size=16)
-    sizes = []
-    with trace_lib.span("pagerank.fingerprint") as sp:
-        for a in (src, dst):
-            # canonicalize to int32 (node ids fit by construction) so
-            # the same graph hashes identically whatever index dtype it
-            # arrives in; no copy when it already is int32
-            a = np.ascontiguousarray(np.asarray(a, dtype=np.int32))
-            h.update(a.tobytes())
-            sizes.append(a.shape[0])
-        hashed = 4 * sum(sizes)
-        if weights is not None:
-            w = np.ascontiguousarray(np.asarray(weights, dtype=np.float32))
-            h.update(w.tobytes())
-            hashed += w.nbytes
-        sp.set(bytes=hashed)
-        return (n, tuple(sizes), weights is not None, h.hexdigest())
 
 
 def _plan_slots(prepared) -> int:
@@ -376,8 +442,7 @@ def _pagerank_onehot(src, dst, n: int, rounds: int, alpha: float,
             pc.compact_tables(prepared[0])      # upload now, memoised
         return prepared
 
-    prepared = _cache_get_or_insert(
-        _graph_fingerprint(src, dst, n, weights), build, _plan_slots)
+    prepared = _cached_plan(src, dst, n, weights, (), build, _plan_slots)
     if prepared is None:
         return None
     if pallas_enabled():
@@ -400,8 +465,6 @@ def _pagerank_compact_sharded(src, dst, n: int, rounds: int, alpha: float,
     from matrel_tpu.ops import pallas_spmv as pc
     from matrel_tpu.ops import spmv as spmv_lib
 
-    key = _graph_fingerprint(src, dst, n, weights) + (mesh, "compact")
-
     def build():
         prepared = prepare_pagerank_onehot(src, dst, n,
                                            max_slots=max_slots,
@@ -411,8 +474,9 @@ def _pagerank_compact_sharded(src, dst, n: int, rounds: int, alpha: float,
         pc.shard_compact_tables(prepared[0], mesh)   # place now
         return prepared
 
-    prepared = _cache_get_or_insert(
-        key, build, lambda pr_: -(-_plan_slots(pr_) // (16 * mesh.size)))
+    prepared = _cached_plan(
+        src, dst, n, weights, (mesh, "compact"), build,
+        lambda pr_: -(-_plan_slots(pr_) // (16 * mesh.size)))
     if prepared is None:
         return None
     plan, dangling = prepared
@@ -463,9 +527,6 @@ def _pagerank_onehot_sharded(src, dst, n: int, rounds: int, alpha: float,
     from matrel_tpu.ops import spmv as spmv_lib
 
     p = mesh.size
-    # Mesh is hashable and identity-precise: same-shaped meshes over
-    # different devices must not share cached (device-committed) plans
-    key = _graph_fingerprint(src, dst, n, weights) + (mesh,)
 
     def build():
         prepared = prepare_pagerank_onehot(src, dst, n,
@@ -475,8 +536,10 @@ def _pagerank_onehot_sharded(src, dst, n: int, rounds: int, alpha: float,
             return None
         return (spmv_lib.shard_plan(prepared[0], mesh), prepared[1])
 
-    prepared = _cache_get_or_insert(
-        key, build, lambda pr_: -(-_plan_slots(pr_) // p))
+    # Mesh compares identity-precise: same-shaped meshes over different
+    # devices must not share cached (device-committed) plans
+    prepared = _cached_plan(src, dst, n, weights, (mesh,), build,
+                            lambda pr_: -(-_plan_slots(pr_) // p))
     if prepared is None:
         return None
     plan, dangling = prepared

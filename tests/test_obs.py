@@ -1062,9 +1062,11 @@ class TestProfilerTier:
         np.testing.assert_array_equal(run(), want)
         assert len(trace_lib.profile_spans()) == before
 
-    def test_pagerank_spans_and_path_counts(self, rng, tmp_path):
+    def test_pagerank_spans_and_path_counts(self, rng, tmp_path,
+                                            monkeypatch):
         from matrel_tpu.obs import trace as trace_lib
         from matrel_tpu.workloads import pagerank as pr
+        monkeypatch.setattr(pr, "_PLAN_CACHE", [])
         src = rng.integers(0, 300, 2000).astype(np.int32)
         dst = rng.integers(0, 300, 2000).astype(np.int32)
         counts = pr.path_counts()
@@ -1083,7 +1085,11 @@ class TestProfilerTier:
         first, second, seg = (
             [r for r in recs if r["qid"] == root["qid"]
              and r is not root] for root in roots)
-        for kids, hit in ((first, False), (second, True)):
+        # the second call knows its graph by comparing both arrays
+        # with the copies the first call's plan kept
+        for kids, hit, known in ((first, False, {"bytes": 0, "how": "new"}),
+                                 (second, True, {"bytes": 2 * 4 * 2000,
+                                                 "how": "compare"})):
             names = [r["name"] for r in sorted(
                 kids, key=lambda r: r["start_ns"])]
             assert names == ["matrel.pagerank.fingerprint",
@@ -1091,8 +1097,7 @@ class TestProfilerTier:
                              "matrel.pagerank.dispatch"]
             by = {r["name"]: r for r in kids}
             assert by["matrel.pagerank.plan"]["attrs"] == {"hit": hit}
-            assert by["matrel.pagerank.fingerprint"]["attrs"] \
-                == {"bytes": 2 * 4 * 2000}
+            assert by["matrel.pagerank.fingerprint"]["attrs"] == known
         assert [r["name"] for r in seg] == ["matrel.pagerank.dispatch"]
 
     def test_serve_batch_span_carries_queue_wait(self, mesh8, tmp_path,

@@ -179,6 +179,142 @@ class TestEdgePageRank:
         assert r.shape == (n,) and abs(r.sum() - 1.0) < 1e-3
 
 
+def _recognition_cases():
+    """name -> (weighted first call, what the second call is handed,
+    how the plan cache must know it). ``g`` is the first call's
+    (src, dst, weights-or-None); an ``edit`` mutates it IN PLACE."""
+    import jax.numpy as jnp
+
+    def edit(which, where):
+        def second(g):
+            a = g[which]
+            i = {"first": 0, "middle": len(a) // 2, "last": len(a) - 1}[where]
+            a[i] = (a[i] + 1) % 100     # another node; another weight
+            return g
+        return second
+
+    def as_jax(g):       # jnp.asarray would hand the same objects back
+        return tuple(None if a is None else jnp.array(a) for a in g)
+
+    return {
+        "same_arrays": (False, lambda g: g, "compare"),
+        "equal_in_new_arrays": (
+            False, lambda g: (g[0].copy(), g[1].copy(), None), "compare"),
+        "equal_as_int64": (
+            False, lambda g: (g[0].astype(np.int64),
+                              g[1].astype(np.int64), None), "compare"),
+        "equal_strided_and_list": (
+            False, lambda g: (np.repeat(g[0], 2)[::2], g[1].tolist(), None),
+            "compare"),
+        "edit_src_first_chunk": (False, edit(0, "first"), "new"),
+        "edit_src_middle_chunk": (False, edit(0, "middle"), "new"),
+        "edit_dst_last_chunk": (False, edit(1, "last"), "new"),
+        "weights_equal_as_float64": (
+            True, lambda g: (g[0], g[1], g[2].astype(np.float64)),
+            "compare"),
+        "weights_added": (
+            False, lambda g: (g[0], g[1],
+                              (1 + np.arange(len(g[0])) % 3)
+                              .astype(np.float32)), "new"),
+        "weights_dropped": (True, lambda g: (g[0], g[1], None), "new"),
+        "weights_edited_last_chunk": (True, edit(2, "last"), "new"),
+        "jax_same_objects": (True, lambda g: g, "identity"),
+        "jax_equal_in_new_arrays": (True, as_jax, "compare"),
+        "jax_then_numpy": (
+            False, lambda g: (np.asarray(g[0]), np.asarray(g[1]), None),
+            "compare"),
+    }
+
+
+class TestPlanRecognition:
+    """The prepared-plan cache knows its graph by comparison with the
+    copy it kept (a jax.Array by identity): never by a digest, never
+    stale after an in-place edit."""
+
+    N, M, ROUNDS = 100, 500, 3
+
+    @pytest.fixture(autouse=True)
+    def _empty_cache(self, monkeypatch):
+        from matrel_tpu.workloads import pagerank as pr
+        monkeypatch.setattr(pr, "_PLAN_CACHE", [])
+        # eight comparison steps over the 500 edges
+        monkeypatch.setattr(pr, "_PROBE_CHUNK", 64)
+
+    def _call(self, g):
+        """(ranks, attrs of the call's spans by name)."""
+        from matrel_tpu.obs import trace as trace_lib
+        from matrel_tpu.workloads import pagerank as pr
+        recs = []
+        tracer = trace_lib.Tracer(lambda kind, rec: recs.append(rec))
+        with trace_lib.entry("test", tracer):
+            r = np.asarray(pr.pagerank_edges(
+                g[0], g[1], self.N, rounds=self.ROUNDS, impl="onehot",
+                weights=g[2]))
+        return r, {rec["name"]: rec.get("attrs", {}) for rec in recs}
+
+    @pytest.mark.parametrize("case", sorted(_recognition_cases()))
+    def test_second_call(self, case, rng):
+        import jax.numpy as jnp
+        from matrel_tpu.workloads import pagerank as pr
+        weighted, second, how = _recognition_cases()[case]
+        # five out-edges a node, so that one weight moves the ranks
+        g = (rng.permutation(np.arange(self.M, dtype=np.int32) % self.N),
+             rng.integers(0, self.N, self.M).astype(np.int32),
+             (1 + rng.integers(0, 9, self.M)).astype(np.float32)
+             if weighted else None)
+        if case.startswith("jax_"):
+            g = tuple(None if a is None else jnp.asarray(a) for a in g)
+        r1, spans = self._call(g)
+        assert spans["pagerank.fingerprint"] == {"bytes": 0, "how": "new"}
+        assert spans["pagerank.plan"] == {"hit": False}
+        g2 = second(g)
+        r2, spans = self._call(g2)
+        assert spans["pagerank.fingerprint"]["how"] == how
+        assert spans["pagerank.plan"] == {"hit": how != "new"}
+        examined = spans["pagerank.fingerprint"]["bytes"]
+        if how == "identity":
+            assert examined == 0
+        elif how == "compare":
+            assert examined == 4 * self.M * sum(a is not None for a in g2)
+        else:
+            assert examined <= 4 * self.M * 3
+        if how != "new":
+            assert len(pr._PLAN_CACHE) == 1
+            np.testing.assert_array_equal(r2, r1)
+            return
+        # answered with the ranks of the graph as it now is
+        assert len(pr._PLAN_CACHE) == 2
+        assert not np.array_equal(r2, r1)
+        pr._PLAN_CACHE.clear()
+        fresh, _ = self._call(tuple(
+            None if a is None else np.array(a) for a in g2))
+        np.testing.assert_array_equal(r2, fresh)
+
+    @pytest.mark.parametrize("handed", ["int32", "int64", "strided"])
+    def test_warm_probe_allocates_less_than_an_edge_array(
+            self, handed, rng, monkeypatch):
+        import tracemalloc
+        from matrel_tpu.workloads import pagerank as pr
+        monkeypatch.setattr(pr, "_PROBE_CHUNK", 1 << 12)
+        m = 1 << 16
+        src = rng.integers(0, self.N, m).astype(np.int32)
+        dst = rng.integers(0, self.N, m).astype(np.int32)
+        key = (self.N, (m, m), False)
+        pr._PLAN_CACHE.append(pr._CachedPlan(
+            key, (src.copy(), dst.copy()), (None, None), (), 0))
+        if handed == "int64":
+            src, dst = src.astype(np.int64), dst.astype(np.int64)
+        elif handed == "strided":
+            src, dst = np.repeat(src, 2)[::2], np.repeat(dst, 2)[::2]
+        tracemalloc.start()
+        try:
+            assert pr._recognise((src, dst), key) is pr._PLAN_CACHE[0]
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+        assert peak < 4 * m // 2
+
+
 class TestStreamingBigChain:
     def test_streaming_chain_matches_numpy(self, mesh8):
         import jax.numpy as jnp
