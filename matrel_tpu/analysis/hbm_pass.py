@@ -32,9 +32,10 @@ def check_hbm_feasibility(root, mesh, config) -> Iterator[Diagnostic]:
     """MV105 on every matmul stamped with a shard_map strategy: its
     per-device working set (operand shards × replication factor +
     accumulator, padded dims, inferred itemsize) must fit
-    ``config.hbm_budget_bytes``. xla/spgemm stamps and fast-path
-    dispatches are exempt — GSPMD decomposes the former itself and the
-    latter's working set is the sparse pair list, not a dense
+    ``config.hbm_budget_bytes`` (a panelled rmm stamp at its stamped
+    panel counts). xla/spgemm stamps and fast-path dispatches are
+    exempt here — the planner estimates and gates xla itself, and
+    spgemm's working set is the sparse pair list, not a dense
     replication factor. Budget 0 disables the pass."""
     budget = config.hbm_budget_bytes
     if budget <= 0:
@@ -63,8 +64,9 @@ def check_hbm_feasibility(root, mesh, config) -> Iterator[Diagnostic]:
         _, pm = padding.padded_shape((kk, mm), mesh)
         dt = planner.infer_dtype(n, config, dmemo)
         isz = np.dtype(dt).itemsize if dt is not None else 4
-        need = planner.strategy_hbm_bytes(strat, pn, pk, pm, gx, gy,
-                                          isz)
+        need = planner.strategy_hbm_bytes(
+            strat, pn, pk, pm, gx, gy, isz,
+            panels=tuple(n.attrs.get("panels", (1, 1))))
         if need > budget:
             hint = ("re-plan on this config (admissible() now "
                     "drops this strategy; cpmm/summa keep the "

@@ -180,13 +180,26 @@ class MatrelConfig:
         array-redistribution-checker discipline of arXiv:2112.01075).
         ``session.verify(expr)`` and ``explain()`` run the passes
         regardless of this gate; it only controls the compile path.
-      hbm_budget_bytes: per-device HBM budget the planner's
-        admissibility gate and the verifier's feasibility pass check
-        strategy working sets against (operand shards × replication
-        factor + accumulator — VERDICT r5 Weak #3/Next #6). Default is
-        a v5e chip's 16 GiB; 0 disables the gate (divisibility-only
-        admissibility, the pre-round-6 behaviour). The xla fallback is
-        never gated — GSPMD chooses its own decomposition.
+      hbm_budget_bytes: per-device HBM budget that a PLAN's reckoned
+        peak is held to (planner.plan_hbm_bytes): the catalog tables
+        the plan reads (residents), every intermediate alive at a
+        product, the product's output at its storage dtype and the
+        strategy's transient — not one product's own working set
+        (PR 27; VERDICT r5 Weak #3/Next #6 for the gate itself). The
+        gate takes the SMALLER of this and what the mesh's devices
+        report (``memory_stats()["bytes_limit"]``,
+        core.mesh.hbm_limit_bytes), so a budget above the chip cannot
+        admit a plan the chip cannot allocate. The default is 15.5 GiB:
+        a v5e reports 16,909,334,528 B (15.75 GiB, 270 MB under the
+        16 GiB this field used to default to) and its compiler keeps
+        258 MB of that back from a program (chip readings, PR 22 and
+        PR 27). The panelled rmm derives its panel counts from the
+        same budget (strategies.rmm_panels); there is no other knob.
+        0 disables the gate (divisibility-only admissibility, the
+        pre-round-6 behaviour). The xla fallback is estimated and
+        gated like the others; where nothing fits, the candidate that
+        needs least is handed over and the plan says so
+        (``refused_hbm``).
       result_cache_max_bytes: byte budget for the session's cross-query
         MATERIALIZED-RESULT cache (matrel_tpu/serve/result_cache.py —
         the MatFast persist/RDD-cache analogue): executed query results
@@ -598,7 +611,7 @@ class MatrelConfig:
     obs_flight_recorder_path: str = ""
     drift_table_path: str = ""
     verify_plans: str = "off"
-    hbm_budget_bytes: int = 16 << 30
+    hbm_budget_bytes: int = 31 << 29
     reshard_peak_budget_bytes: int = 0
     axis_cost_weights: Tuple[float, float] = (1.0, 1.0)
     fault_inject: str = ""

@@ -75,6 +75,31 @@ def mesh_grid_shape(mesh: Mesh) -> Tuple[int, int]:
     return mesh.shape[names[0]], mesh.shape[names[1]]
 
 
+@functools.lru_cache(maxsize=16)
+def device_bytes_limit(mesh: Mesh) -> Optional[int]:
+    """The least ``memory_stats()["bytes_limit"]`` over the mesh's
+    devices — what the runtime will really hand out on a chip (a v5e
+    reports 16,909,334,528 B, 270 MB under 16 GiB) — or None where the
+    backend reports none (the CPU). A device's limit does not change,
+    so it is asked once a mesh."""
+    limits = [(d.memory_stats() or {}).get("bytes_limit")
+              for d in mesh.devices.flat]
+    limits = [int(x) for x in limits if x]
+    return min(limits) if limits else None
+
+
+def hbm_limit_bytes(mesh: Optional[Mesh], config=None) -> int:
+    """What a plan's reckoned peak is held to, per device: the smaller
+    of ``config.hbm_budget_bytes`` and what the mesh's devices report
+    (:func:`device_bytes_limit`; the CPU reports nothing, and the
+    config alone holds). 0: the gate is off."""
+    from matrel_tpu.config import default_config
+    budget = int((config or default_config()).hbm_budget_bytes)
+    if budget <= 0 or mesh is None:
+        return max(budget, 0)
+    return min(budget, device_bytes_limit(mesh) or budget)
+
+
 # -- mesh topology (hierarchical ICI/DCN fabric description) ----------------
 
 #: Default relative inverse-bandwidth of a mesh axis whose hops cross a

@@ -726,8 +726,17 @@ class Lowerer:
                 out = out.astype(a.dtype)
             return fin(out)
 
+        # a plan the memory reckoning went over carries its verdict: the
+        # panelled rmm runs the panel counts the planner derived (one
+        # each where none is stamped), and rounds each panel to the
+        # storage dtype as it leaves the dot
+        panels = (tuple(node.attrs.get("panels", (1, 1)))
+                  if "hbm_plan_bytes" in node.attrs else None)
+        store = (a.dtype if self.config.keep_input_dtype
+                 and a.dtype == b.dtype else None)
         return strategies.run_matmul(strategy, a, b, self.mesh,
-                                     self.config, epilogue=storage_epi)
+                                     self.config, epilogue=storage_epi,
+                                     panels=panels, out_dtype=store)
 
     def _stage_root_relay(self, root: MatExpr, out: Array) -> Array:
         """Root output → canonical 2d through the compiled reshard
@@ -1306,6 +1315,26 @@ def _fusion_meta(opts, cfg) -> Optional[Dict]:
             "est_saved_hbm_bytes": saved_b}
 
 
+def _hbm_meta(opts, mesh) -> Dict:
+    """The plan-level memory reckoning's verdict for ``plan.meta`` (and,
+    through it, the ``dispatch`` span and a Deployment's notes): the
+    mesh's grid, the plan's reckoned peak on one device
+    (``hbm_plan_bytes``: the largest over its products) and one record
+    a product (planner.hbm_report), each also a ``plan.strategy`` span
+    under ``compile``. A one-device plan is not reckoned: the grid
+    alone."""
+    meta: Dict = {"mesh": "x".join(
+        str(g) for g in mesh_lib.mesh_grid_shape(mesh))}
+    products = [rec for o in opts for rec in planner.hbm_report(o)]
+    if products:
+        meta["hbm_plan_bytes"] = max(p["hbm_plan_bytes"] for p in products)
+        meta["products"] = products
+        for rec in products:
+            with trace_lib.span("plan.strategy", **rec):
+                pass
+    return meta
+
+
 def _verify_plans(opts, mesh, cfg) -> Optional[List[dict]]:
     """Run the static verifier (matrel_tpu/analysis/) over annotated
     roots when ``config.verify_plans`` asks for it — PRE-execution,
@@ -1361,6 +1390,7 @@ def compile_exprs(exprs, mesh: Optional[Mesh] = None,
             from matrel_tpu.ir import fusion as fusion_lib
             opts = tuple(fusion_lib.annotate_fusion(o, mesh, cfg)
                          for o in opts)
+    hbm = _hbm_meta(opts, mesh)
     with trace_lib.phase("plan.verify"):
         verify_diags = _verify_plans(opts, mesh, cfg)
     leaf_order = []
@@ -1380,7 +1410,7 @@ def compile_exprs(exprs, mesh: Optional[Mesh] = None,
     meta = {"optimize_ms": round(sp_opt.dur_ms, 3),
             "trace_ms": round(sp_tr.dur_ms, 3),
             "rule_hits": rule_hits,
-            "executors": low.executors or ["xla"]}
+            "executors": low.executors or ["xla"], **hbm}
     if verify_diags is not None:
         meta["diagnostics"] = verify_diags
     prec_meta = _precision_meta(opts, cfg)
@@ -1613,6 +1643,7 @@ def compile_expr(expr: MatExpr, mesh: Optional[Mesh] = None,
             # (the compile_exprs ordering — one contract)
             from matrel_tpu.ir import fusion as fusion_lib
             opt = fusion_lib.annotate_fusion(opt, mesh, cfg)
+    hbm = _hbm_meta((opt,), mesh)
     with trace_lib.phase("plan.verify"):
         verify_diags = _verify_plans((opt,), mesh, cfg)
     leaf_order = expr_leaves(opt)
@@ -1628,7 +1659,7 @@ def compile_expr(expr: MatExpr, mesh: Optional[Mesh] = None,
     meta = {"optimize_ms": round(sp_opt.dur_ms, 3),
             "trace_ms": round(sp_tr.dur_ms, 3),
             "rule_hits": rule_hits,
-            "executors": low.executors or ["xla"]}
+            "executors": low.executors or ["xla"], **hbm}
     if verify_diags is not None:
         meta["diagnostics"] = verify_diags
     prec_meta = _precision_meta((opt,), cfg)

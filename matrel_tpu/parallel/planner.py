@@ -19,6 +19,8 @@ from jax.sharding import Mesh
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib
 from matrel_tpu.ir.expr import MatExpr
+from matrel_tpu.parallel.strategies import (acc_itemsize, rmm_panels,
+                                            rmm_transient_bytes)
 
 
 def _bytes(shape: Tuple[int, int], density: float, itemsize: int = 4) -> float:
@@ -889,20 +891,28 @@ def choose_precision_tier(node: MatExpr,
 
 
 def strategy_hbm_bytes(strategy: str, pn: int, pk: int, pm: int,
-                       gx: int, gy: int, itemsize: int = 4) -> float:
-    """Per-device HBM working set of one strategy's shard_map program,
-    in bytes: operand shards × their replication factor + the output
-    accumulator, at the padded dims the specs actually carve
+                       gx: int, gy: int, itemsize: int = 4,
+                       panels: Tuple[int, int] = (1, 1)) -> float:
+    """Per-device HBM working set of ONE product's shard_map program,
+    taken alone, in bytes: operand shards × their replication factor +
+    the output, at the padded dims the specs actually carve
     (strategies.py in_specs/out_specs). Dense bytes on purpose — every
     strategy here consumes materialised dense operands, so a density
     credit would under-count exactly the plans the feasibility gate
     exists to drop (per-chip memory is THE binding constraint for
     distributed linear algebra on TPUs, arXiv:2112.09017).
 
-    xla is 0: the GSPMD partitioner picks its own decomposition and is
-    the fallback that must survive every gate; spgemm is 0 too — its
-    working set is the sparse pair list, priced by spgemm_estimates,
-    not a dense replication factor."""
+    ``panels`` = (row panels, column panels) of the panelled rmm
+    (strategies.matmul_rmm): one panel of each gathered operand is
+    alive, so the replication shrinks by the panel counts. xla is
+    estimated like the one-panel rmm — GSPMD gathers both operands'
+    panels for a 2D-sharded product, and at the sizes where that
+    matters it is what the chip's compiler was seen to do (PERF.md §6,
+    PR 27) — and is gated like the others; spgemm is 0: its working
+    set is the sparse pair list, priced by spgemm_estimates, not a
+    dense replication factor. What ELSE is alive beside the product
+    (catalog tables, a chain's intermediates) is the plan-level
+    reckoning's, :func:`plan_hbm_bytes`."""
     p = max(gx * gy, 1)
     a = float(pn) * pk * itemsize
     b = float(pk) * pm * itemsize
@@ -915,37 +925,65 @@ def strategy_hbm_bytes(strategy: str, pn: int, pk: int, pm: int,
         # A P(x,y); B P(y,None) — replicated along x; partial C
         # (pn/gx × pm) lives until the reduce-scatter
         return a / p + b / gy + c / gx
-    if strategy == "rmm":
+    if strategy in ("rmm", "xla"):
         # the replication strategy: A holds every y-slice, B every
-        # x-slice (VERDICT r5 Weak #3 — the case that OOMs first)
-        return a / gx + b / gy + c / p
+        # x-slice (VERDICT r5 Weak #3 — the case that OOMs first);
+        # panelled, one row panel of A and one column panel of B
+        r, cp = panels if strategy == "rmm" else (1, 1)
+        return a / gx / r + b / gy / cp + c / p
     if strategy == "summa":
         # P(x,y) tiles double-buffered through the ppermute ring
         return 2.0 * (a / p + b / p) + c / p
-    return 0.0                            # xla / spgemm / unknown
+    return 0.0                            # spgemm / unknown
 
 
-def admissible(strategy: str, pn: int, pk: int, pm: int,
-               gx: int, gy: int, itemsize: int = 4,
-               hbm_budget_bytes: int = 0) -> bool:
-    """Can this strategy's shard_map specs divide the padded dims evenly
-    — and, when ``hbm_budget_bytes`` > 0, does its per-device working
-    set (strategy_hbm_bytes) fit the budget?
+def strategy_transient_bytes(strategy: str, pn: int, pk: int, pm: int,
+                             gx: int, gy: int, itemsize: int = 4,
+                             panels: Tuple[int, int] = (1, 1)) -> float:
+    """Per-device bytes a strategy allocates BESIDE its operands as they
+    lie (2D shards) and its output at the storage dtype — the term the
+    plan-level reckoning adds to the residents and intermediates alive
+    at a product (:func:`plan_hbm_bytes`). ``itemsize`` is the
+    operands' (and the stored output's, keep_input_dtype); narrow
+    operands accumulate in four bytes, and a strategy whose
+    accumulator is the whole tile (summa's carry, cpmm's partial,
+    whatever leaves a shard_map before the storage cast) pays for it
+    here."""
+    p = max(gx * gy, 1)
+    a = float(pn) * pk * itemsize
+    b = float(pk) * pm * itemsize
+    acc = acc_itemsize(itemsize)
+    c_acc = float(pn) * pm * acc
+    wide = c_acc / p if acc > itemsize else 0.0   # tile before the cast
+    if strategy == "rmm":
+        return rmm_transient_bytes(pn, pk, pm, gx, gy, itemsize, panels)
+    if strategy == "xla":
+        # both gathered panels (the partitioner's all-gathers)
+        return ((a / gx if gy > 1 else 0.0)
+                + (b / gy if gx > 1 else 0.0))
+    if strategy == "cpmm":
+        # B re-laid P(y, None); the partial C in the accumulator's
+        # width until the reduce-scatter
+        return (b / gy if gx > 1 else 0.0) + c_acc / gx
+    if strategy == "summa":
+        # skewed copies of both tiles, the shifted ones received
+        # beside them, and the tile-sized accumulator of the carry
+        return 2.0 * (a / p + b / p) + c_acc / p
+    if strategy == "bmm_right":
+        return b + a / p + wide
+    if strategy == "bmm_left":
+        return a + b / p + wide
+    return 0.0                            # spgemm / unknown
+
+
+def divides(strategy: str, pn: int, pk: int, pm: int,
+            gx: int, gy: int) -> bool:
+    """Can this strategy's shard_map specs divide the padded dims evenly?
 
     Size-1 (vector/scalar) dims stay unpadded (padding.py), so matvec-shaped
     multiplies are only eligible for strategies that keep those dims
-    replicated — everything else falls through to the XLA SPMD path.
-    The HBM gate (VERDICT r5 Weak #3 / Next #6) drops over-replicating
-    plans BEFORE costing: a byte model that ranks RMM cheapest on ICI
-    traffic must never hand the executor a plan whose replicated
-    operands cannot exist on the chip. xla is exempt — it is the
-    fallback GSPMD decomposes itself.
-    """
+    replicated — everything else falls through to the XLA SPMD path."""
     p = gx * gy
-    if (hbm_budget_bytes > 0 and strategy != "xla"
-            and strategy_hbm_bytes(strategy, pn, pk, pm, gx, gy,
-                                   itemsize) > hbm_budget_bytes):
-        return False
     if strategy == "bmm_right":
         return pn % p == 0
     if strategy == "bmm_left":
@@ -958,6 +996,115 @@ def admissible(strategy: str, pn: int, pk: int, pm: int,
         return (gx == gy and pn % gx == 0 and pm % gy == 0
                 and pk % gx == 0 and pk % gy == 0)
     return True  # xla
+
+
+def admissible(strategy: str, pn: int, pk: int, pm: int,
+               gx: int, gy: int, itemsize: int = 4,
+               hbm_budget_bytes: int = 0) -> bool:
+    """:func:`divides` — and, when ``hbm_budget_bytes`` > 0, does the
+    product's own per-device working set (strategy_hbm_bytes, one
+    panel) fit the budget?
+
+    The HBM gate (VERDICT r5 Weak #3 / Next #6) drops over-replicating
+    plans BEFORE costing: a byte model that ranks RMM cheapest on ICI
+    traffic must never hand the executor a plan whose replicated
+    operands cannot exist on the chip. xla is gated like the others
+    (PR 27: exempt and estimated at 0, it was handed the plans that
+    could not be allocated). This is the gate of ONE product taken
+    alone, for callers that hold no plan (autotune, the verifier's
+    hints); the planner's own choice is held to the plan-level
+    reckoning (:func:`plan_hbm_bytes`), where rmm may also take more
+    panels."""
+    if (hbm_budget_bytes > 0
+            and strategy_hbm_bytes(strategy, pn, pk, pm, gx, gy,
+                                   itemsize) > hbm_budget_bytes):
+        return False
+    return divides(strategy, pn, pk, pm, gx, gy)
+
+
+def device_bytes(node: MatExpr, mesh: Mesh,
+                 config: Optional[MatrelConfig] = None,
+                 dtype_memo: Optional[dict] = None,
+                 layout_memo: Optional[dict] = None) -> float:
+    """Bytes of a node's value on ONE device: the shard the leaf's array
+    really has there, or the padded shape at the inferred dtype over
+    the devices its inferred layout spreads it on (all of them unless
+    replicated). A block-sparse leaf counts its tile stack whole (it
+    is replicated); a COO leaf's device tables are its plan's, built
+    on first use, and are not reckoned."""
+    if node.kind == "leaf":
+        data = getattr(node.attrs.get("matrix"), "data", None)
+        sharding = getattr(data, "sharding", None)
+        if sharding is not None:
+            shard = sharding.shard_shape(data.shape)
+            return float(np.prod(shard)) * data.dtype.itemsize
+    elif node.kind == "sparse_leaf":
+        return float(node.attrs["matrix"].blocks.nbytes)
+    elif node.kind == "coo_leaf":
+        return 0.0
+    from matrel_tpu.core import padding
+    dt = infer_dtype(node, config, dtype_memo)
+    isz = np.dtype(dt).itemsize if dt is not None else 4
+    total = float(np.prod(padding.padded_shape(node.shape, mesh))) * isz
+    if infer_layout(node, mesh, layout_memo, config) == "rep":
+        return total
+    return total / max(mesh.size, 1)
+
+
+def plan_resident_bytes(root: MatExpr, mesh: Mesh,
+                        config: Optional[MatrelConfig] = None) -> float:
+    """Per-device bytes of every leaf the plan reads (each once): the
+    catalog tables that are resident on the chip whatever the plan
+    does."""
+    seen, total = set(), 0.0
+
+    def walk(n: MatExpr):
+        nonlocal total
+        if n.uid in seen:
+            return
+        seen.add(n.uid)
+        if not n.children:
+            total += device_bytes(n, mesh, config)
+        for c in n.children:
+            walk(c)
+
+    walk(root)
+    return total
+
+
+def plan_hbm_bytes(strategy: str, pn: int, pk: int, pm: int,
+                   gx: int, gy: int, itemsize: int, alive: float,
+                   panels: Tuple[int, int] = (1, 1)) -> float:
+    """A PLAN's reckoned peak on one device while one of its products
+    runs under ``strategy``: ``alive`` — the leaves the plan reads
+    (catalog residents) and every intermediate alive at that product,
+    its own operands among them — plus the product's output at its
+    storage dtype, plus the strategy's transient
+    (:func:`strategy_transient_bytes`). This, not one product's own
+    working set, is what a chip has to hold: three 2 GiB tables and a
+    chain's intermediate leave a v5e's 15.75 GiB 5.75 for the second
+    product's transient (PERF.md §6, PR 27)."""
+    out = float(pn) * pm * itemsize / max(gx * gy, 1)
+    return alive + out + strategy_transient_bytes(
+        strategy, pn, pk, pm, gx, gy, itemsize, panels)
+
+
+def _hbm_gate(cands, pn, pk, pm, gx, gy, itemsize, alive, limit):
+    """The plan-level feasibility gate over candidate strategies:
+    {strategy: (plan bytes, panels, fits)}. rmm takes the fewest panels
+    whose transient fits what ``alive`` and the output leave of
+    ``limit``; with the gate off (limit 0) every candidate fits at one
+    panel."""
+    out_b = float(pn) * pm * itemsize / max(gx * gy, 1)
+    room = limit - alive - out_b if limit > 0 else None
+    out = {}
+    for s in cands:
+        panels = (rmm_panels(pn, pk, pm, gx, gy, itemsize, room)
+                  if s == "rmm" else (1, 1))
+        need = plan_hbm_bytes(s, pn, pk, pm, gx, gy, itemsize, alive,
+                              panels)
+        out[s] = (need, panels, not (limit > 0 and need > limit))
+    return out
 
 
 def choose_strategy(node: MatExpr, mesh: Mesh,
@@ -1035,7 +1182,9 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
                        root_transposed: bool = False,
                        consumer_hint: Optional[str] = None,
                        root_scale: float = 1.0,
-                       cost_detail: Optional[dict] = None
+                       cost_detail: Optional[dict] = None,
+                       alive_bytes: Optional[float] = None,
+                       hbm_detail: Optional[dict] = None
                        ) -> Tuple[str, str]:
     """(strategy, source) for one matmul node. ``source`` records WHY —
     the observability side of the closed loop (physical EXPLAIN prints
@@ -1051,7 +1200,16 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
     when the learned-coefficient ranking ran (every admissible
     candidate had a warm parallel/coeffs.py row), ``{"cost":
     "analytic"}`` when any candidate was cold and the closed forms
-    decided (docs/COST_MODEL.md)."""
+    decided (docs/COST_MODEL.md).
+
+    ``alive_bytes`` is what the PLAN keeps on one device while this
+    product runs — the leaves it reads and the intermediates alive,
+    this product's operands among them (annotate_strategies threads
+    it); None reckons the product alone (its two operands as they
+    lie). Every candidate's plan-level peak (:func:`plan_hbm_bytes`)
+    is held to ``core.mesh.hbm_limit_bytes``. ``hbm_detail`` (an out-param
+    dict, like ``cost_detail``) receives ``chosen``, its ``panels``,
+    ``hbm_plan_bytes`` and ``refused_hbm``."""
     cfg = config or default_config()
     if _spgemm_matmul(node, cfg):
         # S×S below the density crossover: the LOWERING dispatches the
@@ -1067,17 +1225,49 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
         # stacks, device-local pairs); the nnz-proportional FLOP side
         # lives in spgemm_estimates.
         return "spgemm", "dispatch"
-    if cfg.strategy_override != "auto":
-        return cfg.strategy_override, "override"
     a, b = node.children
     n, k = a.shape
     _, m = b.shape
     gx, gy = mesh_lib.mesh_grid_shape(mesh)
-    if gx * gy == 1:
-        return "xla", "default"  # single device: plain local dot
     from matrel_tpu.core import padding
     pn, pk = padding.padded_shape((n, k), mesh)
     _, pm = padding.padded_shape((k, m), mesh)
+    if gx * gy == 1 and cfg.strategy_override == "auto":
+        return "xla", "default"  # single device: plain local dot
+    # the feasibility gate reckons the PLAN's peak on a device, not
+    # one product's own working set: what is alive beside the product
+    # comes from the caller (annotate_strategies), or is the product's
+    # own operands as they lie
+    if alive_bytes is None:
+        alive_bytes = (device_bytes(a, mesh, cfg, dtype_memo, layout_memo)
+                       + device_bytes(b, mesh, cfg, dtype_memo,
+                                      layout_memo))
+    limit = mesh_lib.hbm_limit_bytes(mesh, cfg)
+    # the gate reads the real accumulation itemsize where it is
+    # statically known (bf16 operands still accumulate/store f32-sized
+    # working sets only when promotion says so — infer_dtype is the
+    # one mirror of that); unknown dtypes assume f32
+    dt_out = infer_dtype(node, cfg, dtype_memo)
+    isz = np.dtype(dt_out).itemsize if dt_out is not None else 4
+    # a stamped precision tier changes the operand WIDTH the strategy's
+    # working set is built from (bf16x1 replicates half the bytes, so
+    # plans the f32 budget refuses become feasible; int8 a quarter) —
+    # the gate must see the tier's real itemsize, not the f32 one
+    tier = node.attrs.get("precision_tier")
+    if tier in TIER_ITEMSIZE:
+        isz = TIER_ITEMSIZE[tier]
+    if cfg.strategy_override != "auto":
+        forced = cfg.strategy_override
+        if hbm_detail is not None and gx * gy > 1:
+            # a forced strategy is reckoned like a chosen one (a forced
+            # rmm still derives its panels from the budget); the gate
+            # can only say that it does not fit
+            need, panels, ok = _hbm_gate([forced], pn, pk, pm, gx, gy, isz,
+                                         alive_bytes, limit)[forced]
+            hbm_detail.update(chosen=forced, panels=panels,
+                              hbm_plan_bytes=int(need),
+                              refused_hbm=[] if ok else [forced])
+        return forced, "override"
     la = infer_layout(a, mesh, layout_memo, cfg)
     lb = infer_layout(b, mesh, layout_memo, cfg)
     if cfg.autotune:
@@ -1155,22 +1345,35 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
         cands["summa"] = comm_cost("summa", n, k, m, da, db, gx, gy,
                                    a_layout=la, b_layout=lb,
                                    alpha_bytes=al, weights=wts)
-    # the HBM gate reads the real accumulation itemsize where it is
-    # statically known (bf16 operands still accumulate/store f32-sized
-    # working sets only when promotion says so — infer_dtype is the
-    # one mirror of that); unknown dtypes assume f32
-    dt_out = infer_dtype(node, cfg, dtype_memo)
-    isz = np.dtype(dt_out).itemsize if dt_out is not None else 4
-    # a stamped precision tier changes the operand WIDTH the strategy's
-    # working set is built from (bf16x1 replicates half the bytes, so
-    # plans the f32 budget refuses become feasible; int8 a quarter) —
-    # the gate must see the tier's real itemsize, not the f32 one
-    tier = node.attrs.get("precision_tier")
-    if tier in TIER_ITEMSIZE:
-        isz = TIER_ITEMSIZE[tier]
     cands = {s: c for s, c in cands.items()
-             if admissible(s, pn, pk, pm, gx, gy, itemsize=isz,
-                           hbm_budget_bytes=cfg.hbm_budget_bytes)}
+             if divides(s, pn, pk, pm, gx, gy)}
+    gate = _hbm_gate(cands, pn, pk, pm, gx, gy, isz, alive_bytes, limit)
+    if not any(ok for _, _, ok in gate.values()):
+        # nothing the byte model ranks fits (or divides): the XLA SPMD
+        # path, estimated and gated like the others; where that is
+        # refused too, the candidate that needs least — a plan that may
+        # not fit is still better handed over than none, and
+        # ``refused_hbm`` says so
+        gate.update(_hbm_gate(["xla"], pn, pk, pm, gx, gy, isz,
+                              alive_bytes, limit))
+        cands = {}
+    refused = sorted(s for s, g in gate.items() if not g[2])
+    fits = {s: g for s, g in gate.items() if g[2]}
+    if not fits:
+        least = min(gate, key=lambda s: gate[s][0])
+        fits = {least: gate[least]}
+
+    def _report(strategy):
+        if hbm_detail is not None:
+            hbm_detail.update(chosen=strategy, panels=fits[strategy][1],
+                              hbm_plan_bytes=int(fits[strategy][0]),
+                              refused_hbm=refused)
+
+    cands = {s: c for s, c in cands.items() if s in fits}
+    if "rmm" in cands and fits["rmm"][1][0] > 1 and lb != "rep":
+        # every further row panel gathers B's column panels once more
+        cands["rmm"] += ((fits["rmm"][1][0] - 1) * (b_bytes / gy)
+                         * (gx - 1) / gx * wts[0])
     if root_output:
         # the executor re-lays ROOT outputs to the canonical sharding;
         # a bmm's 1D-sharded result pays that move, 2d emitters do
@@ -1184,7 +1387,9 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
                                            weights=wts) * root_scale
                  for s, c in cands.items()}
     if not cands:
-        return "xla", "default"
+        only = next(iter(fits))
+        _report(only)
+        return only, "default"
     if cfg.coeff_planner_enable:
         # learned-coefficient ranking (parallel/coeffs.py — the ML018
         # seam; docs/COST_MODEL.md): when EVERY admissible candidate
@@ -1227,6 +1432,7 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
         # because the parent reads its row-sharded result for free.
         best = _hint_tiebreak(cands, best, STRATEGY_OUT_LAYOUT.get,
                               consumer_hint, STRATEGY_TIE_REL)
+    _report(best)
     return best, "model"
 
 
@@ -1473,7 +1679,8 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
                         _consumer_hint: Optional[str] = None,
                         _root_scale: float = 1.0,
                         _root_swap: bool = False,
-                        _integral_memo: Optional[dict] = None) -> MatExpr:
+                        _integral_memo: Optional[dict] = None,
+                        _held: Optional[float] = None) -> MatExpr:
     """Bottom-up pass stamping attrs['strategy'] on every matmul node
     and attrs['replicate'] on every row/col index join. One dtype memo
     and one layout memo are threaded through the whole pass and seeded
@@ -1483,17 +1690,40 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
     strategy near-ties (_hint_tiebreak); a matmul whose output layout
     flows to the plan ROOT is additionally charged the fraction
     ``_root_scale`` (_child_root_scale) of the canonical-output reshard
-    its lowering really pays there (_root_reshard_cost)."""
+    its lowering really pays there (_root_reshard_cost).
+
+    ``_held`` is the plan-level memory reckoning (PR 27): the bytes one
+    device keeps while this subtree is evaluated, beside what the
+    subtree makes itself — the leaves the whole plan reads (reckoned
+    once, at the root) and the values of elder siblings waiting for
+    their parent. A matmul's candidates are gated on it plus its own
+    computed operands (choose_strategy_ex ``alive_bytes``). On one
+    device no strategy is chosen and nothing is reckoned."""
     memo = {} if _dtype_memo is None else _dtype_memo
     lmemo = {} if _layout_memo is None else _layout_memo
     imemo = {} if _integral_memo is None else _integral_memo
+    reckon = mesh.size > 1
+    is_root = reckon and _held is None
+    if is_root:
+        # the root's value is the program's output: its buffer is
+        # handed over before anything runs, so it is alive beside
+        # every product below the root (the chip counted it so: 6 GiB
+        # of tables, 2 GiB of output and 8 GiB of temporaries were 16)
+        _held = plan_resident_bytes(e, mesh, config)
+        if e.children:
+            _held += device_bytes(e, mesh, config, memo, lmemo)
     hints = _child_layout_hints(e, mesh, config, dtype_memo=memo)
     swap = _root_swap != (e.kind == "transpose")   # odd transposes flip
-    new_children = tuple(
-        annotate_strategies(c, mesh, config, memo, lmemo, h,
-                            _child_root_scale(e, i, _root_scale), swap,
-                            imemo)
-        for i, (c, h) in enumerate(zip(e.children, hints)))
+    new_children = []
+    alive = _held
+    for i, (c, h) in enumerate(zip(e.children, hints)):
+        nc = annotate_strategies(c, mesh, config, memo, lmemo, h,
+                                 _child_root_scale(e, i, _root_scale),
+                                 swap, imemo, alive)
+        if reckon and nc.children:      # a computed value, kept for e
+            alive += device_bytes(nc, mesh, config, memo, lmemo)
+        new_children.append(nc)
+    new_children = tuple(new_children)
     if any(nc is not oc for nc, oc in zip(new_children, e.children)):
         e = e.with_children(new_children)
     if e.kind == "matmul" and "precision_tier" not in e.attrs:
@@ -1514,6 +1744,11 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
         # contract)
         detail = ({} if config is not None
                   and config.coeff_planner_enable else None)
+        hbm = {} if reckon else None
+        # the root's own value was counted into ``_held`` for the
+        # products below it; at the root it is the product's output
+        own_alive = (alive - device_bytes(e, mesh, config, memo, lmemo)
+                     if is_root else alive)
         strat, source = choose_strategy_ex(e, mesh, config,
                                            dtype_memo=memo,
                                            layout_memo=lmemo,
@@ -1521,10 +1756,22 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
                                            root_transposed=_root_swap,
                                            consumer_hint=_consumer_hint,
                                            root_scale=_root_scale,
-                                           cost_detail=detail)
+                                           cost_detail=detail,
+                                           alive_bytes=own_alive,
+                                           hbm_detail=hbm)
         stamp = {"strategy": strat, "strategy_source": source}
         if detail is not None and detail.get("cost"):
             stamp["cost_model"] = detail["cost"]
+        if hbm:
+            # what the lowering needs (the panelled rmm's panel counts,
+            # stamped only where there is more than one: plans that fit
+            # whole carry no new attr) and what the observability reads
+            # (plan.meta / the plan.strategy spans: hbm_report)
+            if strat == "rmm" and tuple(hbm["panels"]) != (1, 1):
+                stamp["panels"] = tuple(hbm["panels"])
+            if hbm["refused_hbm"]:
+                stamp["refused_hbm"] = tuple(hbm["refused_hbm"])
+            stamp["hbm_plan_bytes"] = hbm["hbm_plan_bytes"]
         e = e.with_attrs(**stamp)
         if strat == "spgemm":
             # registry dispatch (ops/kernel_registry.py): stamp WHICH
@@ -1549,6 +1796,30 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
     infer_dtype(e, config, memo)     # seed this (possibly new-uid) node
     infer_layout(e, mesh, lmemo, config)
     return e
+
+
+def hbm_report(root: MatExpr) -> list:
+    """What the plan-level memory reckoning decided, read back from an
+    ANNOTATED plan: one record a dense matmul, in evaluation order —
+    ``chosen``, ``refused_hbm``, ``panels`` (rows, columns),
+    ``hbm_plan_bytes``. Empty on one device, where nothing is
+    reckoned."""
+    out, seen = [], set()
+
+    def walk(n: MatExpr):
+        if n.uid in seen:
+            return
+        seen.add(n.uid)
+        for c in n.children:
+            walk(c)
+        if n.kind == "matmul" and "hbm_plan_bytes" in n.attrs:
+            out.append({"chosen": n.attrs.get("strategy"),
+                        "refused_hbm": list(n.attrs.get("refused_hbm", ())),
+                        "panels": list(n.attrs.get("panels", (1, 1))),
+                        "hbm_plan_bytes": n.attrs["hbm_plan_bytes"]})
+
+    walk(root)
+    return out
 
 
 def matmul_decisions(root: MatExpr, mesh: Mesh,

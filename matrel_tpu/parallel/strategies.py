@@ -14,10 +14,14 @@ backend"):
                             partial C; `psum_scatter` over y.
   RMM  (replicate blocks so each reducer owns every input of its C block;
         one cogroup shuffle — all-gather-shaped)
-                         →  A replicated along y, B replicated along x
-                            (the resharding IS the all-gather); local full-k
-                            dot produces C sharded P(x, y) with no further
-                            comm.
+                         →  a row panel of A collected along y (the
+                            other devices' slices beside its own), a
+                            column panel of B all-gathered along x;
+                            local dots over the whole contraction
+                            produce that panel of C, sharded P(x, y),
+                            with no further comm. One panel each where
+                            the chip's memory takes it; more, derived
+                            from the budget, where not.
   SUMMA/Cannon (not in the reference; the long-context/ring analogue,
         SURVEY.md §5 "Long-context")
                          →  A, B, C all stay P(x, y); k advances by a
@@ -31,7 +35,7 @@ and assertable from HLO (SURVEY.md §4 "plan shape" tests).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 
@@ -41,6 +45,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from matrel_tpu.utils.compat import shard_map
 
 from matrel_tpu.config import MatrelConfig, default_config
+from matrel_tpu.core import mesh as mesh_lib
 
 STRATEGIES = ("bmm_left", "bmm_right", "cpmm", "rmm", "summa", "xla")
 
@@ -134,21 +139,184 @@ def matmul_cpmm(a: jax.Array, b: jax.Array, mesh: Mesh,
     return f(a, b)
 
 
+def acc_itemsize(itemsize: int) -> int:
+    """Bytes of the accumulator a product of ``itemsize``-byte operands
+    carries (_acc_dtype: bf16 and int8 accumulate in four bytes)."""
+    return max(int(itemsize), 4)
+
+
+def rmm_transient_bytes(pn: int, pk: int, pm: int, gx: int, gy: int,
+                        itemsize: int, panels: Tuple[int, int]) -> float:
+    """What the panelled rmm allocates on one device beside its operand
+    shards as they lie and its stored output: the other devices' slices
+    of one row panel of A (gy − 1 of them; its own it reads in place),
+    one gathered column panel of B (none on a one-row grid) — three
+    where there are several: the one multiplied, the next in flight,
+    and the one more that the compiler's loop pipelining was seen to
+    keep — with the slice of it that a chunk of the contraction reads,
+    and the product's panel in the accumulator's width (two on a grid
+    with columns, where the chunks' partial products are summed; none
+    where one dot stores what it accumulates). Held beside the chip's
+    compiler at 65536² bf16 on a 2×2 mesh it reads 4.75 / 3.4 GiB at
+    8 / 16 column panels where the compiler's own temporaries were
+    5.0 / 3.5 (PR 27)."""
+    r, c = panels
+    rows, cols = pn / gx / r, pm / gy / c
+    acc = acc_itemsize(itemsize)
+    out = rows * (pk / gy) * (gy - 1) * itemsize
+    if gx > 1:
+        out += (3 if c > 1 else 1) * pk * cols * itemsize
+    if gy > 1:
+        out += (pk / gy) * cols * itemsize + 2 * rows * cols * acc
+    elif acc > itemsize:
+        out += rows * cols * acc
+    return out
+
+
+def rmm_panels(pn: int, pk: int, pm: int, gx: int, gy: int,
+               itemsize: int, room: Optional[float]) -> Tuple[int, int]:
+    """(row panels, column panels) of the panelled rmm whose transient
+    fits ``room`` bytes a device: the fewest row panels (every further
+    one gathers B once more), then the fewest column panels. Panels
+    halve while they stay whole and at least 128 wide. Where not even
+    the narrowest fit, the narrowest (the caller's gate refuses them);
+    ``room`` None (the gate is off) is one panel."""
+    if room is None:
+        return (1, 1)
+
+    def halvings(extent):
+        out = [1]
+        while extent % (2 * out[-1]) == 0 and extent // (2 * out[-1]) >= 128:
+            out.append(2 * out[-1])
+        return out
+
+    rows = halvings(pn // gx) if gy > 1 else [1]
+    cols = halvings(pm // gy) if gx > 1 else [1]
+    for r in rows:
+        for c in cols:
+            if rmm_transient_bytes(pn, pk, pm, gx, gy, itemsize,
+                                   (r, c)) <= room:
+                return (r, c)
+    return (rows[-1], cols[-1])
+
+
 def matmul_rmm(a: jax.Array, b: jax.Array, mesh: Mesh,
-               config: Optional[MatrelConfig] = None) -> jax.Array:
-    """Replication-MM: A replicated along y, B replicated along x; each
-    device owns every input of its C tile and computes it in one local dot.
-    The input resharding is the all-gather-shaped cogroup of the reference
-    (SURVEY.md §2 RMM)."""
+               config: Optional[MatrelConfig] = None,
+               panels: Optional[Tuple[int, int]] = None,
+               out_dtype=None) -> jax.Array:
+    """Replication-MM, panelled: each device owns every input of a panel
+    of its C tile — a row panel of A, as its own slice and the slices of
+    the other devices of its mesh row, and a column panel of B gathered
+    along x — and computes it over the WHOLE contraction: one local dot
+    a chunk of the contraction (one chunk a mesh column), the chunks'
+    products summed in the accumulator's dtype, and one rounding, to
+    ``out_dtype`` (the storage dtype; None: the accumulator's), as the
+    panel leaves. Rounded panels are never summed. The moves are the
+    all-gather-shaped cogroup of the reference (SURVEY.md §2 RMM); a
+    device's own slice of A is read where it lies, so what is held
+    beside the operands is (gy − 1)/gy of A's row panel, not a second
+    copy of all of it.
+
+    ``panels`` = (row panels, column panels). One each is the classic
+    RMM: every input of the tile at once. More bound the transient to
+    one row panel of A, one column panel of B with the next one in
+    flight, and the product's panel in the accumulator's width: at
+    65536² bf16 on a 2×2 v5e mesh the whole gathers are 8 GiB beside
+    8 GiB of tables and intermediate, and cannot be allocated (PERF.md
+    §6, PR 27). Every further row panel gathers B once more. The
+    planner derives the counts from what its plan leaves of the chip's
+    memory (planner.choose_strategy_ex); None derives them here for the
+    product taken alone (:func:`rmm_panels` on ``hbm_limit_bytes`` less
+    the operands' shards and the output). There is no width knob."""
     x, y = mesh.axis_names
+    gx, gy = mesh.shape[x], mesh.shape[y]
     prec = _precision(config)
-    out_dtype = _acc_dtype(a, b)
+    acc = _acc_dtype(a, b)
+    store = acc if out_dtype is None else out_dtype
+    n, k = a.shape
+    m = b.shape[1]
+    if panels is None:
+        limit = mesh_lib.hbm_limit_bytes(mesh, config)
+        isz = max(a.dtype.itemsize, b.dtype.itemsize)
+        p = gx * gy
+        panels = rmm_panels(
+            n, k, m, gx, gy, isz,
+            limit - (n * k + k * m) * isz / p
+            - n * m * jnp.dtype(store).itemsize / p if limit > 0 else None)
+    r, c = panels
+
+    # the contraction is cut where it divides (a vector's k = 1 does
+    # not): an operand whose k stays whole arrives with every slice,
+    # and its side runs no collective
+    a_cut = gy > 1 and k % gy == 0
+    b_cut = gx > 1 and k % gx == 0
 
     def kernel(ab, bb):
-        return _local_dot(ab, bb, prec, out_dtype)
+        rows, cols = ab.shape[0] // r, bb.shape[1] // c
+        chunk = ab.shape[1]             # of the contraction: k / gy
+        j = jax.lax.axis_index(y) if a_cut else 0
 
+        def row_panel(i):
+            """A's row panel, a slice a chunk of the contraction: this
+            device's own first, then, s steps along the mesh row, the
+            slice of the device that holds chunk (j + s) % gy."""
+            own = (ab if r == 1 else
+                   jax.lax.dynamic_slice_in_dim(ab, i * rows, rows, 0))
+            if not a_cut:
+                return [own]
+            return [own] + [
+                jax.lax.ppermute(own, y, [(src, (src - s) % gy)
+                                          for src in range(gy)])
+                for s in range(1, gy)]
+
+        def col_panel(jc):      # (k, cols): every x-slice of B's panel
+            pb = (bb if c == 1 else
+                  jax.lax.dynamic_slice_in_dim(bb, jc * cols, cols, 1))
+            return (jax.lax.all_gather(pb, x, axis=0, tiled=True)
+                    if b_cut else pb)
+
+        def dot(slices, pb):
+            """The panel of C over the whole contraction, summed in the
+            accumulator's dtype; one rounding, as the panel leaves."""
+            total = None
+            for s, pa in enumerate(slices):
+                rows_b = (jax.lax.dynamic_slice_in_dim(
+                    pb, ((j + s) % gy) * chunk, chunk, 0) if a_cut else pb)
+                part = _local_dot(pa, rows_b, prec, acc)
+                total = part if total is None else total + part
+            return total.astype(store)
+
+        if r == 1 and c == 1:
+            return dot(row_panel(0), col_panel(0))
+
+        def rows_of(i, out):
+            slices = row_panel(i)
+
+            def cols_of(jc, carry):
+                out, pb = carry
+                # the next column panel is asked for before this one is
+                # multiplied, so its gather runs under the dots (the
+                # last step asks for panel 0 again: every device runs
+                # the same collectives)
+                nxt = col_panel((jc + 1) % c) if c > 1 else pb
+                return jax.lax.dynamic_update_slice(
+                    out, dot(slices, pb), (i * rows, jc * cols)), nxt
+
+            return jax.lax.fori_loop(0, c, cols_of, (out, col_panel(0)))[0]
+
+        out0 = compat.pvary(jnp.zeros((ab.shape[0], bb.shape[1]), store),
+                            (x, y))
+        return jax.lax.fori_loop(0, r, rows_of, out0)
+
+    # neither operand's collectives may start before both operands
+    # exist: the compiler otherwise hoists the moves of a catalog table
+    # over the product that makes the other operand, and what the plan
+    # reckoned for one product is alive during two (seen at 65536²:
+    # 2 GiB of the second product's slices through all of the first)
+    a, b = jax.lax.optimization_barrier((a, b))
     f = shard_map(kernel, mesh=mesh,
-                  in_specs=(P(x, None), P(None, y)),
+                  in_specs=(P(x, y if a_cut else None),
+                            P(x if b_cut else None, y)),
                   out_specs=P(x, y))
     return f(a, b)
 
@@ -234,21 +402,24 @@ MATMUL_IMPLS = {
 
 def run_matmul(strategy: str, a: jax.Array, b: jax.Array, mesh: Mesh,
                config: Optional[MatrelConfig] = None,
-               epilogue=None) -> jax.Array:
+               epilogue=None, panels: Optional[Tuple[int, int]] = None,
+               out_dtype=None) -> jax.Array:
     """``epilogue`` is the fused-region slot (ir/fusion.py /
     docs/FUSION.md): a traceable callable applied to the strategy's
     output INSIDE the same traced computation, so an absorbed
     elementwise/scalar/reduction chain compiles as the contraction's
     epilogue instead of its own dispatch. None (the default) is the
-    historical path, bit-identically."""
+    historical path, bit-identically. ``panels`` and ``out_dtype`` are
+    the panelled rmm's (the planner's panel counts and the storage
+    dtype its panels leave the dot in); no other strategy reads
+    them."""
     # fault site "strategy": the resilience harness's hook at strategy
     # execution (trace time). One truthiness test when injection is off.
     from matrel_tpu.resilience import faults as faults_lib
     faults_lib.check("strategy", config)
-    impl = MATMUL_IMPLS[strategy]
-    if strategy.startswith("bmm"):
-        side = "left" if strategy == "bmm_left" else "right"
-        out = matmul_bmm(a, b, mesh, config, broadcast_side=side)
+    if strategy == "rmm":
+        out = matmul_rmm(a, b, mesh, config, panels=panels,
+                         out_dtype=out_dtype)
     else:
-        out = impl(a, b, mesh, config)
+        out = MATMUL_IMPLS[strategy](a, b, mesh, config)
     return out if epilogue is None else epilogue(out)

@@ -1624,7 +1624,10 @@ class MatrelSession:
         # caught at runtime. One flag check when off.
         lockdep.note_dispatch("session.dispatch")
         with trace_lib.span("dispatch",
-                            executors=plan.meta.get("executors")):
+                            executors=plan.meta.get("executors"),
+                            mesh=plan.meta.get("mesh"),
+                            hbm_plan_bytes=plan.meta.get(
+                                "hbm_plan_bytes")):
             if self._exec_lock is None:
                 return plan.run(bindings=bindings)
             with self._exec_lock:

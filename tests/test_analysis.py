@@ -244,10 +244,11 @@ class TestHBMFeasibility:
     # outer-product working set is ~12 GiB.
     N, K, M = 4096, 1 << 21, 4096
 
-    def _matmul(self, mesh):
+    def _matmul(self, mesh, k=None, a_spec=P(None, None)):
         axes = tuple(mesh.axis_names)
-        A = _phantom_leaf((self.N, self.K), P(None, None))
-        B = _phantom_leaf((self.K, self.M), P(axes[0], axes[1]))
+        k = k or self.K
+        A = _phantom_leaf((self.N, k), a_spec)
+        B = _phantom_leaf((k, self.M), P(axes[0], axes[1]))
         return E.matmul(A, B)
 
     def test_hbm_bytes_closed_forms(self):
@@ -260,8 +261,14 @@ class TestHBMFeasibility:
         # cpmm = a/8 + b/4 + c/2
         assert rmm == pytest.approx(24.008 * gib, rel=0.001)
         assert cpmm == pytest.approx(12.031 * gib, rel=0.001)
+        # xla is estimated like the one-panel rmm (both gathered
+        # panels), no longer at 0 (PR 27)
         assert planner.strategy_hbm_bytes("xla", self.N, self.K,
-                                          self.M, 2, 4) == 0.0
+                                          self.M, 2, 4) == rmm
+        # panels divide the replication: (4, 8) panels of rmm
+        assert planner.strategy_hbm_bytes(
+            "rmm", self.N, self.K, self.M, 2, 4, panels=(4, 8)
+        ) == pytest.approx((16 / 4 + 8 / 8 + 0.008) * gib, rel=0.001)
 
     def test_admissible_gate(self):
         kw = dict(hbm_budget_bytes=16 << 30)
@@ -269,20 +276,47 @@ class TestHBMFeasibility:
                                       2, 4, **kw)
         assert planner.admissible("cpmm", self.N, self.K, self.M,
                                   2, 4, **kw)
-        assert planner.admissible("xla", self.N, self.K, self.M,
-                                  2, 4, **kw)          # never gated
+        # xla is gated like the others: exempt, it was handed the plans
+        # that could not be allocated (PR 27)
+        assert not planner.admissible("xla", self.N, self.K, self.M,
+                                      2, 4, **kw)
         # budget 0 = the pre-round-6 divisibility-only behaviour
         assert planner.admissible("rmm", self.N, self.K, self.M, 2, 4,
                                   hbm_budget_bytes=0)
+        assert planner.admissible("xla", self.N, self.K, self.M, 2, 4,
+                                  hbm_budget_bytes=0)
 
-    def test_planner_routes_rmm_to_cpmm(self, mesh8):
-        node = self._matmul(mesh8)
+    def test_planner_routes_by_the_plans_peak(self, mesh8):
+        """The gate reckons the plan, operands as they lie included
+        (PR 27): both operands 2D, 1 GiB a device each. With room for
+        it cpmm is the byte model's pick; where its re-laid B and its
+        partial no longer fit, the panelled rmm takes as many panels as
+        the budget asks — routed, not refused."""
+        axes = tuple(mesh8.axis_names)
+        node = self._matmul(mesh8, k=1 << 18, a_spec=P(axes[0], axes[1]))
         free = MatrelConfig(hbm_budget_bytes=0)
-        s0, src0 = planner.choose_strategy_ex(node, mesh8, free)
-        assert (s0, src0) == ("rmm", "model")   # the over-replicator wins
-        capped = MatrelConfig()                 # default: 16 GiB budget
-        s1, src1 = planner.choose_strategy_ex(node, mesh8, capped)
-        assert (s1, src1) == ("cpmm", "model")  # routed, not refused
+        assert planner.choose_strategy_ex(node, mesh8, free) \
+            == ("cpmm", "model")
+        roomy, d4 = MatrelConfig(hbm_budget_bytes=4 << 30), {}
+        assert planner.choose_strategy_ex(node, mesh8, roomy,
+                                          hbm_detail=d4) \
+            == ("cpmm", "model")
+        assert d4["refused_hbm"] == [] \
+            and d4["hbm_plan_bytes"] <= 4 << 30
+        tight, d2 = MatrelConfig(hbm_budget_bytes=2 << 30), {}
+        assert planner.choose_strategy_ex(node, mesh8, tight,
+                                          hbm_detail=d2) \
+            == ("rmm", "model")
+        assert "cpmm" in d2["refused_hbm"]
+        assert d2["panels"][0] > 1 and d2["hbm_plan_bytes"] <= 2 << 30
+        # an operand that cannot exist on the chip (A replicated:
+        # 32 GiB a device): nothing fits, the least is handed over,
+        # and the plan says that everything was refused
+        none, dn = MatrelConfig(), {}
+        s, src = planner.choose_strategy_ex(self._matmul(mesh8), mesh8,
+                                            none, hbm_detail=dn)
+        assert src == "default" and s in dn["refused_hbm"]
+        assert dn["hbm_plan_bytes"] > none.hbm_budget_bytes
 
     def test_mv105_flags_overbudget_stamp(self, mesh8):
         bad = self._matmul(mesh8).with_attrs(strategy="rmm",
