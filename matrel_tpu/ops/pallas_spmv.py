@@ -10,9 +10,11 @@ VMEM from the compact layout the plan build already produces
 
 Pipeline per matvec (``spmv_compact``):
 
-  1. XLA: width-8 row gather + fused lane-select
-     ``w[b,c] = x_ext[src8[b,c], lane[b,c]] · val[b,c]`` — the compare
-     mask fuses into the multiply-reduce, nothing extra materialises.
+  1. XLA: ``w[b,c] = x[src8[b,c]·8 + lane[b,c]] · val[b,c]`` through
+     ``spmv.gather_1d``: a row gather of bytes, 128 B a slot (1.34 GB a
+     matvec at 10.5M slots). Gathered as 8 float32 a row the same step
+     materialised ``f32[slots, 8]`` padded to 128 lanes — 5.38 GB
+     written and read back every matvec (PERF.md §6, PR 28).
   2. Pallas, grid over blocks: generate ``oh_hi`` (C, HI') bf16 and the
      w-carrying rhs (C, LO·passes) in VMEM (w carved into bf16 residual
      parts by mantissa masking — f32-faithful at passes=3, see
@@ -135,10 +137,8 @@ def compact_apply(plan_static, tables, ov, x: jax.Array,
     n_rows, n_cols, block, lo = plan_static
     src8, lane, off, val = tables
     nb, cr, _ = src8.shape
-    x_ext = spmv_lib._ext_table(x.astype(jnp.float32))
-    g = jnp.take(x_ext, src8, axis=0)                    # (nb,cr,128,W)
-    sel = lane[..., None] == jnp.arange(spmv_lib.WIDTH, dtype=lane.dtype)
-    w = jnp.sum(g * sel, axis=-1) * val                  # fused select
+    idx = src8 * spmv_lib.WIDTH + lane.astype(jnp.int32)
+    w = spmv_lib.gather_1d(x.astype(jnp.float32), idx) * val
     scatter = _compact_runner(nb, cr * LANE, block, lo, passes,
                               interpret)
     y = scatter(off, w).reshape(-1)[:n_rows]
@@ -148,45 +148,6 @@ def compact_apply(plan_static, tables, ov, x: jax.Array,
 
 
 _compact_jitted = jax.jit(compact_apply, static_argnums=(0, 4, 5))  # matlint: disable=ML010 pre-seam ops runner cache — the porting worklist (the ML009 legacy-kernel idiom)
-
-
-def compact_apply_chunked(plan_static, tables, ov, x: jax.Array,
-                          passes: int = 3, chunks: int = 4,
-                          interpret: bool = False) -> jax.Array:
-    """EXPERIMENTAL gather/scatter pipelining variant of compact_apply
-    (VERDICT r3 #6: attack the ~6 ms/round schedule gap between the
-    27.1 ms round and the ~21 ms gather-engine floor).
-
-    The baseline runs ONE full-graph gather then ONE full-graph Pallas
-    scatter, serialised by the w dependency. Here the block axis is
-    split into ``chunks`` stripes and each stripe's gather feeds its own
-    scatter call: chunk i+1's gather has no dependency on chunk i's
-    scatter, giving XLA's scheduler the freedom to interleave the
-    memory-bound gather with the MXU-bound scatter, and shrinking the
-    live (slots, W) gather intermediate by chunks×. Numerics identical
-    to compact_apply (same kernel, same tables, per-block accumulation
-    is independent across stripes). Measured by
-    tools/pagerank_overlap.py on chip; the stop rule (write the
-    negative result if <10% over baseline) lives there."""
-    n_rows, n_cols, block, lo = plan_static
-    src8, lane, off, val = tables
-    nb, cr, _ = src8.shape
-    x_ext = spmv_lib._ext_table(x.astype(jnp.float32))
-    step = -(-nb // max(chunks, 1))
-    sel_iota = jnp.arange(spmv_lib.WIDTH, dtype=lane.dtype)
-    parts = []
-    for s in range(0, nb, step):
-        e = min(s + step, nb)
-        g = jnp.take(x_ext, src8[s:e], axis=0)           # (c,cr,128,W)
-        sel = lane[s:e, ..., None] == sel_iota
-        w = jnp.sum(g * sel, axis=-1) * val[s:e]
-        scatter = _compact_runner(e - s, cr * LANE, block, lo, passes,
-                                  interpret)
-        parts.append(scatter(off[s:e], w))
-    y = jnp.concatenate(parts, axis=0).reshape(-1)[:n_rows]
-    if ov:
-        y = spmv_lib._overflow_add(y, ov, x, n_rows)
-    return y
 
 
 # -- mesh-sharded ------------------------------------------------------------
@@ -428,7 +389,7 @@ _compact_matmat_jitted = jax.jit(compact_matmat_apply,  # matlint: disable=ML010
 def spmm_compact(plan: spmv_lib.EdgeSpMVPlan, X: jax.Array,
                  passes: int = 3, interpret=None) -> jax.Array:
     """Y = A·X via compact tables (see spmv_compact). k == 1 takes the
-    matvec kernel (its width-8 gather beats the full-index one).
+    matvec kernel (its byte-row gather beats the k-wide float32 one).
     passes=3 is f32-faithful — the same fidelity as the expanded path it
     replaces; pass 2 only where ranking-grade error is acceptable."""
     interpret = _resolve_interpret(interpret)

@@ -8,10 +8,15 @@ for a 10M gather, 88 ms for the matching scatter). Neither the MXU nor the
 VPU has per-lane random access, so this module reshapes the irregular ops
 into the two forms the hardware executes well:
 
-* **Width-W row gather** (``gather_1d``): XLA's TPU gather runs ~3.3×
-  faster per row when each row is W≥8 elements wide (measured: 10M rows at
-  20 ms for W∈[8,128] vs 66 ms for W=1). So gather width-8 rows and select
-  the wanted lane with a precomputed one-hot — the select is cheap VPU work.
+* **Row gather of bytes** (``gather_1d``): XLA's TPU gather runs ~4× faster
+  a row than the scalar path (measured: 10.5M rows in 13.9-16.6 ms against
+  69.6 ms) — but only while the padded table sits in fast memory, and its
+  cost is flat in the row's width because every row of up to 128 elements
+  occupies 128 lanes. That padding is what the step after the gather pays
+  for: 8 float32 a row are 512 B a slot (5.38 GB a matvec at 10.5M slots,
+  and 9.2 ms of vector work to select one lane of it). So rows travel as
+  uint8 (128 B a slot), 2 values a row where the table allows, and an MXU
+  product plus integer shifts rebuild the value bit for bit.
 
 * **Blocked one-hot MXU scatter** (``EdgeSpMVPlan``): destination indices,
   pre-sorted and padded into fixed-capacity rows of 512-node blocks, are
@@ -42,7 +47,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-WIDTH = 8        # gather row width (measured flat cost for 8..128 on v5e)
+WIDTH = 8        # values a table row of the plan layout (src8, lane): the
+                 # gather's cost is flat for 8..128 because every such row
+                 # is padded to 128 lanes; gather_1d picks its own row
 BLOCK = 512      # scatter block: nodes per one-hot block row
 HI = 32          # off = hi*LO + lo one-hot factor sizes; HI*LO == BLOCK
 LO = 16
@@ -63,20 +70,76 @@ def _ext_table(x: jax.Array, width: int = WIDTH) -> jax.Array:
         rows, width)
 
 
-def gather_1d(table: jax.Array, idx: jax.Array,
-              width: int = WIDTH) -> jax.Array:
-    """``table[idx]`` for a 1-D table, via width-row gather + one-hot select.
+# gather_1d's rows. A uint8 row of up to 128 elements occupies 128 B of
+# an (8,128)(4,1) tile, and XLA's TPU gather runs at its row rate
+# (~1.6 ns a row) only while the padded table sits in fast memory:
+# 64 MB tables did (f32[125001,8], u8[500004,8]: 13.9 and 16.6 ms for
+# 10.5M rows), 128 MB and 512 MB tables did not (61 and 48 ms), whatever
+# the row (PERF.md §6, PR 28).
+_ROW_BYTES_PADDED = 128
+_FAST_TABLE_BYTES = 64 << 20
 
-    ~3.3× faster than the scalar gather on TPU for large ``idx``; exact
-    (the select is a VPU multiply by a 0/1 mask, no matmul rounding).
-    ``idx == table.shape[0]`` is a valid sentinel reading 0.
+
+def _row_values(n: int) -> int:
+    """Values a gathered row: the fewest (a power of two from 2 to 32)
+    whose table of ``n + 1`` entries still fits fast memory. Fewer
+    values a row leave less to select from after the gather."""
+    w = 2
+    while w < 32 and (n // w + 1) * _ROW_BYTES_PADDED > _FAST_TABLE_BYTES:
+        w *= 2
+    return w
+
+
+def _halves_matrix(w: int) -> np.ndarray:
+    """(2w, 4w) of 0, 1 and 256: a row of bytes (value l's b0..b3 at
+    4l..4l+3) times it gives the low 16 bits of the w values, then
+    their high 16 bits, each an exact f32 sum of two terms."""
+    byte_weights = np.array([[1, 256, 0, 0], [0, 0, 1, 256]], np.float32)
+    return np.einsum("lm,hb->hlmb", np.eye(w, dtype=np.float32),
+                     byte_weights).reshape(2 * w, 4 * w)
+
+
+def gather_1d(table: jax.Array, idx: jax.Array,
+              width: Optional[int] = None) -> jax.Array:
+    """``table[idx]`` for a 1-D table of a 4-byte dtype, bit for bit.
+    ``idx == table.shape[0]`` is a valid sentinel reading 0; ``idx`` has
+    at least one axis.
+
+    The scalar gather is XLA's slow path on TPU (70 ms for 10M indices
+    on v5e), a row gather its fast one. But a gathered row of 8 float32
+    is laid out in 128 lanes: ``f32[slots, 8]`` in (8,128) tiles, 512 B
+    a slot, and selecting the wanted lane from it is vector work on
+    15/16 padding (9.2 ms for 10.5M slots beside the gather's 13.9).
+    So the row travels as **bytes**: ``width`` values a row are
+    ``4 * width`` uint8 (128 B a slot, 32 slots a vector register), an
+    MXU product with a 0/1/256 matrix moves the bytes from slot-major
+    onto the lanes as exact 16-bit halves (bytes are exact in bfloat16,
+    each sum has at most two terms), and integer shifts, ors and one
+    select among ``width`` rebuild the value: no float arithmetic ever
+    touches it. Row r holds ``table[r], table[r + rows], ...`` (value l
+    of a row lies l * rows further on), so the byte table is built from
+    contiguous slices. ``width`` defaults to :func:`_row_values`.
     """
-    t2 = _ext_table(table, width)
-    hi, lo = idx // width, idx % width
-    g = jnp.take(t2, hi, axis=0)                       # (..., width)
-    sel = (lo[..., None] == jnp.arange(width, dtype=lo.dtype)
-           ).astype(table.dtype)
-    return jnp.sum(g * sel, axis=-1)
+    n = table.shape[0]
+    w = width or _row_values(n)
+    rows = n // w + 1                                  # w * rows >= n + 1
+    padded = jnp.concatenate(
+        [table, jnp.zeros((w * rows - n,), table.dtype)]).reshape(w, rows)
+    byte_rows = jax.lax.bitcast_convert_type(padded, jnp.uint8).transpose(
+        1, 0, 2).reshape(rows, 4 * w)
+    # which value of its row: idx // rows, as w - 1 compares
+    sub = sum((idx >= l * rows).astype(jnp.int32) for l in range(1, w))
+    g = byte_rows.at[idx - sub * rows].get(mode="promise_in_bounds")
+    halves = jnp.einsum("kj,...sj->...ks",
+                        jnp.asarray(_halves_matrix(w), jnp.bfloat16),
+                        g.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    halves = halves.astype(jnp.uint32).reshape(
+        idx.shape[:-1] + (2, w, idx.shape[-1]))
+    vals = halves[..., 0, :, :] | (halves[..., 1, :, :] << 16)  # (..., w, s)
+    sel = sub[..., None, :] == jnp.arange(w, dtype=jnp.int32)[:, None]
+    out = jnp.sum(jnp.where(sel, vals, 0), axis=-2, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(out, table.dtype)
 
 
 @dataclasses.dataclass

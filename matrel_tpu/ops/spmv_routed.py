@@ -1,10 +1,13 @@
 """Routed SpMV: sparse matvec as pure MXU matmuls — no gather engine.
 
 The one-hot SpMV plan (ops/spmv.py) is scatter-free but still pays the
-TPU gather engine ~2 ns per edge slot for the x-row fetch; at BASELINE
-row-5 scale that gather is ~21 ms of the ~30 ms round (measured
-2026-07-30: gather+select 26.9 ms, one-hot scatter 3.0 ms). Locality and
-dtype do not move it — the engine is rate-limited per index. This module
+TPU gather engine 1.3-1.6 ns per edge slot for the x-row fetch; at
+BASELINE row-5 scale that gather is 13.9-16.6 ms of the round (PERF.md
+§6, PR 28). The engine is rate-limited per index: locality does not move
+it, a row's width does not (up to 128 elements), and its element type
+moves it the wrong way (10.5M rows: float32 13.9 ms, uint8 16.6, uint16
+17.5) — what the element type does decide is the bytes a gathered row is
+padded to, and so the cost of the step after it. This module
 removes the gather entirely by reshaping SpMV into the two dense
 contractions the MXU executes well, the same way the reference reshapes
 its matvec into shuffle + per-block kernels (SURVEY.md §3.5).

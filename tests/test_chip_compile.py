@@ -89,6 +89,32 @@ def test_compact_spmv(one_chip):
              _compact_table_shapes(NB, one_chip), (), x, 3, False)
 
 
+def _assert_no_padded_gather(compiled):
+    """PR 28: gathered as 8 float32 a slot, x[idx] came out as
+    f32[10504704,8] in (8,128) tiles: 128 lanes a row, a 5.38 GB
+    temporary written and read back every round. Neither may return."""
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+    assert f"f32[{NB * CAP},{spmv_lib.WIDTH}]" not in compiled.as_text()
+
+
+def test_compact_spmv_gathers_no_padded_rows(one_chip):
+    static = (N_NODES, N_NODES, BLOCK, spmv_lib.LO)
+    x = _sds(one_chip, (N_NODES,), jnp.float32)
+    _assert_no_padded_gather(_compile(
+        pc._compact_jitted, static, _compact_table_shapes(NB, one_chip),
+        (), x, 3, False))
+
+
+def test_pagerank_loop_gathers_no_padded_rows(one_chip):
+    from matrel_tpu.workloads import pagerank
+    static = (N_NODES, N_NODES, BLOCK, spmv_lib.LO)
+    loop = pagerank._compact_runner_loop(N_NODES, 30, 0.85, static, 0, 3,
+                                         False)
+    dangling = _sds(one_chip, (N_NODES,), jnp.float32)
+    _assert_no_padded_gather(_compile(
+        loop, _compact_table_shapes(NB, one_chip), (), dangling))
+
+
 def test_compact_spmv_k_wide(one_chip):
     static = (N_NODES, N_NODES, BLOCK, spmv_lib.LO)
     X = _sds(one_chip, (N_NODES, pc._COL_CHUNK), jnp.float32)

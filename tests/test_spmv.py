@@ -8,6 +8,7 @@ duplicates, weights, skew (overflow path) and the refusal fallback.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from matrel_tpu.ops import spmv as spmv_lib
@@ -524,26 +525,6 @@ class TestCompactSpMV:
         want = coo_oracle(rows, cols, vals, x, n)
         assert np.abs(y - want).max() / np.abs(want).max() < 1e-4
 
-    def test_chunked_pipeline_matches_baseline(self, rng):
-        # compact_apply_chunked (VERDICT r3 #6 overlap experiment) must
-        # be bit-identical in result to compact_apply: same kernel, same
-        # tables, block stripes are independent
-        from matrel_tpu.ops import pallas_spmv as pc
-        n_r, n_c, m = 3000, 3000, 25_000
-        rows, cols, vals = random_coo(rng, n_r, n_c, m)
-        plan = spmv_lib.build_spmv_plan(rows, cols, vals,
-                                        n_rows=n_r, n_cols=n_c)
-        static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
-        tables = pc.compact_tables(plan)
-        x = jnp.asarray(rng.standard_normal(n_c).astype(np.float32))
-        base = np.asarray(pc.compact_apply(static, tables, plan.overflow,
-                                           x, interpret=True))
-        for k in (2, 3, 8):
-            got = np.asarray(pc.compact_apply_chunked(
-                static, tables, plan.overflow, x, chunks=k,
-                interpret=True))
-            np.testing.assert_array_equal(got, base)
-
     def test_overflow_coo_included(self, rng):
         from matrel_tpu.ops import pallas_spmv as pc
         # hub row forces quantile-capacity overflow
@@ -738,3 +719,153 @@ class TestSpmvChoiceIdentity:
                            id(pb): (pa, "expanded")}
         assert low._spmv_forced(pa) == "expanded"
         assert low._spmv_forced(pb) is None     # identity mismatch
+
+
+# -- x[idx], bit for bit (PR 28) ---------------------------------------------
+# The gathered row travels as bytes and is reassembled with integer
+# operations, so what comes back is x[idx] to the last bit, specials
+# included. The form before PR 28 selected the lane by a 0/1 product
+# (``sum(g * sel)``), which turns a neighbouring lane's inf into NaN
+# and -0.0 into +0.0: the specials are held to x[idx], not to that.
+
+_F32_MAX = np.finfo(np.float32).max
+_NAN_PAYLOAD = np.array([0x7FC12345, 0xFFC00001], np.uint32).view(np.float32)
+
+# name -> (table, what the compact matvec may reference). Every entry is
+# read by gather_1d; the scatter kernel splits a product into bfloat16
+# parts, which only a finite non-zero normal value survives, so the
+# matvec references those and keeps the specials as their lane neighbours.
+_BIT_CASES = {
+    "negatives": np.array([-1.5, 2.25, -3e-7, 7.0, -1e30, 0.3, -0.7, 9.0,
+                           -5.5, 6.5], np.float32),
+    "negative_zero": np.array([1.0, -0.0, 2.0, -0.0, 3.0, 0.0, 4.0, -0.0,
+                               5.0], np.float32),
+    "subnormals": np.array([1.0, 1e-45, -3e-39, 2.0, 1.1754942e-38, 3.0,
+                            -1e-45, 4.0, 5.0], np.float32),
+    "infinities": np.array([1.0, np.inf, 2.0, -np.inf, 3.0, np.inf, 4.0,
+                            5.0, -np.inf, 6.0], np.float32),
+    "largest_finite": np.array([_F32_MAX, -_F32_MAX, 1.0, _F32_MAX, 2.0,
+                                -_F32_MAX, 3.0, 4.0, 5.0], np.float32),
+    "nan_payloads": np.concatenate([np.array([1.0, 2.0], np.float32),
+                                    _NAN_PAYLOAD,
+                                    np.array([3.0, 4.0, 5.0, 6.0, 7.0],
+                                             np.float32)]),
+    "length_not_multiple_of_8": np.arange(1, 14, dtype=np.float32) / 7,
+    "length_multiple_of_8": -np.arange(1, 17, dtype=np.float32) / 3,
+}
+
+
+def _bits(a):
+    return np.ascontiguousarray(np.asarray(a, np.float32)).view(np.uint32)
+
+
+def _survives_split(v):
+    """Finite, non-zero, normal: what the bfloat16 split carries exactly."""
+    with np.errstate(invalid="ignore"):
+        return np.isfinite(v) & (np.abs(v) >= np.finfo(np.float32).tiny)
+
+
+@pytest.mark.parametrize("case", sorted(_BIT_CASES))
+def test_gather_is_x_idx_bit_for_bit(case):
+    from matrel_tpu.ops import pallas_spmv as pc
+    table = _BIT_CASES[case]
+    n = table.shape[0]
+    ext = np.concatenate([table, np.zeros(1, np.float32)])
+    # every entry twice, out of order, and the sentinel slot n (reads +0.0)
+    idx = np.concatenate([np.arange(n), [n], np.arange(n)[::-1], [n]]
+                         ).astype(np.int32)
+    # the default row (2 values at this length) and the wider ones that
+    # longer tables take
+    for width in (None, 4, 8, 32):
+        got = spmv_lib.gather_1d(jnp.asarray(table), jnp.asarray(idx),
+                                 width=width)
+        np.testing.assert_array_equal(_bits(got), _bits(ext[idx]))
+    got2d = spmv_lib.gather_1d(jnp.asarray(table),
+                               jnp.asarray(idx.reshape(2, -1)))
+    np.testing.assert_array_equal(_bits(got2d),
+                                  _bits(ext[idx.reshape(2, -1)]))
+
+    # the compact matvec of a selection matrix: row i reads x[cols[i]]
+    # with weight 1, so y is x[cols] once the split parts are summed;
+    # empty rows and the plan's padded slots (sentinel) read +0.0
+    cols = np.flatnonzero(_survives_split(table))[::-1]
+    rows = np.arange(cols.size) * 2          # odd rows stay empty
+    plan = spmv_lib.build_spmv_plan(rows, cols, np.ones(cols.size, np.float32),
+                                    n_rows=2 * cols.size, n_cols=n)
+    static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
+    y = pc.compact_apply(static, pc.compact_tables(plan), plan.overflow,
+                         jnp.asarray(table), interpret=True)
+    want = np.zeros(plan.n_rows, np.float32)
+    want[rows] = table[cols]
+    np.testing.assert_array_equal(_bits(y), _bits(want))
+
+
+def _compact_apply_before_pr28(plan_static, tables, ov, x, passes, interpret):
+    """``compact_apply`` as it stood before PR 28 (8 float32 a gathered
+    row, the lane selected by a 0/1 product): the reference that
+    positive finite ranks must still match bit for bit."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    n_rows, n_cols, block, lo = plan_static
+    src8, lane, off, val = tables
+    nb, cr, _ = src8.shape
+    x_ext = spmv_lib._ext_table(x.astype(jnp.float32))
+    g = jnp.take(x_ext, src8, axis=0)
+    sel = lane[..., None] == jnp.arange(spmv_lib.WIDTH, dtype=lane.dtype)
+    w = jnp.sum(g * sel, axis=-1) * val
+    y = pc._compact_runner(nb, cr * pc.LANE, block, lo, passes,
+                           interpret)(off, w).reshape(-1)[:n_rows]
+    if ov:
+        ov_c, ov_r, ov_v = ov
+        hi, lo_ = ov_c // spmv_lib.WIDTH, ov_c % spmv_lib.WIDTH
+        gs = jnp.take(x_ext, hi, axis=0)
+        s = (lo_[..., None] == jnp.arange(spmv_lib.WIDTH, dtype=lo_.dtype)
+             ).astype(jnp.float32)
+        y = y + jax.ops.segment_sum(jnp.sum(gs * s, axis=-1) * ov_v, ov_r,
+                                    num_segments=n_rows,
+                                    indices_are_sorted=True)
+    return y
+
+
+@pytest.mark.parametrize("hub", [False, True], ids=["uniform", "hub_overflow"])
+def test_pagerank_auto_ranks_bit_identical_to_previous_form(monkeypatch, hub):
+    """pagerank_edges(impl="auto") where the compact executor answers
+    (the TPU's choice, pinned here with Pallas in interpret mode)."""
+    from matrel_tpu import config as config_lib
+    from matrel_tpu.ops import pallas_spmv as pc
+    from matrel_tpu.workloads import pagerank as pr
+    rng = np.random.default_rng(28)
+    n, m, rounds = 3000, 30_000, 8
+    src = rng.integers(0, n, m)
+    dst = rng.integers(0, n, m)
+    if hub:     # one node drawing 30% of the edges: an overflow tail
+        dst = np.where(rng.random(m) < 0.3, 11, dst)
+    monkeypatch.setattr(config_lib, "_default_config",
+                        config_lib.MatrelConfig(pallas_interpret=True))
+    monkeypatch.setattr(pr, "on_tpu", lambda: True)
+    before = pr.path_counts()["compact"]
+    got = pr.pagerank_edges(src, dst, n, rounds=rounds, impl="auto")
+    assert pr.path_counts()["compact"] == before + 1
+
+    plan, dangling = pr.prepare_pagerank_onehot(src, dst, n)
+    assert bool(plan.overflow) == hub
+    static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
+    tables = pc.compact_tables(plan)
+    body = pr._power_body(
+        lambda r: _compact_apply_before_pr28(static, tables, plan.overflow,
+                                             r, 3, True),
+        n, 0.85, dangling)
+    want = jax.jit(lambda: jax.lax.fori_loop(0, rounds, body, pr._r0(n)))()
+    assert np.all(np.asarray(want) > 0) and np.all(np.isfinite(want))
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+@pytest.mark.parametrize("n,want", [
+    (7, 2), (1_000_000, 2), (1_048_574, 2), (1_048_576, 4),
+    (4_000_000, 8), (16_000_000, 32), (100_000_000, 32)])
+def test_row_values_keep_the_byte_table_in_fast_memory(n, want):
+    """128 B a padded row: the fewest values a row whose table stays
+    within 64 MB, the size measured fast (PERF.md section 6, PR 28)."""
+    w = spmv_lib._row_values(n)
+    assert w == want
+    if want < 32:
+        assert (n // w + 1) * 128 <= 64 << 20
