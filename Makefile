@@ -11,14 +11,12 @@
 #              tier-1 pytest path cannot silently skip it either.
 # test       — full CPU suite on the simulated 8-device mesh
 # soak       — oracle fuzz batteries on CPU (fast sanity)
-# soak-tpu   — on-chip soak behind a probe and hard timeouts;
-#              result appended to PROGRESS.jsonl (tools/soak_guard.py).
+# soak-tpu   — the same batteries on the chip (tools/soak.py --tpu).
 #              The real-chip run is the only place Mosaic bf16 behavior
 #              is exercised — run it after any kernel change.
 # multihost  — 2- and 4-process Gloo collectives (DCN shape)
 # native     — build the C++ optimizer/ingestion core
-# bench      — the headline metric (TPU; probe + measurement child)
-# obs-report — aggregate the repo's query/bench/soak event log
+# obs-report — aggregate the repo's query event log
 #              (.matrel_events.jsonl — the history-server analogue);
 #              --check on the summary exits nonzero on any UN-CLEARED
 #              SLO alert (a log ending mid-incident must not read
@@ -29,15 +27,14 @@
 #              chrome-trace export of the tracing spans, then the
 #              tier-4 audit-replay gate (why --audit: sampled served
 #              answers re-executed fresh and proved within their
-#              stamped bounds). Point it at a dry-drill log with
-#              OBS_LOG=/tmp/matrel_batch_dry/events.jsonl
+#              stamped bounds). Point it at another log with
+#              OBS_LOG=<path>
 
 PY ?= python
 SEEDS ?= 10
 OBS_LOG ?= .matrel_events.jsonl
 
-.PHONY: test lint soak soak-tpu multihost native bench tpu-batch \
-        tpu-batch-dry obs-report chaos
+.PHONY: test lint soak soak-tpu multihost native obs-report chaos
 
 lint:
 	$(PY) tools/matlint.py
@@ -59,27 +56,13 @@ chaos:
 	$(PY) tools/soak.py chaos --seeds 25
 
 soak-tpu:
-	$(PY) tools/soak_guard.py --seeds $(SEEDS)
+	$(PY) tools/soak.py all --tpu --seeds $(SEEDS)
 
 multihost:
 	$(PY) -m pytest tests/test_multihost.py -q
 
 native:
 	$(MAKE) -C native
-
-bench:
-	$(PY) bench.py
-
-tpu-batch:
-	sh tools/tpu_batch.sh
-
-# fire-drill: the WHOLE staged capture batch on the CPU backend
-# at toy sizes (VERDICT r5 Next #2) — proves every step runs and emits
-# its parseable artifact, so chip time is spent measuring,
-# not debugging the harness. tests/test_batch_dry.py asserts the
-# artifacts.
-tpu-batch-dry:
-	sh tools/tpu_batch.sh --dry
 
 obs-report:
 	$(PY) -m matrel_tpu history --summary --check --log $(OBS_LOG)

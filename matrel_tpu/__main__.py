@@ -2,7 +2,6 @@
 
 Commands:
   info                  device/mesh/config summary
-  bench                 headline benchmark (one JSON line)
   serve [--port P]      run the JSON-RPC bridge server
   sql "<query>" [--table name=path.npy ...]   one-shot SQL query
   autotune N [K M]      time every matmul strategy for the given dims
@@ -59,11 +58,6 @@ def cmd_info(args):
             "block_size", "broadcast_threshold_bytes", "strategy_override",
             "matmul_precision", "use_pallas", "chain_opt")},
     }, indent=2))
-
-
-def cmd_bench(args):
-    import bench
-    bench.main()
 
 
 def cmd_serve(args):
@@ -157,7 +151,6 @@ def main(argv=None):
     p = argparse.ArgumentParser(prog="matrel_tpu")
     sub = p.add_subparsers(dest="cmd", required=True)
     sub.add_parser("info").set_defaults(fn=cmd_info)
-    sub.add_parser("bench").set_defaults(fn=cmd_bench)
     sp = sub.add_parser("serve")
     sp.add_argument("--port", type=int, default=8765)
     sp.set_defaults(fn=cmd_serve)

@@ -1753,8 +1753,8 @@ def execute(expr: MatExpr, mesh: Optional[Mesh] = None,
 # end: ``compile_staged_units`` emits one jitted program PER PHYSICAL
 # OP (the per-op dispatch floor — a dispatch and an HBM round-trip per
 # plan edge), ``compile_region_units`` one program PER FUSED REGION
-# (XLA sees the whole segment). ``bench.py --fusion`` sweeps the two;
-# the autotune ``fuse|`` loop measures a single region's pair through
+# (XLA sees the whole segment). tests/test_fusion.py runs both; the
+# autotune ``fuse|`` loop measures a single region's pair through
 # the same machinery. This module is the ONE sanctioned jit seam —
 # matlint ML010 keeps jitted-program emission here (and utils/).
 # ---------------------------------------------------------------------------

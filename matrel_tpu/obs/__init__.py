@@ -12,8 +12,7 @@ surfaces). This package is the TPU rebuild's equivalent, three layers:
   event-log analogue: ``MatrelSession`` emits one record per query run
   (optimize/compile/execute phases, rewrite-rule hits, plan-cache
   hit/miss/evictions, per-matmul planner decisions with estimated ICI
-  bytes + FLOPs); ``bench.py`` and ``tools/soak_guard.py`` emit theirs
-  into the same log.
+  bytes + FLOPs).
 - :mod:`matrel_tpu.obs.analyze` + :mod:`matrel_tpu.obs.history` — the
   debugging surfaces: ``session.explain(expr, analyze=True)`` renders
   the physical tree with MEASURED per-op milliseconds next to the

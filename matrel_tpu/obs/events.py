@@ -4,11 +4,8 @@ One line per event, append-only, schema-versioned. ``MatrelSession``
 emits one ``query`` record per run (plus one ``verify`` record when the
 static plan verifier is on — mode, diagnostic count, codes) and one
 ``serve`` record per micro-batched admission (batch size, queue waits,
-result-cache state — session.run_many / the submit pipeline);
-``bench.py`` emits ``bench`` records (``bench_error`` on a final probe
-failure, carrying the error tail and last-known-good) and
-``tools/soak_guard.py`` ``soak`` records into the same file, so one log
-replays the whole history of a host (the history-server input —
+result-cache state — session.run_many / the submit pipeline), so one
+log replays the whole history of a host (the history-server input —
 ``python -m matrel_tpu history`` aggregates it). Round 9 adds ``span``
 records (parent-linked tracing scopes, obs/trace.py — exported to
 Chrome/Perfetto by ``python -m matrel_tpu trace``) and ``analyze``
@@ -16,7 +13,7 @@ records (measured per-op trees joined to decision records — the drift
 auditor's feed, obs/drift.py).
 
 Writing discipline mirrors the repo's other append-only logs
-(PROGRESS.jsonl, SOAKLOG.jsonl): a single ``write()`` of one line per
+(SOAKLOG.jsonl): a single ``write()`` of one line per
 event (atomic for sane line sizes on POSIX), emission failures are
 swallowed after a one-time warning — observability must never fail a
 query — and every record carries ``schema`` + ``ts`` so readers can
@@ -28,27 +25,10 @@ from __future__ import annotations
 import json
 import logging
 import os
-import threading
 import time
 from typing import Iterator, List, Optional
 
-if __package__:
-    from matrel_tpu.utils import lockdep
-else:
-    # Loaded by FILE PATH (bench.py's jax-free parent, soak_guard):
-    # a package import here would execute matrel_tpu/__init__ and
-    # pull jax into a process that deliberately stays off jax (the
-    # chip belongs to its measurement children). Load the lock seam the same way — it is
-    # stdlib-only, and in these processes lockdep is never enabled,
-    # so the private module state is irrelevant (make_lock returns a
-    # raw threading.Lock either way).
-    import importlib.util as _ilu
-    _spec = _ilu.spec_from_file_location(
-        "_matrel_lockdep",
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     os.pardir, "utils", "lockdep.py"))
-    lockdep = _ilu.module_from_spec(_spec)
-    _spec.loader.exec_module(lockdep)
+from matrel_tpu.utils import lockdep
 
 log = logging.getLogger("matrel_tpu.obs")
 
@@ -162,20 +142,6 @@ def _jsonable(v):
         except Exception:  # matlint: disable=ML007 fallback encoder — falls through to repr()
             pass
     return repr(v)
-
-
-def emit_tool_event(kind: str, record: dict,
-                    anchor_dir: Optional[str] = None) -> Optional[dict]:
-    """Emission entry point for out-of-session tools (bench.py,
-    tools/soak_guard.py): resolves the log path from
-    ``$MATREL_OBS_EVENT_LOG``, else the default log name anchored at
-    ``anchor_dir`` (typically the repo root, so tool records land in
-    the same file regardless of cwd). Same never-raises contract as
-    :meth:`EventLog.emit`."""
-    path = os.environ.get("MATREL_OBS_EVENT_LOG")
-    if not path and anchor_dir:
-        path = os.path.join(anchor_dir, DEFAULT_EVENT_LOG)
-    return EventLog(path).emit(kind, record)
 
 
 def read_events(path: Optional[str] = None,

@@ -182,9 +182,6 @@ def summarize(events: List[dict]) -> dict:
         "fusion": fusion if fusion["queries"] else None,
         "reshards": reshards if reshards["matmuls"] else None,
         "rule_hits": rule_hits,
-        "bench_runs": sum(1 for e in events if e.get("kind") == "bench"),
-        "bench_errors": _last_bench_errors(events),
-        "soak_runs": sum(1 for e in events if e.get("kind") == "soak"),
         "span_count": sum(1 for e in events if e.get("kind") == "span"),
         "verify_runs": sum(1 for e in events
                            if e.get("kind") == "verify"),
@@ -224,21 +221,6 @@ def _phase_quantiles(qs: List[dict]) -> Dict[str, dict]:
             entry[f] = {"p50": _pctile(vals, 0.50),
                         "p95": _pctile(vals, 0.95)}
         out[kind] = entry
-    return out
-
-
-def _last_bench_errors(events: List[dict]) -> Dict[str, dict]:
-    """Most recent ``bench_error`` record per metric — the trail
-    bench.py leaves when a probe or measurement fails; the roll-up
-    surfaces it next to the successful runs."""
-    out: Dict[str, dict] = {}
-    for e in events:
-        if e.get("kind") != "bench_error":
-            continue
-        out[str(e.get("metric") or "?")] = {
-            "ts": e.get("ts"),
-            "error": str(e.get("error") or "")[:300],
-        }
     return out
 
 
@@ -648,14 +630,11 @@ def render_summary(events: List[dict]) -> str:
         f"(evicted: {s['plan_cache'].get('evicted', 0)})",
         f"execute_ms: total {_fmt(s['execute_ms_total'])}  "
         f"mean {_fmt(s['execute_ms_mean'])}",
-        f"other events: bench={s['bench_runs']} soak={s['soak_runs']} "
-        f"verify={s['verify_runs']}"
+        f"other events: verify={s['verify_runs']}"
         + (f" ({s['verify_diagnostics']} diagnostic(s))"
            if s["verify_diagnostics"] else "")
         + (f" spans={s['span_count']}" if s.get("span_count") else ""),
     ]
-    for metric, err in sorted((s.get("bench_errors") or {}).items()):
-        lines.append(f"LAST BENCH ERROR [{metric}]: {err['error']}")
     pq = s.get("phase_quantiles") or {}
     if pq:
         lines.append("")

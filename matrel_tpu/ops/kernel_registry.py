@@ -848,7 +848,7 @@ def synthesize_structure(structure: str, n: int, bs: int, mesh,
                          seed: int = 0, dtype="float32"):
     """A BlockSparseMatrix whose tile layout EXHIBITS one structure
     class — the shared generator behind the autotune measurement
-    probes, ``bench.py --sparse-kernels`` and the soak battery, so all
+    probes, tests/test_kernel_registry.py and the soak battery, so all
     three measure the population the classifier actually bins."""
     from matrel_tpu.core.sparse import BlockSparseMatrix
     from jax.sharding import NamedSharding, PartitionSpec as P

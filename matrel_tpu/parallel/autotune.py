@@ -5,7 +5,7 @@ detecting when not)".
 The cost model (planner.py) is an estimate; this module MEASURES. For a
 given (n, k, m, mesh) it times every admissible strategy on-device
 (marginal timing: chained dependent runs with a forced fetch, cancelling
-dispatch latency — see bench.py methodology) and caches the winner.
+dispatch latency) and caches the winner.
 
 The loop is CLOSED via ``config.autotune``: with the flag on, the
 planner consults ``lookup_or_measure`` before trusting its byte model —
@@ -243,9 +243,8 @@ def measure_strategy(strategy: str, A: BlockMatrix, B: BlockMatrix,
                      n_estimates: int = 3, min_window_s: float = 0.05
                      ) -> float:
     """Marginal seconds per multiply for one strategy: the MEDIAN of
-    ``n_estimates`` independent marginal estimates (bench_all
-    methodology — a single marginal on a shared chip records noise as
-    winners, VERDICT r3). The chained-reps budget is floored: when the
+    ``n_estimates`` independent marginal estimates (a single marginal
+    on a shared chip records noise as winners, VERDICT r3). The chained-reps budget is floored: when the
     long chain completes under ``min_window_s`` the reps are scaled up
     so the marginal rises above dispatch jitter. May return a
     NON-POSITIVE value on a hopelessly noisy host — callers must treat

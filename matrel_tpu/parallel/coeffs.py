@@ -213,8 +213,7 @@ def spill_leg_row(leg: str, cls: str, backend: str,
     """The calibration row one spill transfer leg is priced by, or
     None (cold). Legs key the drift table as ``spill:<leg>`` strategy
     tokens — the ``reshard:<kind>`` precedent — so the same
-    drift-driven loop (live ``spill`` events + ``bench.py --spill``
-    sweeps → ``calibrate`` → this seam) closes over them."""
+    drift-driven loop (live ``spill`` events → ``calibrate`` → this seam) closes over them."""
     return strategy_row(f"spill:{leg}", cls, backend, path)
 
 

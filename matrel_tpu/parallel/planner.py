@@ -708,7 +708,7 @@ def tier_matmul_cost(tier: str, n: int, k: int, m: int,
 def tier_error_bound(tier: str, k: int, amax: float = 1.0,
                      bmax: float = 1.0) -> float:
     """Documented max-abs error bound of a k-deep product at a tier
-    (TIER_EPS closed form) — shared by bench.py --precision and the
+    (TIER_EPS closed form) — shared by tests/test_precision.py and the
     soak battery so the asserted bound IS the documented one."""
     return TIER_EPS[tier] * float(k) * float(amax) * float(bmax)
 

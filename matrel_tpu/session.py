@@ -1757,7 +1757,7 @@ class MatrelSession:
                 # device syncs, zero span objects, zero cache-key walks
                 # beyond the plan cache's own (the obs_level="off" /
                 # result_cache_max_bytes=0 / flight-recorder-off /
-                # cse-off contract bench.py relies on; with cse_enable
+                # cse-off contract the benchmark's cells rely on; with cse_enable
                 # a single query must still reach the template
                 # probe/insert seam in _compute_observed)
                 return self._arbitrated_run(

@@ -15,8 +15,7 @@ and the integer queries patch EXACTLY (err bound 0).
 
 The edge batches are CONSTANT-CAPACITY (zero-padded slots): every
 tick's delta shares one signature, so the delta plane re-runs its
-compiled patch plans with rebound factors — the steady-state path
-``bench.py --stream`` measures.
+compiled patch plans with rebound factors (the steady-state path).
 
 ``pagerank()`` is the iterative member: ranks are maintained by
 warm-restarting the power iteration from the cached vector
@@ -200,7 +199,7 @@ class StreamingGraph:
     def step_rebind(self) -> dict:
         """One tick through the HISTORICAL path — a plain register()
         rebind (transitive invalidation, full recompute on the next
-        run) — the control arm ``bench.py --stream`` compares
+        run) — the control arm tests/test_delta.py compares
         against."""
         adds, expires = self.stream.step()
         self._apply_host(adds, expires)

@@ -163,6 +163,83 @@ class TestMeasuredRanking:
         assert d["strategy"] == decoy
         assert d["cost"] == "measured"
 
+    def test_table_built_from_forced_runs_covers_every_class(
+            self, mesh8, table):
+        """The loop the way traffic closes it: every strategy forced on
+        three workloads in three shape classes, the walls written as
+        drift samples at the workloads' own matmul shapes through the
+        auditor's calibrate/update_table, then fresh sessions ranking
+        against that table — every decision ``measured``, every answer
+        equal to the analytic planner's."""
+        import time
+        n, k = 128, 64
+        rng = np.random.default_rng(0)
+        C = [BlockMatrix.random((n, n), mesh=mesh8, seed=2 + i)
+             for i in range(3)]
+        P = BlockMatrix.random((2 * n, 2 * n), mesh=mesh8, seed=5)
+        R, W = (BlockMatrix.from_numpy(
+            rng.random((2 * n, 1), dtype=np.float32), mesh=mesh8)
+            for _ in range(2))
+        X = BlockMatrix.from_numpy(
+            rng.random((4 * n, k), dtype=np.float32), mesh=mesh8)
+        eye = BlockMatrix.from_numpy(np.eye(k, dtype=np.float32),
+                                     mesh=mesh8)
+        workloads = (
+            lambda: C[0].expr().multiply(C[1].expr())
+            .multiply(C[2].expr()),
+            lambda: P.expr().t()
+            .multiply(W.expr().elem_multiply(R.expr()))
+            .multiply_scalar(0.85).add_scalar(0.15 / (2 * n)),
+            lambda: X.expr().t().multiply(X.expr())
+            .multiply_scalar(1.0 / (4 * n))
+            .add(eye.expr().multiply_scalar(0.1)))
+        analytic = MatrelConfig(obs_level="off",
+                                drift_table_path=table)
+        samples = []
+        for make in workloads:
+            for s in CANDS:
+                cfg = analytic.replace(strategy_override=s)
+                sess = MatrelSession(mesh=mesh8, config=cfg)
+                walls = []
+                try:
+                    decs = [
+                        d for d in executor_lib.plan_matmul_decisions(
+                            executor_lib.compile_expr(make(), mesh8, cfg))
+                        if (d.get("flops") or 0) > 0]
+                    for _ in range(2):   # clears coeff_min_samples=2
+                        t0 = time.perf_counter()
+                        sess.run(make()).data.block_until_ready()
+                        walls.append((time.perf_counter() - t0) * 1e3)
+                except ValueError:
+                    # a forced strategy this shape does not admit (a
+                    # matvec under bmm): no row, as in traffic
+                    continue
+                total = sum(d["flops"] for d in decs)
+                samples += [{
+                    "strategy": d.get("strategy", s),
+                    "class": drift.shape_class(
+                        tuple(d.get("dims") or ())),
+                    "backend": "cpu", "tier": "",
+                    "flops": float(d["flops"]),
+                    "est_bytes": float(d.get("est_ici_bytes") or 0.0),
+                    "ms": ms * d["flops"] / total,
+                    "source": "bench"} for ms in walls for d in decs]
+        assert len({s["class"] for s in samples}) == 3
+        drift.update_table(table, drift.calibrate(samples))
+        coeffs.reset_coefficient_cache()
+        measured = self._cfg(table)
+        for make in workloads:
+            decs = executor_lib.plan_matmul_decisions(
+                executor_lib.compile_expr(make(), mesh8, measured))
+            assert decs and all(d["cost"] == "measured" for d in decs)
+            ref = MatrelSession(mesh=mesh8, config=analytic) \
+                .run(make()).to_numpy().astype(np.float64)
+            got = MatrelSession(mesh=mesh8, config=measured) \
+                .run(make()).to_numpy().astype(np.float64)
+            scale = max(float(np.abs(ref).max()), 1.0)
+            np.testing.assert_allclose(got / scale, ref / scale,
+                                       atol=1e-5)
+
     def test_partial_coverage_stays_analytic(self, mesh8, table):
         # all-or-nothing: one cold candidate means ranking measured
         # milliseconds against raw byte-equivalents — a units error

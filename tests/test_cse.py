@@ -101,6 +101,35 @@ class TestCrossQueryCSE:
             np.testing.assert_allclose(out.to_numpy(), want,
                                        rtol=3e-4, atol=3e-4)
 
+    def test_deep_interior_hoists_once_then_rebinds(self, mesh8, rng):
+        """Eight dashboard variants over one cubic polynomial of the
+        Gram (four matmuls deep): the whole interior is hoisted once,
+        answers are bit-equal to the unshared path, and the same batch
+        over a REBOUND leaf answers through the plan templates."""
+        def batch(M, k=8):
+            g = M.expr().t().multiply(M.expr())
+            h = g.multiply(g).multiply(g)
+            return [h.multiply_scalar(1.0 + 0.25 * i) for i in range(k)]
+
+        X, X2 = _mat(rng, 512, 128, mesh8), _mat(rng, 512, 128, mesh8)
+        off = _sess(mesh8).run_many(batch(X))
+        sess = _sess(mesh8, **CSE)
+        on = sess.run_many(batch(X))
+        for a, b in zip(on, off):
+            np.testing.assert_array_equal(a.to_numpy(), b.to_numpy())
+        info = sess.mqo_info()
+        assert info["cse_hoisted"] == info["cse_batches"] == 1
+        outs = sess.run_many(batch(X2))
+        assert sess.mqo_info()["template_hits"] \
+            - info["template_hits"] >= 1
+        x2 = X2.to_numpy().astype(np.float64)
+        g2 = x2.T @ x2
+        h2 = g2 @ g2 @ g2
+        for i, out in enumerate(outs):
+            np.testing.assert_allclose(
+                out.to_numpy() / np.abs(h2).max(),
+                h2 * (1.0 + 0.25 * i) / np.abs(h2).max(), atol=1e-4)
+
     def test_consumer_plan_carries_cse_stamp_and_pricing(
             self, mesh8, rng):
         sess = _sess(mesh8, **CSE)

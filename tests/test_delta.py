@@ -657,6 +657,41 @@ class TestGenerationIsolation:
         assert sess.result_cache_info()["entries"] == 0
 
 
+def test_streaming_dashboard_patched_equals_recomputed(mesh8):
+    """The sliding-window graph dashboard (workloads/streaming.py) over
+    one seeded edge stream, maintained both ways: ``step_delta``
+    patches the cached entries in place, ``step_rebind`` kills and
+    recomputes. After every tick both dashboards agree with each other
+    and with the numpy oracle — the integer queries bit for bit — and
+    the steady-state ticks reuse their compiled patch plans."""
+    from matrel_tpu.workloads.streaming import StreamingGraph
+
+    def dashboard():
+        return StreamingGraph(_sess(mesh8, **RC), n=256, batch_edges=8,
+                              window=6, feature_k=16, seed=0)
+
+    patched, recomputed = dashboard(), dashboard()
+    patched.run_all()
+    recomputed.run_all()
+    for tick in range(3):
+        summary = patched.step_delta()
+        recomputed.step_rebind()
+        got, ref = patched.run_all(), recomputed.run_all()
+        want = patched.oracle()
+        for name, v in got.items():
+            w = np.asarray(want[name], np.float32).reshape(v.shape)
+            if name == "feature_product":
+                np.testing.assert_allclose(v, w, rtol=1e-4, atol=1e-4)
+                np.testing.assert_allclose(v, ref[name], rtol=1e-4,
+                                           atol=1e-4)
+            else:
+                np.testing.assert_array_equal(v, w)
+                np.testing.assert_array_equal(v, ref[name])
+        assert summary["patched"] > 0
+        assert (summary["reused_plans"] > 0) == (tick > 0)
+    assert delta_pass.verify_patched_entries(patched.sess) == []
+
+
 # ---------------------------------------------------------------------------
 # MV113 — both halves, both directions
 # ---------------------------------------------------------------------------

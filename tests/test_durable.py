@@ -315,6 +315,39 @@ class TestSaveRestore:
         _ = sess2.run(_gram(sess2.catalog["a"]))
         assert sess2.result_cache_info()["hits"] == 3
 
+    def test_working_set_over_budget_then_thawed_equals_cold(
+            self, mesh8, rng, tmp_path):
+        """Four entries against an HBM budget of 2.5: sustained repeats
+        are served by promotions from the lower tiers, bit-equal to the
+        first answers; after save_state a thawed session's first answer
+        equals a cold session's recomputation bit for bit."""
+        cfg = _spill_cfg(tmp_path,
+                         result_cache_max_bytes=int(2.5 * ENTRY),
+                         spill_host_max_bytes=2 * ENTRY)
+        names = ["w0", "w1", "w2", "w3"]
+        sess = MatrelSession(mesh=mesh8, config=cfg)
+        mats = _register(sess, rng, names)
+        first = {nm: np.asarray(sess.run(_gram(mats[nm][0])).data)
+                 for nm in names}
+        for _ in range(2):
+            for nm in names:
+                got = np.asarray(sess.run(_gram(mats[nm][0])).data)
+                assert np.array_equal(got, first[nm])
+        assert sess.result_cache_info()["spill"]["promoted"] > 0
+        sess.save_state()
+        thawed = MatrelSession(mesh=mesh8, config=cfg)
+        assert thawed.restore()["rc_entries"] > 0
+        cold = MatrelSession(
+            mesh=mesh8, config=cfg.replace(state_dir=str(
+                tmp_path / "cold")))
+        got_cold = np.asarray(cold.run(_gram(
+            cold.from_numpy(mats["w0"][0].to_numpy()))).data)
+        got_thawed = np.asarray(
+            thawed.run(_gram(thawed.catalog["w0"])).data)
+        assert np.array_equal(got_thawed, got_cold)
+        assert thawed.result_cache_info()["spill"][
+            "thawed_restored"] == 1
+
     def test_integer_results_restore_bit_exact(
             self, mesh8, rng, tmp_path):
         cfg = _spill_cfg(tmp_path, result_cache_max_bytes=64 << 20)

@@ -37,8 +37,8 @@ def _mk(sess, rng, n=64, names=("A", "B")):
 
 
 def _fleet_session(mesh8, rng, n=64, **kw):
-    cfg = MatrelConfig(fleet_slices=2,
-                       result_cache_max_bytes=1 << 28, **kw)
+    cfg = MatrelConfig(**{"fleet_slices": 2,
+                          "result_cache_max_bytes": 1 << 28, **kw})
     sess = MatrelSession(mesh=mesh8, config=cfg)
     mats = _mk(sess, rng, n=n)
     return sess, mats
@@ -383,6 +383,61 @@ class TestFleetServe:
         assert after == before          # zero recompute, zero routing
         d = fleet.directory.info()
         assert d["hits"] == 1 and d["remote_hits"] == 1
+        sess.serve_close()
+
+    def test_working_set_over_one_slice_replays_from_the_fleet(
+            self, mesh8, rng):
+        """Seven distinct queries against a per-slice cache budget of
+        0.6x their working set: one slice would thrash, two slices hold
+        it between them, and the replays answer at the directory's
+        front door — no slice pipeline sees them, and the odd stream
+        length makes some of those hits remote. Then slice 0 is killed
+        mid-stream: every future resolves right or with a typed error."""
+        from matrel_tpu.resilience.errors import ResilienceError
+        n, n_q = 192, 7
+        sess, mats = _fleet_session(
+            mesh8, rng, n=n, serve_max_batch=1,
+            result_cache_max_bytes=int(0.6 * n_q * n * n * 4))
+        fleet = sess._ensure_fleet()
+        qs = [_q(sess).multiply_scalar(1.0 + 0.5 * i)
+              for i in range(n_q)]
+        oracle = mats["A"] @ mats["B"]
+
+        def replay():
+            return [f.result(timeout=60)
+                    for f in [sess.submit(q) for q in qs]]
+
+        replay()
+        sess.serve_drain()
+        before = {sl.slice_id: sl.submitted for sl in fleet.slices}
+        for _ in range(2):
+            for i, o in enumerate(replay()):
+                np.testing.assert_allclose(
+                    np.asarray(o.to_numpy()), oracle * (1.0 + 0.5 * i),
+                    rtol=2e-3, atol=2e-3)
+        sess.serve_drain()
+        assert {sl.slice_id: sl.submitted
+                for sl in fleet.slices} == before
+        assert fleet.directory.info()["remote_hits"] >= 1
+
+        futs = []
+        for r in range(3):
+            for i, q in enumerate(qs):
+                futs.append((i, sess.submit(q)))
+                if r == 1 and i == n_q // 2:
+                    fleet.kill_slice(0)
+        completed = typed = 0
+        for i, f in futs:
+            try:
+                got = np.asarray(f.result(timeout=60).to_numpy())
+            except ResilienceError:
+                typed += 1
+                continue
+            np.testing.assert_allclose(got, oracle * (1.0 + 0.5 * i),
+                                       rtol=2e-3, atol=2e-3)
+            completed += 1
+        assert completed > 0 and completed + typed == len(futs)
+        assert sess.fleet_info()["failovers"] == 1
         sess.serve_close()
 
     def test_slice_local_miss_recomputes_and_records_ownership(

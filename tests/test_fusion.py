@@ -46,6 +46,25 @@ def _chain(mesh, n=32, k=16, seed=0):
     return e, ref
 
 
+def _pagerank_step(mesh, n=256, seed=0, alpha=0.85):
+    """(expr, float64 oracle): one PageRank round as ONE expression,
+    r' = α·(Aᵀ·(w∘r) + Σ(d∘r)/n) + (1-α)/n — prologue below the
+    matvec anchor, epilogue above it."""
+    rng = np.random.default_rng(seed)
+    a = rng.random((n, n), dtype=np.float32)
+    r, w = (rng.random((n, 1), dtype=np.float32) for _ in range(2))
+    d = (rng.random((n, 1)) < 0.05).astype(np.float32)
+    A, R, W, D = (BlockMatrix.from_numpy(x, mesh=mesh)
+                  for x in (a, r, w, d))
+    e = A.expr().t().multiply(W.expr().elem_multiply(R.expr())) \
+        .add(D.expr().elem_multiply(R.expr()).sum()
+             .multiply_scalar(1.0 / n)) \
+        .multiply_scalar(alpha).add_scalar((1.0 - alpha) / n)
+    a, r, w, d = (x.astype(np.float64) for x in (a, r, w, d))
+    ref = alpha * (a.T @ (w * r) + (d * r).sum() / n) + (1 - alpha) / n
+    return e, ref
+
+
 def _annotated(e, mesh, cfg):
     opt = planner.annotate_strategies(optimize(e, cfg), mesh, cfg)
     return fusion_lib.annotate_fusion(opt, mesh, cfg)
@@ -343,11 +362,14 @@ class TestMV111:
 
 
 class TestUnitProgramSeam:
-    def test_dispatch_counts_shrink(self, mesh8):
-        e, ref = _chain(mesh8, seed=14)
+    @pytest.mark.parametrize("chain", [_chain, _pagerank_step],
+                             ids=["linreg_epilogue", "pagerank_step"])
+    def test_dispatch_counts_shrink(self, mesh8, chain):
+        e, ref = chain(mesh8, seed=14)
         staged = executor_lib.compile_staged_units(e, mesh8, CFG_OFF)
         fused = executor_lib.compile_region_units(e, mesh8, CFG_ON)
         assert fused.dispatches < staged.dispatches
+        assert sum(1 for *_, members in fused.units if members > 1) >= 1
         a = np.asarray(staged.run())
         b = np.asarray(fused.run())
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)

@@ -143,8 +143,8 @@ class TestML004ConfigFlow:
             from matrel_tpu.config import MatrelConfig
             cfg = MatrelConfig(obs_level="off")
         """
-        assert _lint(tmp_path, src, "tools/new_bench.py") == []
-        assert _lint(tmp_path, src, "bench.py") == []
+        assert _lint(tmp_path, src, "tools/new_probe.py") == []
+        assert _lint(tmp_path, src, "chip_smoke.py") == []
 
     def test_config_module_itself_exempt(self, tmp_path):
         src = """
@@ -260,7 +260,7 @@ class TestML006RawTiming:
                 return time.time()
         """
         # bench harnesses / tools are entry points, not library code
-        assert _lint(tmp_path, src, "bench.py") == []
+        assert _lint(tmp_path, src, "chip_smoke.py") == []
 
     def test_suppression_with_justification(self, tmp_path):
         src = """
@@ -540,7 +540,7 @@ class TestML010JitSeam:
         assert _lint(tmp_path, src,
                      "matrel_tpu/utils/compat.py") == []
         assert _lint(tmp_path, src, "tools/some_probe.py") == []
-        assert _lint(tmp_path, src, "bench.py") == []
+        assert _lint(tmp_path, src, "chip_smoke.py") == []
 
     def test_suppression_with_justification(self, tmp_path):
         src = """

@@ -152,24 +152,6 @@ class TestEventLog:
         log = EventLog(str(tmp_path / "no" / "such" / "dir" / "ev.jsonl"))
         assert log.emit("query", {"query_id": "q1"}) is None   # swallowed
 
-    def test_emit_tool_event_path_resolution(self, tmp_path,
-                                             monkeypatch):
-        from matrel_tpu.obs.events import emit_tool_event
-        # env var wins
-        envlog = str(tmp_path / "env.jsonl")
-        monkeypatch.setenv("MATREL_OBS_EVENT_LOG", envlog)
-        emit_tool_event("bench", {"value": 1.0},
-                        anchor_dir=str(tmp_path / "anchor"))
-        assert len(read_events(envlog)) == 1
-        # else the default name anchored at anchor_dir
-        monkeypatch.delenv("MATREL_OBS_EVENT_LOG")
-        (tmp_path / "anchor").mkdir()
-        emit_tool_event("soak", {"ok": True},
-                        anchor_dir=str(tmp_path / "anchor"))
-        [rec] = read_events(str(tmp_path / "anchor"
-                                / ".matrel_events.jsonl"))
-        assert rec["kind"] == "soak"
-
 
 class TestEventLogRotation:
     """obs_event_log_max_bytes: single-``.1``-sibling rotation with
@@ -533,8 +515,6 @@ class TestHistory:
                 "plan_cache": {"plans": 1, "evicted": 0},
                 "matmuls": [{"uid": 1, "strategy": "rmm",
                              "flops": 1e9, "est_ici_bytes": 2.0 ** 20}]})
-        log.emit("bench", {"value": 100.0})
-        log.emit("soak", {"ok": True})
         return log.path
 
     def test_summarize(self, tmp_path):
@@ -545,7 +525,6 @@ class TestHistory:
         assert s["execute_ms_total"] == 30.0
         assert s["strategies"]["rmm"]["count"] == 3
         assert s["rule_hits"]["fold_transpose"] == 3
-        assert s["bench_runs"] == 1 and s["soak_runs"] == 1
 
     def test_render_tables(self, tmp_path):
         from matrel_tpu.obs.history import render_queries, render_summary
@@ -615,37 +594,6 @@ class TestInstrumentationGuard:
         assert names == {"matrel_pagerank_compact",
                          "matrel_pagerank_onehot",
                          "matrel_pagerank_segment"}
-
-    def test_bench_emits_bench_event(self, tmp_path, monkeypatch):
-        """bench.py main() appends a `bench` record to the shared log."""
-        import bench
-        path = str(tmp_path / "ev.jsonl")
-        monkeypatch.setenv("MATREL_OBS_EVENT_LOG", path)
-        bench._emit_bench_event({"value": 1.23, "phases": {"setup_s": 0.1}})
-        [rec] = read_events(path)
-        assert rec["kind"] == "bench" and rec["value"] == 1.23
-
-    def test_bench_event_emission_stays_jax_free(self, tmp_path):
-        """The bench parent stays off jax (a chip belongs to one
-        process, and the measurement children need it): emitting its
-        obs event must not import jax."""
-        import os
-        import subprocess
-        import sys
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env = dict(os.environ, MATREL_OBS_EVENT_LOG=str(
-            tmp_path / "ev.jsonl"))
-        out = subprocess.run(
-            [sys.executable, "-c",
-             "import sys, bench; "
-             "bench._emit_bench_event({'value': 1.0}); "
-             "print('jax' in sys.modules)"],
-            capture_output=True, text=True, timeout=120, env=env,
-            cwd=repo)
-        assert out.returncode == 0, out.stderr
-        assert out.stdout.strip() == "False"
-        [rec] = read_events(str(tmp_path / "ev.jsonl"))
-        assert rec["kind"] == "bench"
 
 
 class TestTracingSpans:
@@ -1309,37 +1257,6 @@ class TestDriftAuditor:
         assert "calibration row" in report
         assert len(drift.calibrate(
             list(drift.iter_samples(events)))) >= 1
-
-
-class TestBenchErrorEvent:
-    """Satellite: a failed bench probe leaves a DISTINCT bench_error
-    record (the error tail) the summary surfaces."""
-
-    def test_emit_bench_error(self, tmp_path, monkeypatch):
-        import bench
-        path = str(tmp_path / "ev.jsonl")
-        monkeypatch.setenv("MATREL_OBS_EVENT_LOG", path)
-        bench._emit_bench_error(
-            "dense_blockmatmul_tflops_per_chip",
-            "probe timed out after 180s",
-            extra={"wall_s": 180.2})
-        [rec] = read_events(path)
-        assert rec["kind"] == "bench_error"
-        assert rec["wall_s"] == 180.2
-        assert "last_known_good" not in rec
-
-    def test_summary_surfaces_last_error_per_metric(self, tmp_path):
-        from matrel_tpu.obs.history import render_summary, summarize
-        log = EventLog(str(tmp_path / "ev.jsonl"))
-        log.emit("bench", {"metric": "m1", "value": 10.0})
-        log.emit("bench_error", {"metric": "m1", "error": "older"})
-        log.emit("bench_error", {"metric": "m1", "error": "wedge #2"})
-        events = read_events(log.path)
-        s = summarize(events)
-        assert s["bench_errors"]["m1"]["error"] == "wedge #2"  # last
-        text = render_summary(events)
-        assert "LAST BENCH ERROR [m1]: wedge #2" in text
-        assert "last known good" not in text
 
 
 class TestPhaseQuantiles:

@@ -313,6 +313,47 @@ class TestRunMany:
         assert again[0] is first[0]
         assert again[1] is first[1]
 
+    @pytest.mark.parametrize("batched", [False, True],
+                             ids=["sequential", "batched"])
+    def test_repeated_mixed_stream_second_round_all_hits(
+            self, mesh8, rng, batched):
+        """Dashboard traffic: six distinct queries (a PageRank-style
+        step, a normal-equations solve, a reordered chain, each with a
+        scalar variant) replayed round-robin. With the cache on, every
+        query of the second round is a whole hit — the first round's
+        own result object — and agrees with an uncached session."""
+        n, k = 256, 64
+        M, r = _mat(rng, n, n, mesh8), _mat(rng, n, 1, mesh8)
+        X, y = _mat(rng, n, k, mesh8), _mat(rng, n, 1, mesh8)
+        A, B, C = (_mat(rng, n, k, mesh8), _mat(rng, k, n, mesh8),
+                   _mat(rng, n, k, mesh8))
+        pr = M.expr().multiply(r.expr()).multiply_scalar(0.85)
+        xt = X.expr().t()
+        linreg = xt.multiply(X.expr()).solve(xt.multiply(y.expr()))
+        chain = A.expr().multiply(B.expr().multiply(C.expr()))
+        qs = [pr, pr.add_scalar(0.15 / n), linreg,
+              linreg.multiply_scalar(2.0), chain,
+              chain.multiply_scalar(0.5)]
+        stream = [qs[i % len(qs)] for i in range(18)]
+        sess = _sess(mesh8, **RC)
+
+        def replay():
+            if not batched:
+                return [sess.run(q) for q in stream]
+            return [o for j in range(0, len(stream), 6)
+                    for o in sess.run_many(stream[j:j + 6])]
+
+        first = replay()
+        hits = sess.result_cache_info()["hits"]
+        again = replay()
+        assert sess.result_cache_info()["hits"] - hits >= len(stream)
+        assert all(a is f for a, f in zip(again, first))
+        off = _sess(mesh8)
+        for q, got in zip(qs, again):
+            np.testing.assert_allclose(got.to_numpy(),
+                                       off.run(q).to_numpy(),
+                                       rtol=1e-4, atol=1e-4)
+
     def test_empty_batch(self, mesh8):
         assert _sess(mesh8).run_many([]) == []
 

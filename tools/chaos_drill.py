@@ -1,7 +1,6 @@
 """Chaos drill: a mixed serve stream under a seeded fault schedule.
 
-The resilience layer's acceptance harness (docs/RESILIENCE.md; the
-tpu_batch.sh fire-drill discipline): drive >= 50 queries — direct
+The resilience layer's acceptance harness (docs/RESILIENCE.md): drive >= 50 queries — direct
 ``run``, micro-batched ``run_many``, async ``submit`` — through a
 session whose EVERY instrumented choke point (compile, lower,
 strategy, execute, rc_probe, serve_admit, checkpoint) injects
@@ -25,10 +24,9 @@ converge-to-correct-or-typed-failure:
   - a checkpoint save/restore cycle survives its injected IO faults
     and round-trips the catalog exactly.
 
-Emits one parseable JSON line (tools/tpu_batch.sh step; asserted by
-tests/test_batch_dry.py). CPU-only by construction — this drills the
-recovery plumbing, not the chip, so it forces the CPU backend even
-inside a TPU batch (it never touches the chip).
+Emits one parseable JSON line (asserted by tests/test_drills.py).
+CPU-only by construction — this drills the recovery plumbing, not the
+chip, so it forces the CPU backend.
 MATREL_CHAOS_SEED varies the schedule; any fixed seed is bit-for-bit
 reproducible.
 """
@@ -82,7 +80,7 @@ def main() -> int:
     seed = int(os.environ.get("MATREL_CHAOS_SEED", "0"))
     faults.reset()
     # env (MATREL_*) overrides flow over the drill's base config, so
-    # the dry batch's redirects land every artifact outside the repo
+    # a caller's redirects land every artifact where it says
     cfg = MatrelConfig.from_env(MatrelConfig(
         fault_inject=FAULT_SPEC,
         fault_inject_seed=seed,

@@ -1,9 +1,7 @@
 """Obs tier-2 smoke drill: flight recorder + trace export + drift.
 
 Drives a real session through the round-9 observability surfaces and
-asserts each artifact end to end (the tpu_batch.sh fire-drill
-discipline — a staged tool that crashes on import is found HERE, not
-on chip time):
+asserts each artifact end to end:
 
   1. a 3-query micro-batched serve admission (``run_many``) plus one
      async ``submit`` — the admission/compile/execute span trail;
@@ -15,12 +13,11 @@ on chip time):
      one parent link — the Perfetto-loadable acceptance);
   5. a drift report with the calibration table persisted.
 
-Emits one parseable JSON line (tools/tpu_batch.sh step; asserted by
-tests/test_batch_dry.py). CPU-only by construction — this drills the
-observability plumbing, not the chip, so it forces the CPU backend
-even inside a TPU batch (it never touches the chip).
+Emits one parseable JSON line (asserted by tests/test_drills.py).
+CPU-only by construction — this drills the observability plumbing, not
+the chip, so it forces the CPU backend.
 
-Artifact paths follow the config env knobs, so the dry batch redirects
+Artifact paths follow the config env knobs, so a caller redirects
 everything: MATREL_OBS_EVENT_LOG (span/event log),
 MATREL_OBS_FLIGHT_RECORDER_PATH (dump artifact),
 MATREL_DRIFT_TABLE_PATH (calibration table).
@@ -54,7 +51,7 @@ def main() -> int:
     from matrel_tpu.session import MatrelSession
 
     # env (MATREL_*) overrides flow over the drill's base config, so
-    # the dry batch's redirects land every artifact outside the repo
+    # a caller's redirects land every artifact where it says
     cfg = MatrelConfig.from_env(MatrelConfig(
         obs_level="on", obs_flight_recorder=256,
         result_cache_max_bytes=1 << 26))

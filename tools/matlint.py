@@ -88,8 +88,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: Default scan set for ``make lint`` / the repo-clean test. tests/ is
 #: excluded by design: tests legitimately poke every hazard (poisoned
 #: to_dense spies, sync-forcing fixtures) and carry their own review.
-DEFAULT_PATHS = ("matrel_tpu", "tools", "examples", "bench.py",
-                 "bench_all.py")
+DEFAULT_PATHS = ("matrel_tpu", "tools", "examples", "chip_smoke.py")
 
 _SUPPRESS_RE = re.compile(r"#\s*matlint:\s*disable=([A-Za-z0-9_,\s]+)")
 

@@ -1,9 +1,7 @@
 """Obs tier-4 smoke drill: answer provenance ledger + audit replay.
 
 Drives real sessions through every provenance-bearing serve path and
-then proves the ledgers by full audit replay (the tpu_batch.sh
-fire-drill discipline — a staged tool that crashes on import is found
-HERE, not on chip time):
+then proves the ledgers by full audit replay:
 
   1. a 3-query serve batch (``run_many``) twice — fresh ``execute``
      records, then whole ``rc_hit`` records — plus a superexpression
@@ -21,12 +19,10 @@ HERE, not on chip time):
      comparison: bit-equal when the composed bound is 0, within the
      stamped err_bound otherwise) + the MV115 dynamic ledger check.
 
-Emits one parseable JSON line (tools/tpu_batch.sh step; asserted by
-tests/test_batch_dry.py). CPU-only by construction — this drills the
-lineage plumbing, not the chip, so it forces the CPU backend even
-inside a TPU batch (it never touches the chip). Artifact
-paths follow the config env knobs (MATREL_OBS_EVENT_LOG), so the dry
-batch redirects the event log outside the repo.
+Emits one parseable JSON line (asserted by tests/test_drills.py).
+CPU-only by construction — this drills the lineage plumbing, not the
+chip, so it forces the CPU backend. Artifact paths follow the config
+env knobs (MATREL_OBS_EVENT_LOG), so a caller redirects the event log.
 """
 
 import json
@@ -62,7 +58,7 @@ def main() -> int:
     from matrel_tpu.session import MatrelSession
 
     # env (MATREL_*) overrides flow over the drill's base configs, so
-    # the dry batch's redirects land every artifact outside the repo
+    # a caller's redirects land every artifact where it says
     base = dict(obs_level="on", obs_provenance=256,
                 result_cache_max_bytes=1 << 28)
     mesh = mesh_lib.make_mesh((2, 4))

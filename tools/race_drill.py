@@ -22,8 +22,7 @@ Rebinds and deltas are VALUE-PRESERVING (same numbers, new objects),
 so every resolved answer has one oracle regardless of interleaving:
 any mismatch is a real race, not an ordering ambiguity.
 
-Contract (the artifact line, asserted by tests/test_batch_dry.py and
-staged in tools/tpu_batch.sh):
+Contract (the artifact line, asserted by tests/test_drills.py):
   - 0 wrong answers
   - 0 untyped failures (every refusal is ResilienceError-family)
   - lockdep order graph acyclic, 0 inversions recorded, across all
@@ -36,7 +35,7 @@ Knobs (env, dry-run friendly):
 
 Usage:
   python tools/race_drill.py            # CPU-forced, prints one JSON line
-  MATREL_RACE_SEEDS=2 python tools/race_drill.py   # the batch dry stage
+  MATREL_RACE_SEEDS=2 python tools/race_drill.py   # tier-1's size
 """
 
 from __future__ import annotations
@@ -74,7 +73,7 @@ TIMEOUT = 60            # every wait in the drill is bounded (wedge-safe)
 
 def _base_cfg(**kw) -> MatrelConfig:
     """Drill base config; MATREL_* env still flows over it (the
-    provenance_drill idiom) so the batch script can tighten knobs."""
+    provenance_drill idiom) so a caller can tighten knobs."""
     base = dict(lockdep_enable=True, lockdep_raise=False,
                 serve_max_batch=1,
                 result_cache_max_bytes=64 << 20)

@@ -1752,8 +1752,7 @@ def main():
 def _log_tally(args, n_fails, fail_heads, t_start):
     """Append a machine-checkable tally line to SOAKLOG.jsonl — the
     committed evidence trail for soak runs (round-2 VERDICT: tallies
-    lived only as prose in docs). Every run, CPU or TPU, logs here;
-    soak_guard additionally logs its wrapper event to PROGRESS.jsonl."""
+    lived only as prose in docs). Every run, CPU or TPU, logs here."""
     import json
     import time
     try:
@@ -1770,11 +1769,7 @@ def _log_tally(args, n_fails, fail_heads, t_start):
            "failures": n_fails,
            "fail_heads": [str(f) for f in fail_heads],
            "wall_s": round(time.time() - t_start, 1)}
-    # $MATREL_SOAKLOG_PATH: the dry-batch fire-drill redirects the
-    # tally (toy CPU drills must not write into the committed soak
-    # evidence trail) — same contract as MATREL_PROGRESS_PATH
-    path = os.environ.get("MATREL_SOAKLOG_PATH",
-                          os.path.join(REPO, "SOAKLOG.jsonl"))
+    path = os.path.join(REPO, "SOAKLOG.jsonl")
     try:
         with open(path, "a") as f:
             f.write(json.dumps(rec) + "\n")

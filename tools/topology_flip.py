@@ -12,9 +12,9 @@ and proves, through the real planner entry points, that:
   3. a weighted config executes a real multiply to oracle numerics
      (weights re-route choices, never change results).
 
-Emits one parseable JSON line (tools/tpu_batch.sh step; asserted by
-tests/test_batch_dry.py). CPU-only by construction — this is a
-planning check, so it forces the CPU backend even inside a TPU batch.
+Emits one parseable JSON line (asserted by tests/test_drills.py).
+CPU-only by construction — this is a planning check, so it forces the
+CPU backend.
 """
 
 import json

@@ -10,8 +10,8 @@ declarative tenant x workload mix submits on schedule whether or not
 the engine kept up, which is the only way queue growth, typed
 shedding, weighted fairness and brownout actually happen.
 
-Three phases, one parseable JSON artifact (tpu_batch.sh step in BOTH
-modes; asserted by tests/test_batch_dry.py::test_traffic_row_artifact):
+Three phases, one parseable JSON artifact (asserted by
+tests/test_drills.py):
 
   1. closed-loop calibration: sequential ``run`` over the workload
      pool measures capacity C (the goodput denominator);
@@ -47,8 +47,8 @@ of goodput: the violated (lowest-weight) tenant's fast-window
 burn-rate alert must FIRE during saturation and every alert must
 CLEAR after the load drops, with the Prometheus endpoint strict-
 parsing clean on every poll throughout and still zero wrong answers.
-One parseable ``traffic_slo_harness`` JSON artifact (tpu_batch.sh
-stages both modes; test_batch_dry asserts both).
+One parseable ``traffic_slo_harness`` JSON artifact
+(tests/test_drills.py asserts both modes).
 
 Latency is measured to future RESOLUTION (dispatch-complete — the
 serve plane's own SLA semantics since PR 5). The workload mix reuses
@@ -444,9 +444,8 @@ def main(slo: bool = False) -> int:
         # singles — no dense compute to amortize, collectives grow
         # with the program), and the harness proves the ADMISSION
         # plane — weighted-fair ORDER, quota sheds, brownout,
-        # breakers — not batching throughput (bench.py --serve owns
-        # that; fair batch COMPOSITION is unit-test-pinned in
-        # tests/test_overload.py). MATREL_SERVE_MAX_BATCH widens it
+        # breakers — not batching throughput (fair batch COMPOSITION
+        # is unit-test-pinned in tests/test_overload.py). MATREL_SERVE_MAX_BATCH widens it
         # on a real TPU, where the MXU turns coalescing into a win.
         serve_max_batch=1,
         plan_cache_max_plans=256,
@@ -781,8 +780,8 @@ def main_slices() -> int:
         against its numpy oracle) and only TYPED failures, queued
         entries re-admitted with deadlines/tenants intact.
 
-    One parseable ``traffic_fleet_harness`` JSON artifact (staged in
-    tpu_batch.sh; asserted by test_batch_dry)."""
+    One parseable ``traffic_fleet_harness`` JSON artifact (asserted by
+    tests/test_drills.py)."""
     from matrel_tpu.config import MatrelConfig
     from matrel_tpu.core import mesh as mesh_lib
     from matrel_tpu.resilience import faults
