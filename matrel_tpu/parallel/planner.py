@@ -19,7 +19,8 @@ from jax.sharding import Mesh
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib
 from matrel_tpu.ir.expr import MatExpr
-from matrel_tpu.parallel.strategies import (acc_itemsize, rmm_panels,
+from matrel_tpu.parallel.strategies import (acc_itemsize,
+                                            rmm_moves_under_dot, rmm_panels,
                                             rmm_transient_bytes)
 
 
@@ -1209,7 +1210,7 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
     lie). Every candidate's plan-level peak (:func:`plan_hbm_bytes`)
     is held to ``core.mesh.hbm_limit_bytes``. ``hbm_detail`` (an out-param
     dict, like ``cost_detail``) receives ``chosen``, its ``panels``,
-    ``hbm_plan_bytes`` and ``refused_hbm``."""
+    ``moves_under_dot``, ``hbm_plan_bytes`` and ``refused_hbm``."""
     cfg = config or default_config()
     if _spgemm_matmul(node, cfg):
         # S×S below the density crossover: the LOWERING dispatches the
@@ -1265,6 +1266,8 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
             need, panels, ok = _hbm_gate([forced], pn, pk, pm, gx, gy, isz,
                                          alive_bytes, limit)[forced]
             hbm_detail.update(chosen=forced, panels=panels,
+                              moves_under_dot=rmm_moves_under_dot(
+                                  pk, gy, panels),
                               hbm_plan_bytes=int(need),
                               refused_hbm=[] if ok else [forced])
         return forced, "override"
@@ -1366,6 +1369,8 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
     def _report(strategy):
         if hbm_detail is not None:
             hbm_detail.update(chosen=strategy, panels=fits[strategy][1],
+                              moves_under_dot=rmm_moves_under_dot(
+                                  pk, gy, fits[strategy][1]),
                               hbm_plan_bytes=int(fits[strategy][0]),
                               refused_hbm=refused)
 
@@ -1769,6 +1774,8 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
             # (plan.meta / the plan.strategy spans: hbm_report)
             if strat == "rmm" and tuple(hbm["panels"]) != (1, 1):
                 stamp["panels"] = tuple(hbm["panels"])
+            if hbm["moves_under_dot"]:
+                stamp["moves_under_dot"] = hbm["moves_under_dot"]
             if hbm["refused_hbm"]:
                 stamp["refused_hbm"] = tuple(hbm["refused_hbm"])
             stamp["hbm_plan_bytes"] = hbm["hbm_plan_bytes"]
@@ -1802,6 +1809,8 @@ def hbm_report(root: MatExpr) -> list:
     """What the plan-level memory reckoning decided, read back from an
     ANNOTATED plan: one record a dense matmul, in evaluation order —
     ``chosen``, ``refused_hbm``, ``panels`` (rows, columns),
+    ``moves_under_dot`` (strategies.rmm_moves_under_dot: the moves a
+    row panel of the panelled rmm hides under a dot),
     ``hbm_plan_bytes``. Empty on one device, where nothing is
     reckoned."""
     out, seen = [], set()
@@ -1816,6 +1825,7 @@ def hbm_report(root: MatExpr) -> list:
             out.append({"chosen": n.attrs.get("strategy"),
                         "refused_hbm": list(n.attrs.get("refused_hbm", ())),
                         "panels": list(n.attrs.get("panels", (1, 1))),
+                        "moves_under_dot": n.attrs.get("moves_under_dot", 0),
                         "hbm_plan_bytes": n.attrs["hbm_plan_bytes"]})
 
     walk(root)

@@ -145,6 +145,16 @@ def acc_itemsize(itemsize: int) -> int:
     return max(int(itemsize), 4)
 
 
+def rmm_moves_under_dot(pk: int, gy: int, panels: Tuple[int, int]) -> int:
+    """How many moves of A's slices a row panel of the panelled rmm
+    issues ahead of a dot that hides them: the other gy − 1 devices'
+    slices where the contraction is cut along the mesh row and the
+    product runs in column panels (panel 0 is then multiplied before
+    the loop over the others, its own chunk first, while the slices
+    are on their way); 0 where there is no move or no loop."""
+    return gy - 1 if gy > 1 and pk % gy == 0 and panels[1] > 1 else 0
+
+
 def rmm_transient_bytes(pn: int, pk: int, pm: int, gx: int, gy: int,
                         itemsize: int, panels: Tuple[int, int]) -> float:
     """What the panelled rmm allocates on one device beside its operand
@@ -159,7 +169,12 @@ def rmm_transient_bytes(pn: int, pk: int, pm: int, gx: int, gy: int,
     where one dot stores what it accumulates). Held beside the chip's
     compiler at 65536² bf16 on a 2×2 mesh it reads 4.75 / 3.4 GiB at
     8 / 16 column panels where the compiler's own temporaries were
-    5.0 / 3.5 (PR 27)."""
+    5.0 / 3.5 (PR 27). Where column panel 0 is multiplied ahead of the
+    loop (:func:`rmm_moves_under_dot`) it leaves its dots rounded, as a
+    panel of its own, before the stored output is made around it: one
+    panel in the storage width beside everything the loop holds (0.25
+    GiB at 8 panels there: 5.0 GiB, the described chip's compile of
+    both products 7.25 beside 7.0; PR 30)."""
     r, c = panels
     rows, cols = pn / gx / r, pm / gy / c
     acc = acc_itemsize(itemsize)
@@ -170,6 +185,8 @@ def rmm_transient_bytes(pn: int, pk: int, pm: int, gx: int, gy: int,
         out += (pk / gy) * cols * itemsize + 2 * rows * cols * acc
     elif acc > itemsize:
         out += rows * cols * acc
+    if rmm_moves_under_dot(pk, gy, panels):
+        out += rows * cols * itemsize
     return out
 
 
@@ -223,8 +240,20 @@ def matmul_rmm(a: jax.Array, b: jax.Array, mesh: Mesh,
     flight, and the product's panel in the accumulator's width: at
     65536² bf16 on a 2×2 v5e mesh the whole gathers are 8 GiB beside
     8 GiB of tables and intermediate, and cannot be allocated (PERF.md
-    §6, PR 27). Every further row panel gathers B once more. The
-    planner derives the counts from what its plan leaves of the chip's
+    §6, PR 27). Every further row panel gathers B once more.
+
+    A loop takes its operands whole, so a move asked for in front of it
+    is waited for in front of it, with nothing to run beside it. Where
+    there are both (:func:`rmm_moves_under_dot`: column panels, and the
+    contraction cut along the mesh row), column panel 0 of every row
+    panel is therefore multiplied ahead of the loop, straight-line: its
+    own chunk's dot reads no moved slice and runs while they arrive (at
+    65536² on the 2×2 mesh a dot of 47 ms over a move of 39 that stood
+    exposed, PERF.md §6, PR 30); the loop runs panels 1 … c − 1. Same
+    dots, same order of the sums, same one rounding: the answer is the
+    loop's bit for bit.
+
+    The planner derives the counts from what its plan leaves of the chip's
     memory (planner.choose_strategy_ex); None derives them here for the
     product taken alone (:func:`rmm_panels` on ``hbm_limit_bytes`` less
     the operands' shards and the output). There is no width knob."""
@@ -275,24 +304,40 @@ def matmul_rmm(a: jax.Array, b: jax.Array, mesh: Mesh,
             return (jax.lax.all_gather(pb, x, axis=0, tiled=True)
                     if b_cut else pb)
 
-        def dot(slices, pb):
+        def dot(slices, pb, moving=False):
             """The panel of C over the whole contraction, summed in the
-            accumulator's dtype; one rounding, as the panel leaves."""
-            total = None
-            for s, pa in enumerate(slices):
-                rows_b = (jax.lax.dynamic_slice_in_dim(
+            accumulator's dtype, this device's own chunk first; one
+            rounding, as the panel leaves. ``moving``: the other
+            devices' slices are still on their way."""
+            def rows_b(s):
+                return (jax.lax.dynamic_slice_in_dim(
                     pb, ((j + s) % gy) * chunk, chunk, 0) if a_cut else pb)
-                part = _local_dot(pa, rows_b, prec, acc)
-                total = part if total is None else total + part
+
+            total = _local_dot(slices[0], rows_b(0), prec, acc)
+            moved = slices[1:]
+            if moving:
+                # what the other chunks' dots read exists only once the
+                # own chunk's dot is done, so that dot may not sink
+                # below the slices' arrival and the panel is not
+                # gathered a second time behind it (under memory
+                # pressure the chip's compiler was seen to do both).
+                # Orders, computes nothing; the sum below reads the dot
+                # itself, so it still rides the last dot's output
+                _, moved, pb = jax.lax.optimization_barrier(
+                    (total, moved, pb))
+            for s, pa in enumerate(moved, 1):
+                total = total + _local_dot(pa, rows_b(s), prec, acc)
             return total.astype(store)
 
         if r == 1 and c == 1:
             return dot(row_panel(0), col_panel(0))
 
+        ahead = rmm_moves_under_dot(k, gy, panels) > 0
+
         def rows_of(i, out):
             slices = row_panel(i)
 
-            def cols_of(jc, carry):
+            def cols_of(jc, carry, moving=False):
                 out, pb = carry
                 # the next column panel is asked for before this one is
                 # multiplied, so its gather runs under the dots (the
@@ -300,9 +345,14 @@ def matmul_rmm(a: jax.Array, b: jax.Array, mesh: Mesh,
                 # the same collectives)
                 nxt = col_panel((jc + 1) % c) if c > 1 else pb
                 return jax.lax.dynamic_update_slice(
-                    out, dot(slices, pb), (i * rows, jc * cols)), nxt
+                    out, dot(slices, pb, moving), (i * rows, jc * cols)), nxt
 
-            return jax.lax.fori_loop(0, c, cols_of, (out, col_panel(0)))[0]
+            carry = out, col_panel(0)
+            if ahead:
+                # panel 0 ahead of the loop: a loop waits for the moves
+                # of ``slices`` in front of it, with no dot beside them
+                carry = cols_of(0, carry, moving=True)
+            return jax.lax.fori_loop(int(ahead), c, cols_of, carry)[0]
 
         out0 = compat.pvary(jnp.zeros((ab.shape[0], bb.shape[1]), store),
                             (x, y))

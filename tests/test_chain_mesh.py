@@ -108,15 +108,24 @@ def test_small_budget_panels_and_is_bit_for_bit_one_panel(
     assert rel_err(got, want) < 1e-2
 
 
-@pytest.mark.parametrize("panels", [(1, 2), (2, 1), (2, 4), (4, 2)])
-def test_panelled_rmm_is_the_one_panel_product(mesh_square, tables, panels):
+@pytest.mark.parametrize("grid, panels", [
+    ((2, 2), (1, 2)), ((2, 2), (2, 1)), ((2, 2), (2, 4)), ((2, 2), (4, 2)),
+    # column panel 0 is multiplied ahead of the loop wherever there is a
+    # loop and a slice to move (PR 30): the loop then runs once after
+    # each of two prologues; three moved slices a row panel (gy = 4),
+    # with no gather beside them (1x4) and with one (2x4)
+    ((2, 2), (2, 2)), ((1, 4), (1, 2)), ((2, 4), (2, 4))])
+def test_panelled_rmm_is_the_one_panel_product(tables, grid, panels):
     """Below the session: run_matmul's rmm at forced panel counts."""
-    arrays, _ = tables
-    a, b = arrays["A"], arrays["B"]
+    mesh = mesh_lib.make_mesh(grid, devices=jax.devices()[:grid[0] * grid[1]])
+    x, y = mesh.axis_names
+    a, b = (jax.device_put(tables[0][name],
+                           jax.sharding.NamedSharding(mesh, P(x, y)))
+            for name in "AB")
 
     def product(p):
         return jax.jit(lambda u, v: strategies.run_matmul(
-            "rmm", u, v, mesh_square, MatrelConfig(), panels=p,
+            "rmm", u, v, mesh, MatrelConfig(), panels=p,
             out_dtype=jnp.bfloat16))(a, b)
 
     one, many = product((1, 1)), product(panels)
@@ -204,12 +213,23 @@ def test_cell_estimates_beside_what_the_chip_said(mesh_square):
     for s in ("rmm", "xla", "cpmm", "summa", "bmm_left", "bmm_right"):
         assert planner.plan_hbm_bytes(s, *args, alive) > V5E_BYTES_LIMIT
     # eight column panels: 2 GiB of A's slices, three panels of 0.5 GiB,
-    # a chunk's slice of one, two float32 panels of 0.5 GiB
+    # a chunk's slice of one, two float32 panels of 0.5 GiB, and panel
+    # 0 rounded (0.25 GiB), multiplied ahead of the loop (PR 30)
     room = MatrelConfig().hbm_budget_bytes - alive - 2 * GIB
     assert strategies.rmm_panels(*args, room) == (1, 8)
-    assert strategies.rmm_transient_bytes(*args, (1, 8)) == 4.75 * GIB
+    assert strategies.rmm_moves_under_dot(65536, 2, (1, 8)) == 1
+    assert strategies.rmm_transient_bytes(*args, (1, 8)) == 5.0 * GIB
     assert planner.plan_hbm_bytes("rmm", *args, alive, (1, 8)) \
-        == 14.75 * GIB
+        == 15.0 * GIB
+    # no loop, or no move along the mesh row: nothing is ahead of it
+    assert strategies.rmm_moves_under_dot(65536, 2, (1, 1)) == 0
+    assert strategies.rmm_moves_under_dot(65536, 2, (4, 1)) == 0
+    assert strategies.rmm_moves_under_dot(65536, 1, (1, 8)) == 0
+    assert strategies.rmm_moves_under_dot(65536, 4, (2, 8)) == 3
+    # a 4x1 mesh moves no slice: three gathered panels of 1 GiB and
+    # one float32 panel, as before
+    assert strategies.rmm_transient_bytes(65536, 65536, 65536, 4, 1, 2,
+                                          (1, 8)) == 3.5 * GIB
 
 
 def test_budget_is_held_to_what_the_device_reports(monkeypatch, mesh_square):
@@ -273,8 +293,17 @@ def test_spans_carry_the_reckoning(mesh_square, tables, tmp_path):
         assert r["attrs"]["hbm_plan_bytes"] == meta["hbm_plan_bytes"]
     products = [r["attrs"] for r in mine
                 if r["name"] == "matrel.plan.strategy"]
-    assert [(p["chosen"], list(p["panels"])) for p in products] \
-        == [(p["chosen"], p["panels"]) for p in meta["products"]]
+    assert [(p["chosen"], list(p["panels"]), p["moves_under_dot"])
+            for p in products] \
+        == [(p["chosen"], p["panels"], p["moves_under_dot"])
+            for p in meta["products"]]
+    # gy - 1 moves a row panel run under panel 0's dot where the plan
+    # is panelled; the plan that fits whole has no loop to stand before
+    assert all(p["panels"][1] > 1 and p["moves_under_dot"] == 1
+               for p in products)
+    whole = chain(mesh_square, arrays, MatrelConfig(strategy_override="rmm"))
+    assert [(p["panels"], p["moves_under_dot"])
+            for p in whole[1]["products"]] == [([1, 1], 0)] * 2
     compiles = [r for r in mine if r["name"] == "matrel.compile"]
     assert len(compiles) == 1
     reader = harness.load_module(os.path.join(

@@ -23,6 +23,7 @@ from matrel_tpu.config import MatrelConfig
 from matrel_tpu.ops import kernel_registry as kr
 from matrel_tpu.ops import pallas_spmm, pallas_spmv as pc
 from matrel_tpu.ops import spmv as spmv_lib
+from matrel_tpu.parallel import strategies
 
 # README-scale PageRank plan (tools: build_spmv_plan on 1M/10M uniform)
 NB, CAP, BLOCK = 1954, 5376, 512
@@ -51,6 +52,12 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    return Mesh(np.asarray(topo.devices, dtype=object).reshape(2, 2),
+                ("x", "y"))
 
 
 def _sds(sharding, shape, dtype):
@@ -122,9 +129,8 @@ def test_compact_spmv_k_wide(one_chip):
              _compact_table_shapes(NB, one_chip), (), X, 3, False)
 
 
-def test_compact_spmv_sharded_2x2(topo):
-    mesh = Mesh(np.asarray(topo.devices, dtype=object).reshape(2, 2),
-                ("x", "y"))
+def test_compact_spmv_sharded_2x2(mesh_2x2):
+    mesh = mesh_2x2
     axes = tuple(mesh.axis_names)
     nb_pad = -(-NB // mesh.size) * mesh.size
     tables = _compact_table_shapes(
@@ -216,3 +222,83 @@ def test_band_budget_matches_compiler(one_chip, bs, dtype, wa, rc, fits):
         jax.jit(kernel), _sds(one_chip, (gr, bs, wa * bs), dtype),
         _sds(one_chip, (gr * nch, wa * bs, rc * bs), dtype))
     assert (refused is None) == fits
+
+
+# -- the 65k chain's two panelled products (cell chain_65k_2x2) --------------
+
+CHAIN_N, CHAIN_PANELS = 65536, (1, 8)
+
+
+@pytest.fixture(scope="module")
+def chain_program(mesh_2x2):
+    """``(A * B) * C`` at 65536^2 bfloat16 under the panelled rmm as the
+    cell's plan runs it, both products in ONE program (one product
+    alone misleads: with 6 GiB of arguments beside them the compiler
+    moves what it left in place before, PERF.md section 6, PR 30)."""
+    table = _sds(NamedSharding(mesh_2x2, P("x", "y")), (CHAIN_N, CHAIN_N),
+                 jnp.bfloat16)
+
+    def mm(u, v):
+        return strategies.run_matmul("rmm", u, v, mesh_2x2, MatrelConfig(),
+                                     panels=CHAIN_PANELS,
+                                     out_dtype=jnp.bfloat16)
+
+    return jax.jit(lambda a, b, c: mm(mm(a, b), c)).lower(
+        table, table, table).compile()
+
+
+def _entry_schedule(text, conv_shape):
+    """The scheduled entry computation as a list of operation kinds, in
+    order: ``permute-start``/``permute-done`` with the start's name,
+    and ``dot`` for a fusion whose computation holds a convolution of
+    ``conv_shape``."""
+    dots, current = set(), None
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$",
+                        line)
+        if head:
+            current = head.group(1)
+        elif current and re.search(
+                rf"=\s*{re.escape(conv_shape)}\S*\s+convolution\(", line):
+            dots.add(current)
+    entry = text[text.index("\nENTRY "):]
+    out = []
+    for line in entry[:entry.index("\n}")].splitlines():
+        start = re.match(r"\s*%([\w.\-]+)\s*=.*\scollective-permute-start\(",
+                         line)
+        done = re.search(r"\scollective-permute-done\(%([\w.\-]+)\)", line)
+        calls = re.search(r"\sfusion\(.*calls=%([\w.\-]+)", line)
+        if start:
+            out.append(("permute-start", start.group(1)))
+        elif done:
+            out.append(("permute-done", done.group(1)))
+        elif calls and calls.group(1) in dots:
+            out.append(("dot", calls.group(1)))
+    return out
+
+
+def test_chain_moves_run_under_a_dot(chain_program):
+    """Each product moves a 2 GiB slice of its left operand along the
+    mesh row. In front of a loop the move stood exposed, 39 ms of every
+    chip's time a product (PR 27 to 29); with column panel 0 multiplied
+    ahead of the loop, its own chunk's dot runs between the move's
+    start and its arrival. And no dot is there twice: four ahead of the
+    two loops, the own chunk's and the moved one's of either product."""
+    rows, cols = CHAIN_N // 2, CHAIN_N // 2 // CHAIN_PANELS[1]
+    ops = _entry_schedule(chain_program.as_text(), f"f32[{rows},{cols}]")
+    starts = [i for i, (kind, _) in enumerate(ops) if kind == "permute-start"]
+    assert len(starts) == 2, ops
+    for i in starts:
+        done = ops.index(("permute-done", ops[i][1]))
+        assert "dot" in [kind for kind, _ in ops[i:done]], ops
+    assert [kind for kind, _ in ops].count("dot") == 4, ops
+
+
+def test_chain_temporaries_are_what_the_plan_reckons(chain_program):
+    """The program's temporaries are the 2 GiB intermediate and one
+    product's transient (the two products' are not alive together):
+    ``rmm_transient_bytes`` may stand at most 5% under the compiler."""
+    reckoned = CHAIN_N * CHAIN_N * 2 / 4 + strategies.rmm_transient_bytes(
+        CHAIN_N, CHAIN_N, CHAIN_N, 2, 2, 2, CHAIN_PANELS)
+    assert chain_program.memory_analysis().temp_size_in_bytes \
+        <= 1.05 * reckoned
