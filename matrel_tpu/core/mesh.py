@@ -80,10 +80,16 @@ def device_bytes_limit(mesh: Mesh) -> Optional[int]:
     """The least ``memory_stats()["bytes_limit"]`` over the mesh's
     devices — what the runtime will really hand out on a chip (a v5e
     reports 16,909,334,528 B, 270 MB under 16 GiB) — or None where the
-    backend reports none (the CPU). A device's limit does not change,
-    so it is asked once a mesh."""
-    limits = [(d.memory_stats() or {}).get("bytes_limit")
-              for d in mesh.devices.flat]
+    backend reports none (the CPU) or the device cannot be asked (one
+    that is described and not attached). A device's limit does not
+    change, so it is asked once a mesh."""
+    def stats(d):
+        try:
+            return d.memory_stats() or {}
+        except jax.errors.JaxRuntimeError:
+            return {}
+
+    limits = [stats(d).get("bytes_limit") for d in mesh.devices.flat]
     limits = [int(x) for x in limits if x]
     return min(limits) if limits else None
 

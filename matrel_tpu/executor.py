@@ -689,8 +689,14 @@ class Lowerer:
                     mm = lambda p, q: strategies.run_matmul(
                         strategy, p, q.T, self.mesh, self.config)
                 return fin(symmetric_gram(x, mm).astype(jnp.float32))
-        a, b = ev(node.children[0]), ev(node.children[1])
         strategy = node.attrs.get("strategy", "xla")
+        if (self.mesh.size == 1 and strategy == "xla"
+                and node.attrs.get("precision_tier") in (None, "f32")):
+            out = self._long_contraction(node, ev)
+            if out is not None:
+                self._ran(strategy)
+                return fin(out)
+        a, b = ev(node.children[0]), ev(node.children[1])
         self._ran(strategy)
         if self.config.reshard_peak_budget_bytes > 0:
             # staged reshard lowering (parallel/reshard.py): re-lay
@@ -737,6 +743,24 @@ class Lowerer:
         return strategies.run_matmul(strategy, a, b, self.mesh,
                                      self.config, epilogue=storage_epi,
                                      panels=panels, out_dtype=store)
+
+    def _long_contraction(self, node: MatExpr, ev) -> Optional[Array]:
+        """One device's plain local dot over a LONG float32 contraction
+        (the regression's t(X)·X and t(X)·y over millions of rows):
+        accumulated in panels (strategies.dot_in_panels), or None where
+        the product is not one (bfloat16 and integer tables round, or
+        do not round, their answers on other terms). A transposed
+        operand is handed over by its dimension, untransposed."""
+        l, r = node.children
+        if l.shape[1] < strategies.LONG_CONTRACTION:
+            return None
+        a, ca = (ev(l.children[0]), 0) if l.kind == "transpose" \
+            else (ev(l), 1)
+        b, cb = (ev(r.children[0]), 1) if r.kind == "transpose" \
+            else (ev(r), 0)
+        if a.dtype != jnp.float32 or b.dtype != jnp.float32:
+            return None
+        return strategies.dot_in_panels(a, ca, b, cb, self.config)
 
     def _stage_root_relay(self, root: MatExpr, out: Array) -> Array:
         """Root output → canonical 2d through the compiled reshard
@@ -1315,14 +1339,15 @@ def _fusion_meta(opts, cfg) -> Optional[Dict]:
             "est_saved_hbm_bytes": saved_b}
 
 
-def _hbm_meta(opts, mesh) -> Dict:
+def _hbm_meta(opts, mesh, cfg) -> Dict:
     """The plan-level memory reckoning's verdict for ``plan.meta`` (and,
     through it, the ``dispatch`` span and a Deployment's notes): the
     mesh's grid, the plan's reckoned peak on one device
-    (``hbm_plan_bytes``: the largest over its products) and one record
-    a product (planner.hbm_report), each also a ``plan.strategy`` span
-    under ``compile``. A one-device plan is not reckoned: the grid
-    alone."""
+    (``hbm_plan_bytes``: the largest over its products, solves and
+    materialised transposes) and one record each (planner.hbm_report),
+    each also a ``plan.strategy`` span under ``compile``. A one-device
+    plan over the limit is refused here, before anything is traced
+    (planner.refuse_over_limit)."""
     meta: Dict = {"mesh": "x".join(
         str(g) for g in mesh_lib.mesh_grid_shape(mesh))}
     products = [rec for o in opts for rec in planner.hbm_report(o)]
@@ -1332,6 +1357,7 @@ def _hbm_meta(opts, mesh) -> Dict:
         for rec in products:
             with trace_lib.span("plan.strategy", **rec):
                 pass
+    planner.refuse_over_limit(opts, mesh, cfg)
     return meta
 
 
@@ -1390,7 +1416,8 @@ def compile_exprs(exprs, mesh: Optional[Mesh] = None,
             from matrel_tpu.ir import fusion as fusion_lib
             opts = tuple(fusion_lib.annotate_fusion(o, mesh, cfg)
                          for o in opts)
-    hbm = _hbm_meta(opts, mesh)
+        sp_opt.set(**rule_hits)     # which rewrites made this plan
+    hbm = _hbm_meta(opts, mesh, cfg)
     with trace_lib.phase("plan.verify"):
         verify_diags = _verify_plans(opts, mesh, cfg)
     leaf_order = []
@@ -1643,7 +1670,8 @@ def compile_expr(expr: MatExpr, mesh: Optional[Mesh] = None,
             # (the compile_exprs ordering — one contract)
             from matrel_tpu.ir import fusion as fusion_lib
             opt = fusion_lib.annotate_fusion(opt, mesh, cfg)
-    hbm = _hbm_meta((opt,), mesh)
+        sp_opt.set(**rule_hits)     # which rewrites made this plan
+    hbm = _hbm_meta((opt,), mesh, cfg)
     with trace_lib.phase("plan.verify"):
         verify_diags = _verify_plans((opt,), mesh, cfg)
     leaf_order = expr_leaves(opt)

@@ -13,14 +13,52 @@ from __future__ import annotations
 from typing import List, Optional, Tuple
 
 from matrel_tpu.ir import stats
-from matrel_tpu.ir.expr import MatExpr, matmul
+from matrel_tpu.ir.expr import MatExpr, inverse, matmul, solve, transpose
 
 
 def collect_chain(e: MatExpr) -> List[MatExpr]:
-    """Flatten a maximal matmul tree into its ordered operand list."""
-    if e.kind != "matmul":
-        return [e]
-    return collect_chain(e.children[0]) + collect_chain(e.children[1])
+    """Flatten a maximal product tree into its ordered operand list.
+    ``solve(A, B)`` IS the product A⁻¹·B: it joins the chain as the
+    factor ``inverse(A)`` ahead of B's own factors, so that a solve
+    the user (or R7) bracketed around one factor of a longer product
+    is associated by cost like any other (:func:`join`)."""
+    if e.kind == "matmul":
+        return collect_chain(e.children[0]) + collect_chain(e.children[1])
+    if e.kind == "solve":
+        a, b = e.children
+        return [inverse(a).with_attrs(assume=e.attrs["assume"])] \
+            + collect_chain(b)
+    return [e]
+
+
+def join(el: MatExpr, er: MatExpr) -> MatExpr:
+    """The node for the product el·er of two chain intervals. A bare
+    ``inverse`` factor is never materialised next to a product:
+    A⁻¹·R is ``solve(A, R)`` and L·A⁻¹ is ``solve(Aᵀ, Lᵀ)ᵀ`` (R7's
+    forms, built where the association is decided)."""
+    if el.kind == "inverse":
+        return solve(el.children[0], er,
+                     assume=el.attrs.get("assume", "general"))
+    if er.kind == "inverse":
+        return transpose(solve(transpose(er.children[0]), transpose(el),
+                               assume=er.attrs.get("assume", "general")))
+    return matmul(el, er)
+
+
+def _solve_step_cost(el: MatExpr, er: MatExpr) -> Optional[float]:
+    """FLOPs of :func:`join` where one side is a bare inverse factor
+    (None where neither is): one factorisation of the k x k side and
+    the substitutions against the other side's width. What decides the
+    association of (XᵀX)⁻¹·Xᵀ·y is the second term: 2·k²·N against
+    the N-wide Xᵀ, 2·k² against Xᵀy. Two inverses side by side leave
+    one of them materialised (a solve against the identity)."""
+    if el.kind != "inverse" and er.kind != "inverse":
+        return None
+    k = el.shape[1]
+    if el.kind == "inverse" and er.kind == "inverse":
+        return 2.0 * stats.solve_cost(k, k)
+    return stats.solve_cost(k, er.shape[1] if el.kind == "inverse"
+                            else el.shape[0])
 
 
 def _operand_layouts(operands: List[MatExpr], mesh,
@@ -114,8 +152,11 @@ def optimal_order(operands: List[MatExpr],
             min_samples=getattr(config, "coeff_min_samples", 1)) or None
         if coeff_cw is not None:
             shape_cls = drift_lib.shape_class
+    # an inverse factor is priced as the solve it becomes (the native
+    # mirror knows products only: the same degrade to the reference)
+    has_inverse = any(op.kind == "inverse" for op in operands)
     if (n >= 3 and flop_scale == 1.0 and reshard_budget == 0
-            and coeff_cw is None):
+            and coeff_cw is None and not has_inverse):
         from matrel_tpu.utils import native
         dims = [op.shape[0] for op in operands] + [operands[-1].shape[1]]
         dens = [op.density for op in operands]
@@ -145,18 +186,24 @@ def optimal_order(operands: List[MatExpr],
             for s in range(i, j):
                 cl, el, ll = best[i][s]
                 cr, er, lr = best[s + 1][j]
-                cw = (coeff_cw.get(shape_cls(
-                    (el.shape[0], el.shape[1], er.shape[1])))
-                    if coeff_cw is not None else None)
-                step, lay = stats.chain_step_cost_layout(
-                    el.shape[0], el.shape[1], er.shape[1],
-                    el.density, er.density, gx, gy, ll, lr,
-                    weights=weights, flop_scale=flop_scale,
-                    comm_weight=cw,
-                )
+                step = _solve_step_cost(el, er)
+                if step is not None:
+                    # a local solve on the logical shapes: no
+                    # collective bill, the canonical layout out
+                    step, lay = step * flop_scale, "2d"
+                else:
+                    cw = (coeff_cw.get(shape_cls(
+                        (el.shape[0], el.shape[1], er.shape[1])))
+                        if coeff_cw is not None else None)
+                    step, lay = stats.chain_step_cost_layout(
+                        el.shape[0], el.shape[1], er.shape[1],
+                        el.density, er.density, gx, gy, ll, lr,
+                        weights=weights, flop_scale=flop_scale,
+                        comm_weight=cw,
+                    )
                 total = cl + cr + step
                 if cand is None or total < cand[0]:
-                    cand = (total, matmul(el, er), lay)
+                    cand = (total, join(el, er), lay)
             best[i][j] = cand
     cost, e, _ = best[0][n - 1]
     return e, cost
@@ -164,27 +211,31 @@ def optimal_order(operands: List[MatExpr],
 
 def reorder_chains(e: MatExpr,
                    grid: Tuple[int, int] = (1, 1),
-                   mesh=None, config=None) -> MatExpr:
-    """Recursively find maximal matmul chains and DP-reorder each.
+                   mesh=None, config=None,
+                   counts: Optional[dict] = None) -> MatExpr:
+    """Recursively find maximal product chains and DP-reorder each.
     ``grid`` is the mesh grid shape feeding the comm-aware step cost;
     ``mesh`` additionally makes the step cost layout-aware (the DP sees
     which operands are replicated/1D-sharded on it), under the session
-    ``config`` the planner will also use."""
-    if e.kind == "matmul":
+    ``config`` the planner will also use. ``counts`` (optional) gains
+    ``chain_solve``: the chains with an inverse (or a solve) among
+    their factors that were associated by the solve's cost."""
+    if e.kind in ("matmul", "solve"):
         ops = collect_chain(e)
         # optimize below each chain operand first, then the chain itself
-        ops = [reorder_chains(o, grid, mesh, config)
+        ops = [reorder_chains(o, grid, mesh, config, counts)
                if o.kind != "leaf" else o for o in ops]
-        if len(ops) > 2:
-            new, _ = optimal_order(ops, grid, mesh, config)
-            return new
-        if len(ops) == 2:
+        if any(o.kind == "inverse" for o in ops):
+            if counts is not None:
+                counts["chain_solve"] = counts.get("chain_solve", 0) + 1
+        elif len(ops) == 2:
             return matmul(ops[0], ops[1])
-        return ops[0]
+        new, _ = optimal_order(ops, grid, mesh, config)
+        return new
     if not e.children:
         return e
     new_children = tuple(
-        reorder_chains(c, grid, mesh, config) for c in e.children
+        reorder_chains(c, grid, mesh, config, counts) for c in e.children
     )
     if all(nc is oc for nc, oc in zip(new_children, e.children)):
         return e
@@ -201,6 +252,8 @@ def chain_cost(e: MatExpr, grid: Tuple[int, int] = (1, 1)) -> float:
             l.shape[0], l.shape[1], r.shape[1], l.density, r.density,
             grid[0], grid[1],
         )
+    elif e.kind == "solve":
+        total += stats.solve_cost(e.shape[0], e.shape[1])
     for c in e.children:
         total += chain_cost(c, grid)
     return total

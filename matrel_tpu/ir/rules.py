@@ -19,7 +19,10 @@ Rules, mirroring the reference's Catalyst batch:
      rules above.
   R7 solve fusion: A⁻¹·B → solve(A,B) ; A·B⁻¹ → solve(Bᵀ,Aᵀ)ᵀ ;
      (A⁻¹)⁻¹ → A — the normal-equations pattern (XᵀX)⁻¹·Xᵀy never
-     materialises an inverse.
+     materialises an inverse. Inside a product chain the fusion is
+     R6's: the DP prices an inverse factor as the solve it becomes and
+     brackets it against the narrowest side, so (XᵀX)⁻¹·Xᵀ·y solves
+     against Xᵀy (k×1) however it was typed, never against Xᵀ (k×N).
   R8 rank-1 multiply push-through: (A + u·vᵀ)·B → A·B + u·(vᵀ·B) and
      B·(A + u·vᵀ) → B·A + (B·u)·vᵀ — the outer product is never
      materialised inside a multiply chain (MatFast's rank-1 family).
@@ -35,8 +38,7 @@ from typing import Callable, List, Optional
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.ir import chain as chain_lib
 from matrel_tpu.ir.expr import (
-    MatExpr, agg, elemwise, matmul, scalar_op, select_index, solve,
-    transpose,
+    MatExpr, agg, elemwise, matmul, scalar_op, select_index, transpose,
 )
 
 Rule = Callable[[MatExpr], Optional[MatExpr]]
@@ -219,22 +221,25 @@ def rank1_pushdown(e: MatExpr) -> Optional[MatExpr]:
 # -- R7: solve fusion --------------------------------------------------------
 
 
+def inverse_cancel(e: MatExpr) -> Optional[MatExpr]:
+    """(A⁻¹)⁻¹ → A."""
+    if e.kind == "inverse" and e.children[0].kind == "inverse":
+        return e.children[0].children[0]
+    return None
+
+
 def solve_fusion(e: MatExpr) -> Optional[MatExpr]:
-    """A⁻¹·B → solve(A, B); A·B⁻¹ → solve(Bᵀ, Aᵀ)ᵀ; (A⁻¹)⁻¹ → A.
+    """A⁻¹·B → solve(A, B); A·B⁻¹ → solve(Bᵀ, Aᵀ)ᵀ.
 
     The reference's normal-equations workload writes (XᵀX)⁻¹·(Xᵀy); an
     explicit inverse materialises n² solve results to use n·m of them
     and is less numerically stable than LU-solving against B directly.
+    The forms are chain.join's: with the chain DP on, it is the DP that
+    decides WHICH product an inverse factor is fused with, and this
+    rule runs after it, on what no chain held.
     """
-    if e.kind == "inverse" and e.children[0].kind == "inverse":
-        return e.children[0].children[0]
-    if e.kind != "matmul":
-        return None
-    a, b = e.children
-    if a.kind == "inverse":
-        return solve(a.children[0], b)
-    if b.kind == "inverse":
-        return transpose(solve(transpose(b.children[0]), transpose(a)))
+    if e.kind == "matmul" and any(c.kind == "inverse" for c in e.children):
+        return chain_lib.join(*e.children)
     return None
 
 
@@ -243,20 +248,27 @@ _RULES: List[Rule] = [
     agg_pushdown,
     scalar_folding,
     selection_pushdown,
+    inverse_cancel,
     solve_fusion,
     rank1_pushdown,
 ]
+# ahead of the chain DP an inverse stays a factor of its chain: fused
+# with its left-associated neighbour first, (XᵀX)⁻¹·Xᵀ·y would reach
+# the DP as the two-factor product solve(XᵀX, Xᵀ)·y
+_RULES_AHEAD_OF_CHAIN_DP: List[Rule] = [r for r in _RULES
+                                        if r is not solve_fusion]
 
 _MAX_ITERS = 10
 
 
 def apply_rewrites(e: MatExpr,
-                   counts: Optional[dict] = None) -> MatExpr:
+                   counts: Optional[dict] = None,
+                   rule_batch: Optional[List[Rule]] = None) -> MatExpr:
     """Run the rule batch to fixpoint (bounded, Catalyst-style).
     ``counts`` (optional) accumulates per-rule hit counts."""
     for _ in range(_MAX_ITERS):
         before = e
-        for rule in _RULES:
+        for rule in rule_batch or _RULES:
             e = _rewrite_bottom_up(e, rule, counts)
         if _same_structure(e, before):
             break
@@ -324,13 +336,15 @@ def optimize(e: MatExpr, config: Optional[MatrelConfig] = None,
     reorder); (1, 1) keeps the pure-FLOPs DP. ``mesh`` makes the bill
     layout-aware (round 5): operand PartitionSpecs steer the reorder.
     ``counts`` (optional) accumulates per-rule hit counts plus a
-    ``chain_dp`` entry when the reorder restructured a chain — the
-    rewrite-metrics feed of the obs/ event log."""
+    ``chain_dp`` entry when the reorder restructured a chain and a
+    ``chain_solve`` entry for each chain whose inverse factor the DP
+    bracketed — the rewrite-metrics feed of the obs/ event log."""
     cfg = config or default_config()
     if cfg.rewrite_rules:
-        e = apply_rewrites(e, counts)
+        e = apply_rewrites(e, counts, _RULES_AHEAD_OF_CHAIN_DP
+                           if cfg.chain_opt else None)
     if cfg.chain_opt:
-        reordered = chain_lib.reorder_chains(e, grid, mesh, cfg)
+        reordered = chain_lib.reorder_chains(e, grid, mesh, cfg, counts)
         # structural comparison, not identity: reorder_chains rebuilds
         # matmul nodes even when it keeps the original parenthesisation
         if counts is not None and reordered is not e \

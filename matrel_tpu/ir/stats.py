@@ -60,6 +60,15 @@ def matmul_cost(
     return 2.0 * n * k * m * da * db
 
 
+def solve_cost(k: int, m: int) -> float:
+    """Estimated FLOP cost of solving a (k×k) system against m
+    right-hand sides: one LU factorisation, 2k³/3, and the forward and
+    backward substitutions, 2k²·m. The chain DP's step for an inverse
+    factor (ir/chain.py): the width m the solve is taken against is
+    what the association decides."""
+    return 2.0 * k ** 3 / 3.0 + 2.0 * float(k) * k * m
+
+
 HBM_FLOPS_PER_BYTE = 120.0
 """Blend factor converting HBM bytes into f32-FLOP-equivalents for the
 precision-tier cost model (planner.tier_matmul_cost): a v5e chip

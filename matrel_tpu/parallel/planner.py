@@ -1037,8 +1037,17 @@ def device_bytes(node: MatExpr, mesh: Mesh,
         data = getattr(node.attrs.get("matrix"), "data", None)
         sharding = getattr(data, "sharding", None)
         if sharding is not None:
-            shard = sharding.shard_shape(data.shape)
-            return float(np.prod(shard)) * data.dtype.itemsize
+            # what the buffers really take, where the array can say: it
+            # depends on how a table lies in the chip's (8, 128) tiles
+            # (1000 columns on the lanes are 1024; an N x 1 column laid
+            # row-major is 128 lanes a row, 1.3 GB for 10 MB of data)
+            try:
+                return (float(data.on_device_size_in_bytes())
+                        / max(len(sharding.device_set), 1))
+            except (AttributeError, RuntimeError):
+                # a described shape (no buffer), a deleted array
+                shard = sharding.shard_shape(data.shape)
+                return float(np.prod(shard)) * data.dtype.itemsize
     elif node.kind == "sparse_leaf":
         return float(node.attrs["matrix"].blocks.nbytes)
     elif node.kind == "coo_leaf":
@@ -1071,6 +1080,19 @@ def plan_resident_bytes(root: MatExpr, mesh: Mesh,
 
     walk(root)
     return total
+
+
+class PlanMemoryError(ValueError):
+    """A one-device plan whose reckoned peak is over the device's limit
+    (:func:`refuse_over_limit`): raised before anything is traced."""
+
+
+def solve_transient_bytes(k: int, m: int) -> float:
+    """What a ``solve`` allocates beside its operands and its output:
+    the float32 copy of the k x k left side that the factorisation
+    overwrites, and the float32 right-hand side the substitutions
+    run in (executor._solve computes in float32 on logical shapes)."""
+    return 4.0 * k * k + 4.0 * k * m
 
 
 def plan_hbm_bytes(strategy: str, pn: int, pk: int, pm: int,
@@ -1233,7 +1255,8 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
     from matrel_tpu.core import padding
     pn, pk = padding.padded_shape((n, k), mesh)
     _, pm = padding.padded_shape((k, m), mesh)
-    if gx * gy == 1 and cfg.strategy_override == "auto":
+    if (gx * gy == 1 and cfg.strategy_override == "auto"
+            and hbm_detail is None):
         return "xla", "default"  # single device: plain local dot
     # the feasibility gate reckons the PLAN's peak on a device, not
     # one product's own working set: what is alive beside the product
@@ -1257,20 +1280,27 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
     tier = node.attrs.get("precision_tier")
     if tier in TIER_ITEMSIZE:
         isz = TIER_ITEMSIZE[tier]
-    if cfg.strategy_override != "auto":
-        forced = cfg.strategy_override
-        if hbm_detail is not None and gx * gy > 1:
+    if cfg.strategy_override != "auto" or gx * gy == 1:
+        # one device: the plain local dot, whatever is forced on it
+        # (every strategy's shard_map is that dot on a 1x1 grid): no
+        # choice to make, but the plan's peak at this product is
+        # reckoned all the same
+        forced = (cfg.strategy_override
+                  if cfg.strategy_override != "auto" else "xla")
+        if hbm_detail is not None:
             # a forced strategy is reckoned like a chosen one (a forced
             # rmm still derives its panels from the budget); the gate
             # can only say that it does not fit
-            need, panels, ok = _hbm_gate([forced], pn, pk, pm, gx, gy, isz,
-                                         alive_bytes, limit)[forced]
+            gated = forced if gx * gy > 1 else "xla"
+            need, panels, ok = _hbm_gate([gated], pn, pk, pm, gx, gy, isz,
+                                         alive_bytes, limit)[gated]
             hbm_detail.update(chosen=forced, panels=panels,
                               moves_under_dot=rmm_moves_under_dot(
                                   pk, gy, panels),
                               hbm_plan_bytes=int(need),
                               refused_hbm=[] if ok else [forced])
-        return forced, "override"
+        return ((forced, "override") if cfg.strategy_override != "auto"
+                else ("xla", "default"))
     la = infer_layout(a, mesh, layout_memo, cfg)
     lb = infer_layout(b, mesh, layout_memo, cfg)
     if cfg.autotune:
@@ -1677,6 +1707,17 @@ def _child_layout_hints(e: MatExpr, mesh: Optional[Mesh] = None,
     return (None,) * len(e.children)
 
 
+def _folded_transpose(node: MatExpr, parent: Optional[MatExpr],
+                      mesh: Mesh) -> bool:
+    """A transpose that is no array: on one device the product that
+    reads it contracts over the other dimension (``x.T`` under a
+    ``dot`` is the dot's dimension numbers; compiled for a v5e, t(X)·X
+    of a 10 GB table has 5 MB of temporaries). On a mesh it is a
+    re-lay of the shards and stays counted."""
+    return (node.kind == "transpose" and mesh.size == 1
+            and parent is not None and parent.kind == "matmul")
+
+
 def annotate_strategies(e: MatExpr, mesh: Mesh,
                         config: Optional[MatrelConfig] = None,
                         _dtype_memo: Optional[dict] = None,
@@ -1685,7 +1726,8 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
                         _root_scale: float = 1.0,
                         _root_swap: bool = False,
                         _integral_memo: Optional[dict] = None,
-                        _held: Optional[float] = None) -> MatExpr:
+                        _held: Optional[float] = None,
+                        _parent: Optional[MatExpr] = None) -> MatExpr:
     """Bottom-up pass stamping attrs['strategy'] on every matmul node
     and attrs['replicate'] on every row/col index join. One dtype memo
     and one layout memo are threaded through the whole pass and seeded
@@ -1702,13 +1744,17 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
     subtree makes itself — the leaves the whole plan reads (reckoned
     once, at the root) and the values of elder siblings waiting for
     their parent. A matmul's candidates are gated on it plus its own
-    computed operands (choose_strategy_ex ``alive_bytes``). On one
-    device no strategy is chosen and nothing is reckoned."""
+    computed operands (choose_strategy_ex ``alive_bytes``); a ``solve``
+    and a ``transpose`` that is materialised are stamped with the peak
+    at them too (``hbm_plan_bytes``, and ``refused_hbm`` where it is
+    over the limit). On one device no strategy is chosen, but the plan
+    is reckoned all the same: there a transpose that a product reads is
+    the dot's own dimension numbers and no array, and is not
+    counted."""
     memo = {} if _dtype_memo is None else _dtype_memo
     lmemo = {} if _layout_memo is None else _layout_memo
     imemo = {} if _integral_memo is None else _integral_memo
-    reckon = mesh.size > 1
-    is_root = reckon and _held is None
+    is_root = _held is None
     if is_root:
         # the root's value is the program's output: its buffer is
         # handed over before anything runs, so it is alive beside
@@ -1724,8 +1770,9 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
     for i, (c, h) in enumerate(zip(e.children, hints)):
         nc = annotate_strategies(c, mesh, config, memo, lmemo, h,
                                  _child_root_scale(e, i, _root_scale),
-                                 swap, imemo, alive)
-        if reckon and nc.children:      # a computed value, kept for e
+                                 swap, imemo, alive, e)
+        if nc.children and not _folded_transpose(nc, e, mesh):
+            # a computed value, kept for e
             alive += device_bytes(nc, mesh, config, memo, lmemo)
         new_children.append(nc)
     new_children = tuple(new_children)
@@ -1749,7 +1796,7 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
         # contract)
         detail = ({} if config is not None
                   and config.coeff_planner_enable else None)
-        hbm = {} if reckon else None
+        hbm = {}
         # the root's own value was counted into ``_held`` for the
         # products below it; at the root it is the product's output
         own_alive = (alive - device_bytes(e, mesh, config, memo, lmemo)
@@ -1796,6 +1843,20 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
             e = e.with_attrs(spgemm_kernel=kid,
                              spgemm_structure=struct,
                              spgemm_kernel_source=ksrc)
+    if (e.kind in ("solve", "transpose") and "hbm_plan_bytes" not in e.attrs
+            and not _folded_transpose(e, _parent, mesh)):
+        # no strategy to choose, but an array the device has to hold:
+        # what is alive at it (its computed operands among them), its
+        # own value (the root's was counted into ``_held``), and what
+        # a solve's factorisation allocates beside them
+        need = alive + (0.0 if is_root
+                        else device_bytes(e, mesh, config, memo, lmemo))
+        if e.kind == "solve":
+            need += solve_transient_bytes(*e.shape)
+        limit = mesh_lib.hbm_limit_bytes(mesh, config)
+        e = e.with_attrs(hbm_plan_bytes=int(need),
+                         **({"refused_hbm": (e.kind,)}
+                            if 0 < limit < need else {}))
     if e.kind in ("join_rows", "join_cols") and "replicate" not in e.attrs:
         e = e.with_attrs(replicate=choose_join_scheme(
             e, mesh, config, layout_memo=lmemo,
@@ -1807,12 +1868,13 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
 
 def hbm_report(root: MatExpr) -> list:
     """What the plan-level memory reckoning decided, read back from an
-    ANNOTATED plan: one record a dense matmul, in evaluation order —
-    ``chosen``, ``refused_hbm``, ``panels`` (rows, columns),
+    ANNOTATED plan: one record a dense matmul, a solve and a
+    materialised transpose, in evaluation order — ``node`` (its kind),
+    ``shape``, ``chosen`` (the strategy; the kind where there is none
+    to choose), ``refused_hbm``, ``panels`` (rows, columns),
     ``moves_under_dot`` (strategies.rmm_moves_under_dot: the moves a
     row panel of the panelled rmm hides under a dot),
-    ``hbm_plan_bytes``. Empty on one device, where nothing is
-    reckoned."""
+    ``hbm_plan_bytes`` (the plan's peak on one device at that node)."""
     out, seen = [], set()
 
     def walk(n: MatExpr):
@@ -1821,8 +1883,9 @@ def hbm_report(root: MatExpr) -> list:
         seen.add(n.uid)
         for c in n.children:
             walk(c)
-        if n.kind == "matmul" and "hbm_plan_bytes" in n.attrs:
-            out.append({"chosen": n.attrs.get("strategy"),
+        if "hbm_plan_bytes" in n.attrs:
+            out.append({"node": n.kind, "shape": list(n.shape),
+                        "chosen": n.attrs.get("strategy", n.kind),
                         "refused_hbm": list(n.attrs.get("refused_hbm", ())),
                         "panels": list(n.attrs.get("panels", (1, 1))),
                         "moves_under_dot": n.attrs.get("moves_under_dot", 0),
@@ -1830,6 +1893,49 @@ def hbm_report(root: MatExpr) -> list:
 
     walk(root)
     return out
+
+
+def refuse_over_limit(roots, mesh: Mesh,
+                      config: Optional[MatrelConfig] = None) -> None:
+    """Raise :class:`PlanMemoryError` for a ONE-DEVICE plan that holds
+    a node stamped over the limit, naming the first such node in
+    evaluation order. On a mesh a refused strategy leaves others to
+    choose from, and where none fits the one that needs least is handed
+    over (``refused_hbm`` says so); on one device there is one way to
+    run a node, and what the reckoning adds up are arrays that have to
+    exist — the leaves, the intermediates alive, the node's own value —
+    so a plan over the limit cannot run, and says so here instead of in
+    the compiler or the allocator."""
+    if mesh.size != 1:
+        return
+    seen = set()
+
+    def first_over(n: MatExpr) -> Optional[MatExpr]:
+        if n.uid in seen:
+            return None
+        seen.add(n.uid)
+        for c in n.children:
+            found = first_over(c)
+            if found is not None:
+                return found
+        return n if n.attrs.get("refused_hbm") else None
+
+    for root in roots:
+        n = first_over(root)
+        if n is None:
+            continue
+        own = int(device_bytes(n, mesh, config))
+        limit = mesh_lib.hbm_limit_bytes(mesh, config)
+        raise PlanMemoryError(
+            f"plan refused before tracing: at {n.kind} "
+            f"{n.shape[0]}x{n.shape[1]} ({own:,} bytes of its own) the "
+            f"plan holds {n.attrs['hbm_plan_bytes']:,} bytes on the one "
+            f"device (the tables it reads, the intermediates alive, this "
+            f"node's value), over the limit of {limit:,} bytes "
+            f"(min of hbm_budget_bytes and the device's bytes_limit). "
+            f"Write the query so that no intermediate is that wide, or "
+            f"raise hbm_budget_bytes if the device really has the room "
+            f"(0 turns the reckoning off).")
 
 
 def matmul_decisions(root: MatExpr, mesh: Mesh,

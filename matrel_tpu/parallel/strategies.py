@@ -75,6 +75,54 @@ def _local_dot(a, b, prec, out_dtype):
         precision=prec, preferred_element_type=out_dtype)
 
 
+#: Rows of a long float32 contraction that ONE dot accumulates (see
+#: :func:`dot_in_panels`), and from what length on a contraction counts
+#: as long. A float32 sum that one dot accumulates on the MXU drifts
+#: with its length. Read on a v5e against float64 (PR 31; the diagonal
+#: of the Gram of 2,555,904 rows of uniform [-1, 1), mean over its 1000
+#: entries): +1.83e-4 as one dot at ``highest``, worse than one bfloat16
+#: pass (+4.4e-6 at ``default``); -1.19e-5 in panels of 65,536 rows,
+#: -4.3e-7 in panels of 8,192, -3.2e-8 in panels of 2,048, for 163.9,
+#: 184.2, 168.1 and 174.6 ms. A batched dot over reshaped panels is no
+#: cure: the compiler makes one dot of it again (+1.83e-4). Every
+#: contraction of the benchmark's other cells is shorter than the
+#: threshold.
+ACC_PANEL_ROWS = 8192
+LONG_CONTRACTION = 1 << 17
+
+
+def dot_in_panels(a, ca: int, b, cb: int,
+                  config: Optional[MatrelConfig] = None) -> jax.Array:
+    """The float32 product of ``a`` and ``b`` contracted over ``a``'s
+    dimension ``ca`` and ``b``'s ``cb``, the contraction cut into panels
+    of :data:`ACC_PANEL_ROWS`: each panel is one dot with an accumulator
+    of its own, and the panels' products are added on the vector unit
+    (312 additions for 2.5M rows). The operands come as they lie — a
+    transposed one by its dimension, not as ``x.T`` — because a loop
+    takes its operands whole: a transposed 10 GB table in front of the
+    loop is a second table (compiled for a v5e: refused at 20.5 of 15.75
+    GB), while a panel sliced from the table inside the loop is read in
+    place (temporaries: none)."""
+    dims = (((ca,), (cb,)), ((), ()))
+    prec = _precision(config)
+    length = a.shape[ca]
+
+    def panel(start, rows):
+        return jax.lax.dot_general(
+            jax.lax.dynamic_slice_in_dim(a, start, rows, axis=ca),
+            jax.lax.dynamic_slice_in_dim(b, start, rows, axis=cb),
+            dims, precision=prec, preferred_element_type=jnp.float32)
+
+    whole, tail = divmod(length, ACC_PANEL_ROWS)
+    out = jax.lax.fori_loop(
+        0, whole,
+        lambda i, acc: acc + panel(i * ACC_PANEL_ROWS, ACC_PANEL_ROWS),
+        jnp.zeros((a.shape[1 - ca], b.shape[1 - cb]), jnp.float32))
+    if tail:
+        out = out + panel(whole * ACC_PANEL_ROWS, tail)
+    return out
+
+
 def matmul_xla(a: jax.Array, b: jax.Array, mesh: Mesh,
                config: Optional[MatrelConfig] = None) -> jax.Array:
     """Fallback: one einsum, XLA SPMD chooses the collectives.

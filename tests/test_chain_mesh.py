@@ -248,7 +248,10 @@ def test_budget_is_held_to_what_the_device_reports(monkeypatch, mesh_square):
         mesh_square, MatrelConfig(hbm_budget_bytes=0)) == 0
 
 
-def test_one_device_plans_are_not_reckoned(tables):
+def test_one_device_plans_are_reckoned_too(tables):
+    """PR 31: no strategy to choose on one device, but the same
+    reckoning: three tables, the answer, and at the second product the
+    intermediate beside them; no transient."""
     arrays, _ = tables
     mesh = mesh_lib.make_mesh((1, 1), devices=jax.devices()[:1])
     sess = MatrelSession(mesh=mesh)
@@ -257,7 +260,11 @@ def test_one_device_plans_are_not_reckoned(tables):
             jax.device_put(arr, jax.devices()[0]), (N, N), mesh, P()))
     meta = sess.compile(sess.sql("A * B * C")).meta
     assert meta["mesh"] == "1x1"
-    assert "hbm_plan_bytes" not in meta and "products" not in meta
+    table = N * N * 2
+    assert [(p["chosen"], p["panels"], p["refused_hbm"], p["hbm_plan_bytes"])
+            for p in meta["products"]] == [
+        ("xla", [1, 1], [], 5 * table), ("xla", [1, 1], [], 5 * table)]
+    assert meta["hbm_plan_bytes"] == 5 * table
 
 
 # -- what the spans say ------------------------------------------------------

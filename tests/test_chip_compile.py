@@ -302,3 +302,104 @@ def test_chain_temporaries_are_what_the_plan_reckons(chain_program):
         CHAIN_N, CHAIN_N, CHAIN_N, 2, 2, 2, CHAIN_PANELS)
     assert chain_program.memory_analysis().temp_size_in_bytes \
         <= 1.05 * reckoned
+
+
+# -- the regression's plan at the cell's size (cell linreg_10m_1c) ------------
+
+LINREG_N, LINREG_K = 2_555_904, 1000
+
+
+#: How the two tables may lie on the chip, as jax.experimental.layout
+#: names it: the chip's default for these shapes puts the long dimension
+#: on the 128 lanes (what ``device_put`` and a jitted generator both
+#: gave on a v5e, PR 31: X 10,223,616,000 B, y 10,223,616 B, nothing
+#: padded); row-major in (8, 128) tiles, X takes 1024 lanes a row
+#: (10,468,982,784 B) and y 128 (1,308,622,848 B).
+LINREG_LAYOUTS = {"as_the_chip_lays_them": ((1, 0), 1000 * 4 + 4),
+                  "row_major": ((0, 1), 1024 * 4 + 128 * 4)}
+
+
+@pytest.fixture(scope="module", params=sorted(LINREG_LAYOUTS))
+def linreg_program(topo, request):
+    """``inv(t(X) * X) * t(X) * y`` over one chip's quarter of the 10M x
+    1k table as the session plans and lowers it, compiled for ONE
+    described v5e, the tables in either layout."""
+    from jax.experimental.layout import Format, Layout
+    from matrel_tpu.core.blockmatrix import BlockMatrix
+    from matrel_tpu.session import MatrelSession
+    major_to_minor, bytes_a_row = LINREG_LAYOUTS[request.param]
+    mesh = Mesh(np.asarray(topo.devices[:1], dtype=object).reshape(1, 1),
+                ("x", "y"))
+    whole = NamedSharding(mesh, P(None, None))
+    sess = MatrelSession(mesh=mesh)
+    for name, shape in (("X", (LINREG_N, LINREG_K)), ("y", (LINREG_N, 1))):
+        sess.register(name, BlockMatrix.from_array(
+            _sds(whole, shape, jnp.float32), shape, mesh, P(None, None)))
+    plan = sess.compile(sess.sql("inv(t(X) * X) * t(X) * y"))
+    lie = Format(Layout(major_to_minor=major_to_minor), whole)
+    compiled = plan.jitted.lower(*[
+        _sds(lie, leaf.attrs["matrix"].shape, jnp.float32)
+        for leaf in plan.leaf_order]).compile()
+    return plan, compiled, LINREG_N * bytes_a_row
+
+
+#: Operations that hand an array on and write none: the loop over the
+#: contraction's panels carries the table through them.
+_NO_WRITE = {"parameter", "tuple", "get-tuple-element", "while", "bitcast"}
+
+
+def _arrays_written(text, dim):
+    """(instruction, dims) of every array with ``dim`` among its
+    dimensions that an instruction outside a fused computation yields
+    (inside one nothing is written: the fusion's own result is)."""
+    fused = set(re.findall(r"\sfusion\(.*calls=%([\w.\-]+)", text))
+    out, current = [], None
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$",
+                        line)
+        if head:
+            current = head.group(1)
+            continue
+        inst = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+) = (.*?)\s([\w\-]+)\(",
+                        line)
+        if not inst or current in fused or inst.group(3) in _NO_WRITE:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]+)\]", inst.group(2)):
+            dims = [int(d) for d in dims.split(",")]
+            if dim in dims:
+                out.append((inst.group(1), dims))
+    return out
+
+
+def test_linreg_plan_fits_beside_the_table(linreg_program):
+    """With the tables resident (10.2 GB as the chip lays them, 11.8 GB
+    row-major), the program's temporaries are what the plan reckons —
+    the k x k Gram, Xᵀy, the solve's copies: megabytes — and arguments
+    and temporaries fit the planner's budget. Neither layout makes the
+    loops over the contraction's panels copy the table."""
+    plan, compiled, tables = linreg_program
+    mem = compiled.memory_analysis()
+    n, k = LINREG_N, LINREG_K
+    assert mem.argument_size_in_bytes == tables
+    loops = [ln for ln in compiled.as_text().splitlines()
+             if " while(" in ln and f"f32[{n},{k}]" in ln]
+    assert len(loops) == 2, loops       # t(X)·X and t(X)·y, in panels
+    # described tables are shapes, reckoned at their logical bytes
+    residents, answer = n * k * 4 + n * 4, k * 4
+    reckoned = plan.meta["hbm_plan_bytes"] - residents - answer
+    assert reckoned == 2 * (k * k * 4 + k * 4)
+    assert mem.temp_size_in_bytes <= 1.05 * reckoned
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes <= MatrelConfig().hbm_budget_bytes
+
+
+def test_linreg_plan_writes_no_n_shaped_array(linreg_program):
+    """No transposed copy of X, no pad to 1024 columns, no bfloat16
+    split of it, no N-wide solve: outside the two tables' parameters
+    (and the loops over the contraction's panels, which carry them
+    through) no array with 2,555,904 in its shape is written that is
+    larger than y's column re-laid as a vector (10 MB)."""
+    plan, compiled, _ = linreg_program
+    assert plan.meta["rule_hits"]["chain_solve"] == 1
+    written = _arrays_written(compiled.as_text(), LINREG_N)
+    assert all(int(np.prod(dims)) == LINREG_N for _, dims in written), written

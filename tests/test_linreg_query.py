@@ -1,0 +1,319 @@
+"""Normal-equations regression as a query (PR 31), at tier-1 sizes on
+one CPU device: every way of writing (XᵀX)⁻¹Xᵀy through ``session.sql``
+reaches one plan whose solve is taken against Xᵀy (k x 1) and never
+against Xᵀ (k x N); the one-device memory reckoning stamps that plan and
+refuses the N-wide one by name before anything is traced; the spans say
+both; and the chip's share of the table is a share: the row quarters'
+Grams and right-hand sides add up to the whole table's. k = 100 is a
+multiple of neither 8 nor 128."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from matrel_tpu.config import MatrelConfig
+from matrel_tpu.core import mesh as mesh_lib
+from matrel_tpu.core.blockmatrix import BlockMatrix
+from matrel_tpu.ir import chain as chain_lib
+from matrel_tpu.ir import expr as E
+from matrel_tpu.ir import rules, stats
+from matrel_tpu.parallel import planner, strategies
+from matrel_tpu.session import MatrelSession
+
+N, K = 4096, 100
+SPELLINGS = ["inv(t(X) * X) * t(X) * y",
+             "inv(t(X) * X) * (t(X) * y)",
+             "solve(t(X) * X, t(X)) * y",
+             "t(y) * X * inv(t(X) * X)"]
+
+
+@pytest.fixture(scope="module")
+def one_device():
+    return mesh_lib.make_mesh((1, 1), devices=jax.devices()[:1])
+
+
+@pytest.fixture(scope="module")
+def data():
+    """X uniform [-1, 1), y = X theta* + noise, and the float64 normal
+    equations' answer on the float32 values the tables hold."""
+    rng = np.random.default_rng(31)
+    x = rng.uniform(-1.0, 1.0, (N, K)).astype(np.float32)
+    y = (x @ rng.standard_normal((K, 1)).astype(np.float32)
+         + 0.1 * rng.standard_normal((N, 1)).astype(np.float32))
+    x64, y64 = x.astype(np.float64), y.astype(np.float64)
+    return x, y, np.linalg.solve(x64.T @ x64, x64.T @ y64)
+
+
+def session_of(mesh, x, y, **config):
+    sess = MatrelSession(mesh=mesh, config=MatrelConfig(**config))
+    for name, arr in (("X", x), ("y", y)):
+        sess.register(name, BlockMatrix.from_array(
+            jnp.asarray(arr), arr.shape, mesh, P(None, None)))
+    return sess
+
+
+def nodes(e, parent=None, seen=None):
+    """(node, parent) over the plan, each node once."""
+    seen = set() if seen is None else seen
+    if e.uid in seen:
+        return
+    seen.add(e.uid)
+    yield e, parent
+    for c in e.children:
+        yield from nodes(c, e, seen)
+
+
+def shape_of_plan(e):
+    """The plan as nested tuples of kinds and shapes (leaves by name)."""
+    if not e.children:
+        return ("leaf", e.shape)
+    return (e.kind, e.shape) + tuple(shape_of_plan(c) for c in e.children)
+
+
+def rel_err(got, want):
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+# -- the optimizer: one plan, however the formula was typed -------------------
+
+
+@pytest.mark.parametrize("sql", SPELLINGS)
+def test_every_spelling_solves_against_k_by_1(one_device, data, sql):
+    x, y, want = data
+    sess = session_of(one_device, x, y)
+    expr = sess.sql(sql)
+    plan = sess.compile(expr)
+    solves = [n for n, _ in nodes(plan.optimized) if n.kind == "solve"]
+    assert [n.shape for n in solves] == [(K, 1)]
+    assert not [n for n, _ in nodes(plan.optimized) if n.kind == "inverse"]
+    # N appears in a leaf, and in a leaf's transpose that a product
+    # contracts over: in no value the plan computes
+    for n, parent in nodes(plan.optimized):
+        if N in n.shape and n.children:
+            assert n.kind == "transpose" and not n.children[0].children \
+                and parent.kind == "matmul", (n.kind, n.shape)
+    # the rule counts name the rewrite
+    assert plan.meta["rule_hits"]["chain_solve"] == 1
+    got = sess.compute(expr).to_numpy()
+    got = got.T if got.shape == (1, K) else got
+    assert got.shape == (K, 1) and rel_err(got, want) < 2e-5
+
+
+def test_the_spellings_share_one_plan(one_device, data):
+    x, y, _ = data
+    sess = session_of(one_device, x, y)
+    plans = [shape_of_plan(sess.compile(sess.sql(q)).optimized)
+             for q in SPELLINGS]
+    assert plans[0] == plans[1] == plans[2]
+    assert plans[3] == ("transpose", (1, K), plans[0])   # the answer 1 x k
+    assert plans[0] == (
+        "solve", (K, 1),
+        ("matmul", (K, K), ("transpose", (K, N), ("leaf", (N, K))),
+         ("leaf", (N, K))),
+        ("matmul", (K, 1), ("transpose", (K, N), ("leaf", (N, K))),
+         ("leaf", (N, 1))))
+
+
+def test_association_is_by_cost_not_by_pattern(one_device):
+    """Nothing here is a Gram: a general square A and wide or narrow
+    neighbours. The inverse is bracketed with the side that makes the
+    solve narrow, left or right, and two-factor products fuse as R7
+    always did."""
+    def leaf(n, m):
+        return E.leaf(BlockMatrix.from_array(
+            jnp.zeros((n, m), jnp.float32), (n, m), one_device,
+            P(None, None)))
+
+    a, wide, thin, row = leaf(64, 64), leaf(64, 2048), leaf(2048, 1), \
+        leaf(1, 2048)
+    # A⁻¹ · W · t, typed left to right: solve against W·t (64 x 1)
+    counts = {}
+    e = rules.optimize(E.matmul(E.matmul(E.inverse(a), wide), thin),
+                       counts=counts)
+    assert e.kind == "solve" and e.shape == (64, 1)
+    assert e.children[1].kind == "matmul" and counts["chain_solve"] == 1
+    # r · Wᵀ · A⁻¹ with the wide product typed first: (r·Wᵀ) · A⁻¹ =
+    # solve(Aᵀ, (r·Wᵀ)ᵀ)ᵀ, the solve 64 x 1 again
+    e = rules.optimize(E.matmul(row, E.matmul(E.transpose(wide),
+                                              E.inverse(a))))
+    assert e.kind == "transpose" and e.children[0].kind == "solve"
+    assert e.children[0].shape == (64, 1)
+    # a wide side that cannot be made narrow is solved against as it is
+    e = rules.optimize(E.matmul(E.inverse(a), wide))
+    assert e.kind == "solve" and e.shape == (64, 2048)
+    # the cheaper association costs less by the DP's own reckoning
+    good = E.solve(a, E.matmul(wide, thin))
+    bad = E.matmul(E.solve(a, wide), thin)
+    assert chain_lib.chain_cost(good) < chain_lib.chain_cost(bad)
+    assert stats.solve_cost(64, 1) < stats.solve_cost(64, 2048)
+
+
+def test_the_rewrite_off_keeps_what_was_typed(one_device, data):
+    x, y, want = data
+    sess = session_of(one_device, x, y, chain_opt=False)
+    plan = sess.compile(sess.sql("solve(t(X) * X, t(X)) * y"))
+    assert [n.shape for n, _ in nodes(plan.optimized)
+            if n.kind == "solve"] == [(K, N)]
+    assert "chain_solve" not in plan.meta["rule_hits"]
+    got = sess.compute(sess.sql("solve(t(X) * X, t(X)) * y")).to_numpy()
+    assert rel_err(got, want) < 2e-5
+
+
+# -- the planner: the plan's peak on ONE device -------------------------------
+
+
+def test_the_right_plan_is_stamped(one_device, data):
+    x, y, _ = data
+    sess = session_of(one_device, x, y)
+    meta = sess.compile(sess.sql(SPELLINGS[0])).meta
+    tables = x.nbytes + y.nbytes
+    gram, rhs, theta = K * K * 4, K * 4, K * 4
+    assert [(p["node"], p["shape"], p["chosen"], p["refused_hbm"])
+            for p in meta["products"]] == [
+        ("matmul", [K, K], "xla", []), ("matmul", [K, 1], "xla", []),
+        ("solve", [K, 1], "solve", [])]
+    # residents and the answer from the start; the Gram; Xᵀy beside it;
+    # at the solve both, and its factorisation's copies
+    assert [p["hbm_plan_bytes"] for p in meta["products"]] == [
+        tables + theta + gram, tables + theta + gram + rhs,
+        tables + theta + gram + rhs
+        + int(planner.solve_transient_bytes(K, 1))]
+    assert meta["hbm_plan_bytes"] == meta["products"][-1]["hbm_plan_bytes"]
+    assert meta["mesh"] == "1x1"
+
+
+def test_an_n_wide_plan_is_refused_by_name(one_device, data):
+    x, y, _ = data
+    budget = 2 * x.nbytes     # the table and one more of its size: not two
+    wide = session_of(one_device, x, y, chain_opt=False,
+                      hbm_budget_bytes=budget)
+    with pytest.raises(planner.PlanMemoryError) as refused:
+        wide.compile(wide.sql("solve(t(X) * X, t(X)) * y"))
+    said = str(refused.value)
+    # the first array that does not fit: Xᵀ as the solve's right side
+    assert f"transpose {K}x{N}" in said and f"{x.nbytes:,} bytes" in said
+    assert f"{budget:,} bytes" in said and "before tracing" in said
+    # the same text under the same budget with the rewrite on: it fits
+    sess = session_of(one_device, x, y, hbm_budget_bytes=budget)
+    meta = sess.compile(sess.sql("solve(t(X) * X, t(X)) * y")).meta
+    assert meta["hbm_plan_bytes"] <= budget
+    # and with the reckoning off the wide plan is handed over
+    off = session_of(one_device, x, y, chain_opt=False, hbm_budget_bytes=0)
+    assert "products" in off.compile(
+        off.sql("solve(t(X) * X, t(X)) * y")).meta
+
+
+def test_spans_say_rules_and_reckoning(one_device, data, tmp_path):
+    """Under a profiler session ``matrel.plan.optimize`` carries the
+    plan's rule hits, one ``matrel.plan.strategy`` span stands for every
+    reckoned node with ``hbm_plan_bytes``, and ``matrel.dispatch``
+    carries the plan's."""
+    from matrel_tpu.obs.trace import profile_spans
+    x, y, _ = data
+    sess = session_of(one_device, x, y)
+    before = len(profile_spans())
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        for _ in range(2):
+            sess.compute(sess.sql(SPELLINGS[0])).to_numpy()
+    finally:
+        jax.profiler.stop_trace()
+    mine = profile_spans()[before:]
+    meta = sess.compile(sess.sql(SPELLINGS[0])).meta
+    (optimize,) = [r for r in mine if r["name"] == "matrel.plan.optimize"]
+    assert optimize["attrs"]["chain_solve"] == 1
+    assert optimize["attrs"]["chain_dp"] == 1
+    strategy = [r["attrs"] for r in mine
+                if r["name"] == "matrel.plan.strategy"]
+    assert [(p["node"], p["hbm_plan_bytes"]) for p in strategy] \
+        == [(p["node"], p["hbm_plan_bytes"]) for p in meta["products"]]
+    dispatches = [r for r in mine if r["name"] == "matrel.dispatch"]
+    assert len(dispatches) == 2 and all(
+        r["attrs"]["hbm_plan_bytes"] == meta["hbm_plan_bytes"]
+        and r["attrs"]["mesh"] == "1x1" for r in dispatches)
+    assert len([r for r in mine if r["name"] == "matrel.compile"]) == 1
+
+
+# -- the executor: a long float32 contraction is accumulated in panels --------
+
+
+@pytest.mark.parametrize("ca, cb", [(0, 0), (1, 0), (0, 1), (1, 1)])
+def test_dot_in_panels_is_the_product(ca, cb):
+    """Two whole panels and a tail, every way the operands may lie."""
+    rng = np.random.default_rng(ca * 2 + cb)
+    length = 2 * strategies.ACC_PANEL_ROWS + 77
+    a = rng.uniform(-1, 1, (length, 5) if ca == 0 else (5, length))
+    b = rng.uniform(-1, 1, (length, 3) if cb == 0 else (3, length))
+    want = (a.T if ca == 0 else a) @ (b if cb == 0 else b.T)
+    got = jax.jit(lambda u, v: strategies.dot_in_panels(u, ca, v, cb))(
+        jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32))
+    assert got.dtype == jnp.float32 and rel_err(np.asarray(got), want) < 1e-6
+
+
+def test_only_long_float32_contractions_are_panelled(one_device):
+    """From LONG_CONTRACTION rows on, ``t(X) * X`` and ``t(X) * y`` of
+    float32 tables lower to a loop over panels (the product unchanged);
+    a shorter table, and a bfloat16 one, to the one dot they always
+    were."""
+    rng = np.random.default_rng(5)
+
+    def lowered(n, dtype, sql):
+        x = rng.uniform(-1, 1, (n, 6)).astype(np.float32)
+        y = rng.uniform(-1, 1, (n, 1)).astype(np.float32)
+        sess = MatrelSession(mesh=one_device)
+        for name, arr in (("X", x), ("y", y)):
+            sess.register(name, BlockMatrix.from_array(
+                jnp.asarray(arr, dtype), arr.shape, one_device,
+                P(None, None)))
+        plan = sess.compile(sess.sql(sql))
+        text = plan.jitted.lower(
+            *[leaf.attrs["matrix"].data for leaf in plan.leaf_order]
+        ).as_text()
+        x, y = (np.asarray(jnp.asarray(v, dtype).astype(jnp.float32),
+                           np.float64) for v in (x, y))
+        want = x.T @ (x if sql == "t(X) * X" else y)
+        got = np.asarray(sess.compute(sess.sql(sql)).data
+                         .astype(jnp.float32), np.float64)
+        return "while" in text, rel_err(got, want)
+
+    long = strategies.LONG_CONTRACTION + 40
+    for sql in ("t(X) * X", "t(X) * y"):
+        looped, err = lowered(long, jnp.float32, sql)
+        assert looped and err < 1e-5
+        looped, err = lowered(long - 80, jnp.float32, sql)
+        assert not looped and err < 1e-5
+    looped, err = lowered(long, jnp.bfloat16, "t(X) * X")
+    assert not looped and err < 1e-2
+
+
+# -- the chip's share of the deployment is a share ----------------------------
+
+
+def test_row_quarters_add_up_to_the_whole_table(one_device, data):
+    """model-configs section 4: what four chips would each compute of
+    the row-sharded table — ``t(Xi) * Xi`` and ``t(Xi) * yi`` through
+    the session, as the cell's chip does for its quarter — adds up to
+    the whole table's Gram and right-hand side, and the whole's theta is
+    the reference's."""
+    x, y, want = data
+    whole = session_of(one_device, x, y)
+    gram = whole.compute(whole.sql("t(X) * X")).to_numpy()
+    rhs = whole.compute(whole.sql("t(X) * y")).to_numpy()
+    parts_g = np.zeros((K, K), np.float64)
+    parts_r = np.zeros((K, 1), np.float64)
+    for i in range(4):
+        rows = slice(i * N // 4, (i + 1) * N // 4)
+        quarter = session_of(one_device, x[rows], y[rows])
+        parts_g += quarter.compute(quarter.sql("t(X) * X")).to_numpy()
+        parts_r += quarter.compute(quarter.sql("t(X) * y")).to_numpy()
+    assert rel_err(parts_g, gram) < 1e-5 and rel_err(parts_r, rhs) < 1e-5
+    x64, y64 = x.astype(np.float64), y.astype(np.float64)
+    assert rel_err(parts_g, x64.T @ x64) < 1e-5
+    assert rel_err(parts_r, x64.T @ y64) < 1e-5
+    assert rel_err(np.linalg.solve(parts_g, parts_r), want) < 1e-5
+    assert rel_err(whole.compute(whole.sql(SPELLINGS[0])).to_numpy(),
+                   want) < 2e-5
