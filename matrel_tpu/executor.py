@@ -525,16 +525,6 @@ class Lowerer:
         out = sm(*tables, x, *ov)
         return out if wide else out[:, None]
 
-    @staticmethod
-    def _same_operand(u: MatExpr, v: MatExpr) -> bool:
-        """Do two expression nodes denote the SAME evaluated operand?
-        True for a shared DAG node, or for distinct leaf wrappers of
-        one matrix object (the DSL creates a fresh leaf per .expr())."""
-        if u is v or u.uid == v.uid:
-            return True
-        return (u.kind == "leaf" and v.kind == "leaf"
-                and u.attrs["matrix"] is v.attrs["matrix"])
-
     def _as_block_sparse(self, leaf_node: MatExpr, bs: int):
         """The BlockSparseMatrix form of an S×S matmul operand:
         sparse_leaf carries one already; coo_leaf is BUCKETED into
@@ -655,16 +645,11 @@ class Lowerer:
             out = spmm_lib.apply(st, at, (l.shape[1], l.shape[0]),
                                  self.config, ran=self._ran)
             return fin(out.T)
-        gram = None
-        if l.kind == "transpose" and self._same_operand(l.children[0], r):
-            gram = ("AtA", r)
-        elif r.kind == "transpose" and self._same_operand(r.children[0], l):
-            gram = ("AAt", l)
         # a stamped precision tier OWNS the matmul's numerics — the
         # config-level matmul_precision="high" gram shortcut must not
-        # second-guess it (the tier path below emits its own passes)
-        if node.attrs.get("precision_tier") is not None:
-            gram = None
+        # second-guess it (the tier path below emits its own passes):
+        # gram_operand finds no Gram there
+        gram = planner.gram_operand(node)
         if gram is not None and self.config.matmul_precision == "high":
             side, base = gram
             x = ev(base)
@@ -673,9 +658,10 @@ class Lowerer:
                 # precision="high": of XLA's three bf16x3 products
                 # (hi·hi, hi·lo, lo·hi) the cross terms are transposes
                 # of each other in a Gram, so one MXU pass is a k×k
-                # transpose instead — 33% fewer matmul FLOPs at
-                # identical accuracy (same three products; round-3
-                # floor analysis, docs/ROUND3.md). XLA's generic dot
+                # transpose instead — 33% fewer matmul FLOPs for the
+                # same three products (round-3 floor analysis,
+                # docs/ROUND3.md; what a v5e really computes there is
+                # in ops/gram.py's docstring). XLA's generic dot
                 # cannot apply this: it does not know both operands
                 # are the same matrix. The transpose operand is never
                 # materialised either.
@@ -750,10 +736,19 @@ class Lowerer:
         accumulated in panels (strategies.dot_in_panels), or None where
         the product is not one (bfloat16 and integer tables round, or
         do not round, their answers on other terms). A transposed
-        operand is handed over by its dimension, untransposed."""
+        operand is handed over by its dimension, untransposed. Where
+        both operands are the same one (planner.long_gram) the panels
+        multiply the upper block triangle alone
+        (strategies.gram_in_panels)."""
         l, r = node.children
         if l.shape[1] < strategies.LONG_CONTRACTION:
             return None
+        gram = planner.long_gram(node, self.mesh, self.config,
+                                 self._dt_memo)
+        if gram is not None:
+            side, base = gram
+            return strategies.gram_in_panels(
+                ev(base), 0 if side == "AtA" else 1, self.config)
         a, ca = (ev(l.children[0]), 0) if l.kind == "transpose" \
             else (ev(l), 1)
         b, cb = (ev(r.children[0]), 1) if r.kind == "transpose" \

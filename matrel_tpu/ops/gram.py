@@ -6,9 +6,19 @@ For f32 x split as x = hi + lo (bf16 each), the three products XLA's
 precision=HIGH keeps (hi·hi, hi·lo, lo·hi; lo·lo dropped) collapse in a
 GRAM to two MXU passes plus a k×k transpose, because the cross terms
 are transposes of each other: xᵀx ≈ hiᵀhi + hiᵀlo + (hiᵀlo)ᵀ. Same
-three products, identical accuracy class, 33% fewer matmul FLOPs — an
-optimization XLA's generic dot cannot apply because it does not know
-both operands are the same matrix.
+three products, 33% fewer matmul FLOPs — an optimization XLA's generic
+dot cannot apply because it does not know both operands are the same
+matrix.
+
+That is the algebra. On a v5e it is NOT what runs (chip readings, PR
+31; PERF.md section 7): the compiler drops the round trip through
+bfloat16, so ``x - f32(bf16(x))`` comes out exactly 0, ``lo`` is 0,
+and the split computes bit for bit what ``matmul_precision="default"``
+computes (one bf16 pass: the regression's theta 3.19–4.70e-5 from its
+reference, ``default`` 3.14–4.70e-5, ``highest`` 5.4e-7–1.43e-6), for
+more time than ``default`` takes (75.1 against 70.1 ms a query). No
+benchmark cell runs it; its fate is ROADMAP's D-queue (delete it, or
+build ``hi`` with ``lax.reduce_precision``).
 """
 
 from __future__ import annotations

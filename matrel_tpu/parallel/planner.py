@@ -19,7 +19,8 @@ from jax.sharding import Mesh
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib
 from matrel_tpu.ir.expr import MatExpr
-from matrel_tpu.parallel.strategies import (acc_itemsize,
+from matrel_tpu.parallel.strategies import (LONG_CONTRACTION, acc_itemsize,
+                                            gram_tiles,
                                             rmm_moves_under_dot, rmm_panels,
                                             rmm_transient_bytes)
 
@@ -1707,6 +1708,49 @@ def _child_layout_hints(e: MatExpr, mesh: Optional[Mesh] = None,
     return (None,) * len(e.children)
 
 
+def _same_operand(u: MatExpr, v: MatExpr) -> bool:
+    """Do two expression nodes denote the SAME evaluated operand?
+    True for a shared DAG node, or for distinct leaf wrappers of
+    one matrix object (the DSL creates a fresh leaf per .expr())."""
+    if u is v or u.uid == v.uid:
+        return True
+    return (u.kind == "leaf" and v.kind == "leaf"
+            and u.attrs["matrix"] is v.attrs["matrix"])
+
+
+def gram_operand(node: MatExpr) -> Optional[Tuple[str, MatExpr]]:
+    """("AtA", X) for the product ``t(X) * X`` and ("AAt", X) for ``X *
+    t(X)`` of one evaluated operand, else None. A stamped precision tier
+    owns the product's numerics: such a product is no Gram to anyone."""
+    l, r = node.children
+    if node.attrs.get("precision_tier") is not None:
+        return None
+    if l.kind == "transpose" and _same_operand(l.children[0], r):
+        return "AtA", r
+    if r.kind == "transpose" and _same_operand(r.children[0], l):
+        return "AAt", l
+    return None
+
+
+def long_gram(node: MatExpr, mesh: Mesh,
+              config: Optional[MatrelConfig] = None,
+              dtype_memo: Optional[dict] = None
+              ) -> Optional[Tuple[str, MatExpr]]:
+    """:func:`gram_operand` of a product that is lowered as the upper
+    block triangle of its panels (strategies.gram_in_panels): a float32
+    Gram on one device under the plain local dot whose contraction is
+    LONG_CONTRACTION or longer. The ONE test the stamp (``gram_tiles``,
+    annotate_strategies) and the lowering (executor._long_contraction)
+    both ask, so they cannot disagree."""
+    gram = gram_operand(node)
+    if (gram is None or mesh.size != 1
+            or node.attrs.get("strategy", "xla") != "xla"
+            or node.children[0].shape[1] < LONG_CONTRACTION
+            or infer_dtype(gram[1], config, dtype_memo) != np.float32):
+        return None
+    return gram
+
+
 def _folded_transpose(node: MatExpr, parent: Optional[MatExpr],
                       mesh: Mesh) -> bool:
     """A transpose that is no array: on one device the product that
@@ -1827,6 +1871,11 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
                 stamp["refused_hbm"] = tuple(hbm["refused_hbm"])
             stamp["hbm_plan_bytes"] = hbm["hbm_plan_bytes"]
         e = e.with_attrs(**stamp)
+        if long_gram(e, mesh, config, memo) is not None:
+            # engagement counter of the triangle lowering: block
+            # products a panel (computed, of), for plan.meta and the
+            # plan.strategy spans (hbm_report)
+            e = e.with_attrs(gram_tiles=gram_tiles(e.shape[0]))
         if strat == "spgemm":
             # registry dispatch (ops/kernel_registry.py): stamp WHICH
             # kernel the S×S lowering will run — chosen from the
@@ -1874,7 +1923,10 @@ def hbm_report(root: MatExpr) -> list:
     to choose), ``refused_hbm``, ``panels`` (rows, columns),
     ``moves_under_dot`` (strategies.rmm_moves_under_dot: the moves a
     row panel of the panelled rmm hides under a dot),
-    ``hbm_plan_bytes`` (the plan's peak on one device at that node)."""
+    ``hbm_plan_bytes`` (the plan's peak on one device at that node),
+    and on a long Gram lowered as its upper block triangle alone
+    (:func:`long_gram`) ``gram_tiles``: the block products a panel
+    multiplies, of those the square holds."""
     out, seen = [], set()
 
     def walk(n: MatExpr):
@@ -1890,6 +1942,8 @@ def hbm_report(root: MatExpr) -> list:
                         "panels": list(n.attrs.get("panels", (1, 1))),
                         "moves_under_dot": n.attrs.get("moves_under_dot", 0),
                         "hbm_plan_bytes": n.attrs["hbm_plan_bytes"]})
+            if "gram_tiles" in n.attrs:
+                out[-1]["gram_tiles"] = list(n.attrs["gram_tiles"])
 
     walk(root)
     return out

@@ -91,6 +91,22 @@ ACC_PANEL_ROWS = 8192
 LONG_CONTRACTION = 1 << 17
 
 
+def _sum_of_panels(length: int, panel, zero):
+    """The sum over a contraction of ``length`` of ``panel(start,
+    rows)`` (an array, or a tuple of them, shaped like ``zero``), one
+    panel of :data:`ACC_PANEL_ROWS` a round of a ``fori_loop`` and the
+    ragged tail after it, added on the vector unit in that order."""
+    add = functools.partial(jax.tree_util.tree_map, jnp.add)
+    whole, tail = divmod(length, ACC_PANEL_ROWS)
+    out = jax.lax.fori_loop(
+        0, whole,
+        lambda i, acc: add(acc, panel(i * ACC_PANEL_ROWS, ACC_PANEL_ROWS)),
+        zero)
+    if tail:
+        out = add(out, panel(whole * ACC_PANEL_ROWS, tail))
+    return out
+
+
 def dot_in_panels(a, ca: int, b, cb: int,
                   config: Optional[MatrelConfig] = None) -> jax.Array:
     """The float32 product of ``a`` and ``b`` contracted over ``a``'s
@@ -105,7 +121,6 @@ def dot_in_panels(a, ca: int, b, cb: int,
     place (temporaries: none)."""
     dims = (((ca,), (cb,)), ((), ()))
     prec = _precision(config)
-    length = a.shape[ca]
 
     def panel(start, rows):
         return jax.lax.dot_general(
@@ -113,14 +128,78 @@ def dot_in_panels(a, ca: int, b, cb: int,
             jax.lax.dynamic_slice_in_dim(b, start, rows, axis=cb),
             dims, precision=prec, preferred_element_type=jnp.float32)
 
-    whole, tail = divmod(length, ACC_PANEL_ROWS)
-    out = jax.lax.fori_loop(
-        0, whole,
-        lambda i, acc: acc + panel(i * ACC_PANEL_ROWS, ACC_PANEL_ROWS),
+    return _sum_of_panels(
+        a.shape[ca], panel,
         jnp.zeros((a.shape[1 - ca], b.shape[1 - cb]), jnp.float32))
-    if tail:
-        out = out + panel(whole * ACC_PANEL_ROWS, tail)
-    return out
+
+
+#: Width of a block column of a long Gram's upper block triangle
+#: (:func:`gram_in_panels`): a multiple of the 128 lanes, so a block is
+#: cut at a tile's edge however the table lies. Read on a v5e (PR 32;
+#: the Gram of 2,555,904 x 1000 float32 at ``highest``, host clock
+#: around six calls each, spread under 0.2 ms): the full square 168.1
+#: ms; width 256 (4 dots a panel, 62.5% of the square's operations)
+#: 118.5; width 128 (8 dots, 56.3%) 120.7; 256 then 128s 119.7; 384
+#: then 128s 122.2; 512 then 128s 126.9; block ROWS instead of columns
+#: 119.3 (256) and 119.1 (128). Every dot of a panel costs about 10
+#: us beside its operations at the full square's rate (traced: the four
+#: block columns run at 73, 80, 83 and 87% of the six-pass peak, the
+#: square at 93%; no gap between them), so the fewer dots win what the
+#: finer triangle saves.
+GRAM_BLOCK = 256
+
+
+def gram_blocks(k: int) -> Tuple[Tuple[int, int], ...]:
+    """(start, end) of the block columns a Gram of ``k`` columns is cut
+    into: :data:`GRAM_BLOCK` wide, the last one ragged."""
+    return tuple((s, min(s + GRAM_BLOCK, k))
+                 for s in range(0, k, GRAM_BLOCK))
+
+
+def gram_tiles(k: int) -> Tuple[int, int]:
+    """(computed, of): the block products a panel of
+    :func:`gram_in_panels` multiplies, of those the square holds."""
+    nb = len(gram_blocks(k))
+    return nb * (nb + 1) // 2, nb * nb
+
+
+def gram_in_panels(a, ca: int,
+                   config: Optional[MatrelConfig] = None) -> jax.Array:
+    """The float32 Gram of ``a`` contracted with itself over its
+    dimension ``ca`` (``t(a) * a`` for 0, ``a * t(a)`` for 1), in the
+    panels of :func:`dot_in_panels`, each panel multiplying the upper
+    block triangle alone: block column j is one dot of the panel's
+    first ``end_j`` columns with its block j, with an accumulator of
+    its own, and the lower triangle is one mirror of the k x k result
+    after the loop. An entry above the diagonal is a sum of the same
+    panels' 8,192-row dots as in the square (a narrower dot may add a
+    panel's products in another order: 2.9e-7 of the largest entry
+    apart at most on a v5e); an entry below it is a copy, so the result
+    is symmetric bit for bit (the square's is not). ``a``
+    comes as it lies and is sliced inside the loop: compiled for a v5e
+    each block column is one convolution fusion that reads its two
+    slices of the table in place, in either layout of the table."""
+    free = 1 - ca
+    k = a.shape[free]
+    blocks = gram_blocks(k)
+    dims = (((ca,), (ca,)), ((), ()))
+    prec = _precision(config)
+
+    def panel(start, rows):
+        p = jax.lax.dynamic_slice_in_dim(a, start, rows, axis=ca)
+        return tuple(
+            jax.lax.dot_general(
+                jax.lax.slice_in_dim(p, 0, e, axis=free),
+                jax.lax.slice_in_dim(p, s, e, axis=free),
+                dims, precision=prec, preferred_element_type=jnp.float32)
+            for s, e in blocks)
+
+    cols = _sum_of_panels(
+        a.shape[ca], panel,
+        tuple(jnp.zeros((e, e - s), jnp.float32) for s, e in blocks))
+    upper = jnp.concatenate(
+        [jnp.pad(c, ((0, k - c.shape[0]), (0, 0))) for c in cols], axis=1)
+    return jnp.where(jnp.tri(k, dtype=bool), upper.T, upper)
 
 
 def matmul_xla(a: jax.Array, b: jax.Array, mesh: Mesh,

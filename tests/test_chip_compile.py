@@ -247,6 +247,12 @@ def chain_program(mesh_2x2):
         table, table, table).compile()
 
 
+#: The line that opens a computation of ``compiled.as_text()``; group 1
+#: is its name.
+_COMPUTATION_HEAD = re.compile(
+    r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+
+
 def _entry_schedule(text, conv_shape):
     """The scheduled entry computation as a list of operation kinds, in
     order: ``permute-start``/``permute-done`` with the start's name,
@@ -254,8 +260,7 @@ def _entry_schedule(text, conv_shape):
     ``conv_shape``."""
     dots, current = set(), None
     for line in text.splitlines():
-        head = re.match(r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$",
-                        line)
+        head = _COMPUTATION_HEAD.match(line)
         if head:
             current = head.group(1)
         elif current and re.search(
@@ -355,8 +360,7 @@ def _arrays_written(text, dim):
     fused = set(re.findall(r"\sfusion\(.*calls=%([\w.\-]+)", text))
     out, current = [], None
     for line in text.splitlines():
-        head = re.match(r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$",
-                        line)
+        head = _COMPUTATION_HEAD.match(line)
         if head:
             current = head.group(1)
             continue
@@ -403,3 +407,51 @@ def test_linreg_plan_writes_no_n_shaped_array(linreg_program):
     assert plan.meta["rule_hits"]["chain_solve"] == 1
     written = _arrays_written(compiled.as_text(), LINREG_N)
     assert all(int(np.prod(dims)) == LINREG_N for _, dims in written), written
+
+
+def _loop_bodies(text):
+    """{body: the computations it calls, itself among them} of every
+    ``while`` of the compiled program, and every computation's lines."""
+    lines, current = {}, None
+    for line in text.splitlines():
+        head = _COMPUTATION_HEAD.match(line)
+        if head:
+            current = head.group(1)
+            lines[current] = []
+        elif current:
+            lines[current].append(line)
+
+    def reach(name, seen):
+        if name in seen or name not in lines:
+            return seen
+        seen.add(name)
+        for line in lines[name]:
+            for callee in re.findall(r"calls=%([\w.\-]+)", line):
+                reach(callee, seen)
+        return seen
+
+    return {body: reach(body, set())
+            for body in re.findall(r"body=%([\w.\-]+)", text)}, lines
+
+
+def test_linreg_gram_multiplies_the_triangle_only(linreg_program):
+    """The loop over t(X)·X's panels multiplies the upper block
+    triangle: its convolutions' operations add up to the triangle's
+    share of the square a full-square dot of a panel costs (10 of 16
+    blocks at 256: 62.5%), each block column one convolution whose
+    slices of the table are read in place: no array of a panel's length
+    is written in either layout, beside y's column of it."""
+    _, compiled, _ = linreg_program
+    text = compiled.as_text()
+    rows, k = strategies.ACC_PANEL_ROWS, LINREG_K
+    bodies, lines = _loop_bodies(text)
+    ops = [[2 * rows * int(m) * int(w)
+            for name in reached for line in lines[name]
+            for m, w in re.findall(
+                r"= f32\[(\d+),(\d+)\]\S*\s+convolution\(", line)]
+           for reached in bodies.values()]
+    (gram,) = [o for o in ops if o]      # t(X)·y is a multiply-reduce
+    assert len(gram) == len(strategies.gram_blocks(k))
+    assert 0.5 < sum(gram) / (2 * rows * k * k) <= 0.66
+    assert [dims for _, dims in _arrays_written(text, rows)
+            if max(d for d in dims if d != rows) > 1] == []

@@ -76,6 +76,12 @@ def rel_err(got, want):
     return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
 
 
+def lowered_text(plan):
+    """The plan's program as it is handed to the compiler."""
+    return plan.jitted.lower(
+        *[leaf.attrs["matrix"].data for leaf in plan.leaf_order]).as_text()
+
+
 # -- the optimizer: one plan, however the formula was typed -------------------
 
 
@@ -236,6 +242,41 @@ def test_spans_say_rules_and_reckoning(one_device, data, tmp_path):
         r["attrs"]["hbm_plan_bytes"] == meta["hbm_plan_bytes"]
         and r["attrs"]["mesh"] == "1x1" for r in dispatches)
     assert len([r for r in mine if r["name"] == "matrel.compile"]) == 1
+    # a table of 4,096 rows is no long contraction: nothing says a
+    # triangle, in the records or the spans
+    assert not any("gram_tiles" in p for p in meta["products"] + strategy)
+
+
+def test_records_and_spans_say_the_triangle(one_device, tmp_path):
+    """On a table of LONG_CONTRACTION rows or more the Gram's record in
+    ``plan.meta["products"]`` and its ``matrel.plan.strategy`` span
+    carry ``gram_tiles``, the block products a panel multiplies of those
+    the square holds; ``t(X) * y`` and the solve carry none."""
+    from matrel_tpu.obs.trace import profile_spans
+    rng = np.random.default_rng(32)
+    n, k = strategies.LONG_CONTRACTION + 40, strategies.GRAM_BLOCK + 4
+    x = rng.uniform(-1, 1, (n, k)).astype(np.float32)
+    y = rng.uniform(-1, 1, (n, 1)).astype(np.float32)
+    sess = session_of(one_device, x, y)
+    before = len(profile_spans())
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        sess.compute(sess.sql(SPELLINGS[0])).to_numpy()
+    finally:
+        jax.profiler.stop_trace()
+    meta = sess.compile(sess.sql(SPELLINGS[0])).meta
+    spans = [r["attrs"] for r in profile_spans()[before:]
+             if r["name"] == "matrel.plan.strategy"]
+    for said in (meta["products"], spans):
+        assert [(p["node"], p["shape"], p.get("gram_tiles"))
+                for p in said] == [
+            ("matmul", [k, k], [3, 4]), ("matmul", [k, 1], None),
+            ("solve", [k, 1], None)]
+    assert strategies.gram_tiles(k) == (3, 4)
+    assert strategies.gram_tiles(1000) == (10, 16)
+    assert strategies.gram_tiles(strategies.GRAM_BLOCK) == (1, 1)
 
 
 # -- the executor: a long float32 contraction is accumulated in panels --------
@@ -254,40 +295,146 @@ def test_dot_in_panels_is_the_product(ca, cb):
     assert got.dtype == jnp.float32 and rel_err(np.asarray(got), want) < 1e-6
 
 
-def test_only_long_float32_contractions_are_panelled(one_device):
+@pytest.mark.parametrize("k", [6, 128, 130, 257, 1000])
+@pytest.mark.parametrize("ca", [0, 1])
+def test_gram_in_panels_is_the_gram_and_symmetric(ca, k):
+    """Two whole panels and a tail, ``t(a) * a`` (ca = 0) and ``a *
+    t(a)`` (ca = 1), k from one ragged block to four (the cell's 1000 =
+    3 x 256 + 232): the float64 Gram, symmetric to the last bit because
+    the lower triangle is a copy of the upper. On the upper triangle it
+    is ``dot_in_panels``' full square to 1e-6 of the largest entry and
+    not to the bit: a narrower dot adds the same 8,192 products of an
+    entry in another order (on a v5e 7% of the entries agree to the last
+    bit, the furthest apart by 2.9e-7; PR 32), and the full square is
+    itself not symmetric there."""
+    rng = np.random.default_rng(100 * ca + k)
+    length = 2 * strategies.ACC_PANEL_ROWS + 77
+    a = rng.uniform(-1, 1, (length, k) if ca == 0 else (k, length)) \
+        .astype(np.float32)
+    a64 = a.astype(np.float64)
+    want = a64.T @ a64 if ca == 0 else a64 @ a64.T
+    dev = jnp.asarray(a)
+    got = np.asarray(jax.jit(
+        lambda u: strategies.gram_in_panels(u, ca))(dev))
+    assert got.dtype == np.float32 and got.shape == (k, k)
+    assert rel_err(got, want) < 1e-6
+    assert np.array_equal(got, got.T)
+    square = np.asarray(jax.jit(
+        lambda u: strategies.dot_in_panels(u, ca, u, ca))(dev))
+    upper = np.triu_indices(k)
+    assert rel_err(got[upper], square[upper].astype(np.float64)) < 1e-6
+    assert strategies.gram_tiles(k) == {
+        6: (1, 1), 128: (1, 1), 130: (1, 1), 257: (3, 4), 1000: (10, 16)}[k]
+
+
+@pytest.mark.parametrize("sql", ["t(X) * X", "X * t(X)"])
+def test_a_long_gram_through_the_session_is_the_triangle(one_device, sql):
+    """Both orientations through ``session.sql`` and ``compute`` with a
+    contraction of LONG_CONTRACTION + 40 (16 panels and a tail of 40)
+    and two block columns: the float64 Gram, symmetric to the last bit,
+    stamped ``gram_tiles`` [3, 4]."""
+    rng = np.random.default_rng(len(sql))
+    n, k = strategies.LONG_CONTRACTION + 40, strategies.GRAM_BLOCK + 4
+    x = rng.uniform(-1, 1, (n, k)).astype(np.float32)
+    x64 = x.astype(np.float64)
+    table = x if sql == "t(X) * X" else np.ascontiguousarray(x.T)
+    sess = MatrelSession(mesh=one_device)
+    sess.register("X", BlockMatrix.from_array(
+        jnp.asarray(table), table.shape, one_device, P(None, None)))
+    (record,) = sess.compile(sess.sql(sql)).meta["products"]
+    assert record["gram_tiles"] == [3, 4] and record["shape"] == [k, k]
+    got = sess.compute(sess.sql(sql)).to_numpy()
+    assert rel_err(got, x64.T @ x64) < 1e-6
+    assert np.array_equal(got, got.T)
+
+
+def test_only_long_float32_contractions_are_panelled(one_device,
+                                                     monkeypatch):
     """From LONG_CONTRACTION rows on, ``t(X) * X`` and ``t(X) * y`` of
     float32 tables lower to a loop over panels (the product unchanged);
     a shorter table, and a bfloat16 one, to the one dot they always
-    were."""
+    were. And only the Gram multiplies a triangle (block columns of 4
+    here, so that k = 6 has two: two dots in the loop's body and two in
+    the tail): ``t(X) * y``, ``t(X) * Z`` with Z another table of X's
+    shape, and a Gram whose precision tier is stamped keep one dot a
+    panel; the short and the bfloat16 Gram their one dot."""
+    monkeypatch.setattr(strategies, "GRAM_BLOCK", 4)
     rng = np.random.default_rng(5)
 
-    def lowered(n, dtype, sql):
+    def lowered(n, dtype, sql, **config):
         x = rng.uniform(-1, 1, (n, 6)).astype(np.float32)
+        z = rng.uniform(-1, 1, (n, 6)).astype(np.float32)
         y = rng.uniform(-1, 1, (n, 1)).astype(np.float32)
-        sess = MatrelSession(mesh=one_device)
-        for name, arr in (("X", x), ("y", y)):
+        sess = MatrelSession(mesh=one_device, config=MatrelConfig(**config))
+        for name, arr in (("X", x), ("y", y), ("Z", z)):
             sess.register(name, BlockMatrix.from_array(
                 jnp.asarray(arr, dtype), arr.shape, one_device,
                 P(None, None)))
         plan = sess.compile(sess.sql(sql))
-        text = plan.jitted.lower(
-            *[leaf.attrs["matrix"].data for leaf in plan.leaf_order]
-        ).as_text()
-        x, y = (np.asarray(jnp.asarray(v, dtype).astype(jnp.float32),
-                           np.float64) for v in (x, y))
-        want = x.T @ (x if sql == "t(X) * X" else y)
+        text = lowered_text(plan)
+        x, y, z = (np.asarray(jnp.asarray(v, dtype).astype(jnp.float32),
+                              np.float64) for v in (x, y, z))
+        want = x.T @ {"t(X) * X": x, "t(X) * y": y, "t(X) * Z": z}[sql]
         got = np.asarray(sess.compute(sess.sql(sql)).data
                          .astype(jnp.float32), np.float64)
-        return "while" in text, rel_err(got, want)
+        (record,) = plan.meta["products"]
+        return ("while" in text, text.count("dot_general"),
+                record.get("gram_tiles"), rel_err(got, want))
 
     long = strategies.LONG_CONTRACTION + 40
-    for sql in ("t(X) * X", "t(X) * y"):
-        looped, err = lowered(long, jnp.float32, sql)
-        assert looped and err < 1e-5
-        looped, err = lowered(long - 80, jnp.float32, sql)
-        assert not looped and err < 1e-5
-    looped, err = lowered(long, jnp.bfloat16, "t(X) * X")
-    assert not looped and err < 1e-2
+    for sql, dots, tiles in (("t(X) * X", 4, [3, 4]),
+                             ("t(X) * y", 2, None),
+                             ("t(X) * Z", 2, None)):
+        *said, err = lowered(long, jnp.float32, sql)
+        assert said == [True, dots, tiles] and err < 1e-5
+        looped, dots, tiles, err = lowered(long - 80, jnp.float32, sql)
+        assert (looped, dots, tiles) == (False, 1, None) and err < 1e-5
+    looped, dots, tiles, err = lowered(long, jnp.bfloat16, "t(X) * X")
+    assert (looped, dots, tiles) == (False, 1, None) and err < 1e-2
+    # a stamped tier owns the product's numerics: the float32 tier is
+    # the panelled full square, as it was
+    looped, dots, tiles, err = lowered(long, jnp.float32, "t(X) * X",
+                                       precision_sla="float32")
+    assert (looped, dots, tiles) == (True, 2, None) and err < 1e-5
+
+
+# -- the other cells' products lower as they did -------------------------------
+
+
+def _lowered(mesh, spec, shape, dtype, sql):
+    """(plan.meta's records, the lowered text) of ``sql`` over tables M
+    and N of zeros."""
+    sess = MatrelSession(mesh=mesh)
+    for name in "MN":
+        sess.register(name, BlockMatrix.from_array(
+            jax.device_put(jnp.zeros(shape, dtype),
+                           jax.sharding.NamedSharding(mesh, spec)),
+            shape, mesh, spec))
+    plan = sess.compile(sess.sql(sql))
+    return plan.meta["products"], lowered_text(plan)
+
+
+@pytest.mark.parametrize("sql", ["M * N", "t(M) * M", "M * t(M)"])
+def test_a_dashboard_product_lowers_as_it_did(one_device, sql):
+    """Cell 1's shape: float32 4096^2 on one device, a Gram among them.
+    No contraction is long: one dot, no loop, no ``gram_tiles``."""
+    records, text = _lowered(one_device, P(None, None), (4096, 4096),
+                             jnp.float32, sql)
+    assert not any("gram_tiles" in r for r in records)
+    assert "while" not in text and text.count("dot_general") == 1
+
+
+@pytest.mark.parametrize("sql", ["M * N", "t(M) * M", "M * t(M)"])
+def test_a_mesh_product_lowers_as_it_did(mesh_square, sql):
+    """Cell 4's kind: bfloat16 tables sharded over the 2x2 mesh, a Gram
+    among them, and the same tables in float32 (a mesh's local dots are
+    not this PR's): the strategy's program, no ``gram_tiles``, no loop
+    over panels."""
+    for dtype in (jnp.bfloat16, jnp.float32):
+        records, text = _lowered(mesh_square, P(*mesh_square.axis_names),
+                                 (1024, 1024), dtype, sql)
+        assert records and not any("gram_tiles" in r for r in records)
+        assert "while" not in text
 
 
 # -- the chip's share of the deployment is a share ----------------------------
