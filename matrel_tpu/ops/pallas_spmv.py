@@ -11,16 +11,28 @@ VMEM from the compact layout the plan build already produces
 Pipeline per matvec (``spmv_compact``):
 
   1. XLA: ``w[b,c] = x[src8[b,c]·8 + lane[b,c]] · val[b,c]`` through
-     ``spmv.gather_1d``: a row gather of bytes, 128 B a slot (1.34 GB a
-     matvec at 10.5M slots). Gathered as 8 float32 a row the same step
+     ``spmv.gather_rows``: a row gather of bytes, 128 B a slot (1.34 GB
+     a matvec at 10.5M slots). Gathered as 8 float32 a row the same step
      materialised ``f32[slots, 8]`` padded to 128 lanes — 5.38 GB
-     written and read back every matvec (PERF.md §6, PR 28).
-  2. Pallas, grid over blocks: generate ``oh_hi`` (C, HI') bf16 and the
-     w-carrying rhs (C, LO·passes) in VMEM (w carved into bf16 residual
-     parts by mantissa masking — f32-faithful at passes=3, see
+     written and read back every matvec (PERF.md §6, PR 28). Where those
+     temporaries (``_TEMP_BYTES_A_SLOT``) would pass a quarter of the
+     device's memory the step runs in PANELS of table rows inside one
+     loop, each writing its slice of ``w`` (4 B a slot): 133M slots of a
+     Graph500 scale-22 graph would gather 17 GB on a 15.75 GB chip
+     (PR 33). One panel where everything fits: the program as before.
+  2. Pallas: generate ``oh_hi`` (C, HI') bf16 and the w-carrying rhs
+     (C, LO·passes) in VMEM (w carved into bf16 residual parts by
+     mantissa masking — f32-faithful at passes=3, see
      ops/spmv_routed.py for why masking, not casts), one MXU contraction
-     ``oh_hiᵀ @ rhs`` per block, write the (HI', LO) output tile.
-  3. XLA: overflow-COO accumulation (unchanged contract).
+     ``oh_hiᵀ @ rhs`` per grid step. Blocks layout: a step a block,
+     writing its (HI', LO) output tile. Chunks layout (ops/spmv.py,
+     PR 33): a step a chunk of ``spmv.CHUNK`` slots; the chunk → block
+     table is scalar-prefetched into the output's index map, so the
+     consecutive chunks of one block add into one resident (HI', LO)
+     tile, zeroed on the block's first chunk — a hub block is many
+     steps, not one tall tile, and nothing is left to an overflow COO.
+  3. XLA: overflow-COO accumulation (blocks layout only; unchanged
+     contract).
 
 This executor reads an EdgeSpMVPlan's compact host tables (kept on
 device via a small memo). It is the DEFAULT on real TPU backends for
@@ -49,34 +61,56 @@ from matrel_tpu.ops.spmv_routed import _bf16_split
 LANE = 128
 
 
+def _scatter_tile(off, w, hi_n: int, lo: int, passes: int):
+    """(HI', LO) partial sums of one tile of slots, in VMEM."""
+    # slots ride the MINOR (128-lane) axis throughout: masks with a
+    # <128 minor dim lane-pad 4-8x on the VPU and cost more than the
+    # stored tables they replace (measured 45 ms vs 29 at BASELINE
+    # row-5 scale before this layout)
+    cr = off.shape[0]
+    ids_hi = jax.lax.broadcasted_iota(
+        jnp.int32, (cr, hi_n, LANE), 1)
+    oh_hi = ((off // lo)[:, None, :] == ids_hi).astype(jnp.bfloat16)
+    ids_lo = jax.lax.broadcasted_iota(
+        jnp.int32, (cr, lo, LANE), 1)
+    mask = (off % lo)[:, None, :] == ids_lo
+    rhs = jnp.concatenate(
+        [jnp.where(mask, wp[:, None, :], 0.0)
+         for wp in _bf16_split(w, passes)],
+        axis=1).astype(jnp.bfloat16)                 # (cr,lo·p,128)
+    t = jax.lax.dot_general(
+        oh_hi, rhs,
+        (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)          # (cr,hi_n,lo·p)
+    ts = jnp.sum(t, axis=0)                          # (hi_n, lo·p)
+    th = ts[:, :lo]
+    for p in range(1, passes):
+        th = th + ts[:, p * lo:(p + 1) * lo]
+    return th
+
+
 def _make_scatter_kernel(hi_n: int, lo: int, passes: int):
     def kernel(off_ref, w_ref, y_ref):
-        # slots ride the MINOR (128-lane) axis throughout: masks with a
-        # <128 minor dim lane-pad 4-8x on the VPU and cost more than the
-        # stored tables they replace (measured 45 ms vs 29 at BASELINE
-        # row-5 scale before this layout)
         off = off_ref[0]                                 # (cr, 128)
         w = w_ref[0]
-        cr = off.shape[0]
-        ids_hi = jax.lax.broadcasted_iota(
-            jnp.int32, (cr, hi_n, LANE), 1)
-        oh_hi = ((off // lo)[:, None, :] == ids_hi).astype(jnp.bfloat16)
-        ids_lo = jax.lax.broadcasted_iota(
-            jnp.int32, (cr, lo, LANE), 1)
-        mask = (off % lo)[:, None, :] == ids_lo
-        rhs = jnp.concatenate(
-            [jnp.where(mask, wp[:, None, :], 0.0)
-             for wp in _bf16_split(w, passes)],
-            axis=1).astype(jnp.bfloat16)                 # (cr,lo·p,128)
-        t = jax.lax.dot_general(
-            oh_hi, rhs,
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)          # (cr,hi_n,lo·p)
-        ts = jnp.sum(t, axis=0)                          # (hi_n, lo·p)
-        th = ts[:, :lo]
-        for p in range(1, passes):
-            th = th + ts[:, p * lo:(p + 1) * lo]
-        y_ref[0] = th
+        y_ref[0] = _scatter_tile(off, w, hi_n, lo, passes)
+
+    return kernel
+
+
+def _make_chunk_scatter_kernel(hi_n: int, lo: int, passes: int):
+    def kernel(cb_ref, off_ref, w_ref, y_ref):
+        # consecutive chunks of one block share the output tile (the
+        # index map reads the same cb): zero it on the block's first
+        c = pl.program_id(0)
+        first = jnp.logical_or(
+            c == 0, cb_ref[c] != cb_ref[jnp.maximum(c - 1, 0)])
+
+        @pl.when(first)
+        def _():
+            y_ref[...] = jnp.zeros_like(y_ref)
+
+        y_ref[0] += _scatter_tile(off_ref[0], w_ref[0], hi_n, lo, passes)
 
     return kernel
 
@@ -103,10 +137,40 @@ def _compact_runner(nb: int, cap: int, block: int, lo: int, passes: int,
     return scatter
 
 
+@functools.lru_cache(maxsize=32)
+def _chunk_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
+                  passes: int, interpret: bool):
+    """scatter(chunk_block, off, w) -> (nb, HI', LO): the chunks layout's
+    scatter, a grid step a chunk. ``arbitrary``: the steps of one block
+    must follow one another on one core to share its output tile."""
+    hi_n = block // lo
+    cr = chunk // LANE
+    return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
+        _make_chunk_scatter_kernel(hi_n, lo, passes),
+        name="matrel_spmv_scatter_chunks",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                       # chunk_block
+            grid=(n_chunks,),
+            in_specs=[
+                pl.BlockSpec((1, cr, LANE), lambda c, cb: (c, 0, 0)),
+                pl.BlockSpec((1, cr, LANE), lambda c, cb: (c, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, hi_n, lo),
+                                   lambda c, cb: (cb[c], 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((nb, hi_n, lo), jnp.float32),
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )
+
+
 def compact_tables(plan: spmv_lib.EdgeSpMVPlan):
     """Device copies of the plan's compact layout, memoised on the plan
     (the plan keeps its compact host tables even after expanded-path
-    use, so path order never matters)."""
+    use, so path order never matters): (src8, lane, off, val), and the
+    chunk → block table as a fifth where the plan is laid out in
+    chunks — ``compact_apply`` knows the layout by it."""
     dev = getattr(plan, "_compact_dev", None)
     if dev is None:
         nb, cap = np.asarray(plan.src8).shape
@@ -125,23 +189,100 @@ def compact_tables(plan: spmv_lib.EdgeSpMVPlan):
                    jnp.asarray(np.asarray(plan.lane).reshape(shp)),
                    jnp.asarray(np.asarray(plan.off).reshape(shp)),
                    jnp.asarray(np.asarray(plan.val).reshape(shp)))
+            if plan.chunk_block is not None:
+                dev += (jnp.asarray(plan.chunk_block, jnp.int32),)
         plan._compact_dev = dev
     return dev
+
+
+# What a panel of the matvec keeps alive a slot, read off the
+# described-v5e compile of the PageRank loop (tests/test_chip_compile.py,
+# 8 values a gathered row: 4.78 GB of temporaries for a panel of 21.7M
+# slots, 220 B each; 159 at 2 values a row): the gathered byte rows at
+# 128 B (a uint8 row of up to 128 elements fills 128 lanes), the 16-bit
+# halves the MXU product makes of them (8 B a value of the row), the
+# index and the select.
+_TEMP_BYTES_A_SLOT = 224
+# what a plan holds a slot whatever the panels: the compact tables
+# (src8 4, lane 1, off 4, val 4) and the matvec's slot weights ``w`` (4)
+RESIDENT_BYTES_A_SLOT = 13 + 4
+# the share of the device's memory a panel's temporaries may take: the
+# tables, ``w`` and the caller's vectors have the rest
+_PANEL_SHARE = 0.25
+
+
+def _hbm_limit() -> int:
+    """What one device hands out (core.mesh.hbm_limit_bytes: the smaller
+    of the config's budget and the device's ``bytes_limit``)."""
+    from jax.sharding import Mesh
+    from matrel_tpu.core.mesh import hbm_limit_bytes
+    return hbm_limit_bytes(Mesh(np.asarray(jax.devices()[:1]), ("x",)))
+
+
+def panel_rows(rows: int, cap: int) -> int:
+    """How many table rows (blocks or chunks, ``cap`` slots each) a
+    panel of the matvec takes: all of them where their temporaries stay
+    under ``_PANEL_SHARE`` of the device's memory, else the rows spread
+    evenly over the fewest panels that do (the last panel is moved back
+    to end with the tables, so an uneven split would gather its overlap
+    twice: 11.7% of a Graph500 scale-22 round, my chip run, PR 33)."""
+    room = _PANEL_SHARE * _hbm_limit()
+    most = max(1, int(room // (_TEMP_BYTES_A_SLOT * cap)))
+    return -(-rows // -(-rows // most))
+
+
+def plan_bytes(rows: int, cap: int) -> int:
+    """What a compact plan of ``rows`` x ``cap`` slots holds of one
+    device while a matvec runs: the tables at 13 B a slot, ``w``, and
+    one panel's temporaries."""
+    slots = rows * cap
+    panel = panel_rows(rows, cap) * cap
+    return RESIDENT_BYTES_A_SLOT * slots + _TEMP_BYTES_A_SLOT * panel
+
+
+def _slot_weights(src8, lane, val, x: jax.Array) -> jax.Array:
+    """``w = x[src8·8 + lane] · val``, a slot each, in panels of table
+    rows where one gather over all of them would not fit."""
+    xf = x.astype(jnp.float32)
+    rows, cr, _ = src8.shape
+    per = panel_rows(rows, cr * LANE)
+    if per >= rows:
+        idx = src8 * spmv_lib.WIDTH + lane.astype(jnp.int32)
+        return spmv_lib.gather_1d(xf, idx) * val
+    byte_rows = spmv_lib.byte_table(xf)
+
+    def panel(i, w):
+        # the last panel is moved back to end with the tables: the rows
+        # it shares with the one before are written twice, the same
+        at = jnp.minimum(i * per, rows - per)
+        s8, ln, v = (jax.lax.dynamic_slice_in_dim(a, at, per)
+                     for a in (src8, lane, val))
+        idx = s8 * spmv_lib.WIDTH + ln.astype(jnp.int32)
+        return jax.lax.dynamic_update_slice_in_dim(
+            w, spmv_lib.gather_rows(byte_rows, idx, jnp.float32) * v, at, 0)
+
+    return jax.lax.fori_loop(0, -(-rows // per), panel,
+                             jnp.zeros(val.shape, jnp.float32))
 
 
 def compact_apply(plan_static, tables, ov, x: jax.Array,
                   passes: int = 3, interpret: bool = False) -> jax.Array:
     """Traceable body: y = A·x from compact tables. ``plan_static`` is
-    (n_rows, n_cols, block, lo); ``tables`` from compact_tables(); ``ov``
-    the overflow COO tuple (possibly empty)."""
+    (n_rows, n_cols, block, lo); ``tables`` from compact_tables() (five
+    of them: the chunks layout); ``ov`` the overflow COO tuple (possibly
+    empty)."""
     n_rows, n_cols, block, lo = plan_static
-    src8, lane, off, val = tables
-    nb, cr, _ = src8.shape
-    idx = src8 * spmv_lib.WIDTH + lane.astype(jnp.int32)
-    w = spmv_lib.gather_1d(x.astype(jnp.float32), idx) * val
-    scatter = _compact_runner(nb, cr * LANE, block, lo, passes,
-                              interpret)
-    y = scatter(off, w).reshape(-1)[:n_rows]
+    src8, lane, off, val, *chunk_block = tables
+    rows, cr, _ = src8.shape
+    w = _slot_weights(src8, lane, val, x)
+    if chunk_block:
+        scatter = _chunk_runner(rows, cr * LANE, -(-n_rows // block), block,
+                                lo, passes, interpret)
+        y = scatter(chunk_block[0], off, w).reshape(-1)[:n_rows]
+    else:
+        scatter = _compact_runner(rows, cr * LANE, block, lo, passes,
+                                  interpret)
+        y = scatter(off, w).reshape(-1)[:n_rows]
     if ov:
         y = spmv_lib._overflow_add(y, ov, x, n_rows)
     return y
@@ -164,6 +305,7 @@ def shard_compact_tables(plan: spmv_lib.EdgeSpMVPlan, mesh):
     cache, so rebuilding an equal Mesh per call reuses the transfer."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    spmv_lib._blocks_layout_only(plan, "the sharded compact tables")
     memo = getattr(plan, "_compact_sharded", None)
     if memo is None:
         memo = {}
@@ -396,6 +538,9 @@ def spmm_compact(plan: spmv_lib.EdgeSpMVPlan, X: jax.Array,
     X = jnp.asarray(X, jnp.float32)
     if X.shape[1] == 0:
         return jnp.zeros((plan.n_rows, 0), jnp.float32)
+    if X.shape[1] > 1:
+        spmv_lib._blocks_layout_only(plan, "the k-wide compact kernels "
+                                     "(_compact_runner_k)")
     if X.shape[1] == 1:
         return spmv_compact(plan, X[:, 0], passes=passes,
                             interpret=interpret)[:, None]

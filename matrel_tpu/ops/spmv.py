@@ -30,10 +30,25 @@ into the two forms the hardware executes well:
   free.
 
 Everything is static-shaped per plan (one compile per graph), matching the
-reference's plan-per-query model. Plans whose padding would blow past
-``max_padding`` (heavy-tailed degree distributions) fall back partially via
-a small overflow COO handled by ``segment_sum``, or entirely (build returns
-None) so callers can use the plain path.
+reference's plan-per-query model. A plan has one of two layouts:
+
+* ``blocks`` (the default): every 512-node destination block owns ONE row
+  of ``capacity`` slots, the 0.995 quantile of the blocks' edge counts.
+  A uniform graph pads 5% that way. Edges past a block's capacity go to a
+  small overflow COO handled by ``segment_sum`` (the scalar path above).
+* ``chunks`` (``layout="auto"`` picks it where it pads less; PR 33): the
+  slots come in fixed chunks of ``CHUNK``; a block owns ⌈edges / CHUNK⌉
+  consecutive chunks (at least one), ``chunk_block`` is the static chunk →
+  block table, and there is NO overflow: a hub block simply owns more
+  chunks. On a Graph500 Kronecker graph of scale 22 (largest block 189k
+  edges, median 25k) that is 1.04 slots an edge where one capacity for
+  every block takes 2.98 and still leaves 362k edges to the scalar tail.
+  Only the compact-table Pallas matvec (ops/pallas_spmv.py, one device)
+  takes it; the expanded tables, ``shard_plan`` and the k-wide kernels say
+  so by name.
+
+Either layout is refused (build returns None) when it pads past
+``max_padding`` or ``max_slots``, so callers can use the plain path.
 """
 
 from __future__ import annotations
@@ -53,6 +68,15 @@ WIDTH = 8        # values a table row of the plan layout (src8, lane): the
 BLOCK = 512      # scatter block: nodes per one-hot block row
 HI = 32          # off = hi*LO + lo one-hot factor sizes; HI*LO == BLOCK
 LO = 16
+CHUNK = 2048     # slots a chunk of the ``chunks`` layout: one grid step of
+                 # the Pallas scatter, a (16, 128) tile
+# ``layout="auto"`` weighs the two layouts by the slots a matvec walks.
+# An overflow edge rides XLA's scalar gather and segment_sum (~13 ns,
+# module docstring) where a slot costs ~2 ns (PERF.md §5): 8 slots. A
+# plan under a million slots is cheap whatever its padding and keeps
+# the blocks layout, which every executor takes.
+_OVERFLOW_EDGE_SLOTS = 8
+_SMALL_PLAN_SLOTS = 1 << 20
 
 # probed once at import (os.umask is process-global; toggling it per save
 # would race concurrent file creation in other threads)
@@ -120,13 +144,26 @@ def gather_1d(table: jax.Array, idx: jax.Array,
     of a row lies l * rows further on), so the byte table is built from
     contiguous slices. ``width`` defaults to :func:`_row_values`.
     """
+    byte_rows = byte_table(table, width)
+    return gather_rows(byte_rows, idx, table.dtype)
+
+
+def byte_table(table: jax.Array, width: Optional[int] = None) -> jax.Array:
+    """The ``(rows, 4 * width)`` uint8 table :func:`gather_1d` gathers
+    from: built once a matvec, gathered from once a panel."""
     n = table.shape[0]
     w = width or _row_values(n)
     rows = n // w + 1                                  # w * rows >= n + 1
     padded = jnp.concatenate(
         [table, jnp.zeros((w * rows - n,), table.dtype)]).reshape(w, rows)
-    byte_rows = jax.lax.bitcast_convert_type(padded, jnp.uint8).transpose(
+    return jax.lax.bitcast_convert_type(padded, jnp.uint8).transpose(
         1, 0, 2).reshape(rows, 4 * w)
+
+
+def gather_rows(byte_rows: jax.Array, idx: jax.Array, dtype) -> jax.Array:
+    """``table[idx]`` from :func:`byte_table`'s rows (see
+    :func:`gather_1d`)."""
+    rows, w = byte_rows.shape[0], byte_rows.shape[1] // 4
     # which value of its row: idx // rows, as w - 1 compares
     sub = sum((idx >= l * rows).astype(jnp.int32) for l in range(1, w))
     g = byte_rows.at[idx - sub * rows].get(mode="promise_in_bounds")
@@ -139,7 +176,7 @@ def gather_1d(table: jax.Array, idx: jax.Array,
     vals = halves[..., 0, :, :] | (halves[..., 1, :, :] << 16)  # (..., w, s)
     sel = sub[..., None, :] == jnp.arange(w, dtype=jnp.int32)[:, None]
     out = jnp.sum(jnp.where(sel, vals, 0), axis=-2, dtype=jnp.uint32)
-    return jax.lax.bitcast_convert_type(out, table.dtype)
+    return jax.lax.bitcast_convert_type(out, dtype)
 
 
 @dataclasses.dataclass
@@ -151,7 +188,10 @@ class EdgeSpMVPlan:
     lazily — the host build and the host→device transfer stay ~15x
     smaller than the expanded tables.
 
-    Shapes: B = #row blocks, C = per-block capacity.
+    Shapes: B = #row blocks, C = per-block capacity — or, in the
+    ``chunks`` layout (``chunk_block`` not None), B = #chunks, C = CHUNK,
+    and ``chunk_block[i]`` is the row block chunk i adds into (ascending;
+    every block owns at least one chunk; no overflow).
       src8    (B, C) int32 — width-row index of x per padded edge slot
       lane    (B, C) int8  — cols[e] % WIDTH
       off     (B, C) int32 — rows[e] % block
@@ -173,6 +213,7 @@ class EdgeSpMVPlan:
     ov_rows: Optional[jax.Array]
     ov_vals: Optional[jax.Array]
     padding_ratio: float
+    chunk_block: Optional[np.ndarray] = None    # (B,) int32: chunks layout
     _tables: Optional[tuple] = dataclasses.field(default=None, repr=False)
     _spmm_tables: Optional[tuple] = dataclasses.field(default=None,
                                                       repr=False)
@@ -191,6 +232,7 @@ class EdgeSpMVPlan:
         sharded without ever materialising on a single device."""
         ov = () if self.ov_cols is None else (self.ov_cols, self.ov_rows,
                                               self.ov_vals)
+        _blocks_layout_only(self, "the expanded one-hot tables")
         if self._tables is None:
             src8 = jnp.asarray(self.src8)        # no-op if pre-placed
             sel, oh_hi, oh_lo = _expand_tables(self.block // LO)(
@@ -223,6 +265,17 @@ class EdgeSpMVPlan:
         return self._spmm_tables
 
 
+def _blocks_layout_only(plan: EdgeSpMVPlan, who: str) -> None:
+    """Only the compact-table Pallas matvec walks chunks; every other
+    executor reads row i of the tables as block i."""
+    if plan.chunk_block is not None:
+        raise ValueError(
+            f"{who} take only the blocks layout of an EdgeSpMVPlan; this "
+            "plan is laid out in chunks (build_spmv_plan layout='auto' "
+            "or 'chunks'): build it with layout='blocks', or run it "
+            "through ops.pallas_spmv.spmv_compact")
+
+
 @jax.jit  # matlint: disable=ML010 pre-seam ops runner cache — the porting worklist (the ML009 legacy-kernel idiom)
 def _derive_spmm_tables(src8, sel):
     lane = jnp.argmax(sel != 0.0, axis=-1).astype(jnp.int32)
@@ -251,18 +304,29 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
                     n_cols: int = None, *, block: int = BLOCK,
                     capacity_quantile: float = 0.995,
                     max_padding: float = 4.0,
-                    max_slots: Optional[int] = None
+                    max_slots: Optional[int] = None,
+                    layout: str = "blocks",
+                    refusals: Optional[list] = None
                     ) -> Optional[EdgeSpMVPlan]:
     """Host-side plan build (numpy, once per graph).
 
-    Capacity is the ``capacity_quantile`` of per-block edge counts rounded
-    up to a multiple of 128; edges past it go to the overflow COO. Returns
-    None when even that layout pads worse than ``max_padding``× the edge
-    count, or when the padded slot count exceeds ``max_slots`` (the
-    expanded device tables cost ~224 B/slot of HBM — pass a cap when the
-    caller would rather fall back than spend that) — callers should then
-    use the plain segment_sum path.
+    ``layout="blocks"``: capacity is the ``capacity_quantile`` of
+    per-block edge counts rounded up to a multiple of 128; edges past it
+    go to the overflow COO. ``"chunks"``: every block owns as many
+    chunks of ``CHUNK`` slots as its edges need, and nothing overflows.
+    ``"auto"`` (for a caller whose executor takes both: the compact
+    Pallas matvec on one device) picks ``chunks`` where that walks
+    fewer slots, an overflow edge counted as ``_OVERFLOW_EDGE_SLOTS``,
+    and the blocks layout is not small anyway. Returns None when the
+    layout pads worse than ``max_padding``× the edge count, or when the
+    padded slot count exceeds ``max_slots`` (the expanded device tables
+    cost ~224 B/slot of HBM, the compact ones 13 — pass a cap when the
+    caller would rather fall back than spend that) — callers should
+    then use the plain segment_sum path; which of the two it was is
+    appended to ``refusals`` where the caller hands a list.
     """
+    if layout not in ("blocks", "chunks", "auto"):
+        raise ValueError(f"unknown layout {layout!r}")
     rows = np.asarray(rows, dtype=np.int64)
     cols = np.asarray(cols, dtype=np.int64)
     m = rows.shape[0]
@@ -291,26 +355,52 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
         cap_q = int(np.quantile(cnt[cnt > 0], capacity_quantile)) \
             if (cnt > 0).any() else 0
         cap = max(128, -(-cap_q // 128) * 128)
+    n_ov = int(np.maximum(cnt - cap, 0).sum())
+    owned = np.maximum(-(-cnt // CHUNK), 1)       # chunks a block owns
+    n_chunks = int(owned.sum())
+    if layout == "auto":
+        layout = "chunks" if (
+            nb * cap + n_ov > _SMALL_PLAN_SLOTS
+            and n_chunks * CHUNK < nb * cap + _OVERFLOW_EDGE_SLOTS * n_ov
+        ) else "blocks"
+    if layout == "chunks":
+        n_rows_t, cap, n_ov = n_chunks, CHUNK, 0
+        first = np.zeros(nb + 1, np.int64)
+        np.cumsum(owned * CHUNK, out=first[1:])
+    else:
+        n_rows_t = nb
+        first = np.arange(nb + 1, dtype=np.int64) * cap
+    slots = n_rows_t * cap
     # Refuse only when padding hurts at scale: small plans are cheap no
     # matter the ratio, so the gate needs both the relative and an
     # absolute (1M padded slots) threshold. Callers fall back to the
     # plain segment_sum path on None.
-    if m and nb * cap > max_padding * m and nb * cap > (1 << 20):
+    refused = None
+    if m and slots > max_padding * m and slots > (1 << 20):
+        refused = (f"padding: the {layout} layout takes {slots} slots for "
+                   f"{m} edges, more than max_padding {max_padding:g} an "
+                   "edge")
+    elif max_slots is not None and slots > max_slots:
+        refused = (f"bytes: the {layout} layout takes {slots} slots, the "
+                   f"caller's memory gate holds {max_slots}")
+    if refused:
+        if refusals is not None:
+            refusals.append(refused)
         return None
-    if max_slots is not None and nb * cap > max_slots:
-        return None
-    n_ov = int(np.maximum(cnt - cap, 0).sum())
 
-    filled = native.spmv_fill(rows, cols, vals, n_cols, block, nb, cap,
-                              WIDTH, n_ov) if use_native else None
-    if filled is not None:
-        # Native single-pass counting-sort fill (O(m), no argsort —
-        # slot order within a block is input order; the one-hot
-        # contraction is order-agnostic so results match the numpy path)
-        src8, lane, off, val, ov_r64, ov_c64, ov_v = filled
-    else:
-        src8, lane, off, val, ov_r64, ov_c64, ov_v = _numpy_fill(
-            rows, cols, vals, m, n_cols, block, nb, cap, cnt)
+    # Native single-pass counting-sort fill (O(m), no argsort — slot
+    # order within a block is input order; the one-hot contraction is
+    # order-agnostic so results match the numpy path)
+    filled = None
+    if use_native and layout == "chunks":
+        filled = native.spmv_fill_ragged(rows, cols, vals, n_cols, block,
+                                         first, WIDTH)
+    elif use_native:
+        filled = native.spmv_fill(rows, cols, vals, n_cols, block, nb, cap,
+                                  WIDTH, n_ov)
+    if filled is None:
+        filled = _numpy_fill(rows, cols, vals, m, n_cols, block, first, cnt)
+    src8, lane, off, val, ov_r64, ov_c64, ov_v = filled
 
     if n_ov:
         ov_c = jnp.asarray(ov_c64, jnp.int32)
@@ -321,36 +411,43 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
 
     # compact tables stay host-side numpy; they move to device (default
     # placement or sharded via shard_plan) at expansion time
+    shape = (n_rows_t, cap)
     return EdgeSpMVPlan(
         n_rows=n_rows, n_cols=n_cols, block=block, capacity=cap,
-        src8=np.ascontiguousarray(src8, np.int32),
-        lane=np.ascontiguousarray(lane, np.int8),
-        off=np.ascontiguousarray(off, np.int32),
-        val=np.ascontiguousarray(val, np.float32),
+        src8=np.ascontiguousarray(src8, np.int32).reshape(shape),
+        lane=np.ascontiguousarray(lane, np.int8).reshape(shape),
+        off=np.ascontiguousarray(off, np.int32).reshape(shape),
+        val=np.ascontiguousarray(val, np.float32).reshape(shape),
         ov_cols=ov_c, ov_rows=ov_r, ov_vals=ov_v,
-        padding_ratio=(nb * cap + n_ov) / max(m, 1))
+        padding_ratio=(slots + n_ov) / max(m, 1),
+        chunk_block=(np.repeat(np.arange(nb, dtype=np.int32), owned)
+                     if layout == "chunks" else None))
 
 
-def _numpy_fill(rows, cols, vals, m, n_cols, block, nb, cap, cnt):
+def _numpy_fill(rows, cols, vals, m, n_cols, block, first, cnt):
     """Pure-numpy plan fill (fallback when the native library is
-    unavailable): stable argsort by row, then fancy-indexed scatters."""
+    unavailable): stable argsort by row, then fancy-indexed scatters.
+    Block b owns the flat slots ``first[b]:first[b + 1]`` (one row of
+    ``cap`` in the blocks layout, its chunks in the other); edges past
+    them are the overflow."""
     if vals is None:
         vals = np.ones((m,), np.float32)
     order = np.argsort(rows, kind="stable")
     rows_s, cols_s, vals_s = rows[order], cols[order], vals[order]
     blk = rows_s // block
-    starts = np.zeros(nb + 1, np.int64)
+    starts = np.zeros(first.shape[0], np.int64)
     np.cumsum(cnt, out=starts[1:])
-    slot = np.arange(m, dtype=np.int64) - starts[blk]
-    in_main = slot < cap
+    pos = first[blk] + np.arange(m, dtype=np.int64) - starts[blk]
+    in_main = pos < first[blk + 1]
 
-    src_pad = np.full((nb, cap), n_cols, np.int64)   # sentinel -> reads 0
-    val_pad = np.zeros((nb, cap), np.float32)
-    off_pad = np.zeros((nb, cap), np.int64)
-    b_main, s_main = blk[in_main], slot[in_main]
-    src_pad[b_main, s_main] = cols_s[in_main]
-    val_pad[b_main, s_main] = vals_s[in_main]
-    off_pad[b_main, s_main] = rows_s[in_main] % block
+    slots = int(first[-1])
+    src_pad = np.full(slots, n_cols, np.int64)       # sentinel -> reads 0
+    val_pad = np.zeros(slots, np.float32)
+    off_pad = np.zeros(slots, np.int64)
+    p_main = pos[in_main]
+    src_pad[p_main] = cols_s[in_main]
+    val_pad[p_main] = vals_s[in_main]
+    off_pad[p_main] = rows_s[in_main] % block
     return ((src_pad // WIDTH).astype(np.int32),
             (src_pad % WIDTH).astype(np.int8),
             off_pad.astype(np.int32), val_pad,
@@ -566,6 +663,7 @@ def shard_plan(plan: EdgeSpMVPlan, mesh) -> EdgeSpMVPlan:
     before the plan's tables are expanded."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
+    _blocks_layout_only(plan, "shard_plan and the sharded executors")
     if plan._tables is not None:
         raise ValueError("shard_plan must run before table expansion "
                          "(call it on a freshly built plan)")
@@ -652,15 +750,21 @@ def save_plan(path: str, plan: EdgeSpMVPlan) -> None:
     executor's device copy) happens on the loading process's device.
     Plans keep their compact tables for life, so saving works before OR
     after any executor has used the plan."""
+    chunked = plan.chunk_block is not None
     payload = dict(
         # trailing fields: format version + the WIDTH/LO constants baked
         # into src8/lane/off at build time — loading under different
-        # constants must fail loudly, not gather from wrong rows
+        # constants must fail loudly, not gather from wrong rows. The
+        # chunks layout is version 2: a reader that knows only version 1
+        # would take chunk i for block i
         meta=np.asarray([plan.n_rows, plan.n_cols, plan.block,
-                         plan.capacity, 1, WIDTH, LO], np.int64),
+                         plan.capacity, 2 if chunked else 1, WIDTH, LO],
+                        np.int64),
         padding_ratio=np.asarray([plan.padding_ratio], np.float64),
         src8=np.asarray(plan.src8), lane=np.asarray(plan.lane),
         off=np.asarray(plan.off), val=np.asarray(plan.val))
+    if chunked:
+        payload.update(chunk_block=np.asarray(plan.chunk_block, np.int32))
     if plan.ov_rows is not None:
         payload.update(ov_rows=np.asarray(plan.ov_rows),
                        ov_cols=np.asarray(plan.ov_cols),
@@ -687,10 +791,10 @@ def load_plan(path: str) -> EdgeSpMVPlan:
         meta = [int(v) for v in z["meta"]]
         n_rows, n_cols, block, cap = meta[:4]
         version, width, lo = (meta[4:7] if len(meta) >= 7 else (0, -1, -1))
-        if version != 1 or width != WIDTH or lo != LO:
+        if version not in (1, 2) or width != WIDTH or lo != LO:
             raise ValueError(
                 f"plan file {path!r} was saved with format v{version} "
-                f"(WIDTH={width}, LO={lo}); this build expects v1 "
+                f"(WIDTH={width}, LO={lo}); this build expects v1 or v2 "
                 f"(WIDTH={WIDTH}, LO={LO}) — rebuild the plan")
         has_ov = "ov_rows" in z.files
         return EdgeSpMVPlan(
@@ -699,4 +803,5 @@ def load_plan(path: str) -> EdgeSpMVPlan:
             ov_rows=jnp.asarray(z["ov_rows"]) if has_ov else None,
             ov_cols=jnp.asarray(z["ov_cols"]) if has_ov else None,
             ov_vals=jnp.asarray(z["ov_vals"]) if has_ov else None,
-            padding_ratio=float(z["padding_ratio"][0]))
+            padding_ratio=float(z["padding_ratio"][0]),
+            chunk_block=z["chunk_block"] if version == 2 else None)

@@ -196,6 +196,17 @@ def load() -> Optional[ctypes.CDLL]:
                 np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS"),
                 ctypes.c_int64,                        # ov_cap
             ]
+            lib.matrel_spmv_fill_ragged.restype = ctypes.c_int
+            lib.matrel_spmv_fill_ragged.argtypes = [
+                i64p, i64p, ctypes.c_void_p,          # rows, cols, vals|NULL
+                ctypes.c_int64, ctypes.c_int64,        # m, n_cols
+                ctypes.c_int64, ctypes.c_int64,        # block, nb
+                i64p, ctypes.c_int32,                  # first, width
+                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+                np.ctypeslib.ndpointer(np.int8, flags="C_CONTIGUOUS"),
+                np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+                np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS"),
+            ]
             _has_spmv = True
         except AttributeError as e:
             log.debug("native spmv-plan symbols unavailable: %s", e)
@@ -399,3 +410,35 @@ def spmv_fill(rows: np.ndarray, cols: np.ndarray,
     if got < 0 or got != n_overflow:
         return None
     return (src8, lane, off, val, ov_r[:got], ov_c[:got], ov_v[:got])
+
+
+def spmv_fill_ragged(rows: np.ndarray, cols: np.ndarray,
+                     vals: Optional[np.ndarray], n_cols: int, block: int,
+                     first: np.ndarray, width: int):
+    """The chunks layout's pass 2: block b owns the flat slots
+    ``first[b]:first[b + 1]``. Returns flat (src8, lane, off, val) and
+    three empty overflow arrays (the shape ``spmv_fill`` answers in),
+    or None."""
+    lib = load()
+    if lib is None or not getattr(lib, "_matrel_has_spmv", False):
+        return None
+    rows = np.ascontiguousarray(rows, dtype=np.int64)
+    cols = np.ascontiguousarray(cols, dtype=np.int64)
+    first = np.ascontiguousarray(first, dtype=np.int64)
+    slots = int(first[-1])
+    src8 = np.empty(slots, dtype=np.int32)
+    lane = np.empty(slots, dtype=np.int8)
+    off = np.empty(slots, dtype=np.int32)
+    val = np.empty(slots, dtype=np.float32)
+    if vals is not None:
+        vals = np.ascontiguousarray(vals, dtype=np.float32)
+        vptr = vals.ctypes.data_as(ctypes.c_void_p)
+    else:
+        vptr = None
+    rc = lib.matrel_spmv_fill_ragged(rows, cols, vptr, rows.shape[0],
+                                     n_cols, block, first.shape[0] - 1,
+                                     first, width, src8, lane, off, val)
+    if rc != 0:
+        return None
+    none = np.empty(0, dtype=np.int64)
+    return (src8, lane, off, val, none, none, np.empty(0, np.float32))

@@ -87,14 +87,21 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
     comparing the edge arrays with the copy the plan keeps (numpy
     arrays: ~8-15 ms at 10M edges, so an in-place edit is seen), or by
     identity when it is handed the same ``jax.Array`` objects (no pull
-    to the host).
+    to the host). On one device the compact executor takes a skewed
+    graph too (PR 33): its plan lies in fixed chunks of slots, a hub
+    block owning many (``build_spmv_plan`` ``layout="auto"``: no
+    overflow COO, ~1.04 slots an edge on a Graph500 Kronecker graph),
+    the matvec runs in panels that fit the device, and the plan gate
+    and cache reckon its real bytes; :func:`last_plan` says what ran.
     """
     if impl not in ("auto", "segment", "onehot"):
         raise ValueError(f"unknown impl {impl!r}")
     with trace_lib.entry("pagerank") as sp:
+        _LAST_PLAN.clear()
         out, path = _pagerank_edges(src, dst, n, rounds, alpha, mesh,
                                     impl, weights, passes)
         _PATH_COUNTS[path] += 1
+        _LAST_PLAN["impl"] = path
         sp.set(impl=path)
         return out
 
@@ -103,6 +110,22 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
 _PATH_COUNTS = dict.fromkeys(
     ("compact", "compact_sharded", "onehot", "onehot_sharded", "segment"),
     0)
+
+
+# What the newest pagerank_edges call ran on: ``impl`` and, where a
+# prepared plan answered, what its ``matrel.pagerank.plan`` span carries.
+_LAST_PLAN: dict = {}
+
+
+def last_plan() -> dict:
+    """The executor (``impl``) and the prepared plan's layout
+    (:func:`_plan_attrs`: ``layout``, ``edges``, ``slots``, ``chunks``,
+    ``chunk``, ``overflow_edges``, ``row_values``, ``panels``,
+    ``plan_bytes``, ``hit``; on a build by the one-device path also
+    ``build_s`` and ``upload_s``) of the newest :func:`pagerank_edges` call
+    — what its ``matrel.pagerank`` / ``matrel.pagerank.plan`` spans say
+    under a profiler session, for a caller outside one. A copy."""
+    return dict(_LAST_PLAN)
 
 
 def path_counts() -> dict:
@@ -156,10 +179,11 @@ def _pagerank_edges(src, dst, n, rounds, alpha, mesh, impl, weights,
         # stay on the segment path. Falls back when the degree
         # distribution is too heavy-tailed to pad, or when the expanded
         # tables would exceed the per-device HBM budget (~224 B/slot
-        # expanded, ~30 B/slot compact — _auto_max_slots picks;
+        # expanded, ~17 B/slot compact — _auto_max_slots picks;
         # the cap keeps auto from OOMing on huge graphs that the
         # 8 B/edge segment path handles fine).
         if on_tpu() and _host_fetchable(src) and _host_fetchable(dst):
+            why = []        # what build_spmv_plan refused the graph for
             if mesh is not None:
                 from matrel_tpu.config import pallas_enabled
                 if pallas_enabled():
@@ -167,25 +191,25 @@ def _pagerank_edges(src, dst, n, rounds, alpha, mesh, impl, weights,
                     out = _pagerank_compact_sharded(
                         src, dst, n, rounds, alpha, mesh,
                         max_slots=_auto_max_slots() * mesh.size,
-                        weights=weights, passes=passes)
+                        weights=weights, passes=passes, refusals=why)
                 else:
                     path = "onehot_sharded"
                     out = _pagerank_onehot_sharded(
                         src, dst, n, rounds, alpha, mesh,
                         max_slots=_PLAN_CACHE_MAX_SLOTS * mesh.size,
-                        weights=weights)
+                        weights=weights, refusals=why)
             else:
                 path = _single_device_path()
                 out = _pagerank_onehot(src, dst, n, rounds, alpha,
                                        max_slots=_auto_max_slots(),
-                                       weights=weights, passes=passes)
+                                       weights=weights, passes=passes,
+                                       refusals=why)
             if out is not None:
                 return out, path
             log.warning(
-                "pagerank_edges: the one-hot plan refused this graph "
-                "(degree tail cannot be padded, or the plan exceeds "
-                "%d slots); running the segment-sum path",
-                _auto_max_slots() * (mesh.size if mesh is not None else 1))
+                "pagerank_edges: the one-hot plan refused this graph (%s); "
+                "running the segment-sum path", "; ".join(why) or
+                "build_spmv_plan returned None")
     src = jnp.asarray(src, dtype=jnp.int32)
     dst = jnp.asarray(dst, dtype=jnp.int32)
     w = (jnp.ones_like(src, dtype=jnp.float32) if weights is None
@@ -210,7 +234,8 @@ def _dispatch(run, *args):
 
 
 def prepare_pagerank_onehot(src, dst, n: int, max_slots: int = None,
-                            weights=None):
+                            weights=None, layout: str = "blocks",
+                            refusals: Optional[list] = None):
     """Build the one-hot SpMV plan for a graph (ops/spmv.py), reusable
     across pagerank runs — plan construction is the expensive, per-graph
     step (host sort + pad, one device table expansion).
@@ -219,7 +244,10 @@ def prepare_pagerank_onehot(src, dst, n: int, max_slots: int = None,
     [i] for each edge i→j (w ≡ 1 unweighted) — so the plan is rows=dst,
     cols=src, vals=w/outdeg_w[src]; the normalisation rides the
     gather-select table for free. Returns (plan, dangling_mask), or None
-    when the plan refuses the graph (heavy-tailed padding).
+    when the plan refuses the graph (its padding, or ``max_slots``: the
+    reason is appended to ``refusals``). ``layout`` is
+    ``build_spmv_plan``'s: ``"auto"`` only for the compact executor on
+    one device, which alone walks chunks.
     """
     from matrel_tpu.ops import spmv as spmv_lib
 
@@ -237,7 +265,8 @@ def prepare_pagerank_onehot(src, dst, n: int, max_slots: int = None,
     plan = spmv_lib.build_spmv_plan(dst_np, src_np,
                                     vals=w * inv[src_np],
                                     n_rows=n, n_cols=n,
-                                    max_slots=max_slots)
+                                    max_slots=max_slots, layout=layout,
+                                    refusals=refusals)
     if plan is None:
         return None
     dangling = jnp.asarray((outdeg == 0).astype(np.float32))
@@ -306,14 +335,20 @@ def run_pagerank_compact(prepared, rounds: int = 30, alpha: float = 0.85,
 # already keeps; a plan has about a slot an edge or more, so the budget
 # below holds all single-device entries' copies to ~192 MB (288
 # weighted), and a sharded plan's grow with the mesh as its tables do.
-# Eviction is byte-aware in PER-DEVICE slots
-# (expanded one-hot tables are ~224 B per padded slot — the compact
-# executor's ~30 B/slot plans cost far less, so this budget is the
-# conservative worst case across both executors; sharded plans
-# spread theirs over mesh.size devices): pinning several multi-GB plans
-# would OOM a 16 GB chip, and plans above the budget run uncached.
+# Eviction is by PER-DEVICE bytes, each plan at what its executor really
+# holds: the expanded one-hot tables ~224 B per padded slot, the compact
+# executor's 13 B a slot of tables and 4 of slot weights (PR 33: until
+# then every plan was counted at the expanded price, and a 133M-slot
+# compact plan, 2.3 GB, ran uncached — rebuilt in every call); sharded
+# plans spread theirs over mesh.size devices. Pinning several multi-GB
+# plans would OOM a 16 GB chip, and plans above the budget run uncached.
 _PLAN_CACHE: list = []               # _CachedPlan, oldest first
-_PLAN_CACHE_MAX_SLOTS = 24_000_000   # ≈5.4 GB of expanded tables/device
+_PLAN_CACHE_MAX_SLOTS = 24_000_000   # the expanded path's gate: slots
+_EXPANDED_BYTES_A_SLOT = 224         # compact: pc.RESIDENT_BYTES_A_SLOT
+_PLAN_CACHE_MAX_BYTES = _PLAN_CACHE_MAX_SLOTS * _EXPANDED_BYTES_A_SLOT
+# the share of a device's memory a compact plan may take while it runs
+# (tables, slot weights and a panel's temporaries: pallas_spmv.plan_bytes)
+_PLAN_SHARE = 0.5
 # elements a comparison step: its temporary (a 64 KB mask) stays in the
 # heap and the cache, a first-chunk miss costs ~0.1 ms, and from 64K
 # elements up the whole compare runs at memory speed
@@ -325,7 +360,8 @@ class _CachedPlan(NamedTuple):
     kept: tuple         # the canonical copies: src, dst[, weights]
     refs: tuple         # per array: a weakref to the jax.Array, or None
     prepared: tuple
-    cost: int           # per-device slots
+    cost: int           # per-device bytes
+    attrs: Optional[dict] = None    # _plan_attrs, reckoned once a plan
 
 
 def _host_fetchable(a) -> bool:
@@ -379,10 +415,12 @@ def _recognise(arrays, key):
 
 
 def _cached_plan(src, dst, n: int, weights, tail: tuple, build,
-                 per_dev_slots_of):
+                 compact: bool, devices: int = 1):
     """The prepared plan of this graph for the caller ``tail`` names:
     the cached one, or ``build()``'s (None = refused), cached with its
-    cost in per-device slots unless that exceeds the budget."""
+    cost in per-device bytes (``compact``: the compact tables' price,
+    else the expanded ones', over ``devices``) unless that exceeds the
+    budget."""
     arrays = tuple(a if isinstance(a, (jax.Array, np.ndarray))
                    else np.asarray(a)
                    for a in (src, dst, weights) if a is not None)
@@ -390,25 +428,60 @@ def _cached_plan(src, dst, n: int, weights, tail: tuple, build,
            weights is not None) + tail
     entry = _recognise(arrays, key)
     with trace_lib.span("pagerank.plan") as sp:
-        sp.set(hit=entry is not None)
-        if entry is not None:
-            return entry.prepared
-        prepared = build()
-        if prepared is None:
-            return None
-        cost = per_dev_slots_of(prepared)
-        if cost <= _PLAN_CACHE_MAX_SLOTS:
-            total = sum(e.cost for e in _PLAN_CACHE)
-            while _PLAN_CACHE and total + cost > _PLAN_CACHE_MAX_SLOTS:
-                total -= _PLAN_CACHE.pop(0).cost
-            # the build accepted the ids (all in [0, n)), so int32 holds
-            kept = tuple(np.array(a, dtype=t) for a, t in zip(
-                arrays, (np.int32, np.int32, np.float32)))
-            refs = tuple(weakref.ref(a) if isinstance(a, jax.Array)
-                         else None for a in arrays)
-            _PLAN_CACHE.append(_CachedPlan(key, kept, refs, prepared,
-                                           cost))
+        hit = entry is not None
+        sp.set(hit=hit)
+        if hit:
+            prepared, attrs = entry.prepared, entry.attrs
+        else:
+            prepared = build()
+            if prepared is None:
+                return None
+            attrs = _plan_attrs(prepared[0], arrays[0].shape[0], compact,
+                                devices)
+            from matrel_tpu.ops.pallas_spmv import RESIDENT_BYTES_A_SLOT
+            cost = -(-_plan_slots(prepared) * (
+                RESIDENT_BYTES_A_SLOT if compact
+                else _EXPANDED_BYTES_A_SLOT) // devices)
+            if cost <= _PLAN_CACHE_MAX_BYTES:
+                total = sum(e.cost for e in _PLAN_CACHE)
+                while _PLAN_CACHE and total + cost > _PLAN_CACHE_MAX_BYTES:
+                    total -= _PLAN_CACHE.pop(0).cost
+                # the build accepted the ids (all in [0, n)), so int32
+                # holds
+                kept = tuple(np.array(a, dtype=t) for a, t in zip(
+                    arrays, (np.int32, np.int32, np.float32)))
+                refs = tuple(weakref.ref(a) if isinstance(a, jax.Array)
+                             else None for a in arrays)
+                _PLAN_CACHE.append(_CachedPlan(key, kept, refs, prepared,
+                                               cost, attrs))
+        sp.set(**attrs)
+        _LAST_PLAN.update(attrs, hit=hit)
         return prepared
+
+
+def _plan_attrs(plan, edges: int, compact: bool, devices: int = 1) -> dict:
+    """What ``matrel.pagerank.plan`` and :func:`last_plan` say of a
+    prepared plan, on a hit as on a build: the layout and its padding
+    (``slots`` over ``edges``), the table rows (``chunks``: chunks or
+    blocks) of ``chunk`` slots, the edges left to the scalar overflow
+    path, the values a gathered byte row holds, and what the compact
+    executor reckons of the device (``panels`` a matvec, ``plan_bytes``)
+    — the expanded executor gathers in one piece and holds ~224 B a
+    slot; both a device, of a plan sharded over ``devices``."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    from matrel_tpu.ops import spmv as spmv_lib
+    rows, cap = plan.src8.shape
+    mine = -(-rows // devices)          # table rows a device
+    per = pc.panel_rows(mine, cap) if compact else mine
+    return {"layout": "blocks" if plan.chunk_block is None else "chunks",
+            "edges": int(edges), "slots": int(rows * cap),
+            "chunks": int(rows), "chunk": int(cap),
+            "overflow_edges": (0 if plan.ov_rows is None
+                               else int(plan.ov_rows.shape[0])),
+            "row_values": spmv_lib._row_values(plan.n_cols),
+            "panels": -(-mine // per),
+            "plan_bytes": int(pc.plan_bytes(mine, cap) if compact else
+                              mine * cap * _EXPANDED_BYTES_A_SLOT)}
 
 
 def _plan_slots(prepared) -> int:
@@ -417,35 +490,52 @@ def _plan_slots(prepared) -> int:
 
 
 def _auto_max_slots() -> int:
-    """Plan-size gate for the auto path: when the compact executor will
-    run (~13 B/slot device-side) the budget is 8× the expanded path's
-    (whose ~224 B/slot sized _PLAN_CACHE_MAX_SLOTS). Must consult the
-    SAME gate as the executor choice — with use_pallas=False the
-    expanded tables run, and an 8× budget would admit ~43 GB plans."""
+    """Plan-size gate for the auto path. Where the compact executor will
+    run, the slots whose tables, slot weights (17 B a slot) and one
+    panel of gather temporaries stay under ``_PLAN_SHARE`` of what the
+    device hands out (245M slots on a v5e; PR 33 — until then a fixed
+    192M). Must consult the SAME gate as the executor choice — with
+    use_pallas=False the expanded tables run (~224 B/slot), and they
+    keep their own gate."""
     from matrel_tpu.config import pallas_enabled
     if pallas_enabled():
-        return _PLAN_CACHE_MAX_SLOTS * 8     # ~3 GB compact + host copy
+        from matrel_tpu.ops import pallas_spmv as pc
+        return int((_PLAN_SHARE - pc._PANEL_SHARE) * pc._hbm_limit()
+                   // pc.RESIDENT_BYTES_A_SLOT)
     return _PLAN_CACHE_MAX_SLOTS
 
 
 def _pagerank_onehot(src, dst, n: int, rounds: int, alpha: float,
                      max_slots: int = None, weights=None,
-                     passes: int = 3):
+                     passes: int = 3, refusals: Optional[list] = None):
     from matrel_tpu.config import pallas_enabled
+    compact = pallas_enabled()
 
     def build():
-        prepared = prepare_pagerank_onehot(src, dst, n,
-                                           max_slots=max_slots,
-                                           weights=weights)
-        if prepared is not None and pallas_enabled():
-            from matrel_tpu.ops import pallas_spmv as pc
-            pc.compact_tables(prepared[0])      # upload now, memoised
+        # the compact executor alone walks a plan laid out in chunks
+        with trace_lib.phase("pagerank.plan.build") as built:
+            prepared = prepare_pagerank_onehot(
+                src, dst, n, max_slots=max_slots, weights=weights,
+                layout="auto" if compact else "blocks", refusals=refusals)
+        if prepared is None:
+            return None
+        with trace_lib.phase("pagerank.plan.upload") as placed:
+            if compact:
+                from matrel_tpu.ops import pallas_spmv as pc
+                jax.block_until_ready(           # upload now, memoised
+                    pc.compact_tables(prepared[0]))
+        # what a first call spends before it compiles: last_plan says
+        _LAST_PLAN.update(build_s=round(built.dur_ms / 1e3, 3),
+                          upload_s=round(placed.dur_ms / 1e3, 3))
         return prepared
 
-    prepared = _cached_plan(src, dst, n, weights, (), build, _plan_slots)
+    # keyed by the executor: a plan built for the compact one may lie
+    # in chunks, which the expanded tables cannot be made from
+    prepared = _cached_plan(src, dst, n, weights,
+                            ("compact",) if compact else (), build, compact)
     if prepared is None:
         return None
-    if pallas_enabled():
+    if compact:
         # compact-table Pallas executor: faster and ~17× less HBM than
         # the expanded tables (BASELINE row 5). passes=3 (default) is
         # f32-faithful like the expanded path; callers may pass 2 for
@@ -457,7 +547,8 @@ def _pagerank_onehot(src, dst, n: int, rounds: int, alpha: float,
 
 def _pagerank_compact_sharded(src, dst, n: int, rounds: int, alpha: float,
                               mesh, max_slots: int = None, weights=None,
-                              passes: int = 3, interpret=None):
+                              passes: int = 3, interpret=None,
+                              refusals: Optional[list] = None):
     """Multi-chip PageRank over mesh-sharded COMPACT tables: each device
     holds ~13 B/slot / P and generates its scatter one-hots in VMEM
     (ops/pallas_spmv.py); the whole power iteration is one shard_map'd
@@ -468,15 +559,15 @@ def _pagerank_compact_sharded(src, dst, n: int, rounds: int, alpha: float,
     def build():
         prepared = prepare_pagerank_onehot(src, dst, n,
                                            max_slots=max_slots,
-                                           weights=weights)
+                                           weights=weights,
+                                           refusals=refusals)
         if prepared is None:
             return None
         pc.shard_compact_tables(prepared[0], mesh)   # place now
         return prepared
 
-    prepared = _cached_plan(
-        src, dst, n, weights, (mesh, "compact"), build,
-        lambda pr_: -(-_plan_slots(pr_) // (16 * mesh.size)))
+    prepared = _cached_plan(src, dst, n, weights, (mesh, "compact"), build,
+                            True, mesh.size)
     if prepared is None:
         return None
     plan, dangling = prepared
@@ -520,7 +611,8 @@ def _compact_sharded_loop(n: int, rounds: int, alpha: float, plan_static,
 
 
 def _pagerank_onehot_sharded(src, dst, n: int, rounds: int, alpha: float,
-                             mesh, max_slots: int = None, weights=None):
+                             mesh, max_slots: int = None, weights=None,
+                             refusals: Optional[list] = None):
     """Multi-chip one-hot PageRank: the whole power iteration runs inside
     ONE shard_map'd jitted program; each device owns a slice of
     destination blocks and the round ends in a tiled all_gather of r."""
@@ -531,15 +623,15 @@ def _pagerank_onehot_sharded(src, dst, n: int, rounds: int, alpha: float,
     def build():
         prepared = prepare_pagerank_onehot(src, dst, n,
                                            max_slots=max_slots,
-                                           weights=weights)
+                                           weights=weights,
+                                           refusals=refusals)
         if prepared is None:
             return None
         return (spmv_lib.shard_plan(prepared[0], mesh), prepared[1])
 
     # Mesh compares identity-precise: same-shaped meshes over different
     # devices must not share cached (device-committed) plans
-    prepared = _cached_plan(src, dst, n, weights, (mesh,), build,
-                            lambda pr_: -(-_plan_slots(pr_) // p))
+    prepared = _cached_plan(src, dst, n, weights, (mesh,), build, False, p)
     if prepared is None:
         return None
     plan, dangling = prepared
@@ -755,6 +847,30 @@ def pagerank_block_sparse(S, rounds: int = 30, alpha: float = 0.85,
         r = BlockMatrix.from_array(poststep(contrib.data, r.data),
                                    (n, 1), mesh, r.spec)
     return r.data[:n]
+
+
+def pagerank_reference_edges(src, dst, n: int, rounds: int = 30,
+                             alpha: float = 0.85) -> np.ndarray:
+    """The plain reference of :func:`pagerank_edges`, float64 on the
+    host: LDBC Graphalytics' PageRank over an edge list,
+
+        PR_0(v) = 1/n
+        PR_i(v) = (1 - d)/n + d * (sum_{u -> v} PR_{i-1}(u) / outdeg(u)
+                                   + (1/n) * sum_{w dangling} PR_{i-1}(w))
+
+    for ``rounds`` iterations, d = ``alpha``; a duplicate edge counts
+    twice, a dangling vertex (no out-edge) spreads its rank over all."""
+    import scipy.sparse as sp
+    src = np.asarray(src, np.int64)
+    dst = np.asarray(dst, np.int64)
+    outdeg = np.bincount(src, minlength=n).astype(np.float64)
+    inv = np.where(outdeg > 0, 1.0 / np.maximum(outdeg, 1e-30), 0.0)
+    at = sp.csr_matrix((inv[src], (dst, src)), shape=(n, n))
+    dangling = outdeg == 0
+    r = np.full(n, 1.0 / n)
+    for _ in range(rounds):
+        r = alpha * (at @ r + r[dangling].sum() / n) + (1 - alpha) / n
+    return r
 
 
 def pagerank_numpy_oracle(a, rounds=30, alpha=0.85):

@@ -11,6 +11,9 @@
 // tables in input order; edges past a block's capacity go to the overflow
 // COO, stably sorted by row (segment_sum wants sorted ids).
 //
+// Pass 2' (matrel_spmv_fill_ragged): the same scatter into blocks of
+// their own sizes (the chunks layout), no overflow.
+//
 // Slot order within a block differs from the numpy path (input order vs
 // row-sorted) — the one-hot contraction is order-agnostic, so the
 // contract (tests assert it) is equal spmv RESULTS, not byte-equal
@@ -94,6 +97,45 @@ int64_t matrel_spmv_fill(const int64_t* rows, const int64_t* cols,
         ov_vals[i] = vals ? vals[e] : 1.0f;
     }
     return n_ov;
+}
+
+// The chunks layout's fill: block b owns the flat slots
+// [first[b], first[b+1]) (its chunks, laid one after the other), sized
+// by Python from the counts so that nothing overflows. Same slot order
+// (input order within a block) and sentinels as matrel_spmv_fill.
+// Returns 0, or -1 on an index out of range or a block past its slots.
+int matrel_spmv_fill_ragged(const int64_t* rows, const int64_t* cols,
+                            const float* vals, int64_t m, int64_t n_cols,
+                            int64_t block, int64_t nb,
+                            const int64_t* first, int32_t width,
+                            int32_t* src8, int8_t* lane, int32_t* off,
+                            float* val) {
+    if (block <= 0 || nb <= 0 || width <= 0) return -1;
+    const int64_t slots = first[nb];
+    const int32_t sentinel8 = static_cast<int32_t>(n_cols / width);
+    const int8_t sentinel_lane = static_cast<int8_t>(n_cols % width);
+    for (int64_t s = 0; s < slots; ++s) {
+        src8[s] = sentinel8;
+        lane[s] = sentinel_lane;
+    }
+    std::memset(off, 0, sizeof(int32_t) * slots);
+    std::memset(val, 0, sizeof(float) * slots);
+
+    std::vector<int64_t> next(first, first + nb);
+    for (int64_t e = 0; e < m; ++e) {
+        const int64_t r = rows[e];
+        if (r < 0 || cols[e] < 0) return -1;
+        const int64_t b = r / block;
+        if (b >= nb) return -1;
+        const int64_t p = next[b]++;
+        if (p >= first[b + 1]) return -1;
+        const int64_t c = cols[e];
+        src8[p] = static_cast<int32_t>(c / width);
+        lane[p] = static_cast<int8_t>(c % width);
+        off[p] = static_cast<int32_t>(r % block);
+        val[p] = vals ? vals[e] : 1.0f;
+    }
+    return 0;
 }
 
 }  // extern "C"

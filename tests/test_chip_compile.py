@@ -142,6 +142,48 @@ def test_compact_spmv_sharded_2x2(mesh_2x2):
     assert "all-gather" in text
 
 
+# The Graph500 scale-22 plan of cell pagerank_g500_22_1c (PR 33): chunks of
+# spmv.CHUNK slots for 128.3M directed edges, 8 values a gathered row
+G500_NODES, G500_CHUNKS = 2_396_366, 64_976
+
+
+def _g500_loop(one_chip):
+    from matrel_tpu.workloads import pagerank
+    shp = (G500_CHUNKS, spmv_lib.CHUNK // pc.LANE, pc.LANE)
+    tables = tuple(_sds(one_chip, shp, dt) for dt in (
+        jnp.int32, jnp.int8, jnp.int32, jnp.float32)) + (
+        _sds(one_chip, (G500_CHUNKS,), jnp.int32),)     # chunk -> block
+    static = (G500_NODES, G500_NODES, BLOCK, spmv_lib.LO)
+    loop = pagerank._compact_runner_loop(G500_NODES, 10, 0.85, static, 0, 3,
+                                         False)
+    return _compile(loop, tables, (),
+                    _sds(one_chip, (G500_NODES,), jnp.float32))
+
+
+def test_chunked_pagerank_loop_runs_in_panels(one_chip):
+    """One gather over all 133M slots is a 17 GB temporary on a 15.75 GB
+    chip. In panels a round's temporaries are what ``plan_bytes``
+    reckons, the byte table stays in fast memory inside the panel loop,
+    and the chunk scatter's 65k-entry scalar prefetch compiles."""
+    compiled = _g500_loop(one_chip)
+    text = compiled.as_text()
+    slots = G500_CHUNKS * spmv_lib.CHUNK
+    per = pc.panel_rows(G500_CHUNKS, spmv_lib.CHUNK)
+    assert 1 < per < G500_CHUNKS
+    assert f"u8[{per * spmv_lib.CHUNK},32]" in text       # a panel's rows
+    assert f"u8[{slots},32]" not in text
+    stats = compiled.memory_analysis()
+    # what the gate and the plan cache reckon holds what the compiler
+    # takes: arguments (13 B a slot) and temporaries, within 5%
+    reckoned = pc.plan_bytes(G500_CHUNKS, spmv_lib.CHUNK)
+    taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 0.9 * reckoned < taken < 1.05 * reckoned, (reckoned, taken)
+    rows = G500_NODES // 8 + 1
+    assert spmv_lib._row_values(G500_NODES) == 8
+    assert re.search(rf"u8\[{rows},32\]\{{[^}}]*S\(1\)\}}", text)
+    assert "matrel_spmv_scatter_chunks" in text
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_spmm(one_chip, dtype):

@@ -1040,11 +1040,21 @@ class TestProfilerTier:
                                                  "how": "compare"})):
             names = [r["name"] for r in sorted(
                 kids, key=lambda r: r["start_ns"])]
+            # PR 33: a build times its host fill and its upload
             assert names == ["matrel.pagerank.fingerprint",
-                             "matrel.pagerank.plan",
+                             "matrel.pagerank.plan"] + (
+                [] if hit else ["matrel.pagerank.plan.build",
+                                "matrel.pagerank.plan.upload"]) + [
                              "matrel.pagerank.dispatch"]
             by = {r["name"]: r for r in kids}
-            assert by["matrel.pagerank.plan"]["attrs"] == {"hit": hit}
+            said = by["matrel.pagerank.plan"]["attrs"]
+            assert said["hit"] is hit
+            # PR 33: on a hit as on a build, the plan's layout
+            assert set(said) == {
+                "hit", "layout", "edges", "slots", "chunks", "chunk",
+                "overflow_edges", "row_values", "panels", "plan_bytes"}
+            assert said["layout"] == "blocks" and said["panels"] == 1
+            assert said["slots"] == said["chunks"] * said["chunk"]
             assert by["matrel.pagerank.fingerprint"]["attrs"] == known
         assert [r["name"] for r in seg] == ["matrel.pagerank.dispatch"]
 
@@ -1107,7 +1117,7 @@ class TestProfilerTier:
             and name.value.startswith("matrel_")
 
     def test_all_pallas_call_sites_were_found(self):
-        assert len(_PALLAS_SITES) == 8
+        assert len(_PALLAS_SITES) == 9      # PR 33: the chunk scatter
 
 
 class TestAnalyzeEvent:

@@ -869,3 +869,61 @@ def test_row_values_keep_the_byte_table_in_fast_memory(n, want):
     assert w == want
     if want < 32:
         assert (n // w + 1) * 128 <= 64 << 20
+
+
+
+def test_gather_1d_is_its_two_halves(rng):
+    """PR 33: a panelled matvec builds the byte table once and gathers
+    from it a panel at a time; together the halves are gather_1d."""
+    table = rng.standard_normal(1000).astype(np.float32)
+    idx = rng.integers(0, 1001, (3, 256)).astype(np.int32)
+    for width in (None, 2, 8):
+        rows = spmv_lib.byte_table(jnp.asarray(table), width)
+        w = width or spmv_lib._row_values(1000)
+        assert rows.dtype == jnp.uint8 and rows.shape == (1000 // w + 1,
+                                                          4 * w)
+        got = spmv_lib.gather_rows(rows, jnp.asarray(idx), jnp.float32)
+        want = spmv_lib.gather_1d(jnp.asarray(table), jnp.asarray(idx),
+                                  width=width)
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+        # a panel of the index array gathers the panel of the answer
+        np.testing.assert_array_equal(
+            _bits(spmv_lib.gather_rows(rows, jnp.asarray(idx[1:2]),
+                                       jnp.float32)), _bits(want[1:2]))
+
+
+@pytest.mark.parametrize("layout", ["blocks", "auto"])
+def test_default_layout_keeps_the_overflow_contract(layout):
+    """A hub below the small-plan threshold: blocks and an overflow COO,
+    whichever of the two layouts the caller allows; only "chunks" (or a
+    large plan under "auto") lays it out without one."""
+    rng = np.random.default_rng(3)
+    m = 20_000
+    rows = np.where(rng.random(m) < 0.3, 7, rng.integers(0, 4096, m))
+    cols = rng.integers(0, 512, m)
+    plan = spmv_lib.build_spmv_plan(rows, cols, n_rows=4096, n_cols=512,
+                                    layout=layout)
+    assert plan.chunk_block is None and plan.ov_rows is not None
+    chunks = spmv_lib.build_spmv_plan(rows, cols, n_rows=4096, n_cols=512,
+                                      layout="chunks")
+    assert chunks.chunk_block is not None and chunks.ov_rows is None
+    assert chunks.src8.shape[1] == spmv_lib.CHUNK
+
+
+def test_refusals_are_named():
+    """build_spmv_plan says which of its two gates refused."""
+    n_rows = 512 * 20_000
+    rows = np.arange(20_000, dtype=np.int64) * 512
+    cols = np.zeros(20_000, np.int64)
+    why = []
+    assert spmv_lib.build_spmv_plan(rows, cols, n_rows=n_rows, n_cols=1,
+                                    refusals=why) is None
+    assert why[0].startswith("padding: the blocks layout takes 2560000 ")
+    why = []
+    assert spmv_lib.build_spmv_plan(rows[:10], cols[:10], n_rows=5120,
+                                    n_cols=1, max_slots=100,
+                                    refusals=why) is None
+    assert why[0].startswith("bytes: the blocks layout takes 1280 slots")
+    for layout in ("chunks", "auto"):
+        assert spmv_lib.build_spmv_plan(rows, cols, n_rows=n_rows, n_cols=1,
+                                        layout=layout) is None

@@ -266,11 +266,17 @@ class TestPlanRecognition:
             g = tuple(None if a is None else jnp.asarray(a) for a in g)
         r1, spans = self._call(g)
         assert spans["pagerank.fingerprint"] == {"bytes": 0, "how": "new"}
-        assert spans["pagerank.plan"] == {"hit": False}
+        assert spans["pagerank.plan"]["hit"] is False
+        built = spans["pagerank.plan"]
         g2 = second(g)
         r2, spans = self._call(g2)
         assert spans["pagerank.fingerprint"]["how"] == how
-        assert spans["pagerank.plan"] == {"hit": how != "new"}
+        assert spans["pagerank.plan"]["hit"] is (how != "new")
+        if how != "new":    # a hit says of the plan what its build said
+            assert spans["pagerank.plan"] == {**built, "hit": True}
+            assert built["layout"] == "blocks" and built["edges"] == self.M
+            assert pr.last_plan() == {**spans["pagerank.plan"],
+                                      "impl": "onehot"}
         examined = spans["pagerank.fingerprint"]["bytes"]
         if how == "identity":
             assert examined == 0
