@@ -106,6 +106,11 @@ class Lowerer:
         # "pallas_spmm" / "pallas_spmv", or "xla" — complete once the
         # lowered function has been traced (plan.meta["executors"])
         self.executors: List[str] = []
+        # a long Gram and the product that rides its loop
+        # (planner.gram_riders: {uid: (gram, rider)} under both uids),
+        # and, while a trace runs, the riders' products by uid
+        self._riders: Dict[int, Tuple[MatExpr, MatExpr]] = {}
+        self._rode: Dict[int, Array] = {}
 
     def _ran(self, executor: str) -> None:
         if executor not in self.executors:
@@ -132,9 +137,13 @@ class Lowerer:
         common subexpressions (by node identity) are computed once — e.g.
         XᵀX and Xᵀy of the normal equations share the Xᵀ resharding."""
         leaf_pos = {l.uid: i for i, l in enumerate(leaf_order)}
+        for root in roots:          # a root at a time, as they are stamped
+            self._riders.update(planner.gram_riders(
+                root, self.mesh, self.config, self._dt_memo))
 
         def fn(*leaf_arrays: Array):
             memo: Dict[int, Array] = {}
+            self._rode = {}
             # analyze-mode bookkeeping: _eval recurses through ev, so a
             # node's wall-clock window CONTAINS its children's — track
             # child time per frame and report the EXCLUSIVE remainder
@@ -739,14 +748,27 @@ class Lowerer:
         operand is handed over by its dimension, untransposed. Where
         both operands are the same one (planner.long_gram) the panels
         multiply the upper block triangle alone
-        (strategies.gram_in_panels)."""
+        (strategies.gram_in_panels), and where a second product over
+        the same table rides that loop (planner.gram_riders) the pair
+        is evaluated once: whichever of the two is reached first runs
+        the loop, and the other's product waits in ``_rode``."""
         l, r = node.children
         if l.shape[1] < strategies.LONG_CONTRACTION:
             return None
-        gram = planner.long_gram(node, self.mesh, self.config,
-                                 self._dt_memo)
-        if gram is not None:
-            side, base = gram
+        gram, rider = self._riders.get(node.uid, (None, None))
+        if node is gram:
+            out, self._rode[rider.uid] = strategies.gram_in_panels(
+                ev(l.children[0]), 0, self.config,
+                rhs=ev(rider.children[1]))
+            return out
+        if node is rider:
+            ev(gram)
+            if node.uid in self._rode:  # else the Gram was lowered elsewhere
+                return self._rode[node.uid]
+        found = planner.long_gram(node, self.mesh, self.config,
+                                  self._dt_memo)
+        if found is not None:
+            side, base = found
             return strategies.gram_in_panels(
                 ev(base), 0 if side == "AtA" else 1, self.config)
         a, ca = (ev(l.children[0]), 0) if l.kind == "transpose" \

@@ -11,7 +11,7 @@ tests can assert it, mirroring the reference's Catalyst plan assertions.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 from jax.sharding import Mesh
@@ -20,7 +20,7 @@ from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib
 from matrel_tpu.ir.expr import MatExpr
 from matrel_tpu.parallel.strategies import (LONG_CONTRACTION, acc_itemsize,
-                                            gram_tiles,
+                                            gram_rider_room, gram_tiles,
                                             rmm_moves_under_dot, rmm_panels,
                                             rmm_transient_bytes)
 
@@ -1739,16 +1739,99 @@ def long_gram(node: MatExpr, mesh: Mesh,
     """:func:`gram_operand` of a product that is lowered as the upper
     block triangle of its panels (strategies.gram_in_panels): a float32
     Gram on one device under the plain local dot whose contraction is
-    LONG_CONTRACTION or longer. The ONE test the stamp (``gram_tiles``,
+    LONG_CONTRACTION or longer (``matmul_precision`` "high" lowers every
+    float32 Gram as ops/gram.py's two-pass split instead: none is one
+    there). The ONE test the stamp (``gram_tiles``,
     annotate_strategies) and the lowering (executor._long_contraction)
     both ask, so they cannot disagree."""
     gram = gram_operand(node)
     if (gram is None or mesh.size != 1
+            or (config or default_config()).matmul_precision == "high"
             or node.attrs.get("strategy", "xla") != "xla"
             or node.children[0].shape[1] < LONG_CONTRACTION
             or infer_dtype(gram[1], config, dtype_memo) != np.float32):
         return None
     return gram
+
+
+def _nodes(root: MatExpr) -> List[MatExpr]:
+    """Every node under ``root`` once, in evaluation order."""
+    out, seen = [], set()
+
+    def walk(n: MatExpr):
+        if n.uid in seen:
+            return
+        seen.add(n.uid)
+        for c in n.children:
+            walk(c)
+        out.append(n)
+
+    walk(root)
+    return out
+
+
+def gram_riders(root: MatExpr, mesh: Mesh,
+                config: Optional[MatrelConfig] = None,
+                dtype_memo: Optional[dict] = None
+                ) -> Dict[int, Tuple[MatExpr, MatExpr]]:
+    """{uid: (gram, rider)}, under both nodes' uids, of the products of
+    one plan that are lowered as ONE loop over their table
+    (strategies.gram_in_panels ``rhs``): a :func:`long_gram` ``t(X) *
+    X`` and a ``t(X) * B`` over the very same ``X``, float32 on the
+    plain local dot, whose ``B`` fits the lanes the Gram's last block
+    column leaves spare in its MXU tile (strategies.gram_rider_room: 24
+    columns at k = 1000, none where k is a multiple of 128) and is not
+    computed from the Gram. A Gram carries one product, the first in
+    evaluation order. Like :func:`long_gram`, the ONE test the stamps
+    (``gram_rides`` / ``rides_gram``, annotate_strategies) and the
+    lowering (executor._long_contraction) both ask."""
+    nodes = [n for n in _nodes(root) if n.kind == "matmul"]
+    pairs: Dict[int, Tuple[MatExpr, MatExpr]] = {}
+    for gram in nodes:
+        found = long_gram(gram, mesh, config, dtype_memo)
+        room = gram_rider_room(gram.shape[0])
+        if found is None or found[0] != "AtA" or not room:
+            continue
+        for rider in nodes:
+            l, r = rider.children
+            if (rider.uid not in pairs and l.kind == "transpose"
+                    and _same_operand(l.children[0], found[1])
+                    and r.shape[1] <= room
+                    and rider.attrs.get("strategy", "xla") == "xla"
+                    and rider.attrs.get("precision_tier") is None
+                    and infer_dtype(r, config, dtype_memo) == np.float32
+                    and gram not in _nodes(r)):
+                pairs[gram.uid] = pairs[rider.uid] = (gram, rider)
+                break
+    return pairs
+
+
+def _stamp_gram_riders(root: MatExpr, mesh: Mesh,
+                       config: Optional[MatrelConfig],
+                       dtype_memo: dict) -> MatExpr:
+    """The plan with :func:`gram_riders`' pairs stamped, the engagement
+    counter of the one-pass lowering: ``gram_rides`` (the columns
+    carried) on the Gram, ``rides_gram`` on the product that has no
+    loop of its own. A plan with no pair comes back as it is."""
+    pairs = gram_riders(root, mesh, config, dtype_memo)
+    if not pairs:
+        return root
+    done: Dict[int, MatExpr] = {}
+
+    def rebuild(n: MatExpr) -> MatExpr:
+        if n.uid not in done:
+            kids = tuple(rebuild(c) for c in n.children)
+            out = n if all(a is b for a, b in zip(kids, n.children)) \
+                else n.with_children(kids)
+            if n.uid in pairs:
+                gram, rider = pairs[n.uid]
+                out = out.with_attrs(**(
+                    {"rides_gram": True} if n is rider
+                    else {"gram_rides": rider.shape[1]}))
+            done[n.uid] = out
+        return done[n.uid]
+
+    return rebuild(root)
 
 
 def _folded_transpose(node: MatExpr, parent: Optional[MatExpr],
@@ -1910,6 +1993,8 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
         e = e.with_attrs(replicate=choose_join_scheme(
             e, mesh, config, layout_memo=lmemo,
             consumer_hint=_consumer_hint))
+    if is_root:
+        e = _stamp_gram_riders(e, mesh, config, memo)
     infer_dtype(e, config, memo)     # seed this (possibly new-uid) node
     infer_layout(e, mesh, lmemo, config)
     return e
@@ -1926,15 +2011,12 @@ def hbm_report(root: MatExpr) -> list:
     ``hbm_plan_bytes`` (the plan's peak on one device at that node),
     and on a long Gram lowered as its upper block triangle alone
     (:func:`long_gram`) ``gram_tiles``: the block products a panel
-    multiplies, of those the square holds."""
-    out, seen = [], set()
-
-    def walk(n: MatExpr):
-        if n.uid in seen:
-            return
-        seen.add(n.uid)
-        for c in n.children:
-            walk(c)
+    multiplies, of those the square holds; where that Gram's loop
+    carries a second product over its table (:func:`gram_riders`),
+    ``gram_rides`` (the columns carried) on the Gram and ``rides_gram``
+    on the product carried."""
+    out = []
+    for n in _nodes(root):
         if "hbm_plan_bytes" in n.attrs:
             out.append({"node": n.kind, "shape": list(n.shape),
                         "chosen": n.attrs.get("strategy", n.kind),
@@ -1944,8 +2026,9 @@ def hbm_report(root: MatExpr) -> list:
                         "hbm_plan_bytes": n.attrs["hbm_plan_bytes"]})
             if "gram_tiles" in n.attrs:
                 out[-1]["gram_tiles"] = list(n.attrs["gram_tiles"])
-
-    walk(root)
+            for stamp in ("gram_rides", "rides_gram"):
+                if stamp in n.attrs:
+                    out[-1][stamp] = n.attrs[stamp]
     return out
 
 
@@ -1962,20 +2045,9 @@ def refuse_over_limit(roots, mesh: Mesh,
     the compiler or the allocator."""
     if mesh.size != 1:
         return
-    seen = set()
-
-    def first_over(n: MatExpr) -> Optional[MatExpr]:
-        if n.uid in seen:
-            return None
-        seen.add(n.uid)
-        for c in n.children:
-            found = first_over(c)
-            if found is not None:
-                return found
-        return n if n.attrs.get("refused_hbm") else None
-
     for root in roots:
-        n = first_over(root)
+        n = next((n for n in _nodes(root) if n.attrs.get("refused_hbm")),
+                 None)
         if n is None:
             continue
         own = int(device_bytes(n, mesh, config))

@@ -163,8 +163,18 @@ def gram_tiles(k: int) -> Tuple[int, int]:
     return nb * (nb + 1) // 2, nb * nb
 
 
-def gram_in_panels(a, ca: int,
-                   config: Optional[MatrelConfig] = None) -> jax.Array:
+def gram_rider_room(k: int) -> int:
+    """How many right-hand-side columns ride a Gram of ``k`` columns
+    (:func:`gram_in_panels` ``rhs``): the lanes the last block column
+    leaves spare in its MXU tiles of 128, as far as there are columns
+    below the block to widen its slice into. 24 at k = 1000 (232 of
+    256 lanes), 0 where k is a multiple of 128 or one block."""
+    s, e = gram_blocks(k)[-1]
+    return min(-(e - s) % 128, s)
+
+
+def gram_in_panels(a, ca: int, config: Optional[MatrelConfig] = None,
+                   rhs=None):
     """The float32 Gram of ``a`` contracted with itself over its
     dimension ``ca`` (``t(a) * a`` for 0, ``a * t(a)`` for 1), in the
     panels of :func:`dot_in_panels`, each panel multiplying the upper
@@ -178,28 +188,60 @@ def gram_in_panels(a, ca: int,
     is symmetric bit for bit (the square's is not). ``a``
     comes as it lies and is sliced inside the loop: compiled for a v5e
     each block column is one convolution fusion that reads its two
-    slices of the table in place, in either layout of the table."""
+    slices of the table in place, in either layout of the table.
+
+    ``rhs`` (``(rows, m)`` float32, ``m`` at most
+    :func:`gram_rider_room`) are right-hand sides contracted over the
+    same rows. They ride the last block column: its right operand is
+    the table's slice widened by ``m`` columns on its low side with the
+    riders selected over those columns, its accumulator ``(k, w + m)``,
+    and the result is the pair (Gram, ``t(a) * rhs``) for ONE pass over
+    ``a``; every Gram entry is the sum of the same panels' dots as
+    without riders. Three ways to hand the riders to that dot, compiled
+    for a v5e at 2,555,904 x 1000 and m = 1 (PR 34; estimated cycles of
+    a panel's operations, 530,494 without riders): the select, fused
+    into the convolution with the slice read in place, 540,992 (m = 8:
+    540,892; m = 24: 549,788); ``concatenate([slice, y])``, fused as
+    well but with the 232 columns staged into fast memory first (a
+    ``dynamic-slice`` of 43,294 cycles), 579,630; and
+    :func:`dot_in_panels`' multiply-reduce as a loop-mate of the dots,
+    683,651: what it costs in a loop of its own, since one fusion runs
+    at a time."""
     free = 1 - ca
     k = a.shape[free]
     blocks = gram_blocks(k)
     dims = (((ca,), (ca,)), ((), ()))
     prec = _precision(config)
+    m = 0 if rhs is None else rhs.shape[1]
 
     def panel(start, rows):
         p = jax.lax.dynamic_slice_in_dim(a, start, rows, axis=ca)
+        rights = [jax.lax.slice_in_dim(p, s, e, axis=free)
+                  for s, e in blocks]
+        if m:
+            s, e = blocks[-1]
+            wide = jax.lax.slice_in_dim(p, s - m, e, axis=free)
+            riders = jax.lax.dynamic_slice_in_dim(rhs, start, rows, axis=0)
+            pad = [(0, 0), (0, 0)]
+            pad[free] = (0, e - s)
+            rights[-1] = jnp.where(
+                jax.lax.broadcasted_iota(jnp.int32, wide.shape, free) < m,
+                jnp.pad(riders.T if ca else riders, pad), wide)
         return tuple(
             jax.lax.dot_general(
-                jax.lax.slice_in_dim(p, 0, e, axis=free),
-                jax.lax.slice_in_dim(p, s, e, axis=free),
+                jax.lax.slice_in_dim(p, 0, e, axis=free), right,
                 dims, precision=prec, preferred_element_type=jnp.float32)
-            for s, e in blocks)
+            for (_, e), right in zip(blocks, rights))
 
-    cols = _sum_of_panels(
+    cols = list(_sum_of_panels(
         a.shape[ca], panel,
-        tuple(jnp.zeros((e, e - s), jnp.float32) for s, e in blocks))
+        tuple(jnp.zeros((e, e - s + (m if e == k else 0)), jnp.float32)
+              for s, e in blocks)))
+    rode, cols[-1] = cols[-1][:, :m], cols[-1][:, m:]
     upper = jnp.concatenate(
         [jnp.pad(c, ((0, k - c.shape[0]), (0, 0))) for c in cols], axis=1)
-    return jnp.where(jnp.tri(k, dtype=bool), upper.T, upper)
+    gram = jnp.where(jnp.tri(k, dtype=bool), upper.T, upper)
+    return gram if rhs is None else (gram, rode)
 
 
 def matmul_xla(a: jax.Array, b: jax.Array, mesh: Mesh,

@@ -422,17 +422,20 @@ def test_linreg_plan_fits_beside_the_table(linreg_program):
     row-major), the program's temporaries are what the plan reckons —
     the k x k Gram, Xᵀy, the solve's copies: megabytes — and arguments
     and temporaries fit the planner's budget. Neither layout makes the
-    loops over the contraction's panels copy the table."""
+    loop over the contraction's panels copy the table, and there is ONE
+    such loop (PR 34): t(X)·y rides t(X)·X's."""
     plan, compiled, tables = linreg_program
     mem = compiled.memory_analysis()
     n, k = LINREG_N, LINREG_K
     assert mem.argument_size_in_bytes == tables
     loops = [ln for ln in compiled.as_text().splitlines()
              if " while(" in ln and f"f32[{n},{k}]" in ln]
-    assert len(loops) == 2, loops       # t(X)·X and t(X)·y, in panels
+    assert len(loops) == 1, loops       # t(X)·X and t(X)·y, in panels
     # described tables are shapes, reckoned at their logical bytes
     residents, answer = n * k * 4 + n * 4, k * 4
     reckoned = plan.meta["hbm_plan_bytes"] - residents - answer
+    # the Gram and Xᵀy (the loop's accumulators: the last block column's
+    # holds Xᵀy's column), and the solve's copies of both
     assert reckoned == 2 * (k * k * 4 + k * 4)
     assert mem.temp_size_in_bytes <= 1.05 * reckoned
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
@@ -492,8 +495,37 @@ def test_linreg_gram_multiplies_the_triangle_only(linreg_program):
             for m, w in re.findall(
                 r"= f32\[(\d+),(\d+)\]\S*\s+convolution\(", line)]
            for reached in bodies.values()]
-    (gram,) = [o for o in ops if o]      # t(X)·y is a multiply-reduce
+    (gram,) = [o for o in ops if o]      # the solve's loops hold none
     assert len(gram) == len(strategies.gram_blocks(k))
     assert 0.5 < sum(gram) / (2 * rows * k * k) <= 0.66
     assert [dims for _, dims in _arrays_written(text, rows)
             if max(d for d in dims if d != rows) > 1] == []
+
+
+def test_linreg_rhs_rides_the_gram(linreg_program):
+    """t(X)·y has no loop of its own (PR 34): the plan says the pair,
+    the last block column's convolution is 233 wide (the table's 232
+    columns and y's, selected into the slice inside the fusion), no
+    multiply-reduce runs over a panel, and beside y's own slice of a
+    panel no array of a panel's length is written or staged (a
+    ``concatenate`` of the slice and y is fused as well, but stages the
+    232 columns first: ``dynamic-slice f32[8192,232]``)."""
+    plan, compiled, _ = linreg_program
+    assert [(p.get("gram_rides"), p.get("rides_gram"))
+            for p in plan.meta["products"]] == [
+        (1, None), (None, True), (None, None)]
+    text = compiled.as_text()
+    rows, k = strategies.ACC_PANEL_ROWS, LINREG_K
+    bodies, lines = _loop_bodies(text)
+    (body,) = [reached for reached in bodies.values()
+               if any(" convolution(" in line
+                      for name in reached for line in lines[name])]
+    in_loop = [line for name in body for line in lines[name]]
+    start, end = strategies.gram_blocks(k)[-1]
+    assert [int(w) for line in in_loop for w in re.findall(
+        rf"= f32\[{k},(\d+)\]\S*\s+convolution\(", line)] \
+        == [end - start + 1] == [233]
+    assert not [line for line in in_loop if " reduce(" in line]
+    assert "multiply_reduce" not in text
+    staged = [dims for _, dims in _arrays_written(text, rows)]
+    assert staged and all(sorted(dims) == [1, rows] for dims in staged)

@@ -244,39 +244,86 @@ def test_spans_say_rules_and_reckoning(one_device, data, tmp_path):
     assert len([r for r in mine if r["name"] == "matrel.compile"]) == 1
     # a table of 4,096 rows is no long contraction: nothing says a
     # triangle, in the records or the spans
-    assert not any("gram_tiles" in p for p in meta["products"] + strategy)
+    assert not any(stamp in p for p in meta["products"] + strategy
+                   for stamp in ("gram_tiles", "gram_rides", "rides_gram"))
 
 
 def test_records_and_spans_say_the_triangle(one_device, tmp_path):
     """On a table of LONG_CONTRACTION rows or more the Gram's record in
     ``plan.meta["products"]`` and its ``matrel.plan.strategy`` span
     carry ``gram_tiles``, the block products a panel multiplies of those
-    the square holds; ``t(X) * y`` and the solve carry none."""
+    the square holds, and (PR 34) ``gram_rides``, the one column of
+    ``t(X) * y`` its loop carries; ``t(X) * y`` says ``rides_gram``, the
+    solve carries none of them. The served query's theta is the float64
+    normal equations' to the 2e-5 of the short table's test."""
     from matrel_tpu.obs.trace import profile_spans
     rng = np.random.default_rng(32)
     n, k = strategies.LONG_CONTRACTION + 40, strategies.GRAM_BLOCK + 4
     x = rng.uniform(-1, 1, (n, k)).astype(np.float32)
-    y = rng.uniform(-1, 1, (n, 1)).astype(np.float32)
+    y = (x @ rng.standard_normal((k, 1)).astype(np.float32)
+         + 0.1 * rng.standard_normal((n, 1)).astype(np.float32))
     sess = session_of(one_device, x, y)
     before = len(profile_spans())
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        sess.compute(sess.sql(SPELLINGS[0])).to_numpy()
+        got = sess.compute(sess.sql(SPELLINGS[0])).to_numpy()
     finally:
         jax.profiler.stop_trace()
     meta = sess.compile(sess.sql(SPELLINGS[0])).meta
     spans = [r["attrs"] for r in profile_spans()[before:]
              if r["name"] == "matrel.plan.strategy"]
     for said in (meta["products"], spans):
-        assert [(p["node"], p["shape"], p.get("gram_tiles"))
+        assert [(p["node"], p["shape"], p.get("gram_tiles"),
+                 p.get("gram_rides"), p.get("rides_gram"))
                 for p in said] == [
-            ("matmul", [k, k], [3, 4]), ("matmul", [k, 1], None),
-            ("solve", [k, 1], None)]
+            ("matmul", [k, k], [3, 4], 1, None),
+            ("matmul", [k, 1], None, None, True),
+            ("solve", [k, 1], None, None, None)]
+    x64, y64 = x.astype(np.float64), y.astype(np.float64)
+    assert rel_err(got, np.linalg.solve(x64.T @ x64, x64.T @ y64)) < 2e-5
     assert strategies.gram_tiles(k) == (3, 4)
     assert strategies.gram_tiles(1000) == (10, 16)
     assert strategies.gram_tiles(strategies.GRAM_BLOCK) == (1, 1)
+
+
+# -- the executor: the pair is one loop, whichever is reached first -----------
+
+
+@pytest.mark.parametrize("first", ["gram", "rider"])
+def test_the_pair_is_one_loop_whichever_is_reached_first(one_device, first):
+    """A plan that reads ``t(X) * y`` and ``t(X) * X`` in either order
+    (``(t(X) * y) .* rowsum(t(X) * X)`` and the operands swapped, planned
+    and lowered without the rule batch, which would turn the row sum
+    into a matvec): one loop over the table's panels, the block columns'
+    dots and no other, and the value of the formula."""
+    from matrel_tpu import executor
+    rng = np.random.default_rng(34)
+    n, k = strategies.LONG_CONTRACTION + 40, strategies.GRAM_BLOCK + 4
+    x = rng.uniform(-1, 1, (n, k)).astype(np.float32)
+    y = rng.uniform(-1, 1, (n, 1)).astype(np.float32)
+    lx, ly = (E.leaf(BlockMatrix.from_array(
+        jnp.asarray(arr), arr.shape, one_device, P(None, None)))
+        for arr in (x, y))
+    gram = E.matmul(E.transpose(lx), lx).row_sum()
+    rider = E.matmul(E.transpose(lx), ly)
+    plan = planner.annotate_strategies(
+        gram.elem_multiply(rider) if first == "gram"
+        else rider.elem_multiply(gram), one_device, MatrelConfig())
+    said = [(p.get("gram_rides"), p.get("rides_gram"))
+            for p in planner.hbm_report(plan)]
+    assert said == ([(1, None), (None, True)] if first == "gram"
+                    else [(None, True), (1, None)])
+    fn = jax.jit(executor.Lowerer(one_device, MatrelConfig()).lower(
+        plan, E.leaves(plan)))
+    tables = [leaf.attrs["matrix"].data for leaf in E.leaves(plan)]
+    text = fn.lower(*tables).as_text()
+    assert text.count("stablehlo.while") == 1
+    assert text.count("dot_general") == 2 * len(strategies.gram_blocks(k))
+    x64, y64 = x.astype(np.float64), y.astype(np.float64)
+    want = (x64.T @ y64) * (x64.T @ x64).sum(axis=1, keepdims=True)
+    assert rel_err(np.asarray(fn(*tables))[:k, :1], want) < 1e-5
 
 
 # -- the executor: a long float32 contraction is accumulated in panels --------

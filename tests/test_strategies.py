@@ -978,3 +978,139 @@ class TestTopologyWeightedModel:
         _, _, k0 = s0._compile_entry(e)
         _, _, kw = sw._compile_entry(e)
         assert k0 != kw and kw.startswith("axisw:1x8|")
+
+
+# -- a long Gram's panel loop carries the right-hand sides (PR 34) ------------
+
+
+def _rel(got, want):
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+@pytest.mark.parametrize("k, m, tail, ca", [
+    (k, m, tail, 0)
+    for k in (1000, 520, 300) for m in (1, 8, 24) for tail in (0, 77)
+] + [(520, 8, 77, 1), (300, 1, 0, 1)])
+def test_gram_in_panels_carries_riders(k, m, tail, ca):
+    """One whole panel and a ragged tail (or none), the last block
+    column 232, 8 and 44 wide, ``m`` right-hand sides riding it. The
+    Gram's other block columns are the very same dots and equal the
+    rider-less Gram's bit for bit; the last one is a wider dot of the
+    same panels, equal to 1e-6 of the largest entry (on this CPU
+    backend bit for bit too in all but k = 300, m = 24, where a dot 68
+    wide is blocked otherwise than one 44 wide: 2.6e-7), and the result
+    stays symmetric to the last bit. The riders' columns are the
+    float64 product to 1e-6 of the largest entry (2.6e-7 at most here)
+    and ``dot_in_panels``' to 5e-6: alone, one column is a matrix-vector
+    product that the CPU adds up plainly, 2.5e-6 from float64 itself."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(1000 * k + 10 * m + tail + ca)
+    rows = strategies.ACC_PANEL_ROWS + tail
+    a = rng.uniform(-1, 1, (rows, k) if ca == 0 else (k, rows)) \
+        .astype(np.float32)
+    # the regression's right-hand sides: y = X theta* + noise
+    rhs = ((a if ca == 0 else a.T) @ rng.standard_normal((k, m))
+           + 0.1 * rng.standard_normal((rows, m))).astype(np.float32)
+    assert m <= strategies.gram_rider_room(k)
+    plain = np.asarray(jax.jit(
+        lambda u: strategies.gram_in_panels(u, ca))(jnp.asarray(a)))
+    gram, rode = jax.jit(
+        lambda u, v: strategies.gram_in_panels(u, ca, rhs=v))(
+            jnp.asarray(a), jnp.asarray(rhs))
+    assert gram.dtype == rode.dtype == jnp.float32
+    assert gram.shape == (k, k) and rode.shape == (k, m)
+    gram, rode = np.asarray(gram), np.asarray(rode)
+    below = strategies.gram_blocks(k)[-1][0]
+    assert np.array_equal(gram[:below, :below], plain[:below, :below])
+    assert _rel(gram, plain.astype(np.float64)) < 1e-6
+    assert np.array_equal(gram, gram.T)
+    alone = np.asarray(jax.jit(
+        lambda u, v: strategies.dot_in_panels(u, ca, v, 0))(
+            jnp.asarray(a), jnp.asarray(rhs)))
+    a64 = a.astype(np.float64)
+    want = (a64.T if ca == 0 else a64) @ rhs.astype(np.float64)
+    assert _rel(rode, want) < 1e-6
+    assert _rel(rode, alone.astype(np.float64)) < 5e-6
+
+
+def test_gram_rider_room_is_the_spare_lanes():
+    """The lanes the last block column leaves spare in its tiles of
+    128, as far as columns lie below it: none for one block, none where
+    the last block fills its tiles."""
+    assert [strategies.gram_rider_room(k)
+            for k in (1000, 520, 300, 257, 256, 130, 512, 896, 1024)] \
+        == [24, 120, 84, 127, 0, 0, 0, 0, 0]
+
+
+_RIDER_ROWS = strategies.LONG_CONTRACTION + 40
+_REGRESSION = "inv(t(X) * X) * t(X) * y"
+
+
+def _regression_plan(k, m, sql, dtype="float32", rows=_RIDER_ROWS,
+                     tall=True, **config):
+    """(the stamps of ``plan.meta["products"]``, the ``dot_general``s
+    and the ``while``s of the lowered text) of ``sql`` over described
+    tables X and Z (rows x k, or k x rows) and y (rows x m) on one CPU
+    device: shapes alone, nothing is allocated."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from matrel_tpu.core import mesh as mesh_lib
+    from matrel_tpu.session import MatrelSession
+    mesh = mesh_lib.make_mesh((1, 1), devices=jax.devices()[:1])
+    whole = NamedSharding(mesh, P(None, None))
+    sess = MatrelSession(mesh=mesh, config=MatrelConfig(**config))
+    table = (rows, k) if tall else (k, rows)
+    for name, shape in (("X", table), ("Z", table), ("y", (rows, m))):
+        sess.register(name, BlockMatrix.from_array(
+            jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=whole),
+            shape, mesh, P(None, None)))
+    plan = sess.compile(sess.sql(sql))
+    text = plan.jitted.lower(*[
+        jax.ShapeDtypeStruct(leaf.attrs["matrix"].shape, jnp.dtype(dtype),
+                             sharding=whole)
+        for leaf in plan.leaf_order]).as_text()
+    stamps = [(p.get("gram_tiles") is not None, p.get("gram_rides"),
+               p.get("rides_gram")) for p in plan.meta["products"]]
+    return stamps, text.count("dot_general"), text.count("stablehlo.while")
+
+
+@pytest.mark.parametrize("case, k, m, sql, tall, config, rides, program", [
+    ("one_column", 1000, 1, _REGRESSION, True, {}, 1, (8, 1)),
+    ("the_last_spare_lane", 1000, 24, _REGRESSION, True, {}, 24, (8, 1)),
+    ("one_column_too_many", 1000, 25, _REGRESSION, True, {}, None, (10, 2)),
+    ("no_spare_lane", 512, 1, _REGRESSION, True, {}, None, (6, 2)),
+    ("one_block", 200, 1, _REGRESSION, True, {}, None, (4, 2)),
+    ("side_AAt", 1000, 1, "inv(X * t(X)) * X * y", False, {}, None,
+     (10, 2)),
+    ("another_table", 1000, 1, "inv(t(X) * X) * t(Z) * y", True, {}, None,
+     (10, 2)),
+    ("bfloat16", 1000, 1, _REGRESSION, True, {"dtype": "bfloat16"}, None,
+     (2, 0)),
+    ("the_two_pass_split", 1000, 1, _REGRESSION, True,
+     {"matmul_precision": "high"}, None, (4, 1)),
+])
+def test_what_rides_a_long_gram(case, k, m, sql, tall, config, rides,
+                                program):
+    """The planner's test is of shapes: ``t(X) * y`` rides ``t(X) *
+    X``'s loop iff its columns fit the lanes the last block column
+    leaves spare (k = 1000: 24) over the very same float32 table. Where
+    it rides, the two products are ONE loop and the Gram's dots (a block
+    column each, in the loop and in the tail: 8 at k = 1000); where it
+    does not, both lower as they did: two loops and ``t(X) * y``'s own
+    two dots (a bfloat16 table: one dot each, no loop; under
+    ``matmul_precision`` "high" the Gram is the two-pass split's two
+    dots, no triangle, and ``t(X) * y`` keeps its loop). The solve's own
+    loops are counted on a short table."""
+    dtype = config.get("dtype", "float32")
+    config = {k: v for k, v in config.items() if k != "dtype"}
+    stamps, dots, loops = _regression_plan(k, m, sql, dtype, tall=tall,
+                                           **config)
+    _, _, solve_loops = _regression_plan(k, m, sql, dtype, rows=4096,
+                                         tall=tall, **config)
+    triangle = dtype == "float32" and "matmul_precision" not in config
+    if rides:
+        assert stamps == [(True, rides, None), (False, None, True),
+                          (False, None, None)]
+    else:
+        assert stamps == [(triangle, None, None)] + [(False, None, None)] * 2
+    assert (dots, loops - solve_loops) == program
