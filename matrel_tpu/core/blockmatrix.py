@@ -273,7 +273,20 @@ class BlockMatrix:
     def to_numpy(self) -> np.ndarray:
         """Gather to host, dropping padding."""
         with trace_lib.entry("fetch") as sp:
-            full = np.asarray(jax.device_get(self.data))
+            if sp.live:
+                # the record's split: was the answer there (a flag read);
+                # the wait for it, with the copy issued first as
+                # device_get issues it, so that it follows the answer
+                # with no Python between, as when dark; what the copy
+                # takes after the answer
+                sp.set(ready=self.data.is_ready())
+                with trace_lib.span("fetch.wait"):
+                    self.data.copy_to_host_async()
+                    self.data.block_until_ready()
+                with trace_lib.span("fetch.copy"):
+                    full = np.asarray(jax.device_get(self.data))
+            else:
+                full = np.asarray(jax.device_get(self.data))
             sp.set(bytes=full.nbytes)
             return full[: self.shape[0], : self.shape[1]]
 

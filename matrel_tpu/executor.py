@@ -1151,11 +1151,13 @@ class CompiledPlan:
                 donated.append(i)
             m = bound if bound is not None else l.attrs["matrix"]
             arrays.append(m.data)
-        if donate and donated and self.config.donate_intermediates:
-            out = self._donating_fn(tuple(donated))(*arrays,
-                                                    *self.extra_args)
-        else:
-            out = self.jitted(*arrays, *self.extra_args)
+        # jax's and the runtime's share of the session's ``dispatch``
+        with trace_lib.span("dispatch.launch"):
+            if donate and donated and self.config.donate_intermediates:
+                out = self._donating_fn(tuple(donated))(*arrays,
+                                                        *self.extra_args)
+            else:
+                out = self.jitted(*arrays, *self.extra_args)
         return BlockMatrix.from_array(
             out, self.optimized.shape, self.mesh,
             padding.canonical_spec(tuple(out.shape), self.mesh),
@@ -1274,11 +1276,12 @@ class MultiPlan:
                 donated.append(i)
             m = bound if bound is not None else l.attrs["matrix"]
             arrays.append(m.data)
-        if donate and donated and self.config.donate_intermediates:
-            outs = self._donating_fn(tuple(donated))(*arrays,
-                                                     *self.extra_args)
-        else:
-            outs = self.jitted(*arrays, *self.extra_args)
+        with trace_lib.span("dispatch.launch"):
+            if donate and donated and self.config.donate_intermediates:
+                outs = self._donating_fn(tuple(donated))(*arrays,
+                                                         *self.extra_args)
+            else:
+                outs = self.jitted(*arrays, *self.extra_args)
         return tuple(
             BlockMatrix.from_array(
                 out, root.shape, self.mesh,

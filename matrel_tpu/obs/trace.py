@@ -22,7 +22,9 @@ Four cost tiers, strictly ordered:
   construction — and one record ``{name, start_ns, end_ns, span_id,
   parent_id, qid, tid, attrs}`` in a process-wide bounded ring
   (:func:`profile_spans`; ``time.time_ns()``, the profiler's host
-  clock). No span adds a device sync.
+  clock). No span adds a device sync but ``fetch.wait``, where
+  ``device_get`` was about to make one. A pause of Python's collector
+  is a span of this tier too (``gc``, :func:`_on_gc`).
 - **Flight recorder only** (``config.obs_flight_recorder > 0``,
   obs off): spans are timed and appended to a bounded in-memory ring —
   no file I/O, no event assembly — so a field failure can dump the last
@@ -43,6 +45,7 @@ thread with the tracer; a span below it asks nothing.
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
 import os
@@ -71,13 +74,14 @@ _trace_annotation = None
 class _State:
     """One thread's tracing state, installed by its open entry span."""
 
-    __slots__ = ("live", "tracer", "profiled", "current")
+    __slots__ = ("live", "tracer", "profiled", "current", "gc")
 
     def __init__(self):
         self.live = False       # tracer is not None or profiled
         self.tracer = None      # the entry call's Tracer
         self.profiled = False   # the entry call found a profiler session
         self.current = None     # the innermost open live Span
+        self.gc = None          # the open span of a collection under way
 
 
 class _ThreadState(threading.local):
@@ -113,6 +117,7 @@ class _NoopSpan:
 
     __slots__ = ()
     dur_ms = None
+    live = False
 
     def __enter__(self):
         return self
@@ -215,6 +220,13 @@ class Span:
             self.tracer.emit_span(rec)
         return False
 
+    @property
+    def live(self) -> bool:
+        """Whether the open span records anything: false for the no-op
+        singleton and for a :func:`phase` that only times. A site asks
+        before work done for the record's sake (``to_numpy``'s split)."""
+        return self.span_id is not None
+
     def set(self, **attrs) -> "Span":
         """Attach attributes discovered mid-scope (e.g. cache hit)."""
         self.attrs.update(attrs)
@@ -243,6 +255,8 @@ def entry(name: str, tracer=_KEEP, **attrs):
         tracer = st.tracer
         if tracer is None and not profiled:
             return _NOOP
+    if profiled and not _gc_watched:
+        _watch_gc()
     return Span(name, tracer, attrs, profiled, True, st)
 
 
@@ -264,6 +278,55 @@ def phase(name: str, **attrs) -> Span:
     observability (the pre-span behaviour, one mechanism)."""
     st = _tls.st
     return Span(name, st.tracer, attrs, st.profiled, False, st)
+
+
+#: A pause of Python's cyclic collector, as a span of the profiler
+#: tier. The name's presence tells a reader that this program records
+#: them at all.
+GC_SPAN = "gc"
+_gc_watched = False
+_gc_watch_lock = lockdep.make_lock("obs.gc_watch")
+
+
+def _watch_gc() -> None:
+    """Put :func:`_on_gc` into ``gc.callbacks``: the first
+    :func:`entry` of a process that finds a profiler session, and the
+    first after the callback took itself out. Nothing is installed at
+    import or while dark."""
+    global _gc_watched
+    with _gc_watch_lock:
+        if not _gc_watched:
+            # the flag first: _on_gc clears it only after the append
+            _gc_watched = True
+            gc.callbacks.append(_on_gc)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry: under a profiler session a collection is
+    an entry span ``gc`` (``generation``, ``collected``) on the
+    collecting thread, a child of whatever span it interrupted or,
+    between two queries, a root. It goes to the profiler's trace and
+    the ring alone, never through a tracer: a collection starts at any
+    bytecode boundary, also inside the event log's or the flight ring's
+    critical section, and a sink that took that lock again would never
+    return. A collection that finds no session takes the callback out."""
+    global _gc_watched
+    st = _tls.st
+    if phase == "start":
+        sp = entry(GC_SPAN, None, generation=info["generation"])
+        if sp is _NOOP:
+            # the interpreter walks gc.callbacks by index: taking out
+            # any but the last would skip the one after it this once,
+            # so behind a later registrant this stays, at one dark
+            # entry() a collection
+            if gc.callbacks[-1] is _on_gc:
+                gc.callbacks.pop()
+                _gc_watched = False
+            return
+        st.gc = sp.__enter__()
+    elif st.gc is not None:
+        sp, st.gc = st.gc, None
+        sp.set(collected=info["collected"]).__exit__(None, None, None)
 
 
 class Tracer:

@@ -929,25 +929,39 @@ class TestProfilerTier:
         with self._trace(tmp_path):
             got = sess.compute(sess.sql(q)).to_numpy()
         np.testing.assert_array_equal(got, want)
-        recs = trace_lib.profile_spans()[before:]
+        # a collection that falls into the query is a span too (PR 35)
+        recs = [r for r in trace_lib.profile_spans()[before:]
+                if r["name"] != "matrel.gc"]
         by_name = {r["name"]: r for r in recs}
         assert set(by_name) == {"matrel.sql", "matrel.compute",
                                 "matrel.plan", "matrel.dispatch",
-                                "matrel.fetch"}
+                                "matrel.dispatch.launch", "matrel.fetch",
+                                "matrel.fetch.wait", "matrel.fetch.copy"}
         comp = by_name["matrel.compute"]
         assert comp["parent_id"] is None
         assert comp["attrs"] == {"root_kind": "agg", "path": "fast"}
-        for child in ("matrel.plan", "matrel.dispatch"):
+        # PR 35: what is beneath dispatch and fetch, each inside its own
+        for child, parent in (("matrel.plan", comp),
+                              ("matrel.dispatch", comp),
+                              ("matrel.dispatch.launch",
+                               by_name["matrel.dispatch"]),
+                              ("matrel.fetch.wait", by_name["matrel.fetch"]),
+                              ("matrel.fetch.copy", by_name["matrel.fetch"])):
             c = by_name[child]
-            assert c["parent_id"] == comp["span_id"]
-            assert c["qid"] == comp["qid"]
-            assert comp["start_ns"] <= c["start_ns"] <= c["end_ns"] \
-                <= comp["end_ns"]
+            assert c["parent_id"] == parent["span_id"]
+            assert c["qid"] == parent["qid"]
+            assert parent["start_ns"] <= c["start_ns"] <= c["end_ns"] \
+                <= parent["end_ns"]
+        assert by_name["matrel.fetch.wait"]["end_ns"] \
+            <= by_name["matrel.fetch.copy"]["start_ns"]
         assert by_name["matrel.plan"]["attrs"] == {"hit": True}
         assert by_name["matrel.dispatch"]["attrs"]["executors"] \
             == sess.compile(sess.sql(q)).meta["executors"]
         assert by_name["matrel.sql"]["attrs"] == {"chars": len(q)}
-        assert by_name["matrel.fetch"]["attrs"] == {"bytes": got.nbytes}
+        fetched = by_name["matrel.fetch"]["attrs"]
+        assert set(fetched) == {"ready", "bytes"}
+        assert type(fetched["ready"]) is bool
+        assert fetched["bytes"] == got.nbytes
         # roots of their own: three entry calls, three qids
         assert len({by_name[n]["qid"] for n in (
             "matrel.sql", "matrel.compute", "matrel.fetch")}) == 3
@@ -959,13 +973,75 @@ class TestProfilerTier:
         events = [(ev.name, dict(ev.stats))
                   for plane in ProfileData.from_file(path).planes
                   for line in plane.lines for ev in line.events
-                  if ev.name.startswith("matrel.")]
+                  if ev.name.startswith("matrel.")
+                  and ev.name != "matrel.gc"]
         assert sorted(n for n, _ in events) == sorted(by_name)
         assert {n: st["qid"] for n, st in events} \
             == {n: r["qid"] for n, r in by_name.items()}
         # and with the session over, the path is dark again
+        traced = len(trace_lib.profile_spans())
         sess.compute(sess.sql(q)).to_numpy()
-        assert len(trace_lib.profile_spans()) == before + 5
+        assert len(trace_lib.profile_spans()) == traced
+
+    def test_collector_pause_is_a_span_while_live(self, mesh8, rng,
+                                                  tmp_path):
+        """``gc.callbacks`` holds the callback from the first live entry
+        to the first collection that finds no session; a collection in
+        between is ``matrel.gc`` with ``generation`` and ``collected``,
+        a child of the span it interrupted or a root."""
+        import gc
+        from matrel_tpu.obs import trace as trace_lib
+        sess = self._sql_session(mesh8, rng)
+        q = "rowsum(A * B)"
+        sess.compute(sess.sql(q)).to_numpy()            # warm
+        gc.collect()        # one left by an earlier test takes itself out
+        assert trace_lib._on_gc not in gc.callbacks
+        before = len(trace_lib.profile_spans())
+
+        class Cycle:
+            def __init__(self):
+                self.me = self
+
+        with self._trace(tmp_path):
+            assert trace_lib._on_gc not in gc.callbacks  # no entry yet
+            sess.sql(q)
+            assert gc.callbacks.count(trace_lib._on_gc) == 1
+            Cycle()
+            gc.collect()                                # between queries
+            with trace_lib.entry("outer"):
+                gc.collect(0)                           # inside a span
+            assert gc.callbacks.count(trace_lib._on_gc) == 1
+        recs = trace_lib.profile_spans()[before:]
+        outer = next(r for r in recs if r["name"] == "matrel.outer")
+        full, young = [r for r in recs if r["name"] == "matrel.gc"
+                       and r["attrs"]["generation"] in (2, 0)][-2:]
+        assert full["attrs"]["generation"] == 2
+        assert full["attrs"]["collected"] >= 1 and full["parent_id"] is None
+        assert set(young["attrs"]) == {"generation", "collected"}
+        assert young["parent_id"] == outer["span_id"]
+        assert young["qid"] == outer["qid"]
+        assert outer["start_ns"] <= young["start_ns"] <= young["end_ns"] \
+            <= outer["end_ns"]
+        # the session is over: the next collection finds none
+        assert trace_lib._on_gc in gc.callbacks
+        traced = len(trace_lib.profile_spans())
+        gc.collect()
+        assert trace_lib._on_gc not in gc.callbacks
+        assert len(trace_lib.profile_spans()) == traced
+        # and behind a later registrant it stays until it is the last
+        # (the interpreter walks the list by index)
+        def later(phase, info):
+            pass
+        with self._trace(tmp_path / "again"):
+            sess.sql(q)
+        gc.callbacks.append(later)
+        try:
+            gc.collect()
+            assert trace_lib._on_gc in gc.callbacks
+        finally:
+            gc.callbacks.remove(later)
+        gc.collect()
+        assert trace_lib._on_gc not in gc.callbacks
 
     @pytest.mark.parametrize("entry", ["sql_compute_fetch", "run_many",
                                        "pagerank_edges"])
@@ -974,6 +1050,7 @@ class TestProfilerTier:
         """The structural twin of
         test_repeated_serve_path_creates_no_spans for the default
         deployment: no Span, no TraceAnnotation, nothing in the ring."""
+        import gc
         import jax
         from matrel_tpu.obs import trace as trace_lib
         from matrel_tpu.workloads import pagerank as pr
@@ -1007,8 +1084,17 @@ class TestProfilerTier:
         monkeypatch.setattr(trace_lib.Span, "__init__", poisoned)
         monkeypatch.setattr(jax.profiler.TraceAnnotation, "__init__",
                             no_program_annotation)
+        # PR 35: dark, to_numpy is the one statement it was (no flag
+        # read, no sync of its own) and nobody listens to the collector
+        array_type = type(sess.table("A").data)
+        monkeypatch.setattr(array_type, "is_ready", poisoned)
+        monkeypatch.setattr(array_type, "block_until_ready", poisoned)
+        gc.collect()        # a callback left by a traced test goes here
+        callbacks = list(gc.callbacks)
         np.testing.assert_array_equal(run(), want)
         assert len(trace_lib.profile_spans()) == before
+        assert gc.callbacks == callbacks
+        assert trace_lib._on_gc not in callbacks
 
     def test_pagerank_spans_and_path_counts(self, rng, tmp_path,
                                             monkeypatch):
@@ -1023,7 +1109,8 @@ class TestProfilerTier:
             for _ in range(2):
                 pr.pagerank_edges(src, dst, 300, rounds=3, impl="onehot")
             pr.pagerank_edges(src, dst, 300, rounds=3, impl="segment")
-        recs = trace_lib.profile_spans()[before:]
+        recs = [r for r in trace_lib.profile_spans()[before:]
+                if r["name"] != "matrel.gc"]    # a collection's, PR 35
         roots = [r for r in recs if r["name"] == "matrel.pagerank"]
         assert [r["attrs"]["impl"] for r in roots] \
             == ["onehot", "onehot", "segment"]
