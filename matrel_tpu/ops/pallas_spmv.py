@@ -31,6 +31,16 @@ Pipeline per matvec (``spmv_compact``):
      consecutive chunks of one block add into one resident (HI', LO)
      tile, zeroed on the block's first chunk — a hub block is many
      steps, not one tall tile, and nothing is left to an overflow COO.
+  2b. Pallas, where the plan has hub chunks (``plan.hubs``, PR 36): the
+     slots whose source is a hub skip step 1. ``x[hubs.ids]`` (32,768
+     values at the most, one small gather) lies in VMEM as a ``(M, 128)``
+     table, a
+     slot names its hub by rank, and a second chunk-grid kernel makes
+     ``w`` for a vector register of 1,024 slots with M lane permutes of
+     a table row (``take_along_axis``: ``vperm`` on the XLU), each kept
+     where the rank's row is that one, times ``val`` — then the same
+     ``_scatter_tile``, added onto step 2's block sums, which it takes
+     as its accumulator (aliased). Its ``w`` never exists in HBM.
   3. XLA: overflow-COO accumulation (blocks layout only; unchanged
      contract).
 
@@ -98,19 +108,59 @@ def _make_scatter_kernel(hi_n: int, lo: int, passes: int):
     return kernel
 
 
+def _first_chunk_of_its_block(cb_ref):
+    """Whether this grid step's chunk opens a block: consecutive chunks
+    of one block share the output tile (the index map reads the same
+    ``cb``)."""
+    c = pl.program_id(0)
+    return jnp.logical_or(c == 0,
+                          cb_ref[c] != cb_ref[jnp.maximum(c - 1, 0)])
+
+
 def _make_chunk_scatter_kernel(hi_n: int, lo: int, passes: int):
     def kernel(cb_ref, off_ref, w_ref, y_ref):
-        # consecutive chunks of one block share the output tile (the
-        # index map reads the same cb): zero it on the block's first
-        c = pl.program_id(0)
-        first = jnp.logical_or(
-            c == 0, cb_ref[c] != cb_ref[jnp.maximum(c - 1, 0)])
-
-        @pl.when(first)
+        # the block's tile starts from zero
+        @pl.when(_first_chunk_of_its_block(cb_ref))
         def _():
             y_ref[...] = jnp.zeros_like(y_ref)
 
         y_ref[0] += _scatter_tile(off_ref[0], w_ref[0], hi_n, lo, passes)
+
+    return kernel
+
+
+def _hub_weights(idx, table_ref, m_rows: int):
+    """``table[idx // 128, idx % 128]`` for a tile of slots, a vector
+    register of 8 x 128 at a time: a table row, broadcast over the
+    sublanes, permuted along the lanes by ``idx % 128`` and kept where
+    ``idx // 128`` names that row. The permute moves 32-bit lanes and
+    the select picks whole values, so a slot's weight is the table's
+    entry bit for bit; a padded slot (``idx`` = 128·M) matches no row
+    and weighs 0."""
+    parts = []
+    for s in range(0, idx.shape[0], 8):
+        lane, row = idx[s:s + 8] & (LANE - 1), idx[s:s + 8] >> 7
+        w = jnp.zeros(lane.shape, jnp.float32)
+        for m in range(m_rows):
+            took = jnp.take_along_axis(
+                jnp.broadcast_to(table_ref[m:m + 1, :], lane.shape), lane,
+                axis=1)
+            w = jnp.where(row == m, took, w)
+        parts.append(w)
+    return jnp.concatenate(parts, axis=0)
+
+
+def _make_hub_scatter_kernel(hi_n: int, lo: int, passes: int, m_rows: int):
+    def kernel(cb_ref, idx_ref, off_ref, val_ref, table_ref, acc_ref, y_ref):
+        # as the chunk scatter, but the block's tile starts from the
+        # main scatter's sums (``acc``, which the output aliases: a
+        # block with no hub chunk keeps them untouched)
+        @pl.when(_first_chunk_of_its_block(cb_ref))
+        def _():
+            y_ref[...] = acc_ref[...]
+
+        w = _hub_weights(idx_ref[0], table_ref, m_rows) * val_ref[0]
+        y_ref[0] += _scatter_tile(off_ref[0], w, hi_n, lo, passes)
 
     return kernel
 
@@ -165,12 +215,43 @@ def _chunk_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
     )
 
 
+@functools.lru_cache(maxsize=32)
+def _hub_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
+                passes: int, m_rows: int, interpret: bool):
+    """scatter(chunk_block, idx, off, val, table, acc) -> acc + the hub
+    chunks' block sums, (nb, HI', LO): the chunk scatter whose slot
+    weights come from the ``(m_rows, 128)`` hub table in VMEM."""
+    hi_n = block // lo
+    cr = chunk // LANE
+    slots = pl.BlockSpec((1, cr, LANE), lambda c, cb: (c, 0, 0))
+    sums = pl.BlockSpec((1, hi_n, lo), lambda c, cb: (cb[c], 0, 0))
+    return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
+        _make_hub_scatter_kernel(hi_n, lo, passes, m_rows),
+        name="matrel_spmv_scatter_hubs",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                       # chunk_block
+            grid=(n_chunks,),
+            in_specs=[slots, slots, slots,
+                      pl.BlockSpec((m_rows, LANE), lambda c, cb: (0, 0)),
+                      sums],
+            out_specs=sums,
+        ),
+        out_shape=jax.ShapeDtypeStruct((nb, hi_n, lo), jnp.float32),
+        input_output_aliases={5: 0},                     # acc
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )
+
+
 def compact_tables(plan: spmv_lib.EdgeSpMVPlan):
     """Device copies of the plan's compact layout, memoised on the plan
     (the plan keeps its compact host tables even after expanded-path
     use, so path order never matters): (src8, lane, off, val), and the
     chunk → block table as a fifth where the plan is laid out in
-    chunks — ``compact_apply`` knows the layout by it."""
+    chunks — ``compact_apply`` knows the layout by it — and after it
+    the plan's hub chunks, where it has any: (ids, idx, off, val,
+    chunk_block)."""
     dev = getattr(plan, "_compact_dev", None)
     if dev is None:
         nb, cap = np.asarray(plan.src8).shape
@@ -191,6 +272,12 @@ def compact_tables(plan: spmv_lib.EdgeSpMVPlan):
                    jnp.asarray(np.asarray(plan.val).reshape(shp)))
             if plan.chunk_block is not None:
                 dev += (jnp.asarray(plan.chunk_block, jnp.int32),)
+            if plan.hubs is not None:
+                hub = plan.hubs
+                dev += (jnp.asarray(hub.ids),) + tuple(
+                    jnp.asarray(np.asarray(a).reshape(-1, cr, LANE))
+                    for a in (hub.idx, hub.off, hub.val)) + (
+                    jnp.asarray(hub.chunk_block, jnp.int32),)
         plan._compact_dev = dev
     return dev
 
@@ -206,9 +293,16 @@ _TEMP_BYTES_A_SLOT = 224
 # what a plan holds a slot whatever the panels: the compact tables
 # (src8 4, lane 1, off 4, val 4) and the matvec's slot weights ``w`` (4)
 RESIDENT_BYTES_A_SLOT = 13 + 4
+# and a slot of its hub chunks (idx 4, off 4, val 4): their ``w`` is
+# made in VMEM, and they take no part in the panels
+HUB_BYTES_A_SLOT = 12
 # the share of the device's memory a panel's temporaries may take: the
 # tables, ``w`` and the caller's vectors have the rest
 _PANEL_SHARE = 0.25
+# XLA tiles a panel's fusions by the factors of its row count: 9,007
+# rows, a prime, made ``w``'s update walk a row at a time (11.7 ms a
+# panel by the described-v5e compile's cycle estimate; 9,024 rows 0.47)
+_PANEL_ROWS_MULTIPLE = 64
 
 
 def _hbm_limit() -> int:
@@ -225,19 +319,29 @@ def panel_rows(rows: int, cap: int) -> int:
     under ``_PANEL_SHARE`` of the device's memory, else the rows spread
     evenly over the fewest panels that do (the last panel is moved back
     to end with the tables, so an uneven split would gather its overlap
-    twice: 11.7% of a Graph500 scale-22 round, my chip run, PR 33)."""
+    twice: 11.7% of a Graph500 scale-22 round, my chip run, PR 33), up
+    to a multiple of ``_PANEL_ROWS_MULTIPLE`` where that still fits."""
     room = _PANEL_SHARE * _hbm_limit()
     most = max(1, int(room // (_TEMP_BYTES_A_SLOT * cap)))
-    return -(-rows // -(-rows // most))
+    if most >= rows:
+        return rows
+    per = -(-rows // -(-rows // most))
+    rounded = -(-per // _PANEL_ROWS_MULTIPLE) * _PANEL_ROWS_MULTIPLE
+    return rounded if rounded <= most else per
 
 
-def plan_bytes(rows: int, cap: int) -> int:
-    """What a compact plan of ``rows`` x ``cap`` slots holds of one
-    device while a matvec runs: the tables at 13 B a slot, ``w``, and
-    one panel's temporaries."""
-    slots = rows * cap
+def resident_bytes(slots: int, hub_slots: int = 0) -> int:
+    """What a compact plan holds of one device between matvecs and
+    through them: its tables and ``w``, and its hub chunks' tables."""
+    return RESIDENT_BYTES_A_SLOT * slots + HUB_BYTES_A_SLOT * hub_slots
+
+
+def plan_bytes(rows: int, cap: int, hub_slots: int = 0) -> int:
+    """What a compact plan of ``rows`` x ``cap`` slots, and ``hub_slots``
+    in hub chunks beside them, holds of one device while a matvec runs:
+    :func:`resident_bytes` and one panel's temporaries."""
     panel = panel_rows(rows, cap) * cap
-    return RESIDENT_BYTES_A_SLOT * slots + _TEMP_BYTES_A_SLOT * panel
+    return resident_bytes(rows * cap, hub_slots) + _TEMP_BYTES_A_SLOT * panel
 
 
 def _slot_weights(src8, lane, val, x: jax.Array) -> jax.Array:
@@ -269,16 +373,25 @@ def compact_apply(plan_static, tables, ov, x: jax.Array,
                   passes: int = 3, interpret: bool = False) -> jax.Array:
     """Traceable body: y = A·x from compact tables. ``plan_static`` is
     (n_rows, n_cols, block, lo); ``tables`` from compact_tables() (five
-    of them: the chunks layout); ``ov`` the overflow COO tuple (possibly
-    empty)."""
+    of them: the chunks layout; ten: with hub chunks); ``ov`` the
+    overflow COO tuple (possibly empty)."""
     n_rows, n_cols, block, lo = plan_static
-    src8, lane, off, val, *chunk_block = tables
+    src8, lane, off, val, *chunks = tables
     rows, cr, _ = src8.shape
     w = _slot_weights(src8, lane, val, x)
-    if chunk_block:
-        scatter = _chunk_runner(rows, cr * LANE, -(-n_rows // block), block,
-                                lo, passes, interpret)
-        y = scatter(chunk_block[0], off, w).reshape(-1)[:n_rows]
+    if chunks:
+        chunk_block, *hub = chunks
+        nb = -(-n_rows // block)
+        y = _chunk_runner(rows, cr * LANE, nb, block, lo, passes,
+                          interpret)(chunk_block, off, w)
+        if hub:
+            ids, idx, hub_off, hub_val, hub_block = hub
+            table = x.astype(jnp.float32).at[ids].get(
+                mode="promise_in_bounds").reshape(-1, LANE)
+            y = _hub_runner(idx.shape[0], cr * LANE, nb, block, lo, passes,
+                            table.shape[0], interpret)(
+                hub_block, idx, hub_off, hub_val, table, y)
+        y = y.reshape(-1)[:n_rows]
     else:
         scatter = _compact_runner(rows, cr * LANE, block, lo, passes,
                                   interpret)

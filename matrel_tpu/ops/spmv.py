@@ -45,7 +45,14 @@ reference's plan-per-query model. A plan has one of two layouts:
   every block takes 2.98 and still leaves 362k edges to the scalar tail.
   Only the compact-table Pallas matvec (ops/pallas_spmv.py, one device)
   takes it; the expanded tables, ``shard_plan`` and the k-wide kernels say
-  so by name.
+  so by name. Where the sources are skewed too (PR 36), the edges whose
+  source is among the ``128·M`` of largest out-degree, the *hubs*, lie in
+  a second set of chunks (``HubChunks``): a slot there names its source
+  by rank, and the matvec takes ``x`` for it from a ``(M, 128)`` table in
+  VMEM by lane permutes inside the scatter kernel, so these slots never
+  reach the row gather above (2.2 ns a slot on a v5e against 0.2 to 0.7).
+  On that Graph500 graph 32,768 of 2.4M sources hold 52% of the edges;
+  ``_hub_rows`` chooses M from the degrees, 0 on a flat graph.
 
 Either layout is refused (build returns None) when it pads past
 ``max_padding`` or ``max_slots``, so callers can use the plain path.
@@ -77,6 +84,22 @@ CHUNK = 2048     # slots a chunk of the ``chunks`` layout: one grid step of
 # the blocks layout, which every executor takes.
 _OVERFLOW_EDGE_SLOTS = 8
 _SMALL_PLAN_SLOTS = 1 << 20
+HUB_ROW = 128    # hubs a row of the hub table: the lanes of a vector register
+# The hub kernel walks the whole table for every register of slots: a row
+# costs each hub slot a permute and a select (4.5 bundles a 2,048 slots in
+# the static schedule, 3.8 ns a grid step on a v5e) and saves the row
+# gather's 2.2 ns for each of its own edges, so a row pays while its
+# edges, ``_HUB_ROW_PAYS`` times, outnumber the hub slots there would be
+# (PERF.md §6, PR 36: 1,180 by the chip's step times). ``_HUB_ROWS_MAX``
+# is the widest table the cell has been measured with: a Graph500
+# scale-22 round took 237 / 221 / 205 ms at 64 / 128 / 256 rows; left to
+# itself the rule stops at 277 rows on that graph and runs the same
+# (2.14 s a query both ways). No hubs where they would hold under
+# ``_HUB_MIN_SHARE`` of the edges: a second ragged set and a second
+# kernel for little.
+_HUB_ROW_PAYS = 1200
+_HUB_ROWS_MAX = 256
+_HUB_MIN_SHARE = 0.1
 
 # probed once at import (os.umask is process-global; toggling it per save
 # would race concurrent file creation in other threads)
@@ -180,6 +203,25 @@ def gather_rows(byte_rows: jax.Array, idx: jax.Array, dtype) -> jax.Array:
 
 
 @dataclasses.dataclass
+class HubChunks:
+    """The edges of a ``chunks`` plan whose source is a hub, in chunks of
+    their own (B = #hub chunks; a block without hub edges owns none):
+      ids          (128·M,) int32 — the hubs' column ids by falling edge
+                   count: entry k is row ``k // 128``, lane ``k % 128`` of
+                   the hub table ``x[ids]`` a matvec builds
+      idx          (B, CHUNK) int32 — the slot's hub, as its place in
+                   ``ids``; ``128·M`` in padded slots, which no table row
+                   answers, so they weigh 0 whatever ``x`` holds
+      off, val     (B, CHUNK) — as the plan's own
+      chunk_block  (B,) int32 — ascending."""
+    ids: np.ndarray
+    idx: np.ndarray
+    off: np.ndarray
+    val: np.ndarray
+    chunk_block: np.ndarray
+
+
+@dataclasses.dataclass
 class EdgeSpMVPlan:
     """Compiled layout for ``y[i] = Σ_{e: rows[e]=i} vals[e] · x[cols[e]]``.
 
@@ -191,7 +233,9 @@ class EdgeSpMVPlan:
     Shapes: B = #row blocks, C = per-block capacity — or, in the
     ``chunks`` layout (``chunk_block`` not None), B = #chunks, C = CHUNK,
     and ``chunk_block[i]`` is the row block chunk i adds into (ascending;
-    every block owns at least one chunk; no overflow).
+    every block owns at least one chunk; no overflow). ``hubs`` (chunks
+    layout only) holds the edges whose source is a hub; the tables below
+    then hold the others.
       src8    (B, C) int32 — width-row index of x per padded edge slot
       lane    (B, C) int8  — cols[e] % WIDTH
       off     (B, C) int32 — rows[e] % block
@@ -214,6 +258,7 @@ class EdgeSpMVPlan:
     ov_vals: Optional[jax.Array]
     padding_ratio: float
     chunk_block: Optional[np.ndarray] = None    # (B,) int32: chunks layout
+    hubs: Optional[HubChunks] = None
     _tables: Optional[tuple] = dataclasses.field(default=None, repr=False)
     _spmm_tables: Optional[tuple] = dataclasses.field(default=None,
                                                       repr=False)
@@ -313,7 +358,10 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
     ``layout="blocks"``: capacity is the ``capacity_quantile`` of
     per-block edge counts rounded up to a multiple of 128; edges past it
     go to the overflow COO. ``"chunks"``: every block owns as many
-    chunks of ``CHUNK`` slots as its edges need, and nothing overflows.
+    chunks of ``CHUNK`` slots as its edges need, and nothing overflows;
+    the edges from the sources of largest out-degree go to chunks of
+    their own (``plan.hubs``) where :func:`_hub_rows` finds the sources
+    skewed enough, a choice made from ``cols`` alone.
     ``"auto"`` (for a caller whose executor takes both: the compact
     Pallas matvec on one device) picks ``chunks`` where that walks
     fewer slots, an overflow edge counted as ``_OVERFLOW_EDGE_SLOTS``,
@@ -356,25 +404,37 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
             if (cnt > 0).any() else 0
         cap = max(128, -(-cap_q // 128) * 128)
     n_ov = int(np.maximum(cnt - cap, 0).sum())
-    owned = np.maximum(-(-cnt // CHUNK), 1)       # chunks a block owns
-    n_chunks = int(owned.sum())
     if layout == "auto":
         layout = "chunks" if (
             nb * cap + n_ov > _SMALL_PLAN_SLOTS
-            and n_chunks * CHUNK < nb * cap + _OVERFLOW_EDGE_SLOTS * n_ov
+            and int(_chunks_owned(cnt).sum()) * CHUNK
+            < nb * cap + _OVERFLOW_EDGE_SLOTS * n_ov
         ) else "blocks"
+    hub_ids = None
     if layout == "chunks":
-        n_rows_t, cap, n_ov = n_chunks, CHUNK, 0
-        first = np.zeros(nb + 1, np.int64)
-        np.cumsum(owned * CHUNK, out=first[1:])
+        hub_ids, hub_rank = _choose_hubs(cols, n_cols)
+        if hub_ids is not None:
+            hub_cnt = (native.spmv_counts_hubs(rows, cols, hub_rank, block,
+                                               nb) if use_native else None)
+            if hub_cnt is None:
+                hub_cnt = np.bincount(rows[hub_rank[cols] >= 0] // block,
+                                      minlength=nb)
+            cnt = cnt - hub_cnt
+            hub_first = _first_slots(_chunks_owned(hub_cnt, least=0))
+        owned = _chunks_owned(cnt)
+        n_rows_t, cap, n_ov = int(owned.sum()), CHUNK, 0
+        first = _first_slots(owned)
     else:
         n_rows_t = nb
         first = np.arange(nb + 1, dtype=np.int64) * cap
-    slots = n_rows_t * cap
+    hub_slots = 0 if hub_ids is None else int(hub_first[-1])
+    slots = n_rows_t * cap + hub_slots
     # Refuse only when padding hurts at scale: small plans are cheap no
     # matter the ratio, so the gate needs both the relative and an
     # absolute (1M padded slots) threshold. Callers fall back to the
-    # plain segment_sum path on None.
+    # plain segment_sum path on None. A hub slot holds less of the
+    # device than another (pallas_spmv.HUB_BYTES_A_SLOT) and is counted
+    # as one all the same.
     refused = None
     if m and slots > max_padding * m and slots > (1 << 20):
         refused = (f"padding: the {layout} layout takes {slots} slots for "
@@ -391,15 +451,35 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
     # Native single-pass counting-sort fill (O(m), no argsort — slot
     # order within a block is input order; the one-hot contraction is
     # order-agnostic so results match the numpy path)
-    filled = None
-    if use_native and layout == "chunks":
+    filled = hub_filled = None
+    if hub_ids is not None:
+        both = (native.spmv_fill_ragged_hubs(
+            rows, cols, vals, hub_rank, hub_ids.size, block, first,
+            hub_first, WIDTH) if use_native else None)
+        if both is not None:        # both sets in one walk over the edges
+            filled, hub_filled = both
+        else:
+            # the edge list split, each set filled on its own: the hub
+            # set over the table's columns, a value a "row" (width 1:
+            # ``src8`` comes back as the rank, ``len(hub_ids)`` in
+            # padded slots)
+            of_edge = hub_rank[cols]
+            at, rest = np.flatnonzero(of_edge >= 0), np.flatnonzero(of_edge < 0)
+            idx, _, hub_off, hub_val = _numpy_fill(
+                rows[at], of_edge[at].astype(np.int64),
+                None if vals is None else vals[at], hub_ids.size, block,
+                hub_first, hub_cnt, width=1)[:4]
+            hub_filled = idx, hub_off, hub_val
+            rows, cols = rows[rest], cols[rest]
+            vals = None if vals is None else vals[rest]
+    elif use_native and layout == "chunks":
         filled = native.spmv_fill_ragged(rows, cols, vals, n_cols, block,
                                          first, WIDTH)
     elif use_native:
         filled = native.spmv_fill(rows, cols, vals, n_cols, block, nb, cap,
                                   WIDTH, n_ov)
     if filled is None:
-        filled = _numpy_fill(rows, cols, vals, m, n_cols, block, first, cnt)
+        filled = _numpy_fill(rows, cols, vals, n_cols, block, first, cnt)
     src8, lane, off, val, ov_r64, ov_c64, ov_v = filled
 
     if n_ov:
@@ -408,6 +488,18 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
         ov_v = jnp.asarray(ov_v, jnp.float32)
     else:
         ov_c = ov_r = ov_v = None
+
+    hubs = None
+    if hub_ids is not None:
+        hub_shape = (hub_slots // CHUNK, CHUNK)
+        idx, hub_off, hub_val = hub_filled
+        hubs = HubChunks(
+            ids=hub_ids,
+            idx=np.ascontiguousarray(idx, np.int32).reshape(hub_shape),
+            off=np.ascontiguousarray(hub_off, np.int32).reshape(hub_shape),
+            val=np.ascontiguousarray(hub_val, np.float32).reshape(hub_shape),
+            chunk_block=np.repeat(np.arange(nb, dtype=np.int32),
+                                  np.diff(hub_first) // CHUNK))
 
     # compact tables stay host-side numpy; they move to device (default
     # placement or sharded via shard_plan) at expansion time
@@ -421,15 +513,70 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
         ov_cols=ov_c, ov_rows=ov_r, ov_vals=ov_v,
         padding_ratio=(slots + n_ov) / max(m, 1),
         chunk_block=(np.repeat(np.arange(nb, dtype=np.int32), owned)
-                     if layout == "chunks" else None))
+                     if layout == "chunks" else None),
+        hubs=hubs)
 
 
-def _numpy_fill(rows, cols, vals, m, n_cols, block, first, cnt):
+def _chunks_owned(cnt: np.ndarray, least: int = 1) -> np.ndarray:
+    """Chunks a block of ``cnt`` edges owns: as many as they need, and
+    at least ``least``."""
+    return np.maximum(-(-cnt // CHUNK), least)
+
+
+def _first_slots(owned: np.ndarray) -> np.ndarray:
+    """Block b's chunks are the flat slots ``first[b]:first[b + 1]``."""
+    first = np.zeros(owned.shape[0] + 1, np.int64)
+    np.cumsum(owned * CHUNK, out=first[1:])
+    return first
+
+
+def _hub_rows(deg_desc: np.ndarray, edges: int) -> int:
+    """How many rows of ``HUB_ROW`` the hub table gets, from the sources'
+    edge counts in falling order: rows are taken while one still pays
+    (see ``_HUB_ROW_PAYS``), ``_HUB_ROWS_MAX`` at the most, and none
+    where all of them would hold under ``_HUB_MIN_SHARE`` of the
+    edges."""
+    most = min(_HUB_ROWS_MAX, -(-int(np.count_nonzero(deg_desc)) // HUB_ROW))
+    if most == 0:
+        return 0
+    a_row = np.zeros(most * HUB_ROW, np.int64)
+    top = deg_desc[:a_row.size]
+    a_row[:top.size] = top
+    a_row = a_row.reshape(most, HUB_ROW).sum(1)
+    held = np.cumsum(a_row)
+    pays = a_row * _HUB_ROW_PAYS >= held
+    rows = most if pays.all() else int(np.argmin(pays))
+    return rows if held[rows - 1] >= _HUB_MIN_SHARE * edges else 0
+
+
+def _choose_hubs(cols: np.ndarray, n_cols: int):
+    """(the hubs' column ids by falling edge count, a whole number of
+    table rows; every column's place among them, −1 where it is no hub)
+    — or (None, None) where :func:`_hub_rows` takes no row. Ties fall
+    to the smaller id, so a graph has one answer."""
+    deg = np.bincount(cols, minlength=n_cols)
+    by_deg = np.argsort(-deg, kind="stable")[:_HUB_ROWS_MAX * HUB_ROW]
+    rows = _hub_rows(deg[by_deg], cols.shape[0])
+    if rows == 0:
+        return None, None
+    # a table row is whole: past the last source the ids name column 0,
+    # which no slot asks them for
+    ids = np.zeros(rows * HUB_ROW, np.int32)
+    real = by_deg[:ids.size]
+    ids[:real.size] = real
+    rank = np.full(n_cols, -1, np.int32)
+    rank[real] = np.arange(real.size, dtype=np.int32)
+    return ids, rank
+
+
+def _numpy_fill(rows, cols, vals, n_cols, block, first, cnt,
+                width: int = WIDTH):
     """Pure-numpy plan fill (fallback when the native library is
     unavailable): stable argsort by row, then fancy-indexed scatters.
     Block b owns the flat slots ``first[b]:first[b + 1]`` (one row of
     ``cap`` in the blocks layout, its chunks in the other); edges past
-    them are the overflow."""
+    them are the overflow. A column is a row of ``width`` and a lane."""
+    m = rows.shape[0]
     if vals is None:
         vals = np.ones((m,), np.float32)
     order = np.argsort(rows, kind="stable")
@@ -448,8 +595,8 @@ def _numpy_fill(rows, cols, vals, m, n_cols, block, first, cnt):
     src_pad[p_main] = cols_s[in_main]
     val_pad[p_main] = vals_s[in_main]
     off_pad[p_main] = rows_s[in_main] % block
-    return ((src_pad // WIDTH).astype(np.int32),
-            (src_pad % WIDTH).astype(np.int8),
+    return ((src_pad // width).astype(np.int32),
+            (src_pad % width).astype(np.int8),
             off_pad.astype(np.int32), val_pad,
             rows_s[~in_main], cols_s[~in_main], vals_s[~in_main])
 
@@ -756,15 +903,21 @@ def save_plan(path: str, plan: EdgeSpMVPlan) -> None:
         # into src8/lane/off at build time — loading under different
         # constants must fail loudly, not gather from wrong rows. The
         # chunks layout is version 2: a reader that knows only version 1
-        # would take chunk i for block i
+        # would take chunk i for block i; with hub chunks version 3: a
+        # reader of version 2 would leave their edges out
         meta=np.asarray([plan.n_rows, plan.n_cols, plan.block,
-                         plan.capacity, 2 if chunked else 1, WIDTH, LO],
+                         plan.capacity,
+                         3 if plan.hubs is not None else 2 if chunked else 1,
+                         WIDTH, LO],
                         np.int64),
         padding_ratio=np.asarray([plan.padding_ratio], np.float64),
         src8=np.asarray(plan.src8), lane=np.asarray(plan.lane),
         off=np.asarray(plan.off), val=np.asarray(plan.val))
     if chunked:
         payload.update(chunk_block=np.asarray(plan.chunk_block, np.int32))
+    if plan.hubs is not None:
+        payload.update({f"hub_{f.name}": getattr(plan.hubs, f.name)
+                        for f in dataclasses.fields(HubChunks)})
     if plan.ov_rows is not None:
         payload.update(ov_rows=np.asarray(plan.ov_rows),
                        ov_cols=np.asarray(plan.ov_cols),
@@ -791,10 +944,10 @@ def load_plan(path: str) -> EdgeSpMVPlan:
         meta = [int(v) for v in z["meta"]]
         n_rows, n_cols, block, cap = meta[:4]
         version, width, lo = (meta[4:7] if len(meta) >= 7 else (0, -1, -1))
-        if version not in (1, 2) or width != WIDTH or lo != LO:
+        if version not in (1, 2, 3) or width != WIDTH or lo != LO:
             raise ValueError(
                 f"plan file {path!r} was saved with format v{version} "
-                f"(WIDTH={width}, LO={lo}); this build expects v1 or v2 "
+                f"(WIDTH={width}, LO={lo}); this build expects v1 to v3 "
                 f"(WIDTH={WIDTH}, LO={LO}) — rebuild the plan")
         has_ov = "ov_rows" in z.files
         return EdgeSpMVPlan(
@@ -804,4 +957,7 @@ def load_plan(path: str) -> EdgeSpMVPlan:
             ov_cols=jnp.asarray(z["ov_cols"]) if has_ov else None,
             ov_vals=jnp.asarray(z["ov_vals"]) if has_ov else None,
             padding_ratio=float(z["padding_ratio"][0]),
-            chunk_block=z["chunk_block"] if version == 2 else None)
+            chunk_block=z["chunk_block"] if version >= 2 else None,
+            hubs=HubChunks(**{f.name: z[f"hub_{f.name}"]
+                              for f in dataclasses.fields(HubChunks)})
+            if version == 3 else None)

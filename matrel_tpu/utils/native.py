@@ -212,6 +212,31 @@ def load() -> Optional[ctypes.CDLL]:
             log.debug("native spmv-plan symbols unavailable: %s", e)
             _has_spmv = False
         lib._matrel_has_spmv = _has_spmv
+        try:
+            # the hub-chunk passes bind separately so a stale prebuilt
+            # lib still serves the plain fills
+            i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
+            i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
+            f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
+            lib.matrel_spmv_counts_hubs.restype = ctypes.c_int
+            lib.matrel_spmv_counts_hubs.argtypes = [
+                i64p, i64p, ctypes.c_int64, ctypes.c_int64,   # rows,cols,m,n
+                ctypes.c_int64, ctypes.c_int64, i32p, i64p]   # block,nb,rank
+            lib.matrel_spmv_fill_ragged_hubs.restype = ctypes.c_int
+            lib.matrel_spmv_fill_ragged_hubs.argtypes = [
+                i64p, i64p, ctypes.c_void_p,          # rows, cols, vals|NULL
+                ctypes.c_int64, ctypes.c_int64,        # m, n_cols
+                ctypes.c_int64, ctypes.c_int64,        # block, nb
+                i32p, ctypes.c_int32,                  # hub_rank, n_hubs
+                i64p, i64p, ctypes.c_int32,            # first, hub_first, width
+                i32p, np.ctypeslib.ndpointer(np.int8, flags="C_CONTIGUOUS"),
+                i32p, f32p,                            # src8, lane, off, val
+                i32p, i32p, f32p,                      # hub idx, off, val
+            ]
+            lib._matrel_has_spmv_hubs = _has_spmv
+        except AttributeError as e:
+            log.debug("native hub-chunk symbols unavailable: %s", e)
+            lib._matrel_has_spmv_hubs = False
         return _lib
 
 
@@ -442,3 +467,58 @@ def spmv_fill_ragged(rows: np.ndarray, cols: np.ndarray,
         return None
     none = np.empty(0, dtype=np.int64)
     return (src8, lane, off, val, none, none, np.empty(0, np.float32))
+
+
+def spmv_counts_hubs(rows: np.ndarray, cols: np.ndarray,
+                     hub_rank: np.ndarray, block: int, nb: int
+                     ) -> Optional[np.ndarray]:
+    """Per-block counts of the edges whose source is a hub
+    (``hub_rank[col] >= 0``); None if the native path is unavailable."""
+    lib = load()
+    if lib is None or not getattr(lib, "_matrel_has_spmv_hubs", False):
+        return None
+    rows = np.ascontiguousarray(rows, dtype=np.int64)
+    cols = np.ascontiguousarray(cols, dtype=np.int64)
+    hub_rank = np.ascontiguousarray(hub_rank, dtype=np.int32)
+    counts = np.zeros(nb, dtype=np.int64)
+    rc = lib.matrel_spmv_counts_hubs(rows, cols, rows.shape[0],
+                                     hub_rank.shape[0], block, nb, hub_rank,
+                                     counts)
+    return counts if rc == 0 else None
+
+
+def spmv_fill_ragged_hubs(rows: np.ndarray, cols: np.ndarray,
+                          vals: Optional[np.ndarray], hub_rank: np.ndarray,
+                          n_hubs: int, block: int, first: np.ndarray,
+                          hub_first: np.ndarray, width: int):
+    """The chunks layout's pass 2 with hub chunks, one walk over the
+    edges: an edge whose source has a rank goes to the hub tables, every
+    other to the main ones. Returns (the main tables in the shape
+    ``spmv_fill_ragged`` answers in, flat (hub_idx, hub_off, hub_val)),
+    or None."""
+    lib = load()
+    if lib is None or not getattr(lib, "_matrel_has_spmv_hubs", False):
+        return None
+    rows = np.ascontiguousarray(rows, dtype=np.int64)
+    cols = np.ascontiguousarray(cols, dtype=np.int64)
+    hub_rank = np.ascontiguousarray(hub_rank, dtype=np.int32)
+    first = np.ascontiguousarray(first, dtype=np.int64)
+    hub_first = np.ascontiguousarray(hub_first, dtype=np.int64)
+    slots, hub_slots = int(first[-1]), int(hub_first[-1])
+    main = (np.empty(slots, np.int32), np.empty(slots, np.int8),
+            np.empty(slots, np.int32), np.empty(slots, np.float32))
+    hub = (np.empty(hub_slots, np.int32), np.empty(hub_slots, np.int32),
+           np.empty(hub_slots, np.float32))
+    if vals is not None:
+        vals = np.ascontiguousarray(vals, dtype=np.float32)
+        vptr = vals.ctypes.data_as(ctypes.c_void_p)
+    else:
+        vptr = None
+    rc = lib.matrel_spmv_fill_ragged_hubs(
+        rows, cols, vptr, rows.shape[0], hub_rank.shape[0], block,
+        first.shape[0] - 1, hub_rank, n_hubs, first, hub_first, width,
+        *main, *hub)
+    if rc != 0:
+        return None
+    none = np.empty(0, dtype=np.int64)
+    return main + (none, none, np.empty(0, np.float32)), hub

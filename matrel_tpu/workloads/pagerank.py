@@ -92,7 +92,11 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
     block owning many (``build_spmv_plan`` ``layout="auto"``: no
     overflow COO, ~1.04 slots an edge on a Graph500 Kronecker graph),
     the matvec runs in panels that fit the device, and the plan gate
-    and cache reckon its real bytes; :func:`last_plan` says what ran.
+    and cache reckon its real bytes; where the sources are skewed as
+    well, the edges from the few of largest out-degree take their rank
+    from a table in VMEM inside the scatter kernel (PR 36: the plan's
+    hub chunks, chosen by the build from the degrees);
+    :func:`last_plan` says what ran.
     """
     if impl not in ("auto", "segment", "onehot"):
         raise ValueError(f"unknown impl {impl!r}")
@@ -121,7 +125,8 @@ def last_plan() -> dict:
     """The executor (``impl``) and the prepared plan's layout
     (:func:`_plan_attrs`: ``layout``, ``edges``, ``slots``, ``chunks``,
     ``chunk``, ``overflow_edges``, ``row_values``, ``panels``,
-    ``plan_bytes``, ``hit``; on a build by the one-device path also
+    ``plan_bytes``, ``hubs``, ``hub_slots``, ``hub_chunks``, ``hit``; on
+    a build by the one-device path also
     ``build_s`` and ``upload_s``) of the newest :func:`pagerank_edges` call
     — what its ``matrel.pagerank`` / ``matrel.pagerank.plan`` spans say
     under a profiler session, for a caller outside one. A copy."""
@@ -438,10 +443,11 @@ def _cached_plan(src, dst, n: int, weights, tail: tuple, build,
                 return None
             attrs = _plan_attrs(prepared[0], arrays[0].shape[0], compact,
                                 devices)
-            from matrel_tpu.ops.pallas_spmv import RESIDENT_BYTES_A_SLOT
-            cost = -(-_plan_slots(prepared) * (
-                RESIDENT_BYTES_A_SLOT if compact
-                else _EXPANDED_BYTES_A_SLOT) // devices)
+            from matrel_tpu.ops.pallas_spmv import resident_bytes
+            hub_slots = attrs["hub_slots"]      # 0 off the compact path
+            own = attrs["slots"] - hub_slots
+            cost = -(-(resident_bytes(own, hub_slots) if compact
+                       else own * _EXPANDED_BYTES_A_SLOT) // devices)
             if cost <= _PLAN_CACHE_MAX_BYTES:
                 total = sum(e.cost for e in _PLAN_CACHE)
                 while _PLAN_CACHE and total + cost > _PLAN_CACHE_MAX_BYTES:
@@ -462,31 +468,36 @@ def _cached_plan(src, dst, n: int, weights, tail: tuple, build,
 def _plan_attrs(plan, edges: int, compact: bool, devices: int = 1) -> dict:
     """What ``matrel.pagerank.plan`` and :func:`last_plan` say of a
     prepared plan, on a hit as on a build: the layout and its padding
-    (``slots`` over ``edges``), the table rows (``chunks``: chunks or
-    blocks) of ``chunk`` slots, the edges left to the scalar overflow
-    path, the values a gathered byte row holds, and what the compact
+    (``slots``, the hub chunks' among them, over ``edges``), the table
+    rows (``chunks``: chunks or blocks) of ``chunk`` slots that go
+    through the row gather, the edges left to the scalar overflow
+    path, the values a gathered byte row holds, what the compact
     executor reckons of the device (``panels`` a matvec, ``plan_bytes``)
     — the expanded executor gathers in one piece and holds ~224 B a
-    slot; both a device, of a plan sharded over ``devices``."""
+    slot; both a device, of a plan sharded over ``devices`` — and the
+    hub table (``hubs`` sources, 0 where the build chose none) with the
+    ``hub_chunks`` of ``hub_slots`` whose edges come from it."""
     from matrel_tpu.ops import pallas_spmv as pc
     from matrel_tpu.ops import spmv as spmv_lib
     rows, cap = plan.src8.shape
     mine = -(-rows // devices)          # table rows a device
     per = pc.panel_rows(mine, cap) if compact else mine
+    hub = plan.hubs
+    hub_chunks = 0 if hub is None else int(hub.idx.shape[0])
     return {"layout": "blocks" if plan.chunk_block is None else "chunks",
-            "edges": int(edges), "slots": int(rows * cap),
+            "edges": int(edges), "slots": int((rows + hub_chunks) * cap),
             "chunks": int(rows), "chunk": int(cap),
             "overflow_edges": (0 if plan.ov_rows is None
                                else int(plan.ov_rows.shape[0])),
             "row_values": spmv_lib._row_values(plan.n_cols),
             "panels": -(-mine // per),
-            "plan_bytes": int(pc.plan_bytes(mine, cap) if compact else
-                              mine * cap * _EXPANDED_BYTES_A_SLOT)}
+            "plan_bytes": int(pc.plan_bytes(mine, cap, hub_chunks * cap)
+                              if compact else
+                              mine * cap * _EXPANDED_BYTES_A_SLOT),
+            "hubs": 0 if hub is None else int(hub.ids.shape[0]),
+            "hub_slots": hub_chunks * cap, "hub_chunks": hub_chunks}
 
 
-def _plan_slots(prepared) -> int:
-    plan, _ = prepared
-    return plan.src8.shape[0] * plan.src8.shape[1]
 
 
 def _auto_max_slots() -> int:
@@ -494,9 +505,10 @@ def _auto_max_slots() -> int:
     run, the slots whose tables, slot weights (17 B a slot) and one
     panel of gather temporaries stay under ``_PLAN_SHARE`` of what the
     device hands out (245M slots on a v5e; PR 33 — until then a fixed
-    192M). Must consult the SAME gate as the executor choice — with
-    use_pallas=False the expanded tables run (~224 B/slot), and they
-    keep their own gate."""
+    192M; a slot of a hub chunk holds 12 B and is counted as any
+    other: the gate errs to the safe side). Must consult the SAME gate
+    as the executor choice — with use_pallas=False the expanded tables
+    run (~224 B/slot), and they keep their own gate."""
     from matrel_tpu.config import pallas_enabled
     if pallas_enabled():
         from matrel_tpu.ops import pallas_spmv as pc

@@ -14,6 +14,11 @@
 // Pass 2' (matrel_spmv_fill_ragged): the same scatter into blocks of
 // their own sizes (the chunks layout), no overflow.
 //
+// With hub chunks (PR 36: matrel_spmv_counts_hubs, matrel_spmv_fill_ragged_hubs)
+// the same two passes send an edge whose source has a hub rank to a second
+// set of ragged tables (rank, off, val) and every other edge to the main
+// ones, in one walk over the edge list: no partitioned copy of it.
+//
 // Slot order within a block differs from the numpy path (input order vs
 // row-sorted) — the one-hot contraction is order-agnostic, so the
 // contract (tests assert it) is equal spmv RESULTS, not byte-equal
@@ -134,6 +139,81 @@ int matrel_spmv_fill_ragged(const int64_t* rows, const int64_t* cols,
         lane[p] = static_cast<int8_t>(c % width);
         off[p] = static_cast<int32_t>(r % block);
         val[p] = vals ? vals[e] : 1.0f;
+    }
+    return 0;
+}
+
+// Pass 1 of a plan with hub chunks: per-block counts of the edges whose
+// source is a hub (hub_rank[col] >= 0; hub_rank has n_cols entries).
+// Returns 0, or -1 on an index out of range.
+int matrel_spmv_counts_hubs(const int64_t* rows, const int64_t* cols,
+                            int64_t m, int64_t n_cols, int64_t block,
+                            int64_t nb, const int32_t* hub_rank,
+                            int64_t* counts) {
+    if (block <= 0 || nb <= 0) return -1;
+    std::memset(counts, 0, sizeof(int64_t) * nb);
+    for (int64_t e = 0; e < m; ++e) {
+        const int64_t r = rows[e], c = cols[e];
+        if (r < 0 || c < 0 || c >= n_cols) return -1;
+        const int64_t b = r / block;
+        if (b >= nb) return -1;
+        if (hub_rank[c] >= 0) counts[b]++;
+    }
+    return 0;
+}
+
+// Pass 2 of a plan with hub chunks, one walk over the edges: an edge whose
+// source has a rank goes to the hub tables (block b owns their flat slots
+// [hub_first[b], hub_first[b+1]); hub_idx = the rank, n_hubs in padded
+// slots), every other to the main tables exactly as
+// matrel_spmv_fill_ragged fills them. Returns 0, or -1 on an index out of
+// range or a block past its slots in either set.
+int matrel_spmv_fill_ragged_hubs(const int64_t* rows, const int64_t* cols,
+                                 const float* vals, int64_t m,
+                                 int64_t n_cols, int64_t block, int64_t nb,
+                                 const int32_t* hub_rank, int32_t n_hubs,
+                                 const int64_t* first,
+                                 const int64_t* hub_first, int32_t width,
+                                 int32_t* src8, int8_t* lane, int32_t* off,
+                                 float* val, int32_t* hub_idx,
+                                 int32_t* hub_off, float* hub_val) {
+    if (block <= 0 || nb <= 0 || width <= 0) return -1;
+    const int64_t slots = first[nb], hub_slots = hub_first[nb];
+    const int32_t sentinel8 = static_cast<int32_t>(n_cols / width);
+    const int8_t sentinel_lane = static_cast<int8_t>(n_cols % width);
+    for (int64_t s = 0; s < slots; ++s) {
+        src8[s] = sentinel8;
+        lane[s] = sentinel_lane;
+    }
+    std::memset(off, 0, sizeof(int32_t) * slots);
+    std::memset(val, 0, sizeof(float) * slots);
+    for (int64_t s = 0; s < hub_slots; ++s) hub_idx[s] = n_hubs;
+    std::memset(hub_off, 0, sizeof(int32_t) * hub_slots);
+    std::memset(hub_val, 0, sizeof(float) * hub_slots);
+
+    std::vector<int64_t> next(first, first + nb);
+    std::vector<int64_t> hub_next(hub_first, hub_first + nb);
+    for (int64_t e = 0; e < m; ++e) {
+        const int64_t r = rows[e], c = cols[e];
+        if (r < 0 || c < 0 || c >= n_cols) return -1;
+        const int64_t b = r / block;
+        if (b >= nb) return -1;
+        const int32_t rank = hub_rank[c];
+        const float v = vals ? vals[e] : 1.0f;
+        if (rank >= 0) {
+            const int64_t p = hub_next[b]++;
+            if (p >= hub_first[b + 1]) return -1;
+            hub_idx[p] = rank;
+            hub_off[p] = static_cast<int32_t>(r % block);
+            hub_val[p] = v;
+        } else {
+            const int64_t p = next[b]++;
+            if (p >= first[b + 1]) return -1;
+            src8[p] = static_cast<int32_t>(c / width);
+            lane[p] = static_cast<int8_t>(c % width);
+            off[p] = static_cast<int32_t>(r % block);
+            val[p] = v;
+        }
     }
     return 0;
 }

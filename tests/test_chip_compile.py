@@ -184,6 +184,77 @@ def test_chunked_pagerank_loop_runs_in_panels(one_chip):
     assert "matrel_spmv_scatter_chunks" in text
 
 
+# The same graph's plan with the hub table build_spmv_plan chooses for it
+# (PR 36): 32,768 hubs hold 67.1M of the edges, in chunks of their own
+G500_MAIN_CHUNKS, G500_HUB_CHUNKS, G500_HUBS = 32_247, 35_107, 32_768
+
+
+def _g500_hub_loop(one_chip):
+    from matrel_tpu.workloads import pagerank
+
+    def chunks(n, *dtypes):
+        shp = (n, spmv_lib.CHUNK // pc.LANE, pc.LANE)
+        return tuple(_sds(one_chip, shp, dt) for dt in dtypes) + (
+            _sds(one_chip, (n,), jnp.int32),)            # chunk -> block
+
+    tables = chunks(G500_MAIN_CHUNKS, jnp.int32, jnp.int8, jnp.int32,
+                    jnp.float32) + (
+        _sds(one_chip, (G500_HUBS,), jnp.int32),) + chunks(
+        G500_HUB_CHUNKS, jnp.int32, jnp.int32, jnp.float32)
+    static = (G500_NODES, G500_NODES, BLOCK, spmv_lib.LO)
+    loop = pagerank._compact_runner_loop(G500_NODES, 10, 0.85, static, 0, 3,
+                                         False)
+    return _compile(loop, tables, (),
+                    _sds(one_chip, (G500_NODES,), jnp.float32))
+
+
+def test_chunked_pagerank_loop_with_a_hub_table(one_chip):
+    """The round with hub chunks: two kernels (the chunk scatter over the
+    main chunks, the hub scatter over the others, their slot weights
+    made in VMEM from the (256, 128) table), 4 panels where the 64,976
+    chunks took 8, and arguments and temporaries what ``plan_bytes``
+    reckons with 12 B a hub slot."""
+    compiled = _g500_hub_loop(one_chip)
+    text = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
+    assert "matrel_spmv_scatter_chunks" in text
+    assert "matrel_spmv_scatter_hubs" in text
+    per = pc.panel_rows(G500_MAIN_CHUNKS, spmv_lib.CHUNK)
+    assert -(-G500_MAIN_CHUNKS // per) == 4 and per == 8064
+    assert f"u8[{per * spmv_lib.CHUNK},32]" in text
+    assert f"f32[{G500_HUBS // pc.LANE},{pc.LANE}]" in text  # the hub table
+    stats = compiled.memory_analysis()
+    hub_slots = G500_HUB_CHUNKS * spmv_lib.CHUNK
+    reckoned = pc.plan_bytes(G500_MAIN_CHUNKS, spmv_lib.CHUNK, hub_slots)
+    assert stats.temp_size_in_bytes < reckoned - 13 * (
+        G500_MAIN_CHUNKS * spmv_lib.CHUNK) - pc.HUB_BYTES_A_SLOT * hub_slots
+    taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 0.9 * reckoned < taken < 1.05 * reckoned, (reckoned, taken)
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32],
+                         ids=["i32", "f32"])
+def test_lane_permute_lowers(one_chip, dtype):
+    """The hub kernel stands on ``take_along_axis`` along the lanes of an
+    (8, 128) register lowering to the chip's lane permute; where a
+    compiler stops taking it, its own words are the failure."""
+    from jax.experimental import pallas as pl
+
+    def kernel(table_ref, lane_ref, out_ref):
+        out_ref[...] = jnp.take_along_axis(table_ref[...], lane_ref[...],
+                                           axis=1)
+
+    shape = (8, pc.LANE)
+    permute = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(shape, dtype))
+    try:
+        _compile(jax.jit(permute), _sds(one_chip, shape, dtype),
+                 _sds(one_chip, shape, jnp.int32))
+    except Exception as e:  # noqa: BLE001 — the compiler's words are the result
+        pytest.fail(f"take_along_axis(axis=1) on {shape} no longer lowers "
+                    f"for the described v5e: {e}")
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_spmm(one_chip, dtype):

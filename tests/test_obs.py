@@ -1137,10 +1137,13 @@ class TestProfilerTier:
             said = by["matrel.pagerank.plan"]["attrs"]
             assert said["hit"] is hit
             # PR 33: on a hit as on a build, the plan's layout
+            # PR 36: and its hub table, none in the blocks layout
             assert set(said) == {
                 "hit", "layout", "edges", "slots", "chunks", "chunk",
-                "overflow_edges", "row_values", "panels", "plan_bytes"}
+                "overflow_edges", "row_values", "panels", "plan_bytes",
+                "hubs", "hub_slots", "hub_chunks"}
             assert said["layout"] == "blocks" and said["panels"] == 1
+            assert said["hubs"] == said["hub_slots"] == said["hub_chunks"] == 0
             assert said["slots"] == said["chunks"] * said["chunk"]
             assert by["matrel.pagerank.fingerprint"]["attrs"] == known
         assert [r["name"] for r in seg] == ["matrel.pagerank.dispatch"]
@@ -1204,7 +1207,7 @@ class TestProfilerTier:
             and name.value.startswith("matrel_")
 
     def test_all_pallas_call_sites_were_found(self):
-        assert len(_PALLAS_SITES) == 9      # PR 33: the chunk scatter
+        assert len(_PALLAS_SITES) == 10     # PR 36: the hub scatter
 
 
 class TestAnalyzeEvent:

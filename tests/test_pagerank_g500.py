@@ -2,7 +2,10 @@
 layout of ``build_spmv_plan``, the chunk-grid Pallas scatter, the
 panelled matvec and the byte-reckoned gate, at Kronecker scale 10-12
 with Pallas interpreted, against ``pagerank_reference_edges`` (LDBC
-Graphalytics' equation in float64)."""
+Graphalytics' equation in float64). PR 36: the hub chunks beside them
+(at this scale a full hub table would hold every source, so the tests
+of the chunks themselves build without one, ``no_hubs``, and the hub
+tests with a table of two rows, ``two_hub_rows``)."""
 
 import logging
 import os
@@ -38,6 +41,19 @@ def graphs(g500):
         src, dst = g500.directed_in_seed_order(lo, hi, 2147483999)
         out[scale] = (src, dst, v)
     return out
+
+
+@pytest.fixture
+def no_hubs(monkeypatch):
+    """Every edge in the plan's own chunks, as before PR 36."""
+    monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", 0)
+
+
+@pytest.fixture
+def two_hub_rows(monkeypatch):
+    """A hub table of 256 sources: of a scale-12 graph's 3,335 they hold
+    about half the edges, so both sets of chunks have work."""
+    monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", 2)
 
 
 def _variant(graphs, kind):
@@ -125,7 +141,7 @@ def test_uniform_graph_keeps_the_blocks_layout(rng):
     assert auto.padding_ratio == blocks.padding_ratio < 1.2
 
 
-def test_auto_lays_a_skewed_graph_in_chunks(graphs, monkeypatch):
+def test_auto_lays_a_skewed_graph_in_chunks(graphs, monkeypatch, no_hubs):
     src, dst, v = graphs[12]
     # at this size every plan is "small": the threshold is the only
     # thing that keeps a test-sized skewed graph in blocks
@@ -140,7 +156,7 @@ def test_auto_lays_a_skewed_graph_in_chunks(graphs, monkeypatch):
     assert plan.padding_ratio < 1.10 < blocks.padding_ratio
 
 
-def test_chunks_layout_tables(graphs):
+def test_chunks_layout_tables(graphs, no_hubs):
     src, dst, v = graphs[12]
     plan = spmv_lib.build_spmv_plan(dst, src, n_rows=v, n_cols=v,
                                     layout="chunks")
@@ -165,6 +181,67 @@ def test_chunks_layout_tables(graphs):
     assert np.all(full[~real] == v)              # padded slots: sentinel
 
 
+def test_hub_chunks_hold_the_edges_of_the_largest_sources(graphs,
+                                                          two_hub_rows):
+    src, dst, v = graphs[12]
+    plan = spmv_lib.build_spmv_plan(dst, src, n_rows=v, n_cols=v,
+                                    layout="chunks")
+    hub = plan.hubs
+    deg = np.bincount(src, minlength=v)
+    np.testing.assert_array_equal(
+        hub.ids, np.argsort(-deg, kind="stable")[:2 * spmv_lib.HUB_ROW])
+    assert hub.ids.dtype == hub.idx.dtype == hub.chunk_block.dtype == np.int32
+    assert hub.idx.shape == hub.off.shape == hub.val.shape \
+        == (hub.chunk_block.size, spmv_lib.CHUNK)
+    # a block owns the hub chunks its hub edges need: none without any
+    is_hub = np.isin(src, hub.ids)
+    assert 0.3 < is_hub.mean() < 0.7
+    cnt = np.bincount(dst[is_hub] // 512, minlength=-(-v // 512))
+    np.testing.assert_array_equal(
+        hub.chunk_block, np.repeat(np.arange(cnt.size),
+                                   -(-cnt // spmv_lib.CHUNK)))
+    # every edge lies in exactly one of the two sets, in a chunk of its
+    # own block; a padded hub slot names no table row
+    real, hub_real = plan.val != 0, hub.val != 0
+    assert real.sum() + hub_real.sum() == src.size
+    assert plan.padding_ratio == (plan.val.size + hub.val.size) / src.size
+    full = plan.src8.astype(np.int64) * spmv_lib.WIDTH + plan.lane
+    got = np.concatenate([
+        np.stack([(plan.chunk_block[:, None] * 512 + plan.off)[real],
+                  full[real]], 1),
+        np.stack([(hub.chunk_block[:, None] * 512 + hub.off)[hub_real],
+                  hub.ids[hub.idx[hub_real]]], 1)])
+    want = np.stack([dst, src], 1).astype(np.int64)
+    assert np.array_equal(got[np.lexsort(got.T[::-1])],
+                          want[np.lexsort(want.T[::-1])])
+    assert np.all(hub.idx[~hub_real] == hub.ids.size)
+    assert not np.isin(full[real], hub.ids).any()
+
+
+def test_a_block_without_hub_edges_keeps_the_main_sums(monkeypatch):
+    """Blocks 0 and 2 take edges from the one table row (column 0 with
+    300 edges, and columns 1..127, which win their ties by the smaller
+    id), block 1 only from columns outside it: it owns no hub chunk, and
+    the hub kernel, which starts from the main scatter's sums, leaves
+    its tile as that left it."""
+    monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", 1)
+    rows = np.concatenate([np.arange(150), 1024 + np.arange(150),
+                           200 + np.arange(127), 512 + np.arange(40)])
+    cols = np.concatenate([np.zeros(300, np.int64), 1 + np.arange(127),
+                           300 + np.arange(40)])
+    plan = spmv_lib.build_spmv_plan(rows, cols, n_rows=1536, n_cols=400,
+                                    layout="chunks")
+    np.testing.assert_array_equal(plan.hubs.ids, np.arange(spmv_lib.HUB_ROW))
+    np.testing.assert_array_equal(plan.hubs.chunk_block, [0, 2])
+    np.testing.assert_array_equal(plan.chunk_block, [0, 1, 2])
+    assert (plan.val != 0).sum() == 40
+    x = np.arange(1, 401, dtype=np.float32)
+    y = pc.spmv_compact(plan, jnp.asarray(x), interpret=True)
+    want = np.zeros(1536, np.float32)
+    want[rows] = x[cols]
+    np.testing.assert_array_equal(np.asarray(y), want)
+
+
 def test_an_empty_block_owns_one_chunk():
     rows = np.array([5, 5, 2000], np.int64)      # blocks 1 and 2 empty
     plan = spmv_lib.build_spmv_plan(rows, np.array([0, 1, 2]),
@@ -176,12 +253,14 @@ def test_an_empty_block_owns_one_chunk():
     np.testing.assert_array_equal(np.asarray(y), want)
 
 
+@pytest.mark.parametrize("hub_rows", [0, 2], ids=["no_hubs", "two_hub_rows"])
 @pytest.mark.parametrize("weighted", [False, True])
 def test_native_and_numpy_fills_agree_on_chunks(graphs, monkeypatch, rng,
-                                                weighted):
+                                                weighted, hub_rows):
     if native.spmv_counts(np.zeros(1, np.int64), 512, 1) is None:
         pytest.skip("native library unavailable")
     src, dst, v = graphs[12]
+    monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", hub_rows)
     vals = rng.random(src.size).astype(np.float32) if weighted else None
     nat = spmv_lib.build_spmv_plan(dst, src, vals, v, v, layout="chunks")
     monkeypatch.setattr(native, "spmv_counts", lambda *a: None)
@@ -192,6 +271,17 @@ def test_native_and_numpy_fills_agree_on_chunks(graphs, monkeypatch, rng,
     # (numpy); the same slots are real, and each block holds the same
     # edges, as the same matvec shows bit for bit
     np.testing.assert_array_equal((nat.val != 0).sum(1), (ref.val != 0).sum(1))
+    assert (nat.hubs is not None) == (ref.hubs is not None) == bool(hub_rows)
+    if hub_rows:        # the library splits the edges in one walk, numpy
+        # takes each set out of the list: the same hubs, chunks and slots
+        np.testing.assert_array_equal(nat.hubs.ids, ref.hubs.ids)
+        np.testing.assert_array_equal(nat.hubs.chunk_block,
+                                      ref.hubs.chunk_block)
+        np.testing.assert_array_equal((nat.hubs.val != 0).sum(1),
+                                      (ref.hubs.val != 0).sum(1))
+        per_block = [np.sort((h.chunk_block[:, None] * 1024 + h.idx),
+                             axis=None) for h in (nat.hubs, ref.hubs)]
+        np.testing.assert_array_equal(*per_block)
     x = jnp.asarray(rng.random(v).astype(np.float32))
     a = np.asarray(pc.spmv_compact(nat, x, interpret=True))
     b = np.asarray(pc.spmv_compact(ref, x, interpret=True))
@@ -204,6 +294,28 @@ def test_native_ragged_fill_refuses_a_block_past_its_slots():
     rows = np.zeros(200, np.int64)
     first = np.array([0, 128], np.int64)         # 200 edges, 128 slots
     assert native.spmv_fill_ragged(rows, rows, None, 1, 512, first, 8) is None
+
+
+def test_native_hub_fill_refuses_a_block_past_its_slots():
+    if native.spmv_counts(np.zeros(1, np.int64), 512, 1) is None:
+        pytest.skip("native library unavailable")
+    rows = np.zeros(200, np.int64)
+    cols = np.arange(200) % 2                    # column 1 is the hub
+    rank = np.array([-1, 0], np.int32)
+    np.testing.assert_array_equal(
+        native.spmv_counts_hubs(rows, cols, rank, 512, 1), [100])
+    room, tight = np.array([0, 128], np.int64), np.array([0, 64], np.int64)
+    (src8, lane, off, val, *overflow), (idx, hub_off, hub_val) = \
+        native.spmv_fill_ragged_hubs(rows, cols, None, rank, 128, 512, room,
+                                     room, 8)
+    assert [a.size for a in overflow] == [0, 0, 0]
+    assert (val != 0).sum() == (hub_val != 0).sum() == 100
+    assert set(idx[hub_val != 0]) == {0} and set(idx[hub_val == 0]) == {128}
+    for first, hub_first in ((tight, room), (room, tight)):
+        assert native.spmv_fill_ragged_hubs(rows, cols, None, rank, 128, 512,
+                                            first, hub_first, 8) is None
+    # a column past the rank table is out of range, not read
+    assert native.spmv_counts_hubs(rows, cols + 1, rank, 512, 1) is None
 
 
 # -- the matvec and the ranks ---------------------------------------------------
@@ -256,17 +368,23 @@ def _small_device(monkeypatch, panel_slots):
                         config_lib.MatrelConfig(hbm_budget_bytes=budget))
 
 
-@pytest.mark.parametrize("layout", ["chunks", "blocks"])
+@pytest.mark.parametrize("layout", ["chunks", "blocks", "chunks_and_hubs"])
 def test_panelled_matvec_is_the_unpanelled_one_bit_for_bit(
         graphs, monkeypatch, rng, layout):
     src, dst, v = graphs[12]
-    plan = spmv_lib.build_spmv_plan(dst, src, None, v, v, layout=layout)
+    # the hub chunks take no part in the panels: 5 main chunks a panel
+    # of 29, or of the 20 left beside a hub table of two rows
+    monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX",
+                        2 if layout == "chunks_and_hubs" else 0)
+    plan = spmv_lib.build_spmv_plan(dst, src, None, v, v,
+                                    layout=layout.split("_")[0])
+    assert (plan.hubs is not None) == (layout == "chunks_and_hubs")
     x = jnp.asarray(rng.random(v).astype(np.float32))
     rows, cap = plan.src8.shape
     assert pc.panel_rows(rows, cap) == rows
     whole = np.asarray(pc.spmv_compact(plan, x, interpret=True))
     # 5 chunks (or 2 blocks) a panel: a last panel moved back to overlap
-    per = 5 if layout == "chunks" else 2
+    per = 2 if layout == "blocks" else 5 if layout == "chunks" else 3
     assert rows % per
     _small_device(monkeypatch, per * cap)
     assert pc.panel_rows(rows, cap) == per
@@ -278,13 +396,17 @@ def test_panelled_matvec_is_the_unpanelled_one_bit_for_bit(
 
 
 def test_panel_rows_and_plan_bytes_follow_the_device(monkeypatch):
-    """The g500-22 plan on a v5e: 64,976 chunks of 2,048 slots; a
-    quarter of 15.5 GiB at 224 B a slot holds 9,069 of them, so 8
-    panels, of 8,122 each (no overlap to gather twice)."""
+    """The g500-22 plan without hub chunks on a v5e: 64,976 chunks of
+    2,048 slots; a quarter of 15.5 GiB at 224 B a slot holds 9,069 of
+    them, so 8 panels, of 8,122 each, up to 8,128, a multiple of 64 (48
+    chunks gathered twice). With hub chunks (PR 36) 45,033 are left: 5
+    panels of 9,007, a prime, which XLA tiles a row at a time: 9,024."""
     rows, cap = 64_976, 2048
     per = pc.panel_rows(rows, cap)
     assert int(0.25 * (31 << 29) // (224 * 2048)) == 9069
-    assert per == -(-rows // 8) == 8122 and 8 * per == rows
+    assert -(-rows // 8) == 8122 and per == 8128 and 8 * per - rows == 48
+    assert pc.panel_rows(45_033, cap) == 9024 and -(-45_033 // 5) == 9007
+    assert pc.panel_rows(9069 * 3 - 1, cap) == 9069     # no room to round
     assert pc.plan_bytes(rows, cap) == 17 * rows * cap + 224 * per * cap
     assert pc.plan_bytes(rows, cap) < 0.5 * (31 << 29)
     # the uniform 1M-node plan: one panel
@@ -309,7 +431,7 @@ def compact_auto(monkeypatch):
 
 
 def test_pagerank_edges_auto_answers_a_skewed_graph_in_chunks(
-        graphs, compact_auto):
+        graphs, compact_auto, no_hubs):
     src, dst, v = graphs[12]
     before = pr.path_counts()["compact"]
     got = np.asarray(pr.pagerank_edges(src, dst, v, rounds=10, alpha=0.85),
@@ -337,8 +459,49 @@ def test_pagerank_edges_auto_answers_a_skewed_graph_in_chunks(
     np.testing.assert_array_equal(again, got.astype(np.float32))
 
 
+@pytest.mark.parametrize("hub_rows", [2, None],
+                         ids=["two_hub_rows", "every_source_a_hub"])
+def test_pagerank_edges_reports_the_hub_table(graphs, compact_auto,
+                                              monkeypatch, hub_rows):
+    """``last_plan()`` says what the build chose, on a build as on a
+    hit, and the ranks hold the Graph500 configuration's two limits. At
+    this scale the table the code chooses unpatched (64 rows at the
+    most) has room for all 3,335 sources, and takes 26 rows of them:
+    the 7 of smallest degree would make a row that does not pay."""
+    src, dst, v = graphs[12]
+    if hub_rows:
+        monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", hub_rows)
+    got = np.asarray(pr.pagerank_edges(src, dst, v, rounds=10, alpha=0.85),
+                     np.float64)
+    said = pr.last_plan()
+    assert said["impl"] == "compact" and said["layout"] == "chunks"
+    assert said["hubs"] == spmv_lib.HUB_ROW * (hub_rows or v // 128)
+    assert said["hub_slots"] == said["hub_chunks"] * said["chunk"] > 0
+    assert said["slots"] == (said["chunks"] + said["hub_chunks"]) \
+        * said["chunk"]
+    assert said["overflow_edges"] == 0 and said["edges"] == src.size
+    assert said["plan_bytes"] == pc.plan_bytes(
+        said["chunks"], said["chunk"], said["hub_slots"]) \
+        == pc.plan_bytes(said["chunks"], said["chunk"]) \
+        + 12 * said["hub_slots"]
+    if not hub_rows:            # a main chunk a block, all but empty
+        assert said["chunks"] == -(-v // 512) and v % 128 == 7
+    want = pr.pagerank_reference_edges(src, dst, v, 10, 0.85)
+    assert np.max(np.abs(got - want)) / want.max() < 3e-6
+    assert np.max(np.abs(got - want) / want) < 5e-6
+    # cached at its own price: 17 B a main slot, 12 a hub slot
+    assert [e.cost for e in pr._PLAN_CACHE] == [
+        17 * said["chunks"] * said["chunk"] + 12 * said["hub_slots"]]
+    again = np.asarray(pr.pagerank_edges(src, dst, v, rounds=10, alpha=0.85))
+    hit = pr.last_plan()
+    assert hit["hit"] is True
+    assert [hit[k] for k in ("hubs", "hub_slots", "hub_chunks")] \
+        == [said[k] for k in ("hubs", "hub_slots", "hub_chunks")]
+    np.testing.assert_array_equal(again, got.astype(np.float32))
+
+
 def test_a_compact_plan_is_cached_at_its_own_price(graphs, compact_auto,
-                                                   monkeypatch):
+                                                   monkeypatch, no_hubs):
     """Counted as expanded tables (224 B a slot) this plan would pass
     the budget and be rebuilt in every call; at 17 B a slot it stays."""
     src, dst, v = graphs[10]
@@ -395,7 +558,10 @@ def test_fallback_warning_names_the_refusal(graphs, compact_auto, caplog,
 @pytest.fixture(scope="module")
 def chunked_plan(graphs):
     src, dst, v = graphs[10]
-    return spmv_lib.build_spmv_plan(dst, src, None, v, v, layout="chunks")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(spmv_lib, "_HUB_ROWS_MAX", 0)
+        return spmv_lib.build_spmv_plan(dst, src, None, v, v,
+                                        layout="chunks")
 
 
 @pytest.mark.parametrize("who", ["expanded", "spmm", "shard_plan",
@@ -450,6 +616,25 @@ def test_save_and_load_keep_the_chunks(chunked_plan, tmp_path, rng):
     with np.load(path) as z:
         assert int(z["meta"][4]) == 1 and "chunk_block" not in z.files
     assert spmv_lib.load_plan(path).chunk_block is None
+
+
+def test_save_and_load_keep_the_hub_chunks(graphs, two_hub_rows, tmp_path,
+                                           rng):
+    src, dst, v = graphs[10]
+    plan = spmv_lib.build_spmv_plan(dst, src, None, v, v, layout="chunks")
+    assert plan.hubs is not None
+    path = str(tmp_path / "plan.npz")
+    spmv_lib.save_plan(path, plan)
+    with np.load(path) as z:
+        assert int(z["meta"][4]) == 3            # a version-2 reader stops
+    loaded = spmv_lib.load_plan(path)
+    for name in ("ids", "idx", "off", "val", "chunk_block"):
+        np.testing.assert_array_equal(getattr(loaded.hubs, name),
+                                      getattr(plan.hubs, name))
+    x = jnp.asarray(rng.random(v).astype(np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(pc.spmv_compact(loaded, x, interpret=True)),
+        np.asarray(pc.spmv_compact(plan, x, interpret=True)))
 
 
 def test_unknown_layout_is_refused():

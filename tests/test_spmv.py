@@ -927,3 +927,192 @@ def test_refusals_are_named():
     for layout in ("chunks", "auto"):
         assert spmv_lib.build_spmv_plan(rows, cols, n_rows=n_rows, n_cols=1,
                                         layout=layout) is None
+
+
+# -- hub chunks (PR 36) --------------------------------------------------------
+# In the chunks layout the edges from the sources of largest out-degree
+# lie in chunks of their own, and the scatter kernel takes x for them
+# from a (M, 128) table in VMEM by lane permutes: no row gather.
+
+
+def _hub_rows_max(monkeypatch, rows):
+    if rows is not None:
+        monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", rows)
+
+
+@pytest.mark.parametrize("case", sorted(_BIT_CASES))
+def test_hub_weights_are_x_idx_bit_for_bit(case):
+    """A permute moves 32-bit lanes and a select picks whole values: a
+    hub slot's weight is the table's entry to the last bit, specials
+    included, and a padded slot (the rank past the table) reads +0.0
+    whatever lies in the table."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    table = _BIT_CASES[case]
+    n = table.shape[0]
+    # the case's values on two table rows, the second back to front
+    rows = np.zeros((2, pc.LANE), np.float32)
+    rows[0, 5:5 + n], rows[1, 100:100 + n] = table, table[::-1]
+    idx = np.full((16, pc.LANE), 2 * pc.LANE, np.int32)       # padded slots
+    idx[3, :n] = 5 + np.arange(n)
+    idx[12, 7:7 + n] = pc.LANE + 100 + np.arange(n)
+    idx[9, ::2] = 0                                            # a +0.0 entry
+    got = pc._hub_weights(jnp.asarray(idx), jnp.asarray(rows), 2)
+    want = np.concatenate([rows.reshape(-1), np.zeros(1, np.float32)])[idx]
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+    # the compact matvec of a selection matrix whose sources are all hubs
+    # (fewer than 128: one table row): y is x[cols], the specials its
+    # lane neighbours in the table, empty rows +0.0
+    cols = np.flatnonzero(_survives_split(table))[::-1]
+    sel_rows = np.arange(cols.size) * 2
+    plan = spmv_lib.build_spmv_plan(sel_rows, cols,
+                                    np.ones(cols.size, np.float32),
+                                    n_rows=2 * cols.size, n_cols=n,
+                                    layout="chunks")
+    assert plan.hubs.ids.size == pc.LANE and not plan.val.any()
+    static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
+    y = pc.compact_apply(static, pc.compact_tables(plan), plan.overflow,
+                         jnp.asarray(table), interpret=True)
+    want = np.zeros(plan.n_rows, np.float32)
+    want[sel_rows] = table[cols]
+    np.testing.assert_array_equal(_bits(y), _bits(want))
+
+
+@pytest.fixture(scope="module")
+def kronecker_13():
+    """(src, dst, vertices) of a Graph500 Kronecker graph of scale 13, both
+    directions of every edge: the Graph500 cell's generator."""
+    import os
+    from benchmarks import run as harness
+    g500 = harness.load_module(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmarks", "configs", "ldbc_graphalytics_g500_22.py"))
+    lo, hi, v = g500.kronecker_graph(13, 16, (0.57, 0.19, 0.19), 1)
+    return g500.directed_in_seed_order(lo, hi, 36) + (v,)
+
+
+@pytest.mark.parametrize("hub_rows", [0, 1, 8, None],
+                         ids=["no_hubs", "128_hubs", "1024_hubs",
+                              "as_chosen"])
+def test_skewed_matvec_with_and_without_hubs(kronecker_13, monkeypatch, rng,
+                                             hub_rows):
+    from matrel_tpu.ops import pallas_spmv as pc
+    src, dst, v = kronecker_13
+    _hub_rows_max(monkeypatch, hub_rows)
+    vals = rng.standard_normal(src.size).astype(np.float32)
+    plan = spmv_lib.build_spmv_plan(dst, src, vals, v, v, layout="chunks")
+    hub = plan.hubs
+    assert plan.overflow == () and plan.ov_rows is None
+    if hub_rows == 0:
+        assert hub is None
+        hub_edges = hub_slots = 0
+    else:       # as chosen: 43 rows of this graph's 6,467 sources pay
+        assert hub.ids.size == spmv_lib.HUB_ROW * (hub_rows or 43)
+        hub_edges, hub_slots = int((hub.val != 0).sum()), hub.val.size
+        assert np.isin(src, hub.ids).sum() == hub_edges
+    # every edge in one of the two sets, the rest of both padding
+    assert int((plan.val != 0).sum()) + hub_edges == (vals != 0).sum()
+    assert plan.padding_ratio == (plan.val.size + hub_slots) / src.size
+    x = rng.standard_normal(v).astype(np.float32)
+    y = np.asarray(pc.spmv_compact(plan, jnp.asarray(x), interpret=True))
+    want = coo_oracle(dst, src, vals, x, v)
+    assert np.abs(y - want).max() / np.abs(want).max() < 2e-7
+
+
+def _compact_apply_before_pr36(plan_static, tables, ov, x, passes, interpret):
+    """``compact_apply`` as it stood before PR 36: what a plan without
+    hub chunks must still lower to, word for word."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    n_rows, n_cols, block, lo = plan_static
+    src8, lane, off, val, *chunk_block = tables
+    rows, cr, _ = src8.shape
+    w = pc._slot_weights(src8, lane, val, x)
+    if chunk_block:
+        scatter = pc._chunk_runner(rows, cr * pc.LANE, -(-n_rows // block),
+                                   block, lo, passes, interpret)
+        y = scatter(chunk_block[0], off, w).reshape(-1)[:n_rows]
+    else:
+        scatter = pc._compact_runner(rows, cr * pc.LANE, block, lo, passes,
+                                     interpret)
+        y = scatter(off, w).reshape(-1)[:n_rows]
+    if ov:
+        y = spmv_lib._overflow_add(y, ov, x, n_rows)
+    return y
+
+
+@pytest.mark.parametrize("graph,layout", [
+    ("uniform", "auto"), ("uniform", "blocks"), ("uniform_wide", "chunks"),
+    ("skewed", "blocks"), ("skewed", "auto")])
+def test_no_hubs_off_the_skewed_chunks_and_the_program_is_the_parents(
+        kronecker_13, monkeypatch, rng, graph, layout):
+    """Hubs exist only where the layout is chunks and the largest sources
+    hold their share: a uniform graph (which "auto" lays in blocks; in
+    chunks, the 32,768 largest of 1.5M sources hold 6% of the edges) and
+    every blocks plan have none, and their matvec lowers to the text it
+    lowered to before."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    if graph.startswith("uniform"):
+        v, m = (200_000, 2_000_000) if graph == "uniform" else (
+            1_500_000, 3_000_000)
+        src, dst = rng.integers(0, v, m), rng.integers(0, v, m)
+    else:       # below the small-plan threshold "auto" keeps blocks
+        src, dst, v = kronecker_13
+    plan = spmv_lib.build_spmv_plan(dst, src, None, v, v, layout=layout)
+    assert plan.hubs is None
+    assert (plan.chunk_block is not None) == (layout == "chunks")
+    tables = pc.compact_tables(plan)
+    assert len(tables) == (5 if layout == "chunks" else 4)
+    static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
+    x = jax.ShapeDtypeStruct((v,), jnp.float32)
+    texts = [jax.jit(lambda t, ov, r: apply(static, t, ov, r, 3, True)
+                     ).lower(tables, plan.overflow, x).as_text()
+             for apply in (pc.compact_apply, _compact_apply_before_pr36)]
+    assert "pallas_call" in texts[0] or "while" in texts[0]
+    assert texts[0] == texts[1]
+
+
+def _zipf_degrees(n, exponent, edges):
+    d = np.arange(1, n + 1, dtype=np.float64) ** -exponent
+    return np.maximum((d / d.sum() * edges).astype(np.int64), 1)
+
+
+@pytest.mark.parametrize("name,deg,want_rows", [
+    # 10 edges a source, a million sources: 256 rows hold 3.3%
+    ("flat", np.full(1_000_000, 10), 0),
+    # the 32,768 largest of a million hold 9.4%: under a tenth
+    ("mildly_skewed_under_a_tenth", _zipf_degrees(1_000_000, 0.3, 10**7), 0),
+    # ... and 37%: every row pays, as many as the table may have
+    ("skewed", _zipf_degrees(1_000_000, 0.7, 10**7), 256),
+    # a steep head: 116 rows hold 72%, and the 117th would cost the 7.2M
+    # hub slots more than its 4,400 edges save
+    ("steep", _zipf_degrees(1_000_000, 1.0, 10**7), 116),
+    # the Graph500 scale-22 graph's shares (ISSUE 36: 11 / 17 / 22 / 32 /
+    # 40 / 52 / 62% at 1k .. 64k sources): 980 edges a hub at row 256
+    ("graph500_like", np.concatenate([
+        np.full(1024, 14_000), np.full(1024, 7_500), np.full(2048, 3_100),
+        np.full(4096, 3_000), np.full(8192, 1_250), np.full(16384, 980),
+        np.full(32768, 390), np.full(2_330_000, 21)]), 256),
+    ("fewer_than_128_sources", np.full(50, 40), 1),
+    ("one_source", np.array([100_000]), 1),
+    # 128 hubs of 10,000 edges, then one edge a source: a second row
+    # would cost 1.28M hub slots a permute each for 128 edges
+    ("the_tail_does_not_pay", np.concatenate([np.full(128, 10_000),
+                                              np.ones(500_000, np.int64)]), 1),
+    ("no_edges", np.zeros(1000, np.int64), 0),
+])
+def test_the_hub_table_is_chosen_from_the_degrees(name, deg, want_rows):
+    deg = np.sort(np.asarray(deg, np.int64))[::-1]
+    assert spmv_lib._hub_rows(deg, int(deg.sum())) == want_rows
+    # through the build's own door: the ids are the largest sources, the
+    # smaller id first among equals
+    if 0 < deg.size <= 1000:
+        cols = np.repeat(np.arange(deg.size), deg)
+        ids, rank = spmv_lib._choose_hubs(cols, deg.size)
+        if want_rows == 0:
+            assert ids is None and rank is None
+        else:
+            assert ids.size == want_rows * spmv_lib.HUB_ROW
+            real = min(ids.size, deg.size)
+            np.testing.assert_array_equal(ids[:real], np.arange(real))
+            assert not ids[real:].any()
+            np.testing.assert_array_equal(rank, np.arange(deg.size))
