@@ -12,10 +12,24 @@ XLA scatter anywhere; on real TPU the compact-table Pallas executor of
 lazily and a plain segment-sum fallback for degree distributions the
 planner refuses.
 
-Matvec is the hot op (PageRank-class workloads). `matmat` handles narrow
-dense right-hand sides by reusing the row gather once and cycling the
-one-hot contraction per column — fine for the tall-skinny multivector
-shapes (personalization vectors, feature panels) this type exists for.
+Matvec is the hot op (PageRank-class workloads). `matmat` and the DSL's
+`coo_leaf × dense` product take dense sides of up to 128 columns at the
+cost of 8: a slot's whole row of the dense side is gathered once (a row
+of up to 128 float32 fills 128 lanes whatever it holds) and a chunk-grid
+Pallas kernel scatters the rows through plain one-hot MXU products
+(`ops/pallas_spmv.py`, "k-wide") — factor matrices (GNMF, rank 128) as
+well as the tall-skinny multivector shapes (personalization vectors,
+feature panels) this type began with.
+
+Plans are built once a matrix and an orientation and kept with it. Where
+the compact executor of one device will run them (`_plan_layout`), the
+layout is `"auto"`: skewed matrices lie in chunks (1.01 slots an entry on
+a Netflix-shaped ratings matrix where one capacity a block takes 1.25
+and leaves 12,873 entries to the scalar tail). Where the dense side of a
+k-wide product has more rows than a gather table keeps its row rate for
+(`spmv.source_panels`: 64 MB, 131,064 rows of 512 B), the orientation's
+plan is a `PanelledPlan`: one compact plan a range of sources, every one
+adding into the same output.
 """
 
 from __future__ import annotations
@@ -27,7 +41,85 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from matrel_tpu.obs import trace as trace_lib
 from matrel_tpu.ops import spmv as spmv_lib
+
+# plans built in this process, an orientation of a matrix each (a
+# PanelledPlan's parts are one build)
+_PLAN_BUILDS = 0
+
+
+def plan_builds() -> int:
+    """How many COO plans this process has built (not answered from the
+    matrix's memo): the ``hit`` false ``matrel.spmm.plan.build`` spans,
+    for a caller outside a profiler session."""
+    return _PLAN_BUILDS
+
+
+def _counted_build(build, **said):
+    """``build()`` under its ``matrel.spmm.plan.build`` span, counted."""
+    global _PLAN_BUILDS
+    with trace_lib.span("spmm.plan.build", **said):
+        plan = build()
+    _PLAN_BUILDS += 1
+    return plan
+
+
+def _plan_layout() -> str:
+    """The layout a COOMatrix asks ``build_spmv_plan`` for: ``"auto"``
+    where the executor that will run the plan takes both layouts (the
+    compact-table Pallas executors of ONE device, ops/pallas_spmv.py),
+    ``"blocks"`` where the expanded XLA tables or a mesh's sharded
+    tables will read it."""
+    from matrel_tpu.config import pallas_enabled
+    return ("auto" if pallas_enabled() and len(jax.devices()) == 1
+            else "blocks")
+
+
+@dataclasses.dataclass
+class PanelledPlan:
+    """An orientation's plan for the k-wide product where the dense side
+    has too many rows for one gather table (``spmv.source_panels``):
+    ``parts`` = ((col0, EdgeSpMVPlan), ...), a plan each over the
+    sources ``col0 : col0 + plan.n_cols``, all over the same rows."""
+    n_rows: int
+    n_cols: int
+    block: int
+    parts: tuple
+
+
+def plan_parts(plan) -> tuple:
+    """((col0, EdgeSpMVPlan), ...) of either kind of plan."""
+    return getattr(plan, "parts", None) or ((0, plan),)
+
+
+def plan_facts(plan, entries: int) -> dict:
+    """What plan.meta["spmm"] and a ``matrel.spmm.plan`` span say of a
+    plan of either kind: ``layout``, ``entries``, ``slots``, ``chunks``
+    (table rows), ``source_panels`` and ``table`` (the k-wide gather
+    table's form: ``hbm``, one table whole, or ``panelled``),
+    ``overflow_edges``, and for the k-wide product on one device its
+    ``panels`` (of table rows, over all parts) and ``plan_bytes`` (the
+    tables and the largest panel's temporaries,
+    pallas_spmv.wide_plan_bytes)."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    parts = [p for _, p in plan_parts(plan)]
+    shapes = [np.asarray(p.src8).shape for p in parts]
+    slots = sum(r * c for r, c in shapes)
+    per = [pc.wide_panel_rows(r, c) for r, c in shapes]
+    return {
+        "layout": ("chunks" if parts[0].chunk_block is not None
+                   else "blocks"),
+        "entries": int(entries), "slots": int(slots),
+        "chunks": int(sum(r for r, _ in shapes)),
+        "source_panels": len(parts),
+        "table": "panelled" if len(parts) > 1 else "hbm",
+        "overflow_edges": sum(0 if p.ov_rows is None
+                              else int(p.ov_rows.shape[0]) for p in parts),
+        "panels": int(sum(-(-r // n) for (r, _), n in zip(shapes, per))),
+        "plan_bytes": int(pc.TABLE_BYTES_A_SLOT * slots + max(
+            pc.wide_panel_bytes(r, c) for r, c in shapes)),
+    }
 
 
 @dataclasses.dataclass
@@ -44,6 +136,10 @@ class COOMatrix:
         default=None, repr=False)
     _plan_tried: bool = dataclasses.field(default=False, repr=False)
     _plan_t_tried: bool = dataclasses.field(default=False, repr=False)
+    # the k-wide product's plans where they are not the two above: a
+    # one-slot list each, shared with the transpose view
+    _wide: list = dataclasses.field(default_factory=list, repr=False)
+    _wide_t: list = dataclasses.field(default_factory=list, repr=False)
     # fallback-path caches: (device out_ids, device in_ids, device vals),
     # sorted by out_ids — fixed per matrix, built once per direction
     _seg_fwd: Optional[tuple] = dataclasses.field(default=None, repr=False)
@@ -102,25 +198,72 @@ class COOMatrix:
                          _plan=self._plan_t, _plan_t=self._plan,
                          _plan_tried=self._plan_t_tried,
                          _plan_t_tried=self._plan_tried,
+                         _wide=self._wide_t, _wide_t=self._wide,
                          _seg_fwd=self._seg_bwd, _seg_bwd=self._seg_fwd,
                          _coalesced=self._coalesced)
 
     # ----------------------------------------------------------- plans
+    def _build(self, out_ids, in_ids, n_out: int, n_in: int, sel=None,
+               col0: int = 0):
+        """One compact plan over this matrix's entries (``sel``: a subset
+        of them, sources renumbered from ``col0``): no hub chunks — the
+        k-wide product fetches a hub's whole row like any other."""
+        if sel is not None:
+            out_ids, in_ids = out_ids[sel], in_ids[sel] - col0
+        return spmv_lib.build_spmv_plan(
+            out_ids, in_ids, self.vals if sel is None else self.vals[sel],
+            n_rows=n_out, n_cols=n_in, layout=_plan_layout(), hubs=False)
+
     def _get_plan(self) -> Optional[spmv_lib.EdgeSpMVPlan]:
         if not self._plan_tried:
-            self._plan = spmv_lib.build_spmv_plan(
-                self.rows, self.cols, self.vals,
-                n_rows=self.shape[0], n_cols=self.shape[1])
+            self._plan = _counted_build(
+                lambda: self._build(self.rows, self.cols, *self.shape),
+                orientation="forward")
             self._plan_tried = True
         return self._plan
 
     def _get_plan_t(self) -> Optional[spmv_lib.EdgeSpMVPlan]:
         if not self._plan_t_tried:
-            self._plan_t = spmv_lib.build_spmv_plan(
-                self.cols, self.rows, self.vals,
-                n_rows=self.shape[1], n_cols=self.shape[0])
+            self._plan_t = _counted_build(
+                lambda: self._build(self.cols, self.rows, *self.shape[::-1]),
+                orientation="transposed")
             self._plan_t_tried = True
         return self._plan_t
+
+    def _get_wide_plan(self, transposed: bool = False):
+        """The plan the k-wide product A·X (``transposed``: Aᵀ·X) runs:
+        the orientation's own where X's rows make one gather table, else
+        a :class:`PanelledPlan` over ranges of them, built once; None
+        where the planner refused the matrix."""
+        n_out, n_in = self.shape[::-1] if transposed else self.shape
+        panels = spmv_lib.source_panels(n_in)
+        if panels == 1 or _plan_layout() != "auto":
+            return self._get_plan_t() if transposed else self._get_plan()
+        memo = self._wide_t if transposed else self._wide
+        if not memo:
+            out_ids, in_ids = ((self.cols, self.rows) if transposed
+                               else (self.rows, self.cols))
+            # ranges of whole table rows, as even as they come
+            width = -(-n_in // (panels * spmv_lib.WIDTH)) * spmv_lib.WIDTH
+
+            def build():
+                parts = []
+                for col0 in range(0, n_in, width):
+                    n_part = min(width, n_in - col0)
+                    sel = np.flatnonzero((in_ids >= col0)
+                                         & (in_ids < col0 + n_part))
+                    parts.append((col0, self._build(
+                        out_ids, in_ids, n_out, n_part, sel, col0)))
+                return parts
+
+            parts = _counted_build(
+                build, source_panels=panels,
+                orientation="transposed" if transposed else "forward")
+            memo.append(None if any(p is None for _, p in parts)
+                        else PanelledPlan(n_rows=n_out, n_cols=n_in,
+                                          block=parts[0][1].block,
+                                          parts=tuple(parts)))
+        return memo[0]
 
     def shard(self, mesh) -> "COOMatrix":
         """Return a copy whose forward ``matvec`` runs a plan
@@ -137,7 +280,8 @@ class COOMatrix:
         if self._plan_tried and self._plan is None:
             plan = None                      # known-refused: don't rebuild
         elif (self._plan_tried and self._plan is not None
-              and self._plan._tables is None):
+              and self._plan._tables is None
+              and self._plan.chunk_block is None):
             plan = self._plan                # fresh unexpanded plan: reuse
         else:
             plan = spmv_lib.build_spmv_plan(self.rows, self.cols,
@@ -190,16 +334,20 @@ class COOMatrix:
                              f"{self.shape[0]} rows")
         plan = self._get_plan_t()
         if plan is not None:
+            if self._compact_mode():
+                from matrel_tpu.ops import pallas_spmv as pc
+                return pc.spmv_compact(plan, y)
             return spmv_lib.spmv(plan, y)
         if self._seg_bwd is None:
             self._seg_bwd = self._seg_arrays(self.cols, self.rows)
         return self._segment_matvec(self._seg_bwd, y, self.shape[1])
 
     def matmat(self, X) -> jax.Array:
-        """Y = A·X for dense X (n_cols, k): the k-wide SpMM shares ONE
-        row gather across all columns (ops/spmv.py::spmm; wide X is
-        processed in column chunks). Falls back to a per-column matvec
-        loop only when the planner refused the graph."""
+        """Y = A·X for dense X (n_cols, k): the k-wide SpMM gathers a
+        slot's row of X once for all its columns (ops/pallas_spmv.py on
+        a TPU, 128 columns a pass; ops/spmv.py::spmm elsewhere). Falls
+        back to a per-column matvec loop only when the planner refused
+        the graph."""
         X = jnp.asarray(X, jnp.float32)
         if X.ndim != 2 or X.shape[0] != self.shape[1]:
             raise ValueError(f"X must be ({self.shape[1]}, k), "
@@ -209,7 +357,7 @@ class COOMatrix:
         if self._plan_sharded is not None:
             return spmv_lib.spmm_sharded(self._plan_sharded, X,
                                          self._mesh)
-        plan = self._get_plan()
+        plan = self._get_wide_plan() if X.shape[1] > 1 else self._get_plan()
         if plan is not None:
             if self._compact_mode():
                 from matrel_tpu.ops import pallas_spmv as pc

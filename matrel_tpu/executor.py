@@ -106,6 +106,13 @@ class Lowerer:
         # "pallas_spmm" / "pallas_spmv", or "xla" — complete once the
         # lowered function has been traced (plan.meta["executors"])
         self.executors: List[str] = []
+        # what each coo_leaf product lowered through, in lowering order:
+        # the plan's facts where the compact or expanded SpMV tables
+        # answered (plan.meta["spmm"]: a ``matrel.spmm.plan`` span at
+        # every dispatch), the matrix's shape and bytes where the leaf
+        # was densified (plan.meta["densified_products"])
+        self.spmm: List[dict] = []
+        self.densified: List[dict] = []
         # a long Gram and the product that rides its loop
         # (planner.gram_riders: {uid: (gram, rider)} under both uids),
         # and, while a trace runs, the riders' products by uid
@@ -434,46 +441,53 @@ class Lowerer:
         return jnp.pad(out, ((0, pshape[0] - out.shape[0]),
                              (0, pshape[1] - out.shape[1])))
 
-    def _coo_spmv_stack(self, plan, vectors) -> Array:
-        """SpMV results for a sequence of input vectors (columns of the
-        dense operand) as a (n_rows, k) array; plan tables ride the
-        trace as constants (hoisted into call-time args by
-        _hoist_large_consts). On real TPU the compact-table Pallas
-        executor runs — faster, and the expanded one-hot tables are
-        never built (17× less HBM); CPU keeps the expanded XLA path.
-        Single vectors take the matvec kernel; wider stacks the k-wide
-        SpMM (one shared gather for all columns)."""
+    def _coo_spmv_stack(self, plan, X) -> Array:
+        """A·X for the dense (n_cols, k) operand ``X`` of a coo_leaf
+        product (or its columns, a sequence of vectors), as a
+        (n_rows, k) array; plan tables ride the trace as
+        constants (hoisted into call-time args by _hoist_large_consts).
+        On real TPU the compact-table Pallas executor runs — faster, and
+        the expanded one-hot tables are never built (17× less HBM); CPU
+        keeps the expanded XLA path. One column takes the matvec kernel;
+        more the k-wide SpMM (a slot's row of X gathered once for all
+        its columns), over every source panel of a PanelledPlan."""
         from matrel_tpu.config import pallas_enabled, pallas_interpret_mode
+        from matrel_tpu.core.coo import plan_parts
         from matrel_tpu.ops import spmv as spmv_lib
         use_pallas = pallas_enabled(self.config)
-        choice = self._spmv_forced(plan)
-        if choice == "expanded":
+        parts = plan_parts(plan)
+        chunked = parts[0][1].chunk_block is not None
+        if self._spmv_forced(plan) == "expanded" and not chunked:
             # measured: the expanded XLA one-hot path beats the compact
             # Pallas scatter for this plan shape class on this backend
+            # (a plan laid out in chunks has no expanded form)
             use_pallas = False
         self._ran("pallas_spmv" if use_pallas else "xla")
+        if not hasattr(X, "shape"):
+            X = jnp.stack(list(X), axis=1)
+        k = X.shape[1]
         if use_pallas:
             from matrel_tpu.ops import pallas_spmv as pc
             interp = pallas_interpret_mode(self.config)
             static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
             if self.mesh.size == 1:
-                tables = pc.compact_tables(plan)
-                if len(vectors) == 1:
-                    return pc.compact_apply(static, tables, plan.overflow,
-                                            vectors[0],
+                if k == 1 and len(parts) == 1:
+                    return pc.compact_apply(static, pc.compact_tables(plan),
+                                            plan.overflow, X[:, 0],
                                             interpret=interp)[:, None]
-                return pc.compact_matmat_apply(
-                    static, tables, plan.overflow,
-                    jnp.stack(vectors, axis=1), interpret=interp)
+                with trace_lib.span("spmm.plan.upload"):
+                    static, part_statics, part_arrays = pc.plan_operands(plan)
+                return pc.compact_matmat_parts(static, part_statics,
+                                               part_arrays, X,
+                                               interpret=interp)
             # multi-device: pallas_call has no SPMD partitioning rule,
             # but shard_map hands it per-device shapes — row-decompose
             # the compact tables over the mesh and run the scatter on
             # each device's block slice (13 B/slot everywhere; the
             # expanded ~224 B/slot XLA tables are never built).
-            return self._coo_compact_sharded(pc, plan, static, vectors,
-                                             interp)
+            return self._coo_compact_sharded(pc, plan, static, X, interp)
         if self.mesh.size > 1:
-            # replicate the (small) input vectors before the expanded
+            # replicate the (small) dense operand before the expanded
             # one-hot contraction. A vector sliced from a 2D-sharded
             # operand arrives PARTIALLY sharded (e.g. P('y',) on a
             # (2, 4) mesh) and this container's jax 0.4.37 GSPMD
@@ -483,28 +497,24 @@ class Lowerer:
             # alike — the pre-existing "COO DSL 2x-scale" failure pair
             # and fuzz[49], root-caused round 6. The compact sharded
             # path replicates x by in_spec already; this pins the same
-            # contract on the XLA path. Vectors are SpMV inputs —
-            # n_cols floats — so the reshard is noise next to the
-            # gather it feeds.
+            # contract on the XLA path. The operand is an SpMV input —
+            # n_cols floats a column — so the reshard is noise next to
+            # the gather it feeds.
             from jax.sharding import NamedSharding, PartitionSpec as P
-            repl = NamedSharding(self.mesh, P())
-            vectors = [jax.lax.with_sharding_constraint(v, repl)
-                       for v in vectors]
+            X = jax.lax.with_sharding_constraint(
+                X, NamedSharding(self.mesh, P()))
         static = (plan.n_rows, plan.n_cols, plan.block)
         arrays = plan.arrays()
-        if len(vectors) == 1:
-            return spmv_lib.spmv_apply(static, arrays, vectors[0])[:, None]
-        X = jnp.stack(vectors, axis=1)
+        if k == 1:
+            return spmv_lib.spmv_apply(static, arrays, X[:, 0])[:, None]
         extra = plan.spmm_extra(arrays)   # reuse the staged expansion
         # ≤64-column chunks bound the (B, C, k) gather/weight
         # intermediates, matching spmv.spmm's col_chunk
-        parts = [spmv_lib.spmm_apply(static, arrays, extra,
-                                     X[:, j:j + 64])
-                 for j in range(0, X.shape[1], 64)]
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts,
-                                                                axis=1)
+        outs = [spmv_lib.spmm_apply(static, arrays, extra, X[:, j:j + 64])
+                for j in range(0, k, 64)]
+        return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
 
-    def _coo_compact_sharded(self, pc, plan, static, vectors,
+    def _coo_compact_sharded(self, pc, plan, static, X,
                              interp: bool) -> Array:
         """Compact-table SpMV/SpMM inside the executor's traced program
         on a multi-device mesh: shard_map over the mesh with the tables
@@ -518,9 +528,8 @@ class Lowerer:
         tables = pc.shard_compact_tables(plan, self.mesh)
         axes = tuple(self.mesh.axis_names)
         ov = plan.overflow
-        wide = len(vectors) > 1
-        x = (jnp.stack(vectors, axis=1) if wide else vectors[0]).astype(
-            jnp.float32)
+        wide = X.shape[1] > 1
+        x = (X if wide else X[:, 0]).astype(jnp.float32)
 
         def kern(src8, lane, off, val, xx, *ovv):
             apply = (pc.compact_sharded_matmat_apply if wide
@@ -600,39 +609,44 @@ class Lowerer:
         if _spgemm_dispatch(node, self.config):
             return self._spgemm(node, epilogue=epilogue,
                                 epilogue_elementwise=epilogue_elementwise)
-        # coo_leaf matmuls: per-column one-hot SpMV for narrow dense
-        # operands; wide ones (or refused plans) densify — at that point
-        # the MXU over a dense block layout beats serialized matvecs.
-        # The dispatch predicate is shared with the autotune walk
-        # (_coo_dispatch_plan) so the two can never drift.
-        if l.kind == "coo_leaf":
-            A, k = l.attrs["matrix"], r.shape[1]
+        # coo_leaf × dense: the SpMV tables' k-wide product where the
+        # dense side has at most COO_NARROW_MAX columns (a gathered row
+        # of up to 128 float32 fills 128 lanes: 128 columns cost what 8
+        # do) and the matrix's plan was not refused; else the leaf is
+        # DENSIFIED and the MXU runs the plain dot — a fall-through the
+        # planner prices and, on one device, refuses by name where the
+        # dense copy does not fit (planner.coo_product). The
+        # dispatch predicate is shared with the planner and the
+        # autotune walk (_coo_dispatch_plan) so they can never drift.
+        if l.kind == "coo_leaf" or r.kind == "coo_leaf":
+            # A·S = (Sᵀ·Aᵀ)ᵀ — the matrix's transposed plan, built at
+            # most once
+            flipped = l.kind != "coo_leaf"
+            S = (r if flipped else l).attrs["matrix"]
+            dense = l if flipped else r
             plan = _coo_dispatch_plan(node)
             if plan is None:
-                blk = A.to_block(self.mesh, self.config).data
-                self._ran("xla")
-                return strategies.run_matmul("xla", blk, ev(r), self.mesh,
-                                             self.config,
-                                             epilogue=epilogue)
-            dense = ev(r)
-            out = self._coo_spmv_stack(
-                plan, [dense[: A.shape[1], j] for j in range(k)])
-            return fin(self._pad_to_node(out, node))
-        if r.kind == "coo_leaf":
-            # A·S = (Sᵀ·Aᵀ)ᵀ — use the original matrix's cached
-            # transpose plan (_get_plan_t), built at most once
-            S, k = r.attrs["matrix"], l.shape[0]
-            plan = _coo_dispatch_plan(node)
-            if plan is None:
+                fell = {"shape": list(S.shape), "entries": S.nnz,
+                        "bytes": 4 * S.shape[0] * S.shape[1]}
+                if fell not in self.densified:
+                    self.densified.append(fell)
                 blk = S.to_block(self.mesh, self.config).data
                 self._ran("xla")
-                return strategies.run_matmul("xla", ev(l), blk, self.mesh,
-                                             self.config,
-                                             epilogue=epilogue)
-            a = ev(l)
-            out = self._coo_spmv_stack(
-                plan, [a[i, : l.shape[1]] for i in range(k)]).T
-            return fin(self._pad_to_node(out, node))
+                a, b = (ev(l), blk) if flipped else (blk, ev(r))
+                return strategies.run_matmul("xla", a, b, self.mesh,
+                                             self.config, epilogue=epilogue)
+            from matrel_tpu.core.coo import plan_facts
+            k = dense.shape[0] if flipped else dense.shape[1]
+            facts = {"orientation": "transposed" if flipped else "forward",
+                     "k": k, **plan_facts(plan, S.nnz)}
+            if facts not in self.spmm:      # a retrace says it again
+                self.spmm.append(facts)
+            x = ev(dense)
+            x = (x.T if flipped else x)[: plan.n_cols, :k]
+            # here the plan's tables move to the device, once a process
+            with trace_lib.span("spmm.plan", hit=False, **facts):
+                out = self._coo_spmv_stack(plan, x)
+            return fin(self._pad_to_node(out.T if flipped else out, node))
         if l.kind == "sparse_leaf":
             from matrel_tpu.ops import spmm as spmm_lib
             return spmm_lib.apply(l.attrs["matrix"], ev(r), r.shape,
@@ -1381,6 +1395,18 @@ def _hbm_meta(opts, mesh, cfg) -> Dict:
     return meta
 
 
+def _coo_meta(meta: Dict, low: "Lowerer") -> None:
+    """What the lowering said of the plan's coo_leaf products, for
+    ``plan.meta`` — present only where the plan has such a product (the
+    trace has run: the lists are whole): ``spmm``, the SpMV plans that
+    answer (a ``matrel.spmm.plan`` span each at every dispatch), and
+    ``densified_products``, the leaves that were densified instead."""
+    if low.spmm:
+        meta["spmm"] = low.spmm
+    if low.densified:
+        meta["densified_products"] = low.densified
+
+
 def _verify_plans(opts, mesh, cfg) -> Optional[List[dict]]:
     """Run the static verifier (matrel_tpu/analysis/) over annotated
     roots when ``config.verify_plans`` asks for it — PRE-execution,
@@ -1458,6 +1484,7 @@ def compile_exprs(exprs, mesh: Optional[Mesh] = None,
             "trace_ms": round(sp_tr.dur_ms, 3),
             "rule_hits": rule_hits,
             "executors": low.executors or ["xla"], **hbm}
+    _coo_meta(meta, low)
     if verify_diags is not None:
         meta["diagnostics"] = verify_diags
     prec_meta = _precision_meta(opts, cfg)
@@ -1471,9 +1498,12 @@ def compile_exprs(exprs, mesh: Optional[Mesh] = None,
                      extra_args=extra, meta=meta)
 
 
-# Narrow-operand threshold for the COO SpMV dispatch. The planner's
-# layout inference calls _coo_dispatch_plan itself (not this constant)
-# so the plan-refusal fallback is honoured too.
+# The widest dense side the COO SpMV tables multiply: the columns one
+# pass of the k-wide kernel takes (a gathered float32 row fills 128 lanes,
+# ops/pallas_spmv.WIDE_COLS). A wider side, or a matrix whose plan was
+# refused, densifies the leaf; the planner asks _coo_dispatch_plan itself
+# (not this constant), so it prices, and on one device refuses, exactly
+# the fall-through that would run.
 COO_NARROW_MAX = 128
 
 
@@ -1606,20 +1636,24 @@ def spgemm_kernel_choice(node: MatExpr, config=None, mesh=None):
 
 
 def _coo_dispatch_plan(node: MatExpr):
-    """The EdgeSpMVPlan a coo_leaf matmul node will dispatch through
-    _coo_spmv_stack, or None (the densify path). SINGLE source of truth
-    for the narrow-operand dispatch, shared by Lowerer._matmul and the
-    autotune walk so the two can never drift."""
+    """The plan a coo_leaf matmul node will dispatch through
+    _coo_spmv_stack — the orientation's EdgeSpMVPlan, or for a k-wide
+    product over more sources than one gather table holds its
+    PanelledPlan (core/coo.py) — or None (the densify path). SINGLE
+    source of truth for the narrow-operand dispatch, shared by
+    Lowerer._matmul, the planner and the autotune walk so they can never
+    drift."""
     l, r = node.children
-    if l.kind == "coo_leaf":
-        k = r.shape[1]
-        return (l.attrs["matrix"]._get_plan()
-                if 0 < k <= COO_NARROW_MAX else None)
-    if r.kind == "coo_leaf":
-        k = l.shape[0]
-        return (r.attrs["matrix"]._get_plan_t()
-                if 0 < k <= COO_NARROW_MAX else None)
-    return None
+    flipped = l.kind != "coo_leaf"
+    if flipped and r.kind != "coo_leaf":
+        return None
+    m = (r if flipped else l).attrs["matrix"]
+    k = l.shape[0] if flipped else r.shape[1]
+    if not 0 < k <= COO_NARROW_MAX:
+        return None
+    if k > 1:
+        return m._get_wide_plan(transposed=flipped)
+    return m._get_plan_t() if flipped else m._get_plan()
 
 
 def _autotune_spmv_choices(opts, mesh, cfg) -> dict:
@@ -1641,7 +1675,9 @@ def _autotune_spmv_choices(opts, mesh, cfg) -> dict:
         if n.kind == "matmul" and any(c.kind == "coo_leaf"
                                       for c in n.children):
             plan = _coo_dispatch_plan(n)
-            if plan is not None and id(plan) not in choices:
+            if (plan is not None and not hasattr(plan, "parts")
+                    and plan.chunk_block is None
+                    and id(plan) not in choices):
                 best = autotune.lookup_or_measure_spmv(plan, mesh, cfg)
                 if best is not None:
                     choices[id(plan)] = (plan, best)
@@ -1708,6 +1744,7 @@ def compile_expr(expr: MatExpr, mesh: Optional[Mesh] = None,
             "trace_ms": round(sp_tr.dur_ms, 3),
             "rule_hits": rule_hits,
             "executors": low.executors or ["xla"], **hbm}
+    _coo_meta(meta, low)
     if verify_diags is not None:
         meta["diagnostics"] = verify_diags
     prec_meta = _precision_meta((opt,), cfg)

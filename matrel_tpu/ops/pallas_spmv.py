@@ -292,7 +292,8 @@ def compact_tables(plan: spmv_lib.EdgeSpMVPlan):
 _TEMP_BYTES_A_SLOT = 224
 # what a plan holds a slot whatever the panels: the compact tables
 # (src8 4, lane 1, off 4, val 4) and the matvec's slot weights ``w`` (4)
-RESIDENT_BYTES_A_SLOT = 13 + 4
+TABLE_BYTES_A_SLOT = 13
+RESIDENT_BYTES_A_SLOT = TABLE_BYTES_A_SLOT + 4
 # and a slot of its hub chunks (idx 4, off 4, val 4): their ``w`` is
 # made in VMEM, and they take no part in the panels
 HUB_BYTES_A_SLOT = 12
@@ -313,16 +314,18 @@ def _hbm_limit() -> int:
     return hbm_limit_bytes(Mesh(np.asarray(jax.devices()[:1]), ("x",)))
 
 
-def panel_rows(rows: int, cap: int) -> int:
+def panel_rows(rows: int, cap: int,
+               a_slot: int = _TEMP_BYTES_A_SLOT) -> int:
     """How many table rows (blocks or chunks, ``cap`` slots each) a
-    panel of the matvec takes: all of them where their temporaries stay
+    panel of the matvec takes (``a_slot`` bytes of temporaries a slot;
+    the k-wide product's are its own): all of them where they stay
     under ``_PANEL_SHARE`` of the device's memory, else the rows spread
     evenly over the fewest panels that do (the last panel is moved back
     to end with the tables, so an uneven split would gather its overlap
     twice: 11.7% of a Graph500 scale-22 round, my chip run, PR 33), up
     to a multiple of ``_PANEL_ROWS_MULTIPLE`` where that still fits."""
     room = _PANEL_SHARE * _hbm_limit()
-    most = max(1, int(room // (_TEMP_BYTES_A_SLOT * cap)))
+    most = max(1, int(room // (a_slot * cap)))
     if most >= rows:
         return rows
     per = -(-rows // -(-rows // most))
@@ -544,122 +547,249 @@ def spmv_compact_sharded(plan: spmv_lib.EdgeSpMVPlan, x: jax.Array,
 
 
 # -- k-wide (SpMM) -----------------------------------------------------------
+# Y = A·X for a dense X of k columns. A slot's whole row of X is fetched
+# ONCE (XLA's row gather: a row of up to 128 float32 fills 128 lanes
+# whatever it holds, so 128 columns cost what 8 do), in panels of table
+# rows as the matvec's ``w``, and one chunk-grid kernel scatters a panel:
+# per 128 slots a (block, 128) one-hot of their destination rows, exact in
+# bfloat16, times the slots' rows carved into ``passes`` bfloat16 parts —
+# plain MXU products, the block's (block, 128) tile resident over its
+# consecutive chunks. ``val`` multiplies inside the kernel: XLA does not
+# fuse a multiply into its gather, and a pass of its own over the
+# gathered rows is 1 KB a slot of HBM traffic (PERF.md §6, PR 37). Both
+# layouts take this one kernel: a blocks-layout row is walked as
+# ``capacity / tile`` chunks of its block.
 
-_COL_CHUNK = 8          # lo·passes·chunk = 256 lanes in the rhs concat
+WIDE_COLS = LANE        # columns a pass of the k-wide product takes
+# What a panel of the k-wide product keeps alive a slot, read off the
+# described-v5e compile at the Netflix shape (tests/test_chip_compile.py:
+# 2.66 GB of temporaries for a panel of 4.19M slots, of which 0.49 GB are
+# two copies of the 246 MB output): the gathered row (512 B), the index
+# and the panel's slices of the tables.
+_TEMP_BYTES_A_SLOT_WIDE = 4 * WIDE_COLS + 24
 
 
-def _make_scatter_kernel_k(hi_n: int, lo: int, passes: int, k: int):
-    def kernel(off_ref, w_ref, y_ref):
-        off = off_ref[0]                                 # (cr, 128)
-        w = w_ref[0]                                     # (cr, k, 128)
-        cr = off.shape[0]
-        ids_hi = jax.lax.broadcasted_iota(
-            jnp.int32, (cr, hi_n, LANE), 1)
-        oh_hi = ((off // lo)[:, None, :] == ids_hi).astype(jnp.bfloat16)
-        ids_lo = jax.lax.broadcasted_iota(
-            jnp.int32, (cr, lo, LANE), 1)
-        mask = (off % lo)[:, None, :] == ids_lo          # shared by cols
-        # pass-major part order: the per-pass fold below is then two
-        # (hi, k·lo) slices at 128-aligned offsets — Mosaic rejects the
-        # 4D minor-dim reshape a column-major order would need
-        splits = [_bf16_split(w[:, j, :], passes) for j in range(k)]
-        parts = [jnp.where(mask, splits[j][pi][:, None, :], 0.0)
-                 for pi in range(passes) for j in range(k)]
-        rhs = jnp.concatenate(parts, axis=1).astype(
-            jnp.bfloat16)                                # (cr,p·k·lo,128)
-        t = jax.lax.dot_general(
-            oh_hi, rhs,
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)          # (cr,hi,p·k·lo)
-        ts = jnp.sum(t, axis=0)                          # (hi, p·k·lo)
-        th = ts[:, :k * lo]
-        for pi in range(1, passes):
-            th = th + ts[:, pi * k * lo:(pi + 1) * k * lo]
-        y_ref[0] = th                                    # (hi, k·lo)
+def _make_wide_scatter_kernel(block: int, cr: int, passes: int):
+    def kernel(cb_ref, skip_ref, off_ref, val_ref, g_ref, acc_ref, y_ref):
+        # the block's tile starts from what the panels and parts before
+        # this call summed (``acc``, which the output aliases)
+        @pl.when(_first_chunk_of_its_block(cb_ref))
+        def _():
+            y_ref[...] = acc_ref[...]
+
+        # the last panel is moved back to end with the tables: the
+        # chunks it shares with the one before are not added twice
+        @pl.when(pl.program_id(0) >= skip_ref[0])
+        def _():
+            off, val = off_ref[0], val_ref[0]                # (cr, 128)
+            rows = jax.lax.broadcasted_iota(jnp.int32, (block, LANE), 0)
+            eye = (jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 0)
+                   == jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 1))
+            acc = jnp.zeros((block, WIDE_COLS), jnp.float32)
+            for s in range(cr):
+                oh = (off[s:s + 1, :] == rows).astype(jnp.bfloat16)
+                # the slots' values from the lanes onto the sublanes:
+                # a diagonal select and a lane sum of one term, exact
+                col = jnp.sum(jnp.where(eye, val[s:s + 1, :], 0.0),
+                              axis=1, keepdims=True)         # (128, 1)
+                w = g_ref[0, s * LANE:(s + 1) * LANE, :] * col
+                for part in _bf16_split(w, passes):
+                    # one MXU pass a part, whatever matmul precision the
+                    # caller's context asks of its own float32 dots
+                    acc = acc + jnp.dot(oh, part.astype(jnp.bfloat16),
+                                        precision=jax.lax.Precision.DEFAULT,
+                                        preferred_element_type=jnp.float32)
+            y_ref[0] += acc
 
     return kernel
 
 
 @functools.lru_cache(maxsize=32)
-def _compact_runner_k(nb: int, cap: int, block: int, lo: int,
-                      passes: int, k: int, interpret: bool):
-    hi_n = block // lo
-    cr = cap // LANE
+def _wide_runner(n_chunks: int, chunk: int, nb: int, block: int,
+                 passes: int, interpret: bool):
+    """scatter(chunk_block, skip, off, val, rows, acc) -> acc + the
+    chunks' block sums, (nb, block, 128): ``rows`` are the slots'
+    gathered rows of X, (n_chunks, chunk, 128); the first ``skip[0]``
+    chunks add nothing."""
+    cr = chunk // LANE
+    slots = pl.BlockSpec((1, cr, LANE), lambda c, cb, skip: (c, 0, 0))
+    sums = pl.BlockSpec((1, block, WIDE_COLS),
+                        lambda c, cb, skip: (cb[c], 0, 0))
     return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
-        _make_scatter_kernel_k(hi_n, lo, passes, k),
-        name="matrel_spmv_scatter",
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, cr, LANE), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, cr, k, LANE), lambda b: (b, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, hi_n, k * lo), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, hi_n, k * lo), jnp.float32),
+        _make_wide_scatter_kernel(block, cr, passes),
+        name="matrel_spmm_scatter_chunks",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                       # chunk_block, skip
+            grid=(n_chunks,),
+            in_specs=[slots, slots,
+                      pl.BlockSpec((1, chunk, WIDE_COLS),
+                                   lambda c, cb, skip: (c, 0, 0)),
+                      sums],
+            out_specs=sums,
+        ),
+        out_shape=jax.ShapeDtypeStruct((nb, block, WIDE_COLS), jnp.float32),
+        input_output_aliases={5: 0},                     # acc
         compiler_params=compat.tpu_compiler_params(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )
+
+
+def wide_panel_rows(rows: int, cap: int) -> int:
+    """:func:`panel_rows` for the k-wide product, whose panel holds a
+    slot's whole gathered row."""
+    return panel_rows(rows, cap, _TEMP_BYTES_A_SLOT_WIDE)
+
+
+def wide_panel_bytes(rows: int, cap: int) -> int:
+    """One panel's temporaries of the k-wide product over a plan of
+    ``rows`` x ``cap`` slots."""
+    return _TEMP_BYTES_A_SLOT_WIDE * wide_panel_rows(rows, cap) * cap
+
+
+def wide_plan_bytes(rows: int, cap: int) -> int:
+    """What a compact plan of ``rows`` x ``cap`` slots holds of one
+    device while a k-wide product runs: its tables and one panel's
+    temporaries (the output and the dense side are the caller's)."""
+    return TABLE_BYTES_A_SLOT * rows * cap + wide_panel_bytes(rows, cap)
+
+
+def _as_chunks(src, off, val, chunk_block):
+    """The tables as the chunk kernel walks them. A blocks-layout row of
+    ``cr`` x 128 slots becomes ``cr / d`` chunks of its block, ``d`` the
+    largest divisor of ``cr`` that keeps a chunk within ``spmv.CHUNK``
+    slots: the same memory, read as more rows."""
+    if chunk_block is not None:
+        return src, off, val, chunk_block
+    rows, cr, _ = off.shape
+    most = spmv_lib.CHUNK // LANE
+    d = next(d for d in range(min(cr, most), 0, -1) if cr % d == 0)
+    shp = (rows * (cr // d), d, LANE)
+    return (src.reshape(shp), off.reshape(shp), val.reshape(shp),
+            jnp.asarray(np.repeat(np.arange(rows, dtype=np.int32), cr // d)))
+
+
+def _chunk_sets(tables, n_cols: int):
+    """compact_tables() of either layout as the sets of chunk tables
+    ``(src, off, val, chunk_block)`` the k-wide kernel walks: the plan's
+    own, and its hub chunks' where it has any."""
+    src8, lane, off, val, *chunks = tables
+    chunk_block, *hub = chunks if chunks else (None,)
+    src = src8 * spmv_lib.WIDTH + lane.astype(jnp.int32)
+    sets = [_as_chunks(src, off, val, chunk_block)]
+    if hub:
+        # a hub slot names its source by rank; the padded slots' rank,
+        # one past the last hub, reads the zero row
+        ids, idx, hub_off, hub_val, hub_block = hub
+        ids = jnp.concatenate([ids, jnp.full((1,), n_cols, ids.dtype)])
+        sets.append((ids[idx], hub_off, hub_val, hub_block))
+    return sets
+
+
+def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool):
+    """``y`` (nb, block, 128) plus the block sums of every set of chunk
+    tables against the rows of ``X`` (the set's columns and a zero row
+    for the padded slots, 128 wide), a panel of chunks at a time."""
+    nb = y.shape[0]
+    for src, off, val, cb in sets:
+        rows, cr, _ = off.shape
+        chunk = cr * LANE
+        per = wide_panel_rows(rows, chunk)
+        run = _wide_runner(per, chunk, nb, block, passes, interpret)
+
+        def panel(i, y):
+            at = jnp.minimum(i * per, rows - per)
+            s, o, v, c = (jax.lax.dynamic_slice_in_dim(a, at, per)
+                          for a in (src, off, val, cb))
+            g = X.at[s.reshape(-1)].get(mode="promise_in_bounds")
+            skip = jnp.reshape(i * per - at, (1,)).astype(jnp.int32)
+            return run(c, skip, o, v, g.reshape(per, chunk, WIDE_COLS), y)
+
+        if per >= rows:
+            y = panel(jnp.int32(0), y)
+        else:
+            y = jax.lax.fori_loop(0, -(-rows // per), panel, y)
+    return y
+
+
+def compact_matmat_parts(plan_static, part_statics, part_arrays,
+                         X: jax.Array, passes: int = 3,
+                         interpret: bool = False) -> jax.Array:
+    """Traceable body: Y = A·X for dense X (n_cols, k), A given as one
+    compact plan a range of its columns (COOMatrix's source panels; a
+    plan whole is one part at 0), every one adding onto the same block
+    sums: ``part_statics`` = ((col0, its plan_static), ...) and
+    ``part_arrays`` = ((its compact_tables(), its overflow), ...). More
+    than 128 columns run 128 at a time."""
+    n_rows, _, block, _ = plan_static
+    nb = -(-n_rows // block)
+    k = X.shape[1]
+    Xf = X.astype(jnp.float32)
+    outs = []
+    for j0 in range(0, k, WIDE_COLS):
+        kc = min(WIDE_COLS, k - j0)
+        y = jnp.zeros((nb, block, WIDE_COLS), jnp.float32)
+        for (col0, (_, n_cols, _, _)), (tables, _) in zip(part_statics,
+                                                         part_arrays):
+            # padded slots (src == n_cols) read a zero row; the columns
+            # are padded to the lanes a gathered row fills anyway
+            Xp = jnp.pad(Xf[col0:col0 + n_cols, j0:j0 + kc],
+                         ((0, spmv_lib.WIDTH), (0, WIDE_COLS - kc)))
+            y = _wide_accumulate(y, _chunk_sets(tables, n_cols), Xp, block,
+                                 passes, interpret)
+        outs.append(y.reshape(-1, WIDE_COLS)[:n_rows, :kc])
+    Y = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    for (col0, (_, n_cols, _, _)), (_, ov) in zip(part_statics,
+                                                  part_arrays):
+        if ov:
+            Y = spmv_lib._overflow_add_wide(Y, ov, X[col0:col0 + n_cols],
+                                            n_rows)
+    return Y
 
 
 def compact_matmat_apply(plan_static, tables, ov, X: jax.Array,
                          passes: int = 3,
                          interpret: bool = False) -> jax.Array:
-    """Traceable body: Y = A·X for dense X (n_cols, k). One shared
-    full-index gather serves every column; the scatter masks are built
-    once per block and contracted against all of a chunk's columns."""
-    n_rows, n_cols, block, lo = plan_static
-    src8, lane, off, val = tables
-    nb, cr, _ = src8.shape
-    k = X.shape[1]
-    k_pad = -(-k // _COL_CHUNK) * _COL_CHUNK   # full chunks: the kernel's
-    src_full = src8 * spmv_lib.WIDTH + lane.astype(jnp.int32)
-    # sentinel src_full == n_cols must read 0 (padded slots); zero
-    # columns pad k to the chunk width (sliced off at the end)
-    X_pad = jnp.concatenate(
-        [X.astype(jnp.float32),
-         jnp.zeros((spmv_lib.WIDTH, k), jnp.float32)])
-    if k_pad != k:
-        X_pad = jnp.pad(X_pad, ((0, 0), (0, k_pad - k)))
-    outs = []
-    for j0 in range(0, k_pad, _COL_CHUNK):
-        kc = _COL_CHUNK
-        g = jnp.take(X_pad[:, j0:j0 + kc], src_full, axis=0)
-        w = (g * val[..., None]).transpose(0, 1, 3, 2)   # (nb,cr,kc,128)
-        scatter = _compact_runner_k(nb, cr * LANE, block, lo, passes,
-                                    kc, interpret)
-        y = scatter(off, w)                              # (nb,hi,kc·lo)
-        y = y.reshape(nb, block // lo, kc, lo).transpose(0, 1, 3, 2)
-        outs.append(y.reshape(-1, kc)[:n_rows])
-    Y = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    Y = Y[:, :k]
-    if ov:
-        Y = spmv_lib._overflow_add_wide(Y, ov, X, n_rows)
-    return Y
+    """Traceable body: Y = A·X for dense X (n_cols, k). ``tables`` from
+    compact_tables(), either layout, with hub chunks or none."""
+    return compact_matmat_parts(plan_static, ((0, plan_static),),
+                                ((tables, ov),), X, passes, interpret)
 
 
-_compact_matmat_jitted = jax.jit(compact_matmat_apply,  # matlint: disable=ML010 pre-seam ops runner cache — the porting worklist (the ML009 legacy-kernel idiom)
-                                 static_argnums=(0, 4, 5))
+_compact_matmat_jitted = jax.jit(compact_matmat_parts,  # matlint: disable=ML010 pre-seam ops runner cache — the porting worklist (the ML009 legacy-kernel idiom)
+                                 static_argnums=(0, 1, 4, 5))
 
 
-def spmm_compact(plan: spmv_lib.EdgeSpMVPlan, X: jax.Array,
-                 passes: int = 3, interpret=None) -> jax.Array:
-    """Y = A·X via compact tables (see spmv_compact). k == 1 takes the
-    matvec kernel (its byte-row gather beats the k-wide float32 one).
+def plan_operands(plan):
+    """(plan_static, part_statics, part_arrays) of an EdgeSpMVPlan or of
+    a plan in source panels (anything with ``parts`` = ((col0, plan),
+    ...), ``n_rows``, ``n_cols``, ``block``: core.coo.PanelledPlan), as
+    :func:`compact_matmat_parts` takes them; the parts' tables move to
+    the device on first use."""
+    parts = getattr(plan, "parts", None) or ((0, plan),)
+    static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
+    return (static,
+            tuple((col0, (p.n_rows, p.n_cols, p.block, spmv_lib.LO))
+                  for col0, p in parts),
+            tuple((compact_tables(p), p.overflow) for _, p in parts))
+
+
+def spmm_compact(plan, X: jax.Array, passes: int = 3,
+                 interpret=None) -> jax.Array:
+    """Y = A·X via compact tables (see spmv_compact), either layout, or
+    a plan in source panels. k == 1 takes the matvec kernel (its
+    byte-row gather moves a quarter of what a float32 row does).
     passes=3 is f32-faithful — the same fidelity as the expanded path it
     replaces; pass 2 only where ranking-grade error is acceptable."""
     interpret = _resolve_interpret(interpret)
     X = jnp.asarray(X, jnp.float32)
     if X.shape[1] == 0:
         return jnp.zeros((plan.n_rows, 0), jnp.float32)
-    if X.shape[1] > 1:
-        spmv_lib._blocks_layout_only(plan, "the k-wide compact kernels "
-                                     "(_compact_runner_k)")
-    if X.shape[1] == 1:
+    if X.shape[1] == 1 and not hasattr(plan, "parts"):
         return spmv_compact(plan, X[:, 0], passes=passes,
                             interpret=interpret)[:, None]
-    tables = compact_tables(plan)
-    static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
-    return _compact_matmat_jitted(static, tables, plan.overflow, X,
+    static, part_statics, part_arrays = plan_operands(plan)
+    return _compact_matmat_jitted(static, part_statics, part_arrays, X,
                                   passes, interpret)
 
 

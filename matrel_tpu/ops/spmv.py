@@ -43,9 +43,9 @@ reference's plan-per-query model. A plan has one of two layouts:
   chunks. On a Graph500 Kronecker graph of scale 22 (largest block 189k
   edges, median 25k) that is 1.04 slots an edge where one capacity for
   every block takes 2.98 and still leaves 362k edges to the scalar tail.
-  Only the compact-table Pallas matvec (ops/pallas_spmv.py, one device)
-  takes it; the expanded tables, ``shard_plan`` and the k-wide kernels say
-  so by name. Where the sources are skewed too (PR 36), the edges whose
+  Only the compact-table Pallas executors (ops/pallas_spmv.py, one
+  device: the matvec and, since PR 37, the k-wide product) take it; the
+  expanded tables and ``shard_plan`` say so by name. Where the sources are skewed too (PR 36), the edges whose
   source is among the ``128·M`` of largest out-degree, the *hubs*, lie in
   a second set of chunks (``HubChunks``): a slot there names its source
   by rank, and the matvec takes ``x`` for it from a ``(M, 128)`` table in
@@ -125,6 +125,16 @@ def _ext_table(x: jax.Array, width: int = WIDTH) -> jax.Array:
 # the row (PERF.md §6, PR 28).
 _ROW_BYTES_PADDED = 128
 _FAST_TABLE_BYTES = 64 << 20
+
+
+def source_panels(n_cols: int) -> int:
+    """Into how many column ranges a k-wide product over ``n_cols``
+    sources is split so that each range's gather table keeps the row
+    rate: a float32 row of up to 128 columns is 512 B in the chip's
+    tiles whatever it holds, and the table (the range's rows and the
+    zero row of the padded slots) has to stay within
+    ``_FAST_TABLE_BYTES``. One below 131,065 sources."""
+    return -(-(n_cols + WIDTH) * 4 * _ROW_BYTES_PADDED // _FAST_TABLE_BYTES)
 
 
 def _row_values(n: int) -> int:
@@ -311,14 +321,15 @@ class EdgeSpMVPlan:
 
 
 def _blocks_layout_only(plan: EdgeSpMVPlan, who: str) -> None:
-    """Only the compact-table Pallas matvec walks chunks; every other
-    executor reads row i of the tables as block i."""
+    """Only the compact-table Pallas executors of one device walk
+    chunks; every other executor reads row i of the tables as block
+    i."""
     if plan.chunk_block is not None:
         raise ValueError(
             f"{who} take only the blocks layout of an EdgeSpMVPlan; this "
             "plan is laid out in chunks (build_spmv_plan layout='auto' "
             "or 'chunks'): build it with layout='blocks', or run it "
-            "through ops.pallas_spmv.spmv_compact")
+            "through ops.pallas_spmv.spmv_compact / spmm_compact")
 
 
 @jax.jit  # matlint: disable=ML010 pre-seam ops runner cache — the porting worklist (the ML009 legacy-kernel idiom)
@@ -351,7 +362,8 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
                     max_padding: float = 4.0,
                     max_slots: Optional[int] = None,
                     layout: str = "blocks",
-                    refusals: Optional[list] = None
+                    refusals: Optional[list] = None,
+                    hubs: bool = True
                     ) -> Optional[EdgeSpMVPlan]:
     """Host-side plan build (numpy, once per graph).
 
@@ -361,7 +373,9 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
     chunks of ``CHUNK`` slots as its edges need, and nothing overflows;
     the edges from the sources of largest out-degree go to chunks of
     their own (``plan.hubs``) where :func:`_hub_rows` finds the sources
-    skewed enough, a choice made from ``cols`` alone.
+    skewed enough, a choice made from ``cols`` alone (``hubs=False``:
+    never — a plan that also serves the k-wide product, which fetches a
+    hub's whole row like any other, gains nothing from a second set).
     ``"auto"`` (for a caller whose executor takes both: the compact
     Pallas matvec on one device) picks ``chunks`` where that walks
     fewer slots, an overflow edge counted as ``_OVERFLOW_EDGE_SLOTS``,
@@ -412,7 +426,8 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
         ) else "blocks"
     hub_ids = None
     if layout == "chunks":
-        hub_ids, hub_rank = _choose_hubs(cols, n_cols)
+        if hubs:
+            hub_ids, hub_rank = _choose_hubs(cols, n_cols)
         if hub_ids is not None:
             hub_cnt = (native.spmv_counts_hubs(rows, cols, hub_rank, block,
                                                nb) if use_native else None)

@@ -515,6 +515,41 @@ def _coo_narrow_matmul(n: MatExpr) -> bool:
     return False
 
 
+def coo_product(node: MatExpr, mesh: Mesh) -> Optional[dict]:
+    """How a matmul with a coo_leaf operand will run, and what that
+    keeps on one device beside the dense operand and the output — None
+    for any other node. Through the matrix's SpMV plan
+    (executor._coo_dispatch_plan, the single source of truth):
+    ``chosen`` "coo_spmm", the plan's ``layout``, its ``panels`` (of
+    table rows, of sources) and ``bytes``, the compact tables and one
+    panel's temporaries of the k-wide product (core.coo.plan_facts). Or,
+    where the dense side is wider than the tables multiply or the plan
+    was refused, by DENSIFYING the leaf: ``chosen`` "densify", ``bytes``
+    the dense float32 copy on the mesh's padded shape, ``why``."""
+    l, r = node.children
+    if l.kind != "coo_leaf" and r.kind != "coo_leaf":
+        return None
+    from matrel_tpu import executor as _exec
+    from matrel_tpu.core import coo as coo_lib, padding
+    flipped = l.kind != "coo_leaf"
+    leaf = r if flipped else l
+    plan = _exec._coo_dispatch_plan(node)
+    per_device = max(mesh.size, 1)
+    if plan is not None:
+        facts = coo_lib.plan_facts(plan, leaf.attrs["matrix"].nnz)
+        return {"chosen": "coo_spmm", "layout": facts["layout"],
+                "panels": (facts["panels"], facts["source_panels"]),
+                "bytes": facts["plan_bytes"] / per_device}
+    k = l.shape[0] if flipped else r.shape[1]
+    why = (f"its dense side has {k} columns, more than the "
+           f"{_exec.COO_NARROW_MAX} the SpMV tables multiply"
+           if k > _exec.COO_NARROW_MAX else
+           "build_spmv_plan refused its layout (padding)")
+    pn, pm = padding.padded_shape(leaf.shape, mesh)
+    return {"chosen": "densify", "why": why,
+            "bytes": 4.0 * pn * pm / per_device}
+
+
 def infer_dtype(node: MatExpr, config: Optional[MatrelConfig] = None,
                 memo: Optional[dict] = None):
     """Statically-known output dtype of ANY expression node, or None.
@@ -1953,6 +1988,18 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
             if hbm["refused_hbm"]:
                 stamp["refused_hbm"] = tuple(hbm["refused_hbm"])
             stamp["hbm_plan_bytes"] = hbm["hbm_plan_bytes"]
+            coo = coo_product(e, mesh)
+            if coo is not None:
+                # a sparse operand's tables are its plan's and were not
+                # among the leaves reckoned: what the product keeps
+                # beside its dense operand and its output is the SpMV
+                # plan's tables and panel, or the leaf's dense copy
+                need = int(hbm["hbm_plan_bytes"] + coo["bytes"])
+                limit = mesh_lib.hbm_limit_bytes(mesh, config)
+                stamp.update(coo_product=coo, hbm_plan_bytes=need)
+                stamp.pop("refused_hbm", None)
+                if 0 < limit < need:
+                    stamp["refused_hbm"] = (coo["chosen"],)
         e = e.with_attrs(**stamp)
         if long_gram(e, mesh, config, memo) is not None:
             # engagement counter of the triangle lowering: block
@@ -2024,6 +2071,16 @@ def hbm_report(root: MatExpr) -> list:
                         "panels": list(n.attrs.get("panels", (1, 1))),
                         "moves_under_dot": n.attrs.get("moves_under_dot", 0),
                         "hbm_plan_bytes": n.attrs["hbm_plan_bytes"]})
+            coo = n.attrs.get("coo_product")
+            if coo is not None:
+                # a coo_leaf product: what runs is the SpMV plan or the
+                # densified leaf, not the stamped dense strategy
+                out[-1]["chosen"] = coo["chosen"]
+                if coo["chosen"] == "coo_spmm":
+                    out[-1].update(layout=coo["layout"],
+                                   panels=list(coo["panels"]))
+                else:
+                    out[-1]["densified_bytes"] = int(coo["bytes"])
             if "gram_tiles" in n.attrs:
                 out[-1]["gram_tiles"] = list(n.attrs["gram_tiles"])
             for stamp in ("gram_rides", "rides_gram"):
@@ -2052,6 +2109,23 @@ def refuse_over_limit(roots, mesh: Mesh,
             continue
         own = int(device_bytes(n, mesh, config))
         limit = mesh_lib.hbm_limit_bytes(mesh, config)
+        coo = n.attrs.get("coo_product")
+        if coo is not None and coo["chosen"] == "densify":
+            leaf = next(c for c in n.children if c.kind == "coo_leaf")
+            raise PlanMemoryError(
+                f"plan refused before tracing: the product "
+                f"{n.children[0].shape[0]}x{n.children[0].shape[1]} * "
+                f"{n.children[1].shape[0]}x{n.children[1].shape[1]} would "
+                f"DENSIFY its element-sparse operand "
+                f"({leaf.shape[0]}x{leaf.shape[1]}, "
+                f"{leaf.attrs['matrix'].nnz:,} entries: "
+                f"{int(coo['bytes']):,} bytes as float32) because "
+                f"{coo['why']}; with it the plan holds "
+                f"{n.attrs['hbm_plan_bytes']:,} bytes on the one device, "
+                f"over the limit of {limit:,} bytes. Bracket the query so "
+                f"that the sparse matrix meets a dense side of at most "
+                f"128 columns (A * (B * C), not (A * B) * C), or multiply "
+                f"column panels of the dense side.")
         raise PlanMemoryError(
             f"plan refused before tracing: at {n.kind} "
             f"{n.shape[0]}x{n.shape[1]} ({own:,} bytes of its own) the "

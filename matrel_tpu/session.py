@@ -85,6 +85,10 @@ class MatrelSession:
         # hoisted-byte bounds
         self._plan_cache: "OrderedDict[str, executor_lib.CompiledPlan]" \
             = OrderedDict()
+        # the newest dispatched plan and whether its lookup was a hit
+        # (last_plan())
+        self._last_plan = None
+        self._last_hit = False
         self._plan_cache_bytes = 0
         self._plan_cache_evicted = 0
         self._event_log = None      # lazily built (obs_level != "off")
@@ -494,8 +498,30 @@ class MatrelSession:
             plan = self._plan_cache.get(key)
             if plan is not None:
                 self._plan_cache.move_to_end(key)
-        sp.set(hit=plan is not None)
+        self._last_hit = plan is not None
+        sp.set(hit=self._last_hit)
         return plan
+
+    def last_plan(self) -> dict:
+        """What the newest dispatched plan says of itself — for a caller
+        outside a profiler session, where the spans are dark (the
+        ``workloads.pagerank.last_plan`` idiom): ``hit`` (the plan cache
+        or a plan template answered the lookup), ``executors``,
+        ``hbm_plan_bytes``, and of its coo_leaf products ``spmm`` (one
+        record each that the SpMV tables answer: what its
+        ``matrel.spmm.plan`` span carries) and ``densified_products``
+        (one each whose leaf was densified). Copies; {} before the first
+        dispatch."""
+        plan = self._last_plan
+        if plan is None:
+            return {}
+        meta = plan.meta or {}
+        return {"hit": self._last_hit,
+                "executors": list(meta.get("executors") or ()),
+                "hbm_plan_bytes": meta.get("hbm_plan_bytes"),
+                "spmm": [dict(r) for r in meta.get("spmm", ())],
+                "densified_products": [
+                    dict(r) for r in meta.get("densified_products", ())]}
 
     def _plan_build(self, key: str, build, rung: int):
         """(plan, hit) after a probe missed: compile under the lock and
@@ -1623,11 +1649,18 @@ class MatrelSession:
         # HeldAcrossDispatch diagnostic — the PR 8 drain-wedge class
         # caught at runtime. One flag check when off.
         lockdep.note_dispatch("session.dispatch")
+        self._last_plan = plan
         with trace_lib.span("dispatch",
                             executors=plan.meta.get("executors"),
                             mesh=plan.meta.get("mesh"),
                             hbm_plan_bytes=plan.meta.get(
-                                "hbm_plan_bytes")):
+                                "hbm_plan_bytes")) as sp:
+            if sp.live:
+                # the SpMV plan each coo_leaf product of this program
+                # runs on: built and uploaded once, answered here
+                for rec in plan.meta.get("spmm", ()):
+                    with trace_lib.span("spmm.plan", hit=True, **rec):
+                        pass
             if self._exec_lock is None:
                 return plan.run(bindings=bindings)
             with self._exec_lock:
@@ -1797,8 +1830,16 @@ class MatrelSession:
         # plan-template probe (serve/mqo.py): a structurally
         # identical query modulo dense-leaf bindings rebinds into
         # the cached template's program — zero optimize/trace
-        tpl = (self._template_probe(e, sla, rung)
-               if self._cse_on() else None)
+        tpl = None
+        if self._cse_on():
+            # the lookup of a session with plan templates: a template
+            # answers a query whose dense leaves are new arrays (an
+            # iteration's factors) as the plan cache answers a repeat
+            with trace_lib.span("plan", via="template") as sp:
+                tpl = self._template_probe(e, sla, rung)
+                sp.set(hit=tpl is not None)
+            if tpl is not None:
+                self._last_hit = True
         if tpl is not None:
             plan, pkey, bindings = tpl
             hit, cache_label = True, "template_hit"

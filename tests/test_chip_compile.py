@@ -123,10 +123,84 @@ def test_pagerank_loop_gathers_no_padded_rows(one_chip):
 
 
 def test_compact_spmv_k_wide(one_chip):
+    """The blocks layout through the k-wide chunk kernel: a row of 5,376
+    slots walked as 3 chunks of 1,792."""
     static = (N_NODES, N_NODES, BLOCK, spmv_lib.LO)
-    X = _sds(one_chip, (N_NODES, pc._COL_CHUNK), jnp.float32)
-    _compile(pc._compact_matmat_jitted, static,
-             _compact_table_shapes(NB, one_chip), (), X, 3, False)
+    X = _sds(one_chip, (N_NODES, 8), jnp.float32)
+    compiled = _compile(pc._compact_matmat_jitted, static, ((0, static),),
+                        ((_compact_table_shapes(NB, one_chip), ()),), X, 3,
+                        False)
+    assert "matrel_spmm_scatter_chunks" in compiled.as_text()
+
+
+# The Netflix-shaped ratings matrix of cell gnmf_netflix_r128_1c (PR 37):
+# 480,189 users x 17,770 movies, 100,480,507 ratings in chunks of 2,048
+# slots (1.01 slots an entry), rank 128. Users as rows: one plan, the
+# 9.1 MB t(H) its gather table. Movies as rows: W is 246 MB, past what a
+# gather table keeps its row rate for, so four source panels of 120,048
+# users (61.5 MB) each.
+NF_USERS, NF_MOVIES, NF_RANK = 480_189, 17_770, 128
+NF_CHUNKS, NF_PANEL_CHUNKS, NF_PANEL_USERS = 49_560, 12_300, 120_048
+
+
+def _chunk_table_shapes(chunks, sharding):
+    shp = (chunks, spmv_lib.CHUNK // pc.LANE, pc.LANE)
+    return tuple(_sds(sharding, shp, dt) for dt in (
+        jnp.int32, jnp.int8, jnp.int32, jnp.float32)) + (
+        _sds(sharding, (chunks,), jnp.int32),)
+
+
+def test_gnmf_forward_product_runs_in_panels(one_chip, monkeypatch):
+    """V * t(H) at the full shape: gathering 512 B for every one of
+    101.5M slots at once is 52 GB on a 15.75 GB chip; in panels of
+    chunks the temporaries are what ``wide_plan_bytes`` reckons and the
+    three output-sized buffers of the aliased scatter, a slot's row is
+    gathered once for all 128 columns from a table in fast memory, and
+    the multiply by the ratings happens inside the kernel."""
+    monkeypatch.setattr(pc, "_hbm_limit", lambda: int(15.75 * 2 ** 30))
+    static = (NF_USERS, NF_MOVIES, BLOCK, spmv_lib.LO)
+    compiled = _compile(
+        pc._compact_matmat_jitted, static, ((0, static),),
+        ((_chunk_table_shapes(NF_CHUNKS, one_chip), ()),),
+        _sds(one_chip, (NF_MOVIES, NF_RANK), jnp.float32), 3, False)
+    text = compiled.as_text()
+    per = pc.wide_panel_rows(NF_CHUNKS, spmv_lib.CHUNK)
+    assert 1 < per < NF_CHUNKS
+    slots = per * spmv_lib.CHUNK
+    assert f"f32[{slots},128]" in text                  # a panel's rows
+    assert f"f32[{NF_CHUNKS * spmv_lib.CHUNK},128]" not in text
+    assert text.count(f"f32[{slots},128]{{1,0:T(8,128)}} fusion(") == 1, \
+        "the gathered rows are written once: no multiply pass of XLA's"
+    assert re.search(rf"f32\[{NF_MOVIES + 8},128\]\{{[^}}]*S\(1\)\}}", text)
+    stats = compiled.memory_analysis()
+    out = stats.output_size_in_bytes
+    reckoned = pc.wide_plan_bytes(NF_CHUNKS, spmv_lib.CHUNK) + 3 * out
+    taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 0.9 * reckoned < taken < 1.05 * reckoned, (reckoned, taken)
+
+
+def test_gnmf_transposed_product_gathers_from_source_panels(one_chip,
+                                                            monkeypatch):
+    """t(V) * W at the full shape: W whole (246 MB) is no fast gather
+    table; each of the four source panels' slices of it is (S(1))."""
+    monkeypatch.setattr(pc, "_hbm_limit", lambda: int(15.75 * 2 ** 30))
+    assert spmv_lib.source_panels(NF_USERS) == 4
+    assert spmv_lib.source_panels(NF_MOVIES) == 1
+    static = (NF_MOVIES, NF_USERS, BLOCK, spmv_lib.LO)
+    statics = tuple(
+        (c0, (NF_MOVIES, min(NF_PANEL_USERS, NF_USERS - c0), BLOCK,
+              spmv_lib.LO))
+        for c0 in range(0, NF_USERS, NF_PANEL_USERS))
+    compiled = _compile(
+        pc._compact_matmat_jitted, static, statics,
+        tuple((_chunk_table_shapes(NF_PANEL_CHUNKS, one_chip), ())
+              for _ in statics),
+        _sds(one_chip, (NF_USERS, NF_RANK), jnp.float32), 3, False)
+    text = compiled.as_text()
+    assert re.search(rf"f32\[{NF_PANEL_USERS + 8},128\]\{{[^}}]*S\(1\)\}}",
+                     text)
+    assert f"f32[{NF_USERS + 8},128]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.0e9
 
 
 def test_compact_spmv_sharded_2x2(mesh_2x2):

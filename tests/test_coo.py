@@ -607,3 +607,99 @@ def test_infer_dtype_asserts_coo_payload_f32(mesh8):
     with pytest.raises(TypeError, match="float32"):
         infer_dtype(A.multiply(
             BlockMatrix.from_numpy(x, mesh=mesh8).expr()))
+
+
+class TestWidePlans:
+    """The plans a COOMatrix keeps for the k-wide product (PR 37): the
+    layout by who will run them, the transposed orientation in source
+    panels where the dense side is no one gather table, one build an
+    orientation."""
+
+    @pytest.fixture
+    def on_one_chip(self, monkeypatch):
+        from matrel_tpu import config as config_lib
+        from matrel_tpu.config import MatrelConfig
+        from matrel_tpu.core import coo as coo_lib
+        from matrel_tpu.ops import spmv as spmv_lib
+        was = config_lib._default_config
+        config_lib.set_default_config(MatrelConfig(pallas_interpret=True))
+        monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")
+        monkeypatch.setattr(spmv_lib, "_SMALL_PLAN_SLOTS", 0)
+        yield
+        config_lib._default_config = was
+
+    def _skewed(self, rng, shape=(2600, 900), m=30_000):
+        rows = rng.integers(0, shape[0], m)
+        rows[:m // 2] = rng.integers(0, 300, m // 2)       # a hub block
+        cols = rng.integers(0, shape[1], m)
+        return COOMatrix.from_edges(
+            rows, cols, rng.standard_normal(m).astype(np.float32),
+            shape=shape)
+
+    def test_layout_follows_the_executor(self, rng, on_one_chip,
+                                         monkeypatch):
+        from matrel_tpu.core import coo as coo_lib
+        A = self._skewed(rng)
+        assert A._get_plan().chunk_block is not None       # auto: chunks
+        assert A._get_plan().hubs is None
+        monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "blocks")
+        B = self._skewed(rng)
+        assert B._get_plan().chunk_block is None
+        # the real choice: off a one-device Pallas backend, blocks
+        monkeypatch.undo()
+        assert coo_lib._plan_layout() == "blocks"
+
+    def test_one_table_is_one_plan_shared_with_the_matvec(self, rng,
+                                                          on_one_chip):
+        A = self._skewed(rng)
+        assert A._get_wide_plan() is A._get_plan()
+        assert A._get_wide_plan(transposed=True) is A._get_plan_t()
+
+    def test_source_panels_where_the_table_is_too_tall(self, rng,
+                                                       on_one_chip,
+                                                       monkeypatch):
+        from matrel_tpu.core import coo as coo_lib
+        from matrel_tpu.ops import spmv as spmv_lib
+        monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 1000 * 512)
+        A = self._skewed(rng)
+        builds = coo_lib.plan_builds()
+        fwd = A._get_wide_plan()                 # 900 sources: one table
+        bwd = A._get_wide_plan(transposed=True)  # 2,600: three
+        assert fwd is A._get_plan()
+        assert isinstance(bwd, coo_lib.PanelledPlan)
+        assert [c0 for c0, _ in bwd.parts] == [0, 872, 1744]
+        assert [p.n_cols for _, p in bwd.parts] == [872, 872, 856]
+        assert all(p.n_rows == 900 for _, p in bwd.parts)
+        assert sum(int((np.asarray(p.val) != 0).sum())
+                   for _, p in bwd.parts) == A.nnz
+        assert coo_lib.plan_builds() == builds + 2
+        # kept with the matrix, and with its transpose view
+        assert A._get_wide_plan(transposed=True) is bwd
+        assert A.T._get_wide_plan() is bwd
+        assert coo_lib.plan_builds() == builds + 2
+        facts = coo_lib.plan_facts(bwd, A.nnz)
+        assert facts["source_panels"] == 3 and facts["table"] == "panelled"
+        assert facts["slots"] == sum(p.src8.size for _, p in bwd.parts)
+        # the product over the parts is the product
+        X = rng.standard_normal((2600, 7)).astype(np.float32)
+        got = np.asarray(A.T.matmat(X))
+        np.testing.assert_allclose(got, A.to_dense().T @ X, rtol=2e-5,
+                                   atol=2e-4)
+
+    def test_chunked_plans_serve_every_op(self, rng, on_one_chip):
+        """matvec, rmatvec and matmat of a matrix whose plans lie in
+        chunks all take the compact executors; shard() builds the
+        blocks layout its mesh needs."""
+        A = self._skewed(rng)
+        dense = A.to_dense()
+        x = rng.standard_normal(900).astype(np.float32)
+        y = rng.standard_normal(2600).astype(np.float32)
+        assert A._get_plan().chunk_block is not None
+        np.testing.assert_allclose(np.asarray(A.matvec(x)), dense @ x,
+                                   rtol=2e-5, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(A.rmatvec(y)), dense.T @ y,
+                                   rtol=2e-5, atol=2e-4)
+        assert A._get_plan_t().chunk_block is not None
+        X = rng.standard_normal((900, 3)).astype(np.float32)
+        np.testing.assert_allclose(np.asarray(A.matmat(X)), dense @ X,
+                                   rtol=2e-5, atol=2e-4)

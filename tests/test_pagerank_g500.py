@@ -565,7 +565,7 @@ def chunked_plan(graphs):
 
 
 @pytest.mark.parametrize("who", ["expanded", "spmm", "shard_plan",
-                                 "sharded_compact", "k_wide"])
+                                 "sharded_compact"])
 def test_executors_of_the_blocks_layout_refuse_chunks_by_name(
         chunked_plan, mesh8, who):
     plan = chunked_plan
@@ -577,11 +577,22 @@ def test_executors_of_the_blocks_layout_refuse_chunks_by_name(
             spmv_lib.spmm(plan, jnp.ones((plan.n_cols, 2), jnp.float32))
         elif who == "shard_plan":
             spmv_lib.shard_plan(plan, mesh8)
-        elif who == "sharded_compact":
-            pc.spmv_compact_sharded(plan, x, mesh8, interpret=True)
         else:
-            pc.spmm_compact(plan, jnp.ones((plan.n_cols, 2), jnp.float32),
-                            interpret=True)
+            pc.spmv_compact_sharded(plan, x, mesh8, interpret=True)
+
+
+def test_the_k_wide_compact_product_takes_chunks(chunked_plan, graphs, rng):
+    """Since PR 37 the k-wide kernel walks chunks too (it refused them
+    by name before): the same plan, two columns, against the dense
+    product."""
+    src, dst, v = graphs[10]
+    X = rng.random((v, 2)).astype(np.float32)
+    dense = np.zeros((v, v))
+    np.add.at(dense, (dst, src), 1.0)
+    got = np.asarray(pc.spmm_compact(chunked_plan, jnp.asarray(X),
+                                     interpret=True), np.float64)
+    want = dense @ X
+    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 1e-6
 
 
 def test_sharded_pagerank_keeps_the_blocks_layout(graphs, compact_auto,

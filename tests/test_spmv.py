@@ -1116,3 +1116,106 @@ def test_the_hub_table_is_chosen_from_the_degrees(name, deg, want_rows):
             np.testing.assert_array_equal(ids[:real], np.arange(real))
             assert not ids[real:].any()
             np.testing.assert_array_equal(rank, np.arange(deg.size))
+
+
+# -- the k-wide compact product (PR 37) --------------------------------------------
+
+
+def _skewed_entries(rng, n_rows, n_cols, m):
+    """A hub block (a third of the entries land in rows 512..1023, many
+    chunks), an empty block (rows 1536..2047 hold none), values not
+    exact in bfloat16."""
+    rows = rng.integers(0, n_rows, m)
+    rows[:m // 3] = rng.integers(512, 1024, m // 3)
+    rows[rows // 512 == 3] = 0
+    cols = rng.integers(0, n_cols, m)
+    return rows, cols, rng.standard_normal(m).astype(np.float32)
+
+
+@pytest.mark.parametrize("orientation", ["forward", "transposed"])
+@pytest.mark.parametrize("layout", ["chunks", "blocks"])
+def test_k_wide_compact_product_in_panels(rng, monkeypatch, layout,
+                                          orientation):
+    """Both orientations of a skewed matrix times a 128-wide dense side
+    against the float64 dense product, each layout through the one chunk
+    kernel, forced into several panels by a small byte budget (the last
+    one overlaps the one before: nothing is added twice)."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    n_rows, n_cols, m, k = 3000, 700, 60_000, 128
+    rows, cols, vals = _skewed_entries(rng, n_rows, n_cols, m)
+    if orientation == "transposed":
+        rows, cols, n_rows, n_cols = cols, rows, n_cols, n_rows
+    plan = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
+                                    layout=layout, hubs=False)
+    assert (plan.chunk_block is not None) == (layout == "chunks")
+    table_rows, cap = plan.src8.shape
+    chunk = cap if layout == "chunks" else max(
+        d for d in range(1, 17) if (cap // 128) % d == 0) * 128
+    walked = table_rows * (cap // chunk)
+    # a quarter of the budget holds a few chunks' temporaries: the
+    # first count whose even split leaves the last panel overlapping
+    for most in (7, 5, 4, 3):
+        monkeypatch.setattr(pc, "_hbm_limit", lambda: 4 * most * chunk
+                            * pc._TEMP_BYTES_A_SLOT_WIDE)
+        per = pc.wide_panel_rows(walked, chunk)
+        if walked % per:
+            break
+    assert 1 < per < walked and walked % per, (per, walked)
+    X = rng.standard_normal((n_cols, k)).astype(np.float32)
+    got = np.asarray(pc.spmm_compact(plan, jnp.asarray(X), interpret=True),
+                     np.float64)
+    want = np.zeros((n_rows, k))
+    np.add.at(want, rows, vals.astype(np.float64)[:, None]
+              * X.astype(np.float64)[cols])
+    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 1e-6
+    if orientation == "forward":
+        assert not got[1536:2048].any()             # the empty block
+    # the lower-precision settings are what they say
+    errs = [np.max(np.abs(np.asarray(pc.spmm_compact(
+        plan, jnp.asarray(X), passes=p, interpret=True)) - want))
+        / np.max(np.abs(want)) for p in (2, 1)]
+    assert 1e-6 < errs[0] < 1e-4 < errs[1] < 1e-2
+
+
+@pytest.mark.parametrize("k", [2, 130])
+def test_k_wide_compact_product_any_width_and_hub_chunks(rng, monkeypatch,
+                                                        k):
+    """Narrower than a lane row, wider than one (two passes of columns),
+    and a plan whose skewed sources lie in hub chunks of their own (a
+    PageRank plan handed to the k-wide product): the hub slots' rows are
+    fetched by the hubs' ids."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    monkeypatch.setattr(spmv_lib, "_HUB_MIN_SHARE", 0.0)
+    rows, cols, vals = _skewed_entries(rng, 3000, 700, 40_000)
+    cols[:20_000] = rng.integers(0, 40, 20_000)
+    plan = spmv_lib.build_spmv_plan(rows, cols, vals, 3000, 700,
+                                    layout="chunks")
+    assert plan.hubs is not None
+    X = rng.standard_normal((700, k)).astype(np.float32)
+    got = np.asarray(pc.spmm_compact(plan, jnp.asarray(X), interpret=True),
+                     np.float64)
+    want = np.zeros((3000, k))
+    np.add.at(want, rows, vals.astype(np.float64)[:, None]
+              * X.astype(np.float64)[cols])
+    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 1e-6
+
+
+def test_source_panels_follow_the_gather_tables_bytes():
+    """A float32 row of up to 128 columns is 512 B in the chip's tiles:
+    64 MB hold 131,064 rows and the zero row's eight."""
+    assert spmv_lib.source_panels(17_770) == 1
+    assert spmv_lib.source_panels(131_064) == 1
+    assert spmv_lib.source_panels(131_065) == 2
+    assert spmv_lib.source_panels(480_189) == 4
+
+
+def test_no_hub_chunks_where_the_caller_declines_them(rng, monkeypatch):
+    monkeypatch.setattr(spmv_lib, "_HUB_MIN_SHARE", 0.0)
+    rows, cols, vals = _skewed_entries(rng, 3000, 700, 40_000)
+    cols[:20_000] = rng.integers(0, 40, 20_000)
+    with_hubs = spmv_lib.build_spmv_plan(rows, cols, vals, 3000, 700,
+                                         layout="chunks")
+    without = spmv_lib.build_spmv_plan(rows, cols, vals, 3000, 700,
+                                       layout="chunks", hubs=False)
+    assert with_hubs.hubs is not None and without.hubs is None
+    assert without.src8.size < with_hubs.src8.size + with_hubs.hubs.idx.size
