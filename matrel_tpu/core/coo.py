@@ -99,9 +99,12 @@ def plan_facts(plan, entries: int) -> dict:
     (table rows), ``source_panels`` and ``table`` (the k-wide gather
     table's form: ``hbm``, one table whole, or ``panelled``),
     ``overflow_edges``, and for the k-wide product on one device its
-    ``panels`` (of table rows, over all parts) and ``plan_bytes`` (the
+    ``panels`` (of table rows, over all parts), ``plan_bytes`` (the
     tables and the largest panel's temporaries,
-    pallas_spmv.wide_plan_bytes)."""
+    pallas_spmv.wide_plan_bytes) and ``windowed_chunks``: of the chunks
+    its scatter walks (``chunks`` in the chunks layout; a blocks-layout
+    row is walked as several), those whose one-hot is a 128-row window
+    and not the block (pallas_spmv.wide_windows; 0 where none is)."""
     from matrel_tpu.ops import pallas_spmv as pc
     parts = [p for _, p in plan_parts(plan)]
     shapes = [np.asarray(p.src8).shape for p in parts]
@@ -112,6 +115,7 @@ def plan_facts(plan, entries: int) -> dict:
                    else "blocks"),
         "entries": int(entries), "slots": int(slots),
         "chunks": int(sum(r for r, _ in shapes)),
+        "windowed_chunks": sum(pc.wide_windows(p)[1] for p in parts),
         "source_panels": len(parts),
         "table": "panelled" if len(parts) > 1 else "hbm",
         "overflow_edges": sum(0 if p.ov_rows is None

@@ -551,14 +551,30 @@ def spmv_compact_sharded(plan: spmv_lib.EdgeSpMVPlan, x: jax.Array,
 # ONCE (XLA's row gather: a row of up to 128 float32 fills 128 lanes
 # whatever it holds, so 128 columns cost what 8 do), in panels of table
 # rows as the matvec's ``w``, and one chunk-grid kernel scatters a panel:
-# per 128 slots a (block, 128) one-hot of their destination rows, exact in
-# bfloat16, times the slots' rows carved into ``passes`` bfloat16 parts —
-# plain MXU products, the block's (block, 128) tile resident over its
-# consecutive chunks. ``val`` multiplies inside the kernel: XLA does not
-# fuse a multiply into its gather, and a pass of its own over the
-# gathered rows is 1 KB a slot of HBM traffic (PERF.md §6, PR 37). Both
-# layouts take this one kernel: a blocks-layout row is walked as
-# ``capacity / tile`` chunks of its block.
+# per 128 slots a one-hot of their destination rows, exact in bfloat16,
+# times the slots' rows carved into ``passes`` bfloat16 parts — plain MXU
+# products, the block's (block, 128) tile resident over its consecutive
+# chunks. ``val`` multiplies inside the kernel: XLA does not fuse a
+# multiply into its gather, and a pass of its own over the gathered rows
+# is 1 KB a slot of HBM traffic (PERF.md §6, PR 37). Both layouts take
+# this one kernel: a blocks-layout row is walked as ``capacity / tile``
+# chunks of its block.
+#
+# The one-hot's height follows the rows a chunk really holds (PR 38).
+# ``wide_windows`` reads every chunk table once on the host
+# (``spmv.chunk_windows``): where a chunk's real slots lie within
+# ``spmv.WINDOW`` = 128 rows of one another, ``win[c]`` names the first
+# of them (a multiple of 8), the one-hot is (128, 128) and the sums add
+# into rows ``win : win + 128`` of the tile: a quarter of the MXU pushes,
+# result pops and adds of the (block, 128) one-hot at block 512, the same
+# three parts, the same float32 accumulation. Elsewhere ``win[c]`` is −1
+# and the chunk takes the whole block's one-hot. ``win`` rides as a
+# scalar-prefetch operand beside ``chunk_block``; nothing chooses but the
+# tables. The chunks fill lays a block's slots in row order
+# (native/spmv_plan.cc), so 2,048 slots name a few rows (a Netflix user
+# holds 209 ratings, a movie 5,654) and at most ``block / 120`` chunks
+# of a block spread further; a plan in input order reads −1 throughout
+# and runs the body it always ran.
 
 WIDE_COLS = LANE        # columns a pass of the k-wide product takes
 # What a panel of the k-wide product keeps alive a slot, read off the
@@ -569,8 +585,35 @@ WIDE_COLS = LANE        # columns a pass of the k-wide product takes
 _TEMP_BYTES_A_SLOT_WIDE = 4 * WIDE_COLS + 24
 
 
-def _make_wide_scatter_kernel(block: int, cr: int, passes: int):
-    def kernel(cb_ref, skip_ref, off_ref, val_ref, g_ref, acc_ref, y_ref):
+def _wide_sums(off, val, g_ref, height: int, passes: int):
+    """(height, 128) float32: the chunk's slots' rows of X times ``val``,
+    summed by destination row ``off`` (cr, 128) over rows 0..height; a
+    slot whose ``off`` lies outside them adds nothing."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (height, LANE), 0)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 1))
+    acc = jnp.zeros((height, WIDE_COLS), jnp.float32)
+    for s in range(off.shape[0]):
+        oh = (off[s:s + 1, :] == rows).astype(jnp.bfloat16)
+        # the slots' values from the lanes onto the sublanes:
+        # a diagonal select and a lane sum of one term, exact
+        col = jnp.sum(jnp.where(eye, val[s:s + 1, :], 0.0),
+                      axis=1, keepdims=True)                 # (128, 1)
+        w = g_ref[0, s * LANE:(s + 1) * LANE, :] * col
+        for part in _bf16_split(w, passes):
+            # one MXU pass a part, whatever matmul precision the
+            # caller's context asks of its own float32 dots
+            acc = acc + jnp.dot(oh, part.astype(jnp.bfloat16),
+                                precision=jax.lax.Precision.DEFAULT,
+                                preferred_element_type=jnp.float32)
+    return acc
+
+
+def _make_wide_scatter_kernel(block: int, passes: int):
+    window = spmv_lib.WINDOW
+
+    def kernel(cb_ref, skip_ref, win_ref, off_ref, val_ref, g_ref, acc_ref,
+               y_ref):
         # the block's tile starts from what the panels and parts before
         # this call summed (``acc``, which the output aliases)
         @pl.when(_first_chunk_of_its_block(cb_ref))
@@ -579,27 +622,23 @@ def _make_wide_scatter_kernel(block: int, cr: int, passes: int):
 
         # the last panel is moved back to end with the tables: the
         # chunks it shares with the one before are not added twice
-        @pl.when(pl.program_id(0) >= skip_ref[0])
+        c = pl.program_id(0)
+        live = c >= skip_ref[0]
+        win = win_ref[c]
+
+        # the chunk's rows lie in a window of the tile: its one-hot is
+        # that tall (a block shorter than a window has none)
+        if block >= window:
+            @pl.when(jnp.logical_and(live, win >= 0))
+            def _():
+                at = pl.multiple_of(win, 8)
+                y_ref[0, pl.ds(at, window), :] += _wide_sums(
+                    off_ref[0] - at, val_ref[0], g_ref, window, passes)
+
+        @pl.when(jnp.logical_and(live, win < 0))
         def _():
-            off, val = off_ref[0], val_ref[0]                # (cr, 128)
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block, LANE), 0)
-            eye = (jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 0)
-                   == jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 1))
-            acc = jnp.zeros((block, WIDE_COLS), jnp.float32)
-            for s in range(cr):
-                oh = (off[s:s + 1, :] == rows).astype(jnp.bfloat16)
-                # the slots' values from the lanes onto the sublanes:
-                # a diagonal select and a lane sum of one term, exact
-                col = jnp.sum(jnp.where(eye, val[s:s + 1, :], 0.0),
-                              axis=1, keepdims=True)         # (128, 1)
-                w = g_ref[0, s * LANE:(s + 1) * LANE, :] * col
-                for part in _bf16_split(w, passes):
-                    # one MXU pass a part, whatever matmul precision the
-                    # caller's context asks of its own float32 dots
-                    acc = acc + jnp.dot(oh, part.astype(jnp.bfloat16),
-                                        precision=jax.lax.Precision.DEFAULT,
-                                        preferred_element_type=jnp.float32)
-            y_ref[0] += acc
+            y_ref[0] += _wide_sums(off_ref[0], val_ref[0], g_ref, block,
+                                   passes)
 
     return kernel
 
@@ -607,28 +646,28 @@ def _make_wide_scatter_kernel(block: int, cr: int, passes: int):
 @functools.lru_cache(maxsize=32)
 def _wide_runner(n_chunks: int, chunk: int, nb: int, block: int,
                  passes: int, interpret: bool):
-    """scatter(chunk_block, skip, off, val, rows, acc) -> acc + the
+    """scatter(chunk_block, skip, win, off, val, rows, acc) -> acc + the
     chunks' block sums, (nb, block, 128): ``rows`` are the slots'
     gathered rows of X, (n_chunks, chunk, 128); the first ``skip[0]``
-    chunks add nothing."""
+    chunks add nothing; ``win`` as :func:`wide_windows` gives it."""
     cr = chunk // LANE
-    slots = pl.BlockSpec((1, cr, LANE), lambda c, cb, skip: (c, 0, 0))
+    slots = pl.BlockSpec((1, cr, LANE), lambda c, cb, skip, win: (c, 0, 0))
     sums = pl.BlockSpec((1, block, WIDE_COLS),
-                        lambda c, cb, skip: (cb[c], 0, 0))
+                        lambda c, cb, skip, win: (cb[c], 0, 0))
     return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
-        _make_wide_scatter_kernel(block, cr, passes),
+        _make_wide_scatter_kernel(block, passes),
         name="matrel_spmm_scatter_chunks",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,                       # chunk_block, skip
+            num_scalar_prefetch=3,                  # chunk_block, skip, win
             grid=(n_chunks,),
             in_specs=[slots, slots,
                       pl.BlockSpec((1, chunk, WIDE_COLS),
-                                   lambda c, cb, skip: (c, 0, 0)),
+                                   lambda c, cb, skip, win: (c, 0, 0)),
                       sums],
             out_specs=sums,
         ),
         out_shape=jax.ShapeDtypeStruct((nb, block, WIDE_COLS), jnp.float32),
-        input_output_aliases={5: 0},                     # acc
+        input_output_aliases={6: 0},                     # acc
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -654,25 +693,61 @@ def wide_plan_bytes(rows: int, cap: int) -> int:
     return TABLE_BYTES_A_SLOT * rows * cap + wide_panel_bytes(rows, cap)
 
 
+def _walk(cr: int) -> int:
+    """Rows of 128 slots a chunk takes of a blocks-layout row of ``cr``:
+    the largest divisor of ``cr`` that keeps a chunk within
+    ``spmv.CHUNK`` slots."""
+    most = spmv_lib.CHUNK // LANE
+    return next(d for d in range(min(cr, most), 0, -1) if cr % d == 0)
+
+
 def _as_chunks(src, off, val, chunk_block):
     """The tables as the chunk kernel walks them. A blocks-layout row of
-    ``cr`` x 128 slots becomes ``cr / d`` chunks of its block, ``d`` the
-    largest divisor of ``cr`` that keeps a chunk within ``spmv.CHUNK``
-    slots: the same memory, read as more rows."""
+    ``cr`` x 128 slots becomes ``cr / d`` chunks of its block (``d``:
+    :func:`_walk`): the same memory, read as more rows."""
     if chunk_block is not None:
         return src, off, val, chunk_block
     rows, cr, _ = off.shape
-    most = spmv_lib.CHUNK // LANE
-    d = next(d for d in range(min(cr, most), 0, -1) if cr % d == 0)
+    d = _walk(cr)
     shp = (rows * (cr // d), d, LANE)
     return (src.reshape(shp), off.reshape(shp), val.reshape(shp),
             jnp.asarray(np.repeat(np.arange(rows, dtype=np.int32), cr // d)))
 
 
-def _chunk_sets(tables, n_cols: int):
+def wide_windows(plan: spmv_lib.EdgeSpMVPlan):
+    """(``win`` of every chunk table the k-wide kernel walks of this
+    plan — its own as :func:`_as_chunks` reads them, then its hub
+    chunks' — as device arrays; how many chunks have a window), reckoned
+    once from the host tables (``spmv.chunk_windows``) and memoised on
+    the plan. A padded slot is known by its sentinel source, which reads
+    the zero row."""
+    memo = getattr(plan, "_wide_win", None)
+    if memo is None:
+        off = np.asarray(plan.off)
+        real = ~((np.asarray(plan.src8) == plan.n_cols // spmv_lib.WIDTH)
+                 & (np.asarray(plan.lane) == plan.n_cols % spmv_lib.WIDTH))
+        if plan.chunk_block is None:
+            shp = (-1, _walk(off.shape[1] // LANE) * LANE)
+            off, real = off.reshape(shp), real.reshape(shp)
+        wins = [spmv_lib.chunk_windows(off, real, plan.block)]
+        if plan.hubs is not None:
+            hub = plan.hubs
+            wins.append(spmv_lib.chunk_windows(
+                hub.off, hub.idx < hub.ids.size, plan.block))
+        # committed arrays, not tracers (see compact_tables)
+        with jax.ensure_compile_time_eval():
+            dev = tuple(jnp.asarray(w) for w in wins)
+        memo = plan._wide_win = (
+            dev, int(sum(np.count_nonzero(w >= 0) for w in wins)))
+    return memo
+
+
+def _chunk_sets(tables, n_cols: int, wins=None):
     """compact_tables() of either layout as the sets of chunk tables
-    ``(src, off, val, chunk_block)`` the k-wide kernel walks: the plan's
-    own, and its hub chunks' where it has any."""
+    ``(src, off, val, chunk_block, win)`` the k-wide kernel walks: the
+    plan's own, and its hub chunks' where it has any. ``wins`` from
+    :func:`wide_windows`; without them (tables that are a device's slice
+    inside a shard_map) no chunk has a window."""
     src8, lane, off, val, *chunks = tables
     chunk_block, *hub = chunks if chunks else (None,)
     src = src8 * spmv_lib.WIDTH + lane.astype(jnp.int32)
@@ -683,7 +758,9 @@ def _chunk_sets(tables, n_cols: int):
         ids, idx, hub_off, hub_val, hub_block = hub
         ids = jnp.concatenate([ids, jnp.full((1,), n_cols, ids.dtype)])
         sets.append((ids[idx], hub_off, hub_val, hub_block))
-    return sets
+    if wins is None:
+        wins = [jnp.full(cb.shape, -1, jnp.int32) for *_, cb in sets]
+    return [st + (win,) for st, win in zip(sets, wins)]
 
 
 def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool):
@@ -691,7 +768,7 @@ def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool):
     tables against the rows of ``X`` (the set's columns and a zero row
     for the padded slots, 128 wide), a panel of chunks at a time."""
     nb = y.shape[0]
-    for src, off, val, cb in sets:
+    for src, off, val, cb, win in sets:
         rows, cr, _ = off.shape
         chunk = cr * LANE
         per = wide_panel_rows(rows, chunk)
@@ -699,11 +776,12 @@ def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool):
 
         def panel(i, y):
             at = jnp.minimum(i * per, rows - per)
-            s, o, v, c = (jax.lax.dynamic_slice_in_dim(a, at, per)
-                          for a in (src, off, val, cb))
+            s, o, v, c, w = (jax.lax.dynamic_slice_in_dim(a, at, per)
+                             for a in (src, off, val, cb, win))
             g = X.at[s.reshape(-1)].get(mode="promise_in_bounds")
             skip = jnp.reshape(i * per - at, (1,)).astype(jnp.int32)
-            return run(c, skip, o, v, g.reshape(per, chunk, WIDE_COLS), y)
+            return run(c, skip, w, o, v, g.reshape(per, chunk, WIDE_COLS),
+                       y)
 
         if per >= rows:
             y = panel(jnp.int32(0), y)
@@ -719,8 +797,9 @@ def compact_matmat_parts(plan_static, part_statics, part_arrays,
     compact plan a range of its columns (COOMatrix's source panels; a
     plan whole is one part at 0), every one adding onto the same block
     sums: ``part_statics`` = ((col0, its plan_static), ...) and
-    ``part_arrays`` = ((its compact_tables(), its overflow), ...). More
-    than 128 columns run 128 at a time."""
+    ``part_arrays`` = ((its compact_tables(), its overflow, its
+    wide_windows()[0] or None), ...). More than 128 columns run 128 at a
+    time."""
     n_rows, _, block, _ = plan_static
     nb = -(-n_rows // block)
     k = X.shape[1]
@@ -729,18 +808,18 @@ def compact_matmat_parts(plan_static, part_statics, part_arrays,
     for j0 in range(0, k, WIDE_COLS):
         kc = min(WIDE_COLS, k - j0)
         y = jnp.zeros((nb, block, WIDE_COLS), jnp.float32)
-        for (col0, (_, n_cols, _, _)), (tables, _) in zip(part_statics,
-                                                         part_arrays):
+        for (col0, (_, n_cols, _, _)), (tables, _, wins) in zip(
+                part_statics, part_arrays):
             # padded slots (src == n_cols) read a zero row; the columns
             # are padded to the lanes a gathered row fills anyway
             Xp = jnp.pad(Xf[col0:col0 + n_cols, j0:j0 + kc],
                          ((0, spmv_lib.WIDTH), (0, WIDE_COLS - kc)))
-            y = _wide_accumulate(y, _chunk_sets(tables, n_cols), Xp, block,
-                                 passes, interpret)
+            y = _wide_accumulate(y, _chunk_sets(tables, n_cols, wins), Xp,
+                                 block, passes, interpret)
         outs.append(y.reshape(-1, WIDE_COLS)[:n_rows, :kc])
     Y = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-    for (col0, (_, n_cols, _, _)), (_, ov) in zip(part_statics,
-                                                  part_arrays):
+    for (col0, (_, n_cols, _, _)), (_, ov, _) in zip(part_statics,
+                                                     part_arrays):
         if ov:
             Y = spmv_lib._overflow_add_wide(Y, ov, X[col0:col0 + n_cols],
                                             n_rows)
@@ -751,9 +830,11 @@ def compact_matmat_apply(plan_static, tables, ov, X: jax.Array,
                          passes: int = 3,
                          interpret: bool = False) -> jax.Array:
     """Traceable body: Y = A·X for dense X (n_cols, k). ``tables`` from
-    compact_tables(), either layout, with hub chunks or none."""
+    compact_tables(), either layout, with hub chunks or none — or a
+    device's slice of them inside a shard_map, which is why no chunk
+    has a window here (:func:`spmm_compact` hands the plan's own)."""
     return compact_matmat_parts(plan_static, ((0, plan_static),),
-                                ((tables, ov),), X, passes, interpret)
+                                ((tables, ov, None),), X, passes, interpret)
 
 
 _compact_matmat_jitted = jax.jit(compact_matmat_parts,  # matlint: disable=ML010 pre-seam ops runner cache — the porting worklist (the ML009 legacy-kernel idiom)
@@ -764,14 +845,15 @@ def plan_operands(plan):
     """(plan_static, part_statics, part_arrays) of an EdgeSpMVPlan or of
     a plan in source panels (anything with ``parts`` = ((col0, plan),
     ...), ``n_rows``, ``n_cols``, ``block``: core.coo.PanelledPlan), as
-    :func:`compact_matmat_parts` takes them; the parts' tables move to
-    the device on first use."""
+    :func:`compact_matmat_parts` takes them; the parts' tables and
+    windows move to the device on first use."""
     parts = getattr(plan, "parts", None) or ((0, plan),)
     static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
     return (static,
             tuple((col0, (p.n_rows, p.n_cols, p.block, spmv_lib.LO))
                   for col0, p in parts),
-            tuple((compact_tables(p), p.overflow) for _, p in parts))
+            tuple((compact_tables(p), p.overflow, wide_windows(p)[0])
+                  for _, p in parts))
 
 
 def spmm_compact(plan, X: jax.Array, passes: int = 3,

@@ -45,8 +45,11 @@ reference's plan-per-query model. A plan has one of two layouts:
   every block takes 2.98 and still leaves 362k edges to the scalar tail.
   Only the compact-table Pallas executors (ops/pallas_spmv.py, one
   device: the matvec and, since PR 37, the k-wide product) take it; the
-  expanded tables and ``shard_plan`` say so by name. Where the sources are skewed too (PR 36), the edges whose
-  source is among the ``128·M`` of largest out-degree, the *hubs*, lie in
+  expanded tables and ``shard_plan`` say so by name. Without hub chunks
+  a block's slots lie by destination row (PR 38), so a chunk names few
+  rows and the k-wide scatter's one-hot is a ``WINDOW`` of them tall
+  (``chunk_windows``). Where the sources are skewed too (PR 36), the
+  edges whose source is among the ``128·M`` of largest out-degree, the *hubs*, lie in
   a second set of chunks (``HubChunks``): a slot there names its source
   by rank, and the matvec takes ``x`` for it from a ``(M, 128)`` table in
   VMEM by lane permutes inside the scatter kernel, so these slots never
@@ -77,6 +80,7 @@ HI = 32          # off = hi*LO + lo one-hot factor sizes; HI*LO == BLOCK
 LO = 16
 CHUNK = 2048     # slots a chunk of the ``chunks`` layout: one grid step of
                  # the Pallas scatter, a (16, 128) tile
+WINDOW = 128     # rows of its block a chunk's window spans (chunk_windows)
 # ``layout="auto"`` weighs the two layouts by the slots a matvec walks.
 # An overflow edge rides XLA's scalar gather and segment_sum (~13 ns,
 # module docstring) where a slot costs ~2 ns (PERF.md §5): 8 slots. A
@@ -463,9 +467,12 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
             refusals.append(refused)
         return None
 
-    # Native single-pass counting-sort fill (O(m), no argsort — slot
-    # order within a block is input order; the one-hot contraction is
-    # order-agnostic so results match the numpy path)
+    # Native counting-sort fill (O(m), no argsort). The chunks layout
+    # without hub chunks lies by row inside a block, slot for slot as
+    # the numpy path lays it (the k-wide scatter's windows read that
+    # order: chunk_windows); the other two keep input order inside a
+    # block — the matvec's one-hot contraction is order-agnostic, so
+    # their results match the numpy path
     filled = hub_filled = None
     if hub_ids is not None:
         both = (native.spmv_fill_ragged_hubs(
@@ -543,6 +550,30 @@ def _first_slots(owned: np.ndarray) -> np.ndarray:
     first = np.zeros(owned.shape[0] + 1, np.int64)
     np.cumsum(owned * CHUNK, out=first[1:])
     return first
+
+
+def chunk_windows(off: np.ndarray, real: np.ndarray,
+                  block: int) -> np.ndarray:
+    """``win`` (chunks,) int32 of a chunk table ``off`` (chunks, slots):
+    the row of its block, a multiple of 8, from which ``WINDOW`` rows
+    hold every real slot of the chunk (``real``: not padding) — the
+    k-wide scatter (ops/pallas_spmv.py) then builds that chunk's one-hot
+    ``WINDOW`` rows tall and not ``block`` — or −1 where the chunk's
+    rows spread further, or the block is shorter than a window. It is
+    the chunk's least row rounded down to 8, moved back where it would
+    reach past the block's end. A padded slot's ``off`` (0) takes no
+    part: outside the window it matches no row of the one-hot, and it
+    adds 0 wherever it lands; a chunk that is all padding takes the
+    block's last window. In row order (the chunks fill without hubs,
+    the numpy fills) the chunks of a block overlap in one row at most,
+    so at most ``block / (WINDOW − 8)`` of them read −1 whatever the
+    data."""
+    if block < WINDOW:
+        return np.full(off.shape[0], -1, np.int32)
+    low = np.where(real, off, block).min(axis=1)
+    high = np.where(real, off, -1).max(axis=1)
+    win = np.minimum(low // 8 * 8, block - WINDOW)
+    return np.where(high - win < WINDOW, win, -1).astype(np.int32)
 
 
 def _hub_rows(deg_desc: np.ndarray, edges: int) -> int:

@@ -12,17 +12,25 @@
 // COO, stably sorted by row (segment_sum wants sorted ids).
 //
 // Pass 2' (matrel_spmv_fill_ragged): the same scatter into blocks of
-// their own sizes (the chunks layout), no overflow.
+// their own sizes (the chunks layout), no overflow, and then every block's
+// slots put in row order (below).
 //
 // With hub chunks (PR 36: matrel_spmv_counts_hubs, matrel_spmv_fill_ragged_hubs)
 // the same two passes send an edge whose source has a hub rank to a second
 // set of ragged tables (rank, off, val) and every other edge to the main
 // ones, in one walk over the edge list: no partitioned copy of it.
 //
-// Slot order within a block differs from the numpy path (input order vs
-// row-sorted) — the one-hot contraction is order-agnostic, so the
+// Slot order within a block. matrel_spmv_fill and
+// matrel_spmv_fill_ragged_hubs keep input order where the numpy path sorts
+// by row — the matvec's one-hot contraction is order-agnostic, so their
 // contract (tests assert it) is equal spmv RESULTS, not byte-equal
-// layouts. Sentinel convention matches: src = n_cols, off = 0, val = 0.
+// layouts. matrel_spmv_fill_ragged (PR 38) lays a block's entries by
+// destination row, stable inside a row, as the numpy path does: EQUAL
+// LAYOUTS, slot for slot (tests assert that too). The k-wide scatter
+// (ops/pallas_spmv.py) reads the order: a chunk of 2,048 slots in row
+// order names few rows, and where they lie within 128 of one another its
+// one-hot is 128 rows tall and not the block's 512. Sentinel convention
+// matches everywhere: src = n_cols, off = 0, val = 0.
 
 #include <algorithm>
 #include <cstdint>
@@ -106,8 +114,12 @@ int64_t matrel_spmv_fill(const int64_t* rows, const int64_t* cols,
 
 // The chunks layout's fill: block b owns the flat slots
 // [first[b], first[b+1]) (its chunks, laid one after the other), sized
-// by Python from the counts so that nothing overflows. Same slot order
-// (input order within a block) and sentinels as matrel_spmv_fill.
+// by Python from the counts so that nothing overflows. Same sentinels as
+// matrel_spmv_fill; a block's entries lie by destination row, stable
+// inside a row. Two walks, both O(m): the scatter by block in input order
+// (938 write heads at the Netflix shape, where a head a row would be
+// 480,189 and miss the cache at every write), then a counting sort by
+// `off` inside each block through a scratch copy of its slots.
 // Returns 0, or -1 on an index out of range or a block past its slots.
 int matrel_spmv_fill_ragged(const int64_t* rows, const int64_t* cols,
                             const float* vals, int64_t m, int64_t n_cols,
@@ -139,6 +151,31 @@ int matrel_spmv_fill_ragged(const int64_t* rows, const int64_t* cols,
         lane[p] = static_cast<int8_t>(c % width);
         off[p] = static_cast<int32_t>(r % block);
         val[p] = vals ? vals[e] : 1.0f;
+    }
+
+    // a block's real slots, first[b] .. next[b], by row: per-row counts,
+    // their prefix sum, one scatter walk from the scratch copy
+    std::vector<int64_t> at(block + 1);
+    std::vector<int32_t> t_src, t_off;
+    std::vector<int8_t> t_lane;
+    std::vector<float> t_val;
+    for (int64_t b = 0; b < nb; ++b) {
+        const int64_t p0 = first[b], n = next[b] - p0;
+        if (n < 2) continue;
+        std::fill(at.begin(), at.end(), 0);
+        for (int64_t i = 0; i < n; ++i) at[off[p0 + i] + 1]++;
+        for (int64_t r = 0; r < block; ++r) at[r + 1] += at[r];
+        t_src.assign(src8 + p0, src8 + p0 + n);
+        t_lane.assign(lane + p0, lane + p0 + n);
+        t_off.assign(off + p0, off + p0 + n);
+        t_val.assign(val + p0, val + p0 + n);
+        for (int64_t i = 0; i < n; ++i) {
+            const int64_t p = p0 + at[t_off[i]]++;
+            src8[p] = t_src[i];
+            lane[p] = t_lane[i];
+            off[p] = t_off[i];
+            val[p] = t_val[i];
+        }
     }
     return 0;
 }

@@ -128,8 +128,8 @@ def test_compact_spmv_k_wide(one_chip):
     static = (N_NODES, N_NODES, BLOCK, spmv_lib.LO)
     X = _sds(one_chip, (N_NODES, 8), jnp.float32)
     compiled = _compile(pc._compact_matmat_jitted, static, ((0, static),),
-                        ((_compact_table_shapes(NB, one_chip), ()),), X, 3,
-                        False)
+                        ((_compact_table_shapes(NB, one_chip), (), None),),
+                        X, 3, False)
     assert "matrel_spmm_scatter_chunks" in compiled.as_text()
 
 
@@ -161,7 +161,8 @@ def test_gnmf_forward_product_runs_in_panels(one_chip, monkeypatch):
     static = (NF_USERS, NF_MOVIES, BLOCK, spmv_lib.LO)
     compiled = _compile(
         pc._compact_matmat_jitted, static, ((0, static),),
-        ((_chunk_table_shapes(NF_CHUNKS, one_chip), ()),),
+        ((_chunk_table_shapes(NF_CHUNKS, one_chip), (),
+          (_sds(one_chip, (NF_CHUNKS,), jnp.int32),)),),
         _sds(one_chip, (NF_MOVIES, NF_RANK), jnp.float32), 3, False)
     text = compiled.as_text()
     per = pc.wide_panel_rows(NF_CHUNKS, spmv_lib.CHUNK)
@@ -193,7 +194,8 @@ def test_gnmf_transposed_product_gathers_from_source_panels(one_chip,
         for c0 in range(0, NF_USERS, NF_PANEL_USERS))
     compiled = _compile(
         pc._compact_matmat_jitted, static, statics,
-        tuple((_chunk_table_shapes(NF_PANEL_CHUNKS, one_chip), ())
+        tuple((_chunk_table_shapes(NF_PANEL_CHUNKS, one_chip), (),
+               (_sds(one_chip, (NF_PANEL_CHUNKS,), jnp.int32),))
               for _ in statics),
         _sds(one_chip, (NF_USERS, NF_RANK), jnp.float32), 3, False)
     text = compiled.as_text()

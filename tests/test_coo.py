@@ -686,6 +686,43 @@ class TestWidePlans:
         np.testing.assert_allclose(got, A.to_dense().T @ X, rtol=2e-5,
                                    atol=2e-4)
 
+    @pytest.mark.parametrize("which", ["forward", "transposed_in_panels",
+                                       "blocks_layout"])
+    def test_plan_facts_count_the_windowed_chunks(self, rng, on_one_chip,
+                                                  monkeypatch, which):
+        """``windowed_chunks`` (PR 38): of the chunks the k-wide scatter
+        walks, those whose rows lie in a 128-row window — over every
+        source panel, read off the plans' own tables."""
+        from matrel_tpu.core import coo as coo_lib
+        from matrel_tpu.ops import pallas_spmv as pc
+        from matrel_tpu.ops import spmv as spmv_lib
+        monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 1000 * 512)
+        if which == "blocks_layout":
+            monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "blocks")
+        A = self._skewed(rng)
+        plan = A._get_wide_plan(transposed=which == "transposed_in_panels")
+        facts = coo_lib.plan_facts(plan, A.nnz)
+        parts = [p for _, p in coo_lib.plan_parts(plan)]
+        assert len(parts) == (3 if which == "transposed_in_panels" else 1)
+        want = 0
+        for p in parts:
+            src = np.asarray(p.src8).astype(np.int64) * 8 + np.asarray(p.lane)
+            off = np.asarray(p.off)
+            if p.chunk_block is None:           # a row walked as chunks
+                width = pc._walk(off.shape[1] // 128) * 128
+                off, src = off.reshape(-1, width), src.reshape(-1, width)
+            for o, real in zip(off, src != p.n_cols):
+                rows = o[real]
+                lo = min(rows.min() // 8 * 8, 512 - 128) if rows.size else 0
+                want += bool(rows.size == 0 or rows.max() - lo < 128)
+        assert facts["windowed_chunks"] == want > 0
+        if which == "blocks_layout":
+            assert facts["layout"] == "blocks"
+        else:           # the hub block's chunks at the least
+            assert facts["layout"] == "chunks"
+        # said again from the memo, and by the product's own record
+        assert coo_lib.plan_facts(plan, A.nnz) == facts
+
     def test_chunked_plans_serve_every_op(self, rng, on_one_chip):
         """matvec, rmatvec and matmat of a matrix whose plans lie in
         chunks all take the compact executors; shard() builds the

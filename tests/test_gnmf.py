@@ -112,6 +112,13 @@ def test_three_iterations_match_float64(rng, compact_one_device, rank):
     assert (bwd["source_panels"], bwd["table"]) == (4, "panelled")
     assert fwd["overflow_edges"] == bwd["overflow_edges"] == 0
     assert fwd["entries"] == bwd["entries"] == V.nnz
+    # a block's slots lie in row order: the hub block's chunks hold few
+    # users each and take a 128-row window, on a build and on a hit
+    for facts in (fwd, bwd):
+        assert 0 < facts["windowed_chunks"] <= facts["chunks"]
+        again = [r["windowed_chunks"] for p in said for r in p["spmm"]
+                 if r["orientation"] == facts["orientation"]]
+        assert again == [facts["windowed_chunks"]] * 3
     assert all(not p["densified_products"] for p in said)
     assert all("pallas_spmv" in p["executors"] for p in said)
 
@@ -128,6 +135,10 @@ def test_new_factor_arrays_hit_the_plan_templates(rng, compact_one_device):
     _, _, said = _fit(s, V, BlockMatrix.from_numpy(w0, mesh=s.mesh),
                       BlockMatrix.from_numpy(h0, mesh=s.mesh))
     assert [p["hit"] for p in said] == [False, False] + [True] * 4
+    # what a hit says of the plans is what the build said
+    for build, hit in zip(said[:2], said[2:4]):
+        assert hit["spmm"] == build["spmm"]
+        assert "windowed_chunks" in hit["spmm"][0]
     assert coo_lib.plan_builds() == builds + 2
     assert s.plan_cache_info()["plans"] == 2
     # a second fit from new factors: nothing compiles, nothing is built
@@ -264,7 +275,7 @@ def test_the_spans_say_what_the_reader_says(rng, compact_one_device,
         assert set(p["spmm"][0]) == {
             "orientation", "k", "layout", "entries", "slots", "chunks",
             "source_panels", "table", "overflow_edges", "panels",
-            "plan_bytes"}
+            "plan_bytes", "windowed_chunks"}
     lookups = [r for r in mine if r["name"] == "matrel.plan"]
     assert [r["attrs"] for r in lookups] == [
         {"via": "template", "hit": True}] * 2

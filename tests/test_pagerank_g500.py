@@ -267,10 +267,15 @@ def test_native_and_numpy_fills_agree_on_chunks(graphs, monkeypatch, rng,
     ref = spmv_lib.build_spmv_plan(dst, src, vals, v, v, layout="chunks")
     np.testing.assert_array_equal(nat.chunk_block, ref.chunk_block)
     assert nat.src8.shape == ref.src8.shape
-    # slot order within a block: input order (native), row-sorted
-    # (numpy); the same slots are real, and each block holds the same
-    # edges, as the same matvec shows bit for bit
+    # slot order within a block: with hub chunks input order (native)
+    # and row-sorted (numpy): the same slots are real, and each block
+    # holds the same edges, as the same matvec shows bit for bit;
+    # without them (PR 38) both lie by row, the same tables
     np.testing.assert_array_equal((nat.val != 0).sum(1), (ref.val != 0).sum(1))
+    if not hub_rows:
+        for name in ("src8", "lane", "off", "val"):
+            np.testing.assert_array_equal(getattr(nat, name),
+                                          getattr(ref, name), err_msg=name)
     assert (nat.hubs is not None) == (ref.hubs is not None) == bool(hub_rows)
     if hub_rows:        # the library splits the edges in one walk, numpy
         # takes each set out of the list: the same hubs, chunks and slots

@@ -1219,3 +1219,294 @@ def test_no_hub_chunks_where_the_caller_declines_them(rng, monkeypatch):
                                        layout="chunks", hubs=False)
     assert with_hubs.hubs is not None and without.hubs is None
     assert without.src8.size < with_hubs.src8.size + with_hubs.hubs.idx.size
+
+
+# -- row order and a window a chunk (PR 38) ----------------------------------------
+
+
+def _native_or_skip():
+    from matrel_tpu.utils import native
+    if native.spmv_counts(np.zeros(1, np.int64), 512, 1) is None:
+        pytest.skip("native library unavailable")
+    return native
+
+
+@pytest.mark.parametrize("shape", [
+    (3000, 700, 60_000),        # a hub block of many chunks, an empty one
+    (700, 3000, 60_000),        # two blocks, the second short
+    (512, 40, 100),             # one block, one chunk, mostly padding
+    (5000, 5000, 0),            # no entry at all
+    (1300, 64, 2048 * 3),       # a block's entries fill its chunks whole
+], ids=["hub_block", "two_blocks", "one_chunk", "empty", "no_padding"])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_the_ragged_fills_agree_slot_for_slot_in_row_order(rng, monkeypatch,
+                                                          shape, weighted):
+    """The chunks layout without hubs: the library's fill and numpy's lay
+    the same tables, and a block's real slots lie by row, a row's in
+    input order."""
+    native = _native_or_skip()
+    n_rows, n_cols, m = shape
+    if n_rows == 3000:
+        rows, cols, vals = _skewed_entries(rng, n_rows, n_cols, m)
+    else:
+        rows, cols = rng.integers(0, n_rows, m), rng.integers(0, n_cols, m)
+        vals = rng.standard_normal(m).astype(np.float32)
+    if m == 2048 * 3:
+        rows = np.repeat(np.arange(3) * 512, 2048) + rng.integers(0, 200, m)
+    vals = vals if weighted else None
+    nat = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
+                                   layout="chunks", hubs=False)
+    monkeypatch.setattr(native, "spmv_counts", lambda *a: None)
+    ref = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
+                                   layout="chunks", hubs=False)
+    for name in ("src8", "lane", "off", "val", "chunk_block"):
+        np.testing.assert_array_equal(getattr(nat, name), getattr(ref, name),
+                                      err_msg=name)
+    # by row over the real slots of every block, and stable: the columns
+    # of one row come in the order the entries were given
+    src = nat.src8.astype(np.int64) * 8 + nat.lane
+    real = src != n_cols
+    assert real.sum() == m
+    for b in np.unique(nat.chunk_block):
+        at = nat.chunk_block == b
+        off, s, r = nat.off[at].ravel(), src[at].ravel(), real[at].ravel()
+        assert r[:r.sum()].all(), "padding lies at the block's end"
+        assert (np.diff(off[r]) >= 0).all()
+        given = rows // 512 == b
+        order = np.argsort(rows[given], kind="stable")
+        np.testing.assert_array_equal(s[r], cols[given][order])
+
+
+_WIN_CASES = {
+    # name: (real slots' rows, padded slots, block) -> win
+    "fits": ([200, 201, 250, 327], 0, 512, 200),
+    "one_row": ([77] * 9, 0, 512, 72),
+    "unaligned_least_row": ([13, 14, 130], 0, 512, 8),
+    "spans_128_rows_from_an_aligned_one": ([8, 135], 0, 512, 8),
+    "spans_a_row_more": ([8, 136], 0, 512, -1),
+    "121_rows_from_an_unaligned_one": ([15, 135], 0, 512, 8),
+    "122_rows_from_an_unaligned_one": ([15, 136], 0, 512, -1),
+    "the_whole_block": ([0, 511], 0, 512, -1),
+    "at_the_blocks_end_the_window_moves_back": ([500, 511], 0, 512, 384),
+    "padding_at_the_blocks_end_does_not_void_it": ([480, 511], 5, 512, 384),
+    "padding_does_not_void_a_window_far_from_row_0": ([300, 310], 7, 512,
+                                                      296),
+    "all_padding": ([], 6, 512, 384),
+    "a_block_of_one_window": ([0, 127], 1, 128, 0),
+    "a_block_shorter_than_a_window": ([0, 3], 0, 64, -1),
+    "a_wider_block": ([1000, 1100], 2, 2048, 1000),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WIN_CASES))
+def test_a_chunks_window_is_read_off_its_rows(case):
+    rows, padded, block, want = _WIN_CASES[case]
+    off = np.array([rows + [0] * padded], np.int32)
+    real = np.array([[True] * len(rows) + [False] * padded])
+    # among other chunks, which it does not look at
+    off = np.concatenate([np.array([[0] * off.shape[1]], np.int32), off,
+                          np.array([[block - 1] * off.shape[1]], np.int32)])
+    real = np.concatenate([np.ones_like(real), real, np.ones_like(real)])
+    win = spmv_lib.chunk_windows(off, real, block)
+    assert win.dtype == np.int32 and win.shape == (3,)
+    assert win[1] == want
+    if block >= spmv_lib.WINDOW:
+        assert win[0] == 0 and win[2] == block - spmv_lib.WINDOW
+    if want >= 0:
+        assert want % 8 == 0 and want + spmv_lib.WINDOW <= block
+        assert all(want <= r < want + spmv_lib.WINDOW for r in rows)
+
+
+def _wide_scatter_before_pr38(cb, skip, off, val, g, acc, block, passes=3):
+    """The k-wide chunk scatter as PR 37 wrote it: every chunk takes the
+    whole block's one-hot."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from matrel_tpu.ops import pallas_spmv as pc
+    from matrel_tpu.ops.spmv_routed import _bf16_split
+    n, cr, _ = off.shape
+
+    def kernel(cb_ref, skip_ref, off_ref, val_ref, g_ref, acc_ref, y_ref):
+        @pl.when(pc._first_chunk_of_its_block(cb_ref))
+        def _():
+            y_ref[...] = acc_ref[...]
+
+        @pl.when(pl.program_id(0) >= skip_ref[0])
+        def _():
+            off, val = off_ref[0], val_ref[0]
+            rows = jax.lax.broadcasted_iota(jnp.int32, (block, 128), 0)
+            eye = (jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+                   == jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1))
+            acc = jnp.zeros((block, 128), jnp.float32)
+            for s in range(cr):
+                oh = (off[s:s + 1, :] == rows).astype(jnp.bfloat16)
+                col = jnp.sum(jnp.where(eye, val[s:s + 1, :], 0.0),
+                              axis=1, keepdims=True)
+                w = g_ref[0, s * 128:(s + 1) * 128, :] * col
+                for part in _bf16_split(w, passes):
+                    acc = acc + jnp.dot(oh, part.astype(jnp.bfloat16),
+                                        precision=jax.lax.Precision.DEFAULT,
+                                        preferred_element_type=jnp.float32)
+            y_ref[0] += acc
+
+    slots = pl.BlockSpec((1, cr, 128), lambda c, cb, skip: (c, 0, 0))
+    sums = pl.BlockSpec((1, block, 128), lambda c, cb, skip: (cb[c], 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(n,),
+            in_specs=[slots, slots,
+                      pl.BlockSpec((1, cr * 128, 128),
+                                   lambda c, cb, skip: (c, 0, 0)), sums],
+            out_specs=sums),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, jnp.float32),
+        input_output_aliases={5: 0}, interpret=True)(cb, skip, off, val, g,
+                                                     acc)
+
+
+def _one_block_of_both_kinds(rng):
+    """Block 1 (rows 512..1023): 300 rows of 2 entries, then 11 rows of
+    1,500 — its first chunk in row order spans 300 rows, its eight others
+    a few; block 0 holds a sprinkle, block 2 two chunks over all its
+    rows, block 3 nothing: 13 chunks."""
+    rows = np.concatenate([
+        512 + np.repeat(np.arange(300), 2), 512 + 300 + np.repeat(
+            np.arange(11), 1500), rng.integers(0, 512, 700),
+        rng.integers(1024, 1536, 2100)])
+    rng.shuffle(rows)
+    cols = rng.integers(0, 300, rows.size)
+    return rows, cols, rng.standard_normal(rows.size).astype(np.float32)
+
+
+def _wide_case(name, rng, monkeypatch):
+    """(plan, rows, cols, vals, windowed chunks wanted: all / none / some)"""
+    from matrel_tpu.ops import pallas_spmv as pc
+    n_rows, n_cols = 2048, 300
+    if name == "unsorted_blocks":
+        n_rows = 2560
+    if name == "sorted_chunks":
+        rows, cols, vals = _skewed_entries(rng, n_rows, n_cols, 120_000)
+        plan = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
+                                        layout="chunks", hubs=False)
+        want = "all"
+    elif name == "unsorted_blocks":
+        _native_or_skip()           # numpy's blocks fill sorts by row
+        # 4,224 entries in every block: a table row of 33 x 128 slots,
+        # walked as three chunks of 1,408, none of them padding
+        rows = rng.permutation(np.repeat(np.arange(5) * 512, 4224)
+                               + rng.integers(0, 512, 5 * 4224))
+        cols = rng.integers(0, n_cols, rows.size)
+        vals = rng.standard_normal(rows.size).astype(np.float32)
+        plan = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
+                                        layout="blocks")
+        assert plan.chunk_block is None and plan.src8.shape == (5, 4224)
+        want = "none"
+    elif name == "both_kinds_in_one_block":
+        rows, cols, vals = _one_block_of_both_kinds(rng)
+        plan = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
+                                        layout="chunks", hubs=False)
+        want = "some"
+    else:                           # hub chunks, entries given in row order
+        monkeypatch.setattr(spmv_lib, "_HUB_MIN_SHARE", 0.0)
+        rows, cols, vals = _skewed_entries(rng, n_rows, n_cols, 60_000)
+        cols[:30_000] = rng.integers(0, 40, 30_000)
+        order = np.argsort(rows, kind="stable")
+        rows, cols, vals = rows[order], cols[order], vals[order]
+        plan = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
+                                        layout="chunks")
+        assert plan.hubs is not None
+        want = "all"
+    return plan, rows, cols, vals, want
+
+
+@pytest.mark.parametrize("panels", [False, True], ids=["whole", "panels"])
+@pytest.mark.parametrize("name", ["sorted_chunks", "unsorted_blocks",
+                                  "both_kinds_in_one_block", "hub_chunks"])
+def test_k_wide_product_with_a_window_a_chunk(rng, monkeypatch, name,
+                                              panels):
+    """The k-wide product over chunks that take a 128-row window, chunks
+    that take the block, and both inside one block, whole and in panels
+    whose last is moved back (``skip`` > 0 inside a block): the float64
+    product to 2e-7, and where no chunk has a window the sums PR 37's
+    kernel gave, bit for bit."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    plan, rows, cols, vals, want = _wide_case(name, rng, monkeypatch)
+    wins, windowed = pc.wide_windows(plan)
+    walked = sum(int(w.shape[0]) for w in wins)
+    assert len(wins) == (2 if plan.hubs is not None else 1)
+    if want == "all":
+        assert windowed == walked == plan.src8.shape[0] + (
+            0 if plan.hubs is None else plan.hubs.idx.shape[0])
+    elif want == "none":
+        assert windowed == 0 and walked == 3 * plan.src8.shape[0]
+    else:
+        assert 0 < windowed < walked
+    if name == "both_kinds_in_one_block":
+        win, cb = np.asarray(wins[0]), plan.chunk_block
+        assert sorted(set(np.sign(win[cb == 1]))) == [-1, 1]
+    if panels:
+        # a small byte budget: the largest set of chunks in several
+        # panels, the last of them moved back over the one before
+        chunk = plan.src8.size // int(wins[0].shape[0])
+        n = max(int(w.shape[0]) for w in wins)
+        for most in (7, 5, 4, 3):
+            monkeypatch.setattr(pc, "_hbm_limit", lambda: 4 * most * chunk
+                                * pc._TEMP_BYTES_A_SLOT_WIDE)
+            per = pc.wide_panel_rows(n, chunk)
+            if n % per:
+                break
+        assert 1 < per < n and n % per, (per, n)
+        if name == "both_kinds_in_one_block":
+            # the moved-back panel starts inside block 1
+            assert plan.chunk_block[n - per] == 1 == plan.chunk_block[
+                n - per - 1]
+    k = 128
+    X = rng.standard_normal((plan.n_cols, k)).astype(np.float32)
+    got = np.asarray(pc.spmm_compact(plan, jnp.asarray(X), interpret=True))
+    truth = np.zeros((plan.n_rows, k))
+    np.add.at(truth, rows, vals.astype(np.float64)[:, None]
+              * X.astype(np.float64)[cols])
+    assert np.max(np.abs(got - truth)) / np.max(np.abs(truth)) <= 2e-7
+    if want != "none":
+        assert not got[1536:2048].any()             # the empty block
+    # without the windows every chunk takes the block: as good
+    static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
+    plain = np.asarray(jax.jit(
+        lambda t, ov, x: pc.compact_matmat_apply(static, t, ov, x, 3, True)
+    )(pc.compact_tables(plan), plan.overflow, jnp.asarray(X)))
+    assert np.max(np.abs(plain - truth)) / np.max(np.abs(truth)) <= 2e-7
+    if want == "none":
+        np.testing.assert_array_equal(got, plain)
+
+
+def test_k_wide_chunks_without_a_window_compute_what_pr37_did(rng):
+    """A panel of chunks in input order (``win`` −1 throughout) through
+    the kernel as it is and as PR 37 wrote it: the same bits; and with a
+    window on the chunks that can take one, the same sums to float32's
+    last digits (a row's terms meet in another order)."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    n, cr, block, nb = 6, 4, 512, 3
+    cb = jnp.asarray([0, 0, 1, 1, 1, 2], jnp.int32)
+    off = rng.integers(0, block, (n, cr, 128)).astype(np.int32)
+    off[2] = rng.integers(380, 500, (cr, 128))      # can take a window
+    off[5] = 511
+    val = rng.standard_normal((n, cr, 128)).astype(np.float32)
+    g = rng.standard_normal((n, cr * 128, 128)).astype(np.float32)
+    acc = rng.standard_normal((nb, block, 128)).astype(np.float32)
+    skip = jnp.asarray([1], jnp.int32)
+    was = np.asarray(_wide_scatter_before_pr38(
+        cb, skip, jnp.asarray(off), jnp.asarray(val), jnp.asarray(g),
+        jnp.asarray(acc), block))
+    run = pc._wide_runner(n, cr * 128, nb, block, 3, True)
+    none = jnp.full((n,), -1, jnp.int32)
+    now = np.asarray(run(cb, skip, none, jnp.asarray(off), jnp.asarray(val),
+                         jnp.asarray(g), jnp.asarray(acc)))
+    np.testing.assert_array_equal(now, was)
+    win = spmv_lib.chunk_windows(off.reshape(n, -1),
+                                 np.ones((n, cr * 128), bool), block)
+    assert list(win) == [-1, -1, 376, -1, -1, 384]
+    windowed = np.asarray(run(cb, skip, jnp.asarray(win), jnp.asarray(off),
+                              jnp.asarray(val), jnp.asarray(g),
+                              jnp.asarray(acc)))
+    np.testing.assert_array_equal(windowed[0], was[0])
+    np.testing.assert_allclose(windowed, was, rtol=0, atol=2e-5)
