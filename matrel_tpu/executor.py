@@ -17,6 +17,7 @@ mask padding where zeros would change the answer (max/min/avg/count).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -361,21 +362,42 @@ class Lowerer:
         workload, this is a local (replicated) solve intended for
         small/medium systems (e.g. the k×k Gram matrix); it is not a
         distributed triangular solve. Computed in f32 for stability,
-        cast back when keep_input_dtype asks for it."""
+        cast back when keep_input_dtype asks for it. On a mesh whose
+        devices each hold both operands whole (planner.infer_layout
+        "rep": what an all-reduce leaves of a row-partitioned table's
+        Gram) the replication is pinned: every device factorises its
+        own copy inside a ``shard_map``, with no collective."""
         l, r = node.children
         n = l.shape[0]
         m = r.shape[1]
-        a = ev(l)[:n, :n]
-        b = ev(r)[:n, :m]
-        if node.attrs.get("assume") == "pos":
-            c, low = jax.scipy.linalg.cho_factor(a.astype(jnp.float32))
-            out = jax.scipy.linalg.cho_solve((c, low),
-                                             b.astype(jnp.float32))
+
+        def local(a, b):
+            if node.attrs.get("assume") == "pos":
+                c, low = jax.scipy.linalg.cho_factor(a.astype(jnp.float32))
+                out = jax.scipy.linalg.cho_solve((c, low),
+                                                 b.astype(jnp.float32))
+            else:
+                out = jnp.linalg.solve(a.astype(jnp.float32),
+                                       b.astype(jnp.float32))
+            if self.config.keep_input_dtype and a.dtype == b.dtype:
+                out = out.astype(a.dtype)
+            return out
+
+        if planner.infer_layout(node, self.mesh, self._lay_memo,
+                                self.config) == "rep":
+            # operands every device holds whole (a Gram and a right-hand
+            # side that an all-reduce left on each): every device's own
+            # factorisation, pinned so — the partitioner is not asked
+            # whether to cut a 1000^2 LU in four
+            from jax.sharding import PartitionSpec as P
+            from matrel_tpu.utils.compat import shard_map
+            out = shard_map(lambda a, b: local(a[:n, :n], b[:n, :m]),
+                            mesh=self.mesh, in_specs=(P(), P()),
+                            out_specs=P(), check_vma=False)(ev(l), ev(r))
         else:
-            out = jnp.linalg.solve(a.astype(jnp.float32),
-                                   b.astype(jnp.float32))
-        if self.config.keep_input_dtype and a.dtype == b.dtype:
-            out = out.astype(a.dtype)
+            a = ev(l)[:n, :n]
+            b = ev(r)[:n, :m]
+            out = local(a, b)
         return self._pad_to_node(out, node)
 
     def _inverse(self, node: MatExpr, ev) -> Array:
@@ -699,12 +721,18 @@ class Lowerer:
                         strategy, p, q.T, self.mesh, self.config)
                 return fin(symmetric_gram(x, mm).astype(jnp.float32))
         strategy = node.attrs.get("strategy", "xla")
-        if (self.mesh.size == 1 and strategy == "xla"
+        if (strategy == planner.in_place_strategy(self.mesh)
                 and node.attrs.get("precision_tier") in (None, "f32")):
             out = self._long_contraction(node, ev)
             if out is not None:
                 self._ran(strategy)
                 return fin(out)
+        if strategy == planner.OWN_ROWS:
+            raise ValueError(
+                f"matmul {node.shape} is stamped {planner.OWN_ROWS!r} but "
+                "is no long float32 contraction over operands that lie by "
+                "rows (planner.long_in_place): the stamp was not the "
+                "planner's")
         a, b = ev(node.children[0]), ev(node.children[1])
         self._ran(strategy)
         if self.config.reshard_peak_budget_bytes > 0:
@@ -754,26 +782,34 @@ class Lowerer:
                                      panels=panels, out_dtype=store)
 
     def _long_contraction(self, node: MatExpr, ev) -> Optional[Array]:
-        """One device's plain local dot over a LONG float32 contraction
-        (the regression's t(X)·X and t(X)·y over millions of rows):
-        accumulated in panels (strategies.dot_in_panels), or None where
-        the product is not one (bfloat16 and integer tables round, or
-        do not round, their answers on other terms). A transposed
-        operand is handed over by its dimension, untransposed. Where
-        both operands are the same one (planner.long_gram) the panels
-        multiply the upper block triangle alone
-        (strategies.gram_in_panels), and where a second product over
-        the same table rides that loop (planner.gram_riders) the pair
-        is evaluated once: whichever of the two is reached first runs
-        the loop, and the other's product waits in ``_rode``."""
+        """A product over a LONG float32 contraction (the regression's
+        t(X)·X and t(X)·y over millions of rows) multiplied where its
+        operands lie: accumulated in panels (strategies.dot_in_panels),
+        or None where the product is not one (bfloat16 and integer
+        tables round, or do not round, their answers on other terms).
+        A transposed operand is handed over by its dimension,
+        untransposed. Where both operands are the same one
+        (planner.long_gram) the panels multiply the upper block
+        triangle alone (strategies.gram_in_panels), and where a second
+        product over the same table rides that loop
+        (planner.gram_riders) the pair is evaluated once: whichever of
+        the two is reached first runs the loop, and the other's product
+        waits in ``_rode``. One device runs the loop as it is; a mesh
+        (the stamp planner.OWN_ROWS: the tables lie cut over all
+        devices along the contraction) runs it a device at a time
+        inside one ``shard_map`` and all-reduces the accumulators once
+        (strategies.over_own_rows), and its products come out
+        replicated."""
         l, r = node.children
         if l.shape[1] < strategies.LONG_CONTRACTION:
             return None
+        own = functools.partial(strategies.over_own_rows, self.mesh)
         gram, rider = self._riders.get(node.uid, (None, None))
         if node is gram:
-            out, self._rode[rider.uid] = strategies.gram_in_panels(
-                ev(l.children[0]), 0, self.config,
-                rhs=ev(rider.children[1]))
+            out, self._rode[rider.uid] = own(
+                lambda reduce, x, y: strategies.gram_in_panels(
+                    x, 0, self.config, rhs=y, reduce=reduce),
+                (ev(l.children[0]), ev(rider.children[1])), (0, 0))
             return out
         if node is rider:
             ev(gram)
@@ -783,15 +819,15 @@ class Lowerer:
                                   self._dt_memo)
         if found is not None:
             side, base = found
-            return strategies.gram_in_panels(
-                ev(base), 0 if side == "AtA" else 1, self.config)
-        a, ca = (ev(l.children[0]), 0) if l.kind == "transpose" \
-            else (ev(l), 1)
-        b, cb = (ev(r.children[0]), 1) if r.kind == "transpose" \
-            else (ev(r), 0)
+            c = 0 if side == "AtA" else 1
+            return own(lambda reduce, x: strategies.gram_in_panels(
+                x, c, self.config, reduce=reduce), (ev(base),), (c,))
+        (a, ca), (b, cb) = planner.own_rows_operands(node, self.mesh)
+        a, b = ev(a), ev(b)
         if a.dtype != jnp.float32 or b.dtype != jnp.float32:
             return None
-        return strategies.dot_in_panels(a, ca, b, cb, self.config)
+        return own(lambda reduce, a, b: strategies.dot_in_panels(
+            a, ca, b, cb, self.config, reduce=reduce), (a, b), (ca, cb))
 
     def _stage_root_relay(self, root: MatExpr, out: Array) -> Array:
         """Root output → canonical 2d through the compiled reshard

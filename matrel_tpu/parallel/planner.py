@@ -20,6 +20,7 @@ from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib
 from matrel_tpu.ir.expr import MatExpr
 from matrel_tpu.parallel.strategies import (LONG_CONTRACTION, acc_itemsize,
+                                            gram_reduce_bytes,
                                             gram_rider_room, gram_tiles,
                                             rmm_moves_under_dot, rmm_panels,
                                             rmm_transient_bytes)
@@ -197,6 +198,14 @@ def _comm_detail(strategy: str, n: int, k: int, m: int,
                             to2d(b_bytes, b_layout),
                             extra_steps_w=(g - 1) * wy + (g - 1) * wx)
         return cost, ax["x"], ax["y"]
+    if strategy == OWN_ROWS:
+        # both operands read where they lie (chosen only where they lie
+        # cut over all devices along the contraction: own_rows_operands);
+        # one all-reduce of the partial C over both axes, 2 (g - 1) / g
+        # of it a device and axis
+        t_y = leg(2.0 * c_bytes * (gy - 1) / gy, "y")
+        t_x = leg(2.0 * c_bytes * (gx - 1) / gx, "x")
+        return total(t_y, t_x), ax["x"], ax["y"]
     if strategy == "spgemm":
         # S×S tile-intersection (ops/spgemm.py): both tile stacks are
         # replicated (the broadcast side of the SpMM plan family), the
@@ -294,6 +303,22 @@ def comm_cost_axes(strategy: str, n: int, k: int, m: int,
     return bx, by
 
 
+#: The stamp of a product that reads both operands where they lie, cut
+#: over ALL devices along the contraction, and all-reduces the devices'
+#: partial products (strategies.over_own_rows): upstream's cross-product
+#: multiply over a RowPartitioner's partitions. No byte-model candidate:
+#: chosen from how the operands lie (:func:`long_in_place`), by
+#: ``strategy_source`` "layout".
+OWN_ROWS = "cpmm_rows"
+
+
+def in_place_strategy(mesh: Mesh) -> str:
+    """The stamp of the product that multiplies what each device holds,
+    as it lies: the plain local dot on one device, :data:`OWN_ROWS` on
+    a mesh."""
+    return "xla" if mesh.size == 1 else OWN_ROWS
+
+
 def _norm_axes(e):
     """Normalise one PartitionSpec entry: 1-tuples to their element,
     multi-axis tuples kept as tuples."""
@@ -382,7 +407,9 @@ def infer_layout(node: MatExpr, mesh: Mesh,
       left/right emit the KEPT side's layout;
     - agg: "all"/"diag" produce a replicated 1x1; row-agg of a
       row-sharded operand stays row-sharded (resp. col);
-    - everything else (vec's reshape, solve/inverse local solves,
+    - solve: "rep" where both operands are replicated on a mesh (every
+      device then runs the local solve on its own copy, executor._solve);
+    - everything else (vec's reshape, other solve/inverse local solves,
       materialised value-joins, sparse/coo leaves used densified):
       "2d" — the conservative status quo; free-ness is only ever
       claimed where the lowering pins it.
@@ -479,6 +506,11 @@ def infer_layout(node: MatExpr, mesh: Mesh,
                 return _scheme_out_layout(rep, n, walk(n.children[0]),
                                           walk(n.children[1]))
             return "2d"
+        if k == "solve" and mesh.size > 1 and all(
+                walk(c) == "rep" for c in n.children):
+            # every device's own factorisation of what every device
+            # holds (executor._solve pins it)
+            return "rep"
         return "2d"
 
     return walk(node)
@@ -1010,6 +1042,11 @@ def strategy_transient_bytes(strategy: str, pn: int, pk: int, pm: int,
         return b + a / p + wide
     if strategy == "bmm_left":
         return a + b / p + wide
+    if strategy == OWN_ROWS:
+        # operands read in place (none); the device's partial product
+        # in the loop's accumulators, and the all-reduce's result
+        # beside it (a Gram's block triangle is 5/8 of this)
+        return 2.0 * c_acc
     return 0.0                            # spgemm / unknown
 
 
@@ -1032,6 +1069,8 @@ def divides(strategy: str, pn: int, pk: int, pm: int,
     if strategy == "summa":
         return (gx == gy and pn % gx == 0 and pm % gy == 0
                 and pk % gx == 0 and pk % gy == 0)
+    if strategy == OWN_ROWS:
+        return pk % p == 0
     return True  # xla
 
 
@@ -1143,7 +1182,9 @@ def plan_hbm_bytes(strategy: str, pn: int, pk: int, pm: int,
     working set, is what a chip has to hold: three 2 GiB tables and a
     chain's intermediate leave a v5e's 15.75 GiB 5.75 for the second
     product's transient (PERF.md §6, PR 27)."""
-    out = float(pn) * pm * itemsize / max(gx * gy, 1)
+    out = float(pn) * pm * itemsize
+    if STRATEGY_OUT_LAYOUT.get(strategy) != "rep":  # else whole on each
+        out /= max(gx * gy, 1)
     return alive + out + strategy_transient_bytes(
         strategy, pn, pk, pm, gx, gy, itemsize, panels)
 
@@ -1206,7 +1247,7 @@ def _root_reshard_cost(strategy: str, n: int, m: int,
 #: consumer-aware tiebreak (review r5).
 STRATEGY_OUT_LAYOUT = {"bmm_right": "row", "bmm_left": "col",
                        "cpmm": "2d", "rmm": "2d", "summa": "2d",
-                       "xla": "2d", "spgemm": "2d"}
+                       "xla": "2d", "spgemm": "2d", OWN_ROWS: "rep"}
 
 #: Near-tie band for the consumer-aware STRATEGY tiebreak (the matmul
 #: analogue of JOIN_TIE_REL): candidates within this margin of the
@@ -1249,8 +1290,10 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
     the observability side of the closed loop (physical EXPLAIN prints
     it): "override" (config.strategy_override), "dispatch" (an S×S
     SpGEMM the lowering takes regardless of the byte model), "measured"
-    (autotune table hit), "model" (byte-model argmin), "default"
-    (single device / no admissible candidates).
+    (autotune table hit), "model" (byte-model argmin), "layout" (a
+    long float32 product whose operands lie cut over all devices along
+    the contraction is multiplied where it lies: :func:`long_in_place`),
+    "default" (single device / no admissible candidates).
 
     ``cost_detail`` (an out-param dict, the return tuple stays a
     2-tuple for the existing callers — analysis passes unpack it
@@ -1337,6 +1380,18 @@ def choose_strategy_ex(node: MatExpr, mesh: Mesh,
                               refused_hbm=[] if ok else [forced])
         return ((forced, "override") if cfg.strategy_override != "auto"
                 else ("xla", "default"))
+    if long_in_place(node, mesh, cfg, dtype_memo):
+        # nothing to rank: cpmm, rmm and summa would each re-lay (and a
+        # transposed operand first copy) a table that the devices can
+        # multiply where it lies; the gate can only say that what the
+        # plan keeps beside it does not fit
+        need, panels, ok = _hbm_gate([OWN_ROWS], pn, pk, pm, gx, gy, isz,
+                                     alive_bytes, limit)[OWN_ROWS]
+        if hbm_detail is not None:
+            hbm_detail.update(chosen=OWN_ROWS, panels=panels,
+                              moves_under_dot=0, hbm_plan_bytes=int(need),
+                              refused_hbm=[] if ok else [OWN_ROWS])
+        return OWN_ROWS, "layout"
     la = infer_layout(a, mesh, layout_memo, cfg)
     lb = infer_layout(b, mesh, layout_memo, cfg)
     if cfg.autotune:
@@ -1767,22 +1822,73 @@ def gram_operand(node: MatExpr) -> Optional[Tuple[str, MatExpr]]:
     return None
 
 
+def own_rows_operands(node: MatExpr, mesh: Mesh
+                      ) -> Optional[Tuple[Tuple[MatExpr, int],
+                                          Tuple[MatExpr, int]]]:
+    """((a, ca), (b, cb)) of a product: its operands AS THEY LIE (a
+    transposed one is the table under the transpose) and the dimension
+    each is contracted over — or None on a mesh where not both are
+    leaves cut over ALL devices along that dimension (``_layout_of``
+    "row" for 0, "col" for 1): then a device's share of one is not the
+    other's, and the product is no sum of the devices' own. A leaf's
+    layout is honoured as it lies: a canonical ``P(x, y)`` table is not
+    re-laid by rows behind its owner's back, and plans as it did."""
+    l, r = node.children
+    a, ca = (l.children[0], 0) if l.kind == "transpose" else (l, 1)
+    b, cb = (r.children[0], 1) if r.kind == "transpose" else (r, 0)
+    if mesh.size > 1 and any(_layout_of(t, mesh) != ("row", "col")[c]
+                             for t, c in ((a, ca), (b, cb))):
+        return None
+    return (a, ca), (b, cb)
+
+
+def long_in_place(node: MatExpr, mesh: Mesh,
+                  config: Optional[MatrelConfig] = None,
+                  dtype_memo: Optional[dict] = None) -> bool:
+    """Is this product of a MESH plan multiplied where its operands lie
+    (:data:`OWN_ROWS`: strategies.over_own_rows around the panelled
+    contraction of one device)? A float32 product over a contraction of
+    LONG_CONTRACTION or more whose operands both lie cut over all
+    devices along it (:func:`own_rows_operands`), no strategy forced
+    and no precision tier on it; a Gram under ``matmul_precision``
+    "high" keeps ops/gram.py's two-pass split over a ranked strategy.
+    Asked before the product is stamped (choose_strategy_ex, and
+    annotate_strategies for the transpose beneath it), from what can be
+    observed: shapes, dtypes, the leaves' own layouts, the config."""
+    cfg = config or default_config()
+    if (mesh.size == 1 or cfg.strategy_override != "auto"
+            or node.children[0].shape[1] < LONG_CONTRACTION):
+        return False
+    found = own_rows_operands(node, mesh)
+    if found is None or (cfg.matmul_precision == "high"
+                         and gram_operand(node) is not None):
+        return False
+    tier = (node.attrs["precision_tier"] if "precision_tier" in node.attrs
+            else choose_precision_tier(node, cfg, dtype_memo=dtype_memo))
+    return tier in (None, "f32") and all(
+        infer_dtype(t, cfg, dtype_memo) == np.float32 for t, _ in found)
+
+
 def long_gram(node: MatExpr, mesh: Mesh,
               config: Optional[MatrelConfig] = None,
               dtype_memo: Optional[dict] = None
               ) -> Optional[Tuple[str, MatExpr]]:
     """:func:`gram_operand` of a product that is lowered as the upper
     block triangle of its panels (strategies.gram_in_panels): a float32
-    Gram on one device under the plain local dot whose contraction is
-    LONG_CONTRACTION or longer (``matmul_precision`` "high" lowers every
-    float32 Gram as ops/gram.py's two-pass split instead: none is one
-    there). The ONE test the stamp (``gram_tiles``,
-    annotate_strategies) and the lowering (executor._long_contraction)
-    both ask, so they cannot disagree."""
+    Gram whose contraction is LONG_CONTRACTION or longer, multiplied
+    where its table lies (:func:`in_place_strategy`: under the plain
+    local dot on one device; on a mesh, a device at a time over a table
+    that lies cut over all devices along the contraction, the stamp
+    :data:`OWN_ROWS` that :func:`long_in_place` alone hands out).
+    ``matmul_precision`` "high" lowers every float32 Gram as
+    ops/gram.py's two-pass split instead: none is one there. The ONE
+    test the stamp (``gram_tiles``, annotate_strategies) and the
+    lowering (executor._long_contraction) both ask, for one device and
+    for the mesh, so they cannot disagree."""
     gram = gram_operand(node)
-    if (gram is None or mesh.size != 1
+    if (gram is None
             or (config or default_config()).matmul_precision == "high"
-            or node.attrs.get("strategy", "xla") != "xla"
+            or node.attrs.get("strategy", "xla") != in_place_strategy(mesh)
             or node.children[0].shape[1] < LONG_CONTRACTION
             or infer_dtype(gram[1], config, dtype_memo) != np.float32):
         return None
@@ -1812,8 +1918,9 @@ def gram_riders(root: MatExpr, mesh: Mesh,
     """{uid: (gram, rider)}, under both nodes' uids, of the products of
     one plan that are lowered as ONE loop over their table
     (strategies.gram_in_panels ``rhs``): a :func:`long_gram` ``t(X) *
-    X`` and a ``t(X) * B`` over the very same ``X``, float32 on the
-    plain local dot, whose ``B`` fits the lanes the Gram's last block
+    X`` and a ``t(X) * B`` over the very same ``X``, float32 and
+    multiplied where they lie like it (:func:`in_place_strategy`),
+    whose ``B`` fits the lanes the Gram's last block
     column leaves spare in its MXU tile (strategies.gram_rider_room: 24
     columns at k = 1000, none where k is a multiple of 128) and is not
     computed from the Gram. A Gram carries one product, the first in
@@ -1822,23 +1929,46 @@ def gram_riders(root: MatExpr, mesh: Mesh,
     lowering (executor._long_contraction) both ask."""
     nodes = [n for n in _nodes(root) if n.kind == "matmul"]
     pairs: Dict[int, Tuple[MatExpr, MatExpr]] = {}
+    from matrel_tpu.core import padding
+    own = in_place_strategy(mesh)
     for gram in nodes:
         found = long_gram(gram, mesh, config, dtype_memo)
-        room = gram_rider_room(gram.shape[0])
+        # the arrays a mesh's lowering sees are the padded ones
+        room = gram_rider_room(padding.padded_shape(gram.shape, mesh)[0])
         if found is None or found[0] != "AtA" or not room:
             continue
         for rider in nodes:
             l, r = rider.children
             if (rider.uid not in pairs and l.kind == "transpose"
                     and _same_operand(l.children[0], found[1])
-                    and r.shape[1] <= room
-                    and rider.attrs.get("strategy", "xla") == "xla"
+                    and padding.padded_shape(r.shape, mesh)[1] <= room
+                    and rider.attrs.get("strategy", "xla") == own
                     and rider.attrs.get("precision_tier") is None
                     and infer_dtype(r, config, dtype_memo) == np.float32
                     and gram not in _nodes(r)):
                 pairs[gram.uid] = pairs[rider.uid] = (gram, rider)
                 break
     return pairs
+
+
+def own_rows_stamps(node: MatExpr, mesh: Mesh) -> dict:
+    """What the observability says of a product multiplied where its
+    operands lie on a mesh (:data:`OWN_ROWS`): ``operand_layout`` (how
+    the left table lies: "row" under a transpose), ``devices`` (whose
+    partial products the all-reduce adds), ``rows_a_device`` (of the
+    contraction, as padded) and ``reduce_bytes`` (what the all-reduce
+    moves a device: the partial product; :func:`_stamp_gram_riders`
+    corrects it where the product is a Gram's block triangle with its
+    riders, or rides one and moves nothing of its own)."""
+    from matrel_tpu.core import padding
+    (a, ca), _ = own_rows_operands(node, mesh)
+    pn, pk = padding.padded_shape(node.children[0].shape, mesh)
+    pm = padding.padded_shape(node.shape, mesh)[1]
+    return {"operand_layout": ("row", "col")[ca], "devices": mesh.size,
+            "rows_a_device": pk // mesh.size,
+            "reduce_bytes": (gram_reduce_bytes(pn)
+                             if gram_operand(node) is not None
+                             else 4 * pn * pm)}
 
 
 def _stamp_gram_riders(root: MatExpr, mesh: Mesh,
@@ -1848,6 +1978,7 @@ def _stamp_gram_riders(root: MatExpr, mesh: Mesh,
     counter of the one-pass lowering: ``gram_rides`` (the columns
     carried) on the Gram, ``rides_gram`` on the product that has no
     loop of its own. A plan with no pair comes back as it is."""
+    from matrel_tpu.core import padding
     pairs = gram_riders(root, mesh, config, dtype_memo)
     if not pairs:
         return root
@@ -1863,6 +1994,12 @@ def _stamp_gram_riders(root: MatExpr, mesh: Mesh,
                 out = out.with_attrs(**(
                     {"rides_gram": True} if n is rider
                     else {"gram_rides": rider.shape[1]}))
+                if "reduce_bytes" in out.attrs:
+                    # the pair's ONE all-reduce is the Gram's
+                    out = out.with_attrs(reduce_bytes=0 if n is rider
+                                         else gram_reduce_bytes(
+                                             *padding.padded_shape(
+                                                 rider.shape, mesh)))
             done[n.uid] = out
         return done[n.uid]
 
@@ -1870,14 +2007,20 @@ def _stamp_gram_riders(root: MatExpr, mesh: Mesh,
 
 
 def _folded_transpose(node: MatExpr, parent: Optional[MatExpr],
-                      mesh: Mesh) -> bool:
+                      mesh: Mesh,
+                      config: Optional[MatrelConfig] = None,
+                      dtype_memo: Optional[dict] = None) -> bool:
     """A transpose that is no array: on one device the product that
     reads it contracts over the other dimension (``x.T`` under a
     ``dot`` is the dot's dimension numbers; compiled for a v5e, t(X)·X
-    of a 10 GB table has 5 MB of temporaries). On a mesh it is a
+    of a 10 GB table has 5 MB of temporaries), and so does a mesh's
+    product that multiplies its operands where they lie
+    (:func:`long_in_place`). Under any other product of a mesh it is a
     re-lay of the shards and stays counted."""
-    return (node.kind == "transpose" and mesh.size == 1
-            and parent is not None and parent.kind == "matmul")
+    return (node.kind == "transpose" and parent is not None
+            and parent.kind == "matmul"
+            and (mesh.size == 1
+                 or long_in_place(parent, mesh, config, dtype_memo)))
 
 
 def annotate_strategies(e: MatExpr, mesh: Mesh,
@@ -1933,7 +2076,8 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
         nc = annotate_strategies(c, mesh, config, memo, lmemo, h,
                                  _child_root_scale(e, i, _root_scale),
                                  swap, imemo, alive, e)
-        if nc.children and not _folded_transpose(nc, e, mesh):
+        if nc.children and not _folded_transpose(nc, e, mesh, config,
+                                                 memo):
             # a computed value, kept for e
             alive += device_bytes(nc, mesh, config, memo, lmemo)
         new_children.append(nc)
@@ -2000,6 +2144,8 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
                 stamp.pop("refused_hbm", None)
                 if 0 < limit < need:
                     stamp["refused_hbm"] = (coo["chosen"],)
+        if strat == OWN_ROWS:
+            stamp.update(own_rows_stamps(e, mesh))
         e = e.with_attrs(**stamp)
         if long_gram(e, mesh, config, memo) is not None:
             # engagement counter of the triangle lowering: block
@@ -2023,7 +2169,7 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
                              spgemm_structure=struct,
                              spgemm_kernel_source=ksrc)
     if (e.kind in ("solve", "transpose") and "hbm_plan_bytes" not in e.attrs
-            and not _folded_transpose(e, _parent, mesh)):
+            and not _folded_transpose(e, _parent, mesh, config, memo)):
         # no strategy to choose, but an array the device has to hold:
         # what is alive at it (its computed operands among them), its
         # own value (the root's was counted into ``_held``), and what
@@ -2061,7 +2207,10 @@ def hbm_report(root: MatExpr) -> list:
     multiplies, of those the square holds; where that Gram's loop
     carries a second product over its table (:func:`gram_riders`),
     ``gram_rides`` (the columns carried) on the Gram and ``rides_gram``
-    on the product carried."""
+    on the product carried; on a mesh's product multiplied where its
+    operands lie (``chosen`` :data:`OWN_ROWS`), :func:`own_rows_stamps`'
+    ``operand_layout``, ``devices``, ``rows_a_device`` and
+    ``reduce_bytes``."""
     out = []
     for n in _nodes(root):
         if "hbm_plan_bytes" in n.attrs:
@@ -2083,7 +2232,8 @@ def hbm_report(root: MatExpr) -> list:
                     out[-1]["densified_bytes"] = int(coo["bytes"])
             if "gram_tiles" in n.attrs:
                 out[-1]["gram_tiles"] = list(n.attrs["gram_tiles"])
-            for stamp in ("gram_rides", "rides_gram"):
+            for stamp in ("gram_rides", "rides_gram", "operand_layout",
+                          "devices", "rows_a_device", "reduce_bytes"):
                 if stamp in n.attrs:
                     out[-1][stamp] = n.attrs[stamp]
     return out
@@ -2091,16 +2241,20 @@ def hbm_report(root: MatExpr) -> list:
 
 def refuse_over_limit(roots, mesh: Mesh,
                       config: Optional[MatrelConfig] = None) -> None:
-    """Raise :class:`PlanMemoryError` for a ONE-DEVICE plan that holds
-    a node stamped over the limit, naming the first such node in
-    evaluation order. On a mesh a refused strategy leaves others to
-    choose from, and where none fits the one that needs least is handed
-    over (``refused_hbm`` says so); on one device there is one way to
+    """Raise :class:`PlanMemoryError` for a plan that holds a node
+    stamped over the limit with no way left to run it, naming the first
+    such node in evaluation order. On one device there is one way to
     run a node, and what the reckoning adds up are arrays that have to
     exist — the leaves, the intermediates alive, the node's own value —
     so a plan over the limit cannot run, and says so here instead of in
-    the compiler or the allocator."""
+    the compiler or the allocator. On a mesh a refused strategy leaves
+    others to choose from, and where none fits the one that needs least
+    is handed over (``refused_hbm`` says so): its verdict rests on an
+    estimate of the strategy's transient. A materialised transpose or a
+    solve over the limit is an array that has to exist, there as on one
+    device, and refuses the plan (:func:`_refuse_on_mesh`)."""
     if mesh.size != 1:
+        _refuse_on_mesh(roots, mesh, config)
         return
     for root in roots:
         n = next((n for n in _nodes(root) if n.attrs.get("refused_hbm")),
@@ -2136,6 +2290,43 @@ def refuse_over_limit(roots, mesh: Mesh,
             f"Write the query so that no intermediate is that wide, or "
             f"raise hbm_budget_bytes if the device really has the room "
             f"(0 turns the reckoning off).")
+
+
+def _refuse_on_mesh(roots, mesh: Mesh,
+                    config: Optional[MatrelConfig] = None) -> None:
+    """:func:`refuse_over_limit` for a mesh: raise where a materialised
+    transpose or a solve is over the limit. The message names every
+    node whose verdict left nothing to choose — those, and the products
+    that are over the limit under the strategy they were handed — the
+    first in evaluation order at its head."""
+    over = [n for root in roots for n in _nodes(root)
+            if n.attrs.get("strategy", n.kind)
+            in n.attrs.get("refused_hbm", ())]
+    if all(n.kind == "matmul" for n in over):
+        return
+    limit = mesh_lib.hbm_limit_bytes(mesh, config)
+
+    def says(n):
+        under = (f" under {n.attrs['strategy']} (refused: "
+                 f"{', '.join(n.attrs['refused_hbm'])})"
+                 if n.kind == "matmul" else "")
+        return (f"{n.kind} {n.shape[0]}x{n.shape[1]}{under}: "
+                f"{n.attrs['hbm_plan_bytes']:,} bytes")
+
+    raise PlanMemoryError(
+        f"plan refused before tracing: on the "
+        f"{'x'.join(str(g) for g in mesh_lib.mesh_grid_shape(mesh))} mesh "
+        f"the plan would hold on ONE device (its shards of the tables it "
+        f"reads, the intermediates alive, the node's value and the "
+        f"strategy's transient), at {'; at '.join(says(n) for n in over)}"
+        f" — over the limit of {limit:,} bytes (min of hbm_budget_bytes "
+        f"and the device's bytes_limit), and no strategy that fits was "
+        f"left to choose. A transposed table is a second table, and "
+        f"cpmm, rmm and summa re-lay their operands: a tall table whose "
+        f"Gram or t(X) * y is wanted is multiplied where it lies when it "
+        f"is registered by rows over all devices "
+        f"(PartitionSpec(('x', 'y'), None)). Raise hbm_budget_bytes if "
+        f"the devices really have the room (0 turns the reckoning off).")
 
 
 def matmul_decisions(root: MatExpr, mesh: Mesh,

@@ -108,7 +108,8 @@ def _sum_of_panels(length: int, panel, zero):
 
 
 def dot_in_panels(a, ca: int, b, cb: int,
-                  config: Optional[MatrelConfig] = None) -> jax.Array:
+                  config: Optional[MatrelConfig] = None,
+                  reduce=None) -> jax.Array:
     """The float32 product of ``a`` and ``b`` contracted over ``a``'s
     dimension ``ca`` and ``b``'s ``cb``, the contraction cut into panels
     of :data:`ACC_PANEL_ROWS`: each panel is one dot with an accumulator
@@ -118,7 +119,9 @@ def dot_in_panels(a, ca: int, b, cb: int,
     takes its operands whole: a transposed 10 GB table in front of the
     loop is a second table (compiled for a v5e: refused at 20.5 of 15.75
     GB), while a panel sliced from the table inside the loop is read in
-    place (temporaries: none)."""
+    place (temporaries: none). ``reduce`` is :func:`over_own_rows`':
+    applied to the sum of the panels where ``a`` and ``b`` are one
+    device's share of the contraction."""
     dims = (((ca,), (cb,)), ((), ()))
     prec = _precision(config)
 
@@ -128,9 +131,10 @@ def dot_in_panels(a, ca: int, b, cb: int,
             jax.lax.dynamic_slice_in_dim(b, start, rows, axis=cb),
             dims, precision=prec, preferred_element_type=jnp.float32)
 
-    return _sum_of_panels(
+    out = _sum_of_panels(
         a.shape[ca], panel,
         jnp.zeros((a.shape[1 - ca], b.shape[1 - cb]), jnp.float32))
+    return out if reduce is None else reduce(out)
 
 
 #: Width of a block column of a long Gram's upper block triangle
@@ -174,7 +178,7 @@ def gram_rider_room(k: int) -> int:
 
 
 def gram_in_panels(a, ca: int, config: Optional[MatrelConfig] = None,
-                   rhs=None):
+                   rhs=None, reduce=None):
     """The float32 Gram of ``a`` contracted with itself over its
     dimension ``ca`` (``t(a) * a`` for 0, ``a * t(a)`` for 1), in the
     panels of :func:`dot_in_panels`, each panel multiplying the upper
@@ -206,7 +210,15 @@ def gram_in_panels(a, ca: int, config: Optional[MatrelConfig] = None,
     ``dynamic-slice`` of 43,294 cycles), 579,630; and
     :func:`dot_in_panels`' multiply-reduce as a loop-mate of the dots,
     683,651: what it costs in a loop of its own, since one fusion runs
-    at a time."""
+    at a time.
+
+    ``reduce`` is :func:`over_own_rows`': where ``a`` (and ``rhs``) are
+    one device's rows of a table that lies by rows on a mesh, it sums
+    the block columns' accumulators over the devices, riders and all
+    (:func:`gram_reduce_bytes`: 2.5 MB at k = 1000, where the mirrored
+    Gram would be 4), BEFORE the mirror: an entry below the diagonal
+    stays a copy, so the mesh's Gram is symmetric bit for bit too,
+    whatever order the all-reduce adds its four terms in."""
     free = 1 - ca
     k = a.shape[free]
     blocks = gram_blocks(k)
@@ -237,11 +249,50 @@ def gram_in_panels(a, ca: int, config: Optional[MatrelConfig] = None,
         a.shape[ca], panel,
         tuple(jnp.zeros((e, e - s + (m if e == k else 0)), jnp.float32)
               for s, e in blocks)))
+    if reduce is not None:
+        cols = list(reduce(tuple(cols)))
     rode, cols[-1] = cols[-1][:, :m], cols[-1][:, m:]
     upper = jnp.concatenate(
         [jnp.pad(c, ((0, k - c.shape[0]), (0, 0))) for c in cols], axis=1)
     gram = jnp.where(jnp.tri(k, dtype=bool), upper.T, upper)
     return gram if rhs is None else (gram, rode)
+
+
+def gram_reduce_bytes(k: int, m: int = 0) -> int:
+    """Bytes of the block-column accumulators of :func:`gram_in_panels`
+    over ``k`` columns with ``m`` riders: what its ``reduce`` moves a
+    device (2,504,864 at k = 1000 with one rider)."""
+    return 4 * sum(e * (e - s + (m if e == k else 0))
+                   for s, e in gram_blocks(k))
+
+
+def over_own_rows(mesh: Mesh, body, operands, contracted):
+    """``body(reduce, *operands)`` where every operand lies cut over
+    ALL the mesh's devices along the dimension it is contracted over
+    (``contracted``: 0 for a table that lies by rows, 1 for one that
+    lies by columns): inside ONE ``shard_map`` each device runs
+    ``body`` on the share it holds, read in place (the in_specs are the
+    operands' own layout: nothing is gathered, nothing transposed), and
+    ``reduce`` is one ``psum`` over both mesh axes of whatever ``body``
+    hands it; what ``body`` returns comes out replicated. Upstream's
+    cross-product multiply over a ``RowPartitioner``'s partitions
+    (SURVEY.md section 2), with an all-reduce where Spark reduces by
+    key. On one device there is nothing to reduce and no ``shard_map``:
+    ``body(None, *operands)`` is the whole program, the same code."""
+    if mesh.size == 1:
+        return body(None, *operands)
+    axes = tuple(mesh.axis_names)
+
+    def reduce(partial):
+        return jax.lax.psum(partial, axes)
+
+    # check_vma=False: the loop's accumulators start as zeros that vary
+    # over no axis and are added to products that vary over both
+    return shard_map(
+        functools.partial(body, reduce), mesh=mesh,
+        in_specs=tuple(P(axes, None) if c == 0 else P(None, axes)
+                       for c in contracted),
+        out_specs=P(), check_vma=False)(*operands)
 
 
 def matmul_xla(a: jax.Array, b: jax.Array, mesh: Mesh,
@@ -291,7 +342,12 @@ def matmul_cpmm(a: jax.Array, b: jax.Array, mesh: Mesh,
     Each device holds A[n/gx, k/gy] and B[k/gy, m]; the local outer-product
     partial C[n/gx, m] is summed-and-scattered over y with `psum_scatter` —
     the direct analogue of the reference's reduceByKey over partial C blocks
-    (SURVEY.md §2 CPMM)."""
+    (SURVEY.md §2 CPMM). Both operands are re-laid to these specs as
+    arrays, a transposed one first transposed: a tall table whose Gram is
+    wanted cannot take this path at the size of a chip's memory (its
+    `t(X)` is a second table, X over `y` alone twice a device's share),
+    and one that lies by rows over all devices does not: it is multiplied
+    where it lies (:func:`over_own_rows`, the stamp ``cpmm_rows``)."""
     x, y = mesh.axis_names
     prec = _precision(config)
     out_dtype = _acc_dtype(a, b)

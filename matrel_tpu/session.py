@@ -507,7 +507,12 @@ class MatrelSession:
         outside a profiler session, where the spans are dark (the
         ``workloads.pagerank.last_plan`` idiom): ``hit`` (the plan cache
         or a plan template answered the lookup), ``executors``,
-        ``hbm_plan_bytes``, and of its coo_leaf products ``spmm`` (one
+        ``hbm_plan_bytes``, ``products`` (the memory reckoning's record
+        of each product, solve and materialised transpose:
+        planner.hbm_report, with ``gram_tiles`` / ``gram_rides`` and a
+        mesh's ``operand_layout`` / ``devices`` / ``rows_a_device`` /
+        ``reduce_bytes`` where they apply), and of its coo_leaf products
+        ``spmm`` (one
         record each that the SpMV tables answer: what its
         ``matrel.spmm.plan`` span carries) and ``densified_products``
         (one each whose leaf was densified). Copies; {} before the first
@@ -519,6 +524,7 @@ class MatrelSession:
         return {"hit": self._last_hit,
                 "executors": list(meta.get("executors") or ()),
                 "hbm_plan_bytes": meta.get("hbm_plan_bytes"),
+                "products": [dict(r) for r in meta.get("products", ())],
                 "spmm": [dict(r) for r in meta.get("spmm", ())],
                 "densified_products": [
                     dict(r) for r in meta.get("densified_products", ())]}

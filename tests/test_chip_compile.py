@@ -676,3 +676,85 @@ def test_linreg_rhs_rides_the_gram(linreg_program):
     assert "multiply_reduce" not in text
     staged = [dims for _, dims in _arrays_written(text, rows)]
     assert staged and all(sorted(dims) == [1, rows] for dims in staged)
+
+
+# -- the WHOLE regression, by rows on the 2x2 mesh (cell linreg_10m_2x2) ------
+
+WHOLE_N = 4 * LINREG_N
+
+
+@pytest.fixture(scope="module")
+def linreg_whole_program(mesh_2x2):
+    """``inv(t(X) * X) * t(X) * y`` over all 10,223,616 rows, X and y cut
+    by rows over the four devices (``P(('x', 'y'), None)``: 2,555,904
+    whole rows a chip), as the session plans and lowers it, compiled for
+    the described v5e 2x2."""
+    from matrel_tpu.core.blockmatrix import BlockMatrix
+    from matrel_tpu.session import MatrelSession
+    by_rows = P(("x", "y"), None)
+    rows = NamedSharding(mesh_2x2, by_rows)
+    sess = MatrelSession(mesh=mesh_2x2)
+    for name, shape in (("X", (WHOLE_N, LINREG_K)), ("y", (WHOLE_N, 1))):
+        sess.register(name, BlockMatrix.from_array(
+            _sds(rows, shape, jnp.float32), shape, mesh_2x2, by_rows))
+    plan = sess.compile(sess.sql("inv(t(X) * X) * t(X) * y"))
+    compiled = plan.jitted.lower(*[
+        _sds(rows, leaf.attrs["matrix"].shape, jnp.float32)
+        for leaf in plan.leaf_order]).compile()
+    return plan, compiled
+
+
+def test_whole_linreg_fits_a_chip_beside_its_quarter(linreg_whole_program):
+    """Every chip holds its 10.2 GB of rows and megabytes beside them:
+    the program's temporaries stay inside what the plan reckons for the
+    Gram's accumulators, the all-reduce's result, Xᵀy and the solve's
+    copies, and arguments, output and temporaries fit the budget, at
+    what ``linreg_10m_1c`` plans for one chip (60.6% of ``bytes_limit``)
+    and a few megabytes."""
+    plan, compiled = linreg_whole_program
+    mem = compiled.memory_analysis()
+    n, k = LINREG_N, LINREG_K
+    residents = n * k * 4 + n * 4            # a device's rows of X and y
+    assert mem.argument_size_in_bytes == residents
+    reckoned = plan.meta["hbm_plan_bytes"] - residents
+    assert 0 < reckoned <= 16 * k * k        # megabytes, not a table
+    assert mem.temp_size_in_bytes <= reckoned
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes <= MatrelConfig().hbm_budget_bytes
+
+
+def test_whole_linreg_reduces_once_and_moves_no_table(linreg_whole_program):
+    """ONE all-reduce (the block columns' accumulators, Xᵀy's column
+    riding the last) and no other collective: no all-gather of X, no
+    collective-permute, nothing scattered; no array with a device's
+    2,555,904 or the table's 10,223,616 rows is written beside y's own
+    column (no transposed or re-laid X); one loop over the panels, the
+    block triangle's four convolutions in it; and the solve has no
+    collective inside it (it would be a second one)."""
+    plan, compiled = linreg_whole_program
+    text = compiled.as_text()
+    assert [p["chosen"] for p in plan.meta["products"]] == [
+        "cpmm_rows", "cpmm_rows", "solve"]
+    gram = plan.meta["products"][0]
+    assert (gram["operand_layout"], gram["devices"], gram["rows_a_device"],
+            gram["gram_tiles"], gram["gram_rides"], gram["reduce_bytes"]) \
+        == ("row", 4, LINREG_N, [10, 16], 1,
+            strategies.gram_reduce_bytes(LINREG_K, 1))
+    counts = {op: len(re.findall(rf"\s{op}(?:-start)?\(", text))
+              for op in ("all-reduce", "all-gather", "collective-permute",
+                         "reduce-scatter", "all-to-all")}
+    assert counts == {"all-reduce": 1, "all-gather": 0,
+                      "collective-permute": 0, "reduce-scatter": 0,
+                      "all-to-all": 0}, counts
+    for rows in (LINREG_N, WHOLE_N):
+        written = _arrays_written(text, rows)
+        assert all(int(np.prod(dims)) == rows for _, dims in written), \
+            written
+    loops = [ln for ln in text.splitlines()
+             if " while(" in ln and f"f32[{LINREG_N},{LINREG_K}]" in ln]
+    assert len(loops) == 1, loops
+    bodies, lines = _loop_bodies(text)
+    convs = [[line for name in reached for line in lines[name]
+              if " convolution(" in line] for reached in bodies.values()]
+    assert sorted(len(c) for c in convs if c) \
+        == [len(strategies.gram_blocks(LINREG_K))]

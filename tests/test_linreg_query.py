@@ -511,3 +511,250 @@ def test_row_quarters_add_up_to_the_whole_table(one_device, data):
     assert rel_err(np.linalg.solve(parts_g, parts_r), want) < 1e-5
     assert rel_err(whole.compute(whole.sql(SPELLINGS[0])).to_numpy(),
                    want) < 2e-5
+
+
+# -- the whole table, by rows on a mesh (PR 39; cell linreg_10m_2x2) ------------
+
+#: 4 panels of ACC_PANEL_ROWS and a ragged tail of 520 rows a device; the
+#: whole contraction is long
+ROWS_A_DEVICE = 4 * strategies.ACC_PANEL_ROWS + 520
+MESH_N, MESH_K = 4 * ROWS_A_DEVICE, strategies.GRAM_BLOCK + 4
+
+
+def whole_config():
+    """The benchmark configuration's own module: its generator and its
+    plain reference (nothing of the program)."""
+    import os
+    from benchmarks import run as harness
+    return harness.load_module(os.path.join(
+        os.path.dirname(harness.__file__), "configs",
+        "matrel_linreg_10m_whole.py"))
+
+
+@pytest.fixture(scope="module")
+def by_rows(mesh_square):
+    """(session, X, y): the configuration's seeded tables cut by rows
+    over the 2x2 mesh's four devices, registered as they lie."""
+    from benchmarks.reference import device_key
+    x, y = whole_config().generate(mesh_square, MESH_N, MESH_K,
+                                   ROWS_A_DEVICE, 0.1, device_key(39))
+    return mesh_session(mesh_square, x, y), x, y
+
+
+def lie(mesh, shape, canonical=False):
+    """The spec of a table cut by rows over all devices, or the
+    canonical one of its shape."""
+    from matrel_tpu.core import padding
+    return (padding.canonical_spec(shape, mesh) if canonical
+            else P(tuple(mesh.axis_names), None))
+
+
+def mesh_session(mesh, x, y, canonical=False, **config):
+    sess = MatrelSession(mesh=mesh, config=MatrelConfig(**config))
+    for name, arr in (("X", x), ("y", y)):
+        spec = lie(mesh, tuple(arr.shape), canonical)
+        sess.register(name, BlockMatrix.from_array(
+            jax.device_put(arr, jax.sharding.NamedSharding(mesh, spec)),
+            tuple(arr.shape), mesh, spec))
+    return sess
+
+
+def collective_ops(plan):
+    """{collective: how many such OPERATIONS the compiled program holds}
+    (``plan.collectives()`` counts every mention of the name)."""
+    import re
+    text = plan.hlo()
+    found = {op: len(re.findall(rf"\s{op}(?:-start)?\(", text))
+             for op in ("all-reduce", "all-gather", "reduce-scatter",
+                        "collective-permute", "all-to-all")}
+    return {op: n for op, n in found.items() if n}
+
+
+def test_theta_on_the_mesh_is_the_configurations_reference(by_rows):
+    """``session.sql`` + ``compute`` + ``to_numpy`` on the 2x2 mesh over
+    the row-partitioned table against the configuration's plain
+    reference (panel sums where the rows lie, float64 on the host), at
+    the cell's limit; every device holds whole rows, a ragged last
+    panel among them."""
+    sess, x, y = by_rows
+    assert {s.data.shape for s in x.addressable_shards} \
+        == {(ROWS_A_DEVICE, MESH_K)}
+    assert ROWS_A_DEVICE % strategies.ACC_PANEL_ROWS
+    want = whole_config().PanelSums(
+        MESH_K, strategies.ACC_PANEL_ROWS).solve(x, y)
+    got = sess.compute(sess.sql(SPELLINGS[0])).to_numpy()
+    assert got.shape == (MESH_K, 1) and rel_err(got, want) < 5e-6
+    assert sess.last_plan()["executors"] == [planner.OWN_ROWS]
+
+
+def test_the_mesh_gram_is_the_sum_of_its_devices_quarters(by_rows):
+    """``test_row_quarters_add_up_to_the_whole_table`` ties a chip's
+    share to the whole on one device; this ties the mesh to the shares:
+    the mesh's Gram and ``t(X) * y`` are the sums of what
+    ``gram_in_panels`` gives for each device's own rows, and the Gram
+    is symmetric to the last bit (the all-reduce adds block columns,
+    the mirror copies them after it)."""
+    sess, x, y = by_rows
+    gram = sess.compute(sess.sql("t(X) * X")).to_numpy()
+    rhs = sess.compute(sess.sql("t(X) * y")).to_numpy()
+    parts_g = np.zeros((MESH_K, MESH_K), np.float64)
+    parts_r = np.zeros((MESH_K, 1), np.float64)
+    for xs, ys in zip(x.addressable_shards, y.addressable_shards):
+        g, r = jax.jit(lambda a, b: strategies.gram_in_panels(
+            a, 0, MatrelConfig(), rhs=b))(xs.data, ys.data)
+        parts_g += np.asarray(g, np.float64)
+        parts_r += np.asarray(r, np.float64)
+    assert rel_err(gram, parts_g) < 1e-6 and rel_err(rhs, parts_r) < 1e-6
+    assert np.array_equal(gram, gram.T)
+
+
+def test_the_mesh_program_reduces_once_and_moves_no_table(by_rows):
+    """The regression's program on the mesh: ONE all-reduce (the block
+    columns' accumulators, Xᵀy's column with the last) and no other
+    collective, no all-gather of X, no transposed or gathered array of
+    X's shape, one loop over the panels with the triangle's dots and the
+    tail's, and the solve inside a shard_map of its own."""
+    sess, x, _ = by_rows
+    plan = sess.compile(sess.sql(SPELLINGS[0]))
+    assert collective_ops(plan) == {"all-reduce": 1}
+    text = lowered_text(plan)
+    # asked for a block column at a time, combined into the one above
+    assert text.count("stablehlo.all_reduce") == len(
+        strategies.gram_blocks(MESH_K))
+    assert "all_gather" not in text and "collective_permute" not in text
+    for rows in (MESH_N, ROWS_A_DEVICE):
+        assert f"tensor<{MESH_K}x{rows}xf32>" not in text
+    # the panels' loop (the solve's pivots loop beside it): the block
+    # columns' dots in its body and in the ragged tail, and no other
+    assert "stablehlo.while" in text
+    assert text.count("dot_general") == 2 * len(
+        strategies.gram_blocks(MESH_K))
+    assert text.count("sdy.manual_computation") \
+        + text.count("shard_map") >= 2
+
+
+def test_the_stamps_agree_on_span_meta_and_last_plan(by_rows, tmp_path):
+    """``operand_layout``, ``devices``, ``rows_a_device`` and
+    ``reduce_bytes`` beside ``gram_tiles`` and ``gram_rides``: the same
+    on the ``matrel.plan.strategy`` spans, in ``plan.meta["products"]``
+    and from ``last_plan()``; ``chosen`` names the lowering, the Gram's
+    all-reduce carries the rider's column and the rider moves nothing
+    of its own; ``matrel.dispatch`` says the mesh."""
+    from matrel_tpu.obs.trace import profile_spans
+    _, x, y = by_rows
+    sess = mesh_session(x.sharding.mesh, x, y)
+    before = len(profile_spans())
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        sess.compute(sess.sql(SPELLINGS[0])).to_numpy()
+    finally:
+        jax.profiler.stop_trace()
+    mine = profile_spans()[before:]
+    spans = [r["attrs"] for r in mine if r["name"] == "matrel.plan.strategy"]
+    meta = sess.compile(sess.sql(SPELLINGS[0])).meta
+    k = MESH_K
+    for said in (spans, meta["products"], sess.last_plan()["products"]):
+        assert [(p["node"], p["chosen"], p.get("operand_layout"),
+                 p.get("devices"), p.get("rows_a_device"),
+                 p.get("reduce_bytes"), p.get("gram_tiles"),
+                 p.get("gram_rides"), p.get("rides_gram"))
+                for p in said] == [
+            ("matmul", "cpmm_rows", "row", 4, ROWS_A_DEVICE,
+             strategies.gram_reduce_bytes(k, 1), [3, 4], 1, None),
+            ("matmul", "cpmm_rows", "row", 4, ROWS_A_DEVICE, 0, None, None,
+             True),
+            ("solve", "solve", None, None, None, None, None, None, None)]
+    assert strategies.gram_reduce_bytes(k, 1) == 4 * (
+        256 * 256 + k * (k - 256 + 1))
+    assert strategies.gram_reduce_bytes(1000, 1) == 2_504_864
+    (dispatch,) = [r["attrs"] for r in mine if r["name"] == "matrel.dispatch"]
+    assert dispatch["mesh"] == "2x2" \
+        and dispatch["hbm_plan_bytes"] == meta["hbm_plan_bytes"]
+    # a device's rows of X and y, and megabytes beside them
+    shard = ROWS_A_DEVICE * (k + 1) * 4
+    assert shard < meta["hbm_plan_bytes"] < shard + 16 * k * k * 4
+
+
+def test_a_lone_long_product_by_rows_is_panelled_too(by_rows):
+    """``t(X) * y`` with no Gram beside it: ``dot_in_panels`` over every
+    device's own rows under the same ``shard_map``, one all-reduce."""
+    sess, x, y = by_rows
+    plan = sess.compile(sess.sql("t(X) * y"))
+    (record,) = plan.meta["products"]
+    assert record["chosen"] == "cpmm_rows" and "gram_tiles" not in record
+    assert record["reduce_bytes"] == 4 * MESH_K
+    assert collective_ops(plan) == {"all-reduce": 1}
+    assert "stablehlo.while" in lowered_text(plan)
+    got = sess.compute(sess.sql("t(X) * y")).to_numpy()
+    want = np.asarray(x, np.float64).T @ np.asarray(y, np.float64)
+    assert rel_err(got, want) < 1e-6
+
+
+@pytest.mark.parametrize("case", ["bfloat16", "short", "high", "canonical",
+                                  "forced"])
+def test_only_a_long_float32_gram_by_rows_takes_the_lowering(mesh_square,
+                                                             case):
+    """On a mesh only a long float32 contraction whose operands lie by
+    rows over all devices is multiplied where it lies: a bfloat16
+    table, a short contraction, ``matmul_precision`` "high" (ops/gram.py's
+    split over a ranked strategy), a canonical ``P(x, y)`` table (not
+    re-laid behind its owner's back) and a forced strategy plan as they
+    did: a ranked strategy, no ``gram_tiles``, no loop over panels."""
+    n = MESH_N - (80 * 1024 if case == "short" else 0)
+    dtype = jnp.bfloat16 if case == "bfloat16" else jnp.float32
+    config = {"high": {"matmul_precision": "high"},
+              "forced": {"strategy_override": "cpmm"}}.get(case, {})
+    x = jnp.zeros((n, 8), dtype)
+    sess = mesh_session(mesh_square, x, x[:, :1],
+                        canonical=case == "canonical", **config)
+    plan = sess.compile(sess.sql("t(X) * X"))
+    records = [p for p in plan.meta["products"] if p["node"] == "matmul"]
+    assert records and all(
+        p["chosen"] in strategies.STRATEGIES and "gram_tiles" not in p
+        and "operand_layout" not in p for p in records), records
+    assert planner.OWN_ROWS not in plan.meta["executors"]
+    assert "stablehlo.while" not in lowered_text(plan)
+    # and the long float32 Gram by rows beside it does
+    if case == "short":
+        x = jnp.zeros((MESH_N, 8), jnp.float32)
+        taken = mesh_session(mesh_square, x, x[:, :1])
+        (record,) = taken.compile(taken.sql("t(X) * X")).meta["products"]
+        assert record["chosen"] == planner.OWN_ROWS \
+            and record["gram_tiles"] == [1, 1]
+
+
+def _described_whole(mesh, canonical):
+    """A session over the whole table's SHAPES (no array) on ``mesh``."""
+    sess = MatrelSession(mesh=mesh)
+    for name, shape in (("X", (10_223_616, 1000)), ("y", (10_223_616, 1))):
+        spec = lie(mesh, shape, canonical)
+        sess.register(name, BlockMatrix.from_array(
+            jax.ShapeDtypeStruct(shape, jnp.float32,
+                                 sharding=jax.sharding.NamedSharding(
+                                     mesh, spec)), shape, mesh, spec))
+    return sess
+
+
+def test_the_gate_refuses_the_cpmm_plan_of_the_whole_table_by_name(
+        mesh_square):
+    """From shapes alone, before anything is traced: a canonical
+    ``P(x, y)`` whole table plans as it did (``cpmm`` over a transposed
+    copy), which no chip holds: a PlanMemoryError that names the
+    transpose, the product and its strategy, and says how the table has
+    to lie. The same shapes by rows plan at a quarter and megabytes."""
+    sess = _described_whole(mesh_square, canonical=True)
+    with pytest.raises(planner.PlanMemoryError) as refused:
+        sess.compile(sess.sql(SPELLINGS[0]))
+    said = str(refused.value)
+    assert "transpose 1000x10223616" in said
+    assert "matmul 1000x1000 under cpmm" in said and "2x2 mesh" in said
+    assert "PartitionSpec(('x', 'y'), None)" in said
+    rows = _described_whole(mesh_square, canonical=False)
+    meta = rows.compile(rows.sql(SPELLINGS[0])).meta
+    assert meta["executors"] == [planner.OWN_ROWS]
+    quarter = 2_555_904 * 1001 * 4
+    assert quarter < meta["hbm_plan_bytes"] < quarter + 16_000_000
+    assert [p.get("rows_a_device") for p in meta["products"]] \
+        == [2_555_904, 2_555_904, None]
