@@ -32,15 +32,22 @@ Pipeline per matvec (``spmv_compact``):
      tile, zeroed on the block's first chunk — a hub block is many
      steps, not one tall tile, and nothing is left to an overflow COO.
   2b. Pallas, where the plan has hub chunks (``plan.hubs``, PR 36): the
-     slots whose source is a hub skip step 1. ``x[hubs.ids]`` (32,768
-     values at the most, one small gather) lies in VMEM as a ``(M, 128)``
-     table, a
-     slot names its hub by rank, and a second chunk-grid kernel makes
-     ``w`` for a vector register of 1,024 slots with M lane permutes of
-     a table row (``take_along_axis``: ``vperm`` on the XLU), each kept
-     where the rank's row is that one, times ``val`` — then the same
+     slots whose source is a hub skip step 1. ``x[hubs.ids]`` (one small
+     scalar gather) lies in VMEM as a ``(M, 128)`` table, a slot names
+     its hub by rank, and a second chunk-grid kernel makes ``w`` for a
+     vector register of 1,024 slots by lane permutes of table rows
+     (``take_along_axis``: ``vperm`` on the XLU), each kept where the
+     rank's row is that one, times ``val`` — then the same
      ``_scatter_tile``, added onto step 2's block sums, which it takes
-     as its accumulator (aliased). Its ``w`` never exists in HBM.
+     as its accumulator (aliased). Its ``w`` never exists in HBM. The
+     rows permuted are the register's own (PR 42): a block's hub slots
+     lie by table row, the build records the run of rows each register
+     names (``spmv.hub_walks``), and the kernel walks that run in steps
+     of ``spmv.HUB_WALK`` = 64 rows from aligned (8, 128) loads, its
+     bounds scalar-prefetched beside ``chunk_block``. The XLU takes a
+     permute every ~2.5 cycles whatever is done about it, so the rows
+     walked are the kernel's cost: all M for every register until PR 42
+     (which held the table at 256 rows), about M a BLOCK since.
   3. XLA: overflow-COO accumulation (blocks layout only; unchanged
      contract).
 
@@ -129,29 +136,78 @@ def _make_chunk_scatter_kernel(hi_n: int, lo: int, passes: int):
     return kernel
 
 
-def _hub_weights(idx, table_ref, m_rows: int):
+def _hub_weights(idx, table_ref, walk, step: int):
     """``table[idx // 128, idx % 128]`` for a tile of slots, a vector
     register of 8 x 128 at a time: a table row, broadcast over the
     sublanes, permuted along the lanes by ``idx % 128`` and kept where
-    ``idx // 128`` names that row. The permute moves 32-bit lanes and
-    the select picks whole values, so a slot's weight is the table's
-    entry bit for bit; a padded slot (``idx`` = 128·M) matches no row
-    and weighs 0."""
+    ``idx // 128`` names that row — for the rows the register's walk
+    names and no other (``walk(r)``: the r-th register's first row, a
+    multiple of 8, and its steps of ``step`` = ``spmv.HUB_WALK`` rows,
+    one at the least, as ``spmv.hub_walks`` reckoned them from these
+    very slots). A step is straight-line code over aligned (8, 128)
+    loads, so that its permutes are in flight together: the XLU answers
+    one ~90 cycles after it was asked, and a loop trip waits for its
+    last (80 ns a trip and 2.1 ns a row on a v5e, PERF.md section 6,
+    PR 42). The first step, which every register has, stands outside the
+    loop, where the scheduler lays it under the scatter's own work. The
+    permute moves 32-bit lanes and the select picks whole values, so a
+    slot's weight is the table's entry bit for bit; a padded slot
+    (``idx`` = 128·M) matches no row and weighs 0."""
+    tile = spmv_lib.HUB_TILE
     parts = []
-    for s in range(0, idx.shape[0], 8):
+    for r, s in enumerate(range(0, idx.shape[0], 8)):
         lane, row = idx[s:s + 8] & (LANE - 1), idx[s:s + 8] >> 7
-        w = jnp.zeros(lane.shape, jnp.float32)
-        for m in range(m_rows):
-            took = jnp.take_along_axis(
-                jnp.broadcast_to(table_ref[m:m + 1, :], lane.shape), lane,
-                axis=1)
-            w = jnp.where(row == m, took, w)
-        parts.append(w)
+        first, steps = walk(r)
+
+        def walk_step(t, w, lane=lane, row=row, first=first):
+            at = pl.multiple_of(first + step * t, tile)
+            here = row - at
+            for g in range(0, step, tile):
+                rows = table_ref[pl.ds(pl.multiple_of(at + g, tile), tile), :]
+                for j in range(tile):
+                    took = jnp.take_along_axis(
+                        jnp.broadcast_to(rows[j:j + 1, :], lane.shape), lane,
+                        axis=1, mode="promise_in_bounds")
+                    w = jnp.where(here == g + j, took, w)
+            return w
+
+        parts.append(jax.lax.fori_loop(
+            1, steps, walk_step,
+            walk_step(0, jnp.zeros(lane.shape, jnp.float32))))
     return jnp.concatenate(parts, axis=0)
 
 
-def _make_hub_scatter_kernel(hi_n: int, lo: int, passes: int, m_rows: int):
-    def kernel(cb_ref, idx_ref, off_ref, val_ref, table_ref, acc_ref, y_ref):
+# A hub chunk's walks ride as ONE int32 a chunk (scalar prefetch lives in
+# SMEM: with ``chunk_block`` 8 B a hub chunk, ``spmv._HUB_CHUNKS_MAX``): a
+# register has a field of 32 / registers bits, its steps in the low
+# ``_WALK_STEP_BITS`` and its first row, in tiles of ``HUB_TILE``, above
+# them: room for the 4,096 rows ``spmv._HUB_ROWS_MAX`` may reach at the most.
+_WALK_STEP_BITS = 7
+
+
+def _pack_walks(first: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """(chunks,) int32 of ``HubChunks.first`` / ``.rows``; a table too
+    tall for the fields, or rows that are no whole steps of the
+    ``HUB_WALK`` the kernel will be traced with, are refused."""
+    regs = first.shape[1]
+    bits = 32 // regs
+    steps, tiles = rows // spmv_lib.HUB_WALK, first // spmv_lib.HUB_TILE
+    if first.size and ((rows % spmv_lib.HUB_WALK).any()
+                       or steps.max() >> _WALK_STEP_BITS
+                       or tiles.max() >> (bits - _WALK_STEP_BITS)):
+        raise ValueError("hub walks do not fit a packed word: "
+                         f"{int(rows.max())} rows from {int(first.max())}")
+    word = np.zeros(first.shape[0], np.uint32)
+    for r in range(regs):
+        word |= (tiles[:, r] << _WALK_STEP_BITS
+                 | steps[:, r]).astype(np.uint32) << np.uint32(bits * r)
+    return word.view(np.int32)
+
+
+def _make_hub_scatter_kernel(hi_n: int, lo: int, passes: int, regs: int,
+                             step: int):
+    def kernel(cb_ref, walk_ref, idx_ref, off_ref, val_ref, table_ref,
+               acc_ref, y_ref):
         # as the chunk scatter, but the block's tile starts from the
         # main scatter's sums (``acc``, which the output aliases: a
         # block with no hub chunk keeps them untouched)
@@ -159,7 +215,14 @@ def _make_hub_scatter_kernel(hi_n: int, lo: int, passes: int, m_rows: int):
         def _():
             y_ref[...] = acc_ref[...]
 
-        w = _hub_weights(idx_ref[0], table_ref, m_rows) * val_ref[0]
+        def walk(r):
+            bits = 32 // regs
+            field = (walk_ref[pl.program_id(0)] >> (bits * r)) & (
+                (1 << bits) - 1)
+            return ((field >> _WALK_STEP_BITS) * spmv_lib.HUB_TILE,
+                    field & ((1 << _WALK_STEP_BITS) - 1))
+
+        w = _hub_weights(idx_ref[0], table_ref, walk, step) * val_ref[0]
         y_ref[0] += _scatter_tile(off_ref[0], w, hi_n, lo, passes)
 
     return kernel
@@ -217,27 +280,29 @@ def _chunk_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
 
 @functools.lru_cache(maxsize=32)
 def _hub_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
-                passes: int, m_rows: int, interpret: bool):
-    """scatter(chunk_block, idx, off, val, table, acc) -> acc + the hub
-    chunks' block sums, (nb, HI', LO): the chunk scatter whose slot
-    weights come from the ``(m_rows, 128)`` hub table in VMEM."""
+                passes: int, m_rows: int, step: int, interpret: bool):
+    """scatter(chunk_block, walks, idx, off, val, table, acc) -> acc + the
+    hub chunks' block sums, (nb, HI', LO): the chunk scatter whose slot
+    weights come from the ``(m_rows, 128)`` hub table in VMEM, a
+    register's from the rows ``walks`` names for it (:func:`_pack_walks`),
+    ``step`` rows a loop trip."""
     hi_n = block // lo
     cr = chunk // LANE
-    slots = pl.BlockSpec((1, cr, LANE), lambda c, cb: (c, 0, 0))
-    sums = pl.BlockSpec((1, hi_n, lo), lambda c, cb: (cb[c], 0, 0))
+    slots = pl.BlockSpec((1, cr, LANE), lambda c, cb, wk: (c, 0, 0))
+    sums = pl.BlockSpec((1, hi_n, lo), lambda c, cb, wk: (cb[c], 0, 0))
     return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
-        _make_hub_scatter_kernel(hi_n, lo, passes, m_rows),
+        _make_hub_scatter_kernel(hi_n, lo, passes, cr // 8, step),
         name="matrel_spmv_scatter_hubs",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,                       # chunk_block
+            num_scalar_prefetch=2,                   # chunk_block, walks
             grid=(n_chunks,),
             in_specs=[slots, slots, slots,
-                      pl.BlockSpec((m_rows, LANE), lambda c, cb: (0, 0)),
+                      pl.BlockSpec((m_rows, LANE), lambda c, cb, wk: (0, 0)),
                       sums],
             out_specs=sums,
         ),
         out_shape=jax.ShapeDtypeStruct((nb, hi_n, lo), jnp.float32),
-        input_output_aliases={5: 0},                     # acc
+        input_output_aliases={6: 0},                     # acc
         compiler_params=compat.tpu_compiler_params(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
@@ -251,7 +316,8 @@ def compact_tables(plan: spmv_lib.EdgeSpMVPlan):
     chunk → block table as a fifth where the plan is laid out in
     chunks — ``compact_apply`` knows the layout by it — and after it
     the plan's hub chunks, where it has any: (ids, idx, off, val,
-    chunk_block)."""
+    chunk_block, walks), the walks packed a chunk
+    (:func:`_pack_walks`)."""
     dev = getattr(plan, "_compact_dev", None)
     if dev is None:
         nb, cap = np.asarray(plan.src8).shape
@@ -277,7 +343,8 @@ def compact_tables(plan: spmv_lib.EdgeSpMVPlan):
                 dev += (jnp.asarray(hub.ids),) + tuple(
                     jnp.asarray(np.asarray(a).reshape(-1, cr, LANE))
                     for a in (hub.idx, hub.off, hub.val)) + (
-                    jnp.asarray(hub.chunk_block, jnp.int32),)
+                    jnp.asarray(hub.chunk_block, jnp.int32),
+                    jnp.asarray(_pack_walks(hub.first, hub.rows)))
         plan._compact_dev = dev
     return dev
 
@@ -376,7 +443,7 @@ def compact_apply(plan_static, tables, ov, x: jax.Array,
                   passes: int = 3, interpret: bool = False) -> jax.Array:
     """Traceable body: y = A·x from compact tables. ``plan_static`` is
     (n_rows, n_cols, block, lo); ``tables`` from compact_tables() (five
-    of them: the chunks layout; ten: with hub chunks); ``ov`` the
+    of them: the chunks layout; eleven: with hub chunks); ``ov`` the
     overflow COO tuple (possibly empty)."""
     n_rows, n_cols, block, lo = plan_static
     src8, lane, off, val, *chunks = tables
@@ -388,12 +455,15 @@ def compact_apply(plan_static, tables, ov, x: jax.Array,
         y = _chunk_runner(rows, cr * LANE, nb, block, lo, passes,
                           interpret)(chunk_block, off, w)
         if hub:
-            ids, idx, hub_off, hub_val, hub_block = hub
+            ids, idx, hub_off, hub_val, hub_block, walks = hub
             table = x.astype(jnp.float32).at[ids].get(
                 mode="promise_in_bounds").reshape(-1, LANE)
+            # up to whole walk steps: rows of zeros, which no slot names
+            table = jnp.pad(table, ((0, spmv_lib.hub_table_rows(
+                table.shape[0]) - table.shape[0]), (0, 0)))
             y = _hub_runner(idx.shape[0], cr * LANE, nb, block, lo, passes,
-                            table.shape[0], interpret)(
-                hub_block, idx, hub_off, hub_val, table, y)
+                            table.shape[0], spmv_lib.HUB_WALK, interpret)(
+                hub_block, walks, idx, hub_off, hub_val, table, y)
         y = y.reshape(-1)[:n_rows]
     else:
         scatter = _compact_runner(rows, cr * LANE, block, lo, passes,
@@ -755,7 +825,7 @@ def _chunk_sets(tables, n_cols: int, wins=None):
     if hub:
         # a hub slot names its source by rank; the padded slots' rank,
         # one past the last hub, reads the zero row
-        ids, idx, hub_off, hub_val, hub_block = hub
+        ids, idx, hub_off, hub_val, hub_block, _ = hub
         ids = jnp.concatenate([ids, jnp.full((1,), n_cols, ids.dtype)])
         sets.append((ids[idx], hub_off, hub_val, hub_block))
     if wins is None:

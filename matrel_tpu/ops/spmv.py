@@ -54,8 +54,13 @@ reference's plan-per-query model. A plan has one of two layouts:
   by rank, and the matvec takes ``x`` for it from a ``(M, 128)`` table in
   VMEM by lane permutes inside the scatter kernel, so these slots never
   reach the row gather above (2.2 ns a slot on a v5e against 0.2 to 0.7).
-  On that Graph500 graph 32,768 of 2.4M sources hold 52% of the edges;
-  ``_hub_rows`` chooses M from the degrees, 0 on a flat graph.
+  A block's hub slots lie by table row (PR 42), so a vector register of
+  1,024 of them names a short run of rows, recorded beside the chunk
+  (``hub_walks``), and the kernel walks those rows and not the table: a
+  row costs a walk step a block and no longer a permute every hub slot,
+  which is what lets the table grow. On that Graph500 graph 32,768 of
+  2.4M sources hold 52% of the edges and 286,720 hold 86%; ``_hub_rows``
+  chooses M from the degrees and the block count, 0 on a flat graph.
 
 Either layout is refused (build returns None) when it pads past
 ``max_padding`` or ``max_slots``, so callers can use the plain path.
@@ -89,21 +94,44 @@ WINDOW = 128     # rows of its block a chunk's window spans (chunk_windows)
 _OVERFLOW_EDGE_SLOTS = 8
 _SMALL_PLAN_SLOTS = 1 << 20
 HUB_ROW = 128    # hubs a row of the hub table: the lanes of a vector register
-# The hub kernel walks the whole table for every register of slots: a row
-# costs each hub slot a permute and a select (4.5 bundles a 2,048 slots in
-# the static schedule, 3.8 ns a grid step on a v5e) and saves the row
-# gather's 2.2 ns for each of its own edges, so a row pays while its
-# edges, ``_HUB_ROW_PAYS`` times, outnumber the hub slots there would be
-# (PERF.md §6, PR 36: 1,180 by the chip's step times). ``_HUB_ROWS_MAX``
-# is the widest table the cell has been measured with: a Graph500
-# scale-22 round took 237 / 221 / 205 ms at 64 / 128 / 256 rows; left to
-# itself the rule stops at 277 rows on that graph and runs the same
-# (2.14 s a query both ways). No hubs where they would hold under
+HUB_REG = 1024   # slots a vector register (8 sublanes of 128 lanes): the
+                 # unit whose table rows a hub chunk records (hub_walks)
+HUB_TILE = 8     # table rows an aligned (8, 128) load of the table takes: a
+                 # walk starts on a multiple
+HUB_WALK = 64    # table rows a step of the hub kernel's walk takes (so many
+                 # lane permutes in flight a loop trip: PERF.md section 6,
+                 # PR 42); a walk, and the table as the kernel holds it,
+                 # is a whole number of steps
+# The hub kernel walks, for every register of slots, the table rows the
+# register names (PR 42; until then all of them, which is why the table
+# stopped at 256 rows). A block's hub slots lie by table row, so its
+# registers share the table out among them: a row is walked about once
+# a block (3.1 ns a walked row on a v5e, loop trips of ``HUB_WALK`` rows
+# and all) and costs the matvec its own entries of ``x[ids]`` (XLA's
+# scalar gather, 128 values at 8.6 ns), and it saves the main path's
+# 2.4 ns (row gather, byte product, select) less a hub slot's 0.2 for
+# each of its own edges. So a row pays while its edges outnumber
+# ``_HUB_ROW_EDGES_A_BLOCK`` for every block and ``_HUB_ROW_EDGES``
+# besides: 7,500 edges a row on the Graph500 scale-22 graph's 4,681
+# blocks, where the chip's sweep of forced widths put the break-even at
+# about 7,000 (PERF.md section 6, PR 42: a query took 1.48 / 1.36 / 1.36
+# / 1.41 / 1.45 s at 1,024 / 2,048 / the rule's own 2,240 / 3,072 /
+# 4,096 rows, 2.15 at PR 36's 256). One graph and one block count stand
+# behind the two constants: the split between them is the
+# microbenchmark's, not a sweep's. ``_HUB_ROWS_MAX`` is the widest table
+# that sweep ran (and the tallest whose walks fit their packed word,
+# ``pallas_spmv._pack_walks``). No hubs where they would hold under
 # ``_HUB_MIN_SHARE`` of the edges: a second ragged set and a second
 # kernel for little.
-_HUB_ROW_PAYS = 1200
-_HUB_ROWS_MAX = 256
+_HUB_ROW_EDGES_A_BLOCK = 1.5
+_HUB_ROW_EDGES = 500
+_HUB_ROWS_MAX = 4096
 _HUB_MIN_SHARE = 0.1
+# The hub kernel's two scalar-prefetched words a chunk (its block, its
+# registers' walks) lie in SMEM, 1 MiB on a v5e: 125,000 chunks compiled
+# for the described chip and 131,072 did not (PR 42). The rule stops
+# widening the table before a graph's hub chunks pass this.
+_HUB_CHUNKS_MAX = 120_000
 
 # probed once at import (os.umask is process-global; toggling it per save
 # would race concurrent file creation in other threads)
@@ -227,12 +255,26 @@ class HubChunks:
                    ``ids``; ``128·M`` in padded slots, which no table row
                    answers, so they weigh 0 whatever ``x`` holds
       off, val     (B, CHUNK) — as the plan's own
-      chunk_block  (B,) int32 — ascending."""
+      chunk_block  (B,) int32 — ascending
+      first, rows  (B, CHUNK // HUB_REG) int32 — the table rows
+                   ``first : first + rows`` a register of the chunk's
+                   slots names (:func:`hub_walks`), whole steps of
+                   ``HUB_WALK``: what the hub kernel walks for it.
+    A block's real slots lie by table row (``idx // 128``), in input
+    order inside a row, the padding after them."""
     ids: np.ndarray
     idx: np.ndarray
     off: np.ndarray
     val: np.ndarray
     chunk_block: np.ndarray
+    first: np.ndarray
+    rows: np.ndarray
+
+    @classmethod
+    def of(cls, ids, idx, off, val, chunk_block) -> "HubChunks":
+        """With the walks reckoned from ``idx``."""
+        first, rows = hub_walks(idx, ids.shape[0])
+        return cls(ids, idx, off, val, chunk_block, first, rows)
 
 
 @dataclasses.dataclass
@@ -431,7 +473,7 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
     hub_ids = None
     if layout == "chunks":
         if hubs:
-            hub_ids, hub_rank = _choose_hubs(cols, n_cols)
+            hub_ids, hub_rank = _choose_hubs(cols, n_cols, nb)
         if hub_ids is not None:
             hub_cnt = (native.spmv_counts_hubs(rows, cols, hub_rank, block,
                                                nb) if use_native else None)
@@ -470,9 +512,11 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
     # Native counting-sort fill (O(m), no argsort). The chunks layout
     # without hub chunks lies by row inside a block, slot for slot as
     # the numpy path lays it (the k-wide scatter's windows read that
-    # order: chunk_windows); the other two keep input order inside a
-    # block — the matvec's one-hot contraction is order-agnostic, so
-    # their results match the numpy path
+    # order: chunk_windows), and a block's hub slots by table row, slot
+    # for slot too (the hub kernel's walks read that one: hub_walks);
+    # the blocks layout and the main chunks beside hub chunks keep
+    # input order inside a block — the matvec's one-hot contraction is
+    # order-agnostic, so their results match the numpy path
     filled = hub_filled = None
     if hub_ids is not None:
         both = (native.spmv_fill_ragged_hubs(
@@ -487,10 +531,12 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
             # padded slots)
             of_edge = hub_rank[cols]
             at, rest = np.flatnonzero(of_edge >= 0), np.flatnonzero(of_edge < 0)
+            at = at[np.argsort(rows[at] // block * (hub_ids.size // HUB_ROW)
+                               + of_edge[at] // HUB_ROW, kind="stable")]
             idx, _, hub_off, hub_val = _numpy_fill(
                 rows[at], of_edge[at].astype(np.int64),
                 None if vals is None else vals[at], hub_ids.size, block,
-                hub_first, hub_cnt, width=1)[:4]
+                hub_first, hub_cnt, width=1, in_order=True)[:4]
             hub_filled = idx, hub_off, hub_val
             rows, cols = rows[rest], cols[rest]
             vals = None if vals is None else vals[rest]
@@ -515,13 +561,13 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
     if hub_ids is not None:
         hub_shape = (hub_slots // CHUNK, CHUNK)
         idx, hub_off, hub_val = hub_filled
-        hubs = HubChunks(
-            ids=hub_ids,
-            idx=np.ascontiguousarray(idx, np.int32).reshape(hub_shape),
-            off=np.ascontiguousarray(hub_off, np.int32).reshape(hub_shape),
-            val=np.ascontiguousarray(hub_val, np.float32).reshape(hub_shape),
-            chunk_block=np.repeat(np.arange(nb, dtype=np.int32),
-                                  np.diff(hub_first) // CHUNK))
+        hubs = HubChunks.of(
+            hub_ids,
+            np.ascontiguousarray(idx, np.int32).reshape(hub_shape),
+            np.ascontiguousarray(hub_off, np.int32).reshape(hub_shape),
+            np.ascontiguousarray(hub_val, np.float32).reshape(hub_shape),
+            np.repeat(np.arange(nb, dtype=np.int32),
+                      np.diff(hub_first) // CHUNK))
 
     # compact tables stay host-side numpy; they move to device (default
     # placement or sharded via shard_plan) at expansion time
@@ -576,33 +622,43 @@ def chunk_windows(off: np.ndarray, real: np.ndarray,
     return np.where(high - win < WINDOW, win, -1).astype(np.int32)
 
 
-def _hub_rows(deg_desc: np.ndarray, edges: int) -> int:
+def _hub_rows(deg_desc: np.ndarray, edges: int, blocks: int) -> int:
     """How many rows of ``HUB_ROW`` the hub table gets, from the sources'
-    edge counts in falling order: rows are taken while one still pays
-    (see ``_HUB_ROW_PAYS``), ``_HUB_ROWS_MAX`` at the most, and none
+    edge counts in falling order and the number of destination blocks,
+    each of which walks a row once: rows are taken while one still pays
+    (see ``_HUB_ROW_EDGES_A_BLOCK``) and the hub chunks there would be
+    at the most stay under ``_HUB_CHUNKS_MAX``; the walk step the last
+    of them opens is walked whole whatever it holds, so its other rows
+    cost their entries of ``x[ids]`` alone and are taken while they pay
+    those (``_HUB_ROW_EDGES``); ``_HUB_ROWS_MAX`` at the most, and none
     where all of them would hold under ``_HUB_MIN_SHARE`` of the
     edges."""
     most = min(_HUB_ROWS_MAX, -(-int(np.count_nonzero(deg_desc)) // HUB_ROW))
-    if most == 0:
-        return 0
     a_row = np.zeros(most * HUB_ROW, np.int64)
     top = deg_desc[:a_row.size]
     a_row[:top.size] = top
     a_row = a_row.reshape(most, HUB_ROW).sum(1)
     held = np.cumsum(a_row)
-    pays = a_row * _HUB_ROW_PAYS >= held
-    rows = most if pays.all() else int(np.argmin(pays))
-    return rows if held[rows - 1] >= _HUB_MIN_SHARE * edges else 0
+    # a block's hub slots round up to whole chunks: one more at the most
+    fits = held // CHUNK + blocks <= _HUB_CHUNKS_MAX
+
+    def taken(rows: int, upto: int, price: float) -> int:
+        pays = (a_row[rows:upto] >= price) & fits[rows:upto]
+        return upto if pays.all() else rows + int(np.argmin(pays))
+
+    rows = taken(0, most, _HUB_ROW_EDGES_A_BLOCK * blocks + _HUB_ROW_EDGES)
+    rows = taken(rows, min(hub_table_rows(rows), most), _HUB_ROW_EDGES)
+    return rows if rows and held[rows - 1] >= _HUB_MIN_SHARE * edges else 0
 
 
-def _choose_hubs(cols: np.ndarray, n_cols: int):
+def _choose_hubs(cols: np.ndarray, n_cols: int, blocks: int):
     """(the hubs' column ids by falling edge count, a whole number of
     table rows; every column's place among them, −1 where it is no hub)
     — or (None, None) where :func:`_hub_rows` takes no row. Ties fall
     to the smaller id, so a graph has one answer."""
     deg = np.bincount(cols, minlength=n_cols)
     by_deg = np.argsort(-deg, kind="stable")[:_HUB_ROWS_MAX * HUB_ROW]
-    rows = _hub_rows(deg[by_deg], cols.shape[0])
+    rows = _hub_rows(deg[by_deg], cols.shape[0], blocks)
     if rows == 0:
         return None, None
     # a table row is whole: past the last source the ids name column 0,
@@ -615,18 +671,55 @@ def _choose_hubs(cols: np.ndarray, n_cols: int):
     return ids, rank
 
 
+def hub_walks(idx: np.ndarray, n_hubs: int):
+    """(``first``, ``rows``), each (chunks, CHUNK // HUB_REG) int32, of a
+    hub chunk table ``idx`` (chunks, CHUNK): the run of table rows,
+    ``first : first + rows``, that holds the row ``idx // 128`` of every
+    real slot of a register of ``HUB_REG`` slots; ``first`` a multiple
+    of ``HUB_TILE``, ``rows`` a whole number of steps of ``HUB_WALK``,
+    one at the least, and the run inside the table as the kernel holds
+    it (:func:`hub_table_rows`). The hub kernel walks that run for the
+    register and no other row. A padded slot (``idx`` = ``n_hubs``)
+    takes no part; a register of padding alone walks the table's first
+    step, as the kernel walks one whatever it is told. Whatever the
+    order of the slots the run covers them; in the order the build lays
+    them (a block's slots by table row) the runs of a block's registers
+    share the table out and overlap in a step at most."""
+    row = idx.reshape(idx.shape[0], -1, HUB_REG) >> 7
+    padded = n_hubs >> 7           # one past the table's last row
+    low = row.min(axis=2)          # padding is the largest row there is
+    high = np.where(row < padded, row, -1).max(axis=2)
+    low = np.where(high < 0, 0, low)
+    first = low // HUB_TILE * HUB_TILE
+    rows = np.maximum(-(-(high + 1 - first) // HUB_WALK), 1) * HUB_WALK
+    first = np.minimum(first, hub_table_rows(padded) - rows)
+    return first.astype(np.int32), rows.astype(np.int32)
+
+
+def hub_table_rows(rows: int) -> int:
+    """Rows of the hub table as the kernel holds it: a whole number of
+    walk steps (past the table's own the rows are zeros, which no slot
+    names)."""
+    return -(-rows // HUB_WALK) * HUB_WALK
+
+
 def _numpy_fill(rows, cols, vals, n_cols, block, first, cnt,
-                width: int = WIDTH):
+                width: int = WIDTH, in_order: bool = False):
     """Pure-numpy plan fill (fallback when the native library is
-    unavailable): stable argsort by row, then fancy-indexed scatters.
-    Block b owns the flat slots ``first[b]:first[b + 1]`` (one row of
-    ``cap`` in the blocks layout, its chunks in the other); edges past
-    them are the overflow. A column is a row of ``width`` and a lane."""
+    unavailable): stable argsort by row (``in_order``: the edges come
+    block by block already, in the order their slots shall have), then
+    fancy-indexed scatters. Block b owns the flat slots
+    ``first[b]:first[b + 1]`` (one row of ``cap`` in the blocks layout,
+    its chunks in the other); edges past them are the overflow. A column
+    is a row of ``width`` and a lane."""
     m = rows.shape[0]
     if vals is None:
         vals = np.ones((m,), np.float32)
-    order = np.argsort(rows, kind="stable")
-    rows_s, cols_s, vals_s = rows[order], cols[order], vals[order]
+    if in_order:
+        rows_s, cols_s, vals_s = rows, cols, vals
+    else:
+        order = np.argsort(rows, kind="stable")
+        rows_s, cols_s, vals_s = rows[order], cols[order], vals[order]
     blk = rows_s // block
     starts = np.zeros(first.shape[0], np.int64)
     np.cumsum(cnt, out=starts[1:])
@@ -937,6 +1030,10 @@ def spmv_sharded(plan: EdgeSpMVPlan, x: jax.Array, mesh) -> jax.Array:
 # -- plan persistence --------------------------------------------------------
 
 
+# what a plan file holds of its hub chunks
+_HUB_FILE_FIELDS = ("ids", "idx", "off", "val", "chunk_block")
+
+
 def save_plan(path: str, plan: EdgeSpMVPlan) -> None:
     """Persist a plan's compact layout (one .npz). The expensive build
     (host sort/fill) is skipped on load; table expansion (or the compact
@@ -950,7 +1047,9 @@ def save_plan(path: str, plan: EdgeSpMVPlan) -> None:
         # constants must fail loudly, not gather from wrong rows. The
         # chunks layout is version 2: a reader that knows only version 1
         # would take chunk i for block i; with hub chunks version 3: a
-        # reader of version 2 would leave their edges out
+        # reader of version 2 would leave their edges out (their walks
+        # are reckoned from the slots on load: a file of PR 36, whose
+        # hub slots lie in input order, loads and runs)
         meta=np.asarray([plan.n_rows, plan.n_cols, plan.block,
                          plan.capacity,
                          3 if plan.hubs is not None else 2 if chunked else 1,
@@ -962,8 +1061,8 @@ def save_plan(path: str, plan: EdgeSpMVPlan) -> None:
     if chunked:
         payload.update(chunk_block=np.asarray(plan.chunk_block, np.int32))
     if plan.hubs is not None:
-        payload.update({f"hub_{f.name}": getattr(plan.hubs, f.name)
-                        for f in dataclasses.fields(HubChunks)})
+        payload.update({f"hub_{name}": getattr(plan.hubs, name)
+                        for name in _HUB_FILE_FIELDS})
     if plan.ov_rows is not None:
         payload.update(ov_rows=np.asarray(plan.ov_rows),
                        ov_cols=np.asarray(plan.ov_cols),
@@ -1004,6 +1103,6 @@ def load_plan(path: str) -> EdgeSpMVPlan:
             ov_vals=jnp.asarray(z["ov_vals"]) if has_ov else None,
             padding_ratio=float(z["padding_ratio"][0]),
             chunk_block=z["chunk_block"] if version >= 2 else None,
-            hubs=HubChunks(**{f.name: z[f"hub_{f.name}"]
-                              for f in dataclasses.fields(HubChunks)})
+            hubs=HubChunks.of(*(z[f"hub_{name}"]
+                                for name in _HUB_FILE_FIELDS))
             if version == 3 else None)

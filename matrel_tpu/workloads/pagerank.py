@@ -93,9 +93,10 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
     overflow COO, ~1.04 slots an edge on a Graph500 Kronecker graph),
     the matvec runs in panels that fit the device, and the plan gate
     and cache reckon its real bytes; where the sources are skewed as
-    well, the edges from the few of largest out-degree take their rank
-    from a table in VMEM inside the scatter kernel (PR 36: the plan's
-    hub chunks, chosen by the build from the degrees);
+    well, the edges from the sources of largest out-degree take their
+    rank from a table in VMEM inside the scatter kernel (PR 36: the
+    plan's hub chunks, chosen by the build from the degrees; PR 42: a
+    register of slots walks only the table rows it names);
     :func:`last_plan` says what ran.
     """
     if impl not in ("auto", "segment", "onehot"):
@@ -125,7 +126,8 @@ def last_plan() -> dict:
     """The executor (``impl``) and the prepared plan's layout
     (:func:`_plan_attrs`: ``layout``, ``edges``, ``slots``, ``chunks``,
     ``chunk``, ``overflow_edges``, ``row_values``, ``panels``,
-    ``plan_bytes``, ``hubs``, ``hub_slots``, ``hub_chunks``, ``hit``; on
+    ``plan_bytes``, ``hubs``, ``hub_slots``, ``hub_chunks``,
+    ``hub_walk_rows``, ``hit``; on
     a build by the one-device path also
     ``build_s`` and ``upload_s``) of the newest :func:`pagerank_edges` call
     — what its ``matrel.pagerank`` / ``matrel.pagerank.plan`` spans say
@@ -476,7 +478,11 @@ def _plan_attrs(plan, edges: int, compact: bool, devices: int = 1) -> dict:
     — the expanded executor gathers in one piece and holds ~224 B a
     slot; both a device, of a plan sharded over ``devices`` — and the
     hub table (``hubs`` sources, 0 where the build chose none) with the
-    ``hub_chunks`` of ``hub_slots`` whose edges come from it."""
+    ``hub_chunks`` of ``hub_slots`` whose edges come from it and the
+    table rows the hub kernel walks a matvec (``hub_walk_rows``: the
+    rows every register of 1,024 hub slots names, summed; walking the
+    whole table for each, as until PR 42, would be ``hub_chunks`` x
+    ``chunk`` / 1,024 x ``hubs`` / 128)."""
     from matrel_tpu.ops import pallas_spmv as pc
     from matrel_tpu.ops import spmv as spmv_lib
     rows, cap = plan.src8.shape
@@ -495,7 +501,8 @@ def _plan_attrs(plan, edges: int, compact: bool, devices: int = 1) -> dict:
                               if compact else
                               mine * cap * _EXPANDED_BYTES_A_SLOT),
             "hubs": 0 if hub is None else int(hub.ids.shape[0]),
-            "hub_slots": hub_chunks * cap, "hub_chunks": hub_chunks}
+            "hub_slots": hub_chunks * cap, "hub_chunks": hub_chunks,
+            "hub_walk_rows": 0 if hub is None else int(hub.rows.sum())}
 
 
 

@@ -20,17 +20,21 @@
 // set of ragged tables (rank, off, val) and every other edge to the main
 // ones, in one walk over the edge list: no partitioned copy of it.
 //
-// Slot order within a block. matrel_spmv_fill and
-// matrel_spmv_fill_ragged_hubs keep input order where the numpy path sorts
-// by row — the matvec's one-hot contraction is order-agnostic, so their
-// contract (tests assert it) is equal spmv RESULTS, not byte-equal
+// Slot order within a block. matrel_spmv_fill, and the main tables of
+// matrel_spmv_fill_ragged_hubs, keep input order where the numpy path
+// sorts by row — the matvec's one-hot contraction is order-agnostic, so
+// their contract (tests assert it) is equal spmv RESULTS, not byte-equal
 // layouts. matrel_spmv_fill_ragged (PR 38) lays a block's entries by
 // destination row, stable inside a row, as the numpy path does: EQUAL
 // LAYOUTS, slot for slot (tests assert that too). The k-wide scatter
 // (ops/pallas_spmv.py) reads the order: a chunk of 2,048 slots in row
 // order names few rows, and where they lie within 128 of one another its
-// one-hot is 128 rows tall and not the block's 512. Sentinel convention
-// matches everywhere: src = n_cols, off = 0, val = 0.
+// one-hot is 128 rows tall and not the block's 512. The hub tables of
+// matrel_spmv_fill_ragged_hubs (PR 42) lie by the hub table's row
+// (rank / 128), stable inside a row, as the numpy path lays them: equal
+// layouts again. The hub kernel reads that order: a register of 1,024
+// slots names a short run of table rows and walks those alone. Sentinel
+// convention matches everywhere: src = n_cols, off = 0, val = 0.
 
 #include <algorithm>
 #include <cstdint>
@@ -202,9 +206,13 @@ int matrel_spmv_counts_hubs(const int64_t* rows, const int64_t* cols,
 // Pass 2 of a plan with hub chunks, one walk over the edges: an edge whose
 // source has a rank goes to the hub tables (block b owns their flat slots
 // [hub_first[b], hub_first[b+1]); hub_idx = the rank, n_hubs in padded
-// slots), every other to the main tables exactly as
-// matrel_spmv_fill_ragged fills them. Returns 0, or -1 on an index out of
-// range or a block past its slots in either set.
+// slots), every other to the main tables, sentinels as
+// matrel_spmv_fill_ragged's, a block's slots in input order. Then every
+// block's hub slots are put by table row (rank / 128) with a counting sort
+// through a scratch copy, as matrel_spmv_fill_ragged sorts by `off`: one
+// more walk over the hub slots, no sort of the edge list. Returns 0, or -1
+// on an index or a rank out of range or a block past its slots in either
+// set.
 int matrel_spmv_fill_ragged_hubs(const int64_t* rows, const int64_t* cols,
                                  const float* vals, int64_t m,
                                  int64_t n_cols, int64_t block, int64_t nb,
@@ -236,6 +244,7 @@ int matrel_spmv_fill_ragged_hubs(const int64_t* rows, const int64_t* cols,
         const int64_t b = r / block;
         if (b >= nb) return -1;
         const int32_t rank = hub_rank[c];
+        if (rank >= n_hubs) return -1;      // the sort below counts by rank
         const float v = vals ? vals[e] : 1.0f;
         if (rank >= 0) {
             const int64_t p = hub_next[b]++;
@@ -250,6 +259,28 @@ int matrel_spmv_fill_ragged_hubs(const int64_t* rows, const int64_t* cols,
             lane[p] = static_cast<int8_t>(c % width);
             off[p] = static_cast<int32_t>(r % block);
             val[p] = v;
+        }
+    }
+
+    // a block's real hub slots, hub_first[b] .. hub_next[b], by table row
+    const int64_t table_rows = (static_cast<int64_t>(n_hubs) + 127) / 128;
+    std::vector<int64_t> at(table_rows + 1);
+    std::vector<int32_t> t_idx, t_off;
+    std::vector<float> t_val;
+    for (int64_t b = 0; b < nb; ++b) {
+        const int64_t p0 = hub_first[b], n = hub_next[b] - p0;
+        if (n < 2) continue;
+        std::fill(at.begin(), at.end(), 0);
+        for (int64_t i = 0; i < n; ++i) at[(hub_idx[p0 + i] >> 7) + 1]++;
+        for (int64_t r = 0; r < table_rows; ++r) at[r + 1] += at[r];
+        t_idx.assign(hub_idx + p0, hub_idx + p0 + n);
+        t_off.assign(hub_off + p0, hub_off + p0 + n);
+        t_val.assign(hub_val + p0, hub_val + p0 + n);
+        for (int64_t i = 0; i < n; ++i) {
+            const int64_t p = p0 + at[t_idx[i] >> 7]++;
+            hub_idx[p] = t_idx[i];
+            hub_off[p] = t_off[i];
+            hub_val[p] = t_val[i];
         }
     }
     return 0;

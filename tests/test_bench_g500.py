@@ -231,13 +231,17 @@ def test_readers_read_the_programs_ring(dep, tmp_path):
     assert set(plan["attrs"]) >= {"hit", "layout", "edges", "slots",
                                   "chunks", "chunk", "overflow_edges",
                                   "row_values", "panels", "plan_bytes",
-                                  "hubs", "hub_slots", "hub_chunks"}
+                                  "hubs", "hub_slots", "hub_chunks",
+                                  "hub_walk_rows"}
     # slots are both sets of chunks (PR 36), so the reader keeps meaning
     # slots over edges; at scale 10 each of two blocks ends two ragged
     # sets (37% where the cell's graph pads 7.5%)
     attrs = plan["attrs"]
     assert attrs["hubs"] > 0 and attrs["slots"] == (
         attrs["chunks"] + attrs["hub_chunks"]) * attrs["chunk"]
+    # PR 42: the registers walk the rows they name, a step each at the
+    # least
+    assert attrs["hub_walk_rows"] >= 2 * 64 * attrs["hub_chunks"]
     pad = _load("metrics", "g500_slot_padding_pct.py").read(run, mine)
     assert pad == pytest.approx(
         100.0 * (attrs["slots"] / dep.src.size - 1)) and pad < 40

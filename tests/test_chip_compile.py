@@ -261,8 +261,9 @@ def test_chunked_pagerank_loop_runs_in_panels(one_chip):
 
 
 # The same graph's plan with the hub table build_spmv_plan chooses for it
-# (PR 36): 32,768 hubs hold 67.1M of the edges, in chunks of their own
-G500_MAIN_CHUNKS, G500_HUB_CHUNKS, G500_HUBS = 32_247, 35_107, 32_768
+# (PR 36, PR 42): 286,720 hubs (2,240 table rows) hold 110.5M of the edges,
+# in chunks of their own whose registers walk the table rows they name
+G500_MAIN_CHUNKS, G500_HUB_CHUNKS, G500_HUBS = 11_179, 56_310, 286_720
 
 
 def _g500_hub_loop(one_chip):
@@ -276,7 +277,8 @@ def _g500_hub_loop(one_chip):
     tables = chunks(G500_MAIN_CHUNKS, jnp.int32, jnp.int8, jnp.int32,
                     jnp.float32) + (
         _sds(one_chip, (G500_HUBS,), jnp.int32),) + chunks(
-        G500_HUB_CHUNKS, jnp.int32, jnp.int32, jnp.float32)
+        G500_HUB_CHUNKS, jnp.int32, jnp.int32, jnp.float32) + (
+        _sds(one_chip, (G500_HUB_CHUNKS,), jnp.int32),)          # walks
     static = (G500_NODES, G500_NODES, BLOCK, spmv_lib.LO)
     loop = pagerank._compact_runner_loop(G500_NODES, 10, 0.85, static, 0, 3,
                                          False)
@@ -287,16 +289,19 @@ def _g500_hub_loop(one_chip):
 def test_chunked_pagerank_loop_with_a_hub_table(one_chip):
     """The round with hub chunks: two kernels (the chunk scatter over the
     main chunks, the hub scatter over the others, their slot weights
-    made in VMEM from the (256, 128) table), 4 panels where the 64,976
-    chunks took 8, and arguments and temporaries what ``plan_bytes``
-    reckons with 12 B a hub slot."""
+    made in VMEM from the (2240, 128) table by a walk of the rows each
+    register names: a loop with scalar-prefetched bounds), the main set
+    in panels, the two scalar-prefetch arrays of the 55k hub chunks
+    (chunk -> block, a chunk's two walks in one word: 450 KB) inside
+    SMEM, and arguments and temporaries what ``plan_bytes`` reckons with
+    12 B a hub slot."""
     compiled = _g500_hub_loop(one_chip)
     text = compiled.as_text()
     assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
     assert "matrel_spmv_scatter_chunks" in text
     assert "matrel_spmv_scatter_hubs" in text
     per = pc.panel_rows(G500_MAIN_CHUNKS, spmv_lib.CHUNK)
-    assert -(-G500_MAIN_CHUNKS // per) == 4 and per == 8064
+    assert -(-G500_MAIN_CHUNKS // per) == 2 and per % 64 == 0
     assert f"u8[{per * spmv_lib.CHUNK},32]" in text
     assert f"f32[{G500_HUBS // pc.LANE},{pc.LANE}]" in text  # the hub table
     stats = compiled.memory_analysis()
@@ -306,6 +311,58 @@ def test_chunked_pagerank_loop_with_a_hub_table(one_chip):
         G500_MAIN_CHUNKS * spmv_lib.CHUNK) - pc.HUB_BYTES_A_SLOT * hub_slots
     taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
     assert 0.9 * reckoned < taken < 1.05 * reckoned, (reckoned, taken)
+
+
+def test_the_most_hub_chunks_the_rule_allows_fit_smem(one_chip):
+    """The hub kernel's scalar prefetch is 8 B a chunk in a 1 MiB SMEM:
+    as many chunks as ``_hub_rows`` lets a graph have compile (a twelfth
+    more did not, PR 42), at the tallest table it may choose."""
+    n, nb = spmv_lib._HUB_CHUNKS_MAX, 2 * G500_NODES // BLOCK
+    rows = spmv_lib.hub_table_rows(spmv_lib._HUB_ROWS_MAX)
+    run = pc._hub_runner(n, spmv_lib.CHUNK, nb, BLOCK, spmv_lib.LO, 3, rows,
+                         spmv_lib.HUB_WALK, False)
+    slots = (n, spmv_lib.CHUNK // pc.LANE, pc.LANE)
+    _compile(jax.jit(run), _sds(one_chip, (n,), jnp.int32),
+             _sds(one_chip, (n,), jnp.int32),
+             *(_sds(one_chip, slots, dt)
+               for dt in (jnp.int32, jnp.int32, jnp.float32)),
+             _sds(one_chip, (rows, pc.LANE), jnp.float32),
+             _sds(one_chip, (nb, BLOCK // spmv_lib.LO, spmv_lib.LO),
+                  jnp.float32))
+
+
+def test_aligned_table_rows_load_at_a_prefetched_offset(one_chip):
+    """The hub walk stands on an (8, 128) load of the table in VMEM at a
+    row offset read from a scalar-prefetch operand and promised a
+    multiple of 8, inside a loop whose trip count is prefetched too;
+    where a compiler stops taking it, its own words are the failure."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(walk_ref, table_ref, out_ref):
+        first, steps = walk_ref[0], walk_ref[1]
+
+        def step(t, acc):
+            at = pl.multiple_of(first + 8 * t, 8)
+            return acc + table_ref[pl.ds(at, 8), :]
+
+        out_ref[...] = jax.lax.fori_loop(
+            0, steps, step, jnp.zeros(out_ref.shape, jnp.float32))
+
+    rows = G500_HUBS // pc.LANE
+    walk = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec((rows, pc.LANE), lambda i, w: (0, 0))],
+            out_specs=pl.BlockSpec((8, pc.LANE), lambda i, w: (0, 0))),
+        out_shape=jax.ShapeDtypeStruct((8, pc.LANE), jnp.float32))
+    try:
+        _compile(jax.jit(walk), _sds(one_chip, (2,), jnp.int32),
+                 _sds(one_chip, (rows, pc.LANE), jnp.float32))
+    except Exception as e:  # noqa: BLE001 — the compiler's words are the result
+        pytest.fail("an aligned dynamic (8, 128) load of the hub table no "
+                    f"longer lowers for the described v5e: {e}")
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32],

@@ -649,6 +649,42 @@ class TestWidePlans:
         monkeypatch.undo()
         assert coo_lib._plan_layout() == "blocks"
 
+    def test_a_matrix_of_skewed_sources_has_no_hub_chunks(self, rng,
+                                                          on_one_chip):
+        """A COOMatrix declines hub chunks whatever its columns' degrees
+        (PR 36), so PR 42's order of hub slots never reaches it: a
+        block's slots lie by destination row (PR 38), five tables go to
+        the device, and the k-wide product lowers to its one kernel."""
+        import jax
+        import jax.numpy as jnp
+        from matrel_tpu.ops import pallas_spmv as pc
+        from matrel_tpu.ops import spmv as spmv_lib
+        m, shape = 60_000, (2600, 900)
+        rows = rng.integers(0, shape[0], m)
+        cols = np.where(rng.random(m) < 0.7, rng.integers(0, 40, m),
+                        rng.integers(0, shape[1], m))   # 40 hot columns
+        vals = rng.standard_normal(m).astype(np.float32)
+        A = COOMatrix.from_edges(rows, cols, vals, shape=shape)
+        plan = A._get_plan()
+        # the same edges through the door PageRank uses do get hubs
+        assert spmv_lib.build_spmv_plan(rows, cols, vals, *shape,
+                                        layout="chunks").hubs is not None
+        assert plan.hubs is None and plan.chunk_block is not None
+        assert len(pc.compact_tables(plan)) == 5
+        real = plan.val != 0
+        for b in np.unique(plan.chunk_block):
+            mine = plan.chunk_block == b
+            assert (np.diff(plan.off[mine][real[mine]]) >= 0).all()
+        _, windowed = pc.wide_windows(plan)
+        assert windowed == plan.src8.shape[0]
+        static, part_statics, part_arrays = pc.plan_operands(plan)
+        text = jax.jit(lambda pa, x: pc.compact_matmat_parts(
+            static, part_statics, pa, x, 3, False)).trace(
+            part_arrays, jax.ShapeDtypeStruct((shape[1], 16), jnp.float32)
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        assert "matrel_spmm_scatter_chunks" in text
+        assert "matrel_spmv_scatter_hubs" not in text
+
     def test_one_table_is_one_plan_shared_with_the_matvec(self, rng,
                                                           on_one_chip):
         A = self._skewed(rng)

@@ -123,6 +123,44 @@ def test_three_iterations_match_float64(rng, compact_one_device, rank):
     assert all("pallas_spmv" in p["executors"] for p in said)
 
 
+@pytest.mark.parametrize("transposed", [False, True],
+                         ids=["forward", "transposed_in_panels"])
+def test_the_ratings_plans_have_no_hub_chunks(rng, compact_one_device,
+                                              transposed):
+    """PR 42 lays a plan's HUB slots by the hub table's row. A ratings
+    matrix's plans have none, though its hot movies are hubs by any
+    count (the same entries through PageRank's door get a table): every
+    part keeps its slots by destination row, a window a chunk where the
+    rows allow, five tables on the device, and the k-wide kernel alone
+    in the lowered product."""
+    import jax
+    import jax.numpy as jnp
+    from matrel_tpu.ops import pallas_spmv as pc
+    V = _ratings(rng)
+    plan = V._get_wide_plan(transposed=transposed)
+    parts = [p for _, p in getattr(plan, "parts", ((0, plan),))]
+    assert len(parts) == (4 if transposed else 1)
+    for part in parts:
+        assert part.hubs is None and part.chunk_block is not None
+        assert len(pc.compact_tables(part)) == 5
+        real = part.val != 0
+        for b in np.unique(part.chunk_block):
+            mine = part.chunk_block == b
+            assert (np.diff(part.off[mine][real[mine]]) >= 0).all()
+    if not transposed:
+        hubbed = spmv_lib.build_spmv_plan(
+            V.rows, V.cols, V.vals, *V.shape, layout="chunks")
+        assert hubbed.hubs is not None
+    static, part_statics, part_arrays = pc.plan_operands(plan)
+    n_in = V.shape[0] if transposed else V.shape[1]
+    text = jax.jit(lambda pa, x: pc.compact_matmat_parts(
+        static, part_statics, pa, x, 3, False)).trace(
+        part_arrays, jax.ShapeDtypeStruct((n_in, 128), jnp.float32)
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("matrel_spmm_scatter_chunks") >= len(parts)
+    assert "scatter_hubs" not in text
+
+
 def test_new_factor_arrays_hit_the_plan_templates(rng, compact_one_device):
     """W and H are new arrays every update: the first iteration compiles
     its two programs, every later update rebinds them, and V's plans are

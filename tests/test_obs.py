@@ -1141,9 +1141,10 @@ class TestProfilerTier:
             assert set(said) == {
                 "hit", "layout", "edges", "slots", "chunks", "chunk",
                 "overflow_edges", "row_values", "panels", "plan_bytes",
-                "hubs", "hub_slots", "hub_chunks"}
+                "hubs", "hub_slots", "hub_chunks", "hub_walk_rows"}
             assert said["layout"] == "blocks" and said["panels"] == 1
-            assert said["hubs"] == said["hub_slots"] == said["hub_chunks"] == 0
+            assert said["hubs"] == said["hub_slots"] == said["hub_chunks"] \
+                == said["hub_walk_rows"] == 0
             assert said["slots"] == said["chunks"] * said["chunk"]
             assert by["matrel.pagerank.fingerprint"]["attrs"] == known
         assert [r["name"] for r in seg] == ["matrel.pagerank.dispatch"]

@@ -225,6 +225,7 @@ def test_a_block_without_hub_edges_keeps_the_main_sums(monkeypatch):
     the hub kernel, which starts from the main scatter's sums, leaves
     its tile as that left it."""
     monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", 1)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 0)  # so few edges pay
     rows = np.concatenate([np.arange(150), 1024 + np.arange(150),
                            200 + np.arange(127), 512 + np.arange(40)])
     cols = np.concatenate([np.zeros(300, np.int64), 1 + np.arange(127),
@@ -267,10 +268,12 @@ def test_native_and_numpy_fills_agree_on_chunks(graphs, monkeypatch, rng,
     ref = spmv_lib.build_spmv_plan(dst, src, vals, v, v, layout="chunks")
     np.testing.assert_array_equal(nat.chunk_block, ref.chunk_block)
     assert nat.src8.shape == ref.src8.shape
-    # slot order within a block: with hub chunks input order (native)
-    # and row-sorted (numpy): the same slots are real, and each block
-    # holds the same edges, as the same matvec shows bit for bit;
-    # without them (PR 38) both lie by row, the same tables
+    # slot order within a block: the main chunks beside hub chunks keep
+    # input order (native) and lie row-sorted (numpy): the same slots
+    # are real, and each block holds the same edges, as the same matvec
+    # shows; without hub chunks (PR 38) both lie by row, the same
+    # tables; and the hub chunks lie by table row, stable inside one,
+    # in both (PR 42): the same tables and the same walks
     np.testing.assert_array_equal((nat.val != 0).sum(1), (ref.val != 0).sum(1))
     if not hub_rows:
         for name in ("src8", "lane", "off", "val"):
@@ -284,9 +287,10 @@ def test_native_and_numpy_fills_agree_on_chunks(graphs, monkeypatch, rng,
                                       ref.hubs.chunk_block)
         np.testing.assert_array_equal((nat.hubs.val != 0).sum(1),
                                       (ref.hubs.val != 0).sum(1))
-        per_block = [np.sort((h.chunk_block[:, None] * 1024 + h.idx),
-                             axis=None) for h in (nat.hubs, ref.hubs)]
-        np.testing.assert_array_equal(*per_block)
+        for name in ("idx", "off", "val", "first", "rows"):
+            np.testing.assert_array_equal(getattr(nat.hubs, name),
+                                          getattr(ref.hubs, name),
+                                          err_msg=name)
     x = jnp.asarray(rng.random(v).astype(np.float32))
     a = np.asarray(pc.spmv_compact(nat, x, interpret=True))
     b = np.asarray(pc.spmv_compact(ref, x, interpret=True))
@@ -321,6 +325,10 @@ def test_native_hub_fill_refuses_a_block_past_its_slots():
                                             first, hub_first, 8) is None
     # a column past the rank table is out of range, not read
     assert native.spmv_counts_hubs(rows, cols + 1, rank, 512, 1) is None
+    # nor a rank past the table the sort counts by (PR 42)
+    assert native.spmv_fill_ragged_hubs(
+        rows, cols, None, np.array([-1, 128], np.int32), 128, 512, room,
+        room, 8) is None
 
 
 # -- the matvec and the ranks ---------------------------------------------------
@@ -465,14 +473,14 @@ def test_pagerank_edges_auto_answers_a_skewed_graph_in_chunks(
 
 
 @pytest.mark.parametrize("hub_rows", [2, None],
-                         ids=["two_hub_rows", "every_source_a_hub"])
+                         ids=["two_hub_rows", "as_chosen"])
 def test_pagerank_edges_reports_the_hub_table(graphs, compact_auto,
                                               monkeypatch, hub_rows):
     """``last_plan()`` says what the build chose, on a build as on a
     hit, and the ranks hold the Graph500 configuration's two limits. At
-    this scale the table the code chooses unpatched (64 rows at the
-    most) has room for all 3,335 sources, and takes 26 rows of them:
-    the 7 of smallest degree would make a row that does not pay."""
+    this scale the table the code chooses unpatched has 18 rows of the
+    3,335 sources' 27: 17 pay, one more pays its entries of ``x[ids]``
+    in the walk step they open."""
     src, dst, v = graphs[12]
     if hub_rows:
         monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", hub_rows)
@@ -480,17 +488,26 @@ def test_pagerank_edges_reports_the_hub_table(graphs, compact_auto,
                      np.float64)
     said = pr.last_plan()
     assert said["impl"] == "compact" and said["layout"] == "chunks"
-    assert said["hubs"] == spmv_lib.HUB_ROW * (hub_rows or v // 128)
+    assert said["hubs"] == spmv_lib.HUB_ROW * (hub_rows or 18)
     assert said["hub_slots"] == said["hub_chunks"] * said["chunk"] > 0
     assert said["slots"] == (said["chunks"] + said["hub_chunks"]) \
         * said["chunk"]
+    # PR 42: the table rows the hub chunks' registers walk a matvec: a
+    # step for each of a chunk's two registers at the least, and (the
+    # slots lie by table row) about the table once a block beside that,
+    # not once a register
+    step = spmv_lib.HUB_WALK
+    blocks = -(-v // 512)
+    table_rows = spmv_lib.hub_table_rows(said["hubs"] // 128)
+    assert 2 * step * said["hub_chunks"] <= said["hub_walk_rows"] <= (
+        blocks * table_rows + 2 * step * 2 * said["hub_chunks"])
     assert said["overflow_edges"] == 0 and said["edges"] == src.size
     assert said["plan_bytes"] == pc.plan_bytes(
         said["chunks"], said["chunk"], said["hub_slots"]) \
         == pc.plan_bytes(said["chunks"], said["chunk"]) \
         + 12 * said["hub_slots"]
     if not hub_rows:            # a main chunk a block, all but empty
-        assert said["chunks"] == -(-v // 512) and v % 128 == 7
+        assert said["chunks"] == -(-v // 512)
     want = pr.pagerank_reference_edges(src, dst, v, 10, 0.85)
     assert np.max(np.abs(got - want)) / want.max() < 3e-6
     assert np.max(np.abs(got - want) / want) < 5e-6
@@ -500,8 +517,8 @@ def test_pagerank_edges_reports_the_hub_table(graphs, compact_auto,
     again = np.asarray(pr.pagerank_edges(src, dst, v, rounds=10, alpha=0.85))
     hit = pr.last_plan()
     assert hit["hit"] is True
-    assert [hit[k] for k in ("hubs", "hub_slots", "hub_chunks")] \
-        == [said[k] for k in ("hubs", "hub_slots", "hub_chunks")]
+    counters = ("hubs", "hub_slots", "hub_chunks", "hub_walk_rows")
+    assert [hit[k] for k in counters] == [said[k] for k in counters]
     np.testing.assert_array_equal(again, got.astype(np.float32))
 
 
@@ -643,14 +660,31 @@ def test_save_and_load_keep_the_hub_chunks(graphs, two_hub_rows, tmp_path,
     spmv_lib.save_plan(path, plan)
     with np.load(path) as z:
         assert int(z["meta"][4]) == 3            # a version-2 reader stops
+        payload = {k: z[k] for k in z.files}
+    # the walks are not in the file: they are reckoned from its slots
+    assert not {"hub_first", "hub_rows"} & set(payload)
     loaded = spmv_lib.load_plan(path)
-    for name in ("ids", "idx", "off", "val", "chunk_block"):
+    fields = ("ids", "idx", "off", "val", "chunk_block", "first", "rows")
+    for name in fields:
         np.testing.assert_array_equal(getattr(loaded.hubs, name),
                                       getattr(plan.hubs, name))
     x = jnp.asarray(rng.random(v).astype(np.float32))
+    want = np.asarray(pc.spmv_compact(plan, x, interpret=True))
     np.testing.assert_array_equal(
-        np.asarray(pc.spmv_compact(loaded, x, interpret=True)),
-        np.asarray(pc.spmv_compact(plan, x, interpret=True)))
+        np.asarray(pc.spmv_compact(loaded, x, interpret=True)), want)
+    # whatever order those lie in: a file of PR 36 (input order) loads
+    # with the walks that order needs, and runs
+    perm = np.argsort(rng.random(plan.hubs.idx.shape), axis=1)
+    for name in ("hub_idx", "hub_off", "hub_val"):
+        payload[name] = np.take_along_axis(payload[name], perm, axis=1)
+    np.savez_compressed(path, **payload)
+    old = spmv_lib.load_plan(path)
+    np.testing.assert_array_equal(old.hubs.idx, payload["hub_idx"])
+    assert old.hubs.rows.shape == plan.hubs.rows.shape
+    assert old.hubs.rows.sum() >= plan.hubs.rows.sum()
+    np.testing.assert_allclose(
+        np.asarray(pc.spmv_compact(old, x, interpret=True)), want,
+        rtol=2e-6, atol=0)
 
 
 def test_unknown_layout_is_refused():

@@ -935,41 +935,103 @@ def test_refusals_are_named():
 # from a (M, 128) table in VMEM by lane permutes: no row gather.
 
 
+# the table rows of a Kronecker scale-13 graph's 6,467 sources and 13
+# blocks by the rule left to itself: 32 pay, one more pays its entries of
+# x[ids] in the walk step they open (the kernel holds a whole step of 64);
+# at steps of 8 rows the 32 are whole steps and open none
+AS_CHOSEN_ROWS_AT_SCALE_13 = {64: 33, 8: 32}
+# and of the Graph500 scale-22 graph of cell pagerank_g500_22_1c (2,396,366
+# sources, 4,681 blocks), from its shares of the edges (below)
+G500_ROWS_CHOSEN = 2240
+
+
+@pytest.fixture
+def steps_of_eight(monkeypatch):
+    """Walk steps of one (8, 128) tile, so that a table of a few dozen
+    rows is several steps tall (the code's own step is 64 rows)."""
+    monkeypatch.setattr(spmv_lib, "HUB_WALK", 8)
+
+
 def _hub_rows_max(monkeypatch, rows):
     if rows is not None:
         monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", rows)
 
 
+def _hub_weights_in_a_kernel(idx, table, first, rows):
+    """``pallas_spmv._hub_weights`` of one chunk of slots ``idx`` (16,
+    128), its two registers walking the table rows ``first[r] : first[r]
+    + rows[r]``, run as the hub kernel runs it (interpreted)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from matrel_tpu.ops import pallas_spmv as pc
+
+    def kernel(walk_ref, idx_ref, table_ref, out_ref):
+        out_ref[...] = pc._hub_weights(
+            idx_ref[...], table_ref,
+            lambda r: (walk_ref[2 * r], walk_ref[2 * r + 1]),
+            spmv_lib.HUB_WALK)
+
+    whole = lambda shape: pl.BlockSpec(shape, lambda i, w: (0, 0))
+    walk = np.stack([first, np.asarray(rows) // spmv_lib.HUB_WALK],
+                    1).astype(np.int32).reshape(-1)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[whole(idx.shape), whole(table.shape)],
+            out_specs=whole(idx.shape)),
+        out_shape=jax.ShapeDtypeStruct(idx.shape, jnp.float32),
+        interpret=True)(jnp.asarray(walk), jnp.asarray(idx),
+                        jnp.asarray(table))
+
+
 @pytest.mark.parametrize("case", sorted(_BIT_CASES))
-def test_hub_weights_are_x_idx_bit_for_bit(case):
+def test_hub_weights_are_x_idx_bit_for_bit(case, monkeypatch, steps_of_eight):
     """A permute moves 32-bit lanes and a select picks whole values: a
     hub slot's weight is the table's entry to the last bit, specials
-    included, and a padded slot (the rank past the table) reads +0.0
-    whatever lies in the table."""
+    included, through the walk of the rows the register names (PR 42:
+    two steps for the one, the second and third tile of the table; one
+    for the other), and a padded slot (the rank past the table) reads
+    +0.0 whatever lies in the table."""
     from matrel_tpu.ops import pallas_spmv as pc
     table = _BIT_CASES[case]
     n = table.shape[0]
-    # the case's values on two table rows, the second back to front
-    rows = np.zeros((2, pc.LANE), np.float32)
-    rows[0, 5:5 + n], rows[1, 100:100 + n] = table, table[::-1]
-    idx = np.full((16, pc.LANE), 2 * pc.LANE, np.int32)       # padded slots
-    idx[3, :n] = 5 + np.arange(n)
-    idx[12, 7:7 + n] = pc.LANE + 100 + np.arange(n)
-    idx[9, ::2] = 0                                            # a +0.0 entry
-    got = pc._hub_weights(jnp.asarray(idx), jnp.asarray(rows), 2)
+    # the case's values on three of the table's 32 rows, one back to front
+    rows = np.full((32, pc.LANE), np.float32(7.0))
+    rows[9, 5:5 + n], rows[17, 100:100 + n] = table, table[::-1]
+    rows[30, 20:20 + n] = table
+    rows[9, 0] = 0.0
+    idx = np.full((16, pc.LANE), 32 * pc.LANE, np.int32)      # padded slots
+    idx[3, :n] = 9 * pc.LANE + 5 + np.arange(n)
+    idx[6, 7:7 + n] = 17 * pc.LANE + 100 + np.arange(n)
+    idx[12, 3:3 + n] = 30 * pc.LANE + 20 + np.arange(n)
+    idx[5, ::2] = 9 * pc.LANE                                 # a +0.0 entry
+    first, walked = spmv_lib.hub_walks(idx[None], 32 * pc.LANE)
+    np.testing.assert_array_equal(first[0], [8, 24])
+    np.testing.assert_array_equal(walked[0], [16, 8])
+    got = _hub_weights_in_a_kernel(idx, rows, first[0], walked[0])
     want = np.concatenate([rows.reshape(-1), np.zeros(1, np.float32)])[idx]
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    # a row the walk leaves out is not read: the slots of row 17 weigh 0
+    got = _hub_weights_in_a_kernel(idx, rows, [8, 24], [8, 8])
+    want[6] = 0.0
     np.testing.assert_array_equal(_bits(got), _bits(want))
 
     # the compact matvec of a selection matrix whose sources are all hubs
-    # (fewer than 128: one table row): y is x[cols], the specials its
-    # lane neighbours in the table, empty rows +0.0
+    # (fewer than 128: the table's one row, which the kernel holds as a
+    # walk step of 8 rows here, the others zeros):
+    # y is x[cols], the specials its lane neighbours in the table, empty
+    # rows +0.0 (a table row of so few edges pays only because the test
+    # says so)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 0)
     cols = np.flatnonzero(_survives_split(table))[::-1]
     sel_rows = np.arange(cols.size) * 2
     plan = spmv_lib.build_spmv_plan(sel_rows, cols,
                                     np.ones(cols.size, np.float32),
                                     n_rows=2 * cols.size, n_cols=n,
                                     layout="chunks")
-    assert plan.hubs.ids.size == pc.LANE and not plan.val.any()
+    assert plan.hubs.ids.size == pc.LANE
+    assert not plan.val.any()
     static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
     y = pc.compact_apply(static, pc.compact_tables(plan), plan.overflow,
                          jnp.asarray(table), interpret=True)
@@ -991,14 +1053,50 @@ def kronecker_13():
     return g500.directed_in_seed_order(lo, hi, 36) + (v,)
 
 
-@pytest.mark.parametrize("hub_rows", [0, 1, 8, None],
+def _assert_walks_cover(hub):
+    """Every register's recorded run of table rows starts on a tile, is
+    whole walk steps (one at the least: a register of padding walks the
+    table's first), lies inside the table as the kernel holds it, and
+    holds the row of every real slot of the register; and no run is a
+    step longer than its slots need."""
+    step, tile, n = spmv_lib.HUB_WALK, spmv_lib.HUB_TILE, hub.ids.size
+    regs = spmv_lib.CHUNK // spmv_lib.HUB_REG
+    assert hub.first.shape == hub.rows.shape == (hub.idx.shape[0], regs)
+    assert hub.first.dtype == hub.rows.dtype == np.int32
+    assert not (hub.first % tile).any() and not (hub.rows % step).any()
+    assert (hub.first >= 0).all()
+    assert (hub.first + hub.rows
+            <= spmv_lib.hub_table_rows(n // spmv_lib.HUB_ROW)).all()
+    row = hub.idx.reshape(-1, regs, spmv_lib.HUB_REG) >> 7
+    real = hub.idx.reshape(row.shape) < n
+    inside = (row >= hub.first[..., None]) & (
+        row < (hub.first + hub.rows)[..., None])
+    assert inside[real].all()
+    some = real.any(axis=2)
+    assert (hub.rows >= step).all()
+    np.testing.assert_array_equal(hub.rows[~some], step)    # the least
+    np.testing.assert_array_equal(hub.first[~some], 0)
+    low = np.where(real, row, n).min(axis=2)
+    high = np.where(real, row, -1).max(axis=2)
+    assert (hub.rows[some] < (high - low)[some] + 1 + tile + step).all()
+
+
+@pytest.mark.parametrize("step", [64, 8], ids=["steps_of_64", "steps_of_8"])
+@pytest.mark.parametrize("hub_rows", [0, 1, 8, 32, None],
                          ids=["no_hubs", "128_hubs", "1024_hubs",
-                              "as_chosen"])
+                              "4096_hubs", "as_chosen"])
 def test_skewed_matvec_with_and_without_hubs(kronecker_13, monkeypatch, rng,
-                                             hub_rows):
+                                             hub_rows, step):
+    """``step``: the rows a walk step takes, the code's own 64 (at this
+    scale a table is a step tall, or less and padded) and 8, where the
+    walks are several steps long and differ a register."""
     from matrel_tpu.ops import pallas_spmv as pc
     src, dst, v = kronecker_13
+    monkeypatch.setattr(spmv_lib, "HUB_WALK", step)
     _hub_rows_max(monkeypatch, hub_rows)
+    if hub_rows:        # a table of exactly that many rows
+        monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES_A_BLOCK", 0.0)
+        monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 0)
     vals = rng.standard_normal(src.size).astype(np.float32)
     plan = spmv_lib.build_spmv_plan(dst, src, vals, v, v, layout="chunks")
     hub = plan.hubs
@@ -1006,10 +1104,22 @@ def test_skewed_matvec_with_and_without_hubs(kronecker_13, monkeypatch, rng,
     if hub_rows == 0:
         assert hub is None
         hub_edges = hub_slots = 0
-    else:       # as chosen: 43 rows of this graph's 6,467 sources pay
-        assert hub.ids.size == spmv_lib.HUB_ROW * (hub_rows or 43)
+    else:
+        assert hub.ids.size == spmv_lib.HUB_ROW * (
+            hub_rows or AS_CHOSEN_ROWS_AT_SCALE_13[step])
         hub_edges, hub_slots = int((hub.val != 0).sum()), hub.val.size
         assert np.isin(src, hub.ids).sum() == hub_edges
+        _assert_walks_cover(hub)
+        # a block's real slots lie by table row, the padding after them
+        for b in np.unique(hub.chunk_block):
+            mine = hub.idx[hub.chunk_block == b].reshape(-1)
+            assert (np.diff(mine >> 7) >= 0).all()
+        # and so its registers share the table out: they walk little
+        # more than the table once a block, not once a register
+        blocks = np.unique(hub.chunk_block).size
+        assert hub.rows.sum() <= blocks * spmv_lib.hub_table_rows(
+            hub.ids.size // spmv_lib.HUB_ROW) \
+            + 2 * spmv_lib.HUB_WALK * hub.rows.size
     # every edge in one of the two sets, the rest of both padding
     assert int((plan.val != 0).sum()) + hub_edges == (vals != 0).sum()
     assert plan.padding_ratio == (plan.val.size + hub_slots) / src.size
@@ -1017,6 +1127,74 @@ def test_skewed_matvec_with_and_without_hubs(kronecker_13, monkeypatch, rng,
     y = np.asarray(pc.spmv_compact(plan, jnp.asarray(x), interpret=True))
     want = coo_oracle(dst, src, vals, x, v)
     assert np.abs(y - want).max() / np.abs(want).max() < 2e-7
+
+
+def test_a_chunk_that_names_the_whole_table_and_a_block_with_one_hub_slot(
+        monkeypatch, rng, steps_of_eight):
+    """Block 0 takes one edge from every one of 2,048 hubs (16 table
+    rows): its one chunk is full and its two registers walk the table's
+    two halves. Block 1 takes a single hub edge, from the table's last
+    row: a chunk of one real slot, one walk of one step and one of
+    none. Block 2 takes all hubs but the last, and the other sources."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES_A_BLOCK", 0.0)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 0)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", 16)
+    n_hubs = 16 * spmv_lib.HUB_ROW
+    # every hub has two edges (among equals the smaller id ranks first,
+    # so hub h lies at table row h // 128): one into block 0 and one into
+    # block 2, but for the last hub, whose second goes into block 1; the
+    # other 300 sources have one edge each, into block 2
+    cols = np.concatenate([np.arange(n_hubs), np.arange(n_hubs),
+                           n_hubs + np.arange(300)])
+    rows = np.concatenate([rng.integers(0, 512, n_hubs),
+                           1024 + rng.integers(0, 512, n_hubs - 1), [700],
+                           1024 + rng.integers(0, 512, 300)])
+    order = rng.permutation(cols.size)
+    rows, cols = rows[order], cols[order]
+    vals = rng.standard_normal(cols.size).astype(np.float32)
+    n = n_hubs + 300
+    plan = spmv_lib.build_spmv_plan(rows, cols, vals, 1536, n,
+                                    layout="chunks")
+    hub = plan.hubs
+    assert hub.ids.size == n_hubs
+    np.testing.assert_array_equal(hub.chunk_block, [0, 1, 2])
+    np.testing.assert_array_equal(hub.first, [[0, 8], [8, 0], [0, 8]])
+    np.testing.assert_array_equal(hub.rows, [[8, 8], [8, 8], [8, 8]])
+    _assert_walks_cover(hub)
+    x = rng.standard_normal(n).astype(np.float32)
+    y = np.asarray(pc.spmv_compact(plan, jnp.asarray(x), interpret=True))
+    want = coo_oracle(rows, cols, vals, x, 1536)
+    assert np.abs(y - want).max() / np.abs(want).max() < 2e-7
+
+
+def test_a_plan_in_input_order_walks_what_its_slots_name(kronecker_13,
+                                                         monkeypatch, rng,
+                                                         steps_of_eight):
+    """The walks are reckoned from the slots, whatever their order: hub
+    chunks whose slots lie in another order (a plan file of PR 36) name
+    wide runs, which the kernel walks to the same answer."""
+    import dataclasses
+    from matrel_tpu.ops import pallas_spmv as pc
+    src, dst, v = kronecker_13
+    _hub_rows_max(monkeypatch, 32)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES_A_BLOCK", 0.0)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 0)
+    plan = spmv_lib.build_spmv_plan(dst, src, None, v, v, layout="chunks")
+    hub = plan.hubs
+    # (the same permutation a chunk for the three tables)
+    perm = np.argsort(rng.random(hub.idx.shape), axis=1)
+    take = lambda a: np.take_along_axis(a, perm, axis=1)
+    other = spmv_lib.HubChunks.of(hub.ids, take(hub.idx), take(hub.off),
+                                  take(hub.val), hub.chunk_block)
+    _assert_walks_cover(other)
+    assert other.rows.sum() > hub.rows.sum()
+    x = jnp.asarray(rng.standard_normal(v).astype(np.float32))
+    a = pc.spmv_compact(plan, x, interpret=True)
+    b = pc.spmv_compact(dataclasses.replace(plan, hubs=other), x,
+                        interpret=True)
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-6,
+                               atol=1e-6)
 
 
 def _compact_apply_before_pr36(plan_static, tables, ov, x, passes, interpret):
@@ -1076,38 +1254,84 @@ def _zipf_degrees(n, exponent, edges):
     return np.maximum((d / d.sum() * edges).astype(np.int64), 1)
 
 
-@pytest.mark.parametrize("name,deg,want_rows", [
-    # 10 edges a source, a million sources: 256 rows hold 3.3%
-    ("flat", np.full(1_000_000, 10), 0),
-    # the 32,768 largest of a million hold 9.4%: under a tenth
-    ("mildly_skewed_under_a_tenth", _zipf_degrees(1_000_000, 0.3, 10**7), 0),
-    # ... and 37%: every row pays, as many as the table may have
-    ("skewed", _zipf_degrees(1_000_000, 0.7, 10**7), 256),
-    # a steep head: 116 rows hold 72%, and the 117th would cost the 7.2M
-    # hub slots more than its 4,400 edges save
-    ("steep", _zipf_degrees(1_000_000, 1.0, 10**7), 116),
-    # the Graph500 scale-22 graph's shares (ISSUE 36: 11 / 17 / 22 / 32 /
-    # 40 / 52 / 62% at 1k .. 64k sources): 980 edges a hub at row 256
-    ("graph500_like", np.concatenate([
-        np.full(1024, 14_000), np.full(1024, 7_500), np.full(2048, 3_100),
-        np.full(4096, 3_000), np.full(8192, 1_250), np.full(16384, 980),
-        np.full(32768, 390), np.full(2_330_000, 21)]), 256),
-    ("fewer_than_128_sources", np.full(50, 40), 1),
-    ("one_source", np.array([100_000]), 1),
-    # 128 hubs of 10,000 edges, then one edge a source: a second row
-    # would cost 1.28M hub slots a permute each for 128 edges
-    ("the_tail_does_not_pay", np.concatenate([np.full(128, 10_000),
-                                              np.ones(500_000, np.int64)]), 1),
-    ("no_edges", np.zeros(1000, np.int64), 0),
+def _a_row_pays(blocks):
+    """Edges a table row needs to pay for itself (see ``_hub_rows``)."""
+    return int(np.ceil(spmv_lib._HUB_ROW_EDGES_A_BLOCK * blocks
+                       + spmv_lib._HUB_ROW_EDGES))
+
+
+def _a_row_fills(pays):
+    """Edges of a row that pays its entries of ``x[ids]`` and no walk of
+    its own: between ``_HUB_ROW_EDGES`` and what a row pays with."""
+    return (spmv_lib._HUB_ROW_EDGES + pays) // 2
+
+
+def _rows_of(edges_a_row, rows):
+    """``rows`` table rows of 128 sources that hold ``edges_a_row``."""
+    return np.full(128 * rows, -(-edges_a_row // 128), np.int64)
+
+
+# the Graph500 scale-22 graph's 128.3M directed edges by sources of falling
+# degree, as (table rows, mean edges a row) between marks (my desk count
+# on the cell's own graph, PR 42: 52.3 / 62.0 / 74.5 / 84.7 / 85.9 / 92.3 /
+# 97.5% of the edges at 256 / 512 / 1,024 / 2,048 / 2,192 / 4,096 / 8,192
+# rows; the degrees step down from 9,728 to 7,156 edges a row at 2,192)
+_G500_ROWS = [(256, 262_126), (256, 48_815), (512, 31_231), (1024, 12_767),
+              (144, 10_675), (1904, 4_338), (4096, 1_617), (10_529, 304)]
+_G500_DEG = np.concatenate([_rows_of(edges, rows)
+                            for rows, edges in _G500_ROWS])
+G500_BLOCKS = 4681
+
+
+@pytest.mark.parametrize("name,deg,blocks,want_rows", [
+    # 10 edges a source, a million sources: a row of 1,280 edges pays
+    # for a walk in none of 1,954 blocks
+    ("flat", np.full(1_000_000, 10), 1954, 0),
+    # rows that pay, twice over, but hold a twentieth of the edges
+    ("paying_rows_under_a_tenth", lambda pays: np.concatenate([
+        _rows_of(2 * pays, 8), np.full(62 * pays, 10)]),
+     1954, 0),
+    # 70 rows pay, the 71st would not: the second walk step of 64 rows
+    # is walked whole all the same, and the 58 rows that fill it hold
+    # edges enough to pay their entries of x[ids]
+    ("taken_while_they_pay", lambda pays: np.concatenate([
+        _rows_of(2 * pays, 70), _rows_of(_a_row_fills(pays), 400)]),
+     300, 128),
+    # ... and here they do not: a table of 70 rows (the kernel holds it
+    # as 128, the others zeros that cost no gather)
+    ("the_step_is_not_filled_for_nothing", lambda pays: np.concatenate([
+        _rows_of(2 * pays, 70), np.ones(128 * 400, np.int64)]), 300, 70),
+    # ... and here some do: the rows of a step one by one
+    ("the_step_is_filled_while_a_row_pays_its_gather",
+     lambda pays: np.concatenate([
+         _rows_of(2 * pays, 70), _rows_of(_a_row_fills(pays), 9),
+         np.ones(128 * 400, np.int64)]), 300, 79),
+    # every row pays: as many as the table may have
+    ("capped", lambda pays: _rows_of(3 * pays, 2 * spmv_lib._HUB_ROWS_MAX),
+     64, "cap"),
+    # no more rows than there are sources for
+    ("fewer_than_128_sources", lambda pays: np.full(50, pays), 4, 1),
+    ("one_source", np.array([100_000]), 1, 1),
+    ("one_source_of_few_edges", np.array([20]), 1, 0),
+    # 128 hubs of 10,000 edges, then one edge a source
+    ("the_tail_does_not_pay", np.concatenate([
+        np.full(128, 10_000), np.ones(500_000, np.int64)]), 977, 1),
+    ("no_edges", np.zeros(1000, np.int64), 2, 0),
+    ("graph500_scale_22", _G500_DEG, G500_BLOCKS, G500_ROWS_CHOSEN),
 ])
-def test_the_hub_table_is_chosen_from_the_degrees(name, deg, want_rows):
+def test_the_hub_table_is_chosen_from_the_degrees(name, deg, blocks,
+                                                  want_rows):
+    if callable(deg):
+        deg = deg(_a_row_pays(blocks))
+    if want_rows == "cap":
+        want_rows = spmv_lib._HUB_ROWS_MAX
     deg = np.sort(np.asarray(deg, np.int64))[::-1]
-    assert spmv_lib._hub_rows(deg, int(deg.sum())) == want_rows
+    assert spmv_lib._hub_rows(deg, int(deg.sum()), blocks) == want_rows
     # through the build's own door: the ids are the largest sources, the
     # smaller id first among equals
     if 0 < deg.size <= 1000:
         cols = np.repeat(np.arange(deg.size), deg)
-        ids, rank = spmv_lib._choose_hubs(cols, deg.size)
+        ids, rank = spmv_lib._choose_hubs(cols, deg.size, blocks)
         if want_rows == 0:
             assert ids is None and rank is None
         else:
@@ -1116,6 +1340,72 @@ def test_the_hub_table_is_chosen_from_the_degrees(name, deg, want_rows):
             np.testing.assert_array_equal(ids[:real], np.arange(real))
             assert not ids[real:].any()
             np.testing.assert_array_equal(rank, np.arange(deg.size))
+
+
+def test_a_row_costs_a_walk_a_block_and_no_longer_every_hub_slot():
+    """PR 36's rule charged a row to every hub slot; since PR 42 a row is
+    walked about once a block: the table is as tall on a graph of ten
+    times the edges a source, shorter where the blocks are more, and the
+    hubs hold the more of the edges the steeper the degrees fall."""
+    rows_of = lambda deg, blocks: spmv_lib._hub_rows(
+        np.sort(deg)[::-1], int(deg.sum()), blocks)
+    deg = _zipf_degrees(1_000_000, 0.8, 10**7)
+    assert rows_of(10 * deg, 1954) >= rows_of(deg, 1954) > 0
+    by_blocks = [rows_of(deg, b) for b in (100, 1954, 20_000, 200_000)]
+    assert by_blocks == sorted(by_blocks, reverse=True)
+    assert by_blocks[0] > by_blocks[-1]
+    shares = []
+    for exponent in (0.5, 0.7, 0.9, 1.1):
+        deg = np.sort(_zipf_degrees(1_000_000, exponent, 10**7))[::-1]
+        rows = spmv_lib._hub_rows(deg, int(deg.sum()), 1954)
+        shares.append(deg[:128 * rows].sum() / deg.sum())
+    assert shares == sorted(shares) and shares[-1] > 0.5
+
+
+def test_the_hub_table_stops_where_its_chunks_would_pass_smem(monkeypatch):
+    """The hub kernel's scalar-prefetched words, 8 B a chunk, lie in
+    SMEM: the rule stops taking rows before the hub chunks there could
+    be (their edges in whole chunks, one more a block) pass
+    ``_HUB_CHUNKS_MAX`` — a narrower table and no refused compile — and
+    takes none where the blocks alone would."""
+    blocks, pays = 300, _a_row_pays(300)
+    deg = _rows_of(10 * pays, 200)
+    a_row = int(deg[:128].sum())
+    edges = int(deg.sum())
+    assert spmv_lib._hub_rows(deg, edges, blocks) == 200
+    monkeypatch.setattr(spmv_lib, "_HUB_CHUNKS_MAX",
+                        blocks + 50 * a_row // spmv_lib.CHUNK)
+    rows = spmv_lib._hub_rows(deg, edges, blocks)
+    assert rows == 50
+    assert (-(-rows * a_row // spmv_lib.CHUNK) + blocks
+            <= spmv_lib._HUB_CHUNKS_MAX + 1)
+    monkeypatch.setattr(spmv_lib, "_HUB_CHUNKS_MAX", blocks)
+    assert spmv_lib._hub_rows(deg, edges, blocks) == 0
+
+
+def test_a_chunks_walks_ride_in_one_word():
+    """Both registers' walks of a hub chunk in one int32 (first row in
+    tiles of 8, steps of ``HUB_WALK``), up to the tallest table the rule
+    may choose, the sign bit included; what does not fit is refused."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    tall = spmv_lib.hub_table_rows(spmv_lib._HUB_ROWS_MAX)
+    assert tall <= 4096
+    step = spmv_lib.HUB_WALK
+    first = np.array([[0, tall - step], [tall - step, 0], [8, 4032],
+                      [0, 0]], np.int32)
+    rows = np.array([[tall, step], [step, tall], [2 * step, step],
+                     [step, step]], np.int32)
+    word = pc._pack_walks(first, rows)
+    assert word.dtype == np.int32 and word.shape == (4,)
+    field = (word[:, None] >> np.array([0, 16])) & 0xFFFF   # as the kernel
+    np.testing.assert_array_equal((field >> pc._WALK_STEP_BITS) * 8, first)
+    np.testing.assert_array_equal(
+        (field & ((1 << pc._WALK_STEP_BITS) - 1)) * step, rows)
+    with pytest.raises(ValueError, match="packed word"):
+        pc._pack_walks(np.array([[4096, 0]], np.int32), rows[3:])
+    with pytest.raises(ValueError, match="packed word"):
+        pc._pack_walks(first[3:], np.array([[step + 8, step]], np.int32))
+    assert pc._pack_walks(first[:0], rows[:0]).shape == (0,)
 
 
 # -- the k-wide compact product (PR 37) --------------------------------------------
@@ -1406,8 +1696,11 @@ def _wide_case(name, rng, monkeypatch):
         plan = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
                                         layout="chunks", hubs=False)
         want = "some"
-    else:                           # hub chunks, entries given in row order
+    else:       # hub chunks: their slots lie by the hub table's row (PR
+        # 42: the matvec's walks read that order), not by destination
+        # row, so fewer of them take a window than of the main chunks
         monkeypatch.setattr(spmv_lib, "_HUB_MIN_SHARE", 0.0)
+        monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 0)
         rows, cols, vals = _skewed_entries(rng, n_rows, n_cols, 60_000)
         cols[:30_000] = rng.integers(0, 40, 30_000)
         order = np.argsort(rows, kind="stable")
@@ -1415,7 +1708,7 @@ def _wide_case(name, rng, monkeypatch):
         plan = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
                                         layout="chunks")
         assert plan.hubs is not None
-        want = "all"
+        want = "some"
     return plan, rows, cols, vals, want
 
 
