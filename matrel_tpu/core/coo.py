@@ -30,11 +30,34 @@ k-wide product has more rows than a gather table keeps its row rate for
 (`spmv.source_panels`: 64 MB, 131,064 rows of 512 B), the orientation's
 plan is a `PanelledPlan`: one compact plan a range of sources, every one
 adding into the same output.
+
+A matrix's k-wide plans also have a DENSE PART where its own degrees
+ask for one (`DenseLines`, PR 43). Gather and scatter cost by the entry
+(2.6 ns an entry a product on a v5e), a dense line by its length; a
+ratings matrix's hottest movie columns are 5-48 % dense and its tail
+0.01 %. So the lines of one axis that hold more entries than a dense
+line costs (`_dense_groups`: in whole groups of 128, in order of degree,
+inside a byte budget) leave the coordinate list's compact layout for ONE
+slab `(length of the other axis, lines)` on the device, duplicates
+summed, kept on the matrix and shared by both orientations and by `.T`:
+where the lines are a product's sources it adds `slab @ X[lines]`, where
+they are its destinations `Y[lines] += slab^T X`, on the MXU with
+float32 sums, a long contraction in panels (`strategies.dot_in_panels`).
+The slab is float32 and multiplied at `highest`; it is bfloat16, twice
+the lines in the bytes, only where the build has checked that it holds
+every value exactly (ratings 1 to 5 in distinct cells: `_bfloat16_holds`
+and `DenseLines.fill`), and then multiplies the dense side's three
+bfloat16 parts: the same sums. The compact plans hold what is left, laid
+out exactly as they would be alone, and `plan_facts`' `entries` counts
+those; a matrix none of whose lines pays has no slab and the plans it
+always had. It is upstream's own design (a block is a dense or a CSC
+payload by its density), by the line instead of by the block.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -76,16 +99,180 @@ def _plan_layout() -> str:
             else "blocks")
 
 
+# The dense part's rule. A line pays where the entries it holds cost
+# more on the compact path than its whole length costs on the MXU.
+# ``_SPARSE_NS_AN_ENTRY``: a product's gather and scatter, by the entry;
+# ``_DENSE_NS_A_CELL``: a slab's cell (128 columns, float32 at six
+# bfloat16 passes or an exact bfloat16 slab at three, and its bytes of
+# HBM), by the slab's dtype. Read off one sweep of forced widths on the
+# Netflix-shaped matrix on a v5e (PERF.md section 6, PR 43: a fit of six
+# products a width, 0 to 4,352 lines, least squares to 3 ms of every
+# reading): a float32 line of 480,189 cells breaks even at 1,851
+# entries, a bfloat16 one at 1,343. They stand on that one matrix.
+# Lines come in groups of ``_DENSE_GROUP``, the MXU's contraction width:
+# a 129th line costs what a 256th does.
+_SPARSE_NS_AN_ENTRY = 2.67
+_DENSE_NS_A_CELL = {"float32": 0.0103, "bfloat16": 0.0075}
+_DENSE_GROUP = 128
+# the share of the device's memory the slab may take (less where the
+# plans' other bytes leave less: _dense_room)
+_DENSE_SHARE = 0.25
+# entries a call of the slab's scatter adds
+_SLAB_PIECE = 1 << 22
+
+
+@dataclasses.dataclass
+class DenseLines:
+    """The dense part of a COOMatrix's k-wide plans: the ``lines``
+    (ascending ids) of ``axis`` (0: rows, 1: columns, of the matrix that
+    chose them — its transpose view reads the axis flipped) whose
+    ``entries`` of the coordinate list lie in ``slab``, ``(length of the
+    other axis, lines up to whole groups of 128)`` on the device, column
+    j the line ``lines[j]``, the spare columns zero, a repeated cell's
+    values summed. float32 — or bfloat16 (``dtype``) where the choice
+    found every value of the matrix exactly a bfloat16's and no cell
+    turns out repeated (:meth:`fill` checks; the ratings 1 to 5 are):
+    such a slab times the dense side's three bfloat16 parts is the same
+    sums as the float32 one at ``highest``, at half the bytes a line.
+    Chosen by :func:`_choose_dense_lines`; the slab and the device copy
+    of ``lines`` come with the first wide plan's build."""
+    axis: int
+    lines: np.ndarray
+    column_of: np.ndarray       # a line's column of the slab, -1: none
+    entries: int
+    dtype: str = "float32"
+    slab: Optional[jax.Array] = None
+    lines_dev: Optional[jax.Array] = None
+
+    @property
+    def width(self) -> int:
+        return -(-self.lines.size // _DENSE_GROUP) * _DENSE_GROUP
+
+    def holds(self, ids: np.ndarray) -> np.ndarray:
+        """Which of the entries whose ids along ``axis`` are ``ids``
+        lie on a dense line."""
+        return self.column_of[ids] >= 0
+
+    def fill(self, ids, others, vals, length: int) -> bool:
+        """Add the dense lines' entries (``ids`` along ``axis``,
+        ``others`` along the other, of ``length``) into a new slab, one
+        device scatter a piece of ``_SLAB_PIECE`` entries. False, and no
+        slab, where a bfloat16 slab would not hold the entries exactly:
+        every value is a bfloat16's and none is zero (the choice saw to
+        that), so it does unless a cell is listed twice — and then it
+        has fewer cells that are not zero than it was given entries."""
+        n = ids.size
+        piece = min(_SLAB_PIECE, -(-max(n, 1) // 1024) * 1024)
+        # whole pieces: what is over adds zeros to cell (0, 0)
+        row, col, val = (np.zeros(-(-n // piece) * piece, dt)
+                         for dt in (np.int32, np.int32, np.float32))
+        row[:n], col[:n], val[:n] = others, self.column_of[ids], vals
+        slab = jnp.zeros((length, self.width), self.dtype)
+        for s in range(0, row.size, piece):
+            slab = _slab_add(slab, row[s:s + piece], col[s:s + piece],
+                             val[s:s + piece])
+        if (self.dtype != "float32"
+                and int(jnp.count_nonzero(slab)) != n):
+            return False
+        self.slab = slab
+        self.lines_dev = jnp.asarray(self.lines, jnp.int32)
+        return True
+
+
+@functools.partial(jax.jit, donate_argnums=0)  # matlint: disable=ML010 a plan build's one-off device scatter, not a query path
+def _slab_add(slab, row, col, val):
+    return slab.at[row, col].add(val.astype(slab.dtype))
+
+
+def _dense_room(limit: int, shape, left: np.ndarray) -> np.ndarray:
+    """The bytes a slab may take on a device that hands out ``limit``,
+    for each count ``left`` of entries that would stay in the compact
+    tables: ``_DENSE_SHARE`` of the device, less where the rest — both
+    orientations' tables, a panel of gathered rows at its share, and the
+    dense side and the output of a 128-wide product three times over (the
+    aliased scatter's buffers) — leaves less."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    rest = (2 * pc.TABLE_BYTES_A_SLOT * left + pc._PANEL_SHARE * limit
+            + 3 * 4 * pc.WIDE_COLS * (shape[0] + shape[1]))
+    return np.minimum(_DENSE_SHARE * limit, limit - rest)
+
+
+def _dense_groups(deg: np.ndarray, length: int, nnz: int, shape, limit: int,
+                  dtype: str):
+    """How many of ``deg``'s lines (of ``length`` cells of ``dtype``
+    each), taken in order of degree in groups of ``_DENSE_GROUP``,
+    pay as dense lines and fit: a group is taken while its entries cost
+    the compact path more than 128 dense lines cost the MXU, and the
+    slab with it stays in the room :func:`_dense_room` leaves. Returns
+    (the lines' ids in order of degree, the entries they hold)."""
+    order = np.argsort(-deg, kind="stable")
+    held = np.add.reduceat(deg[order], np.arange(0, order.size, _DENSE_GROUP))
+    groups = np.arange(1, held.size + 1)
+    pays = held * _SPARSE_NS_AN_ENTRY > (
+        _DENSE_GROUP * length * _DENSE_NS_A_CELL[dtype])
+    cells = 1.0 * length * _DENSE_GROUP * groups
+    # XLA's scatter, which fills the slab, indexes its cells in int32
+    fits = (cells < 2 ** 31) & (
+        np.dtype(dtype).itemsize * cells <= _dense_room(
+            limit, shape, nnz - np.cumsum(held)))
+    ok = pays & fits
+    take = int(ok.size if ok.all() else np.argmin(ok))
+    return order[:take * _DENSE_GROUP], int(held[:take].sum())
+
+
+def _bfloat16_holds(vals: np.ndarray) -> bool:
+    """Whether every value is exactly a bfloat16's and none is zero (a
+    slab of such values, no cell repeated, is exact in bfloat16, and its
+    cells that are not zero count its entries)."""
+    bits = np.ascontiguousarray(vals, np.float32).view(np.uint32)
+    return bool(vals.size) and not (bits & 0xFFFF).any() and bool(
+        (bits << 1).all())
+
+
+def _choose_dense_lines(rows, cols, vals, shape, flipped: bool,
+                        exact: bool = True) -> Optional[DenseLines]:
+    """The dense lines of a matrix, from its degrees alone: of the two
+    axes the one whose paying lines hold more entries (the columns on a
+    tie); None where no group of lines pays. A bfloat16 slab (twice the
+    lines in the bytes) where ``exact`` and :func:`_bfloat16_holds`.
+    ``flipped``: the matrix is a transpose view, whose memo is the
+    first-built matrix's — the axis is recorded as that one has it."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    limit = pc._hbm_limit()
+    dtype = "bfloat16" if exact and _bfloat16_holds(vals) else "float32"
+    best = None
+    for axis, ids in ((1, cols), (0, rows)):
+        deg = np.bincount(ids, minlength=shape[axis])
+        lines, entries = _dense_groups(deg, shape[1 - axis], rows.size,
+                                       shape, limit, dtype)
+        if entries and (best is None or entries > best.entries):
+            lines = np.sort(lines[deg[lines] > 0])
+            column_of = np.full(shape[axis], -1, np.int32)
+            column_of[lines] = np.arange(lines.size, dtype=np.int32)
+            best = DenseLines(axis=axis ^ flipped, lines=lines,
+                              column_of=column_of, entries=entries,
+                              dtype=dtype)
+    return best
+
+
 @dataclasses.dataclass
 class PanelledPlan:
-    """An orientation's plan for the k-wide product where the dense side
-    has too many rows for one gather table (``spmv.source_panels``):
-    ``parts`` = ((col0, EdgeSpMVPlan), ...), a plan each over the
-    sources ``col0 : col0 + plan.n_cols``, all over the same rows."""
+    """An orientation's plan for the k-wide product where it is more
+    than one compact plan: ``parts`` = ((col0, EdgeSpMVPlan), ...), a
+    plan each over the sources ``col0 : col0 + plan.n_cols`` (the ranges
+    of ``spmv.source_panels`` where the dense side has too many rows for
+    one gather table), all over the same rows; and, where the matrix has
+    one, its ``dense`` part, whose lines are this product's
+    ``dense_role``, its "sources" or its "destinations", and the
+    ``dense_axis`` ("rows" / "columns") of the matrix asked for the
+    plan. The parts then hold the entries of every other line."""
     n_rows: int
     n_cols: int
     block: int
     parts: tuple
+    dense: Optional[DenseLines] = None
+    dense_role: str = ""
+    dense_axis: str = ""
 
 
 def plan_parts(plan) -> tuple:
@@ -95,25 +282,34 @@ def plan_parts(plan) -> tuple:
 
 def plan_facts(plan, entries: int) -> dict:
     """What plan.meta["spmm"] and a ``matrel.spmm.plan`` span say of a
-    plan of either kind: ``layout``, ``entries``, ``slots``, ``chunks``
-    (table rows), ``source_panels`` and ``table`` (the k-wide gather
-    table's form: ``hbm``, one table whole, or ``panelled``),
-    ``overflow_edges``, and for the k-wide product on one device its
-    ``panels`` (of table rows, over all parts), ``plan_bytes`` (the
-    tables and the largest panel's temporaries,
-    pallas_spmv.wide_plan_bytes) and ``windowed_chunks``: of the chunks
-    its scatter walks (``chunks`` in the chunks layout; a blocks-layout
-    row is walked as several), those whose one-hot is a 128-row window
-    and not the block (pallas_spmv.wide_windows; 0 where none is)."""
+    plan of either kind (``entries``: the matrix's nnz): ``layout``,
+    ``entries`` (those LAID OUT IN SLOTS: all of them but the dense
+    part's), ``slots``, ``chunks`` (table rows), ``source_panels`` and
+    ``table`` (the k-wide gather table's form: ``hbm``, one table whole,
+    or ``panelled``), ``overflow_edges``, and for the k-wide product on
+    one device its ``panels`` (of table rows, over all parts),
+    ``plan_bytes`` (the tables, the largest panel's temporaries,
+    pallas_spmv.wide_plan_bytes, and the slab, once) and
+    ``windowed_chunks``: of the chunks its scatter walks (``chunks`` in
+    the chunks layout; a blocks-layout row is walked as several), those
+    whose one-hot is a 128-row window and not the block
+    (pallas_spmv.wide_windows; 0 where none is). Where the matrix has a
+    dense part (:class:`DenseLines`), also ``dense_lines``,
+    ``dense_axis`` (``rows`` / ``columns`` of the matrix the product
+    names), ``dense_entries`` (``entries + dense_entries`` is the nnz),
+    ``dense_bytes`` and ``dense_dtype`` — the same for both
+    orientations, which share the one slab; absent where no line paid."""
     from matrel_tpu.ops import pallas_spmv as pc
     parts = [p for _, p in plan_parts(plan)]
     shapes = [np.asarray(p.src8).shape for p in parts]
     slots = sum(r * c for r, c in shapes)
     per = [pc.wide_panel_rows(r, c) for r, c in shapes]
-    return {
+    dense = getattr(plan, "dense", None)
+    facts = {
         "layout": ("chunks" if parts[0].chunk_block is not None
                    else "blocks"),
-        "entries": int(entries), "slots": int(slots),
+        "entries": int(entries) - (0 if dense is None else dense.entries),
+        "slots": int(slots),
         "chunks": int(sum(r for r, _ in shapes)),
         "windowed_chunks": sum(pc.wide_windows(p)[1] for p in parts),
         "source_panels": len(parts),
@@ -122,8 +318,16 @@ def plan_facts(plan, entries: int) -> dict:
                               else int(p.ov_rows.shape[0]) for p in parts),
         "panels": int(sum(-(-r // n) for (r, _), n in zip(shapes, per))),
         "plan_bytes": int(pc.TABLE_BYTES_A_SLOT * slots + max(
-            pc.wide_panel_bytes(r, c) for r, c in shapes)),
+            pc.wide_panel_bytes(r, c) for r, c in shapes)
+            + (0 if dense is None else dense.slab.nbytes)),
     }
+    if dense is not None:
+        facts.update(dense_lines=int(dense.lines.size),
+                     dense_axis=plan.dense_axis,
+                     dense_entries=int(dense.entries),
+                     dense_bytes=int(dense.slab.nbytes),
+                     dense_dtype=dense.dtype)
+    return facts
 
 
 @dataclasses.dataclass
@@ -144,6 +348,11 @@ class COOMatrix:
     # one-slot list each, shared with the transpose view
     _wide: list = dataclasses.field(default_factory=list, repr=False)
     _wide_t: list = dataclasses.field(default_factory=list, repr=False)
+    # the k-wide plans' dense part, or None, once it was asked for: a
+    # one-slot list shared with the transpose view, which reads its axis
+    # flipped
+    _dense: list = dataclasses.field(default_factory=list, repr=False)
+    _flipped: bool = dataclasses.field(default=False, repr=False)
     # fallback-path caches: (device out_ids, device in_ids, device vals),
     # sorted by out_ids — fixed per matrix, built once per direction
     _seg_fwd: Optional[tuple] = dataclasses.field(default=None, repr=False)
@@ -203,6 +412,7 @@ class COOMatrix:
                          _plan_tried=self._plan_t_tried,
                          _plan_t_tried=self._plan_tried,
                          _wide=self._wide_t, _wide_t=self._wide,
+                         _dense=self._dense, _flipped=not self._flipped,
                          _seg_fwd=self._seg_bwd, _seg_bwd=self._seg_fwd,
                          _coalesced=self._coalesced)
 
@@ -234,39 +444,86 @@ class COOMatrix:
             self._plan_t_tried = True
         return self._plan_t
 
+    def _dense_lines(self, exact: bool = True) -> Optional[DenseLines]:
+        """The k-wide plans' dense part, chosen once from this matrix's
+        own degrees and values (its slab is the first wide plan's build
+        to fill; ``exact`` false: chosen again, in float32, by a build
+        whose bfloat16 slab met a repeated cell); None where no line
+        pays."""
+        if not self._dense or not exact:
+            self._dense[:] = [_choose_dense_lines(
+                self.rows, self.cols, self.vals, self.shape, self._flipped,
+                exact)]
+        return self._dense[0]
+
     def _get_wide_plan(self, transposed: bool = False):
         """The plan the k-wide product A·X (``transposed``: Aᵀ·X) runs:
-        the orientation's own where X's rows make one gather table, else
-        a :class:`PanelledPlan` over ranges of them, built once; None
-        where the planner refused the matrix."""
+        the orientation's own where X's rows make one gather table and
+        no line of the matrix is dense, else a :class:`PanelledPlan`
+        (over ranges of X's rows, beside the dense part), built once;
+        None where the planner refused the matrix."""
         n_out, n_in = self.shape[::-1] if transposed else self.shape
-        panels = spmv_lib.source_panels(n_in)
-        if panels == 1 or _plan_layout() != "auto":
-            return self._get_plan_t() if transposed else self._get_plan()
+        own = self._get_plan_t if transposed else self._get_plan
+        if _plan_layout() != "auto":
+            return own()
         memo = self._wide_t if transposed else self._wide
-        if not memo:
-            out_ids, in_ids = ((self.cols, self.rows) if transposed
-                               else (self.rows, self.cols))
-            # ranges of whole table rows, as even as they come
-            width = -(-n_in // (panels * spmv_lib.WIDTH)) * spmv_lib.WIDTH
+        if memo:
+            return memo[0]
+        panels = spmv_lib.source_panels(n_in)
+        if panels == 1 and self._dense_lines() is None:
+            return own()
+        out_ids, in_ids = ((self.cols, self.rows) if transposed
+                           else (self.rows, self.cols))
+        # ranges of whole table rows, as even as they come
+        width = -(-n_in // (panels * spmv_lib.WIDTH)) * spmv_lib.WIDTH
 
-            def build():
-                parts = []
-                for col0 in range(0, n_in, width):
-                    n_part = min(width, n_in - col0)
-                    sel = np.flatnonzero((in_ids >= col0)
-                                         & (in_ids < col0 + n_part))
-                    parts.append((col0, self._build(
-                        out_ids, in_ids, n_out, n_part, sel, col0)))
-                return parts
+        def entries_left():
+            """Which entries stay in the tables: all but the dense
+            lines', whose slab this build fills where none has."""
+            dense = self._dense_lines()
+            if dense is None:
+                return None
+            # the lines' axis as this view has it (1: its columns)
+            axis = dense.axis ^ self._flipped
+            ids, others = ((self.cols, self.rows) if axis
+                           else (self.rows, self.cols))
+            mine = dense.holds(ids)
+            if dense.slab is None:
+                at = np.flatnonzero(mine)
+                with jax.ensure_compile_time_eval():
+                    if not dense.fill(ids[at], others[at], self.vals[at],
+                                      self.shape[1 - axis]):
+                        self._dense_lines(exact=False)
+                        return entries_left()
+            return ~mine
 
-            parts = _counted_build(
-                build, source_panels=panels,
-                orientation="transposed" if transposed else "forward")
-            memo.append(None if any(p is None for _, p in parts)
-                        else PanelledPlan(n_rows=n_out, n_cols=n_in,
-                                          block=parts[0][1].block,
-                                          parts=tuple(parts)))
+        def build():
+            keep = entries_left()
+            parts = []
+            for col0 in range(0, n_in, width):
+                n_part = min(width, n_in - col0)
+                here = (in_ids >= col0) & (in_ids < col0 + n_part)
+                sel = np.flatnonzero(here if keep is None else here & keep)
+                parts.append((col0, self._build(
+                    out_ids, in_ids, n_out, n_part, sel, col0)))
+            return parts
+
+        said = {"source_panels": panels} if panels > 1 else {}
+        parts = _counted_build(
+            build, orientation="transposed" if transposed else "forward",
+            **said)
+        dense, about = self._dense_lines(), {}
+        if dense is not None:
+            axis = dense.axis ^ self._flipped
+            # A·X reads its sources off A's columns, Aᵀ·X off its rows
+            about = {"dense": dense,
+                     "dense_role": ("sources" if bool(axis) != transposed
+                                    else "destinations"),
+                     "dense_axis": "columns" if axis else "rows"}
+        memo.append(None if any(p is None for _, p in parts)
+                    else PanelledPlan(
+                        n_rows=n_out, n_cols=n_in, block=parts[0][1].block,
+                        parts=tuple(parts), **about))
         return memo[0]
 
     def shard(self, mesh) -> "COOMatrix":

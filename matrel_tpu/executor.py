@@ -472,7 +472,8 @@ class Lowerer:
         the expanded one-hot tables are never built (17× less HBM); CPU
         keeps the expanded XLA path. One column takes the matvec kernel;
         more the k-wide SpMM (a slot's row of X gathered once for all
-        its columns), over every source panel of a PanelledPlan."""
+        its columns), over every source panel of a PanelledPlan, and its
+        dense part on the MXU where the matrix has one (PR 43)."""
         from matrel_tpu.config import pallas_enabled, pallas_interpret_mode
         from matrel_tpu.core.coo import plan_parts
         from matrel_tpu.ops import spmv as spmv_lib
@@ -493,7 +494,7 @@ class Lowerer:
             interp = pallas_interpret_mode(self.config)
             static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
             if self.mesh.size == 1:
-                if k == 1 and len(parts) == 1:
+                if k == 1 and not hasattr(plan, "parts"):
                     return pc.compact_apply(static, pc.compact_tables(plan),
                                             plan.overflow, X[:, 0],
                                             interpret=interp)[:, None]

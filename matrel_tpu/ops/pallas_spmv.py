@@ -62,6 +62,7 @@ shard_map variants below are the multi-device form). Measured trade
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -645,6 +646,12 @@ def spmv_compact_sharded(plan: spmv_lib.EdgeSpMVPlan, x: jax.Array,
 # holds 209 ratings, a movie 5,654) and at most ``block / 120`` chunks
 # of a block spread further; a plan in input order reads −1 throughout
 # and runs the body it always ran.
+#
+# A plan may bring a DENSE PART beside its compact parts (PR 43,
+# core.coo.DenseLines): the lines of one axis that hold more entries than
+# a dense line costs lie in one slab on the device and not in the tables.
+# ``_dense_part`` adds their product on the MXU; gather and scatter, which
+# cost by the entry, see what is left.
 
 WIDE_COLS = LANE        # columns a pass of the k-wide product takes
 # What a panel of the k-wide product keeps alive a slot, read off the
@@ -860,6 +867,49 @@ def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool):
     return y
 
 
+def _dense_part(Y, role: str, slab, lines, X):
+    """``Y`` plus a plan's dense part (core.coo.DenseLines: ``slab``
+    (n, lines up to whole groups of 128), column j the line
+    ``lines[j]``) times ``X``: where the lines are the product's
+    ``"sources"``, ``slab @ X[lines]``; where its ``"destinations"``,
+    ``slabᵀ · X`` added into the rows ``lines``, the slab read as it
+    lies. On the MXU with float32 sums, a long contraction in panels
+    (``strategies.dot_in_panels``: one dot over 480k rows drifts): a
+    float32 slab at ``highest`` whatever the caller's config asks of its
+    own dots (the compact part is float32-faithful whatever it asks); a
+    bfloat16 slab, which holds its values exactly, against the dense
+    side's three bfloat16 parts side by side — the passes of ``highest``
+    that are not zero, in one reading of the slab."""
+    from matrel_tpu.config import default_config
+    from matrel_tpu.parallel import strategies
+
+    def dot(a, ca, b):
+        # contracted over ``a``'s dimension ``ca`` and ``b``'s rows
+        if a.shape[ca] >= strategies.LONG_CONTRACTION:
+            return strategies.dot_in_panels(a, ca, b, 0, dataclasses.replace(
+                default_config(), matmul_precision="highest"))
+        return jax.lax.dot_general(
+            a, b, (((ca,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    def times(ca, b):
+        if slab.dtype == jnp.float32:
+            return dot(slab, ca, b)
+        k = b.shape[1]
+        parts = dot(slab, ca, jnp.concatenate(
+            [p.astype(jnp.bfloat16) for p in _bf16_split(b, 3)], axis=1))
+        return parts[:, :k] + parts[:, k:2 * k] + parts[:, 2 * k:]
+
+    Xf = X.astype(jnp.float32)
+    n = lines.shape[0]
+    if role == "sources":
+        mine = Xf.at[lines].get(mode="promise_in_bounds")
+        return Y + times(1, jnp.pad(mine, ((0, slab.shape[1] - n), (0, 0))))
+    return Y.at[lines].add(times(0, Xf)[:n], indices_are_sorted=True,
+                           unique_indices=True, mode="promise_in_bounds")
+
+
 def compact_matmat_parts(plan_static, part_statics, part_arrays,
                          X: jax.Array, passes: int = 3,
                          interpret: bool = False) -> jax.Array:
@@ -869,7 +919,13 @@ def compact_matmat_parts(plan_static, part_statics, part_arrays,
     sums: ``part_statics`` = ((col0, its plan_static), ...) and
     ``part_arrays`` = ((its compact_tables(), its overflow, its
     wide_windows()[0] or None), ...). More than 128 columns run 128 at a
-    time."""
+    time. A plan's dense part rides as a last part, (role, None) and
+    (slab, lines), and adds :func:`_dense_part`; ``passes`` governs the
+    compact parts."""
+    dense = None
+    if part_statics and isinstance(part_statics[-1][0], str):
+        dense = (part_statics[-1][0],) + tuple(part_arrays[-1])
+        part_statics, part_arrays = part_statics[:-1], part_arrays[:-1]
     n_rows, _, block, _ = plan_static
     nb = -(-n_rows // block)
     k = X.shape[1]
@@ -893,7 +949,7 @@ def compact_matmat_parts(plan_static, part_statics, part_arrays,
         if ov:
             Y = spmv_lib._overflow_add_wide(Y, ov, X[col0:col0 + n_cols],
                                             n_rows)
-    return Y
+    return Y if dense is None else _dense_part(Y, *dense, X)
 
 
 def compact_matmat_apply(plan_static, tables, ov, X: jax.Array,
@@ -915,15 +971,20 @@ def plan_operands(plan):
     """(plan_static, part_statics, part_arrays) of an EdgeSpMVPlan or of
     a plan in source panels (anything with ``parts`` = ((col0, plan),
     ...), ``n_rows``, ``n_cols``, ``block``: core.coo.PanelledPlan), as
-    :func:`compact_matmat_parts` takes them; the parts' tables and
-    windows move to the device on first use."""
+    :func:`compact_matmat_parts` takes them, the plan's ``dense`` part,
+    where it has one, last; the parts' tables and windows move to the
+    device on first use."""
     parts = getattr(plan, "parts", None) or ((0, plan),)
     static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
-    return (static,
-            tuple((col0, (p.n_rows, p.n_cols, p.block, spmv_lib.LO))
-                  for col0, p in parts),
-            tuple((compact_tables(p), p.overflow, wide_windows(p)[0])
-                  for _, p in parts))
+    statics = tuple((col0, (p.n_rows, p.n_cols, p.block, spmv_lib.LO))
+                    for col0, p in parts)
+    arrays = tuple((compact_tables(p), p.overflow, wide_windows(p)[0])
+                   for _, p in parts)
+    dense = getattr(plan, "dense", None)
+    if dense is not None:
+        statics += ((plan.dense_role, None),)
+        arrays += ((dense.slab, dense.lines_dev),)
+    return static, statics, arrays
 
 
 def spmm_compact(plan, X: jax.Array, passes: int = 3,

@@ -205,6 +205,89 @@ def test_gnmf_transposed_product_gathers_from_source_panels(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 5.0e9
 
 
+# The same matrix with its dense part (PR 43): the 2,176 hottest movie
+# columns (25% of the chip's bytes_limit: 17 groups of 128) in one float32
+# slab, 83.3% of the ratings; the compact plans hold the other 16.8M
+# entries in chunks of 2,048 slots.
+NF_LINES, NF_REST_CHUNKS = 2_176, 8_300
+
+
+def _cycles_ms(text, shape):
+    """Milliseconds at 1.45 GHz of the fusions of ``text`` whose result
+    is ``shape`` and whose root is a dot, by the compiler's own
+    ``estimated_cycles`` (PR 34)."""
+    out = []
+    for line in text.splitlines():
+        m = re.search(r'"estimated_cycles":"?(\d+)', line)
+        if m and f"= {shape}" in line and "dot_general" in line:
+            out.append(int(m.group(1)) / 1.45e6)
+    return out
+
+
+def test_gnmf_products_with_a_slab(one_chip, monkeypatch):
+    """Both products of a GNMF iteration over plans with a dense part, at
+    the cell's shapes: the slab is an argument read where it lies (no
+    transposed or relaid copy among the temporaries), the memory is what
+    ``plan_facts`` reckons (tables, a panel, the slab once), and the two
+    dense parts cost what the MXU takes for them: the forward one ONE
+    fused dot, the transposed one a loop of 58 panels of 8,192 users
+    and a tail, each within twice the six-pass floor (8.1 ms)."""
+    monkeypatch.setattr(pc, "_hbm_limit", lambda: int(15.75 * 2 ** 30))
+    slab = _sds(one_chip, (NF_USERS, NF_LINES), jnp.float32)
+    lines = _sds(one_chip, (NF_LINES,), jnp.int32)
+    floor_ms = 2 * NF_USERS * NF_LINES * NF_RANK * 6 / 197e12 * 1e3
+
+    def wins(chunks):
+        return (_sds(one_chip, (chunks,), jnp.int32),)
+
+    # V * t(H): the lines are sources
+    static = (NF_USERS, NF_MOVIES, BLOCK, spmv_lib.LO)
+    fwd = _compile(
+        pc._compact_matmat_jitted, static,
+        ((0, static), ("sources", None)),
+        ((_chunk_table_shapes(NF_REST_CHUNKS, one_chip), (),
+          wins(NF_REST_CHUNKS)), (slab, lines)),
+        _sds(one_chip, (NF_MOVIES, NF_RANK), jnp.float32), 3, False)
+    stats = fwd.memory_analysis()
+    out = stats.output_size_in_bytes
+    reckoned = (pc.wide_plan_bytes(NF_REST_CHUNKS, spmv_lib.CHUNK)
+                + 4 * NF_USERS * NF_LINES + 3 * out)
+    taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 0.9 * reckoned < taken < 1.05 * reckoned, (reckoned, taken)
+    (dot_ms,) = _cycles_ms(fwd.as_text(), f"f32[{NF_USERS},128]")
+    assert floor_ms < dot_ms < 2 * floor_ms, dot_ms
+
+    # t(V) * W: the lines are destinations, W in four source panels
+    static = (NF_MOVIES, NF_USERS, BLOCK, spmv_lib.LO)
+    statics = tuple(
+        (c0, (NF_MOVIES, min(NF_PANEL_USERS, NF_USERS - c0), BLOCK,
+              spmv_lib.LO))
+        for c0 in range(0, NF_USERS, NF_PANEL_USERS))
+    per = NF_REST_CHUNKS // 4
+    bwd = _compile(
+        pc._compact_matmat_jitted, static,
+        statics + (("destinations", None),),
+        tuple((_chunk_table_shapes(per, one_chip), (), wins(per))
+              for _ in statics) + ((slab, lines),),
+        _sds(one_chip, (NF_USERS, NF_RANK), jnp.float32), 3, False)
+    stats = bwd.memory_analysis()
+    # no second slab: the temporaries are two source panels' gathered
+    # rows (the next one's gather under this one's scatter)
+    rows = pc._TEMP_BYTES_A_SLOT_WIDE * per * spmv_lib.CHUNK
+    assert stats.temp_size_in_bytes < 2.1 * rows
+    assert stats.argument_size_in_bytes + stats.temp_size_in_bytes < \
+        int(15.75 * 2 ** 30)
+    text = bwd.as_text()
+    assert f"f32[{NF_LINES},{NF_USERS}]" not in text       # no transpose
+    assert not re.search(rf"= f32\[{NF_USERS},{NF_LINES}\]\S* "
+                         r"(copy|transpose)\(", text)
+    panel_ms = _cycles_ms(text, f"f32[{NF_LINES},128]")
+    assert len(panel_ms) == 2                   # the loop's body, the tail
+    whole, tail = divmod(NF_USERS, strategies.ACC_PANEL_ROWS)
+    loop_ms = max(panel_ms) * whole + min(panel_ms)
+    assert tail and floor_ms < loop_ms < 2 * floor_ms, panel_ms
+
+
 def test_compact_spmv_sharded_2x2(mesh_2x2):
     mesh = mesh_2x2
     axes = tuple(mesh.axis_names)

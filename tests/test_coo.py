@@ -625,6 +625,9 @@ class TestWidePlans:
         config_lib.set_default_config(MatrelConfig(pallas_interpret=True))
         monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")
         monkeypatch.setattr(spmv_lib, "_SMALL_PLAN_SLOTS", 0)
+        # no room for a slab: at this length a line pays from 10 entries
+        # on (TestDenseLines gives the dense part room)
+        monkeypatch.setattr(coo_lib, "_DENSE_SHARE", 0.0)
         yield
         config_lib._default_config = was
 
@@ -776,3 +779,391 @@ class TestWidePlans:
         X = rng.standard_normal((900, 3)).astype(np.float32)
         np.testing.assert_allclose(np.asarray(A.matmat(X)), dense @ X,
                                    rtol=2e-5, atol=2e-4)
+
+
+def _tables_hash(plan):
+    """sha256 of a wide plan's statics and compact host tables."""
+    import hashlib
+    from matrel_tpu.core import coo as coo_lib
+    h = hashlib.sha256()
+    for col0, p in coo_lib.plan_parts(plan):
+        h.update(str((col0, p.n_rows, p.n_cols, p.block)).encode())
+        for a in (p.src8, p.lane, p.off, p.val, p.chunk_block):
+            h.update(b"-" if a is None
+                     else np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+def _program_hash(plan, n_in):
+    """sha256 of the k-wide product's program lowered for the chip, the
+    Mosaic kernels' serialized bodies and the source locations (file
+    paths, line numbers) taken out."""
+    import hashlib
+    import re
+    import jax
+    import jax.numpy as jnp
+    from matrel_tpu.ops import pallas_spmv as pc
+    static, statics, arrays = pc.plan_operands(plan)
+    text = jax.jit(lambda pa, x: pc.compact_matmat_parts(
+        static, statics, pa, x, 3, False)).trace(
+        arrays, jax.ShapeDtypeStruct((n_in, 128), jnp.float32)
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    text = re.sub(r'backend_config = "[^"]*"', "", text)
+    return hashlib.sha256(re.sub(r"loc\([^)]*\)", "", text).encode()
+                          ).hexdigest(), text
+
+
+class TestDenseLines:
+    """The dense part of a COOMatrix's k-wide plans (PR 43): the lines of
+    one axis that hold more entries than a dense line costs lie in ONE
+    float32 slab, shared by both orientations and the transpose view and
+    multiplied on the MXU; the compact plans hold the rest; a matrix no
+    line of which pays keeps the plans it had."""
+
+    SHAPE, HOT = (2600, 4000), 128
+
+    @pytest.fixture
+    def on_one_chip(self, monkeypatch):
+        """As TestWidePlans', with room for a slab, and a gather table
+        of 3,000 rows at the most (the 4,000 columns are two source
+        panels, the 2,600 rows one)."""
+        from matrel_tpu import config as config_lib
+        from matrel_tpu.config import MatrelConfig
+        from matrel_tpu.core import coo as coo_lib
+        from matrel_tpu.ops import spmv as spmv_lib
+        was = config_lib._default_config
+        config_lib.set_default_config(MatrelConfig(pallas_interpret=True))
+        monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")
+        monkeypatch.setattr(spmv_lib, "_SMALL_PLAN_SLOTS", 0)
+        monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 3000 * 512)
+        yield
+        config_lib._default_config = was
+
+    def _hot_columns(self, rng, repeats=0):
+        """128 hot columns of ~200 entries (a column of 2,600 cells pays
+        from 10 on) over a tail of 3,000 entries, under one a column (a
+        row of 4,000 cells pays from 15 on; a row holds 11, and the few
+        groups of rows that do pay hold a tenth of what the columns do);
+        values that no bfloat16 holds; ``repeats`` cells listed twice."""
+        n, m = self.SHAPE
+        hot = rng.choice(m, self.HOT, replace=False)
+        rows = rng.integers(0, n, 28_600)
+        cols = np.concatenate([hot[rng.integers(0, self.HOT, 25_600)],
+                               rng.integers(0, m, 3_000)])
+        if repeats:
+            again = rng.integers(0, rows.size, repeats)
+            rows = np.concatenate([rows, rows[again]])
+            cols = np.concatenate([cols, cols[again]])
+        return hot, COOMatrix.from_edges(
+            rows, cols, rng.standard_normal(rows.size).astype(np.float32),
+            shape=self.SHAPE)
+
+    def test_one_slab_for_both_orientations_and_the_view(self, rng,
+                                                         on_one_chip):
+        from matrel_tpu.core import coo as coo_lib
+        hot, A = self._hot_columns(rng)
+        builds = coo_lib.plan_builds()
+        fwd = A._get_wide_plan()
+        bwd = A._get_wide_plan(transposed=True)
+        assert coo_lib.plan_builds() == builds + 2   # one an orientation
+        assert fwd.dense is bwd.dense is A._dense_lines()
+        assert (fwd.dense_role, bwd.dense_role) == ("sources",
+                                                    "destinations")
+        dense = fwd.dense
+        assert dense.axis == 1 and set(dense.lines) == set(hot)
+        assert dense.slab.shape == (2600, 128)
+        assert dense.slab.dtype == np.float32
+        # the view shares plans and slab, and reads the axis flipped
+        assert A.T._get_wide_plan() is bwd
+        assert A.T._get_wide_plan(transposed=True) is fwd
+        assert A.T._dense_lines() is dense
+        assert coo_lib.plan_builds() == builds + 2
+        held = int(np.isin(A.cols, hot).sum())
+        for plan, panels in ((fwd, 2), (bwd, 1)):
+            facts = coo_lib.plan_facts(plan, A.nnz)
+            assert facts["entries"] + facts["dense_entries"] == A.nnz
+            assert facts["dense_entries"] == held > 0.85 * A.nnz
+            assert facts["dense_lines"] == 128
+            assert facts["dense_axis"] == "columns"
+            assert facts["dense_bytes"] == 4 * 2600 * 128
+            assert facts["dense_dtype"] == "float32"
+            assert facts["source_panels"] == panels
+            # the slots hold the residual: a padding, not a share
+            assert facts["entries"] <= facts["slots"]
+            assert sum(int((np.asarray(p.val) != 0).sum())
+                       for _, p in plan.parts) == A.nnz - held
+            # tables, the largest panel, the slab once
+            assert facts["plan_bytes"] >= facts["dense_bytes"] + 13 * \
+                facts["slots"]
+            assert facts["plan_bytes"] < 2 * facts["dense_bytes"] + \
+                (13 + 536) * facts["slots"]
+        # the slab is the hot columns, summed in float32
+        want = A.to_dense()[:, dense.lines]
+        np.testing.assert_allclose(np.asarray(dense.slab), want, atol=1e-6)
+
+    @pytest.mark.parametrize("k", [8, 128])
+    @pytest.mark.parametrize("how", ["forward", "transposed", "view"])
+    def test_products_match_float64(self, rng, on_one_chip, k, how):
+        _, A = self._hot_columns(rng)
+        D = A.to_dense().astype(np.float64)
+        if how == "forward":
+            X = rng.standard_normal((4000, k)).astype(np.float32)
+            got, want = A.matmat(X), D @ X
+        else:
+            X = rng.standard_normal((2600, k)).astype(np.float32)
+            got = (A.T.matmat(X) if how == "view" else
+                   self._transposed_product(A, X))
+            want = D.T @ X
+        assert np.abs(np.asarray(got) - want).max() / np.abs(want).max() \
+            < 1e-6
+
+    @staticmethod
+    def _transposed_product(A, X):
+        from matrel_tpu.ops import pallas_spmv as pc
+        return pc.spmm_compact(A._get_wide_plan(transposed=True), X)
+
+    def test_repeated_cells_add(self, rng, on_one_chip):
+        """A coordinate list may repeat a cell: its values add, in the
+        slab as in the tables."""
+        from matrel_tpu.core import coo as coo_lib
+        _, A = self._hot_columns(rng, repeats=4_000)
+        keys = A.rows * 4000 + A.cols
+        assert np.unique(keys).size < keys.size - 3_000
+        D = A.to_dense().astype(np.float64)
+        X = rng.standard_normal((4000, 8)).astype(np.float32)
+        Z = rng.standard_normal((2600, 8)).astype(np.float32)
+        for got, want in ((A.matmat(X), D @ X), (A.T.matmat(Z), D.T @ Z)):
+            assert np.abs(np.asarray(got) - want).max() / \
+                np.abs(want).max() < 1e-6
+        facts = coo_lib.plan_facts(A._get_wide_plan(), A.nnz)
+        assert facts["entries"] + facts["dense_entries"] == A.nnz
+
+    def test_passes_govern_the_compact_part_of_the_whole_product(
+            self, rng, on_one_chip):
+        """``spmm_compact(plan, X, passes=2)`` on a plan with a dense
+        part is still the whole product (the benchmark's program
+        controls call it so): the dense part at ``highest``, the compact
+        part at the passes asked for."""
+        from matrel_tpu.ops import pallas_spmv as pc
+        _, A = self._hot_columns(rng)
+        D = A.to_dense().astype(np.float64)
+        X = rng.standard_normal((2600, 16)).astype(np.float32)
+        plan = A._get_wide_plan(transposed=True)
+        want = D.T @ X
+        err = {p: np.abs(np.asarray(pc.spmm_compact(plan, X, passes=p))
+                         - want).max() / np.abs(want).max()
+               for p in (3, 2, 1)}
+        assert err[3] < 1e-6 < err[2] < 1e-4 < err[1] < 2e-2
+
+    def test_a_long_contraction_adds_in_panels(self, rng, on_one_chip,
+                                               monkeypatch):
+        """A matrix taller than ``LONG_CONTRACTION``: where the dense
+        lines are the product's destinations the slab's 140,000 rows are
+        contracted in panels of 8,192 (one dot over them drifts on the
+        MXU, PR 31) and the answer is float64's to 1e-6."""
+        import jax
+        import jax.numpy as jnp
+        from matrel_tpu.ops import pallas_spmv as pc
+        from matrel_tpu.ops import spmv as spmv_lib
+        from matrel_tpu.parallel import strategies
+        monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 64 << 20)
+        n, m = 140_000, 2_000
+        assert n > strategies.LONG_CONTRACTION
+        hot = rng.choice(m, 128, replace=False)
+        rows = rng.integers(0, n, 135_000)
+        cols = np.concatenate([hot[rng.integers(0, 128, 128_000)],
+                               rng.integers(0, m, 7_000)])
+        A = COOMatrix.from_edges(
+            rows, cols, rng.integers(1, 6, rows.size).astype(np.float32),
+            shape=(n, m))
+        plan = A._get_wide_plan(transposed=True)
+        assert plan.dense_role == "destinations"
+        assert plan.dense.slab.shape == (n, 128)
+        X = rng.random((n, 8), dtype=np.float32)
+        want = np.zeros((m, 8))
+        np.add.at(want, A.cols, A.vals[:, None].astype(np.float64) * X[A.rows])
+        got = np.asarray(pc.spmm_compact(plan, X))
+        assert np.abs(got - want).max() / np.abs(want).max() < 1e-6
+        static, statics, arrays = pc.plan_operands(plan)
+        text = jax.jit(lambda pa, x: pc.compact_matmat_parts(
+            static, statics, pa, x, 3, False)).trace(
+            arrays, jax.ShapeDtypeStruct((n, 8), jnp.float32)
+        ).lower(lowering_platforms=("tpu",)).as_text()
+        assert f"{strategies.ACC_PANEL_ROWS}x128xf32" in text   # a panel
+        assert "stablehlo.while" in text
+
+    def _starred(self, rng, repeats=0, zeros=0):
+        """``_hot_columns`` with distinct cells and values 1 to 5, every
+        one a bfloat16's; then ``repeats`` of the cells listed twice, or
+        ``zeros`` of the values zero."""
+        n, m = self.SHAPE
+        _, A = self._hot_columns(rng)
+        keys = np.unique(A.rows * m + A.cols)
+        vals = rng.integers(1, 6, keys.size).astype(np.float32)
+        vals[:zeros] = 0
+        keys = np.concatenate([keys, keys[:repeats]])
+        vals = np.concatenate([vals, vals[:repeats]])
+        return COOMatrix.from_edges(keys // m, keys % m, vals,
+                                    shape=self.SHAPE)
+
+    @pytest.mark.parametrize("k", [8, 128])
+    def test_values_a_bfloat16_holds_lie_in_a_bfloat16_slab(self, rng,
+                                                            on_one_chip, k):
+        """Ratings 1 to 5 in distinct cells: the slab is bfloat16, exact,
+        a line half the bytes (and the MXU's passes) of a float32 one;
+        times the dense side's three bfloat16 parts it gives float64's
+        product to 1e-6 in both orientations."""
+        from matrel_tpu.core import coo as coo_lib
+        A = self._starred(rng)
+        fwd = A._get_wide_plan()
+        bwd = A._get_wide_plan(transposed=True)
+        dense = fwd.dense
+        assert dense is bwd.dense and dense.dtype == "bfloat16"
+        assert str(dense.slab.dtype) == "bfloat16"
+        facts = coo_lib.plan_facts(fwd, A.nnz)
+        assert facts["dense_dtype"] == "bfloat16"
+        assert facts["dense_lines"] == 128
+        assert facts["dense_axis"] == "columns"
+        assert facts["dense_bytes"] == 2 * 2600 * 128    # half a float32's
+        assert facts["entries"] + facts["dense_entries"] == A.nnz
+        D = A.to_dense()
+        np.testing.assert_array_equal(
+            np.asarray(dense.slab[:, :dense.lines.size], np.float32),
+            D[:, dense.lines])
+        D = D.astype(np.float64)
+        X = rng.standard_normal((4000, k)).astype(np.float32)
+        Z = rng.standard_normal((2600, k)).astype(np.float32)
+        for got, want in ((A.matmat(X), D @ X), (A.T.matmat(Z), D.T @ Z)):
+            assert np.abs(np.asarray(got) - want).max() / \
+                np.abs(want).max() < 1e-6
+
+    @pytest.mark.parametrize("how", ["repeats", "zeros"])
+    def test_a_bfloat16_slab_only_behind_the_check(self, rng, on_one_chip,
+                                                   how):
+        """A cell listed twice may sum to no bfloat16 (and a zero would
+        hide one from the count that finds it): the fill says no and the
+        lines are chosen again for a float32 slab; the products are
+        float64's either way, and an orientation is still one build."""
+        from matrel_tpu.core import coo as coo_lib
+        A = self._starred(rng, **{how: 300})
+        builds = coo_lib.plan_builds()
+        fwd = A._get_wide_plan()
+        bwd = A._get_wide_plan(transposed=True)
+        assert coo_lib.plan_builds() == builds + 2
+        assert fwd.dense is bwd.dense and fwd.dense.dtype == "float32"
+        assert fwd.dense.slab.dtype == np.float32
+        facts = coo_lib.plan_facts(bwd, A.nnz)
+        assert facts["dense_lines"] == 128
+        assert facts["entries"] + facts["dense_entries"] == A.nnz
+        D = A.to_dense().astype(np.float64)
+        X = rng.standard_normal((4000, 8)).astype(np.float32)
+        Z = rng.standard_normal((2600, 8)).astype(np.float32)
+        for got, want in ((A.matmat(X), D @ X), (A.T.matmat(Z), D.T @ Z)):
+            assert np.abs(np.asarray(got) - want).max() / \
+                np.abs(want).max() < 1e-6
+
+    def test_a_uniform_matrix_keeps_its_plans_and_its_program(
+            self, rng, on_one_chip, monkeypatch):
+        """No line of a uniform matrix pays: no slab, the orientation's
+        own plan where the dense side is one table and the source panels
+        it had where it is not, table for table and, lowered for the
+        chip, instruction for instruction what the recipe before PR 43
+        gives (by hash; the same hashes came from the parent's tree,
+        CHANGES.md)."""
+        from matrel_tpu.core import coo as coo_lib
+        from matrel_tpu.ops import spmv as spmv_lib
+        monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 8000 * 512)
+        r = np.random.default_rng(43)
+        shape, m = (20_000, 6_000), 20_000
+        A = COOMatrix.from_edges(
+            r.integers(0, shape[0], m), r.integers(0, shape[1], m),
+            r.standard_normal(m).astype(np.float32), shape=shape)
+        fwd = A._get_wide_plan()
+        bwd = A._get_wide_plan(transposed=True)
+        assert A._dense_lines() is None
+        assert fwd is A._get_plan()
+        assert isinstance(bwd, coo_lib.PanelledPlan) and bwd.dense is None
+        assert "dense_lines" not in coo_lib.plan_facts(bwd, A.nnz)
+        assert coo_lib.plan_facts(bwd, A.nnz)["entries"] == A.nnz
+        # the recipe as it was: the transposed orientation a plan a range
+        # of 6,672 sources, built from the entries whose source is there
+        parts = []
+        for col0 in range(0, shape[0], 6_672):
+            n_part = min(6_672, shape[0] - col0)
+            sel = np.flatnonzero((A.rows >= col0) & (A.rows < col0 + n_part))
+            parts.append((col0, spmv_lib.build_spmv_plan(
+                A.cols[sel], A.rows[sel] - col0, A.vals[sel],
+                n_rows=shape[1], n_cols=n_part, layout="auto", hubs=False)))
+        was = coo_lib.PanelledPlan(n_rows=shape[1], n_cols=shape[0],
+                                   block=parts[0][1].block,
+                                   parts=tuple(parts))
+        assert _tables_hash(bwd) == _tables_hash(was)
+        got, text = _program_hash(bwd, shape[0])
+        assert got == _program_hash(was, shape[0])[0]
+        assert "dot_general" not in text
+
+    def test_a_small_budget_keeps_the_densest_lines_that_fit(
+            self, rng, on_one_chip, monkeypatch):
+        """256 columns pay; where the device has room for one group of
+        128 the slab holds the 128 densest, and the plan's reckoned bytes
+        stay inside what the device hands out."""
+        from matrel_tpu.core import coo as coo_lib
+        from matrel_tpu.ops import pallas_spmv as pc
+        n, m = self.SHAPE
+        hot = rng.choice(m, 256, replace=False)
+        deg = np.r_[np.full(128, 150), np.full(128, 60)]   # two groups
+        cols = np.concatenate([np.repeat(hot, deg),
+                               rng.integers(0, m, 2_000)])
+        rows = rng.integers(0, n, cols.size)
+        vals = rng.standard_normal(cols.size).astype(np.float32)
+
+        def lines(limit):
+            monkeypatch.setattr(pc, "_hbm_limit", lambda: limit)
+            A = COOMatrix.from_edges(rows, cols, vals, shape=self.SHAPE)
+            return A, A._dense_lines()
+
+        _, roomy = lines(1 << 30)
+        assert roomy.lines.size == 256
+        # a slab of 128 lines is 1.33 MB; of 16 MB the tables, a panel
+        # and the product's dense sides leave 1.7
+        limit = 16 << 20
+        A, tight = lines(limit)
+        assert tight.lines.size == 128
+        degrees = np.bincount(cols, minlength=m)
+        assert degrees[tight.lines].min() >= np.sort(degrees)[-128]
+        facts = coo_lib.plan_facts(A._get_wide_plan(), A.nnz)
+        assert facts["dense_bytes"] <= coo_lib._DENSE_SHARE * limit
+        assert facts["plan_bytes"] < limit
+        X = rng.standard_normal((m, 8)).astype(np.float32)
+        np.testing.assert_allclose(np.asarray(A.matmat(X)),
+                                   A.to_dense() @ X, rtol=2e-5, atol=2e-4)
+        _, none = lines(4 << 20)        # no room for a group: no slab
+        assert none is None
+
+    def test_the_rule_reads_rows_as_well_as_columns(self, rng, on_one_chip):
+        """The dense lines are those of the axis whose paying lines hold
+        more: a matrix with hot ROWS gets a slab of rows, its forward
+        product's destinations."""
+        from matrel_tpu.core import coo as coo_lib
+        _, At = self._hot_columns(rng)
+        A = COOMatrix.from_edges(At.cols, At.rows, At.vals,
+                                 shape=self.SHAPE[::-1])
+        plan = A._get_wide_plan()
+        assert plan.dense.axis == 0 and plan.dense_role == "destinations"
+        assert coo_lib.plan_facts(plan, A.nnz)["dense_axis"] == "rows"
+        X = rng.standard_normal((2600, 8)).astype(np.float32)
+        want = A.to_dense().astype(np.float64) @ X
+        assert np.abs(np.asarray(A.matmat(X)) - want).max() / \
+            np.abs(want).max() < 1e-6
+        # the first-built matrix's columns are its view's rows
+        assert At.T._get_wide_plan().dense_axis == "rows"
+
+    def test_a_matrix_on_a_mesh_and_a_matvec_get_what_they_had(self, rng):
+        """Off the one-device compact executor (``_plan_layout`` says
+        ``blocks``) no slab is chosen, and a k = 1 product never asks."""
+        from matrel_tpu.core import coo as coo_lib
+        _, A = self._hot_columns(rng)
+        assert coo_lib._plan_layout() == "blocks"
+        assert A._get_wide_plan() is A._get_plan()
+        assert A._get_wide_plan(transposed=True) is A._get_plan_t()
+        assert A._dense == []

@@ -51,11 +51,15 @@ def compact_one_device(monkeypatch):
     """What the chip is to a COOMatrix: the compact Pallas executors of
     one device (interpreted), plans past the small-plan threshold as the
     real matrix's are, and a gather table of 1,000 rows at the most, so
-    that t(V) * W runs in source panels as the 480,189 users do."""
+    that t(V) * W runs in source panels as the 480,189 users do. No room
+    for a slab (PR 43: a line of 3,000 cells would pay from 11 entries
+    on, and every movie would leave the tables these tests are about;
+    the dense part's own tests give it room)."""
     cfg = MatrelConfig(pallas_interpret=True, cse_enable=True)
     was = config_lib._default_config
     config_lib.set_default_config(cfg)
     monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")
+    monkeypatch.setattr(coo_lib, "_DENSE_SHARE", 0.0)
     monkeypatch.setattr(spmv_lib, "_SMALL_PLAN_SLOTS", 0)
     monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 1000 * 512)
     yield cfg
@@ -318,3 +322,170 @@ def test_the_spans_say_what_the_reader_says(rng, compact_one_device,
     assert [r["attrs"] for r in lookups] == [
         {"via": "template", "hit": True}] * 2
     assert not [r for r in mine if r["name"] == "matrel.compile"]
+
+
+# -- the dense part (PR 43) ---------------------------------------------------
+
+WIDE_MOVIES, HOT_MOVIES = 12_000, 128
+
+
+def _ratings_with_hot_movies(rng, stars=1.1):
+    """A ratings matrix as the rule sees the real one: 128 hot movies of
+    ~250 ratings (a column of 3,000 users pays from 12 on, from 9 in
+    bfloat16) over a tail of 14,000, one a movie at the least and two at
+    the most of most; a user holds 15 of 12,000 (a row pays from 47 on,
+    from 34 in bfloat16: the first group or two of users may, and hold
+    a tenth of what the hot movies hold). Distinct cells, values 1 to 5 times
+    ``stars`` (1.1: no bfloat16 holds them; 1: every one does)."""
+    hot = rng.choice(WIDE_MOVIES, HOT_MOVIES, replace=False)
+    cols = np.concatenate([hot[rng.integers(0, HOT_MOVIES, 32_000)],
+                           np.arange(WIDE_MOVIES),
+                           rng.integers(0, WIDE_MOVIES, 2_000)])
+    keys = np.unique(rng.integers(0, USERS, cols.size) * WIDE_MOVIES + cols)
+    rows, cols = keys // WIDE_MOVIES, keys % WIDE_MOVIES
+    vals = rng.integers(1, 6, rows.size) * np.float32(stars)
+    return hot, COOMatrix.from_edges(rows, cols, vals.astype(np.float32),
+                                     shape=(USERS, WIDE_MOVIES))
+
+
+@pytest.fixture
+def compact_with_room(compact_one_device, monkeypatch):
+    """``compact_one_device`` with the slab's share of the device as it
+    ships, and a gather table of 2,000 rows (the users are two source
+    panels, the movies seven)."""
+    monkeypatch.undo()
+    monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")
+    monkeypatch.setattr(spmv_lib, "_SMALL_PLAN_SLOTS", 0)
+    monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 2000 * 512)
+    return compact_one_device
+
+
+@pytest.mark.parametrize("rank,stars", [(16, 1.1), (128, 1.1), (16, 1)],
+                         ids=["r16-float32", "r128-float32", "r16-bfloat16"])
+def test_a_fit_over_a_slab_matches_float64(rng, compact_with_room, rank,
+                                           stars):
+    """Both updates through ``session.sql`` + ``compute`` on a matrix
+    whose hot movies lie in a slab: the factors are float64's, both
+    orientations say the same slab, the slots hold the rest, and the
+    templates answer as before. Ratings that are bfloat16's lie in a
+    bfloat16 slab, to the same 3e-6."""
+    hot, V = _ratings_with_hot_movies(rng, stars)
+    s = _session(compact_with_room)
+    builds = coo_lib.plan_builds()
+    w0 = rng.random((USERS, rank), dtype=np.float32) + 1e-3
+    h0 = rng.random((rank, WIDE_MOVIES), dtype=np.float32) + 1e-3
+    W, H, said = _fit(s, V, BlockMatrix.from_numpy(w0, mesh=s.mesh),
+                      BlockMatrix.from_numpy(h0, mesh=s.mesh))
+    want_w, want_h = _fit_float64(V, w0, h0)
+    for got, want in ((W.to_numpy(), want_w), (H.to_numpy(), want_h)):
+        assert np.all(np.isfinite(got)) and np.all(got >= 0)
+        assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 3e-6
+    assert coo_lib.plan_builds() == builds + 2
+    assert [p["hit"] for p in said] == [False, False] + [True] * 4
+    held = int(np.isin(V.cols, hot).sum())
+    by = {r["orientation"]: r for p in said for r in p["spmm"]}
+    for facts in by.values():
+        assert facts["dense_axis"] == "columns"
+        assert facts["entries"] + facts["dense_entries"] == V.nnz
+        assert facts["entries"] <= facts["slots"]
+        assert facts["overflow_edges"] == 0
+        if stars == 1:
+            assert facts["dense_dtype"] == "bfloat16"
+            assert facts["dense_lines"] % 128 == 0
+            assert facts["dense_lines"] >= HOT_MOVIES
+            assert facts["dense_entries"] >= held
+            assert facts["dense_bytes"] == 2 * USERS * facts["dense_lines"]
+        else:
+            assert facts["dense_dtype"] == "float32"
+            assert facts["dense_lines"] == HOT_MOVIES
+            assert facts["dense_entries"] == held
+            assert facts["dense_bytes"] == 4 * USERS * HOT_MOVIES
+    assert (by["forward"]["source_panels"],
+            by["transposed"]["source_panels"]) == (7, 2)
+    # a hit says of the slab what the build said
+    for build, hit in zip(said[:2], said[2:4]):
+        assert hit["spmm"] == build["spmm"]
+    assert all(not p["densified_products"] for p in said)
+    assert all("pallas_spmv" in p["executors"] for p in said)
+    # one slab: the forward plan's is the transposed plan's
+    assert V._get_wide_plan().dense is V._get_wide_plan(True).dense
+
+
+def test_the_planner_counts_the_slab_once(rng, compact_with_room):
+    """hbm_plan_bytes of a coo_leaf product over a plan with a dense
+    part: the residual's tables and panel, and the slab, once."""
+    from matrel_tpu import executor
+    from matrel_tpu.ops import pallas_spmv as pc
+    from matrel_tpu.parallel import planner
+    _, V = _ratings_with_hot_movies(rng)
+    s = _session(compact_with_room)
+    s.register("V", V)
+    s.register("H", BlockMatrix.from_numpy(
+        rng.random((16, WIDE_MOVIES), dtype=np.float32), mesh=s.mesh))
+    s.register("W", BlockMatrix.from_numpy(
+        rng.random((USERS, 16), dtype=np.float32), mesh=s.mesh))
+    slab = 4 * USERS * HOT_MOVIES
+    for sql, transposed in (("V * t(H)", False), ("t(W) * V", True)):
+        plan = executor.compile_expr(s.sql(sql), s.mesh, s.config)
+        (rec,) = [r for r in hbm_report(plan.optimized)
+                  if r["node"] == "matmul"]
+        assert rec["chosen"] == "coo_spmm" and rec["refused_hbm"] == []
+        wide = V._get_wide_plan(transposed)
+        facts = coo_lib.plan_facts(wide, V.nnz)
+        tables = sum(pc.wide_plan_bytes(*np.asarray(p.src8).shape)
+                     for _, p in wide.parts)
+        assert slab <= facts["plan_bytes"] - 13 * facts["slots"] < tables \
+            - 13 * facts["slots"] + slab + 1
+        (node,) = [n for n in planner._nodes(plan.optimized)
+                   if n.attrs.get("coo_product")]
+        assert node.attrs["coo_product"]["bytes"] == facts["plan_bytes"]
+        operands = 4 * 16 * (USERS + WIDE_MOVIES)
+        assert facts["plan_bytes"] <= rec["hbm_plan_bytes"] \
+            < facts["plan_bytes"] + slab + 64 * operands
+
+
+def test_a_tight_device_gets_a_smaller_slab_not_a_refusal(rng, monkeypatch):
+    """The slab is built to what the device has left: whatever budget
+    serves the product without a slab serves it with the rule in force,
+    and the refusal of a budget too small for the tables alone is the
+    one it always was (no ``PlanMemoryError`` the slab caused)."""
+    from matrel_tpu import executor
+    hot, _ = _ratings_with_hot_movies(rng)
+    state = rng.bit_generator.state
+    was = config_lib._default_config
+    served = {}
+    try:
+        for share in (coo_lib._DENSE_SHARE, 0.0):
+            monkeypatch.setattr(coo_lib, "_DENSE_SHARE", share)
+            monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")
+            monkeypatch.setattr(spmv_lib, "_SMALL_PLAN_SLOTS", 0)
+            for budget in (4 << 20, 12 << 20, 14 << 20, 16 << 20, 64 << 20):
+                cfg = MatrelConfig(pallas_interpret=True,
+                                   hbm_budget_bytes=budget)
+                config_lib.set_default_config(cfg)
+                rng.bit_generator.state = state
+                _, V = _ratings_with_hot_movies(rng)
+                s = _session(cfg)
+                s.register("V", V)
+                s.register("H", BlockMatrix.from_numpy(np.ones(
+                    (16, WIDE_MOVIES), np.float32), mesh=s.mesh))
+                try:
+                    plan = executor.compile_expr(s.sql("V * t(H)"), s.mesh,
+                                                 s.config)
+                    said = plan.meta["spmm"][0]
+                    served[share, budget] = said.get("dense_lines", 0)
+                    assert plan.meta["hbm_plan_bytes"] <= budget
+                except PlanMemoryError:
+                    served[share, budget] = None
+    finally:
+        config_lib._default_config = was
+    share = max(k[0] for k in served)
+    for budget in (4 << 20, 12 << 20, 14 << 20, 16 << 20, 64 << 20):
+        if served[0.0, budget] is None:
+            assert served[share, budget] is None
+        else:
+            assert served[share, budget] is not None, (budget, served)
+    assert served[0.0, 4 << 20] is None             # the tables alone
+    assert served[share, 64 << 20] == HOT_MOVIES    # room: the slab
+    assert 0 in (served[share, 12 << 20], served[share, 14 << 20],
+                 served[share, 16 << 20])           # tight: none, served
