@@ -1,9 +1,11 @@
 #!/usr/bin/env python3
 """chip_smoke.py — the quickest proof that the system still starts on the
-chip: the driver's five configurations (BASELINE.json ``configs``) plus
-one S x S product, answered on a real TPU through the normal entry points
-(the bridge server, ``session.submit``/``compute``, the workloads), each
-answer compared with numpy/scipy on the same seeded data.
+chip: the driver's configurations 1, 2, 4 and 5 (BASELINE.json
+``configs``; the third, the regression, is the benchmark's cells
+``linreg_10m_1c`` and ``linreg_10m_2x2``) plus one S x S product,
+answered on a real TPU through the normal entry points (the bridge
+server, ``session.submit``/``compute``, the workloads), each answer
+compared with numpy/scipy on the same seeded data.
 
     python chip_smoke.py                # one chip, one process (the driver)
     python chip_smoke.py --chips 4      # ONLY the 2x2-mesh phase (builder)
@@ -216,49 +218,6 @@ def phase_submit(sess, queries, refs, tol):
                    "after_round2": after},
          max_err=err, **{"pass": ok})
     sess.serve_close(timeout=60)
-
-
-def phase_linreg(sizes, seed, mesh):
-    """Configuration 3: streaming normal equations; X is generated per
-    panel ON the device by an integer hash of (row, col, panel, seed), y
-    = X theta* exactly, so theta is known by construction."""
-    import jax.numpy as jnp
-    import numpy as np
-    from matrel_tpu.workloads.linreg import fit_streaming
-
-    n, k = sizes["linreg_rows"], sizes["linreg_k"]
-    panel = min(262_144, n)
-    theta_star = (1.0 + (np.arange(k) % 7) / 7.0).astype(np.float32)
-
-    def panel_fn(p):
-        # murmur3's 32-bit finalizer over (row, col, panel, seed): a
-        # NONLINEAR mix (an LCG of r and c is a function of u_r + v_c,
-        # whose Gram has cond ~1e5 — too ill-conditioned to recover
-        # theta in f32); entries uniform in [-1, 1), cond(X'X) ~ 1
-        u = jnp.uint32
-        r = jnp.arange(panel, dtype=u)[:, None]
-        c = jnp.arange(k, dtype=u)[None, :]
-        h = (r * u(0x9E3779B1) + c * u(0x85EBCA77)
-             + (p.astype(u) + u(seed)) * u(0xC2B2AE3D))
-        h = (h ^ (h >> 16)) * u(0x85EBCA6B)
-        h = (h ^ (h >> 13)) * u(0xC2B2AE35)
-        h = h ^ (h >> 16)
-        xp = (h >> 8).astype(jnp.float32) * (2.0 ** -23) - 1.0
-        yp = xp @ jnp.asarray(theta_star)[:, None]
-        return xp, yp
-
-    theta, first, warm = first_and_warm(
-        lambda: fit_streaming(n, k, panel_fn, panel_rows=panel, mesh=mesh))
-    theta = np.asarray(theta)[:, 0]
-    err = rel_err(theta, theta_star)
-    n_panels = math.ceil(n / panel)
-    emit(query="linreg.fit_streaming", shapes={"X": [n_panels * panel, k]},
-         dtype="float32", precision="highest",
-         executor="workloads.linreg.fit_streaming (one jitted fori_loop "
-         f"over {n_panels} device-generated panels + Cholesky)",
-         first_call_s=first, warm_s=warm, max_err=err,
-         check="theta vs theta* known by construction",
-         **{"pass": err <= 1e-3})
 
 
 def phase_spmm(sizes, seed, mesh, cfg):
@@ -594,8 +553,6 @@ def sizes_for(scale: float) -> dict:
         "dense_n": round_to(4096 * scale, 128, 256),
         "chain_big": round_to(10_000 * scale, 8, 200),
         "chain_small": 100,
-        "linreg_rows": max(int(10_000_000 * scale), 4096),
-        "linreg_k": 1000,
         "spmm_n": round_to(100_352 * scale, 512, 2048),
         "pr_nodes": max(int(1_000_000 * scale), 4096),
         "pr_edges": max(int(10_000_000 * scale), 40_960),
@@ -688,7 +645,6 @@ def main() -> int:
         for name in ("M", "N", "A", "B", "C"):
             cached.register(name, sess.table(name))
         phase_submit(cached, queries, refs, tol)
-        phase_linreg(sizes, args.seed, mesh)
         phase_spmm(sizes, args.seed, mesh, cfg)
         phase_pagerank(sizes, args.seed)
         phase_spgemm(sess, sizes, args.seed)

@@ -1,6 +1,6 @@
 """Symmetric 2-pass bf16 Gram split — the ONE implementation of the
-round-3 identity (docs/ROUND3.md floor analysis) shared by the
-executor's AᵀA/AAᵀ lowering and the streaming linreg workload.
+round-3 identity (docs/ROUND3.md floor analysis) behind the
+executor's AᵀA/AAᵀ lowering.
 
 For f32 x split as x = hi + lo (bf16 each), the three products XLA's
 precision=HIGH keeps (hi·hi, hi·lo, lo·hi; lo·lo dropped) collapse in a

@@ -23,7 +23,7 @@ Pipeline per matvec (``spmv_compact``):
   2. Pallas: generate ``oh_hi`` (C, HI') bf16 and the w-carrying rhs
      (C, LO·passes) in VMEM (w carved into bf16 residual parts by
      mantissa masking — f32-faithful at passes=3, see
-     ops/spmv_routed.py for why masking, not casts), one MXU contraction
+     ``_bf16_split`` for why masking, not casts), one MXU contraction
      ``oh_hiᵀ @ rhs`` per grid step. Blocks layout: a step a block,
      writing its (HI', LO) output tile. Chunks layout (ops/spmv.py,
      PR 33): a step a chunk of ``spmv.CHUNK`` slots; the chunk → block
@@ -74,9 +74,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from matrel_tpu.ops import spmv as spmv_lib
-from matrel_tpu.ops.spmv_routed import _bf16_split
 
 LANE = 128
+
+
+def _bf16_split(v, passes: int):
+    """Residual bf16 decomposition: Σ parts ≈ v with error ~2^(-8·passes).
+    The one-hot factor of the scatter's matmul is exact in bf16, so the
+    split of the VALUE side is the only precision knob.
+
+    Parts are carved by MASKING the low mantissa bits (truncation toward
+    zero), not by dtype casts, and returned as f32 arrays whose values
+    sit exactly on the bf16 grid (a later astype(bf16) is lossless).
+    Two reasons: pallas interpret mode ELIDES bf16 rounding on casts
+    (measured 2026-07-30: astype(bf16).astype(f32) round-trips unrounded
+    inside a kernel), which silently collapsed a cast-based split to its
+    first term; and Mosaic only supports minor-dim-inserting broadcasts
+    for 32-bit types, so downstream masking must happen in f32 anyway."""
+    parts = []
+    rem = v
+    for _ in range(passes):
+        bits = jax.lax.bitcast_convert_type(rem, jnp.uint32)
+        hi = jax.lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32)
+        parts.append(hi)                        # f32, on the bf16 grid
+        rem = rem - hi
+    return parts
 
 
 def _scatter_tile(off, w, hi_n: int, lo: int, passes: int):

@@ -4,7 +4,7 @@ docs/PRECISION.md).
 
 The scheme is arXiv:2112.09017's split summation: decompose each f32
 operand into bf16 slices (hi = bf16(x), lo = bf16(x − hi) — the same
-residual construction as ops/gram.hi_lo_split and spmv_routed's
+residual construction as ops/gram.hi_lo_split and pallas_spmv's
 ``_bf16_split``) and accumulate the significant cross-products in f32
 on the MXU. Keeping hi·hi + hi·lo + lo·hi (3 passes) drops only the
 lo·lo term, whose relative magnitude is ~2^-16 — f32-class accuracy at
@@ -33,7 +33,7 @@ def bf16_slices(x: Array, k: int) -> List[Array]:
     """f32 → k bf16 residual slices with Σ slices ≈ x (error ~2^(-8k)
     relative). k=2 delegates to :func:`ops.gram.hi_lo_split` — the ONE
     cast-and-subtract residual construction (two copies of the split
-    numerics would drift; cf. spmv_routed._bf16_split's interpret-mode
+    numerics would drift; cf. pallas_spmv._bf16_split's interpret-mode
     caveat, which masks mantissas for exactly that reason)."""
     from matrel_tpu.ops.gram import hi_lo_split
     if k == 2:

@@ -1,8 +1,8 @@
 """Conjugate gradient — iterative SPD solve as ONE jitted program.
 
 The reference solves normal equations with a direct driver-side solve
-(Cholesky; `linreg.fit`). CG is the iterative alternative when the
-system is large or the operator is only available as a matvec: each
+(Cholesky; here the query ``inv(t(X) * X) * t(X) * y``). CG is the
+iterative alternative when the system is large or the operator is only available as a matvec: each
 step is one distributed matvec + a few vector reductions, compiled
 into a single ``lax.while_loop`` (tolerance- AND iteration-bounded —
 compiler-friendly control flow, no host round-trips).
@@ -91,7 +91,8 @@ def cg_least_squares(X: Union[BlockMatrix, E.MatExpr], y,
                      maxiter: int = 1000) -> Tuple[jax.Array, int]:
     """argmin ‖Xθ − y‖² (+ l2‖θ‖²) by CG on the NORMAL EQUATIONS
     operator v ↦ Xᵀ(Xv) + l2·v — the Gram matrix never materialises
-    (two matvecs per iteration; the iterative face of linreg.fit)."""
+    (two matvecs per iteration; the iterative face of the
+    normal-equations query)."""
     from matrel_tpu.workloads.eigen import _dense_data
     e = E.as_expr(X)
     k = e.shape[1]

@@ -267,7 +267,7 @@ def prepare_pagerank_onehot(src, dst, n: int, max_slots: int = None,
     outdeg = np.bincount(src_np, weights=w,
                          minlength=n).astype(np.float32)
     # epsilon (not 1.0) floor: weighted out-masses below 1 must not be
-    # clamped or the ranks skew (same rationale as pagerank_block_sparse)
+    # clamped or the ranks skew
     inv = np.where(outdeg > 0, 1.0 / np.maximum(outdeg, 1e-30), 0.0)
     plan = spmv_lib.build_spmv_plan(dst_np, src_np,
                                     vals=w * inv[src_np],
@@ -766,106 +766,6 @@ def _edges_runner(n: int, rounds: int, alpha: float):
         return jax.lax.fori_loop(0, rounds, body, _r0(n))
 
     return prepare, matrel_pagerank_segment
-
-
-def pagerank_csr(src, dst, n: int, rounds: int = 30, alpha: float = 0.85,
-                 max_degree_factor: float = 2.0):
-    """PageRank via a padded in-neighbor table — scatter-free matvec.
-
-    Build (host-side, once) a dense (n, D) table of in-neighbors padded
-    with a sentinel, where D is the max in-degree; each round is then a
-    dense gather + row-sum — no scatter in the loop. The padded table does
-    D/mean-degree × the gathers of the edge-list form, so this only wins
-    when the in-degree distribution is TIGHT (near-regular graphs, D ≲
-    2×mean — measured on 1M/10M uniform-random edges, D≈3.5×mean, the
-    segment-sum form is ~2.5× faster). Anything looser falls back to
-    ``pagerank_edges``.
-    """
-    src = np.asarray(src, dtype=np.int32)
-    dst = np.asarray(dst, dtype=np.int32)
-    indeg = np.bincount(dst, minlength=n)
-    D = int(indeg.max()) if len(dst) else 0
-    mean_deg = max(len(dst) / max(n, 1), 1.0)
-    if D > max_degree_factor * mean_deg:
-        return pagerank_edges(src, dst, n, rounds, alpha)
-    order = np.argsort(dst, kind="stable")
-    dst_s, src_s = dst[order], src[order]
-    offsets = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(indeg, out=offsets[1:])
-    slot = np.arange(len(dst_s)) - offsets[dst_s]
-    neighbors = np.full((n, max(D, 1)), n, dtype=np.int32)  # n = sentinel
-    neighbors[dst_s, slot] = src_s
-    outdeg = np.bincount(src, minlength=n).astype(np.float32)
-    run = _csr_runner(int(n), int(rounds), float(alpha), int(max(D, 1)))
-    return run(jnp.asarray(neighbors), jnp.asarray(outdeg))
-
-
-@functools.lru_cache(maxsize=32)
-def _csr_runner(n: int, rounds: int, alpha: float, D: int):
-    @jax.jit  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
-    def run(neighbors, outdeg):
-        inv_deg = jnp.where(outdeg > 0, 1.0 / jnp.maximum(outdeg, 1.0), 0.0)
-        dangling = (outdeg == 0).astype(jnp.float32)
-
-        def matvec(r):
-            w = r * inv_deg
-            w_pad = jnp.concatenate([w, jnp.zeros((1,), w.dtype)])  # sentinel
-            return jnp.sum(w_pad[neighbors], axis=1)
-
-        body = _power_body(matvec, n, alpha, dangling)
-        return jax.lax.fori_loop(0, rounds, body, _r0(n))
-
-    return run
-
-
-def pagerank_block_sparse(S, rounds: int = 30, alpha: float = 0.85,
-                          config: Optional[MatrelConfig] = None) -> jax.Array:
-    """PageRank on a block-sparse adjacency (clustered graphs where tiles
-    are dense enough to pay — web/community graphs; for uniform-random
-    edge lists use pagerank_edges). The matvec is the SpMM fast path over
-    Âᵀ; the loop is host-driven but each round is one cached compiled
-    program (no re-trace), mirroring the reference's per-round plan
-    execution without its shuffle."""
-    from matrel_tpu.core.blockmatrix import BlockMatrix
-    from matrel_tpu.ops import spmm as spmm_lib
-
-    n = S.shape[0]
-    if S.shape[0] != S.shape[1]:
-        raise ValueError(f"adjacency must be square, got {S.shape}")
-    st = S.transpose()
-    mesh = S.mesh
-    deg_bm = spmm_lib.spmm(
-        S, BlockMatrix.from_numpy(np.ones((n, 1), np.float32), mesh=mesh),
-        config)
-
-    @jax.jit  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
-    def prep(deg):
-        # epsilon (not 1.0) floor: weighted adjacencies can have row sums
-        # below 1, and clamping those would silently skew the ranks
-        inv = jnp.where(deg > 0, 1.0 / jnp.maximum(deg, 1e-30), 0.0)
-        dangling = ((deg == 0) &
-                    (jnp.arange(deg.shape[0])[:, None] < n)).astype(jnp.float32)
-        return inv, dangling
-
-    inv_deg, dangling = prep(deg_bm.data)
-    teleport = (1.0 - alpha) / n
-    r = BlockMatrix.from_numpy(np.full((n, 1), 1.0 / n, np.float32),
-                               mesh=mesh)
-
-    @jax.jit  # matlint: disable=ML010 workload runner cache, jitted once per static dims outside the plan path
-    def poststep(contrib, r_old):
-        dmass = jnp.sum(dangling * r_old)
-        r_new = alpha * (contrib + dmass / n) + teleport
-        valid = (jnp.arange(r_new.shape[0]) < n)[:, None]
-        return jnp.where(valid, r_new, 0.0)
-
-    for _ in range(rounds):
-        weighted = BlockMatrix.from_array(r.data * inv_deg,
-                                          (n, 1), mesh, r.spec)
-        contrib = spmm_lib.spmm(st, weighted, config)
-        r = BlockMatrix.from_array(poststep(contrib.data, r.data),
-                                   (n, 1), mesh, r.spec)
-    return r.data[:n]
 
 
 def pagerank_reference_edges(src, dst, n: int, rounds: int = 30,

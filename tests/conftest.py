@@ -54,6 +54,31 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture(scope="session")
+def run_at_root():
+    """``run(args, devices=8)``: ``python *args`` from the repository's
+    root in a process of its own on ``devices`` virtual CPU devices
+    (platform env hermetic), exit code 0 asserted; its stdout."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def run(args, devices=8):
+        env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = \
+            f"--xla_force_host_platform_device_count={devices}"
+        # prepend the repo to the inherited path
+        prev = [p for p in env.get("PYTHONPATH", "").split(os.pathsep) if p]
+        env["PYTHONPATH"] = os.pathsep.join([repo, *prev])
+        r = subprocess.run([sys.executable, *args], capture_output=True,
+                           text=True, timeout=300, env=env, cwd=repo)
+        assert r.returncode == 0, (args, r.stdout[-800:], r.stderr[-800:])
+        return r.stdout.strip()
+
+    return run
+
+
 @pytest.fixture(autouse=True)
 def _fresh_session():
     from matrel_tpu import session

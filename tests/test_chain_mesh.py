@@ -134,6 +134,33 @@ def test_panelled_rmm_is_the_one_panel_product(tables, grid, panels):
                           np.asarray(many.astype(jnp.float32)))
 
 
+@pytest.mark.parametrize("n, mid", [(64, 8), (60, 6)])
+@pytest.mark.parametrize("grid", [(2, 4), (4, 2), (2, 2)])
+def test_a_float32_chain_is_numpys_whether_or_not_the_mesh_divides_it(
+        grid, n, mid, rng):
+    """What the streaming chain's tests held, on the path that stays:
+    ``A * B * C`` in float32 through session.sql + session.compute on
+    eight devices (both ways round) and on four, against numpy; 64 and
+    8 are multiples of every grid side, 60 and 6 of neither 4 nor 8
+    (the session pads the tables; nothing is refused), and the skewed
+    shapes leave the chain DP one cheap order to find."""
+    mesh = mesh_lib.make_mesh(grid, devices=jax.devices()[:grid[0] * grid[1]])
+    host = {"A": rng.standard_normal((n, mid)).astype(np.float32),
+            "B": rng.standard_normal((mid, n)).astype(np.float32),
+            "C": rng.standard_normal((n, mid)).astype(np.float32)}
+    sess = MatrelSession(mesh=mesh)
+    for name, arr in host.items():
+        sess.register(name, sess.from_numpy(arr))
+    expr = sess.sql("A * B * C")
+    out = sess.compute(expr)
+    assert out.shape == (n, mid)
+    np.testing.assert_allclose(out.to_numpy(),
+                               host["A"] @ host["B"] @ host["C"],
+                               rtol=1e-4, atol=1e-4)
+    inner = sess.compile(expr).optimized.children[1]
+    assert inner.kind == "matmul" and inner.shape == (mid, mid)
+
+
 def test_rmm_alone_derives_its_panels_from_the_budget(mesh_square, tables):
     """A caller that holds no plan (autotune, a tiered pass): the
     strategy reckons the product taken alone."""

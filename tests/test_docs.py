@@ -3,7 +3,8 @@
 One case a document: every file name it cites in code spans resolves to
 a file of the repository, and every ``make <target>`` it names is a
 target of the Makefile. The histories (CHANGES.md, PERF.md, ROADMAP.md)
-name files that are gone on purpose and are not held to this.
+name files that are gone on purpose and are not held to this; nor is a
+span that is itself a pointer into history (``git show <commit>:<path>``).
 """
 
 import fnmatch
@@ -74,7 +75,8 @@ def _resolves(name, ignored, files, basenames):
 def test_document_cites_what_exists(doc, tree, make_targets):
     with open(os.path.join(REPO, doc)) as f:
         text = f.read()
-    spans = [text] if doc == "Makefile" else _CODE.findall(text)
+    spans = [text] if doc == "Makefile" else [
+        s for s in _CODE.findall(text) if not s.startswith("`git show ")]
     cited = sorted({m.group(0) for s in spans for m in _FILE.finditer(s)})
     stray = [n for n in cited if not _resolves(n, *tree)]
     assert not stray, f"{doc} cites files that do not exist: {stray}"

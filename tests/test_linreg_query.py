@@ -484,6 +484,66 @@ def test_a_mesh_product_lowers_as_it_did(mesh_square, sql):
         assert "while" not in text
 
 
+# -- what the pre-session fit held (PR 44): a ridge term, "high", the 2x4 mesh --
+
+
+def test_a_ridge_term_is_solved_with_the_gram(one_device, data):
+    """``fit(l2=)``'s fact as a query: the penalty is a registered k x k
+    table, the chain DP brackets the inverse of the SUM as one solve
+    against ``t(X) * y`` (k x 1, never k x N), the answer is the float64
+    ridge solution on the tables' values, and a heavy penalty shrinks
+    it."""
+    x, y, want = data
+    x64, y64 = x.astype(np.float64), y.astype(np.float64)
+    sess = session_of(one_device, x, y)
+    norms = []
+    for lam in (0.0, 500.0):
+        ridge = (lam * np.eye(K)).astype(np.float32)
+        sess.register("R", BlockMatrix.from_array(
+            jnp.asarray(ridge), ridge.shape, one_device, P(None, None)))
+        expr = sess.sql("inv(t(X) * X + R) * t(X) * y")
+        plan = sess.compile(expr)
+        (solve,) = [n for n, _ in nodes(plan.optimized) if n.kind == "solve"]
+        assert solve.shape == (K, 1)
+        assert solve.children[0].kind == "elemwise"
+        assert plan.meta["rule_hits"]["chain_solve"] == 1
+        got = sess.compute(expr).to_numpy()
+        assert rel_err(got, np.linalg.solve(
+            x64.T @ x64 + lam * np.eye(K), x64.T @ y64)) < 2e-5
+        norms.append(float(np.linalg.norm(got)))
+    assert rel_err(got, want) > 1e-2 and norms[1] < 0.9 * norms[0]
+
+
+@pytest.mark.parametrize("case", ["by_rows", "by_rows_high", "canonical"])
+def test_a_short_table_on_the_2x4_mesh_is_lstsq(mesh8, case):
+    """What ``fit`` / ``fit_fused`` held, on the path that stays: the
+    query over eight devices, ``X`` and ``y`` by rows over all of them
+    or canonical ``P(x, y)``, against float64 ``lstsq``; a short
+    contraction goes through the ranked strategies (nothing is
+    multiplied in place), and ``matmul_precision`` "high" multiplies
+    ops/gram.py's bfloat16 parts and still recovers theta."""
+    rng = np.random.default_rng(44)
+    x = rng.standard_normal((256, 8)).astype(np.float32)
+    y = (x @ np.linspace(1, 2, 8).reshape(8, 1).astype(np.float32)
+         + 0.01 * rng.standard_normal((256, 1)).astype(np.float32))
+    high = case == "by_rows_high"
+    sess = mesh_session(mesh8, jnp.asarray(x), jnp.asarray(y),
+                        canonical=case == "canonical",
+                        **({"matmul_precision": "high"} if high else {}))
+    expr = sess.sql(SPELLINGS[0])
+    plan = sess.compile(expr)
+    assert plan.meta["mesh"] == "2x4"
+    assert plan.meta["rule_hits"]["chain_solve"] == 1
+    assert planner.OWN_ROWS not in plan.meta["executors"]
+    assert set(plan.meta["executors"]) <= set(strategies.STRATEGIES)
+    assert ("bf16" in lowered_text(plan)) is high
+    want = np.linalg.lstsq(x.astype(np.float64), y.astype(np.float64),
+                           rcond=None)[0]
+    got = sess.compute(expr).to_numpy()
+    assert got.shape == (8, 1)
+    assert rel_err(got, want) < (2e-5 if high else 2e-6)
+
+
 # -- the chip's share of the deployment is a share ----------------------------
 
 
@@ -723,6 +783,31 @@ def test_only_a_long_float32_gram_by_rows_takes_the_lowering(mesh_square,
         (record,) = taken.compile(taken.sql("t(X) * X")).meta["products"]
         assert record["chosen"] == planner.OWN_ROWS \
             and record["gram_tiles"] == [1, 1]
+
+
+def test_a_long_table_by_rows_on_the_2x4_mesh_is_multiplied_in_place(mesh8):
+    """``dryrun_multichip``'s regression stage as a test: eight devices
+    (the 2x2 fixture above has four), ``X`` and ``y`` by rows over all
+    of them, two panels and a ragged tail of 3 rows a device: both
+    products are multiplied where the rows lie, nothing but all-reduces
+    crosses the mesh, against float64 ``lstsq``."""
+    rows = 8 * (strategies.LONG_CONTRACTION // 8 + 3)
+    rng = np.random.default_rng(44)
+    x = rng.standard_normal((rows, 128)).astype(np.float32)
+    y = (x @ rng.standard_normal((128, 1))).astype(np.float32)
+    sess = mesh_session(mesh8, jnp.asarray(x), jnp.asarray(y))
+    expr = sess.sql(SPELLINGS[0])
+    plan = sess.compile(expr)
+    assert plan.meta["executors"] == [planner.OWN_ROWS]
+    gram, rhs, _ = plan.meta["products"]
+    for product in (gram, rhs):
+        assert product["devices"] == 8 \
+            and product["rows_a_device"] == rows // 8
+    assert gram["gram_tiles"] == [1, 1]
+    assert set(collective_ops(plan)) == {"all-reduce"}
+    want = np.linalg.lstsq(x.astype(np.float64), y.astype(np.float64),
+                           rcond=None)[0]
+    assert rel_err(sess.compute(expr).to_numpy(), want) < 5e-6
 
 
 def _described_whole(mesh, canonical):

@@ -494,8 +494,7 @@ class TestML009KernelSeam:
         # the porting worklist: every pre-registry kernel module lints
         # clean ONLY via its inline ML009 suppressions
         import os
-        for mod in ("pallas_spmm.py", "pallas_spmv.py",
-                    "spmv_routed.py"):
+        for mod in ("pallas_spmm.py", "pallas_spmv.py"):
             path = os.path.join(matlint.REPO, "matrel_tpu", "ops", mod)
             assert "disable=ML009" in open(path).read(), mod
             got = matlint.lint_file(path)
@@ -556,9 +555,8 @@ class TestML010JitSeam:
         # the porting worklist: the pre-seam jit sites lint clean ONLY
         # via their inline ML010 suppressions (the ML009 idiom)
         import os
-        for mod in ("workloads/pagerank.py", "workloads/linreg.py",
-                    "ops/spmv.py", "parallel/autotune.py",
-                    "core/blockmatrix.py"):
+        for mod in ("workloads/pagerank.py", "ops/spmv.py",
+                    "parallel/autotune.py", "core/blockmatrix.py"):
             path = os.path.join(matlint.REPO, "matrel_tpu", *mod.split("/"))
             assert "disable=ML010" in open(path).read(), mod
             got = matlint.lint_file(path)

@@ -7,27 +7,7 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-#: This container ships jax 0.4.37, whose CPU backend refuses
-#: cross-process computations outright: device_put onto a
-#: cross-process NamedSharding asserts spec equality via a global psum
-#: that fails with "Multiprocess computations aren't implemented on the
-#: CPU backend" (jax/_src/dispatch.py -> multihost_utils.assert_equal;
-#: reproduced round 6 by running tools/multihost_check.py by hand —
-#: every worker dies at BlockMatrix.from_numpy). The seed targeted the
-#: jax 0.6 CPU Gloo collectives backend where this works; nothing in
-#: this repo can add the capability to the pinned jaxlib, so the two
-#: Gloo tests are expected failures HERE and real coverage on
-#: containers with the newer jax (strict=False keeps them green there).
-_GLOO_XFAIL = pytest.mark.xfail(
-    strict=False,
-    reason="jax 0.4.37 CPU backend: 'Multiprocess computations aren't "
-           "implemented on the CPU backend' — cross-process Gloo "
-           "collectives need the jax 0.6 CPU backend the seed "
-           "targeted")
 
 
 def _run_check(nproc: int, tool_timeout: int, outer_timeout: int) -> str:
@@ -51,13 +31,11 @@ def _run_check(nproc: int, tool_timeout: int, outer_timeout: int) -> str:
     return out
 
 
-@_GLOO_XFAIL
 def test_two_process_collectives():
     out = _run_check(nproc=2, tool_timeout=120, outer_timeout=240)
     assert "over 8 devices" in out
 
 
-@_GLOO_XFAIL
 def test_four_process_collectives():
     """4 processes x 4 virtual devices each — the DCN shape of a 4-host
     pod slice (docs/INTERNALS.md's manual run, folded into CI per

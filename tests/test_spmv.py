@@ -396,104 +396,26 @@ class TestPageRankOneHot:
         assert abs(got.sum() - 1.0) < 1e-3
 
 
-class TestRoutedSpMV:
-    """ops/spmv_routed.py — the matmul-only (gather-free) formulation,
-    exercised in pallas interpret mode on the CPU mesh."""
-
-    def test_matches_oracle_two_groups(self, rng):
-        from matrel_tpu.ops import spmv_routed as rt
-        n, m = 40_000, 20_000          # spans 3 groups of 16384
-        rows, cols, vals = random_coo(rng, n, n, m)
-        plan = rt.build_routed_plan(rows, cols, vals, n, n)
-        assert plan is not None
-        x = rng.standard_normal(n).astype(np.float32)
-        y = np.asarray(rt.routed_spmv(plan, jnp.asarray(x),
-                                      interpret=True))
-        oracle = coo_oracle(rows, cols, vals, x, n)
-        scale = np.abs(oracle).max()
-        assert np.abs(y - oracle).max() / scale < 5e-4   # passes=2
-
-    def test_three_passes_f32_faithful(self, rng):
-        from matrel_tpu.ops import spmv_routed as rt
-        n, m = 20_000, 5_000
-        rows, cols, vals = random_coo(rng, n, n, m)
-        plan = rt.build_routed_plan(rows, cols, vals, n, n)
-        x = rng.standard_normal(n).astype(np.float32)
-        y = np.asarray(rt.routed_spmv(plan, jnp.asarray(x), passes=3,
-                                      interpret=True))
-        oracle = coo_oracle(rows, cols, vals, x, n)
-        assert np.abs(y - oracle).max() / np.abs(oracle).max() < 1e-6
-
-    def test_rectangular_and_empty_groups(self, rng):
-        from matrel_tpu.ops import spmv_routed as rt
-        n_rows, n_cols, m = 5_000, 33_000, 8_000
-        rows, cols, vals = random_coo(rng, n_rows, n_cols, m)
-        plan = rt.build_routed_plan(rows, cols, vals, n_rows, n_cols)
-        x = rng.standard_normal(n_cols).astype(np.float32)
-        y = np.asarray(rt.routed_spmv(plan, jnp.asarray(x),
-                                      interpret=True))
-        oracle = coo_oracle(rows, cols, vals, x, n_rows)
-        scale = max(np.abs(oracle).max(), 1e-9)
-        assert np.abs(y - oracle).max() / scale < 5e-4
-
-    def test_overflow_coo(self, rng):
-        from matrel_tpu.ops import spmv_routed as rt
-        # multiple cells with one hot cell: the 0-quantile capacity
-        # binds at the coolest cell's count, overflowing the hot one
-        # into the COO fallback
-        n = 40_000               # 3x3 groups
-        m = 3_000
-        rows, cols, vals = random_coo(rng, n, n, m)
-        rows[:1500] = 7          # hot cell: half the edges in cell (0,0)
-        cols[:1500] = 11
-        plan = rt.build_routed_plan(rows, cols, vals, n, n,
-                                    capacity_quantile=0.0,
-                                    max_padding=1000.0)
-        assert plan.ov_rows is not None and plan.ov_rows.shape[0] > 0
-        x = rng.standard_normal(n).astype(np.float32)
-        y = np.asarray(rt.routed_spmv(plan, jnp.asarray(x),
-                                      interpret=True))
-        oracle = coo_oracle(rows, cols, vals, x, n)
-        scale = np.abs(oracle).max()
-        assert np.abs(y - oracle).max() / scale < 5e-4
-
-    def test_build_gates(self, rng):
-        from matrel_tpu.ops import spmv_routed as rt
-        rows, cols, vals = random_coo(rng, 100, 100, 20)
-        # tiny graph: one cell of cap>=128 pads >3x the edge count
-        assert rt.build_routed_plan(rows, cols, vals, 100, 100) is None
-        # explicit slot cap
-        assert rt.build_routed_plan(rows, cols, vals, 100, 100,
-                                    max_padding=100.0,
-                                    max_slots=10) is None
-
-    def test_bf16_split_reconstructs(self):
-        from matrel_tpu.ops.spmv_routed import _bf16_split
-        v = jnp.asarray(np.random.default_rng(0)
-                        .standard_normal(4096).astype(np.float32))
-        # truncation-based split: one-sided error, bound ~2^(-7·passes)
-        for passes, tol in ((2, 1e-4), (3, 1e-6)):
-            parts = _bf16_split(v, passes)
-            # parts sit exactly on the bf16 grid (lossless astype)
-            for p in parts[:-1]:
-                assert np.array_equal(
-                    np.asarray(p),
-                    np.asarray(p.astype(jnp.bfloat16).astype(jnp.float32)))
-            back = np.sum([np.asarray(p, np.float64) for p in parts],
-                          axis=0)
-            rel = np.abs(back - np.asarray(v, np.float64))
-            rel = rel / np.maximum(np.abs(np.asarray(v)), 1e-30)
-            assert rel.max() < tol
-
-    def test_cap_ceiling_gates(self, rng):
-        from matrel_tpu.ops import spmv_routed as rt
-        # one edge-dense cell: capacity would exceed the VMEM-safe
-        # ceiling, so the build must refuse (fallback contract), not
-        # fail at kernel compile time
-        n, m = 16_000, 300_000
-        rows, cols, vals = random_coo(rng, n, n, m)
-        assert rt.build_routed_plan(rows, cols, vals, n, n,
-                                    max_padding=100.0) is None
+@pytest.mark.parametrize("passes,tol", [(1, 2.0 ** -7), (2, 1e-4),
+                                        (3, 1e-6)])
+def test_bf16_split_reconstructs(passes, tol):
+    from matrel_tpu.ops.pallas_spmv import _bf16_split
+    v = jnp.asarray(np.random.default_rng(0)
+                    .standard_normal(4096).astype(np.float32))
+    # truncation-based split: one-sided error, bound ~2^(-7·passes)
+    parts = _bf16_split(v, passes)
+    assert len(parts) == passes
+    # parts sit exactly on the bf16 grid (lossless astype)
+    for p in parts:
+        assert np.array_equal(
+            np.asarray(p),
+            np.asarray(p.astype(jnp.bfloat16).astype(jnp.float32)))
+    back = np.sum([np.asarray(p, np.float64) for p in parts], axis=0)
+    rel = np.abs(back - np.asarray(v, np.float64))
+    rel = rel / np.maximum(np.abs(np.asarray(v)), 1e-30)
+    assert rel.max() < tol
+    # truncation toward zero: no part overshoots what is left
+    assert np.all(np.abs(back) <= np.abs(np.asarray(v, np.float64)))
 
 
 class TestCompactSpMV:
@@ -1613,7 +1535,7 @@ def _wide_scatter_before_pr38(cb, skip, off, val, g, acc, block, passes=3):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     from matrel_tpu.ops import pallas_spmv as pc
-    from matrel_tpu.ops.spmv_routed import _bf16_split
+    from matrel_tpu.ops.pallas_spmv import _bf16_split
     n, cr, _ = off.shape
 
     def kernel(cb_ref, skip_ref, off_ref, val_ref, g_ref, acc_ref, y_ref):

@@ -1,45 +1,13 @@
-"""Workload acceptance tests (SURVEY.md §7.5, BASELINE.md rows 2/3/5):
-linreg, chain reorder, PageRank — numerics vs host oracles on the 8-device
-mesh."""
+"""Workload acceptance tests (SURVEY.md §7.5, BASELINE.md rows 2/5):
+chain reorder, PageRank, the analytics workloads — numerics vs host
+oracles on the 8-device mesh. The regression is a query:
+tests/test_linreg_query.py."""
 
 import numpy as np
 import pytest
 
 from matrel_tpu.core.blockmatrix import BlockMatrix
-from matrel_tpu.workloads import chain_bench, linreg, pagerank
-
-
-class TestLinreg:
-    def _data(self, rng, n=256, k=8):
-        x = rng.standard_normal((n, k)).astype(np.float32)
-        theta_true = rng.standard_normal((k, 1)).astype(np.float32)
-        y = x @ theta_true + 0.01 * rng.standard_normal((n, 1)).astype(np.float32)
-        return x, y, theta_true
-
-    def test_fit_matches_lstsq(self, mesh8, rng):
-        x, y, _ = self._data(rng)
-        X = BlockMatrix.from_numpy(x, mesh=mesh8)
-        Y = BlockMatrix.from_numpy(y, mesh=mesh8)
-        theta = np.asarray(linreg.fit(X, Y))
-        oracle = np.linalg.lstsq(x, y, rcond=None)[0]
-        np.testing.assert_allclose(theta, oracle, rtol=1e-2, atol=1e-3)
-
-    def test_fit_fused_matches(self, mesh8, rng):
-        x, y, _ = self._data(rng)
-        from jax.sharding import PartitionSpec as P
-        X = BlockMatrix.from_numpy(x, mesh=mesh8, spec=P(("x", "y"), None))
-        Y = BlockMatrix.from_numpy(y, mesh=mesh8, spec=P(("x", "y"), None))
-        theta = np.asarray(linreg.fit_fused(X, Y))
-        oracle = np.linalg.lstsq(x, y, rcond=None)[0]
-        np.testing.assert_allclose(theta, oracle, rtol=1e-2, atol=1e-3)
-
-    def test_ridge_shrinks(self, mesh8, rng):
-        x, y, _ = self._data(rng)
-        X = BlockMatrix.from_numpy(x, mesh=mesh8)
-        Y = BlockMatrix.from_numpy(y, mesh=mesh8)
-        t0 = np.asarray(linreg.fit(X, Y, l2=0.0))
-        t1 = np.asarray(linreg.fit(X, Y, l2=100.0))
-        assert np.linalg.norm(t1) < np.linalg.norm(t0)
+from matrel_tpu.workloads import chain_bench, pagerank
 
 
 class TestChain:
@@ -83,68 +51,19 @@ class TestPageRank:
         np.testing.assert_allclose(r, oracle, rtol=1e-3, atol=1e-6)
 
 
-class TestStreamingLinreg:
-    def test_streaming_matches_dense(self, mesh8):
-        import jax
-        import jax.numpy as jnp
-        from matrel_tpu.workloads.linreg import fit_streaming
-        k, n, panel = 8, 512, 128
-        theta_true = jnp.arange(1.0, k + 1.0).reshape(k, 1)
-
-        def panel_fn(p):
-            key = jax.random.fold_in(jax.random.PRNGKey(0), p)
-            xp = jax.random.normal(key, (panel, k), jnp.float32)
-            yp = xp @ theta_true
-            return xp, yp
-
-        theta = np.asarray(fit_streaming(n, k, panel_fn, panel_rows=panel,
-                                         mesh=mesh8))
-        np.testing.assert_allclose(theta, np.asarray(theta_true),
-                                   rtol=1e-3, atol=1e-3)
-
-    def test_streaming_high_symmetric_matches_oracle(self, mesh8):
-        # round-3: precision="high" on f32 panels takes the SYMMETRIC
-        # 2-pass bf16 split; theta must still recover to f32-level
-        # accuracy and agree with the "highest" path closely
-        import jax
-        import jax.numpy as jnp
-        from matrel_tpu.workloads.linreg import fit_streaming
-        k, n, panel = 16, 1024, 256
-        theta_true = jnp.linspace(-2.0, 2.0, k).reshape(k, 1)
-
-        def panel_fn(p):
-            key = jax.random.fold_in(jax.random.PRNGKey(3), p)
-            xp = jax.random.normal(key, (panel, k), jnp.float32)
-            yp = xp @ theta_true
-            return xp, yp
-
-        th_high = np.asarray(fit_streaming(n, k, panel_fn,
-                                           panel_rows=panel, mesh=mesh8,
-                                           precision="high"))
-        th_highest = np.asarray(fit_streaming(n, k, panel_fn,
-                                              panel_rows=panel,
-                                              mesh=mesh8,
-                                              precision="highest"))
-        np.testing.assert_allclose(th_high, np.asarray(theta_true),
-                                   rtol=5e-3, atol=5e-3)
-        np.testing.assert_allclose(th_high, th_highest, rtol=5e-3,
-                                   atol=5e-3)
-
-    def test_symmetric_gram_term_equivalence(self):
-        # the 2-pass identity itself: HiHi + HiLo + HiLo^T equals the
-        # generic 3-term split HiHi + HiLo + LoHi exactly
-        import jax.numpy as jnp
-        rng = np.random.default_rng(5)
-        x = jnp.asarray(rng.standard_normal((64, 8)), jnp.float32)
-        hi = x.astype(jnp.bfloat16)
-        lo = (x - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-        d = lambda a, b: jnp.einsum("nk,nj->kj", a, b,
-                                    preferred_element_type=jnp.float32)
-        sym = d(hi, hi) + d(hi, lo) + d(hi, lo).T
-        generic = d(hi, hi) + d(hi, lo) + d(lo, hi)
-        np.testing.assert_allclose(np.asarray(sym), np.asarray(generic),
-                                   rtol=0, atol=0)
-
+def test_symmetric_gram_term_equivalence():
+    # ops/gram.py's 2-pass identity: HiHi + HiLo + HiLo^T equals the
+    # generic 3-term split HiHi + HiLo + LoHi exactly
+    import jax.numpy as jnp
+    from matrel_tpu.ops.gram import hi_lo_split, symmetric_gram
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((64, 8)),
+                    jnp.float32)
+    hi, lo = hi_lo_split(x)
+    d = lambda a, b: jnp.einsum("nk,nj->kj", a, b,
+                                preferred_element_type=jnp.float32)
+    generic = d(hi, hi) + d(hi, lo) + d(lo, hi)
+    np.testing.assert_allclose(np.asarray(symmetric_gram(x, d)),
+                               np.asarray(generic), rtol=0, atol=0)
 
 
 class TestEdgePageRank:
@@ -159,23 +78,46 @@ class TestEdgePageRank:
         np.testing.assert_allclose(r, oracle, rtol=1e-3, atol=1e-7)
         assert r.sum() == pytest.approx(1.0, rel=1e-3)
 
-    def test_csr_matches_edges(self, mesh8, rng):
-        from matrel_tpu.workloads.pagerank import pagerank_csr, pagerank_edges
-        n = 80
-        a = (rng.random((n, n)) < 0.1).astype(np.float32)
+    @pytest.mark.parametrize("impl", ["segment", "onehot", "auto", "dense"])
+    def test_out_mass_below_one_is_not_clamped(self, impl, mesh8, rng):
+        # a weighted graph whose out-masses lie below 1: the floor of the
+        # inverse out-mass must be an epsilon, not 1.0, or ranks skew
+        # silently (regression); every executor a CPU runs, and the dense
+        # adjacency on the mesh
+        n = 32
+        a = np.zeros((n, n), dtype=np.float32)
+        a[0:8, 8:16] = 0.1 * (rng.random((8, 8)) < 0.6)
+        a[8:16, 16:24] = 0.1 * (rng.random((8, 8)) < 0.6)
+        a[16:24, 0:8] = 0.1 * (rng.random((8, 8)) < 0.6)
         np.fill_diagonal(a, 0)
-        src, dst = np.nonzero(a)
-        r_csr = np.asarray(pagerank_csr(src, dst, n, rounds=20))
-        r_seg = np.asarray(pagerank_edges(src, dst, n, rounds=20))
-        np.testing.assert_allclose(r_csr, r_seg, rtol=1e-4, atol=1e-8)
+        assert 0 < a.sum(1).max() < 1
+        if impl == "dense":
+            r = np.asarray(pagerank.pagerank(
+                BlockMatrix.from_numpy(a, mesh=mesh8), rounds=20)).ravel()
+        else:
+            src, dst = np.nonzero(a)
+            r = np.asarray(pagerank.pagerank_edges(
+                src, dst, n, rounds=20, impl=impl, weights=a[src, dst]))
+        oracle = pagerank.pagerank_numpy_oracle(a, rounds=20).ravel()
+        np.testing.assert_allclose(r, oracle, rtol=1e-3, atol=1e-6)
 
-    def test_csr_fallback_on_hub(self, rng):
+    @pytest.mark.parametrize("impl", ["segment", "onehot"])
+    @pytest.mark.parametrize("graph", ["hub", "near_regular"])
+    def test_edges_match_the_plain_reference(self, graph, impl, rng):
         from matrel_tpu.workloads import pagerank as pr
-        n = 50
-        # hub graph: every node points at node 0 (in-degree 49 >> mean 1)
-        src = np.arange(1, n, dtype=np.int32)
-        dst = np.zeros(n - 1, dtype=np.int32)
-        r = np.asarray(pr.pagerank_csr(src, dst, n, rounds=10))
+        if graph == "hub":
+            # every node points at node 0: in-degree 49 beside a mean of 1
+            n = 50
+            src = np.arange(1, n, dtype=np.int32)
+            dst = np.zeros(n - 1, dtype=np.int32)
+        else:
+            n = 80
+            a = (rng.random((n, n)) < 0.1).astype(np.float32)
+            np.fill_diagonal(a, 0)
+            src, dst = np.nonzero(a)
+        r = np.asarray(pr.pagerank_edges(src, dst, n, rounds=20, impl=impl))
+        want = pr.pagerank_reference_edges(src, dst, n, rounds=20)
+        np.testing.assert_allclose(r, want, rtol=1e-4, atol=1e-8)
         assert r.shape == (n,) and abs(r.sum() - 1.0) < 1e-3
 
 
@@ -321,114 +263,6 @@ class TestPlanRecognition:
         assert peak < 4 * m // 2
 
 
-class TestStreamingBigChain:
-    def test_streaming_chain_matches_numpy(self, mesh8):
-        import jax.numpy as jnp
-        from matrel_tpu.workloads.big_chain import streaming_chain, default_gen
-        n, tile, panel = 64, 8, 16
-        gens = tuple(default_gen(s, tile, jnp.float32, 0.05) for s in (1, 2, 3))
-        got = float(streaming_chain(n, *gens, tile=tile, panel=panel,
-                                    dtype=jnp.float32))
-        kt = n // tile
-        full = [np.block([[np.asarray(g(jnp.int32(i), jnp.int32(j)))
-                           for j in range(kt)] for i in range(kt)])
-                for g in gens]
-        oracle = float(((full[0] @ full[1] @ full[2]) ** 2).sum())
-        assert got == pytest.approx(oracle, rel=1e-4)
-
-    def test_rejects_misaligned(self):
-        from matrel_tpu.workloads.big_chain import streaming_chain, default_gen
-        g = default_gen(0, 8)
-        with pytest.raises(ValueError):
-            streaming_chain(60, g, g, g, tile=8, panel=16)
-
-    @pytest.mark.parametrize("mk", ["default_gen", "cheap_gen"])
-    def test_slab_matches_numpy_and_accum(self, mk):
-        import jax.numpy as jnp
-        from matrel_tpu.workloads import big_chain
-        gen_factory = getattr(big_chain, mk)
-        n, tile, panel = 64, 8, 16
-        gens = tuple(gen_factory(s, tile, jnp.float32, 0.05)
-                     for s in (1, 2, 3))
-        slab = float(big_chain.streaming_chain_slab(
-            n, *gens, tile=tile, panel=panel, dtype=jnp.float32))
-        accum = float(big_chain.streaming_chain(
-            n, *gens, tile=tile, panel=panel, dtype=jnp.float32))
-        full = [np.asarray(g.slab(0, 0, (n, n)), dtype=np.float64)
-                for g in gens]
-        oracle = float(((full[0] @ full[1] @ full[2]) ** 2).sum())
-        assert slab == pytest.approx(accum, rel=1e-5)
-        assert slab == pytest.approx(oracle, rel=1e-4)
-
-    def test_slab_gen_consistency(self):
-        # .slab(r0, c0) must produce exactly the tiles gen(bi, bj) does
-        import jax.numpy as jnp
-        from matrel_tpu.workloads.big_chain import default_gen, cheap_gen
-        for mk in (default_gen, cheap_gen):
-            g = mk(3, 8, jnp.float32, 0.05)
-            tile_11 = np.asarray(g(1, 2))
-            slab = np.asarray(g.slab(8, 16, (8, 8)))
-            np.testing.assert_allclose(slab, tile_11, atol=2e-7)
-
-    def test_slab_requires_capable_gens(self):
-        from matrel_tpu.workloads.big_chain import streaming_chain_slab
-        with pytest.raises(ValueError, match="slab"):
-            streaming_chain_slab(64, lambda i, j: None, lambda i, j: None,
-                                 lambda i, j: None, tile=8, panel=16)
-
-    def test_sharded_matches_single(self, mesh8):
-        import jax.numpy as jnp
-        from matrel_tpu.workloads.big_chain import (
-            streaming_chain, streaming_chain_sharded, default_gen)
-        n, tile, panel = 128, 8, 16  # 8 panels = 1 per device
-        gens = tuple(default_gen(s, tile, jnp.float32, 0.05) for s in (1, 2, 3))
-        single = float(streaming_chain(n, *gens, tile=tile, panel=panel,
-                                       dtype=jnp.float32))
-        sharded = float(streaming_chain_sharded(n, *gens, mesh=mesh8,
-                                                tile=tile, panel=panel,
-                                                dtype=jnp.float32))
-        assert sharded == pytest.approx(single, rel=1e-5)
-
-
-class TestBlockSparsePageRank:
-    def test_matches_dense_oracle(self, mesh8, rng):
-        from matrel_tpu.core.sparse import BlockSparseMatrix
-        from matrel_tpu.workloads.pagerank import (
-            pagerank_block_sparse, pagerank_numpy_oracle)
-        n, bs = 32, 8
-        # clustered adjacency: a few dense blocks
-        a = np.zeros((n, n), dtype=np.float32)
-        a[0:8, 8:16] = (rng.random((8, 8)) < 0.6)
-        a[8:16, 0:8] = (rng.random((8, 8)) < 0.6)
-        a[16:24, 24:32] = (rng.random((8, 8)) < 0.6)
-        np.fill_diagonal(a, 0)
-        S = BlockSparseMatrix.from_numpy(a, block_size=bs, mesh=mesh8)
-        from matrel_tpu.config import MatrelConfig
-        r = np.asarray(pagerank_block_sparse(S, rounds=20,
-                                             config=MatrelConfig(use_pallas=False)))
-        oracle = pagerank_numpy_oracle(a, rounds=20)
-        np.testing.assert_allclose(r, oracle, rtol=1e-3, atol=1e-6)
-
-    def test_weighted_adjacency_small_row_sums(self, mesh8, rng):
-        # Row sums < 1 (weighted graph): the inverse-degree floor must be
-        # an epsilon, not 1.0, or ranks skew silently (regression).
-        from matrel_tpu.core.sparse import BlockSparseMatrix
-        from matrel_tpu.workloads.pagerank import (
-            pagerank_block_sparse, pagerank_numpy_oracle)
-        from matrel_tpu.config import MatrelConfig
-        n, bs = 32, 8
-        a = np.zeros((n, n), dtype=np.float32)
-        a[0:8, 8:16] = 0.1 * (rng.random((8, 8)) < 0.6)
-        a[8:16, 16:24] = 0.1 * (rng.random((8, 8)) < 0.6)
-        a[16:24, 0:8] = 0.1 * (rng.random((8, 8)) < 0.6)
-        np.fill_diagonal(a, 0)
-        S = BlockSparseMatrix.from_numpy(a, block_size=bs, mesh=mesh8)
-        r = np.asarray(pagerank_block_sparse(
-            S, rounds=20, config=MatrelConfig(use_pallas=False)))
-        oracle = pagerank_numpy_oracle(a, rounds=20)
-        np.testing.assert_allclose(r, oracle, rtol=1e-3, atol=1e-6)
-
-
 class TestTriangleCount:
     def test_matches_numpy_oracle(self, mesh8, rng):
         from matrel_tpu.workloads import triangles as T
@@ -505,24 +339,6 @@ class TestCosineSimilarity:
                 if c == (jnp.bfloat16, jnp.bfloat16)], calls
         np.testing.assert_allclose(
             out, S.cosine_similarity_numpy_oracle(x), rtol=5e-3, atol=5e-3)
-
-
-def test_fit_fused_honors_high_precision(mesh8, rng):
-    # round-3: fit_fused with precision="high" takes the symmetric
-    # 2-pass Gram and still recovers theta
-    import jax.numpy as jnp
-    from matrel_tpu.config import MatrelConfig
-    from matrel_tpu.workloads.linreg import fit_fused
-    from matrel_tpu.core.blockmatrix import BlockMatrix
-    from jax.sharding import PartitionSpec as P
-    x = rng.standard_normal((256, 8)).astype(np.float32)
-    tt = np.linspace(1, 2, 8).reshape(8, 1).astype(np.float32)
-    y = x @ tt
-    X = BlockMatrix.from_numpy(x, mesh=mesh8, spec=P(("x", "y"), None))
-    Y = BlockMatrix.from_numpy(y, mesh=mesh8, spec=P(("x", "y"), None))
-    th = np.asarray(fit_fused(X, Y,
-                              config=MatrelConfig(matmul_precision="high")))
-    np.testing.assert_allclose(th, tt, rtol=5e-3, atol=5e-3)
 
 
 class TestPowerIteration:

@@ -547,9 +547,8 @@ class KernelSeamRule(Rule):
     where future GPU/multi-backend kernels must land, ROADMAP north
     star). Scope: ``matrel_tpu/ops/`` (the executor's kernel modules);
     the registry module itself is the sanctioned home. The legacy
-    SpMV/SpMM paths (ops/pallas_spmv.py, ops/pallas_spmm.py,
-    ops/spmv_routed.py) predate the registry and stay unported this
-    round — they carry justified inline suppressions, which double as
+    SpMV/SpMM paths (ops/pallas_spmv.py, ops/pallas_spmm.py) predate
+    the registry and stay unported this round — they carry justified inline suppressions, which double as
     the porting worklist."""
 
     id = "ML009"

@@ -181,8 +181,7 @@ def soak_spmv(n_trials: int, base: int, tol: float):
 
 def soak_sharded(n_trials: int, base: int, tol: float):
     """Mesh-sharded sparse paths vs scipy oracles: tile-stack SpMM
-    (spmm_sharded) and one-hot sharded SpMV (spmv_sharded). The routed
-    formulation has its own battery (soak_routed)."""
+    (spmm_sharded) and one-hot sharded SpMV (spmv_sharded)."""
     import numpy as np
     import scipy.sparse as sp
     import jax.numpy as jnp
@@ -284,55 +283,6 @@ def soak_sharded(n_trials: int, base: int, tol: float):
                                        rtol=5e-3, atol=5e-3)
         except Exception as ex:  # noqa: BLE001
             fails.append(("sharded", trial, type(ex).__name__,
-                          str(ex)[:150]))
-    return fails
-
-
-def soak_routed(n_trials: int, base: int, tol: float,
-                interpret: bool = True):
-    """Routed (gather-free) SpMV plans vs scipy. ``interpret=True`` is
-    the CPU battery; ``interpret=False`` under --tpu runs the kernels
-    through REAL Mosaic once per round (VERDICT r3 #7: a kept kernel
-    that only ever ran interpret mode is latent rot — real-chip soak
-    has caught Mosaic bugs CI missed, e.g. seed 50114)."""
-    import numpy as np
-    import scipy.sparse as sp
-    import jax.numpy as jnp
-    from matrel_tpu.ops import spmv_routed as rt
-
-    fails = []
-    for trial in range(n_trials):
-        rng = np.random.default_rng(base + trial)
-        try:
-            if interpret:
-                n_r = int(rng.integers(1000, 50_000))
-                n_c = int(rng.integers(1000, 50_000))
-                m = int(rng.integers(100, 40_000))
-            else:
-                # on-chip: small shapes — this battery validates Mosaic
-                # lowering, not throughput (the routed path measured 52
-                # ms vs 29 at row-5 scale and is kept as a reference
-                # formulation)
-                n_r = int(rng.integers(1000, 8_000))
-                n_c = int(rng.integers(1000, 8_000))
-                m = int(rng.integers(100, 10_000))
-            rows = rng.integers(0, n_r, m)
-            cols = rng.integers(0, n_c, m)
-            vals = rng.standard_normal(m).astype(np.float32)
-            plan = rt.build_routed_plan(rows, cols, vals, n_r, n_c,
-                                        max_padding=50.0)
-            if plan is None:
-                continue
-            x = rng.standard_normal(n_c).astype(np.float32)
-            want = sp.coo_matrix((vals, (rows, cols)),
-                                 shape=(n_r, n_c)) @ x
-            scale = max(float(np.abs(want).max()), 1.0)
-            got = np.asarray(rt.routed_spmv(plan, jnp.asarray(x),
-                                            interpret=interpret))
-            np.testing.assert_allclose(got / scale, want / scale,
-                                       rtol=tol, atol=tol)
-        except Exception as ex:  # noqa: BLE001
-            fails.append(("routed", trial, type(ex).__name__,
                           str(ex)[:150]))
     return fails
 
@@ -1680,8 +1630,8 @@ def soak_durable(n_trials: int, base: int, tol: float):
 def main():
     p = argparse.ArgumentParser()
     p.add_argument("battery",
-                   choices=["fuzz", "deep", "spmv", "sharded", "routed",
-                            "ckpt", "serve", "precision", "chaos",
+                   choices=["fuzz", "deep", "spmv", "sharded", "ckpt",
+                            "serve", "precision", "chaos",
                             "sparse_kernels", "fusion", "overload",
                             "stream", "fleet", "cse", "race",
                             "coeffs", "durable", "all"])
@@ -1732,16 +1682,6 @@ def main():
                                      args.base, tol)
     if args.battery in ("fusion", "all"):
         fails += soak_fusion(max(args.seeds // 4, 6), args.base, tol)
-    if args.battery in ("routed", "all"):
-        if args.tpu:
-            # REAL-Mosaic routed battery: few trials, small shapes —
-            # enough to prove the kernels lower and agree with scipy on
-            # the chip (VERDICT r3 #7)
-            fails += soak_routed(max(args.seeds // 4, 3), args.base,
-                                 5e-4, interpret=False)
-        else:
-            fails += soak_routed(max(args.seeds // 2, 5), args.base,
-                                 5e-4)
     print(f"SOAK COMPLETE: {len(fails)} failures")
     for f in fails[:20]:
         print(" ", f)
