@@ -330,6 +330,48 @@ def plan_facts(plan, entries: int) -> dict:
     return facts
 
 
+def sampled_facts(plan, entries: int, shared: bool) -> dict:
+    """What plan.meta["sampled"] and a ``matrel.sampled.plan`` span say
+    of the plan a sampled product runs on (executor._sampled_product;
+    ``entries``: the matrix's nnz; ``shared``: the product's dense side
+    is the factor whose rows its sources name, so one gather serves the
+    entry's dot and the scatter): ``entries`` (ALL of them: every one is
+    sampled), ``dense_entries`` (those on the slab, whose quotient the
+    MXU makes a panel at a time; 0 where the matrix has no dense part),
+    ``lines``, ``slab_dtype`` and ``panel_rows`` (the slab rows a panel
+    of the quotient takes), and of the compact parts, which hold the
+    rest, :func:`plan_facts`' ``layout``, ``slots``, ``chunks``,
+    ``source_panels``, ``overflow_edges`` and, at this product's own
+    panel size (a slot holds one or two gathered rows more),
+    ``panels`` (pallas_spmv.sampled_panels: split further where a
+    panel's destinations would not make a fast gather table);
+    ``hbm_plan_bytes``: the tables, the largest panel's
+    temporaries, the slab once and one panel of its quotient."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    from matrel_tpu.parallel import strategies
+    own = plan_facts(plan, entries)
+    more = 1 if shared else 2
+    sets = pc.sampled_panels(plan, shared)
+    dense = getattr(plan, "dense", None)
+    facts = {k: own[k] for k in ("layout", "slots", "chunks",
+                                 "source_panels", "overflow_edges")}
+    facts.update(
+        entries=int(entries), dense_entries=own.get("dense_entries", 0),
+        lines=own.get("dense_lines", 0),
+        slab_dtype=own.get("dense_dtype", ""),
+        panel_rows=strategies.ACC_PANEL_ROWS if dense is not None else 0,
+        shared_gather=bool(shared),
+        panels=int(sum(-(-rows // per) for per, _, _, rows in sets)),
+        hbm_plan_bytes=int(
+            pc.TABLE_BYTES_A_SLOT * own["slots"]
+            + pc._wide_slot_bytes(more) * max(per * cap
+                                              for per, _, cap, _ in sets)
+            + (0 if dense is None else dense.slab.nbytes
+               # a panel's cells, dot and quotient, float32
+               + 3 * 4 * strategies.ACC_PANEL_ROWS * dense.width)))
+    return facts
+
+
 @dataclasses.dataclass
 class COOMatrix:
     """Immutable element-sparse matrix over a fixed coordinate list."""

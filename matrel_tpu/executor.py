@@ -29,7 +29,8 @@ from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib, padding
 from matrel_tpu.core.blockmatrix import BlockMatrix
 from matrel_tpu.ir import expr as expr_mod, rules
-from matrel_tpu.ir.expr import MatExpr, leaves as expr_leaves
+from matrel_tpu.ir.expr import (COO_NARROW_MAX, MatExpr,  # noqa: F401 (re-exported)
+                                leaves as expr_leaves)
 from matrel_tpu.obs import trace as trace_lib
 from matrel_tpu.parallel import planner, strategies
 from matrel_tpu.resilience import faults as faults_lib
@@ -114,6 +115,10 @@ class Lowerer:
         # was densified (plan.meta["densified_products"])
         self.spmm: List[dict] = []
         self.densified: List[dict] = []
+        # and what each sampled product lowered through where it was
+        # answered fused (plan.meta["sampled"]: core.coo.sampled_facts,
+        # a ``matrel.sampled.plan`` span at every dispatch)
+        self.sampled: List[dict] = []
         # a long Gram and the product that rides its loop
         # (planner.gram_riders: {uid: (gram, rider)} under both uids),
         # and, while a trace runs, the riders' products by uid
@@ -245,10 +250,23 @@ class Lowerer:
             # SpMM fast path handles the matmul case below
             return node.attrs["matrix"].to_dense(self.config).data
         if k == "coo_leaf":
-            # same densify fallback for element-sparse leaves; matmuls
-            # take the one-hot SpMV path in _matmul
+            # an element-sparse leaf read as an array is densified, and
+            # said to be (plan.meta["densified_products"]). Its products
+            # with a narrow dense side never come here: the SpMV tables
+            # answer them in _matmul, and those of a ``sampled`` node
+            # over it the fused sampled product
+            self._densifies(node.attrs["matrix"])
             return node.attrs["matrix"].to_block(self.mesh,
                                                  self.config).data
+        if k == "sampled":
+            # not an operand of a product that answers it fused
+            # (_sampled_dispatch_plan): the element-wise node it was
+            # written as, the leaf densified and the product whole
+            s, a, b = node.children
+            self._ran("xla")
+            return self._elemwise_op(
+                node.attrs["op"], ev(s), strategies.run_matmul(
+                    "xla", ev(a), ev(b), self.mesh, self.config))
         if k == "transpose":
             return ev(node.children[0]).T
         if k == "matmul":
@@ -458,6 +476,12 @@ class Lowerer:
                                 (0, pshape[1] - out.shape[1])))
         return out
 
+    def _densifies(self, S) -> None:
+        fell = {"shape": list(S.shape), "entries": S.nnz,
+                "bytes": 4 * S.shape[0] * S.shape[1]}
+        if fell not in self.densified:      # a retrace says it again
+            self.densified.append(fell)
+
     def _pad_to_node(self, out: Array, node: MatExpr) -> Array:
         pshape = padding.padded_shape(node.shape, self.mesh)
         return jnp.pad(out, ((0, pshape[0] - out.shape[0]),
@@ -632,6 +656,13 @@ class Lowerer:
         if _spgemm_dispatch(node, self.config):
             return self._spgemm(node, epilogue=epilogue,
                                 epilogue_elementwise=epilogue_elementwise)
+        # sampled × dense: (S op (A·B))·Z through S's SpMV plan, the
+        # sampled values made on the way (_sampled_product); where no
+        # plan answers it the node lowers as an array below, densified
+        if l.kind == "sampled" or r.kind == "sampled":
+            plan = _sampled_dispatch_plan(node, self.mesh, self.config)
+            if plan is not None:
+                return fin(self._sampled_product(node, plan, ev))
         # coo_leaf × dense: the SpMV tables' k-wide product where the
         # dense side has at most COO_NARROW_MAX columns (a gathered row
         # of up to 128 float32 fills 128 lanes: 128 columns cost what 8
@@ -649,10 +680,7 @@ class Lowerer:
             dense = l if flipped else r
             plan = _coo_dispatch_plan(node)
             if plan is None:
-                fell = {"shape": list(S.shape), "entries": S.nnz,
-                        "bytes": 4 * S.shape[0] * S.shape[1]}
-                if fell not in self.densified:
-                    self.densified.append(fell)
+                self._densifies(S)
                 blk = S.to_block(self.mesh, self.config).data
                 self._ran("xla")
                 a, b = (ev(l), blk) if flipped else (blk, ev(r))
@@ -782,6 +810,56 @@ class Lowerer:
                                      self.config, epilogue=storage_epi,
                                      panels=panels, out_dtype=store)
 
+    def _sampled_product(self, node: MatExpr, plan, ev) -> Array:
+        """``sampled · Z`` or ``Z · sampled`` (= (sampledᵀ · Zᵀ)ᵀ, on
+        the matrix's transposed plan) for a sampled node ``S op (A·B)``
+        and a dense side of at most COO_NARROW_MAX columns, fused
+        (ops/pallas_spmv.sampled_matmat_parts): an entry's value is made
+        from the two rows its coordinates name in ``A`` and ``t(B)``
+        and scattered at once; neither ``A·B`` nor the sampled values
+        exist whole. Where ``Z`` is the factor whose rows the plan's
+        sources name (t(W) · (V ./ (W·H)); (V ./ (W·H)) · t(H)) one
+        gather serves both."""
+        from matrel_tpu.config import pallas_interpret_mode
+        from matrel_tpu.core.coo import sampled_facts
+        from matrel_tpu.ops import pallas_spmv as pc
+        l, r = node.children
+        flipped = l.kind != "sampled"
+        smp, dense = (r, l) if flipped else (l, r)
+        S, A, B = smp.children
+        k = dense.shape[0] if flipped else dense.shape[1]
+        inner = A.shape[1]
+
+        def a_rows() -> Array:
+            return ev(A)[: S.shape[0], :inner]
+
+        def b_rows() -> Array:
+            return ev(B).T[: S.shape[1], :inner]
+
+        # the plan's sources are S's columns (t(B)'s rows) in the
+        # forward product, its rows (A's) in the transposed one
+        of_src, of_dst = (a_rows, b_rows) if flipped else (b_rows, a_rows)
+        shared = _same_table(dense, flipped, A if flipped else B,
+                             not flipped)
+        facts = {"orientation": "transposed" if flipped else "forward",
+                 "op": smp.attrs["op"], "k": k, "inner": inner,
+                 **sampled_facts(plan, S.attrs["matrix"].nnz, shared)}
+        if facts not in self.sampled:       # a retrace says it again
+            self.sampled.append(facts)
+        self._ran("pallas_spmv")
+
+        z = ev(dense)
+        z = (z.T if flipped else z)[: plan.n_cols, :k]
+        with trace_lib.span("sampled.plan", hit=False, **facts):
+            with trace_lib.span("spmm.plan.upload"):
+                static, part_statics, part_arrays = pc.plan_operands(plan)
+            out = pc.sampled_matmat_parts(
+                static, part_statics, part_arrays, z, smp.attrs["op"],
+                None if shared else of_src(), of_dst(),
+                interpret=pallas_interpret_mode(self.config),
+                panels=pc.sampled_panels(plan, shared))
+        return self._pad_to_node(out.T if flipped else out, node)
+
     def _long_contraction(self, node: MatExpr, ev) -> Optional[Array]:
         """A product over a LONG float32 contraction (the regression's
         t(X)·X and t(X)·y over millions of rows) multiplied where its
@@ -877,25 +955,28 @@ class Lowerer:
             a = self._slice_for_broadcast(a, l.shape, node.shape)
             b = self._slice_for_broadcast(b, r.shape, node.shape)
         op = node.attrs["op"]
-        if op == "add":
-            out = a + b
-        elif op == "sub":
-            out = a - b
-        elif op == "mul":
-            out = a * b
-        elif op == "div":
-            safe_b = jnp.where(b == 0, jnp.ones((), b.dtype), b)
-            out = jnp.where(b == 0, jnp.zeros((), jnp.result_type(a, b)),
-                            a / safe_b)
-        elif op == "min":
-            out = jnp.minimum(a, b)
-        elif op == "max":
-            out = jnp.maximum(a, b)
-        else:
-            raise NotImplementedError(op)
+        out = self._elemwise_op(op, a, b)
         if broadcast and op != "mul":
             out = _mask_to_logical(out, node.shape)
         return out
+
+    @staticmethod
+    def _elemwise_op(op: str, a: Array, b: Array) -> Array:
+        if op == "add":
+            return a + b
+        if op == "sub":
+            return a - b
+        if op == "mul":
+            return a * b
+        if op == "div":
+            safe_b = jnp.where(b == 0, jnp.ones((), b.dtype), b)
+            return jnp.where(b == 0, jnp.zeros((), jnp.result_type(a, b)),
+                             a / safe_b)
+        if op == "min":
+            return jnp.minimum(a, b)
+        if op == "max":
+            return jnp.maximum(a, b)
+        raise NotImplementedError(op)
 
     @staticmethod
     def _slice_for_broadcast(x: Array, lshape, out_shape) -> Array:
@@ -1440,6 +1521,8 @@ def _coo_meta(meta: Dict, low: "Lowerer") -> None:
     ``densified_products``, the leaves that were densified instead."""
     if low.spmm:
         meta["spmm"] = low.spmm
+    if low.sampled:
+        meta["sampled"] = low.sampled
     if low.densified:
         meta["densified_products"] = low.densified
 
@@ -1535,13 +1618,11 @@ def compile_exprs(exprs, mesh: Optional[Mesh] = None,
                      extra_args=extra, meta=meta)
 
 
-# The widest dense side the COO SpMV tables multiply: the columns one
-# pass of the k-wide kernel takes (a gathered float32 row fills 128 lanes,
-# ops/pallas_spmv.WIDE_COLS). A wider side, or a matrix whose plan was
-# refused, densifies the leaf; the planner asks _coo_dispatch_plan itself
-# (not this constant), so it prices, and on one device refuses, exactly
-# the fall-through that would run.
-COO_NARROW_MAX = 128
+# COO_NARROW_MAX (ir/expr.py): the widest dense side the COO SpMV tables
+# multiply. A wider side, or a matrix whose plan was refused, densifies
+# the leaf; the planner asks _coo_dispatch_plan itself (not the
+# constant), so it prices, and on one device refuses, exactly the
+# fall-through that would run.
 
 
 #: Matmul operand kinds the SpGEMM dispatch accepts.
@@ -1691,6 +1772,43 @@ def _coo_dispatch_plan(node: MatExpr):
     if k > 1:
         return m._get_wide_plan(transposed=flipped)
     return m._get_plan_t() if flipped else m._get_plan()
+
+
+def _sampled_dispatch_plan(node: MatExpr, mesh: Mesh,
+                           config: Optional[MatrelConfig] = None):
+    """The plan a matmul with a ``sampled`` operand is answered FUSED
+    through (Lowerer._sampled_product): the k-wide plan of the sampled
+    leaf's matrix in the product's orientation — or None, and the
+    sampled node then lowers as the dense array it stands for. Fused
+    where the other side is dense with at most COO_NARROW_MAX columns,
+    on one device, where the compact-table Pallas executor runs
+    (config.pallas_enabled) and the planner did not refuse the matrix.
+    SINGLE source of truth, shared by the lowering and the planner's
+    memory reckoning (planner.coo_product)."""
+    from matrel_tpu.config import pallas_enabled
+    l, r = node.children
+    flipped = l.kind != "sampled"
+    smp, dense = (r, l) if flipped else (l, r)
+    if smp.kind != "sampled" or dense.kind in (
+            "sampled", "sparse_leaf", "coo_leaf"):
+        return None
+    k = dense.shape[0] if flipped else dense.shape[1]
+    if (not 0 < k <= COO_NARROW_MAX or mesh.size != 1
+            or not pallas_enabled(config)):
+        return None
+    return smp.children[0].attrs["matrix"]._get_wide_plan(
+        transposed=flipped)
+
+
+def _same_table(z: MatExpr, z_t: bool, f: MatExpr, f_t: bool) -> bool:
+    """Whether ``z`` (read transposed where ``z_t``) and ``f`` (where
+    ``f_t``) are the same array: the same node under transposes of the
+    same parity."""
+    while z.kind == "transpose":
+        z, z_t = z.children[0], not z_t
+    while f.kind == "transpose":
+        f, f_t = f.children[0], not f_t
+    return z.uid == f.uid and z_t == f_t
 
 
 def _autotune_spmv_choices(opts, mesh, cfg) -> dict:

@@ -12,7 +12,9 @@ Node set mirrors the reference's logical operators:
   ScalarOp (add/mul/pow by a scalar), Agg (sum/count/avg/max/min over
   row/col/all/diag — covers rowSum/colSum/sum/trace), Vec, RankOneUpdate,
   Inverse/Solve (dense local linear solves — the normal-equations step),
-  SelectValue/SelectIndex (relational σ), JoinOnIndex/JoinOnValue (⋈).
+  SelectValue/SelectIndex (relational σ), JoinOnIndex/JoinOnValue (⋈),
+  Sampled (an element-sparse leaf ∘ or ./ a dense product, defined only
+  at the leaf's entries — SystemML's wdivmm / wsloss family).
 
 All shape/sparsity metadata lives on the nodes so the optimizer runs as pure
 Python before any tracing.
@@ -298,6 +300,35 @@ def elemwise(op: str, a: MatExpr, b: MatExpr) -> MatExpr:
     return MatExpr("elemwise", (a, b), shape, nnz, {"op": op})
 
 
+SAMPLED_OPS = ("div", "mul")
+# The widest dense side the COO SpMV tables multiply, and the longest
+# inner dimension of a sampled product: the columns one pass of the
+# k-wide kernel takes (a gathered float32 row fills 128 lanes,
+# ops/pallas_spmv.WIDE_COLS).
+COO_NARROW_MAX = 128
+
+
+def sampled(op: str, s: MatExpr, a: MatExpr, b: MatExpr) -> MatExpr:
+    """``S op (A·B)`` for an element-sparse leaf ``S`` and a dense
+    product, ``op`` "div" (S ./ (A·B)) or "mul" (S ∘ (A·B)): a value
+    defined only at S's entries and zero elsewhere (0 / x = 0, and
+    x / 0 = 0 as the element-wise div gives it), so it has S's
+    structure and the product A·B is never wanted whole. Its three
+    children are the leaf and the product's two factors; written by
+    ``rules.sampled_product``, never by the DSL. Under a product with a
+    narrow dense side the executor answers it fused, the quotient never
+    stored (executor._sampled_product); anywhere else it lowers as the
+    element-wise node it came from, the leaf densified."""
+    if op not in SAMPLED_OPS:
+        raise ValueError(f"unknown sampled op {op}")
+    if s.kind != "coo_leaf":
+        raise ValueError("sampled: the sampling operand must be a coo_leaf")
+    if a.shape[1] != b.shape[0] or s.shape != (a.shape[0], b.shape[1]):
+        raise ValueError(f"sampled shape mismatch: {s.shape} against "
+                         f"{a.shape} x {b.shape}")
+    return MatExpr("sampled", (s, a, b), s.shape, s.nnz, {"op": op})
+
+
 def scalar_op(op: str, a: MatExpr, s: float) -> MatExpr:
     if op not in SCALAR_OPS:
         raise ValueError(f"unknown scalar op {op}")
@@ -495,7 +526,7 @@ def pretty(e: MatExpr, indent: int = 0, mesh=None,
     "rep" claim is config-dependent — review r5)."""
     pad = "  " * indent
     extra = ""
-    if e.kind == "elemwise":
+    if e.kind in ("elemwise", "sampled"):
         extra = f" op={e.attrs['op']}"
     elif e.kind == "scalar":
         extra = f" op={e.attrs['op']} v={e.attrs['value']}"

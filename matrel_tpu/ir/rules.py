@@ -26,6 +26,11 @@ Rules, mirroring the reference's Catalyst batch:
   R8 rank-1 multiply push-through: (A + u·vᵀ)·B → A·B + u·(vᵀ·B) and
      B·(A + u·vᵀ) → B·A + (B·u)·vᵀ — the outer product is never
      materialised inside a multiply chain (MatFast's rank-1 family).
+  R9 sampled product: S ./ (A·B), S ∘ (A·B), (A·B) ∘ S with S an
+     element-sparse leaf and A·B a dense product of a narrow inner
+     dimension → sampled(op, S, A, B): the product is wanted only at
+     S's entries (SystemML's wdivmm pattern; the KL-divergence NMF
+     updates).
 
 Each rule is a bottom-up tree transform; the batch runs to fixpoint with a
 bound, Catalyst-style.
@@ -38,7 +43,8 @@ from typing import Callable, List, Optional
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.ir import chain as chain_lib
 from matrel_tpu.ir.expr import (
-    MatExpr, agg, elemwise, matmul, scalar_op, select_index, transpose,
+    COO_NARROW_MAX, MatExpr, agg, elemwise, matmul, sampled, scalar_op,
+    select_index, transpose,
 )
 
 Rule = Callable[[MatExpr], Optional[MatExpr]]
@@ -218,6 +224,39 @@ def rank1_pushdown(e: MatExpr) -> Optional[MatExpr]:
     return None
 
 
+# -- R9: sampled product -----------------------------------------------------
+
+
+def sampled_product(e: MatExpr) -> Optional[MatExpr]:
+    """S ./ (A·B) → sampled(div, S, A, B); S ∘ (A·B) and (A·B) ∘ S →
+    sampled(mul, S, A, B), for a ``coo_leaf`` S and a product of two
+    dense operands whose inner dimension is at most COO_NARROW_MAX
+    (the two rows a sampled entry's dot gathers fill 128 lanes each).
+
+    The result is zero wherever S is, so A·B is wanted at S's entries
+    alone: under a product with a narrow dense side the executor never
+    stores either whole. (A·B) ./ S is not matched (it is dense: x / 0
+    is defined as 0 everywhere S is not). It fires by what it sees; the
+    lowering decides where the node is answered fused."""
+    if e.kind != "elemwise" or e.attrs["op"] not in ("div", "mul"):
+        return None
+    l, r = e.children
+    if l.shape != r.shape:
+        return None
+
+    def narrow(p: MatExpr) -> bool:
+        return (p.kind == "matmul"
+                and 0 < p.children[0].shape[1] <= COO_NARROW_MAX
+                and not any(c.kind in ("sparse_leaf", "coo_leaf")
+                            for c in p.children))
+
+    if l.kind == "coo_leaf" and narrow(r):
+        return sampled(e.attrs["op"], l, *r.children)
+    if e.attrs["op"] == "mul" and r.kind == "coo_leaf" and narrow(l):
+        return sampled("mul", r, *l.children)
+    return None
+
+
 # -- R7: solve fusion --------------------------------------------------------
 
 
@@ -251,6 +290,7 @@ _RULES: List[Rule] = [
     inverse_cancel,
     solve_fusion,
     rank1_pushdown,
+    sampled_product,
 ]
 # ahead of the chain DP an inverse stays a factor of its chain: fused
 # with its left-associated neighbour first, (XᵀX)⁻¹·Xᵀ·y would reach

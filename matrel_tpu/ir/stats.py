@@ -10,7 +10,8 @@ Estimation model (standard independence assumptions, as in MatFast/MatRel):
   density(A·B)   ≈ 1 - (1 - dA*dB)^k   (k = contraction dim)
   density(A+B)   ≈ min(1, dA + dB)
   density(A⊙B)  ≈ dA * dB
-  transpose/scalar-mul preserve density; scalar-add densifies.
+  transpose/scalar-mul preserve density; scalar-add densifies; a
+  sampled node (S op (A·B), ir/expr.py) has its leaf S's structure.
 """
 
 from __future__ import annotations
@@ -164,6 +165,14 @@ def integral_abs_bound(node, memo: dict = None):
             if None in (ba, bu, bv):
                 return None
             return ba + bu * bv
+        if k == "sampled":
+            # S .* (A·B) at S's entries; a quotient has no bound
+            if n.attrs.get("op") != "mul":
+                return None
+            bs, ba, bb = (walk(c) for c in n.children)
+            if None in (bs, ba, bb):
+                return None
+            return bs * float(n.children[1].shape[1]) * ba * bb
         if k == "join_index":
             mk = n.attrs.get("merge_kind")
             vals = [walk(c) for c in n.children]
@@ -244,6 +253,9 @@ def infer_integral(node, memo: dict = None) -> bool:
             return False             # avg divides
         if k == "rank1":
             return all(walk(c) for c in n.children)
+        if k == "sampled":
+            return (n.attrs.get("op") == "mul"
+                    and all(walk(c) for c in n.children))
         if k in ("join_index", "join_rows", "join_cols", "join_value"):
             # structured merges are closed over integers; callables are
             # black boxes

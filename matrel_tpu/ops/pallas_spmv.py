@@ -676,6 +676,18 @@ def spmv_compact_sharded(plan: spmv_lib.EdgeSpMVPlan, x: jax.Array,
 # ``_dense_part`` adds their product on the MXU; gather and scatter, which
 # cost by the entry, see what is left.
 
+#
+# A SAMPLED product (ir/expr.py ``sampled``, PR 46) is the same product
+# over values that are made on the way: an entry's value is the matrix's
+# own ``op`` (divided by, or times) the dot of the two 128-wide rows its
+# coordinates name in the factors of a dense product, so ``(S ./ (A·B))``
+# is never stored. ``sampled_matmat_parts`` gathers a slot's second row
+# beside the one the scatter takes anyway (one gather serves both where
+# the product's dense side IS that factor), multiplies and sums the pair
+# on the vector unit, and hands the quotient to the same scatter kernel
+# in ``val``'s place; the dense part makes a panel of the slab's quotient
+# on the MXU and multiplies it at once (``_sampled_dense_part``).
+
 WIDE_COLS = LANE        # columns a pass of the k-wide product takes
 # What a panel of the k-wide product keeps alive a slot, read off the
 # described-v5e compile at the Netflix shape (tests/test_chip_compile.py:
@@ -683,6 +695,12 @@ WIDE_COLS = LANE        # columns a pass of the k-wide product takes
 # two copies of the 246 MB output): the gathered row (512 B), the index
 # and the panel's slices of the tables.
 _TEMP_BYTES_A_SLOT_WIDE = 4 * WIDE_COLS + 24
+
+
+# and of a sampled product: the rows the entry's dot gathers beside them
+# (the destination's; the source's too where the scatter's own rows are
+# not that factor's), the dot and the quotient
+_TEMP_BYTES_A_SLOT_SAMPLED = 4 * WIDE_COLS + 8
 
 
 def _wide_sums(off, val, g_ref, height: int, passes: int):
@@ -774,16 +792,25 @@ def _wide_runner(n_chunks: int, chunk: int, nb: int, block: int,
     )
 
 
-def wide_panel_rows(rows: int, cap: int) -> int:
+def _wide_slot_bytes(sampled_rows: int) -> int:
+    """A panel's temporaries a slot: the k-wide product's own, and a
+    sampled product's ``sampled_rows`` further gathered rows."""
+    return (_TEMP_BYTES_A_SLOT_WIDE
+            + sampled_rows * _TEMP_BYTES_A_SLOT_SAMPLED)
+
+
+def wide_panel_rows(rows: int, cap: int, sampled_rows: int = 0) -> int:
     """:func:`panel_rows` for the k-wide product, whose panel holds a
-    slot's whole gathered row."""
-    return panel_rows(rows, cap, _TEMP_BYTES_A_SLOT_WIDE)
+    slot's whole gathered row (a sampled product's: ``sampled_rows``
+    more of them)."""
+    return panel_rows(rows, cap, _wide_slot_bytes(sampled_rows))
 
 
-def wide_panel_bytes(rows: int, cap: int) -> int:
+def wide_panel_bytes(rows: int, cap: int, sampled_rows: int = 0) -> int:
     """One panel's temporaries of the k-wide product over a plan of
     ``rows`` x ``cap`` slots."""
-    return _TEMP_BYTES_A_SLOT_WIDE * wide_panel_rows(rows, cap) * cap
+    return (_wide_slot_bytes(sampled_rows)
+            * wide_panel_rows(rows, cap, sampled_rows) * cap)
 
 
 def wide_plan_bytes(rows: int, cap: int) -> int:
@@ -863,15 +890,28 @@ def _chunk_sets(tables, n_cols: int, wins=None):
     return [st + (win,) for st, win in zip(sets, wins)]
 
 
-def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool):
+def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool,
+                     sampled=None):
     """``y`` (nb, block, 128) plus the block sums of every set of chunk
     tables against the rows of ``X`` (the set's columns and a zero row
-    for the padded slots, 128 wide), a panel of chunks at a time."""
+    for the padded slots, 128 wide), a panel of chunks at a time.
+    ``sampled`` = (op, source rows or None, destination rows, panels)
+    makes the product a sampled one: a slot's value is the table's
+    ``op`` the dot of its source's row (``X``'s own where None: one
+    gather serves the dot and the scatter) and its destination's, both
+    tables laid out as ``X`` is and as the block sums are. ``panels``
+    (:func:`sampled_panels`, or None) gives each set its panel's chunks
+    and the blocks a panel's destinations span: the panel gathers them
+    from that window of the table, which stays in fast memory where
+    the whole table would not."""
     nb = y.shape[0]
-    for src, off, val, cb, win in sets:
+    more = 0 if sampled is None else 1 + (sampled[1] is not None)
+    for n_set, (src, off, val, cb, win) in enumerate(sets):
         rows, cr, _ = off.shape
         chunk = cr * LANE
-        per = wide_panel_rows(rows, chunk)
+        per, span = wide_panel_rows(rows, chunk, more), nb
+        if sampled is not None and sampled[3] is not None:
+            per, span = sampled[3][n_set][:2]
         run = _wide_runner(per, chunk, nb, block, passes, interpret)
 
         def panel(i, y):
@@ -879,6 +919,20 @@ def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool):
             s, o, v, c, w = (jax.lax.dynamic_slice_in_dim(a, at, per)
                              for a in (src, off, val, cb, win))
             g = X.at[s.reshape(-1)].get(mode="promise_in_bounds")
+            if sampled is not None:
+                op, of_src, of_dst = sampled[:3]
+                mine = g if of_src is None else of_src.at[
+                    s.reshape(-1)].get(mode="promise_in_bounds")
+                # the blocks ascend with the chunks: a panel's
+                # destinations lie in ``span`` blocks from its first
+                first = jnp.minimum(c[0], nb - span) if span < nb else 0
+                dest = ((c - first)[:, None, None] * block + o).reshape(-1)
+                table = of_dst if span >= nb else \
+                    jax.lax.dynamic_slice_in_dim(of_dst, first * block,
+                                                 span * block)
+                theirs = table.at[dest].get(mode="promise_in_bounds")
+                v = sampled_values(op, v, jnp.sum(mine * theirs, axis=1)
+                                   .reshape(v.shape))
             skip = jnp.reshape(i * per - at, (1,)).astype(jnp.int32)
             return run(c, skip, w, o, v, g.reshape(per, chunk, WIDE_COLS),
                        y)
@@ -973,6 +1027,194 @@ def compact_matmat_parts(plan_static, part_statics, part_arrays,
             Y = spmv_lib._overflow_add_wide(Y, ov, X[col0:col0 + n_cols],
                                             n_rows)
     return Y if dense is None else _dense_part(Y, *dense, X)
+
+
+def sampled_panels(plan, shared: bool) -> tuple:
+    """((chunks a panel, blocks a panel's destinations span, slots a
+    chunk, chunks), ...) for every set of chunk tables a sampled product
+    walks of ``plan``, its parts in order (:func:`_chunk_sets`; a set of
+    hub chunks, which no COOMatrix plan has, takes the whole table). A
+    panel is what
+    :func:`wide_panel_rows` gives a slot of one (``shared``) or two more
+    gathered rows, split further while the rows of the destination's
+    factor its chunks name — consecutive blocks, for the chunks ascend
+    by block — make a gather table past ``spmv._FAST_TABLE_BYTES``: XLA's
+    row gather keeps its rate only from a table in fast memory (W's
+    480,189 rows whole read 14.9 ns a row, my chip run, PR 46). Reckoned
+    once from the host tables, as the panels walk them (the last moved
+    back to end with the tables)."""
+    more = 1 if shared else 2
+    most = max(spmv_lib._FAST_TABLE_BYTES // (4 * WIDE_COLS), 1)
+    out = []
+    for _, p in (getattr(plan, "parts", None) or ((0, plan),)):
+        rows, cap = np.asarray(p.src8).shape
+        nb = -(-p.n_rows // p.block)
+        if p.chunk_block is None:
+            walks = (cap // LANE) // _walk(cap // LANE)
+            cb = np.repeat(np.arange(rows), walks)
+            rows, cap = rows * walks, cap // walks
+        else:
+            cb = np.asarray(p.chunk_block)
+        per = wide_panel_rows(rows, cap, more)
+
+        def span(per):
+            at = np.minimum(np.arange(0, rows, per), rows - per)
+            return int((cb[at + per - 1] - cb[at]).max()) + 1
+
+        # (a block alone past the table's size is gathered as it is)
+        while span(per) > max(most // p.block, 1) and per > 1:
+            per = min(per - 1, -(-rows // (-(-rows // per) + 1)))
+        out.append((per, min(span(per), nb), cap, rows))
+        if p.hubs is not None:
+            hub_rows = np.asarray(p.hubs.chunk_block).shape[0]
+            out.append((wide_panel_rows(hub_rows, cap, more), nb, cap,
+                        hub_rows))
+    return tuple(out)
+
+
+def sampled_values(op: str, s, d):
+    """``s op d`` as the executor's element-wise node gives it where
+    ``s`` is a sparse matrix's values: ``s * d``, or ``s / d`` with
+    ``x / 0 = 0`` (and so ``0 / 0 = 0``: a padded slot, a cell the slab
+    does not hold)."""
+    if op == "mul":
+        return s * d
+    return jnp.where(d == 0, jnp.zeros((), s.dtype),
+                     s / jnp.where(d == 0, jnp.ones((), d.dtype), d))
+
+
+def _sampled_dense_part(Y, role: str, slab, lines, Z, op: str, of_src,
+                        of_dst):
+    """``Y`` plus the dense lines' share of a sampled product
+    (:func:`sampled_matmat_parts`), a panel of
+    ``strategies.ACC_PANEL_ROWS`` rows of the slab at a time: the panel
+    of the dense product at the slab's cells on the MXU, ``D = P_p ·
+    R[lines]ᵀ`` (``P`` the factor whose rows the slab's rows name, ``R``
+    the one its lines name), the sampled values ``Q = slab_p op D`` where
+    the slab holds an entry (the slab is the STRUCTURE here: its zeros
+    are the cells without one), and ``Q``'s product at once — where the
+    lines are the product's ``"sources"``, ``Y_p += Q · Z[lines]``; where
+    its ``"destinations"``, ``Y[lines] += Qᵀ · Z_p``, summed over the
+    panels on the vector unit. ``Q`` never leaves its panel. Every dot is
+    float32 at ``highest`` whatever the slab's dtype: the quotient is no
+    bfloat16's, so a product costs six MXU passes where the slab's own
+    values cost three."""
+    from matrel_tpu.parallel import strategies
+    n, width = lines.shape[0], slab.shape[1]
+    along = role == "sources"
+    P, R = (of_dst, of_src) if along else (of_src, of_dst)
+
+    def at_lines(t):
+        return jnp.pad(t.at[lines].get(mode="promise_in_bounds"),
+                       ((0, width - n), (0, 0)))
+
+    def dot(a, ca, b, cb):
+        return jax.lax.dot_general(
+            a, b, (((ca,), (cb,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    RL = at_lines(R)
+
+    def quotient(start, rows):
+        cells = jax.lax.dynamic_slice_in_dim(slab, start, rows) \
+            .astype(jnp.float32)
+        return sampled_values(op, cells, dot(
+            jax.lax.dynamic_slice_in_dim(P, start, rows), 1, RL, 1))
+
+    def over_panels(step, carry):
+        """``step(start, rows, carry)`` over the slab's rows, a panel a
+        round of a ``fori_loop`` and the ragged tail after it."""
+        per = strategies.ACC_PANEL_ROWS
+        whole, tail = divmod(slab.shape[0], per)
+        if whole:
+            carry = jax.lax.fori_loop(
+                0, whole, lambda i, c: step(i * per, per, c), carry)
+        return step(whole * per, tail, carry) if tail else carry
+
+    if along:
+        ZL = at_lines(Z)
+
+        def step(start, rows, Y):
+            mine = jax.lax.dynamic_slice_in_dim(Y, start, rows)
+            return jax.lax.dynamic_update_slice_in_dim(
+                Y, mine + dot(quotient(start, rows), 1, ZL, 0), start, 0)
+
+        return over_panels(step, Y)
+    sums = over_panels(
+        lambda start, rows, acc: acc + dot(
+            quotient(start, rows), 0,
+            jax.lax.dynamic_slice_in_dim(Z, start, rows), 0),
+        jnp.zeros((width, Z.shape[1]), jnp.float32))
+    return Y.at[lines].add(sums[:n], indices_are_sorted=True,
+                           unique_indices=True, mode="promise_in_bounds")
+
+
+def sampled_matmat_parts(plan_static, part_statics, part_arrays,
+                         Z: jax.Array, op: str, of_src, of_dst,
+                         passes: int = 3, interpret: bool = False,
+                         panels=None) -> jax.Array:
+    """Traceable body: ``(A op (P·R)) · Z`` for the matrix ``A`` of a
+    plan (its operands as :func:`compact_matmat_parts` takes them,
+    :func:`plan_operands`), a dense ``Z`` (n_cols, k <= 128) and a
+    dense product of which only A's entries are wanted: entry (i, j) is
+    A's value ``op`` ("div" / "mul") the dot of ``of_dst[i]`` and
+    ``of_src[j]``, the rows of the product's two factors (n_rows x k'
+    and n_cols x k', k' <= 128) that the entry's coordinates name.
+    ``of_src`` None says that it is ``Z`` itself. The compact parts
+    gather both rows a slot, panel by panel, and scatter through the
+    k-wide kernel (``passes`` 3: float32-faithful, and what the
+    executor runs); the dense part is :func:`_sampled_dense_part`,
+    float32 at ``highest`` whatever ``passes`` says; overflow entries go
+    by the scalar path with the same values. ``panels``:
+    :func:`sampled_panels` of the plan (None: :func:`wide_panel_rows`'
+    panels, the destination's rows gathered from the whole table). The
+    sampled values are never stored whole."""
+    dense = None
+    if part_statics and isinstance(part_statics[-1][0], str):
+        dense = (part_statics[-1][0],) + tuple(part_arrays[-1])
+        part_statics, part_arrays = part_statics[:-1], part_arrays[:-1]
+    n_rows, _, block, _ = plan_static
+    nb = -(-n_rows // block)
+    k = Z.shape[1]
+
+    def lanes(t):       # a gathered row fills 128 lanes whatever it holds
+        t = t.astype(jnp.float32)
+        return jnp.pad(t, ((0, 0), (0, WIDE_COLS - t.shape[1])))
+
+    Zf = lanes(Z)
+    src = None if of_src is None else lanes(of_src)
+    dst = jnp.pad(lanes(of_dst), ((0, nb * block - n_rows), (0, 0)))
+    y = jnp.zeros((nb, block, WIDE_COLS), jnp.float32)
+    zero_row = ((0, spmv_lib.WIDTH), (0, 0))
+    n_sets = 0
+    for (col0, (_, n_cols, _, _)), (tables, _, wins) in zip(
+            part_statics, part_arrays):
+        sets = _chunk_sets(tables, n_cols, wins)
+        y = _wide_accumulate(
+            y, sets, jnp.pad(Zf[col0:col0 + n_cols], zero_row), block,
+            passes, interpret,
+            sampled=(op, None if src is None else jnp.pad(
+                src[col0:col0 + n_cols], zero_row), dst,
+                None if panels is None
+                else panels[n_sets:n_sets + len(sets)]))
+        n_sets += len(sets)
+    Y = y.reshape(-1, WIDE_COLS)[:n_rows, :k]
+    mine = Zf if src is None else src
+    for (col0, (_, n_cols, _, _)), (_, ov, _) in zip(part_statics,
+                                                     part_arrays):
+        if ov:
+            ov_c, ov_r, ov_v = ov
+            d = jnp.sum(mine[col0:col0 + n_cols][ov_c] * dst[ov_r], axis=1)
+            Y = spmv_lib._overflow_add_wide(
+                Y, (ov_c, ov_r, sampled_values(op, ov_v, d)),
+                Z[col0:col0 + n_cols], n_rows)
+    if dense is None:
+        return Y
+    role, slab, lines = dense
+    inner = of_dst.shape[1]
+    return _sampled_dense_part(Y, role, slab, lines, Zf[:, :k], op,
+                               mine[:, :inner], dst[:n_rows, :inner])
 
 
 def compact_matmat_apply(plan_static, tables, ov, X: jax.Array,

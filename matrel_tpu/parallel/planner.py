@@ -547,10 +547,39 @@ def _coo_narrow_matmul(n: MatExpr) -> bool:
     return False
 
 
-def coo_product(node: MatExpr, mesh: Mesh) -> Optional[dict]:
+def fused_sampled(node: MatExpr, parent: Optional[MatExpr], mesh: Mesh,
+                  config: Optional[MatrelConfig] = None) -> bool:
+    """A ``sampled`` node that is no array: an operand of a product
+    that answers it fused (executor._sampled_dispatch_plan, the single
+    source of truth), its values made and multiplied an entry and a
+    panel at a time. Anywhere else it lowers as the dense array it
+    stands for and is counted as one."""
+    if (node.kind != "sampled" or parent is None
+            or parent.kind != "matmul"):
+        return False
+    from matrel_tpu import executor as _exec
+    return _exec._sampled_dispatch_plan(parent, mesh, config) is not None
+
+
+def sampled_dense_bytes(node: MatExpr, mesh: Mesh) -> float:
+    """What a ``sampled`` node that lowers as an array keeps on one
+    device beside its own value: the leaf's dense float32 copy and the
+    dense product it is sampled from, both of its own padded shape."""
+    from matrel_tpu.core import padding
+    pn, pm = padding.padded_shape(node.shape, mesh)
+    return 2 * 4.0 * pn * pm / max(mesh.size, 1)
+
+
+def coo_product(node: MatExpr, mesh: Mesh,
+                config: Optional[MatrelConfig] = None) -> Optional[dict]:
     """How a matmul with a coo_leaf operand will run, and what that
     keeps on one device beside the dense operand and the output — None
-    for any other node. Through the matrix's SpMV plan
+    for any other node. A product that answers a ``sampled`` operand
+    fused (:func:`fused_sampled`): ``chosen`` "sampled_spmm", the plan's
+    ``layout`` and ``panels`` and ``bytes`` as core.coo.sampled_facts
+    reckons them (the tables, a panel's gathered rows, the slab and one
+    panel of its quotient: neither the dense product nor the sampled
+    values whole). Through the matrix's SpMV plan
     (executor._coo_dispatch_plan, the single source of truth):
     ``chosen`` "coo_spmm", the plan's ``layout``, its ``panels`` (of
     table rows, of sources) and ``bytes``, the compact tables and one
@@ -559,10 +588,22 @@ def coo_product(node: MatExpr, mesh: Mesh) -> Optional[dict]:
     was refused, by DENSIFYING the leaf: ``chosen`` "densify", ``bytes``
     the dense float32 copy on the mesh's padded shape, ``why``."""
     l, r = node.children
-    if l.kind != "coo_leaf" and r.kind != "coo_leaf":
-        return None
     from matrel_tpu import executor as _exec
     from matrel_tpu.core import coo as coo_lib, padding
+    if l.kind == "sampled" or r.kind == "sampled":
+        plan = _exec._sampled_dispatch_plan(node, mesh, config)
+        if plan is None:
+            return None
+        smp = l if l.kind == "sampled" else r
+        # the shared gather is the lowering's to find; without it a
+        # panel holds one gathered row a slot more
+        facts = coo_lib.sampled_facts(
+            plan, smp.children[0].attrs["matrix"].nnz, shared=False)
+        return {"chosen": "sampled_spmm", "layout": facts["layout"],
+                "panels": (facts["panels"], facts["source_panels"]),
+                "bytes": float(facts["hbm_plan_bytes"])}
+    if l.kind != "coo_leaf" and r.kind != "coo_leaf":
+        return None
     flipped = l.kind != "coo_leaf"
     leaf = r if flipped else l
     plan = _exec._coo_dispatch_plan(node)
@@ -673,7 +714,7 @@ def infer_dtype(node: MatExpr, config: Optional[MatrelConfig] = None,
             if "bfloat16" in (np.dtype(da).name, np.dtype(db).name):
                 return np.dtype("float32")
             return _promote(da, db)
-        if k in ("elemwise", "rank1", "join_value"):
+        if k in ("elemwise", "rank1", "join_value", "sampled"):
             return _promote(*(walk(c) for c in n.children))
         if k == "inverse":
             da = walk(n.children[0])
@@ -2076,8 +2117,9 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
         nc = annotate_strategies(c, mesh, config, memo, lmemo, h,
                                  _child_root_scale(e, i, _root_scale),
                                  swap, imemo, alive, e)
-        if nc.children and not _folded_transpose(nc, e, mesh, config,
-                                                 memo):
+        if (nc.children
+                and not _folded_transpose(nc, e, mesh, config, memo)
+                and not fused_sampled(c, e, mesh, config)):
             # a computed value, kept for e
             alive += device_bytes(nc, mesh, config, memo, lmemo)
         new_children.append(nc)
@@ -2132,7 +2174,7 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
             if hbm["refused_hbm"]:
                 stamp["refused_hbm"] = tuple(hbm["refused_hbm"])
             stamp["hbm_plan_bytes"] = hbm["hbm_plan_bytes"]
-            coo = coo_product(e, mesh)
+            coo = coo_product(e, mesh, config)
             if coo is not None:
                 # a sparse operand's tables are its plan's and were not
                 # among the leaves reckoned: what the product keeps
@@ -2182,6 +2224,17 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
         e = e.with_attrs(hbm_plan_bytes=int(need),
                          **({"refused_hbm": (e.kind,)}
                             if 0 < limit < need else {}))
+    if (e.kind == "sampled" and "hbm_plan_bytes" not in e.attrs
+            and not fused_sampled(e, _parent, mesh, config)):
+        # lowered as the element-wise node it was written as: its own
+        # value, the leaf's dense copy and the dense product whole
+        need = (alive + sampled_dense_bytes(e, mesh)
+                + (0.0 if is_root
+                   else device_bytes(e, mesh, config, memo, lmemo)))
+        limit = mesh_lib.hbm_limit_bytes(mesh, config)
+        e = e.with_attrs(hbm_plan_bytes=int(need),
+                         **({"refused_hbm": ("sampled",)}
+                            if 0 < limit < need else {}))
     if e.kind in ("join_rows", "join_cols") and "replicate" not in e.attrs:
         e = e.with_attrs(replicate=choose_join_scheme(
             e, mesh, config, layout_memo=lmemo,
@@ -2225,7 +2278,7 @@ def hbm_report(root: MatExpr) -> list:
                 # a coo_leaf product: what runs is the SpMV plan or the
                 # densified leaf, not the stamped dense strategy
                 out[-1]["chosen"] = coo["chosen"]
-                if coo["chosen"] == "coo_spmm":
+                if coo["chosen"] != "densify":
                     out[-1].update(layout=coo["layout"],
                                    panels=list(coo["panels"]))
                 else:
@@ -2264,6 +2317,25 @@ def refuse_over_limit(roots, mesh: Mesh,
         own = int(device_bytes(n, mesh, config))
         limit = mesh_lib.hbm_limit_bytes(mesh, config)
         coo = n.attrs.get("coo_product")
+        if n.kind == "sampled":
+            leaf, a, b = n.children
+            raise PlanMemoryError(
+                f"plan refused before tracing: the sampled node "
+                f"{leaf.shape[0]}x{leaf.shape[1]} "
+                f"{'./' if n.attrs['op'] == 'div' else '.*'} "
+                f"({a.shape[0]}x{a.shape[1]} * {b.shape[0]}x{b.shape[1]}) "
+                f"is no operand of a product that answers it at its "
+                f"entries alone (one device, the compact-table executor, "
+                f"a dense side of at most 128 columns), so it would "
+                f"DENSIFY its element-sparse operand "
+                f"({leaf.attrs['matrix'].nnz:,} entries) and multiply "
+                f"the dense product whole: "
+                f"{int(sampled_dense_bytes(n, mesh)):,} bytes as float32 "
+                f"beside its own value; with them the plan holds "
+                f"{n.attrs['hbm_plan_bytes']:,} bytes on the one device, "
+                f"over the limit of {limit:,} bytes. Multiply it by a "
+                f"narrow dense side (t(X) * (S ./ (A * B)), "
+                f"(S ./ (A * B)) * Y), which needs neither.")
         if coo is not None and coo["chosen"] == "densify":
             leaf = next(c for c in n.children if c.kind == "coo_leaf")
             raise PlanMemoryError(

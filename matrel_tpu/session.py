@@ -514,9 +514,11 @@ class MatrelSession:
         ``reduce_bytes`` where they apply), and of its coo_leaf products
         ``spmm`` (one
         record each that the SpMV tables answer: what its
-        ``matrel.spmm.plan`` span carries) and ``densified_products``
-        (one each whose leaf was densified). Copies; {} before the first
-        dispatch."""
+        ``matrel.spmm.plan`` span carries), ``sampled`` (one each
+        sampled product answered fused, core.coo.sampled_facts: what its
+        ``matrel.sampled.plan`` span carries) and ``densified_products``
+        (one each leaf that was densified, under a product or read as
+        an array). Copies; {} before the first dispatch."""
         plan = self._last_plan
         if plan is None:
             return {}
@@ -526,6 +528,7 @@ class MatrelSession:
                 "hbm_plan_bytes": meta.get("hbm_plan_bytes"),
                 "products": [dict(r) for r in meta.get("products", ())],
                 "spmm": [dict(r) for r in meta.get("spmm", ())],
+                "sampled": [dict(r) for r in meta.get("sampled", ())],
                 "densified_products": [
                     dict(r) for r in meta.get("densified_products", ())]}
 
@@ -1664,9 +1667,11 @@ class MatrelSession:
             if sp.live:
                 # the SpMV plan each coo_leaf product of this program
                 # runs on: built and uploaded once, answered here
-                for rec in plan.meta.get("spmm", ()):
-                    with trace_lib.span("spmm.plan", hit=True, **rec):
-                        pass
+                for name in ("spmm", "sampled"):
+                    for rec in plan.meta.get(name, ()):
+                        with trace_lib.span(name + ".plan", hit=True,
+                                            **rec):
+                            pass
             if self._exec_lock is None:
                 return plan.run(bindings=bindings)
             with self._exec_lock:

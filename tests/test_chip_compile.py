@@ -288,6 +288,84 @@ def test_gnmf_products_with_a_slab(one_chip, monkeypatch):
     assert tail and floor_ms < loop_ms < 2 * floor_ms, panel_ms
 
 
+# The cell pnmf_netflix_r128_1c's two sampled products (PR 46) over the
+# same matrix as the chip laid it out (PR 43's slab as the chip chose it:
+# 4,224 lines in bfloat16; the residual's 7.8M entries in 4,289 forward
+# chunks, and 3,902 transposed ones in four source panels)
+PN_LINES, PN_FWD_CHUNKS = 4_224, 4_289
+PN_BWD_CHUNKS = (996, 978, 970, 958)
+
+
+def test_pnmf_sampled_products(one_chip, monkeypatch):
+    """(V ./ (W H)) * t(H) and t(W) * (V ./ (W H)) at the cell's shapes:
+    neither W H nor the quotient exists whole (no users x movies and no
+    users x lines float32 array), a panel's destination rows are
+    gathered from a window of W that lies in fast memory (the whole
+    table, 246 MB, does not: 14.9 ns a row on the chip), the slab is
+    read where it lies, and arguments and temporaries stay inside what
+    ``sampled_facts`` reckons the plan to hold."""
+    monkeypatch.setattr(pc, "_hbm_limit", lambda: int(15.75 * 2 ** 30))
+    slab = _sds(one_chip, (NF_USERS, PN_LINES), jnp.bfloat16)
+    lines = _sds(one_chip, (PN_LINES,), jnp.int32)
+    run = jax.jit(pc.sampled_matmat_parts,
+                  static_argnums=(0, 1, 4, 7, 8, 9))
+    window = spmv_lib._FAST_TABLE_BYTES // 512          # rows of 512 B
+    panel = 3 * 4 * strategies.ACC_PANEL_ROWS * PN_LINES
+
+    def wins(chunks):
+        return (_sds(one_chip, (chunks,), jnp.int32),)
+
+    def check(compiled, slots_a_panel, out_bytes):
+        text = compiled.as_text()
+        assert f"f32[{NF_USERS},{NF_MOVIES}]" not in text
+        assert f"f32[{NF_USERS},{PN_LINES}]" not in text
+        assert f"f32[{PN_LINES},{NF_USERS}]" not in text
+        assert not re.search(rf"= bf16\[{NF_USERS},{PN_LINES}\]\S* "
+                             r"(copy|transpose)\(", text)
+        assert "matrel_spmm_scatter_chunks" in text
+        stats = compiled.memory_analysis()
+        reckoned = (stats.argument_size_in_bytes + panel + 3 * out_bytes
+                    + pc._wide_slot_bytes(1) * slots_a_panel)
+        taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+        assert taken < 1.05 * reckoned, (reckoned, taken)
+        assert taken + out_bytes < int(15.75 * 2 ** 30)
+        return text
+
+    # the W update: sources the movies (t(H)'s rows serve the dot and
+    # the scatter), destinations the users, four panels of a window each
+    static = (NF_USERS, NF_MOVIES, BLOCK, spmv_lib.LO)
+    per = -(-PN_FWD_CHUNKS // 4)
+    fwd = _compile(
+        run, static, ((0, static), ("sources", None)),
+        ((_chunk_table_shapes(PN_FWD_CHUNKS, one_chip), (),
+          wins(PN_FWD_CHUNKS)), (slab, lines)),
+        _sds(one_chip, (NF_MOVIES, NF_RANK), jnp.float32), "div", None,
+        _sds(one_chip, (NF_USERS, NF_RANK), jnp.float32), 3, False,
+        ((per, window // BLOCK, spmv_lib.CHUNK, PN_FWD_CHUNKS),))
+    text = check(fwd, per * spmv_lib.CHUNK, 4 * NF_USERS * NF_RANK)
+    assert re.search(rf"f32\[{window},128\]\{{[^}}]*S\(1\)\}}", text)
+    assert f"f32[{per * spmv_lib.CHUNK},128]" in text   # a panel's rows
+
+    # the H update: sources the users in four source panels (W's rows
+    # serve both), destinations the movies: t(H) whole is a fast table
+    static = (NF_MOVIES, NF_USERS, BLOCK, spmv_lib.LO)
+    statics = tuple(
+        (c0, (NF_MOVIES, min(NF_PANEL_USERS, NF_USERS - c0), BLOCK,
+              spmv_lib.LO))
+        for c0 in range(0, NF_USERS, NF_PANEL_USERS))
+    nb = -(-NF_MOVIES // BLOCK)
+    bwd = _compile(
+        run, static, statics + (("destinations", None),),
+        tuple((_chunk_table_shapes(c, one_chip), (), wins(c))
+              for c in PN_BWD_CHUNKS) + ((slab, lines),),
+        _sds(one_chip, (NF_USERS, NF_RANK), jnp.float32), "div", None,
+        _sds(one_chip, (NF_MOVIES, NF_RANK), jnp.float32), 3, False,
+        tuple((c, nb, spmv_lib.CHUNK, c) for c in PN_BWD_CHUNKS))
+    text = check(bwd, 2 * max(PN_BWD_CHUNKS) * spmv_lib.CHUNK,
+                 4 * NF_MOVIES * NF_RANK)
+    assert re.search(rf"f32\[{nb * BLOCK},128\]\{{[^}}]*S\(1\)\}}", text)
+
+
 def test_compact_spmv_sharded_2x2(mesh_2x2):
     mesh = mesh_2x2
     axes = tuple(mesh.axis_names)

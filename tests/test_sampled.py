@@ -1,0 +1,353 @@
+"""The ``sampled`` node (PR 46): ``S ./ (A * B)`` and ``S .* (A * B)`` for
+an element-sparse leaf ``S``, wanted only at S's entries. The rule that
+writes it, the two fused products of it through ``session.sql`` +
+``compute`` against float64 numpy (a matrix with dense lines and one
+without, values that are no bfloat16's), what ``last_plan()`` says, the
+array it stays anywhere else, and the gate that refuses that array by
+name where it does not fit."""
+
+import numpy as np
+import pytest
+
+from matrel_tpu import config as config_lib
+from matrel_tpu.config import MatrelConfig
+from matrel_tpu.core import coo as coo_lib
+from matrel_tpu.core.blockmatrix import BlockMatrix
+from matrel_tpu.core.coo import COOMatrix
+from matrel_tpu.ir import expr as E, rules
+from matrel_tpu.ops import spmv as spmv_lib
+from matrel_tpu.parallel.planner import PlanMemoryError
+from matrel_tpu.session import MatrelSession
+
+SQL_H = "H .* (t(W) * (V / (W * H))) / t(colsum(W))"
+SQL_W = "W .* ((V / (W * H)) * t(H)) / t(rowsum(H))"
+USERS, MOVIES, RANK, HOT = 1500, 7000, 16, 128
+
+
+def _session(config=None):
+    """One chip's session: a 1x1 mesh of the first device."""
+    import jax
+    from matrel_tpu.core import mesh as mesh_lib
+    return MatrelSession(
+        mesh=mesh_lib.make_mesh((1, 1), devices=jax.devices()[:1]),
+        config=config or MatrelConfig())
+
+
+@pytest.fixture
+def one_chip(monkeypatch):
+    """What the chip is to a COOMatrix (tests/test_gnmf.py's fixture,
+    with room for a slab): the compact Pallas executors of one device,
+    interpreted, plans past the small-plan threshold, a gather table of
+    500 rows at the most so that a product over the users runs in three
+    source panels (one over the movies in fifteen)."""
+    cfg = MatrelConfig(pallas_interpret=True, cse_enable=True)
+    was = config_lib._default_config
+    config_lib.set_default_config(cfg)
+    monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")
+    monkeypatch.setattr(spmv_lib, "_SMALL_PLAN_SLOTS", 0)
+    monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 500 * 512)
+    yield cfg
+    config_lib._default_config = was
+
+
+def _ratings(rng, hot: bool, exact: bool):
+    """A ratings matrix as the rule sees the real one
+    (tests/test_gnmf.py's ``_ratings_with_hot_movies``, halved): where
+    ``hot``, 128 hot movies of ~125 ratings (a column of 1,500 users
+    pays from 6 on) over a tail of 7,000 movies of one or two (a user
+    holds 16 of them at the most of most: a row pays from 20 on);
+    values 1 to 5 where ``exact``, else float32 numbers that are no
+    bfloat16's; one cell listed twice."""
+    cols = [np.arange(MOVIES), rng.integers(0, MOVIES, 1_000)]
+    if hot:
+        cols.append(rng.choice(MOVIES, HOT, replace=False)[
+            rng.integers(0, HOT, 16_000)])
+    cols = np.concatenate(cols)
+    keys = np.unique(rng.integers(0, USERS, cols.size) * MOVIES + cols)
+    rows, cols = keys // MOVIES, keys % MOVIES
+    vals = (rng.integers(1, 6, rows.size).astype(np.float32) if exact
+            else rng.uniform(0.5, 5.0, rows.size).astype(np.float32))
+    if not exact:
+        rows, cols = np.append(rows, rows[7]), np.append(cols, cols[7])
+        vals = np.append(vals, np.float32(1.25))
+    order = rng.permutation(rows.size)
+    return COOMatrix.from_edges(rows[order], cols[order], vals[order],
+                                shape=(USERS, MOVIES))
+
+
+def _factors(rng, s):
+    w = rng.uniform(0.1, 1.0, (USERS, RANK)).astype(np.float32)
+    h = rng.uniform(0.1, 1.0, (RANK, MOVIES)).astype(np.float32)
+    return w, h, BlockMatrix.from_numpy(w, mesh=s.mesh), \
+        BlockMatrix.from_numpy(h, mesh=s.mesh)
+
+
+# -- the rule -------------------------------------------------------------------
+
+
+def _leaves(rng, inner=8):
+    r = rng.integers(0, 30, 50)
+    c = rng.integers(0, 20, 50)
+    S = COOMatrix.from_edges(r, c, np.ones(50, np.float32), shape=(30, 20))
+    A = BlockMatrix.from_numpy(
+        rng.random((30, inner), dtype=np.float32)).expr()
+    B = BlockMatrix.from_numpy(
+        rng.random((inner, 20), dtype=np.float32)).expr()
+    D = BlockMatrix.from_numpy(rng.random((30, 20), dtype=np.float32)).expr()
+    return S.expr(), A, B, D
+
+
+@pytest.mark.parametrize("build,fires", [
+    (lambda S, A, B, D: E.elemwise("div", S, E.matmul(A, B)), "div"),
+    (lambda S, A, B, D: E.elemwise("mul", S, E.matmul(A, B)), "mul"),
+    (lambda S, A, B, D: E.elemwise("mul", E.matmul(A, B), S), "mul"),
+    # a dense leaf samples nothing; (A * B) / S is dense (x / 0 = 0
+    # everywhere S is not); no other element-wise op has S's structure
+    (lambda S, A, B, D: E.elemwise("div", D, E.matmul(A, B)), None),
+    (lambda S, A, B, D: E.elemwise("div", E.matmul(A, B), S), None),
+    (lambda S, A, B, D: E.elemwise("add", S, E.matmul(A, B)), None),
+    # a product of a sparse operand is no dense product
+    (lambda S, A, B, D: E.elemwise("mul", S, E.matmul(
+        S, E.matmul(E.transpose(B), B))), None),
+], ids=["S./(AB)", "S.*(AB)", "(AB).*S", "dense./(AB)", "(AB)./S",
+        "S+(AB)", "S.*(S*(tB*B))"])
+def test_the_rule_fires_on_what_it_sees(rng, build, fires):
+    S, A, B, D = _leaves(rng)
+    counts = {}
+    out = rules.optimize(build(S, A, B, D), counts=counts)
+    if fires is None:
+        assert out.kind == "elemwise" and "sampled_product" not in counts
+        return
+    assert out.kind == "sampled" and out.attrs["op"] == fires
+    assert out.children == (S, A, B)
+    assert out.shape == S.shape and out.nnz == S.nnz
+    assert counts["sampled_product"] == 1
+
+
+def test_the_rule_leaves_a_product_wider_than_the_tables(rng):
+    S, A, B, _ = _leaves(rng, inner=E.COO_NARROW_MAX + 1)
+    out = rules.optimize(E.elemwise("div", S, E.matmul(A, B)))
+    assert out.kind == "elemwise"
+    S, A, B, _ = _leaves(rng, inner=E.COO_NARROW_MAX)
+    assert rules.optimize(
+        E.elemwise("div", S, E.matmul(A, B))).kind == "sampled"
+
+
+# -- the fused products -------------------------------------------------------------
+
+
+@pytest.mark.parametrize("hot,exact", [(True, True), (True, False),
+                                       (False, False)],
+                         ids=["bfloat16-slab", "float32-slab", "no-slab"])
+def test_both_products_match_float64(rng, one_chip, hot, exact):
+    """t(W) * (V ./ (W H)) and (V ./ (W H)) * t(H) inside the two
+    updates, against float64 numpy, entry by entry. rtol 5e-6: the program's sums are
+    float32 (an entry's dot of 16 terms, a hot movie's 125 quotients
+    added on the MXU at ``highest``), the quotient itself a float32
+    division and two more by the sums of the update: a dozen roundings
+    of 6e-8 an entry, read 2.1e-6 at the worst of 112,000 entries."""
+    V = _ratings(rng, hot, exact)
+    s = _session(one_chip)
+    w, h, W, H = _factors(rng, s)
+    s.register("V", V)
+    s.register("W", W)
+    s.register("H", H)
+    Vd = V.to_dense().astype(np.float64)
+    Q = np.where(Vd != 0, Vd / (w.astype(np.float64) @ h), 0.0)
+    for sql, want, orientation in (
+            (SQL_H, h * (w.T @ Q) / w.sum(0)[:, None], "transposed"),
+            (SQL_W, w * (Q @ h.T) / h.sum(1)[None, :], "forward")):
+        got = s.compute(s.sql(sql)).to_numpy()
+        np.testing.assert_allclose(got, want, rtol=5e-6)
+        said = s.last_plan()
+        assert said["densified_products"] == [] and said["spmm"] == []
+        assert said["executors"] == ["pallas_spmv"]
+        (rec,) = said["sampled"]
+        assert (rec["orientation"], rec["op"]) == (orientation, "div")
+        assert rec["k"] == rec["inner"] == RANK
+        assert rec["entries"] == V.nnz and rec["overflow_edges"] == 0
+        # W is the product's dense side and the factor whose rows the
+        # transposed plan's sources name (t(H) in the forward one)
+        assert rec["shared_gather"] is True
+        assert rec["source_panels"] == (4 if orientation == "transposed"
+                                        else 15)
+        if hot:
+            assert rec["lines"] == HOT and rec["panel_rows"] == 8192
+            assert 0 < rec["dense_entries"] < V.nnz
+            assert rec["slab_dtype"] == ("bfloat16" if exact
+                                         else "float32")
+        else:
+            assert (rec["lines"], rec["dense_entries"], rec["slab_dtype"],
+                    rec["panel_rows"]) == (0, 0, "", 0)
+        assert 0 < rec["hbm_plan_bytes"] <= said["hbm_plan_bytes"]
+
+
+def test_the_sampled_product_by_mul_and_a_side_that_is_no_factor(
+        rng, one_chip):
+    """``.*`` either way round, and a dense side that is not one of the
+    product's factors (its rows are gathered beside the factor's)."""
+    V = _ratings(rng, hot=True, exact=False)
+    s = _session(one_chip)
+    w, h, W, H = _factors(rng, s)
+    x = rng.uniform(-1, 1, (5, USERS)).astype(np.float32)
+    y = rng.uniform(-1, 1, (MOVIES, 3)).astype(np.float32)
+    for name, m in (("V", V), ("W", W), ("H", H),
+                    ("X", BlockMatrix.from_numpy(x, mesh=s.mesh)),
+                    ("Y", BlockMatrix.from_numpy(y, mesh=s.mesh))):
+        s.register(name, m)
+    P = V.to_dense().astype(np.float64) * (w.astype(np.float64) @ h)
+    for sql, want, shared in (("X * (V .* (W * H))", x @ P, False),
+                              ("((W * H) .* V) * Y", P @ y, False),
+                              ("((W * H) .* V) * t(H)", P @ h.T, True)):
+        got = s.compute(s.sql(sql)).to_numpy()
+        assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-6
+        (rec,) = s.last_plan()["sampled"]
+        assert rec["op"] == "mul" and rec["shared_gather"] is shared
+        assert s.last_plan()["densified_products"] == []
+
+
+def test_a_zero_denominator_gives_zero(rng, one_chip):
+    """x / 0 = 0, as the element-wise div gives it: a movie whose column
+    of H is zero has W H = 0 at every one of its entries."""
+    V = _ratings(rng, hot=True, exact=False)
+    s = _session(one_chip)
+    w, h, W, H = _factors(rng, s)
+    dense = V._get_wide_plan().dense
+    tail = np.flatnonzero(dense.column_of < 0)[0]
+    h[:, [dense.lines[0], tail]] = 0.0  # a dense line and one of the tail
+    s.register("V", V)
+    s.register("W", W)
+    s.register("H", BlockMatrix.from_numpy(h, mesh=s.mesh))
+    got = s.compute(s.sql("(V / (W * H)) * t(H)")).to_numpy()
+    Vd = V.to_dense().astype(np.float64)
+    D = w.astype(np.float64) @ h
+    Q = np.where(D != 0, Vd / np.where(D != 0, D, 1.0), 0.0)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, Q @ h.T, rtol=5e-6)
+
+
+def test_a_blocks_layout_plan_with_an_overflow_tail(rng, monkeypatch):
+    """The layout a mesh's host gives a COOMatrix (``blocks``, one heavy
+    row past the capacity in the scalar overflow list): the same
+    answer, the overflow entries sampled like the rest."""
+    cfg = MatrelConfig(pallas_interpret=True)
+    was = config_lib._default_config
+    config_lib.set_default_config(cfg)
+    monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "blocks")
+    try:
+        m = 20_000
+        r = np.where(rng.random(m) < 0.3, 7, rng.integers(0, 2048, m))
+        c = rng.integers(0, 512, m)
+        V = COOMatrix.from_edges(r, c, rng.uniform(0.5, 2, m), shape=(2048,
+                                                                      512))
+        assert V._get_wide_plan().ov_rows is not None
+        s = _session(cfg)
+        w = rng.uniform(0.1, 1, (2048, 8)).astype(np.float32)
+        h = rng.uniform(0.1, 1, (8, 512)).astype(np.float32)
+        s.register("V", V)
+        s.register("W", BlockMatrix.from_numpy(w, mesh=s.mesh))
+        s.register("H", BlockMatrix.from_numpy(h, mesh=s.mesh))
+        got = s.compute(s.sql("(V / (W * H)) * t(H)")).to_numpy()
+        (rec,) = s.last_plan()["sampled"]
+        assert rec["layout"] == "blocks" and rec["overflow_edges"] > 0
+        Vd = V.to_dense().astype(np.float64)
+        np.testing.assert_allclose(
+            got, (Vd / (w.astype(np.float64) @ h)) @ h.T, rtol=3e-6)
+    finally:
+        config_lib._default_config = was
+
+
+# -- anywhere else it is the array it was ----------------------------------------------
+
+
+def test_alone_or_beside_a_wide_side_it_is_densified_and_says_so(
+        rng, one_chip):
+    V = _ratings(rng, hot=False, exact=False)
+    s = _session(one_chip)
+    w, h, W, H = _factors(rng, s)
+    wide = rng.uniform(-1, 1, (MOVIES, 130)).astype(np.float32)
+    for name, m in (("V", V), ("W", W), ("H", H),
+                    ("Y", BlockMatrix.from_numpy(wide, mesh=s.mesh))):
+        s.register(name, m)
+    Vd = V.to_dense().astype(np.float64)
+    Q = Vd / (w.astype(np.float64) @ h)
+    fell = [{"shape": [USERS, MOVIES], "entries": V.nnz,
+             "bytes": 4 * USERS * MOVIES}]
+    for sql, want in (("V / (W * H)", Q), ("(V / (W * H)) * Y", Q @ wide),
+                      ("rowsum(V / (W * H))", Q.sum(1, keepdims=True))):
+        got = s.compute(s.sql(sql)).to_numpy()
+        assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 3e-6
+        said = s.last_plan()
+        assert said["sampled"] == [] and said["densified_products"] == fell
+    # the sampled node is in the plan all the same: the rule fires by
+    # what it sees, the lowering decides
+    assert "sampled" in s.sql("V / (W * H)").optimized().kind
+
+
+def test_off_the_chips_executor_the_product_is_the_dense_one(rng):
+    """No compact-table executor (a default CPU session; a mesh): the
+    product of a sampled node is today's answer, the leaf densified."""
+    V = _ratings(rng, hot=False, exact=False)
+    s = _session()
+    w, h, W, H = _factors(rng, s)
+    s.register("V", V)
+    s.register("W", W)
+    s.register("H", H)
+    got = s.compute(s.sql("(V / (W * H)) * t(H)")).to_numpy()
+    Vd = V.to_dense().astype(np.float64)
+    want = (Vd / (w.astype(np.float64) @ h)) @ h.T
+    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 3e-6
+    said = s.last_plan()
+    assert said["sampled"] == [] and len(said["densified_products"]) == 1
+
+
+def test_the_gnmf_updates_say_what_they_said(rng, one_chip):
+    """The Euclidean updates hold no sampled node: their plans list
+    their coo_leaf products under ``spmm`` as before and nothing under
+    ``sampled``."""
+    V = _ratings(rng, hot=True, exact=True)
+    s = _session(one_chip)
+    _, _, W, H = _factors(rng, s)
+    s.register("V", V)
+    s.register("W", W)
+    s.register("H", H)
+    for sql, orientation in (("H .* (t(W) * V) / (t(W) * W * H)",
+                              "transposed"),
+                             ("W .* (V * t(H)) / (W * H * t(H))",
+                              "forward")):
+        s.compute(s.sql(sql))
+        said = s.last_plan()
+        assert said["sampled"] == [] and said["densified_products"] == []
+        (rec,) = said["spmm"]
+        assert rec["orientation"] == orientation
+        assert rec["dense_lines"] == HOT and rec["dense_dtype"] == "bfloat16"
+        assert "sampled_product" not in s.sql(sql).optimized().kind
+
+
+# -- the gate -------------------------------------------------------------------------
+
+
+def test_the_unfused_quotient_is_refused_by_name_before_anything_is_made(
+        rng, one_chip, monkeypatch):
+    """``V / (W * H)`` alone at a size that does not fit: refused at
+    once, by name, with nothing densified and nothing multiplied; under
+    its product the same matrices are answered."""
+    import dataclasses
+    cfg = dataclasses.replace(one_chip, hbm_budget_bytes=2 * 4 * USERS
+                              * MOVIES)
+    V = _ratings(rng, hot=False, exact=False)
+    monkeypatch.setattr(COOMatrix, "to_block", lambda *a, **k: pytest.fail(
+        "the leaf was densified"))
+    s = _session(cfg)
+    _, _, W, H = _factors(rng, s)
+    s.register("V", V)
+    s.register("W", W)
+    s.register("H", H)
+    with pytest.raises(PlanMemoryError, match="the sampled node "
+                       rf"{USERS}x{MOVIES} ./ .*would\s+DENSIFY"):
+        s.compute(s.sql("V / (W * H)"))
+    with pytest.raises(PlanMemoryError, match="the sampled node"):
+        s.compute(s.sql("rowsum(V / (W * H))"))
+    out = s.compute(s.sql(SQL_W))
+    assert out.shape == (USERS, RANK)
+    assert s.last_plan()["hbm_plan_bytes"] < cfg.hbm_budget_bytes
