@@ -339,19 +339,20 @@ def sampled_facts(plan, entries: int, shared: bool) -> dict:
     sampled), ``dense_entries`` (those on the slab, whose quotient the
     MXU makes a panel at a time; 0 where the matrix has no dense part),
     ``lines``, ``slab_dtype`` and ``panel_rows`` (the slab rows a panel
-    of the quotient takes), and of the compact parts, which hold the
-    rest, :func:`plan_facts`' ``layout``, ``slots``, ``chunks``,
-    ``source_panels``, ``overflow_edges`` and, at this product's own
-    panel size (a slot holds one or two gathered rows more),
-    ``panels`` (pallas_spmv.sampled_panels: split further where a
-    panel's destinations would not make a fast gather table);
-    ``hbm_plan_bytes``: the tables, the largest panel's
-    temporaries, the slab once and one panel of its quotient."""
+    of the quotient takes), ``dot`` ("kernel": the scatter kernel takes
+    the destination's rows off its block tile and makes the entry's dot;
+    no path of this tree gathers them) and of the compact parts, which
+    hold the rest, :func:`plan_facts`' ``layout``, ``slots``,
+    ``chunks``, ``source_panels``, ``overflow_edges`` and, at this
+    product's own panel size (a slot holds one gathered row more where
+    the gather is not ``shared``), ``panels``; ``hbm_plan_bytes``: the
+    tables, the largest panel's temporaries, the slab once and one
+    panel of its quotient."""
     from matrel_tpu.ops import pallas_spmv as pc
     from matrel_tpu.parallel import strategies
     own = plan_facts(plan, entries)
-    more = 1 if shared else 2
-    sets = pc.sampled_panels(plan, shared)
+    more = 0 if shared else 1
+    shapes = [np.asarray(p.src8).shape for _, p in plan_parts(plan)]
     dense = getattr(plan, "dense", None)
     facts = {k: own[k] for k in ("layout", "slots", "chunks",
                                  "source_panels", "overflow_edges")}
@@ -360,12 +361,12 @@ def sampled_facts(plan, entries: int, shared: bool) -> dict:
         lines=own.get("dense_lines", 0),
         slab_dtype=own.get("dense_dtype", ""),
         panel_rows=strategies.ACC_PANEL_ROWS if dense is not None else 0,
-        shared_gather=bool(shared),
-        panels=int(sum(-(-rows // per) for per, _, _, rows in sets)),
+        shared_gather=bool(shared), dot="kernel",
+        panels=int(sum(-(-r // pc.wide_panel_rows(r, c, more))
+                       for r, c in shapes)),
         hbm_plan_bytes=int(
             pc.TABLE_BYTES_A_SLOT * own["slots"]
-            + pc._wide_slot_bytes(more) * max(per * cap
-                                              for per, _, cap, _ in sets)
+            + max(pc.wide_panel_bytes(r, c, more) for r, c in shapes)
             + (0 if dense is None else dense.slab.nbytes
                # a panel's cells, dot and quotient, float32
                + 3 * 4 * strategies.ACC_PANEL_ROWS * dense.width)))

@@ -817,9 +817,11 @@ class Lowerer:
         (ops/pallas_spmv.sampled_matmat_parts): an entry's value is made
         from the two rows its coordinates name in ``A`` and ``t(B)``
         and scattered at once; neither ``A·B`` nor the sampled values
-        exist whole. Where ``Z`` is the factor whose rows the plan's
-        sources name (t(W) · (V ./ (W·H)); (V ./ (W·H)) · t(H)) one
-        gather serves both."""
+        exist whole. The kernel takes the destination's rows off the
+        block tile it adds into and makes the dot (``dot`` "kernel" in
+        the facts); where ``Z`` is the factor whose rows the plan's
+        sources name (t(W) · (V ./ (W·H)); (V ./ (W·H)) · t(H)) the one
+        gather there is serves the dot and the scatter."""
         from matrel_tpu.config import pallas_interpret_mode
         from matrel_tpu.core.coo import sampled_facts
         from matrel_tpu.ops import pallas_spmv as pc
@@ -856,8 +858,7 @@ class Lowerer:
             out = pc.sampled_matmat_parts(
                 static, part_statics, part_arrays, z, smp.attrs["op"],
                 None if shared else of_src(), of_dst(),
-                interpret=pallas_interpret_mode(self.config),
-                panels=pc.sampled_panels(plan, shared))
+                interpret=pallas_interpret_mode(self.config))
         return self._pad_to_node(out.T if flipped else out, node)
 
     def _long_contraction(self, node: MatExpr, ev) -> Optional[Array]:

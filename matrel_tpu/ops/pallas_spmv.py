@@ -681,12 +681,17 @@ def spmv_compact_sharded(plan: spmv_lib.EdgeSpMVPlan, x: jax.Array,
 # over values that are made on the way: an entry's value is the matrix's
 # own ``op`` (divided by, or times) the dot of the two 128-wide rows its
 # coordinates name in the factors of a dense product, so ``(S ./ (A·B))``
-# is never stored. ``sampled_matmat_parts`` gathers a slot's second row
-# beside the one the scatter takes anyway (one gather serves both where
-# the product's dense side IS that factor), multiplies and sums the pair
-# on the vector unit, and hands the quotient to the same scatter kernel
-# in ``val``'s place; the dense part makes a panel of the slab's quotient
-# on the MXU and multiplies it at once (``_sampled_dense_part``).
+# is never stored. Its compact parts run through a kernel of their own
+# (PR 47, ``matrel_sampled_scatter_chunks``): the scatter above plus, a
+# sub-row of 128 slots, the destination factor's rows of the slots off
+# the BLOCK TILE of that factor, which rides beside the block sums by
+# the same index map (the one-hot, transposed, times the tile: a chunk's
+# slots all land in one block, most within a window of it), the entry's
+# dot with the source's row (the gathered rows the scatter multiplies
+# anyway where the product's dense side IS that factor, else a second
+# rows operand) and ``val op dot``. XLA gathers a slot's source row and
+# nothing else. The dense part makes a panel of the slab's quotient on
+# the MXU and multiplies it at once (``_sampled_dense_part``).
 
 WIDE_COLS = LANE        # columns a pass of the k-wide product takes
 # What a panel of the k-wide product keeps alive a slot, read off the
@@ -697,10 +702,10 @@ WIDE_COLS = LANE        # columns a pass of the k-wide product takes
 _TEMP_BYTES_A_SLOT_WIDE = 4 * WIDE_COLS + 24
 
 
-# and of a sampled product: the rows the entry's dot gathers beside them
-# (the destination's; the source's too where the scatter's own rows are
-# not that factor's), the dot and the quotient
-_TEMP_BYTES_A_SLOT_SAMPLED = 4 * WIDE_COLS + 8
+# and of a sampled product whose scatter's own rows are not the source
+# factor's: that factor's row beside them (the destination's rows, the
+# dot and the quotient live in the kernel)
+_TEMP_BYTES_A_SLOT_SAMPLED = 4 * WIDE_COLS
 
 
 def _wide_sums(off, val, g_ref, height: int, passes: int):
@@ -792,9 +797,127 @@ def _wide_runner(n_chunks: int, chunk: int, nb: int, block: int,
     )
 
 
+def _sampled_sums(off, val, g_ref, m_ref, tile, op: str, passes: int):
+    """:func:`_wide_sums` of a sampled product, (height, 128) float32
+    over the rows of ``tile``, the chunk's block (or window) of the
+    destination factor: a slot's value is ``val op`` the dot of its
+    source's row (``m_ref``'s; ``g_ref``'s own where None) and its
+    destination's. In two rounds over the sub-rows of 128 slots, so that
+    each keeps the MXU fed (one round a sub-row read 6,805 bundles a
+    windowed step on the described v5e, two read 3,415): first every
+    sub-row's destination rows — its one-hot, TRANSPOSED, times the
+    tile's three bfloat16 parts, which is the float32 row again
+    whatever ``passes`` says — and their dots, a lane sum, moved from
+    the sublanes onto the lanes where ``val`` lies; then ONE ``val op
+    dot`` for the chunk; then the scatter that :func:`_wide_sums`
+    makes, a sub-row's quotient moved back onto the sublanes as it
+    moves ``val``."""
+    height = tile.shape[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (height, LANE), 0)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (LANE, LANE), 1))
+
+    def dot(a, b, ca):
+        # one MXU pass a part, ``a`` contracted over its dimension ``ca``
+        return jax.lax.dot_general(
+            a, b.astype(jnp.bfloat16), (((ca,), (0,)), ((), ())),
+            precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32)
+
+    def rows_of(ref, s):
+        return ref[0, s * LANE:(s + 1) * LANE, :]
+
+    # split once a step, not once a sub-row
+    parts = _bf16_split(tile, 3)
+    ohs, dots = [], []
+    for s in range(off.shape[0]):
+        oh = (off[s:s + 1, :] == rows).astype(jnp.bfloat16)
+        theirs = sum(dot(oh, part, 0) for part in parts)     # (128, 128)
+        d = jnp.sum(rows_of(g_ref if m_ref is None else m_ref, s) * theirs,
+                    axis=1, keepdims=True)                    # (128, 1)
+        # a diagonal select and a sum of one term, exact
+        dots.append(jnp.sum(jnp.where(eye, d, 0.0), axis=0, keepdims=True))
+        ohs.append(oh)
+    q = sampled_values(op, val, jnp.concatenate(dots, axis=0))
+    acc = jnp.zeros((height, WIDE_COLS), jnp.float32)
+    for s, oh in enumerate(ohs):
+        col = jnp.sum(jnp.where(eye, q[s:s + 1, :], 0.0),
+                      axis=1, keepdims=True)                  # (128, 1)
+        for part in _bf16_split(rows_of(g_ref, s) * col, passes):
+            acc = acc + dot(oh, part, 1)
+    return acc
+
+
+def _make_sampled_scatter_kernel(block: int, passes: int, op: str,
+                                 shared: bool):
+    """:func:`_make_wide_scatter_kernel` for a sampled product: a slot's
+    value is made here. The chunk's block of the destination factor
+    rides beside the block sums (``dst_ref``, the same index map)."""
+    window = spmv_lib.WINDOW
+
+    def kernel(cb_ref, skip_ref, win_ref, off_ref, val_ref, g_ref, *refs):
+        m_ref = None if shared else refs[0]
+        dst_ref, acc_ref, y_ref = refs[-3:]
+
+        @pl.when(_first_chunk_of_its_block(cb_ref))
+        def _():
+            y_ref[...] = acc_ref[...]
+
+        c = pl.program_id(0)
+        live = c >= skip_ref[0]
+        win = win_ref[c]
+
+        if block >= window:
+            @pl.when(jnp.logical_and(live, win >= 0))
+            def _():
+                at = pl.multiple_of(win, 8)
+                y_ref[0, pl.ds(at, window), :] += _sampled_sums(
+                    off_ref[0] - at, val_ref[0], g_ref, m_ref,
+                    dst_ref[0, pl.ds(at, window), :], op, passes)
+
+        @pl.when(jnp.logical_and(live, win < 0))
+        def _():
+            y_ref[0] += _sampled_sums(off_ref[0], val_ref[0], g_ref, m_ref,
+                                      dst_ref[0], op, passes)
+
+    return kernel
+
+
+@functools.lru_cache(maxsize=32)
+def _sampled_runner(n_chunks: int, chunk: int, nb: int, block: int,
+                    passes: int, op: str, shared: bool, interpret: bool):
+    """scatter(chunk_block, skip, win, off, val, rows[, source rows],
+    dst, acc) -> acc + the chunks' block sums of a sampled product:
+    :func:`_wide_runner`'s operands, the slots' rows of the source
+    factor where they are not ``rows`` themselves, and ``dst`` (nb,
+    block, 128), the destination factor laid out as the sums are."""
+    cr = chunk // LANE
+    slots = pl.BlockSpec((1, cr, LANE), lambda c, cb, skip, win: (c, 0, 0))
+    rows = pl.BlockSpec((1, chunk, WIDE_COLS),
+                        lambda c, cb, skip, win: (c, 0, 0))
+    sums = pl.BlockSpec((1, block, WIDE_COLS),
+                        lambda c, cb, skip, win: (cb[c], 0, 0))
+    n_rows = 1 if shared else 2
+    return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
+        _make_sampled_scatter_kernel(block, passes, op, shared),
+        name="matrel_sampled_scatter_chunks",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,                  # chunk_block, skip, win
+            grid=(n_chunks,),
+            in_specs=[slots, slots] + [rows] * n_rows + [sums, sums],
+            out_specs=sums,
+        ),
+        out_shape=jax.ShapeDtypeStruct((nb, block, WIDE_COLS), jnp.float32),
+        input_output_aliases={6 + n_rows: 0},            # acc
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )
+
+
 def _wide_slot_bytes(sampled_rows: int) -> int:
     """A panel's temporaries a slot: the k-wide product's own, and a
-    sampled product's ``sampled_rows`` further gathered rows."""
+    sampled product's ``sampled_rows`` (0 or 1) further gathered rows."""
     return (_TEMP_BYTES_A_SLOT_WIDE
             + sampled_rows * _TEMP_BYTES_A_SLOT_SAMPLED)
 
@@ -890,28 +1013,22 @@ def _chunk_sets(tables, n_cols: int, wins=None):
     return [st + (win,) for st, win in zip(sets, wins)]
 
 
-def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool,
-                     sampled=None):
+def _in_panels(rows: int, per: int, panel, y):
+    """``panel(i, y)`` over ``rows`` table rows, ``per`` a panel."""
+    if per >= rows:
+        return panel(jnp.int32(0), y)
+    return jax.lax.fori_loop(0, -(-rows // per), panel, y)
+
+
+def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool):
     """``y`` (nb, block, 128) plus the block sums of every set of chunk
     tables against the rows of ``X`` (the set's columns and a zero row
-    for the padded slots, 128 wide), a panel of chunks at a time.
-    ``sampled`` = (op, source rows or None, destination rows, panels)
-    makes the product a sampled one: a slot's value is the table's
-    ``op`` the dot of its source's row (``X``'s own where None: one
-    gather serves the dot and the scatter) and its destination's, both
-    tables laid out as ``X`` is and as the block sums are. ``panels``
-    (:func:`sampled_panels`, or None) gives each set its panel's chunks
-    and the blocks a panel's destinations span: the panel gathers them
-    from that window of the table, which stays in fast memory where
-    the whole table would not."""
+    for the padded slots, 128 wide), a panel of chunks at a time."""
     nb = y.shape[0]
-    more = 0 if sampled is None else 1 + (sampled[1] is not None)
-    for n_set, (src, off, val, cb, win) in enumerate(sets):
+    for src, off, val, cb, win in sets:
         rows, cr, _ = off.shape
         chunk = cr * LANE
-        per, span = wide_panel_rows(rows, chunk, more), nb
-        if sampled is not None and sampled[3] is not None:
-            per, span = sampled[3][n_set][:2]
+        per = wide_panel_rows(rows, chunk)
         run = _wide_runner(per, chunk, nb, block, passes, interpret)
 
         def panel(i, y):
@@ -919,28 +1036,41 @@ def _wide_accumulate(y, sets, X, block: int, passes: int, interpret: bool,
             s, o, v, c, w = (jax.lax.dynamic_slice_in_dim(a, at, per)
                              for a in (src, off, val, cb, win))
             g = X.at[s.reshape(-1)].get(mode="promise_in_bounds")
-            if sampled is not None:
-                op, of_src, of_dst = sampled[:3]
-                mine = g if of_src is None else of_src.at[
-                    s.reshape(-1)].get(mode="promise_in_bounds")
-                # the blocks ascend with the chunks: a panel's
-                # destinations lie in ``span`` blocks from its first
-                first = jnp.minimum(c[0], nb - span) if span < nb else 0
-                dest = ((c - first)[:, None, None] * block + o).reshape(-1)
-                table = of_dst if span >= nb else \
-                    jax.lax.dynamic_slice_in_dim(of_dst, first * block,
-                                                 span * block)
-                theirs = table.at[dest].get(mode="promise_in_bounds")
-                v = sampled_values(op, v, jnp.sum(mine * theirs, axis=1)
-                                   .reshape(v.shape))
             skip = jnp.reshape(i * per - at, (1,)).astype(jnp.int32)
             return run(c, skip, w, o, v, g.reshape(per, chunk, WIDE_COLS),
                        y)
 
-        if per >= rows:
-            y = panel(jnp.int32(0), y)
-        else:
-            y = jax.lax.fori_loop(0, -(-rows // per), panel, y)
+        y = _in_panels(rows, per, panel, y)
+    return y
+
+
+def _sampled_accumulate(y, sets, X, block: int, passes: int,
+                        interpret: bool, op: str, of_src, of_dst):
+    """:func:`_wide_accumulate` of a sampled product: a slot's value is
+    the table's ``op`` the dot of its source's row of ``of_src`` (laid
+    out as ``X`` is; None: ``X`` itself, one gather serves the dot and
+    the scatter) and its destination's row of ``of_dst`` (nb, block,
+    128), which the kernel takes off the block tile the chunk adds into:
+    no gather reads the destination's factor."""
+    nb = y.shape[0]
+    for src, off, val, cb, win in sets:
+        rows, cr, _ = off.shape
+        chunk = cr * LANE
+        per = wide_panel_rows(rows, chunk, 0 if of_src is None else 1)
+        run = _sampled_runner(per, chunk, nb, block, passes, op,
+                              of_src is None, interpret)
+
+        def panel(i, y):
+            at = jnp.minimum(i * per, rows - per)
+            s, o, v, c, w = (jax.lax.dynamic_slice_in_dim(a, at, per)
+                             for a in (src, off, val, cb, win))
+            gathered = [t.at[s.reshape(-1)].get(mode="promise_in_bounds")
+                        .reshape(per, chunk, WIDE_COLS)
+                        for t in (X, of_src) if t is not None]
+            skip = jnp.reshape(i * per - at, (1,)).astype(jnp.int32)
+            return run(c, skip, w, o, v, *gathered, of_dst, y)
+
+        y = _in_panels(rows, per, panel, y)
     return y
 
 
@@ -1029,49 +1159,6 @@ def compact_matmat_parts(plan_static, part_statics, part_arrays,
     return Y if dense is None else _dense_part(Y, *dense, X)
 
 
-def sampled_panels(plan, shared: bool) -> tuple:
-    """((chunks a panel, blocks a panel's destinations span, slots a
-    chunk, chunks), ...) for every set of chunk tables a sampled product
-    walks of ``plan``, its parts in order (:func:`_chunk_sets`; a set of
-    hub chunks, which no COOMatrix plan has, takes the whole table). A
-    panel is what
-    :func:`wide_panel_rows` gives a slot of one (``shared``) or two more
-    gathered rows, split further while the rows of the destination's
-    factor its chunks name — consecutive blocks, for the chunks ascend
-    by block — make a gather table past ``spmv._FAST_TABLE_BYTES``: XLA's
-    row gather keeps its rate only from a table in fast memory (W's
-    480,189 rows whole read 14.9 ns a row, my chip run, PR 46). Reckoned
-    once from the host tables, as the panels walk them (the last moved
-    back to end with the tables)."""
-    more = 1 if shared else 2
-    most = max(spmv_lib._FAST_TABLE_BYTES // (4 * WIDE_COLS), 1)
-    out = []
-    for _, p in (getattr(plan, "parts", None) or ((0, plan),)):
-        rows, cap = np.asarray(p.src8).shape
-        nb = -(-p.n_rows // p.block)
-        if p.chunk_block is None:
-            walks = (cap // LANE) // _walk(cap // LANE)
-            cb = np.repeat(np.arange(rows), walks)
-            rows, cap = rows * walks, cap // walks
-        else:
-            cb = np.asarray(p.chunk_block)
-        per = wide_panel_rows(rows, cap, more)
-
-        def span(per):
-            at = np.minimum(np.arange(0, rows, per), rows - per)
-            return int((cb[at + per - 1] - cb[at]).max()) + 1
-
-        # (a block alone past the table's size is gathered as it is)
-        while span(per) > max(most // p.block, 1) and per > 1:
-            per = min(per - 1, -(-rows // (-(-rows // per) + 1)))
-        out.append((per, min(span(per), nb), cap, rows))
-        if p.hubs is not None:
-            hub_rows = np.asarray(p.hubs.chunk_block).shape[0]
-            out.append((wide_panel_rows(hub_rows, cap, more), nb, cap,
-                        hub_rows))
-    return tuple(out)
-
-
 def sampled_values(op: str, s, d):
     """``s op d`` as the executor's element-wise node gives it where
     ``s`` is a sparse matrix's values: ``s * d``, or ``s / d`` with
@@ -1152,8 +1239,8 @@ def _sampled_dense_part(Y, role: str, slab, lines, Z, op: str, of_src,
 
 def sampled_matmat_parts(plan_static, part_statics, part_arrays,
                          Z: jax.Array, op: str, of_src, of_dst,
-                         passes: int = 3, interpret: bool = False,
-                         panels=None) -> jax.Array:
+                         passes: int = 3,
+                         interpret: bool = False) -> jax.Array:
     """Traceable body: ``(A op (P·R)) · Z`` for the matrix ``A`` of a
     plan (its operands as :func:`compact_matmat_parts` takes them,
     :func:`plan_operands`), a dense ``Z`` (n_cols, k <= 128) and a
@@ -1162,14 +1249,15 @@ def sampled_matmat_parts(plan_static, part_statics, part_arrays,
     ``of_src[j]``, the rows of the product's two factors (n_rows x k'
     and n_cols x k', k' <= 128) that the entry's coordinates name.
     ``of_src`` None says that it is ``Z`` itself. The compact parts
-    gather both rows a slot, panel by panel, and scatter through the
-    k-wide kernel (``passes`` 3: float32-faithful, and what the
-    executor runs); the dense part is :func:`_sampled_dense_part`,
-    float32 at ``highest`` whatever ``passes`` says; overflow entries go
-    by the scalar path with the same values. ``panels``:
-    :func:`sampled_panels` of the plan (None: :func:`wide_panel_rows`'
-    panels, the destination's rows gathered from the whole table). The
-    sampled values are never stored whole."""
+    gather a slot's source rows, panel by panel, and scatter through
+    the sampled kernel (``matrel_sampled_scatter_chunks``), which takes
+    the destination's rows off the block tile it adds into, makes the
+    entry's dot and its value, and adds (``passes`` 3: float32-faithful,
+    and what the executor runs; they are the parts of the scatter's
+    contributions, the dot is float32 whatever they say); the dense
+    part is :func:`_sampled_dense_part`, float32 at ``highest``
+    whatever ``passes`` says; overflow entries go by the scalar path
+    with the same values. The sampled values are never stored whole."""
     dense = None
     if part_statics and isinstance(part_statics[-1][0], str):
         dense = (part_statics[-1][0],) + tuple(part_arrays[-1])
@@ -1187,18 +1275,14 @@ def sampled_matmat_parts(plan_static, part_statics, part_arrays,
     dst = jnp.pad(lanes(of_dst), ((0, nb * block - n_rows), (0, 0)))
     y = jnp.zeros((nb, block, WIDE_COLS), jnp.float32)
     zero_row = ((0, spmv_lib.WIDTH), (0, 0))
-    n_sets = 0
+    tiles = dst.reshape(nb, block, WIDE_COLS)
     for (col0, (_, n_cols, _, _)), (tables, _, wins) in zip(
             part_statics, part_arrays):
-        sets = _chunk_sets(tables, n_cols, wins)
-        y = _wide_accumulate(
-            y, sets, jnp.pad(Zf[col0:col0 + n_cols], zero_row), block,
-            passes, interpret,
-            sampled=(op, None if src is None else jnp.pad(
-                src[col0:col0 + n_cols], zero_row), dst,
-                None if panels is None
-                else panels[n_sets:n_sets + len(sets)]))
-        n_sets += len(sets)
+        y = _sampled_accumulate(
+            y, _chunk_sets(tables, n_cols, wins),
+            jnp.pad(Zf[col0:col0 + n_cols], zero_row), block, passes,
+            interpret, op, None if src is None else jnp.pad(
+                src[col0:col0 + n_cols], zero_row), tiles)
     Y = y.reshape(-1, WIDE_COLS)[:n_rows, :k]
     mine = Zf if src is None else src
     for (col0, (_, n_cols, _, _)), (_, ov, _) in zip(part_statics,

@@ -296,74 +296,108 @@ PN_LINES, PN_FWD_CHUNKS = 4_224, 4_289
 PN_BWD_CHUNKS = (996, 978, 970, 958)
 
 
+def _gather_operands(text, rows_over=0):
+    """The shape of the TABLE of every gather of more than ``rows_over``
+    rows in a compiled program's text: the first operand of each
+    ``gather(`` instruction, by its own definition (XLA fuses a gather:
+    its table is then a parameter of the fused computation, which names
+    its shape)."""
+    shape_of = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[\d,]*\])", text))
+    return [shape_of[name] for rows, name in re.findall(
+        r"= \w+\[(\d+)[\d,]*\]\S* gather\((%[\w.\-]+)", text)
+        if int(rows) > rows_over]
+
+
 def test_pnmf_sampled_products(one_chip, monkeypatch):
-    """(V ./ (W H)) * t(H) and t(W) * (V ./ (W H)) at the cell's shapes:
-    neither W H nor the quotient exists whole (no users x movies and no
-    users x lines float32 array), a panel's destination rows are
-    gathered from a window of W that lies in fast memory (the whole
-    table, 246 MB, does not: 14.9 ns a row on the chip), the slab is
-    read where it lies, and arguments and temporaries stay inside what
-    ``sampled_facts`` reckons the plan to hold."""
+    """(V ./ (W H)) * t(H) and t(W) * (V ./ (W H)) at the cell's shapes
+    through the sampled kernel (PR 47): neither W H nor the quotient
+    exists whole (no users x movies and no users x lines float32 array),
+    NO gather reads the destination's factor — the kernel takes its rows
+    off the block tile it adds into — so W's 246 MB are no gather's
+    table in either product (a source panel's 61.5 MB of them are, in
+    fast memory), the panels are ``wide_panel_rows``' (2 forward, as
+    GNMF's), the slab is read where it lies, and arguments and
+    temporaries stay inside what ``sampled_facts`` reckons the plan to
+    hold. Prints what the compile says of the kernel; its bundles a
+    step are read offline (PERF.md section 6, PR 47: 3,415 a windowed
+    and 11,983 a whole-block step where the plain kernel reads 1,928
+    and 5,796)."""
     monkeypatch.setattr(pc, "_hbm_limit", lambda: int(15.75 * 2 ** 30))
     slab = _sds(one_chip, (NF_USERS, PN_LINES), jnp.bfloat16)
     lines = _sds(one_chip, (PN_LINES,), jnp.int32)
     run = jax.jit(pc.sampled_matmat_parts,
-                  static_argnums=(0, 1, 4, 7, 8, 9))
-    window = spmv_lib._FAST_TABLE_BYTES // 512          # rows of 512 B
+                  static_argnums=(0, 1, 4, 7, 8))
     panel = 3 * 4 * strategies.ACC_PANEL_ROWS * PN_LINES
 
     def wins(chunks):
         return (_sds(one_chip, (chunks,), jnp.int32),)
 
-    def check(compiled, slots_a_panel, out_bytes):
+    def check(compiled, slots_a_panel, out_bytes, dst_rows):
         text = compiled.as_text()
         assert f"f32[{NF_USERS},{NF_MOVIES}]" not in text
         assert f"f32[{NF_USERS},{PN_LINES}]" not in text
         assert f"f32[{PN_LINES},{NF_USERS}]" not in text
         assert not re.search(rf"= bf16\[{NF_USERS},{PN_LINES}\]\S* "
                              r"(copy|transpose)\(", text)
-        assert "matrel_spmm_scatter_chunks" in text
+        assert "matrel_sampled_scatter_chunks" in text
+        assert "matrel_spmm_scatter_chunks" not in text
+        # the gathers by the slot (the dense lines' own factor rows, a
+        # gather of 4,224, are the dense part's and stay)
+        tables = _gather_operands(text, rows_over=PN_LINES)
+        nb = -(-dst_rows // BLOCK)
+        assert tables
+        # the destination's factor, as the kernel takes it or as given
+        assert not any(t in tables for t in (
+            f"f32[{nb * BLOCK},128]", f"f32[{nb},{BLOCK},128]",
+            f"f32[{dst_rows},128]")), tables
+        # and W whole (246 MB) under no name
+        assert not any(re.match(rf"f32\[{NF_USERS}\D|f32\[{NF_USERS + 8}\D"
+                                rf"|f32\[{938 * BLOCK}\D", t)
+                       for t in tables), tables
         stats = compiled.memory_analysis()
         reckoned = (stats.argument_size_in_bytes + panel + 3 * out_bytes
-                    + pc._wide_slot_bytes(1) * slots_a_panel)
+                    + pc._wide_slot_bytes(0) * slots_a_panel)
         taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
         assert taken < 1.05 * reckoned, (reckoned, taken)
         assert taken + out_bytes < int(15.75 * 2 ** 30)
+        print(f"gather tables {sorted(set(tables))}; temporaries "
+              f"{stats.temp_size_in_bytes:,} B of {reckoned:,} reckoned")
         return text
 
     # the W update: sources the movies (t(H)'s rows serve the dot and
-    # the scatter), destinations the users, four panels of a window each
+    # the scatter), destinations the users: W's rows off the block tile
     static = (NF_USERS, NF_MOVIES, BLOCK, spmv_lib.LO)
-    per = -(-PN_FWD_CHUNKS // 4)
+    per = pc.wide_panel_rows(PN_FWD_CHUNKS, spmv_lib.CHUNK)
+    assert -(-PN_FWD_CHUNKS // per) == 2
     fwd = _compile(
         run, static, ((0, static), ("sources", None)),
         ((_chunk_table_shapes(PN_FWD_CHUNKS, one_chip), (),
           wins(PN_FWD_CHUNKS)), (slab, lines)),
         _sds(one_chip, (NF_MOVIES, NF_RANK), jnp.float32), "div", None,
-        _sds(one_chip, (NF_USERS, NF_RANK), jnp.float32), 3, False,
-        ((per, window // BLOCK, spmv_lib.CHUNK, PN_FWD_CHUNKS),))
-    text = check(fwd, per * spmv_lib.CHUNK, 4 * NF_USERS * NF_RANK)
-    assert re.search(rf"f32\[{window},128\]\{{[^}}]*S\(1\)\}}", text)
+        _sds(one_chip, (NF_USERS, NF_RANK), jnp.float32), 3, False)
+    text = check(fwd, per * spmv_lib.CHUNK, 4 * NF_USERS * NF_RANK,
+                 NF_USERS)
     assert f"f32[{per * spmv_lib.CHUNK},128]" in text   # a panel's rows
+    assert re.search(rf"f32\[{NF_MOVIES + 8},128\]\{{[^}}]*S\(1\)\}}", text)
 
     # the H update: sources the users in four source panels (W's rows
-    # serve both), destinations the movies: t(H) whole is a fast table
+    # serve both: a panel's 120,048 are a fast table), destinations the
+    # movies: t(H)'s rows off the block tile
     static = (NF_MOVIES, NF_USERS, BLOCK, spmv_lib.LO)
     statics = tuple(
         (c0, (NF_MOVIES, min(NF_PANEL_USERS, NF_USERS - c0), BLOCK,
               spmv_lib.LO))
         for c0 in range(0, NF_USERS, NF_PANEL_USERS))
-    nb = -(-NF_MOVIES // BLOCK)
     bwd = _compile(
         run, static, statics + (("destinations", None),),
         tuple((_chunk_table_shapes(c, one_chip), (), wins(c))
               for c in PN_BWD_CHUNKS) + ((slab, lines),),
         _sds(one_chip, (NF_USERS, NF_RANK), jnp.float32), "div", None,
-        _sds(one_chip, (NF_MOVIES, NF_RANK), jnp.float32), 3, False,
-        tuple((c, nb, spmv_lib.CHUNK, c) for c in PN_BWD_CHUNKS))
+        _sds(one_chip, (NF_MOVIES, NF_RANK), jnp.float32), 3, False)
     text = check(bwd, 2 * max(PN_BWD_CHUNKS) * spmv_lib.CHUNK,
-                 4 * NF_MOVIES * NF_RANK)
-    assert re.search(rf"f32\[{nb * BLOCK},128\]\{{[^}}]*S\(1\)\}}", text)
+                 4 * NF_MOVIES * NF_RANK, NF_MOVIES)
+    assert re.search(rf"f32\[{NF_PANEL_USERS + 8},128\]\{{[^}}]*S\(1\)\}}",
+                     text)
 
 
 def test_compact_spmv_sharded_2x2(mesh_2x2):

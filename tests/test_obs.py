@@ -1208,8 +1208,9 @@ class TestProfilerTier:
             and name.value.startswith("matrel_")
 
     def test_all_pallas_call_sites_were_found(self):
-        # PR 36: the hub scatter; PR 44: less the routed SpMV's two
-        assert len(_PALLAS_SITES) == 8
+        # PR 36: the hub scatter; PR 44: less the routed SpMV's two;
+        # PR 47: the sampled scatter
+        assert len(_PALLAS_SITES) == 9
 
 
 class TestAnalyzeEvent:

@@ -136,10 +136,12 @@ def test_the_rule_leaves_a_product_wider_than_the_tables(rng):
 # -- the fused products -------------------------------------------------------------
 
 
+@pytest.mark.parametrize("orientation", ["transposed", "forward"])
 @pytest.mark.parametrize("hot,exact", [(True, True), (True, False),
                                        (False, False)],
                          ids=["bfloat16-slab", "float32-slab", "no-slab"])
-def test_both_products_match_float64(rng, one_chip, hot, exact):
+def test_both_products_match_float64(rng, one_chip, hot, exact,
+                                     orientation):
     """t(W) * (V ./ (W H)) and (V ./ (W H)) * t(H) inside the two
     updates, against float64 numpy, entry by entry. rtol 5e-6: the program's sums are
     float32 (an entry's dot of 16 terms, a hot movie's 125 quotients
@@ -154,38 +156,46 @@ def test_both_products_match_float64(rng, one_chip, hot, exact):
     s.register("H", H)
     Vd = V.to_dense().astype(np.float64)
     Q = np.where(Vd != 0, Vd / (w.astype(np.float64) @ h), 0.0)
-    for sql, want, orientation in (
-            (SQL_H, h * (w.T @ Q) / w.sum(0)[:, None], "transposed"),
-            (SQL_W, w * (Q @ h.T) / h.sum(1)[None, :], "forward")):
-        got = s.compute(s.sql(sql)).to_numpy()
-        np.testing.assert_allclose(got, want, rtol=5e-6)
-        said = s.last_plan()
-        assert said["densified_products"] == [] and said["spmm"] == []
-        assert said["executors"] == ["pallas_spmv"]
-        (rec,) = said["sampled"]
-        assert (rec["orientation"], rec["op"]) == (orientation, "div")
-        assert rec["k"] == rec["inner"] == RANK
-        assert rec["entries"] == V.nnz and rec["overflow_edges"] == 0
-        # W is the product's dense side and the factor whose rows the
-        # transposed plan's sources name (t(H) in the forward one)
-        assert rec["shared_gather"] is True
-        assert rec["source_panels"] == (4 if orientation == "transposed"
-                                        else 15)
-        if hot:
-            assert rec["lines"] == HOT and rec["panel_rows"] == 8192
-            assert 0 < rec["dense_entries"] < V.nnz
-            assert rec["slab_dtype"] == ("bfloat16" if exact
-                                         else "float32")
-        else:
-            assert (rec["lines"], rec["dense_entries"], rec["slab_dtype"],
-                    rec["panel_rows"]) == (0, 0, "", 0)
-        assert 0 < rec["hbm_plan_bytes"] <= said["hbm_plan_bytes"]
+    sql, want = {
+        "transposed": (SQL_H, h * (w.T @ Q) / w.sum(0)[:, None]),
+        "forward": (SQL_W, w * (Q @ h.T) / h.sum(1)[None, :])}[orientation]
+    got = s.compute(s.sql(sql)).to_numpy()
+    np.testing.assert_allclose(got, want, rtol=5e-6)
+    said = s.last_plan()
+    assert said["densified_products"] == [] and said["spmm"] == []
+    assert said["executors"] == ["pallas_spmv"]
+    (rec,) = said["sampled"]
+    assert (rec["orientation"], rec["op"]) == (orientation, "div")
+    assert rec["k"] == rec["inner"] == RANK
+    assert rec["entries"] == V.nnz and rec["overflow_edges"] == 0
+    # W is the product's dense side and the factor whose rows the
+    # transposed plan's sources name (t(H) in the forward one); the
+    # destination's rows and the dot are the kernel's (PR 47)
+    assert rec["shared_gather"] is True and rec["dot"] == "kernel"
+    assert rec["source_panels"] == (4 if orientation == "transposed"
+                                    else 15)
+    # a panel of table rows each source panel: no split for a window
+    assert rec["panels"] == rec["source_panels"]
+    if hot:
+        assert rec["lines"] == HOT and rec["panel_rows"] == 8192
+        assert 0 < rec["dense_entries"] < V.nnz
+        assert rec["slab_dtype"] == ("bfloat16" if exact
+                                     else "float32")
+    else:
+        assert (rec["lines"], rec["dense_entries"], rec["slab_dtype"],
+                rec["panel_rows"]) == (0, 0, "", 0)
+    assert 0 < rec["hbm_plan_bytes"] <= said["hbm_plan_bytes"]
 
 
+@pytest.mark.parametrize("sql,shared", [
+    ("X * (V .* (W * H))", False), ("((W * H) .* V) * Y", False),
+    ("((W * H) .* V) * t(H)", True)],
+    ids=["side-before", "side-after", "side-a-factor"])
 def test_the_sampled_product_by_mul_and_a_side_that_is_no_factor(
-        rng, one_chip):
+        rng, one_chip, sql, shared):
     """``.*`` either way round, and a dense side that is not one of the
-    product's factors (its rows are gathered beside the factor's)."""
+    product's factors (its rows are gathered beside the factor's: the
+    kernel's second rows operand)."""
     V = _ratings(rng, hot=True, exact=False)
     s = _session(one_chip)
     w, h, W, H = _factors(rng, s)
@@ -196,14 +206,14 @@ def test_the_sampled_product_by_mul_and_a_side_that_is_no_factor(
                     ("Y", BlockMatrix.from_numpy(y, mesh=s.mesh))):
         s.register(name, m)
     P = V.to_dense().astype(np.float64) * (w.astype(np.float64) @ h)
-    for sql, want, shared in (("X * (V .* (W * H))", x @ P, False),
-                              ("((W * H) .* V) * Y", P @ y, False),
-                              ("((W * H) .* V) * t(H)", P @ h.T, True)):
-        got = s.compute(s.sql(sql)).to_numpy()
-        assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-6
-        (rec,) = s.last_plan()["sampled"]
-        assert rec["op"] == "mul" and rec["shared_gather"] is shared
-        assert s.last_plan()["densified_products"] == []
+    want = {"X * (V .* (W * H))": x @ P, "((W * H) .* V) * Y": P @ y,
+            "((W * H) .* V) * t(H)": P @ h.T}[sql]
+    got = s.compute(s.sql(sql)).to_numpy()
+    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-6
+    (rec,) = s.last_plan()["sampled"]
+    assert rec["op"] == "mul" and rec["shared_gather"] is shared
+    assert rec["dot"] == "kernel"
+    assert s.last_plan()["densified_products"] == []
 
 
 def test_a_zero_denominator_gives_zero(rng, one_chip):
@@ -255,6 +265,176 @@ def test_a_blocks_layout_plan_with_an_overflow_tail(rng, monkeypatch):
             got, (Vd / (w.astype(np.float64) @ h)) @ h.T, rtol=3e-6)
     finally:
         config_lib._default_config = was
+
+
+# -- the kernel (PR 47) --------------------------------------------------------------
+
+
+def _kernel_plan(name, rng, powers_of_two=False):
+    """(plan, rows, cols, vals) of a matrix of 300 columns whose chunks
+    are what ``name`` says: ``windows`` — 39 entries a row, a chunk of
+    2,048 slots in row order names ~52 rows, every chunk takes a
+    128-row window; ``whole`` — 1,500 entries a block of 512 rows, one
+    chunk that spans it; ``both`` — a block of each kind and one with
+    both; ``short-block`` — blocks of 64 rows, shorter than a window."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    n_rows, n_cols, block = 1536, 300, 512
+    if name == "windows":
+        rows = rng.integers(0, n_rows, 60_000)
+    elif name == "whole":
+        rows = rng.integers(0, n_rows, 4_500)
+    elif name == "both":
+        rows = np.concatenate([
+            rng.integers(0, 512, 20_000), rng.integers(512, 1024, 1_500),
+            1024 + rng.integers(0, 60, 4_000),
+            rng.integers(1024, 1536, 1_500)])
+    else:
+        n_rows, block = 320, 64
+        rows = rng.integers(0, n_rows, 6_000)
+    rows = rng.permutation(rows)
+    cols = rng.integers(0, n_cols, rows.size)
+    vals = (2.0 ** rng.integers(0, 3, rows.size) if powers_of_two
+            else rng.uniform(0.5, 5.0, rows.size)).astype(np.float32)
+    plan = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
+                                    block=block, layout="chunks", hubs=False)
+    win = np.asarray(pc.wide_windows(plan)[0][0])
+    assert plan.overflow == () and {
+        "windows": (win >= 0).all(), "whole": (win < 0).all(),
+        "both": sorted(set(win[plan.chunk_block == 2] >= 0)) == [False, True],
+        "short-block": (win < 0).all()}[name], win
+    return plan, rows, cols, vals
+
+
+def _sampled_oracle(rows, cols, vals, op, src, dst, Z, n_rows):
+    """float64: sum over the entries of ``(val op <src[col], dst[row]>)
+    * Z[col]`` by row, ``x / 0 = 0``."""
+    d = np.einsum("ek,ek->e", src.astype(np.float64)[cols],
+                  dst.astype(np.float64)[rows])
+    v = vals.astype(np.float64)
+    q = v * d if op == "mul" else np.where(
+        d != 0, v / np.where(d != 0, d, 1.0), 0.0)
+    want = np.zeros((n_rows, Z.shape[1]))
+    np.add.at(want, rows, q[:, None] * Z.astype(np.float64)[cols])
+    return want
+
+
+def _sampled_kernel(plan, Z, op, of_src, of_dst, passes=3):
+    import jax.numpy as jnp
+    from matrel_tpu.ops import pallas_spmv as pc
+    static, statics, arrays = pc.plan_operands(plan)
+    return np.asarray(pc.sampled_matmat_parts(
+        static, statics, arrays, jnp.asarray(Z), op,
+        None if of_src is None else jnp.asarray(of_src),
+        jnp.asarray(of_dst), passes=passes, interpret=True))
+
+
+@pytest.mark.parametrize("op", ["div", "mul"])
+@pytest.mark.parametrize("shared", [True, False],
+                         ids=["shared", "second-rows-operand"])
+@pytest.mark.parametrize("name", ["windows", "whole", "both", "short-block"])
+def test_the_sampled_kernel_matches_float64(rng, name, shared, op):
+    """``matrel_sampled_scatter_chunks`` interpreted, on chunks that
+    take a window, chunks that take the block, both inside one block
+    and a block shorter than a window; the source rows the scatter's
+    own or a second operand; ``./`` and ``.*``; some destination rows
+    ZERO (a zero denominator gives 0) and every block's last chunk
+    padded (a padded slot gives 0, not 0 / 0): against float64 at the
+    2e-6 of max |want| the file holds a product to."""
+    plan, rows, cols, vals = _kernel_plan(name, rng)
+    k, inner = 24, 16
+    src = rng.uniform(0.1, 1.0, (plan.n_cols, inner)).astype(np.float32)
+    dst = rng.uniform(0.1, 1.0, (plan.n_rows, inner)).astype(np.float32)
+    dst[rng.integers(0, plan.n_rows, 40)] = 0.0
+    Z = src if shared else rng.uniform(-1, 1, (plan.n_cols, k)).astype(
+        np.float32)
+    got = _sampled_kernel(plan, Z, op, None if shared else src, dst)
+    want = _sampled_oracle(rows, cols, vals, op, src, dst, Z, plan.n_rows)
+    assert got.shape == want.shape and np.all(np.isfinite(got))
+    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-6
+    empty = np.setdiff1d(np.arange(plan.n_rows), rows)
+    assert not got[empty].any()
+
+
+@pytest.mark.parametrize("passes,lo,hi", [(3, 0.0, 2e-6), (2, 2e-6, 3e-4),
+                                          (1, 3e-4, 3e-2)])
+def test_passes_change_the_scatter_and_not_the_dot(rng, passes, lo, hi):
+    """``passes`` are the bfloat16 parts of the scatter's contributions
+    (PERF.md section 2's controls read them so): fewer of them move a
+    product of arbitrary values by what a part holds. The entry's dot
+    takes the destination's rows in THREE parts whatever ``passes``
+    says: rows (131,329, 257) that need all three (2^17 + 2^8 + 1)
+    against sources (1, -1) give the dot 2^17 exactly, and with values
+    and a scattered side that are small powers of two every
+    contribution fits ONE part, so the answer is exact at every
+    ``passes`` (a dot from two parts would read 131,071, from one
+    130,816)."""
+    plan, rows, cols, vals = _kernel_plan("both", rng)
+    src = rng.uniform(0.1, 1.0, (plan.n_cols, 16)).astype(np.float32)
+    dst = rng.uniform(0.1, 1.0, (plan.n_rows, 16)).astype(np.float32)
+    got = _sampled_kernel(plan, src, "div", None, dst, passes)
+    want = _sampled_oracle(rows, cols, vals, "div", src, dst, src,
+                           plan.n_rows)
+    err = np.max(np.abs(got - want)) / np.max(np.abs(want))
+    assert lo <= err < hi, err
+    # the exact case, through the second rows operand
+    plan, rows, cols, vals = _kernel_plan("both", rng, powers_of_two=True)
+    ones = np.zeros((plan.n_cols, 2), np.float32)
+    ones[:, 0], ones[:, 1] = 1.0, -1.0
+    tall = np.zeros((plan.n_rows, 2), np.float32)
+    tall[:, 0], tall[:, 1] = 131329.0, 257.0
+    twos = (2.0 ** rng.integers(0, 3, (plan.n_cols, 8))).astype(np.float32)
+    exact = _sampled_kernel(plan, twos, "mul", ones, tall, passes)
+    np.testing.assert_array_equal(exact, _sampled_oracle(
+        rows, cols, vals, "mul", ones, tall, twos, plan.n_rows))
+
+
+def test_a_plain_product_lowers_as_it_did_before_the_sampled_kernel(rng):
+    """The GNMF guard: ``coo_leaf x dense`` through
+    ``compact_matmat_parts`` lowers for the chip to the text the parent
+    of PR 47 lowered it to — the Mosaic kernel's serialized body (which
+    embeds paths and line numbers) read back and printed without debug
+    info — by its SHA-256, recorded on that parent in this container's
+    jax: the sampled kernel is a second ``pallas_call``, and nothing of
+    it is in a plain product's program."""
+    import base64
+    import hashlib
+    import re
+    import jax
+    import jax.numpy as jnp
+    from jax._src.interpreters import mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    from matrel_tpu.ops import pallas_spmv as pc
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the recorded text is jax 0.9.0's")
+    fixed = np.random.default_rng(7)
+    rows = np.concatenate([fixed.integers(0, 512, 30_000),
+                           fixed.integers(512, 1024, 1_500)])
+    cols = fixed.integers(0, 300, rows.size)
+    plan = spmv_lib.build_spmv_plan(
+        rows, cols, fixed.standard_normal(rows.size).astype(np.float32),
+        1024, 300, layout="chunks", hubs=False)
+    static, statics, arrays = pc.plan_operands(plan)
+    text = jax.jit(lambda pa, x: pc.compact_matmat_parts(
+        static, statics, pa, x, 3, False)).trace(
+        arrays, jax.ShapeDtypeStruct((300, 128), jnp.float32)
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("matrel_spmm_scatter_chunks") == 1
+    assert "matrel_sampled" not in text
+
+    def body(m):
+        with mlir.make_ir_context() as ctx:
+            tpu.register_dialect(ctx)
+            ctx.allow_unregistered_dialects = True
+            mod = ir.Module.parse(base64.b64decode(m.group(1)))
+            return mod.operation.get_asm(enable_debug_info=False)
+
+    text, n = re.subn(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22", body,
+                      text)
+    assert n == 1
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "6a25b79afc2f9a1c20b05430d1bb3731"
+        "efc5e9552b344238ec2bad7b2ae268c5")
 
 
 # -- anywhere else it is the array it was ----------------------------------------------
