@@ -292,9 +292,11 @@ def plan_facts(plan, entries: int) -> dict:
     pallas_spmv.wide_plan_bytes, and the slab, once) and
     ``windowed_chunks``: of the chunks its scatter walks (``chunks`` in
     the chunks layout; a blocks-layout row is walked as several), those
-    whose one-hot is a 128-row window and not the block
-    (pallas_spmv.wide_windows; 0 where none is). Where the matrix has a
-    dense part (:class:`DenseLines`), also ``dense_lines``,
+    whose one-hot is a window shorter than the block
+    (pallas_spmv.wide_windows; 0 where none is), and ``window_rows``,
+    the same chunks by their window's height ({"128": n, "256": n}: the
+    ladder ``spmv.WINDOWS``; the rest take the whole block). Where the
+    matrix has a dense part (:class:`DenseLines`), also ``dense_lines``,
     ``dense_axis`` (``rows`` / ``columns`` of the matrix the product
     names), ``dense_entries`` (``entries + dense_entries`` is the nnz),
     ``dense_bytes`` and ``dense_dtype`` — the same for both
@@ -305,13 +307,17 @@ def plan_facts(plan, entries: int) -> dict:
     slots = sum(r * c for r, c in shapes)
     per = [pc.wide_panel_rows(r, c) for r, c in shapes]
     dense = getattr(plan, "dense", None)
+    tall = [pc.wide_windows(p)[1] for p in parts]
+    window_rows = {str(h): sum(t[h] for t in tall)
+                   for h in spmv_lib.WINDOWS}
     facts = {
         "layout": ("chunks" if parts[0].chunk_block is not None
                    else "blocks"),
         "entries": int(entries) - (0 if dense is None else dense.entries),
         "slots": int(slots),
         "chunks": int(sum(r for r, _ in shapes)),
-        "windowed_chunks": sum(pc.wide_windows(p)[1] for p in parts),
+        "windowed_chunks": sum(window_rows.values()),
+        "window_rows": window_rows,
         "source_panels": len(parts),
         "table": "panelled" if len(parts) > 1 else "hbm",
         "overflow_edges": sum(0 if p.ov_rows is None
@@ -343,7 +349,8 @@ def sampled_facts(plan, entries: int, shared: bool) -> dict:
     the destination's rows off its block tile and makes the entry's dot;
     no path of this tree gathers them) and of the compact parts, which
     hold the rest, :func:`plan_facts`' ``layout``, ``slots``,
-    ``chunks``, ``source_panels``, ``overflow_edges`` and, at this
+    ``chunks``, ``windowed_chunks``, ``window_rows``,
+    ``source_panels``, ``overflow_edges`` and, at this
     product's own panel size (a slot holds one gathered row more where
     the gather is not ``shared``), ``panels``; ``hbm_plan_bytes``: the
     tables, the largest panel's temporaries, the slab once and one
@@ -355,6 +362,7 @@ def sampled_facts(plan, entries: int, shared: bool) -> dict:
     shapes = [np.asarray(p.src8).shape for _, p in plan_parts(plan)]
     dense = getattr(plan, "dense", None)
     facts = {k: own[k] for k in ("layout", "slots", "chunks",
+                                 "windowed_chunks", "window_rows",
                                  "source_panels", "overflow_edges")}
     facts.update(
         entries=int(entries), dense_entries=own.get("dense_entries", 0),

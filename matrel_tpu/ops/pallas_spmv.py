@@ -654,21 +654,25 @@ def spmv_compact_sharded(plan: spmv_lib.EdgeSpMVPlan, x: jax.Array,
 # this one kernel: a blocks-layout row is walked as ``capacity / tile``
 # chunks of its block.
 #
-# The one-hot's height follows the rows a chunk really holds (PR 38).
-# ``wide_windows`` reads every chunk table once on the host
-# (``spmv.chunk_windows``): where a chunk's real slots lie within
-# ``spmv.WINDOW`` = 128 rows of one another, ``win[c]`` names the first
-# of them (a multiple of 8), the one-hot is (128, 128) and the sums add
-# into rows ``win : win + 128`` of the tile: a quarter of the MXU pushes,
-# result pops and adds of the (block, 128) one-hot at block 512, the same
-# three parts, the same float32 accumulation. Elsewhere ``win[c]`` is −1
-# and the chunk takes the whole block's one-hot. ``win`` rides as a
-# scalar-prefetch operand beside ``chunk_block``; nothing chooses but the
-# tables. The chunks fill lays a block's slots in row order
-# (native/spmv_plan.cc), so 2,048 slots name a few rows (a Netflix user
-# holds 209 ratings, a movie 5,654) and at most ``block / 120`` chunks
-# of a block spread further; a plan in input order reads −1 throughout
-# and runs the body it always ran.
+# The one-hot's height follows the rows a chunk really holds (PR 38; PR
+# 49: from a ladder of heights). ``wide_windows`` reads every chunk table
+# once on the host (``spmv.chunk_windows``): ``win[c]`` names the
+# shortest rung of ``spmv.WINDOWS`` (128, 256 rows; those below the
+# block) that holds the chunk's real slots and the first of its rows (a
+# multiple of 8, the rung's index in the low bits), the one-hot is
+# (rung, 128) and the sums add into rows ``start : start + rung`` of the
+# tile: at 128 rows a quarter, at 256 a half of the MXU pushes, result
+# pops and adds of the (block, 128) one-hot at block 512, the same three
+# parts, the same float32 accumulation. Where no rung holds them
+# ``win[c]`` is −1 and the chunk takes the whole block's one-hot. ``win``
+# rides as a scalar-prefetch operand beside ``chunk_block`` and the
+# kernel holds a body a height under ``pl.when`` (``_by_window``);
+# nothing chooses but the tables. The chunks fill lays a block's slots
+# in row order (native/spmv_plan.cc), so 2,048 slots name a few rows (a
+# Netflix user holds 209 ratings, a movie 5,654; the residual beside a
+# slab 16, so a chunk spans ~125 rows and 45% of them need the second
+# rung); a plan in input order reads −1 throughout and runs the body it
+# always ran.
 #
 # A plan may bring a DENSE PART beside its compact parts (PR 43,
 # core.coo.DenseLines): the lines of one axis that hold more entries than
@@ -732,9 +736,25 @@ def _wide_sums(off, val, g_ref, height: int, passes: int):
     return acc
 
 
-def _make_wide_scatter_kernel(block: int, passes: int):
-    window = spmv_lib.WINDOW
+def _by_window(block: int, live, win, add) -> None:
+    """``add(at, height)`` under the ``pl.when`` that ``win``
+    (``spmv.chunk_windows``: start + rung, or −1) chooses for a live
+    chunk: one body a rung of the ladder below ``block`` — the chunk's
+    rows lie in that window of the tile from row ``at``, its one-hot is
+    that tall — and the whole block's (``at`` None) where no rung holds
+    them."""
+    for rung, height in enumerate(spmv_lib.WINDOWS):
+        if height < block:
+            @pl.when(live & (win >= 0) & (win & 7 == rung))
+            def _(rung=rung, height=height):
+                add(pl.multiple_of(win - rung, 8), height)
 
+    @pl.when(live & (win < 0))
+    def _():
+        add(None, block)
+
+
+def _make_wide_scatter_kernel(block: int, passes: int):
     def kernel(cb_ref, skip_ref, win_ref, off_ref, val_ref, g_ref, acc_ref,
                y_ref):
         # the block's tile starts from what the panels and parts before
@@ -743,25 +763,18 @@ def _make_wide_scatter_kernel(block: int, passes: int):
         def _():
             y_ref[...] = acc_ref[...]
 
+        def add(at, height):
+            if at is None:
+                y_ref[0] += _wide_sums(off_ref[0], val_ref[0], g_ref,
+                                       height, passes)
+            else:
+                y_ref[0, pl.ds(at, height), :] += _wide_sums(
+                    off_ref[0] - at, val_ref[0], g_ref, height, passes)
+
         # the last panel is moved back to end with the tables: the
         # chunks it shares with the one before are not added twice
         c = pl.program_id(0)
-        live = c >= skip_ref[0]
-        win = win_ref[c]
-
-        # the chunk's rows lie in a window of the tile: its one-hot is
-        # that tall (a block shorter than a window has none)
-        if block >= window:
-            @pl.when(jnp.logical_and(live, win >= 0))
-            def _():
-                at = pl.multiple_of(win, 8)
-                y_ref[0, pl.ds(at, window), :] += _wide_sums(
-                    off_ref[0] - at, val_ref[0], g_ref, window, passes)
-
-        @pl.when(jnp.logical_and(live, win < 0))
-        def _():
-            y_ref[0] += _wide_sums(off_ref[0], val_ref[0], g_ref, block,
-                                   passes)
+        _by_window(block, c >= skip_ref[0], win_ref[c], add)
 
     return kernel
 
@@ -853,8 +866,6 @@ def _make_sampled_scatter_kernel(block: int, passes: int, op: str,
     """:func:`_make_wide_scatter_kernel` for a sampled product: a slot's
     value is made here. The chunk's block of the destination factor
     rides beside the block sums (``dst_ref``, the same index map)."""
-    window = spmv_lib.WINDOW
-
     def kernel(cb_ref, skip_ref, win_ref, off_ref, val_ref, g_ref, *refs):
         m_ref = None if shared else refs[0]
         dst_ref, acc_ref, y_ref = refs[-3:]
@@ -863,22 +874,17 @@ def _make_sampled_scatter_kernel(block: int, passes: int, op: str,
         def _():
             y_ref[...] = acc_ref[...]
 
-        c = pl.program_id(0)
-        live = c >= skip_ref[0]
-        win = win_ref[c]
-
-        if block >= window:
-            @pl.when(jnp.logical_and(live, win >= 0))
-            def _():
-                at = pl.multiple_of(win, 8)
-                y_ref[0, pl.ds(at, window), :] += _sampled_sums(
+        def add(at, height):
+            if at is None:
+                y_ref[0] += _sampled_sums(off_ref[0], val_ref[0], g_ref,
+                                          m_ref, dst_ref[0], op, passes)
+            else:
+                y_ref[0, pl.ds(at, height), :] += _sampled_sums(
                     off_ref[0] - at, val_ref[0], g_ref, m_ref,
-                    dst_ref[0, pl.ds(at, window), :], op, passes)
+                    dst_ref[0, pl.ds(at, height), :], op, passes)
 
-        @pl.when(jnp.logical_and(live, win < 0))
-        def _():
-            y_ref[0] += _sampled_sums(off_ref[0], val_ref[0], g_ref, m_ref,
-                                      dst_ref[0], op, passes)
+        c = pl.program_id(0)
+        _by_window(block, c >= skip_ref[0], win_ref[c], add)
 
     return kernel
 
@@ -967,10 +973,11 @@ def _as_chunks(src, off, val, chunk_block):
 def wide_windows(plan: spmv_lib.EdgeSpMVPlan):
     """(``win`` of every chunk table the k-wide kernel walks of this
     plan — its own as :func:`_as_chunks` reads them, then its hub
-    chunks' — as device arrays; how many chunks have a window), reckoned
-    once from the host tables (``spmv.chunk_windows``) and memoised on
-    the plan. A padded slot is known by its sentinel source, which reads
-    the zero row."""
+    chunks' — as device arrays; how many chunks have a window of each
+    height of ``spmv.WINDOWS``, {height: chunks}), reckoned once from
+    the host tables (``spmv.chunk_windows``) and memoised on the plan. A
+    padded slot is known by its sentinel source, which reads the zero
+    row."""
     memo = getattr(plan, "_wide_win", None)
     if memo is None:
         off = np.asarray(plan.off)
@@ -987,8 +994,9 @@ def wide_windows(plan: spmv_lib.EdgeSpMVPlan):
         # committed arrays, not tracers (see compact_tables)
         with jax.ensure_compile_time_eval():
             dev = tuple(jnp.asarray(w) for w in wins)
-        memo = plan._wide_win = (
-            dev, int(sum(np.count_nonzero(w >= 0) for w in wins)))
+        tall = np.concatenate([spmv_lib.window_of(w)[1] for w in wins])
+        memo = plan._wide_win = (dev, {
+            h: int(np.count_nonzero(tall == h)) for h in spmv_lib.WINDOWS})
     return memo
 
 

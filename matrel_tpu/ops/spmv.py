@@ -47,8 +47,9 @@ reference's plan-per-query model. A plan has one of two layouts:
   device: the matvec and, since PR 37, the k-wide product) take it; the
   expanded tables and ``shard_plan`` say so by name. Without hub chunks
   a block's slots lie by destination row (PR 38), so a chunk names few
-  rows and the k-wide scatter's one-hot is a ``WINDOW`` of them tall
-  (``chunk_windows``). Where the sources are skewed too (PR 36), the
+  rows and the k-wide scatter's one-hot is as tall as the shortest rung
+  of ``WINDOWS`` that holds them (``chunk_windows``; PR 49: a ladder).
+  Where the sources are skewed too (PR 36), the
   edges whose source is among the ``128·M`` of largest out-degree, the *hubs*, lie in
   a second set of chunks (``HubChunks``): a slot there names its source
   by rank, and the matvec takes ``x`` for it from a ``(M, 128)`` table in
@@ -85,7 +86,9 @@ HI = 32          # off = hi*LO + lo one-hot factor sizes; HI*LO == BLOCK
 LO = 16
 CHUNK = 2048     # slots a chunk of the ``chunks`` layout: one grid step of
                  # the Pallas scatter, a (16, 128) tile
-WINDOW = 128     # rows of its block a chunk's window spans (chunk_windows)
+WINDOWS = (128, 256)    # the ladder of heights, in rows of its block, a
+                        # chunk's window takes (chunk_windows): at most 8
+                        # rungs, rising, each a multiple of 8
 # ``layout="auto"`` weighs the two layouts by the slots a matvec walks.
 # An overflow edge rides XLA's scalar gather and segment_sum (~13 ns,
 # module docstring) where a slot costs ~2 ns (PERF.md §5): 8 slots. A
@@ -601,25 +604,43 @@ def _first_slots(owned: np.ndarray) -> np.ndarray:
 def chunk_windows(off: np.ndarray, real: np.ndarray,
                   block: int) -> np.ndarray:
     """``win`` (chunks,) int32 of a chunk table ``off`` (chunks, slots):
-    the row of its block, a multiple of 8, from which ``WINDOW`` rows
-    hold every real slot of the chunk (``real``: not padding) — the
-    k-wide scatter (ops/pallas_spmv.py) then builds that chunk's one-hot
-    ``WINDOW`` rows tall and not ``block`` — or −1 where the chunk's
-    rows spread further, or the block is shorter than a window. It is
-    the chunk's least row rounded down to 8, moved back where it would
-    reach past the block's end. A padded slot's ``off`` (0) takes no
-    part: outside the window it matches no row of the one-hot, and it
-    adds 0 wherever it lands; a chunk that is all padding takes the
-    block's last window. In row order (the chunks fill without hubs,
-    the numpy fills) the chunks of a block overlap in one row at most,
-    so at most ``block / (WINDOW − 8)`` of them read −1 whatever the
-    data."""
-    if block < WINDOW:
-        return np.full(off.shape[0], -1, np.int32)
+    for every chunk the SHORTEST rung of the ladder ``WINDOWS`` whose
+    rows, from a start inside the chunk's block, hold every real slot of
+    the chunk (``real``: not padding) — the k-wide scatter
+    (ops/pallas_spmv.py) then builds that chunk's one-hot that many rows
+    tall and not ``block`` — as ``start + rung``: the start is a
+    multiple of 8, so the rung's index in ``WINDOWS`` rides in the three
+    low bits (:func:`window_of` reads them apart). −1 where no rung
+    holds the chunk's rows: the whole block. A rung not below ``block``
+    drops out, so a block no taller than the first has no windows. The
+    start is the chunk's least row rounded down to 8, moved back where
+    the rung would reach past the block's end. A padded slot's ``off``
+    (0) takes no part: outside the window it matches no row of the
+    one-hot, and it adds 0 wherever it lands; a chunk that is all
+    padding takes the block's last window of the first rung. In row
+    order (the chunks fill without hubs, the numpy fills) the chunks of
+    a block overlap in one row at most, so at most ``block / (h − 8)``
+    of them spread past a rung of ``h`` rows whatever the data; tables
+    in input order read −1 throughout."""
     low = np.where(real, off, block).min(axis=1)
     high = np.where(real, off, -1).max(axis=1)
-    win = np.minimum(low // 8 * 8, block - WINDOW)
-    return np.where(high - win < WINDOW, win, -1).astype(np.int32)
+    win = np.full(off.shape[0], -1, np.int32)
+    # the tallest first: a shorter rung that holds the chunk overrides
+    for rung, height in reversed(list(enumerate(WINDOWS))):
+        if height < block:
+            at = np.minimum(low // 8 * 8, block - height)
+            win = np.where(high - at < height, at + rung, win)
+    return win.astype(np.int32)
+
+
+def window_of(win):
+    """(start, height) of every chunk of a table :func:`chunk_windows`
+    made: (−1, 0) where the chunk takes the whole block."""
+    win = np.asarray(win)
+    has = win >= 0
+    rung = np.where(has, win & 7, 0)
+    return (np.where(has, win & ~7, -1),
+            np.where(has, np.asarray(WINDOWS)[rung], 0))
 
 
 def _hub_rows(deg_desc: np.ndarray, edges: int, blocks: int) -> int:

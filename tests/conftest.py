@@ -54,6 +54,30 @@ def rng():
     return np.random.default_rng(42)
 
 
+@pytest.fixture()
+def ladder_cells(rng):
+    """(rows, cols): the distinct cells of a 2,048 x 2,048 matrix whose
+    compact plans in chunks of 2,048 slots over blocks of 512 lines
+    hold, in BOTH orientations, chunks of every height the k-wide
+    scatter's ladder has (``ops/spmv.py`` ``chunk_windows``): block 1's
+    lines hold 7 entries each (300 lines: a chunk in line order spans
+    293 of them, the whole block), then 11 (180 lines: a chunk spans
+    ~190, the 256-row rung), then 400 (32 lines: five a chunk, the
+    128-row rung), their other coordinates distinct in block 3; rows
+    and columns alike, in a drawn order."""
+    counts = np.r_[np.full(300, 7), np.full(180, 11), np.full(32, 400)]
+    line = 512 + np.repeat(np.arange(512), counts)
+
+    def across():
+        return 1536 + np.concatenate(
+            [rng.choice(512, n, replace=False) for n in counts])
+
+    rows = np.concatenate([line, across()])
+    cols = np.concatenate([across(), line])
+    order = rng.permutation(rows.size)
+    return rows[order], cols[order]
+
+
 @pytest.fixture(scope="session")
 def run_at_root():
     """``run(args, devices=8)``: ``python *args`` from the repository's

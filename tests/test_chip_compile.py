@@ -156,7 +156,10 @@ def test_gnmf_forward_product_runs_in_panels(one_chip, monkeypatch):
     chunks the temporaries are what ``wide_plan_bytes`` reckons and the
     three output-sized buffers of the aliased scatter, a slot's row is
     gathered once for all 128 columns from a table in fast memory, and
-    the multiply by the ratings happens inside the kernel."""
+    the multiply by the ratings happens inside the kernel, which holds a
+    body a height of the one-hot (PR 49: 1,931 bundles a step at a
+    128-row window, 3,089 at a 256-row one, 5,796 at the whole block,
+    read offline; ``win`` one int32 a chunk as before)."""
     monkeypatch.setattr(pc, "_hbm_limit", lambda: int(15.75 * 2 ** 30))
     static = (NF_USERS, NF_MOVIES, BLOCK, spmv_lib.LO)
     compiled = _compile(
@@ -318,10 +321,14 @@ def test_pnmf_sampled_products(one_chip, monkeypatch):
     fast memory), the panels are ``wide_panel_rows``' (2 forward, as
     GNMF's), the slab is read where it lies, and arguments and
     temporaries stay inside what ``sampled_facts`` reckons the plan to
-    hold. Prints what the compile says of the kernel; its bundles a
-    step are read offline (PERF.md section 6, PR 47: 3,415 a windowed
-    and 11,983 a whole-block step where the plain kernel reads 1,928
-    and 5,796)."""
+    hold. ``win`` is one int32 a chunk, start and rung of the ladder in
+    one word (PR 49: the operands are PR 47's). Prints what the compile
+    says of the kernel; its bundles a step are read offline, one body a
+    compile (PERF.md section 6, PR 49: 3,418 at a 128-row window, 5,883
+    at a 256-row one and 11,983 at the whole block, where the plain
+    kernel reads 1,931, 3,089 and 5,796; the three bodies in the one
+    kernel 20,783 and 10,397, each ``pl.when`` region what it reads
+    alone less a prologue of ~210)."""
     monkeypatch.setattr(pc, "_hbm_limit", lambda: int(15.75 * 2 ** 30))
     slab = _sds(one_chip, (NF_USERS, PN_LINES), jnp.bfloat16)
     lines = _sds(one_chip, (PN_LINES,), jnp.int32)

@@ -678,8 +678,8 @@ class TestWidePlans:
         for b in np.unique(plan.chunk_block):
             mine = plan.chunk_block == b
             assert (np.diff(plan.off[mine][real[mine]]) >= 0).all()
-        _, windowed = pc.wide_windows(plan)
-        assert windowed == plan.src8.shape[0]
+        _, tall = pc.wide_windows(plan)
+        assert sum(tall.values()) == plan.src8.shape[0]
         static, part_statics, part_arrays = pc.plan_operands(plan)
         text = jax.jit(lambda pa, x: pc.compact_matmat_parts(
             static, part_statics, pa, x, 3, False)).trace(
@@ -730,8 +730,10 @@ class TestWidePlans:
     def test_plan_facts_count_the_windowed_chunks(self, rng, on_one_chip,
                                                   monkeypatch, which):
         """``windowed_chunks`` (PR 38): of the chunks the k-wide scatter
-        walks, those whose rows lie in a 128-row window — over every
-        source panel, read off the plans' own tables."""
+        walks, those whose rows lie in a window shorter than the block,
+        and ``window_rows`` (PR 49), the same chunks by the rung of the
+        ladder they take — over every source panel, read off the plans'
+        own tables."""
         from matrel_tpu.core import coo as coo_lib
         from matrel_tpu.ops import pallas_spmv as pc
         from matrel_tpu.ops import spmv as spmv_lib
@@ -743,7 +745,7 @@ class TestWidePlans:
         facts = coo_lib.plan_facts(plan, A.nnz)
         parts = [p for _, p in coo_lib.plan_parts(plan)]
         assert len(parts) == (3 if which == "transposed_in_panels" else 1)
-        want = 0
+        want = {128: 0, 256: 0}
         for p in parts:
             src = np.asarray(p.src8).astype(np.int64) * 8 + np.asarray(p.lane)
             off = np.asarray(p.off)
@@ -752,15 +754,61 @@ class TestWidePlans:
                 off, src = off.reshape(-1, width), src.reshape(-1, width)
             for o, real in zip(off, src != p.n_cols):
                 rows = o[real]
-                lo = min(rows.min() // 8 * 8, 512 - 128) if rows.size else 0
-                want += bool(rows.size == 0 or rows.max() - lo < 128)
-        assert facts["windowed_chunks"] == want > 0
+                for tall in want:           # the shortest rung that holds
+                    lo = (min(rows.min() // 8 * 8, 512 - tall)
+                          if rows.size else 0)
+                    if rows.size == 0 or rows.max() - lo < tall:
+                        want[tall] += 1
+                        break
+        assert facts["window_rows"] == {str(h): n for h, n in want.items()}
+        assert facts["windowed_chunks"] == sum(want.values()) > 0
         if which == "blocks_layout":
             assert facts["layout"] == "blocks"
         else:           # the hub block's chunks at the least
             assert facts["layout"] == "chunks"
         # said again from the memo, and by the product's own record
         assert coo_lib.plan_facts(plan, A.nnz) == facts
+
+    @pytest.mark.parametrize("transposed", [False, True],
+                             ids=["forward", "transposed"])
+    def test_a_window_as_tall_as_the_chunk_needs(self, rng, on_one_chip,
+                                                 ladder_cells, transposed):
+        """The ladder (PR 49): over a matrix whose chunks take a 128-row
+        window, a 256-row one and the whole block, ``window_rows`` says
+        how many take which, and the k-wide product is the product with
+        the windows withheld (every chunk the whole block's one-hot),
+        bit for bit: the same slots into the same rows through the same
+        parts, only the one-hot's all-zero rows not multiplied."""
+        import jax
+        import jax.numpy as jnp
+        from matrel_tpu.core import coo as coo_lib
+        from matrel_tpu.ops import pallas_spmv as pc
+        from matrel_tpu.ops import spmv as spmv_lib
+        rows, cols = ladder_cells
+        A = COOMatrix.from_edges(
+            rows, cols, rng.standard_normal(rows.size).astype(np.float32),
+            shape=(2048, 2048))
+        plan = A._get_wide_plan(transposed=transposed)
+        facts = coo_lib.plan_facts(plan, A.nnz)
+        assert facts["layout"] == "chunks" and facts["source_panels"] == 1
+        tall = facts["window_rows"]
+        assert sorted(tall) == ["128", "256"]
+        assert tall["256"] == 1 and tall["128"] > 8
+        assert facts["windowed_chunks"] == sum(tall.values()) \
+            == facts["chunks"] - 1
+        height = spmv_lib.window_of(pc.wide_windows(plan)[0][0])[1]
+        assert height[plan.chunk_block == 1][:3].tolist() == [0, 256, 128]
+        X = rng.standard_normal((2048, 128)).astype(np.float32)
+        got = np.asarray((A.T if transposed else A).matmat(X))
+        static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
+        withheld = np.asarray(jax.jit(
+            lambda t, x: pc.compact_matmat_apply(static, t, (), x, 3, True)
+        )(pc.compact_tables(plan), jnp.asarray(X)))
+        assert plan.overflow == ()
+        np.testing.assert_array_equal(got, withheld)
+        dense = (A.to_dense().T if transposed else A.to_dense())
+        want = dense.astype(np.float64) @ X
+        assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-7
 
     def test_chunked_plans_serve_every_op(self, rng, on_one_chip):
         """matvec, rmatvec and matmat of a matrix whose plans lie in

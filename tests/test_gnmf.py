@@ -127,6 +127,53 @@ def test_three_iterations_match_float64(rng, compact_one_device, rank):
     assert all("pallas_spmv" in p["executors"] for p in said)
 
 
+@pytest.mark.parametrize("orientation", ["transposed", "forward"])
+def test_a_window_as_tall_as_the_chunk_needs(rng, compact_one_device,
+                                             monkeypatch, ladder_cells,
+                                             orientation):
+    """The ladder (PR 49) under both of GNMF's sparse products: over a
+    matrix whose chunks take a 128-row window, a 256-row one and the
+    whole block in both orientations, ``last_plan()["spmm"]`` says how
+    many take which height, and the product is the product with the
+    windows withheld, bit for bit."""
+    import jax.numpy as jnp
+    from matrel_tpu.ops import pallas_spmv as pc
+    # one gather table: in panels of 1,000 sources, two of them empty,
+    # the build lays this matrix out in blocks
+    monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 4096 * 512)
+    rows, cols = ladder_cells
+    n, rank = 2048, 16
+    V = COOMatrix.from_edges(
+        rows, cols, rng.integers(1, 6, rows.size).astype(np.float32),
+        shape=(n, n))
+    s = _session(compact_one_device)
+    x = rng.random((n, rank), dtype=np.float32)
+    flipped = orientation == "transposed"
+    s.register("V", V)
+    s.register("X", BlockMatrix.from_numpy(x.T if flipped else x,
+                                           mesh=s.mesh))
+    got = s.compute(s.sql("X * V" if flipped else "V * X")).to_numpy()
+    (rec,) = s.last_plan()["spmm"]
+    assert rec["orientation"] == orientation and rec["layout"] == "chunks"
+    assert rec["source_panels"] == 1
+    assert sorted(rec["window_rows"]) == ["128", "256"]
+    assert rec["window_rows"]["256"] == 1 and rec["window_rows"]["128"] > 8
+    assert rec["windowed_chunks"] == sum(rec["window_rows"].values()) \
+        == rec["chunks"] - 1
+    plan = V._get_wide_plan(transposed=flipped)
+    static, statics, arrays = pc.plan_operands(plan)
+
+    def product(arrays):
+        return np.asarray(pc.compact_matmat_parts(
+            static, statics, arrays, jnp.asarray(x), 3, True))
+
+    assert all(wins is not None for _, _, wins in arrays)
+    mine = product(arrays)
+    np.testing.assert_array_equal(mine, product(tuple(
+        (tables, ov, None) for tables, ov, _ in arrays)))
+    np.testing.assert_array_equal(got.T if flipped else got, mine)
+
+
 @pytest.mark.parametrize("transposed", [False, True],
                          ids=["forward", "transposed_in_panels"])
 def test_the_ratings_plans_have_no_hub_chunks(rng, compact_one_device,
@@ -317,7 +364,7 @@ def test_the_spans_say_what_the_reader_says(rng, compact_one_device,
         assert set(p["spmm"][0]) == {
             "orientation", "k", "layout", "entries", "slots", "chunks",
             "source_panels", "table", "overflow_edges", "panels",
-            "plan_bytes", "windowed_chunks"}
+            "plan_bytes", "windowed_chunks", "window_rows"}
     lookups = [r for r in mine if r["name"] == "matrel.plan"]
     assert [r["attrs"] for r in lookups] == [
         {"via": "template", "hit": True}] * 2

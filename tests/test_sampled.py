@@ -355,6 +355,61 @@ def test_the_sampled_kernel_matches_float64(rng, name, shared, op):
     assert not got[empty].any()
 
 
+@pytest.mark.parametrize("orientation", ["transposed", "forward"])
+def test_a_window_as_tall_as_the_chunk_needs(rng, one_chip, monkeypatch,
+                                             ladder_cells, orientation):
+    """The ladder (PR 49) under both sampled products: over a matrix
+    whose chunks take a 128-row window, a 256-row one and the whole
+    block in both orientations, the kernel gives what it gives with the
+    windows withheld (every chunk the whole block's one-hot, twice),
+    bit for bit, and ``last_plan()["sampled"]`` says how many chunks
+    take which height."""
+    import jax.numpy as jnp
+    from matrel_tpu.ops import pallas_spmv as pc
+    # the residual alone, in one source panel: the lines of 400 entries
+    # would pay as dense lines, a table of 500 rows would cut them up
+    monkeypatch.setattr(coo_lib, "_DENSE_SHARE", 0.0)
+    monkeypatch.setattr(spmv_lib, "_FAST_TABLE_BYTES", 4096 * 512)
+    rows, cols = ladder_cells
+    n = 2048
+    V = COOMatrix.from_edges(
+        rows, cols, rng.uniform(0.5, 5.0, rows.size).astype(np.float32),
+        shape=(n, n))
+    s = _session(one_chip)
+    w = rng.uniform(0.1, 1.0, (n, RANK)).astype(np.float32)
+    h = rng.uniform(0.1, 1.0, (RANK, n)).astype(np.float32)
+    s.register("V", V)
+    s.register("W", BlockMatrix.from_numpy(w, mesh=s.mesh))
+    s.register("H", BlockMatrix.from_numpy(h, mesh=s.mesh))
+    flipped = orientation == "transposed"
+    got = s.compute(s.sql(SQL_H if flipped else SQL_W)).to_numpy()
+    (rec,) = s.last_plan()["sampled"]
+    assert rec["orientation"] == orientation and rec["dense_entries"] == 0
+    assert rec["source_panels"] == 1 and rec["layout"] == "chunks"
+    assert sorted(rec["window_rows"]) == ["128", "256"]
+    assert rec["window_rows"]["256"] == 1 and rec["window_rows"]["128"] > 8
+    assert rec["windowed_chunks"] == sum(rec["window_rows"].values()) \
+        == rec["chunks"] - 1
+    Vd = V.to_dense().astype(np.float64)
+    Q = np.where(Vd != 0, Vd / (w.astype(np.float64) @ h), 0.0)
+    want = (h * (w.T @ Q) / w.sum(0)[:, None] if flipped
+            else w * (Q @ h.T) / h.sum(1)[None, :])
+    np.testing.assert_allclose(got, want, rtol=5e-6)
+    # the product itself, with its windows and without
+    plan = V._get_wide_plan(transposed=flipped)
+    static, statics, arrays = pc.plan_operands(plan)
+    src, dst = (w, h.T) if flipped else (h.T, w)
+
+    def product(arrays):
+        return np.asarray(pc.sampled_matmat_parts(
+            static, statics, arrays, jnp.asarray(src), "div", None,
+            jnp.asarray(dst), interpret=True))
+
+    assert all(wins is not None for _, _, wins in arrays)
+    np.testing.assert_array_equal(product(arrays), product(tuple(
+        (tables, ov, None) for tables, ov, _ in arrays)))
+
+
 @pytest.mark.parametrize("passes,lo,hi", [(3, 0.0, 2e-6), (2, 2e-6, 3e-4),
                                           (1, 3e-4, 3e-2)])
 def test_passes_change_the_scatter_and_not_the_dot(rng, passes, lo, hi):
@@ -388,14 +443,16 @@ def test_passes_change_the_scatter_and_not_the_dot(rng, passes, lo, hi):
         rows, cols, vals, "mul", ones, tall, twos, plan.n_rows))
 
 
-def test_a_plain_product_lowers_as_it_did_before_the_sampled_kernel(rng):
+def test_a_plain_product_lowers_to_the_recorded_program(rng):
     """The GNMF guard: ``coo_leaf x dense`` through
-    ``compact_matmat_parts`` lowers for the chip to the text the parent
-    of PR 47 lowered it to — the Mosaic kernel's serialized body (which
-    embeds paths and line numbers) read back and printed without debug
-    info — by its SHA-256, recorded on that parent in this container's
-    jax: the sampled kernel is a second ``pallas_call``, and nothing of
-    it is in a plain product's program."""
+    ``compact_matmat_parts`` lowers for the chip to a recorded text —
+    the Mosaic kernel's serialized body (which embeds paths and line
+    numbers) read back and printed without debug info — by its SHA-256,
+    recorded in this container's jax: the sampled kernel is a second
+    ``pallas_call``, and nothing of it is in a plain product's program.
+    Recorded anew by PR 49, which gave the plain kernel its 256-row
+    body on purpose (PR 47's record: 6a25b79a...ae268c5); a PR that
+    means to leave this product alone leaves the hash alone."""
     import base64
     import hashlib
     import re
@@ -433,8 +490,8 @@ def test_a_plain_product_lowers_as_it_did_before_the_sampled_kernel(rng):
                       text)
     assert n == 1
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "6a25b79afc2f9a1c20b05430d1bb3731"
-        "efc5e9552b344238ec2bad7b2ae268c5")
+        "63f5fe2df950e45070a30e40e93db5bc"
+        "d183c43629c6f0ffca80baab6967e394")
 
 
 # -- anywhere else it is the array it was ----------------------------------------------

@@ -1490,28 +1490,49 @@ def test_the_ragged_fills_agree_slot_for_slot_in_row_order(rng, monkeypatch,
 
 
 _WIN_CASES = {
-    # name: (real slots' rows, padded slots, block) -> win
-    "fits": ([200, 201, 250, 327], 0, 512, 200),
-    "one_row": ([77] * 9, 0, 512, 72),
-    "unaligned_least_row": ([13, 14, 130], 0, 512, 8),
-    "spans_128_rows_from_an_aligned_one": ([8, 135], 0, 512, 8),
-    "spans_a_row_more": ([8, 136], 0, 512, -1),
-    "121_rows_from_an_unaligned_one": ([15, 135], 0, 512, 8),
-    "122_rows_from_an_unaligned_one": ([15, 136], 0, 512, -1),
-    "the_whole_block": ([0, 511], 0, 512, -1),
-    "at_the_blocks_end_the_window_moves_back": ([500, 511], 0, 512, 384),
-    "padding_at_the_blocks_end_does_not_void_it": ([480, 511], 5, 512, 384),
+    # name: (real slots' rows, padded slots, block) -> (start, height) of
+    # the chunk's window; (-1, 0): the whole block
+    "fits": ([200, 201, 250, 327], 0, 512, (200, 128)),
+    "one_row": ([77] * 9, 0, 512, (72, 128)),
+    "unaligned_least_row": ([13, 14, 130], 0, 512, (8, 128)),
+    "spans_128_rows_from_an_aligned_one": ([8, 135], 0, 512, (8, 128)),
+    "spans_a_row_more": ([8, 136], 0, 512, (8, 256)),
+    "121_rows_from_an_unaligned_one": ([15, 135], 0, 512, (8, 128)),
+    "122_rows_from_an_unaligned_one": ([15, 136], 0, 512, (8, 256)),
+    "spans_256_rows_from_an_aligned_one": ([8, 263], 0, 512, (8, 256)),
+    "spans_257_rows": ([8, 264], 0, 512, (-1, 0)),
+    "249_rows_from_an_unaligned_one": ([15, 263], 0, 512, (8, 256)),
+    "250_rows_from_an_unaligned_one": ([15, 264], 0, 512, (-1, 0)),
+    "the_whole_block": ([0, 511], 0, 512, (-1, 0)),
+    "at_the_blocks_end_the_window_moves_back": ([500, 511], 0, 512,
+                                                (384, 128)),
+    "at_the_blocks_end_the_taller_window_moves_back": (
+        [300, 511], 0, 512, (256, 256)),
+    "padding_at_the_blocks_end_does_not_void_it": ([480, 511], 5, 512,
+                                                   (384, 128)),
     "padding_does_not_void_a_window_far_from_row_0": ([300, 310], 7, 512,
-                                                      296),
-    "all_padding": ([], 6, 512, 384),
-    "a_block_of_one_window": ([0, 127], 1, 128, 0),
-    "a_block_shorter_than_a_window": ([0, 3], 0, 64, -1),
-    "a_wider_block": ([1000, 1100], 2, 2048, 1000),
+                                                      (296, 128)),
+    "padding_does_not_void_a_taller_window": ([200, 400], 7, 512,
+                                              (200, 256)),
+    "all_padding": ([], 6, 512, (384, 128)),
+    "a_block_of_one_window": ([0, 127], 1, 128, (-1, 0)),
+    "a_block_shorter_than_a_window": ([0, 3], 0, 64, (-1, 0)),
+    "a_block_of_the_taller_window_keeps_the_shorter": (
+        [104, 231], 0, 256, (104, 128)),
+    "a_block_of_the_taller_window_has_none_of_it": (
+        [104, 232], 0, 256, (-1, 0)),
+    "all_padding_in_a_block_of_the_taller_window": ([], 3, 256,
+                                                    (128, 128)),
+    "a_wider_block": ([1000, 1100], 2, 2048, (1000, 128)),
+    "a_wider_block_a_taller_window": ([1000, 1200], 2, 2048, (1000, 256)),
 }
 
 
 @pytest.mark.parametrize("case", sorted(_WIN_CASES))
 def test_a_chunks_window_is_read_off_its_rows(case):
+    """The ladder (PR 49): the shortest rung of ``WINDOWS`` below the
+    block that holds the chunk's real slots, from the chunk's least row
+    rounded down to 8 and moved back off the block's end."""
     rows, padded, block, want = _WIN_CASES[case]
     off = np.array([rows + [0] * padded], np.int32)
     real = np.array([[True] * len(rows) + [False] * padded])
@@ -1521,12 +1542,34 @@ def test_a_chunks_window_is_read_off_its_rows(case):
     real = np.concatenate([np.ones_like(real), real, np.ones_like(real)])
     win = spmv_lib.chunk_windows(off, real, block)
     assert win.dtype == np.int32 and win.shape == (3,)
-    assert win[1] == want
-    if block >= spmv_lib.WINDOW:
-        assert win[0] == 0 and win[2] == block - spmv_lib.WINDOW
-    if want >= 0:
-        assert want % 8 == 0 and want + spmv_lib.WINDOW <= block
-        assert all(want <= r < want + spmv_lib.WINDOW for r in rows)
+    start, height = (a.tolist() for a in spmv_lib.window_of(win))
+    assert (start[1], height[1]) == want
+    assert (win[1] == -1) == (want == (-1, 0))
+    shortest = spmv_lib.WINDOWS[0]
+    if block > shortest:
+        assert (start[0], height[0]) == (0, shortest)
+        assert (start[2], height[2]) == (block - shortest, shortest)
+    else:
+        assert list(win) == [-1] * 3
+    if want[1]:
+        at, tall = want
+        assert tall in spmv_lib.WINDOWS and tall < block
+        assert at % 8 == 0 and at + tall <= block
+        assert all(at <= r < at + tall for r in rows)
+        # and no shorter rung holds them from its own start
+        for less in (h for h in spmv_lib.WINDOWS if h < tall):
+            low = min(min(rows) // 8 * 8, block - less)
+            assert max(rows) - low >= less
+
+
+def test_a_table_in_input_order_has_no_windows(rng):
+    """Chunks whose slots name rows all over their block (the blocks
+    fill, a PageRank plan's two sets) read "whole block" throughout."""
+    off = rng.integers(0, 512, (40, 2048)).astype(np.int32)
+    win = spmv_lib.chunk_windows(off, np.ones(off.shape, bool), 512)
+    assert list(win) == [-1] * 40
+    start, height = spmv_lib.window_of(win)
+    assert not height.any() and (start == -1).all()
 
 
 def _wide_scatter_before_pr38(cb, skip, off, val, g, acc, block, passes=3):
@@ -1590,6 +1633,22 @@ def _one_block_of_both_kinds(rng):
     return rows, cols, rng.standard_normal(rows.size).astype(np.float32)
 
 
+def _one_block_of_three_heights(rng):
+    """Block 1 (rows 512..1023) in row order: a chunk over 293 rows (7
+    entries a row: the whole block), one over ~190 (11 a row: the
+    256-row rung), eight over a few (1,500 a row: the 128-row rung);
+    block 0 a sprinkle, block 2 two chunks over all its rows, block 3
+    nothing."""
+    rows = np.concatenate([
+        512 + np.repeat(np.arange(300), 7),
+        512 + 300 + np.repeat(np.arange(180), 11),
+        512 + 480 + np.repeat(np.arange(11), 1500),
+        rng.integers(0, 512, 700), rng.integers(1024, 1536, 2100)])
+    rng.shuffle(rows)
+    cols = rng.integers(0, 300, rows.size)
+    return rows, cols, rng.standard_normal(rows.size).astype(np.float32)
+
+
 def _wide_case(name, rng, monkeypatch):
     """(plan, rows, cols, vals, windowed chunks wanted: all / none / some)"""
     from matrel_tpu.ops import pallas_spmv as pc
@@ -1613,8 +1672,10 @@ def _wide_case(name, rng, monkeypatch):
                                         layout="blocks")
         assert plan.chunk_block is None and plan.src8.shape == (5, 4224)
         want = "none"
-    elif name == "both_kinds_in_one_block":
-        rows, cols, vals = _one_block_of_both_kinds(rng)
+    elif name in ("both_kinds_in_one_block", "three_heights_in_one_block"):
+        rows, cols, vals = (
+            _one_block_of_both_kinds if name == "both_kinds_in_one_block"
+            else _one_block_of_three_heights)(rng)
         plan = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
                                         layout="chunks", hubs=False)
         want = "some"
@@ -1636,17 +1697,22 @@ def _wide_case(name, rng, monkeypatch):
 
 @pytest.mark.parametrize("panels", [False, True], ids=["whole", "panels"])
 @pytest.mark.parametrize("name", ["sorted_chunks", "unsorted_blocks",
-                                  "both_kinds_in_one_block", "hub_chunks"])
+                                  "both_kinds_in_one_block",
+                                  "three_heights_in_one_block",
+                                  "hub_chunks"])
 def test_k_wide_product_with_a_window_a_chunk(rng, monkeypatch, name,
                                               panels):
     """The k-wide product over chunks that take a 128-row window, chunks
-    that take the block, and both inside one block, whole and in panels
-    whose last is moved back (``skip`` > 0 inside a block): the float64
-    product to 2e-7, and where no chunk has a window the sums PR 37's
-    kernel gave, bit for bit."""
+    that take a 256-row one (PR 49), chunks that take the block, and all
+    of them inside one block, whole and in panels whose last is moved
+    back (``skip`` > 0 inside a block): the float64 product to 2e-7, and
+    where no chunk has a window the sums PR 37's kernel gave, bit for
+    bit."""
     from matrel_tpu.ops import pallas_spmv as pc
     plan, rows, cols, vals, want = _wide_case(name, rng, monkeypatch)
-    wins, windowed = pc.wide_windows(plan)
+    wins, tall = pc.wide_windows(plan)
+    assert sorted(tall) == list(spmv_lib.WINDOWS)
+    windowed = sum(tall.values())
     walked = sum(int(w.shape[0]) for w in wins)
     assert len(wins) == (2 if plan.hubs is not None else 1)
     if want == "all":
@@ -1659,6 +1725,10 @@ def test_k_wide_product_with_a_window_a_chunk(rng, monkeypatch, name,
     if name == "both_kinds_in_one_block":
         win, cb = np.asarray(wins[0]), plan.chunk_block
         assert sorted(set(np.sign(win[cb == 1]))) == [-1, 1]
+    if name == "three_heights_in_one_block":
+        height = spmv_lib.window_of(wins[0])[1][plan.chunk_block == 1]
+        assert height.tolist() == [0, 256] + [128] * 9
+        assert tall[256] == 1
     if panels:
         # a small byte budget: the largest set of chunks in several
         # panels, the last of them moved back over the one before
@@ -1671,7 +1741,7 @@ def test_k_wide_product_with_a_window_a_chunk(rng, monkeypatch, name,
             if n % per:
                 break
         assert 1 < per < n and n % per, (per, n)
-        if name == "both_kinds_in_one_block":
+        if name.endswith("in_one_block"):
             # the moved-back panel starts inside block 1
             assert plan.chunk_block[n - per] == 1 == plan.chunk_block[
                 n - per - 1]
@@ -1719,7 +1789,8 @@ def test_k_wide_chunks_without_a_window_compute_what_pr37_did(rng):
     np.testing.assert_array_equal(now, was)
     win = spmv_lib.chunk_windows(off.reshape(n, -1),
                                  np.ones((n, cr * 128), bool), block)
-    assert list(win) == [-1, -1, 376, -1, -1, 384]
+    assert [a.tolist() for a in spmv_lib.window_of(win)] == [
+        [-1, -1, 376, -1, -1, 384], [0, 0, 128, 0, 0, 128]]
     windowed = np.asarray(run(cb, skip, jnp.asarray(win), jnp.asarray(off),
                               jnp.asarray(val), jnp.asarray(g),
                               jnp.asarray(acc)))
