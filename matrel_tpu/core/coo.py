@@ -381,6 +381,77 @@ def sampled_facts(plan, entries: int, shared: bool) -> dict:
     return facts
 
 
+def semiring_facts(m: "COOMatrix", plan, reduce: str) -> dict:
+    """What plan.meta["semiring"] and a ``matrel.semiring.plan`` span
+    say of how a (max | min, ×) product of ``m`` (one entry a cell:
+    :meth:`COOMatrix.entry_view`) and a column is answered: ``reduce``
+    and ``merge``; ``how`` — ``kernel`` (``plan``: its forward plan, in
+    chunks whose blocks' slots lie in row order, through
+    ``matrel_spmv_reduce_chunks``: the kernel's engagement counter) or
+    ``xla`` (``plan`` None: ``segment_max`` / ``segment_min`` over the
+    entries sorted by row — the CPU, a mesh, a refused plan, the blocks
+    layout of a small one); ``entries``; of a plan its ``layout``,
+    ``slots``, ``chunks``, ``panels`` (of table rows, the matvec's own)
+    and ``overflow_edges``; ``full_rows`` (rows that hold every column,
+    answered on their own off a dense copy of theirs: the only rows
+    whose extremum the 0 of a missing cell has no part in); and
+    ``hbm_plan_bytes``: the tables, the slots' weights and the largest
+    panel's temporaries (pallas_spmv.plan_bytes), or the sorted entries
+    and one gather over them, and the full rows' copy."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    full = m.full_rows()
+    facts = {"reduce": reduce, "merge": "mul",
+             "how": "xla" if plan is None else "kernel",
+             "entries": m.nnz,
+             "full_rows": 0 if full is None else int(full[0].shape[0])}
+    own = 0 if full is None else int(full[1].nbytes)
+    if plan is None:
+        # out ids, in ids, values, the products; a gathered byte row
+        facts["hbm_plan_bytes"] = own + (16 + pc._TEMP_BYTES_A_SLOT) * m.nnz
+        return facts
+    rows, cap = np.asarray(plan.src8).shape
+    facts.update(
+        layout="chunks", slots=int(rows * cap), chunks=int(rows),
+        panels=int(-(-rows // pc.panel_rows(rows, cap))),
+        overflow_edges=0,
+        hbm_plan_bytes=own + int(pc.plan_bytes(rows, cap)))
+    return facts
+
+
+def semiring_apply(m: "COOMatrix", plan, x, reduce: str,
+                   interpret: bool = False):
+    """Traceable: ``y[i] = (max | min)_j m[i, j] · x[j]`` over ALL of
+    ``m``'s columns (a missing cell is the 0 it is in the dense matrix),
+    (n_rows,) float32, for ``m`` with one entry a cell and ``x``
+    (n_cols,). The entries' extrema with 0 in the running of every row
+    — from the compact tables (pallas_spmv.reduce_apply) where ``plan``
+    is one that kernel reads, else XLA's segment reduction over the
+    entries sorted by row (an empty segment's ∓inf meets the 0 there) —
+    and then the rows that hold every column, whose extremum has no 0
+    in it, from their own dense copy. Products are single float32
+    multiplies and nothing is added, so this is what the dense lowering
+    gives, bit for bit, for finite values."""
+    from matrel_tpu.ops import pallas_spmv as pc
+    x = x.astype(jnp.float32)
+    of_two, of_segments, of_axis = {
+        "max": (jnp.maximum, jax.ops.segment_max, jnp.max),
+        "min": (jnp.minimum, jax.ops.segment_min, jnp.min)}[reduce]
+    if plan is not None:
+        static = (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO)
+        y = pc.reduce_apply(static, pc.compact_tables(plan), x, reduce,
+                            interpret=interpret)
+    else:
+        out_s, in_s, val_s = m.sorted_entries()
+        y = of_two(of_segments(
+            val_s * spmv_lib.gather_1d(x, in_s), out_s,
+            num_segments=m.shape[0], indices_are_sorted=True), 0.0)
+    full = m.full_rows()
+    if full is not None:
+        ids, rows = full
+        y = y.at[ids].set(of_axis(rows * x[None, :], axis=1))
+    return y
+
+
 @dataclasses.dataclass
 class COOMatrix:
     """Immutable element-sparse matrix over a fixed coordinate list."""
@@ -417,6 +488,10 @@ class COOMatrix:
     # True when coordinates are known-unique (outputs of coalesce/
     # select_value/join): lets chained relational ops skip the re-sort
     _coalesced: bool = dataclasses.field(default=False, repr=False)
+    # what a (max | min, ×) product reads, each found once: this matrix
+    # with one entry a cell (entry_view) and its full rows (full_rows)
+    _entry_memo: list = dataclasses.field(default_factory=list, repr=False)
+    _full: list = dataclasses.field(default_factory=list, repr=False)
 
     # ---------------------------------------------------------- build
     @classmethod
@@ -633,9 +708,8 @@ class COOMatrix:
                 from matrel_tpu.ops import pallas_spmv as pc
                 return pc.spmv_compact(plan, x)
             return spmv_lib.spmv(plan, x)
-        if self._seg_fwd is None:
-            self._seg_fwd = self._seg_arrays(self.rows, self.cols)
-        return self._segment_matvec(self._seg_fwd, x, self.shape[0])
+        return self._segment_matvec(self.sorted_entries(), x,
+                                    self.shape[0])
 
     def rmatvec(self, y) -> jax.Array:
         """x = Aᵀ·y, shape (n_cols,) — uses the lazily-built transpose
@@ -689,6 +763,54 @@ class COOMatrix:
         w = val_s * spmv_lib.gather_1d(x, in_s)
         return jax.ops.segment_sum(w, out_s, num_segments=n_out,
                                    indices_are_sorted=True)
+
+    def entry_view(self) -> "COOMatrix":
+        """This matrix with ONE entry a cell: itself where no coordinate
+        repeats (found by one sort of the keys, once; from then on it
+        knows, ``_coalesced``), else :meth:`coalesce`'s sum of the
+        repeats, kept. Sums never ask (a repeated cell adds up in the
+        plan as in the dense matrix); an extremum over a row's products
+        has to (executor._semiring_product)."""
+        if not self._coalesced and not self._entry_memo:
+            keys = self.rows * self.shape[1] + self.cols
+            keys.sort()
+            if bool((keys[1:] == keys[:-1]).any()):
+                self._entry_memo.append(self.coalesce())
+            else:
+                self._coalesced = True
+        return self if self._coalesced else self._entry_memo[0]
+
+    def full_rows(self) -> Optional[tuple]:
+        """(ids (f,) int32, rows (f, n_cols) float32) on the device: the
+        rows of a matrix with one entry a cell (:meth:`entry_view`) that
+        hold EVERY column, as dense rows — f · n_cols of the matrix's
+        own entries — or None where there is none (a graph's adjacency
+        matrix, any matrix with fewer entries than columns)."""
+        if not self._full:
+            n, m = self.shape
+            found = None
+            if m and self.nnz >= m:
+                ids = np.flatnonzero(np.bincount(self.rows, minlength=n)
+                                     == m)
+                if ids.size:
+                    at = np.flatnonzero(np.isin(self.rows, ids))
+                    dense = np.zeros((ids.size, m), np.float32)
+                    dense[np.searchsorted(ids, self.rows[at]),
+                          self.cols[at]] = self.vals[at]
+                    with jax.ensure_compile_time_eval():
+                        found = (jnp.asarray(ids, jnp.int32),
+                                 jnp.asarray(dense))
+            self._full.append(found)
+        return self._full[0]
+
+    def sorted_entries(self) -> tuple:
+        """(row ids, column ids, values) on the device, sorted by row:
+        the segment paths' tables, made once (committed arrays even when
+        first asked for inside an executor trace)."""
+        if self._seg_fwd is None:
+            with jax.ensure_compile_time_eval():
+                self._seg_fwd = self._seg_arrays(self.rows, self.cols)
+        return self._seg_fwd
 
     def to_dense(self) -> np.ndarray:
         """Host densification (small matrices / tests)."""

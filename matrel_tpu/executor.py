@@ -119,6 +119,13 @@ class Lowerer:
         # answered fused (plan.meta["sampled"]: core.coo.sampled_facts,
         # a ``matrel.sampled.plan`` span at every dispatch)
         self.sampled: List[dict] = []
+        # and how each (max | min, ×) semiring product was answered
+        # (plan.meta["semiring"]: core.coo.semiring_facts, a
+        # ``matrel.semiring.plan`` span at every dispatch); and the
+        # row/col joins with merge "mul" that densified an
+        # element-sparse operand instead (plan.meta["densified_joins"])
+        self.semiring: List[dict] = []
+        self.densified_joins = 0
         # a long Gram and the product that rides its loop
         # (planner.gram_riders: {uid: (gram, rider)} under both uids),
         # and, while a trace runs, the riders' products by uid
@@ -267,6 +274,8 @@ class Lowerer:
             return self._elemwise_op(
                 node.attrs["op"], ev(s), strategies.run_matmul(
                     "xla", ev(a), ev(b), self.mesh, self.config))
+        if k == "semiring":
+            return self._semiring_product(node, ev)
         if k == "transpose":
             return ev(node.children[0]).T
         if k == "matmul":
@@ -446,6 +455,11 @@ class Lowerer:
                 f"join_pair_cap_entries = {cap}); select/aggregate the "
                 f"operands first or raise the cap in MatrelConfig.")
         l, r = node.children
+        if (node.attrs.get("merge_kind") == "mul"
+                and "coo_leaf" in (l.kind, r.kind)):
+            # a semiring product in all but its shape (two rows joined,
+            # another aggregate above): the leaf is densified below
+            self.densified_joins += 1
         a = ev(l)[: l.shape[0], : l.shape[1]]
         b = ev(r)[: r.shape[0], : r.shape[1]]
         rep = node.attrs.get("replicate")
@@ -860,6 +874,36 @@ class Lowerer:
                 None if shared else of_src(), of_dst(),
                 interpret=pallas_interpret_mode(self.config))
         return self._pad_to_node(out.T if flipped else out, node)
+
+    def _semiring_product(self, node: MatExpr, ev) -> Array:
+        """``semiring(max | min, S, x)``: each row's extremum of
+        ``S[i, j] · x[j]`` over ALL columns — what ``_agg`` over the
+        dense ``join_cols`` gives, missing cells' zeros and all — from
+        S's entries alone (core.coo.semiring_apply): through the
+        matrix's forward plan and the chunk grid's reduction kernel
+        where :func:`_semiring_dispatch` finds one it reads, else XLA's
+        segment reduction over the sorted entries. Neither the (n × m)
+        join nor a dense S exists."""
+        from matrel_tpu.config import pallas_interpret_mode
+        from matrel_tpu.core.coo import semiring_apply, semiring_facts
+        x = node.children[1]
+        m, plan = _semiring_dispatch(node, self.mesh, self.config)
+        facts = semiring_facts(m, plan, node.attrs["reduce"])
+        if facts not in self.semiring:      # a retrace says it again
+            self.semiring.append(facts)
+        self._ran("xla" if plan is None else "pallas_spmv")
+        col = ev(x)[: m.shape[1], 0]
+        if self.mesh.size > 1:
+            # one replicated column: the gather and the segment
+            # reduction then need no partitioner (see _coo_spmv_stack)
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            col = jax.lax.with_sharding_constraint(
+                col, NamedSharding(self.mesh, P()))
+        # here the plan's tables move to the device, once a process
+        with trace_lib.span("semiring.plan", hit=False, **facts):
+            y = semiring_apply(m, plan, col, node.attrs["reduce"],
+                               interpret=pallas_interpret_mode(self.config))
+        return self._pad_to_node(y[:, None], node)
 
     def _long_contraction(self, node: MatExpr, ev) -> Optional[Array]:
         """A product over a LONG float32 contraction (the regression's
@@ -1524,6 +1568,10 @@ def _coo_meta(meta: Dict, low: "Lowerer") -> None:
         meta["spmm"] = low.spmm
     if low.sampled:
         meta["sampled"] = low.sampled
+    if low.semiring:
+        meta["semiring"] = low.semiring
+    if low.densified_joins:
+        meta["densified_joins"] = low.densified_joins
     if low.densified:
         meta["densified_products"] = low.densified
 
@@ -1799,6 +1847,28 @@ def _sampled_dispatch_plan(node: MatExpr, mesh: Mesh,
         return None
     return smp.children[0].attrs["matrix"]._get_wide_plan(
         transposed=flipped)
+
+
+def _semiring_dispatch(node: MatExpr, mesh: Mesh,
+                       config: Optional[MatrelConfig] = None):
+    """(matrix, plan) a ``semiring`` node is answered from: the leaf's
+    matrix with one entry a cell (COOMatrix.entry_view: an extremum,
+    unlike a sum, has to know a repeated cell) and its forward SpMV plan
+    where the chunk grid's reduction kernel reads it — one device, the
+    compact-table Pallas executor on (config.pallas_enabled), a plan in
+    chunks, without hub chunks, its blocks' slots in row order
+    (spmv.rows_in_order) — else None, and XLA's segment reduction over
+    the sorted entries answers. SINGLE source of truth, shared by the
+    lowering and the planner's memory reckoning
+    (planner.semiring_product)."""
+    from matrel_tpu.config import pallas_enabled
+    from matrel_tpu.ops import spmv as spmv_lib
+    m = node.children[0].attrs["matrix"].entry_view()
+    if mesh.size == 1 and pallas_enabled(config):
+        plan = m._get_plan()
+        if plan is not None and spmv_lib.rows_in_order(plan):
+            return m, plan
+    return m, None
 
 
 def _same_table(z: MatExpr, z_t: bool, f: MatExpr, f_t: bool) -> bool:

@@ -15,6 +15,9 @@ Node set mirrors the reference's logical operators:
   SelectValue/SelectIndex (relational σ), JoinOnIndex/JoinOnValue (⋈),
   Sampled (an element-sparse leaf ∘ or ./ a dense product, defined only
   at the leaf's entries — SystemML's wdivmm / wsloss family).
+  Semiring (an element-sparse leaf times one column under (max, ×) or
+  (min, ×): γ_max/min by row over a column join, MatRel's "aggregate
+  over a join" whose (sum, mul) case is the matrix product).
 
 All shape/sparsity metadata lives on the nodes so the optimizer runs as pure
 Python before any tracing.
@@ -329,6 +332,32 @@ def sampled(op: str, s: MatExpr, a: MatExpr, b: MatExpr) -> MatExpr:
     return MatExpr("sampled", (s, a, b), s.shape, s.nnz, {"op": op})
 
 
+SEMIRING_REDUCES = ("max", "min")
+
+
+def semiring(reduce: str, s: MatExpr, x: MatExpr) -> MatExpr:
+    """The (``reduce``, ×) product of an element-sparse leaf ``S``
+    (n × m) and ONE column ``x`` (m × 1): ``y[i] = reduce_j S[i, j] ·
+    x[j]`` over ALL m columns, a missing cell of ``S`` counting as the 0
+    it is in the dense matrix (so a row that misses a cell has a 0 in
+    the running for its extremum, a row with no entry gives 0, and only
+    a row with all m entries gives its products' own extremum): exactly
+    ``agg(reduce, row)(join_cols(S, t(x), mul))``, which
+    ``rules.semiring_product`` rewrites to this node and the DSL never
+    writes. Its two children are the leaf and the column, so no pass
+    ever prices the (n × m) join; the executor answers it from the
+    leaf's entries alone (executor._semiring_product)."""
+    if reduce not in SEMIRING_REDUCES:
+        raise ValueError(f"unknown semiring reduction {reduce}")
+    if s.kind != "coo_leaf":
+        raise ValueError("semiring: the matrix operand must be a coo_leaf")
+    if x.shape != (s.shape[1], 1):
+        raise ValueError(f"semiring shape mismatch: {s.shape} against a "
+                         f"column {x.shape}")
+    return MatExpr("semiring", (s, x), (s.shape[0], 1), None,
+                   {"reduce": reduce, "merge": "mul"})
+
+
 def scalar_op(op: str, a: MatExpr, s: float) -> MatExpr:
     if op not in SCALAR_OPS:
         raise ValueError(f"unknown scalar op {op}")
@@ -530,6 +559,8 @@ def pretty(e: MatExpr, indent: int = 0, mesh=None,
         extra = f" op={e.attrs['op']}"
     elif e.kind == "scalar":
         extra = f" op={e.attrs['op']} v={e.attrs['value']}"
+    elif e.kind == "semiring":
+        extra = f" ({e.attrs['reduce']}, {e.attrs['merge']})"
     elif e.kind == "agg":
         extra = f" {e.attrs['agg']}/{e.attrs['axis']}"
     elif e.kind == "matmul" and "strategy" in e.attrs:

@@ -31,6 +31,12 @@ Rules, mirroring the reference's Catalyst batch:
      dimension → sampled(op, S, A, B): the product is wanted only at
      S's entries (SystemML's wdivmm pattern; the KL-divergence NMF
      updates).
+  R10 semiring product: rowmax / rowmin over a column join with merge
+     "mul" of an element-sparse leaf S and one row t(x) →
+     semiring(max | min, S, x): γ by row over ⋈ on the column index, the
+     (max, ×) / (min, ×) sibling of the matrix product; the (n × m)
+     join is never priced or built (a round of label propagation:
+     LDBC Graphalytics' WCC).
 
 Each rule is a bottom-up tree transform; the batch runs to fixpoint with a
 bound, Catalyst-style.
@@ -43,8 +49,8 @@ from typing import Callable, List, Optional
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.ir import chain as chain_lib
 from matrel_tpu.ir.expr import (
-    COO_NARROW_MAX, MatExpr, agg, elemwise, matmul, sampled, scalar_op,
-    select_index, transpose,
+    COO_NARROW_MAX, SEMIRING_REDUCES, MatExpr, agg, elemwise, matmul,
+    sampled, scalar_op, select_index, semiring, transpose,
 )
 
 Rule = Callable[[MatExpr], Optional[MatExpr]]
@@ -257,6 +263,36 @@ def sampled_product(e: MatExpr) -> Optional[MatExpr]:
     return None
 
 
+# -- R10: semiring product ---------------------------------------------------
+
+
+def semiring_product(e: MatExpr) -> Optional[MatExpr]:
+    """agg(max | min, row)(join_cols(S, b, "mul")) → semiring(max | min,
+    S, t(b)) for a ``coo_leaf`` S (n × m) and ONE row b (1 × m), in
+    either order of the join's operands (the merge commutes): the join
+    pairs every cell S[i, j] with b[j] and the aggregate takes each
+    row's extremum, so the (n × m) joined matrix is wanted a row at a
+    time only. It fires by what it sees — a dense leaf, a merge that is
+    not the structured "mul", a ``b`` of two rows (the join is then
+    (2n × m)) or an aggregate along another axis lower as before, the
+    join materialised under ``join_pair_cap_entries``. The mirror
+    (colmax over a row join) is not matched: a transposed leaf would be
+    a new matrix, and a new plan, every time the rule ran."""
+    if (e.kind != "agg" or e.attrs["agg"] not in SEMIRING_REDUCES
+            or e.attrs["axis"] != "row"):
+        return None
+    (j,) = e.children
+    if j.kind != "join_cols" or j.attrs.get("merge_kind") != "mul":
+        return None
+    s, b = j.children
+    if s.kind != "coo_leaf":
+        s, b = b, s
+    if s.kind != "coo_leaf" or b.shape[0] != 1 or b.kind == "coo_leaf":
+        return None
+    x = b.children[0] if b.kind == "transpose" else transpose(b)
+    return semiring(e.attrs["agg"], s, x)
+
+
 # -- R7: solve fusion --------------------------------------------------------
 
 
@@ -291,6 +327,7 @@ _RULES: List[Rule] = [
     solve_fusion,
     rank1_pushdown,
     sampled_product,
+    semiring_product,
 ]
 # ahead of the chain DP an inverse stays a factor of its chain: fused
 # with its left-associated neighbour first, (XᵀX)⁻¹·Xᵀ·y would reach

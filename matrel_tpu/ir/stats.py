@@ -11,7 +11,9 @@ Estimation model (standard independence assumptions, as in MatFast/MatRel):
   density(A+B)   ≈ min(1, dA + dB)
   density(A⊙B)  ≈ dA * dB
   transpose/scalar-mul preserve density; scalar-add densifies; a
-  sampled node (S op (A·B), ir/expr.py) has its leaf S's structure.
+  sampled node (S op (A·B), ir/expr.py) has its leaf S's structure; a
+  semiring node ((max | min, ×) of a leaf and a column) is one dense
+  column, its (n × m) join never a node.
 """
 
 from __future__ import annotations
@@ -173,6 +175,12 @@ def integral_abs_bound(node, memo: dict = None):
             if None in (bs, ba, bb):
                 return None
             return bs * float(n.children[1].shape[1]) * ba * bb
+        if k == "semiring":
+            # an extremum of S[i, j] · x[j] (or the 0 of a missing cell)
+            bs, bx = (walk(c) for c in n.children)
+            if None in (bs, bx):
+                return None
+            return bs * bx
         if k == "join_index":
             mk = n.attrs.get("merge_kind")
             vals = [walk(c) for c in n.children]
@@ -256,6 +264,8 @@ def infer_integral(node, memo: dict = None) -> bool:
         if k == "sampled":
             return (n.attrs.get("op") == "mul"
                     and all(walk(c) for c in n.children))
+        if k == "semiring":
+            return all(walk(c) for c in n.children)
         if k in ("join_index", "join_rows", "join_cols", "join_value"):
             # structured merges are closed over integers; callables are
             # black boxes

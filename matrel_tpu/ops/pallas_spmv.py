@@ -252,6 +252,73 @@ def _make_hub_scatter_kernel(hi_n: int, lo: int, passes: int, regs: int,
     return kernel
 
 
+REDUCES = {"max": jnp.maximum, "min": jnp.minimum}
+
+
+def _segment_ends(off, w, reduce: str):
+    """A tile of slots whose equal ``off`` lie side by side in every row
+    of 128 (a block's slots in row order, ``spmv.rows_in_order``): the
+    (max | min) of each such run of ``w`` at the run's last slot, 0 at
+    every other. A segmented scan along the lanes in seven doubling
+    steps: a slot takes its neighbour ``d`` to the left into its
+    extremum where both name one row (then so does every slot between
+    them), and a run ends where the next slot names another row or the
+    row of 128 does. Lane rotations and selects alone: no value is ever
+    rounded."""
+    op = REDUCES[reduce]
+    lane = jax.lax.broadcasted_iota(jnp.int32, off.shape, 1)
+    d = 1
+    while d < LANE:
+        same = (pltpu.roll(off, d, axis=1) == off) & (lane >= d)
+        w = jnp.where(same, op(w, pltpu.roll(w, d, axis=1)), w)
+        d *= 2
+    ends = (pltpu.roll(off, LANE - 1, axis=1) != off) | (lane == LANE - 1)
+    return jnp.where(ends, w, 0.0)
+
+
+def _reduce_tile(off, w, hi_n: int, lo: int, reduce: str):
+    """(HI', LO) extrema of one tile of slots, 0 in the running of every
+    row: each row of 128 slots places its runs' extrema
+    (:func:`_segment_ends`) by the one-hot product of
+    :func:`_scatter_tile` — a row of the tile gets ONE run's value and
+    zeros from a row of 128, so its three bfloat16 parts add up to the
+    float32 it was, bit for bit — and the tile rows of 128 then combine
+    by (max | min) where the sum stood. A tile row no slot names reads
+    0, and so does a padded slot (``off`` 0, ``w`` 0): the 0 of the
+    matrix's missing cells, which the caller wants in the running of
+    every row but a full one (core.coo.reduce_rows)."""
+    cr = off.shape[0]
+    ends = _segment_ends(off, w, reduce)
+    ids_hi = jax.lax.broadcasted_iota(jnp.int32, (cr, hi_n, LANE), 1)
+    oh_hi = ((off // lo)[:, None, :] == ids_hi).astype(jnp.bfloat16)
+    ids_lo = jax.lax.broadcasted_iota(jnp.int32, (cr, lo, LANE), 1)
+    mask = (off % lo)[:, None, :] == ids_lo
+    th = None
+    for part in _bf16_split(ends, 3):
+        rhs = jnp.where(mask, part[:, None, :], 0.0).astype(jnp.bfloat16)
+        t = jax.lax.dot_general(
+            oh_hi, rhs, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)          # (cr, hi_n, lo)
+        th = t if th is None else th + t
+    out = th[0]
+    for r in range(1, cr):
+        out = REDUCES[reduce](out, th[r])
+    return out
+
+
+def _make_chunk_reduce_kernel(hi_n: int, lo: int, reduce: str):
+    def kernel(cb_ref, off_ref, w_ref, y_ref):
+        # the block's tile starts from the 0 of a missing cell
+        @pl.when(_first_chunk_of_its_block(cb_ref))
+        def _():
+            y_ref[...] = jnp.zeros_like(y_ref)
+
+        y_ref[0] = REDUCES[reduce](
+            y_ref[0], _reduce_tile(off_ref[0], w_ref[0], hi_n, lo, reduce))
+
+    return kernel
+
+
 @functools.lru_cache(maxsize=32)
 def _compact_runner(nb: int, cap: int, block: int, lo: int, passes: int,
                     interpret: bool):
@@ -285,6 +352,35 @@ def _chunk_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
     return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
         _make_chunk_scatter_kernel(hi_n, lo, passes),
         name="matrel_spmv_scatter_chunks",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                       # chunk_block
+            grid=(n_chunks,),
+            in_specs=[
+                pl.BlockSpec((1, cr, LANE), lambda c, cb: (c, 0, 0)),
+                pl.BlockSpec((1, cr, LANE), lambda c, cb: (c, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, hi_n, lo),
+                                   lambda c, cb: (cb[c], 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((nb, hi_n, lo), jnp.float32),
+        compiler_params=compat.tpu_compiler_params(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )
+
+
+@functools.lru_cache(maxsize=32)
+def _reduce_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
+                   reduce: str, interpret: bool):
+    """reduce(chunk_block, off, w) -> (nb, HI', LO): the chunk grid of
+    :func:`_chunk_runner` with another body — a block's rows take the
+    (max | min) of their slots' weights and of 0 where that one adds
+    them up. float32 throughout: no ``passes``."""
+    hi_n = block // lo
+    cr = chunk // LANE
+    return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel family, unported to the registry (the chunk scatter's grid)
+        _make_chunk_reduce_kernel(hi_n, lo, reduce),
+        name="matrel_spmv_reduce_chunks",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,                       # chunk_block
             grid=(n_chunks,),
@@ -496,6 +592,25 @@ def compact_apply(plan_static, tables, ov, x: jax.Array,
     if ov:
         y = spmv_lib._overflow_add(y, ov, x, n_rows)
     return y
+
+
+def reduce_apply(plan_static, tables, x: jax.Array, reduce: str,
+                 interpret: bool = False) -> jax.Array:
+    """Traceable body: ``y[i] = (max | min)(0, A[i, j] · x[j] over row
+    i's entries)`` from the compact tables of a plan in chunks whose
+    blocks' slots lie in row order and that has no hub chunks
+    (``spmv.rows_in_order``): the matvec's gather (:func:`_slot_weights`,
+    in its panels) and the chunk grid's reduction
+    (``matrel_spmv_reduce_chunks``) where the matvec adds. Every product
+    is one float32 multiply and no sum follows it, so ``y`` is what the
+    dense ``A .* x`` would give, bit for bit."""
+    n_rows, n_cols, block, lo = plan_static
+    src8, lane, off, val, chunk_block = tables
+    rows, cr, _ = src8.shape
+    w = _slot_weights(src8, lane, val, x)
+    y = _reduce_runner(rows, cr * LANE, -(-n_rows // block), block, lo,
+                       reduce, interpret)(chunk_block, off, w)
+    return y.reshape(-1)[:n_rows]
 
 
 _compact_jitted = jax.jit(compact_apply, static_argnums=(0, 4, 5))  # matlint: disable=ML010 pre-seam ops runner cache — the porting worklist (the ML009 legacy-kernel idiom)

@@ -633,6 +633,31 @@ def chunk_windows(off: np.ndarray, real: np.ndarray,
     return win.astype(np.int32)
 
 
+def rows_in_order(plan: "EdgeSpMVPlan") -> bool:
+    """Whether the (max | min) reduction over the chunk grid
+    (ops/pallas_spmv.reduce_apply) may read this plan: laid out in
+    chunks, no hub chunks, and in every row of 128 slots the real slots
+    first, their ``off`` never falling — so the slots that name one
+    destination row lie side by side there, which is what its segmented
+    scan takes for granted (the matvec's one-hot SUM is order-agnostic
+    and never asked). The chunks fills without hubs lay a block's slots
+    so (PR 38); checked on the tables themselves, once a plan."""
+    if plan.chunk_block is None or plan.hubs is not None:
+        return False
+    said = getattr(plan, "_rows_in_order", None)
+    if said is None:
+        lanes = (-1, 128)
+        off = np.asarray(plan.off).reshape(lanes)
+        # a padded slot names the sentinel source ``n_cols``
+        real = ~((np.asarray(plan.src8) == plan.n_cols // WIDTH)
+                 & (np.asarray(plan.lane) == plan.n_cols % WIDTH)
+                 ).reshape(lanes)
+        said = bool(np.all(real[:, 1:] <= real[:, :-1]) and np.all(
+            (off[:, 1:] >= off[:, :-1]) | ~real[:, 1:]))
+        plan._rows_in_order = said
+    return said
+
+
 def window_of(win):
     """(start, height) of every chunk of a table :func:`chunk_windows`
     made: (−1, 0) where the chunk takes the whole block."""

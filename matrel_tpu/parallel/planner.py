@@ -570,6 +570,28 @@ def sampled_dense_bytes(node: MatExpr, mesh: Mesh) -> float:
     return 2 * 4.0 * pn * pm / max(mesh.size, 1)
 
 
+def semiring_product(node: MatExpr, mesh: Mesh,
+                     config: Optional[MatrelConfig] = None) -> dict:
+    """How a ``semiring`` node will run, and what that keeps on one
+    device beside the column it reads and the column it makes
+    (executor._semiring_dispatch, the single source of truth):
+    ``chosen`` "coo_reduce" (the matrix's forward SpMV plan through the
+    chunk grid's reduction kernel, with the plan's ``layout`` and
+    ``panels``) or "coo_reduce_xla" (XLA's segment reduction over the
+    sorted entries), and ``bytes`` as core.coo.semiring_facts reckons
+    them. Either way from the leaf's entries alone: the (n × m) join
+    the node was written as is priced nowhere."""
+    from matrel_tpu import executor as _exec
+    from matrel_tpu.core import coo as coo_lib
+    m, plan = _exec._semiring_dispatch(node, mesh, config)
+    facts = coo_lib.semiring_facts(m, plan, node.attrs["reduce"])
+    out = {"chosen": "coo_reduce" if plan is not None else "coo_reduce_xla",
+           "bytes": float(facts["hbm_plan_bytes"])}
+    if plan is not None:
+        out.update(layout=facts["layout"], panels=(facts["panels"], 1))
+    return out
+
+
 def coo_product(node: MatExpr, mesh: Mesh,
                 config: Optional[MatrelConfig] = None) -> Optional[dict]:
     """How a matmul with a coo_leaf operand will run, and what that
@@ -714,7 +736,7 @@ def infer_dtype(node: MatExpr, config: Optional[MatrelConfig] = None,
             if "bfloat16" in (np.dtype(da).name, np.dtype(db).name):
                 return np.dtype("float32")
             return _promote(da, db)
-        if k in ("elemwise", "rank1", "join_value", "sampled"):
+        if k in ("elemwise", "rank1", "join_value", "sampled", "semiring"):
             return _promote(*(walk(c) for c in n.children))
         if k == "inverse":
             da = walk(n.children[0])
@@ -2235,6 +2257,18 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
         e = e.with_attrs(hbm_plan_bytes=int(need),
                          **({"refused_hbm": ("sampled",)}
                             if 0 < limit < need else {}))
+    if e.kind == "semiring" and "hbm_plan_bytes" not in e.attrs:
+        # the leaf's tables are its plan's and were not among the
+        # leaves reckoned: what is alive (the column it reads among
+        # it), its own column, and what answers it keeps
+        how = semiring_product(e, mesh, config)
+        need = (alive + how["bytes"]
+                + (0.0 if is_root
+                   else device_bytes(e, mesh, config, memo, lmemo)))
+        limit = mesh_lib.hbm_limit_bytes(mesh, config)
+        e = e.with_attrs(hbm_plan_bytes=int(need), coo_product=how,
+                         **({"refused_hbm": (how["chosen"],)}
+                            if 0 < limit < need else {}))
     if e.kind in ("join_rows", "join_cols") and "replicate" not in e.attrs:
         e = e.with_attrs(replicate=choose_join_scheme(
             e, mesh, config, layout_memo=lmemo,
@@ -2275,13 +2309,14 @@ def hbm_report(root: MatExpr) -> list:
                         "hbm_plan_bytes": n.attrs["hbm_plan_bytes"]})
             coo = n.attrs.get("coo_product")
             if coo is not None:
-                # a coo_leaf product: what runs is the SpMV plan or the
-                # densified leaf, not the stamped dense strategy
+                # a coo_leaf product, or a semiring product over one:
+                # what runs is the SpMV plan, the segment reduction or
+                # the densified leaf, not a stamped dense strategy
                 out[-1]["chosen"] = coo["chosen"]
-                if coo["chosen"] != "densify":
+                if "layout" in coo:
                     out[-1].update(layout=coo["layout"],
                                    panels=list(coo["panels"]))
-                else:
+                elif coo["chosen"] == "densify":
                     out[-1]["densified_bytes"] = int(coo["bytes"])
             if "gram_tiles" in n.attrs:
                 out[-1]["gram_tiles"] = list(n.attrs["gram_tiles"])

@@ -516,7 +516,10 @@ class MatrelSession:
         record each that the SpMV tables answer: what its
         ``matrel.spmm.plan`` span carries), ``sampled`` (one each
         sampled product answered fused, core.coo.sampled_facts: what its
-        ``matrel.sampled.plan`` span carries) and ``densified_products``
+        ``matrel.sampled.plan`` span carries), ``semiring`` (one each
+        (max | min, ×) product of a coo_leaf and a column,
+        core.coo.semiring_facts: what its ``matrel.semiring.plan`` span
+        carries) and ``densified_products``
         (one each leaf that was densified, under a product or read as
         an array). Copies; {} before the first dispatch."""
         plan = self._last_plan
@@ -529,6 +532,7 @@ class MatrelSession:
                 "products": [dict(r) for r in meta.get("products", ())],
                 "spmm": [dict(r) for r in meta.get("spmm", ())],
                 "sampled": [dict(r) for r in meta.get("sampled", ())],
+                "semiring": [dict(r) for r in meta.get("semiring", ())],
                 "densified_products": [
                     dict(r) for r in meta.get("densified_products", ())]}
 
@@ -1481,6 +1485,13 @@ class MatrelSession:
                 REGISTRY.counter(f"optimizer.rule.{rule}").inc(n)
         for d in matmuls:
             REGISTRY.counter(f"planner.strategy.{d['strategy']}").inc()
+        # (max | min, ×) products answered from the leaf's entries, and
+        # "mul" joins over an element-sparse leaf that densified it
+        if meta.get("semiring"):
+            REGISTRY.counter("semiring.fused").inc(len(meta["semiring"]))
+        if meta.get("densified_joins"):
+            REGISTRY.counter("semiring.densified").inc(
+                meta["densified_joins"])
 
     def _emit_verify_event(self, plan) -> None:
         """One ``verify`` record per observed query run (obs_level on
@@ -1667,7 +1678,7 @@ class MatrelSession:
             if sp.live:
                 # the SpMV plan each coo_leaf product of this program
                 # runs on: built and uploaded once, answered here
-                for name in ("spmm", "sampled"):
+                for name in ("spmm", "sampled", "semiring"):
                     for rec in plan.meta.get(name, ()):
                         with trace_lib.span(name + ".plan", hit=True,
                                             **rec):

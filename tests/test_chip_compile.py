@@ -462,6 +462,38 @@ def test_chunked_pagerank_loop_runs_in_panels(one_chip):
     assert "matrel_spmv_scatter_chunks" in text
 
 
+@pytest.mark.parametrize("reduce,chunks", [("max", G500_CHUNKS),
+                                           ("min", 4_096)])
+def test_semiring_round_reduces_in_the_chunk_grid(one_chip, reduce, chunks):
+    """Cell wcc_g500_22_1c's product (PR 50): the (max, x) reduction of
+    the graph's 133M slots and a label column, compiled by Mosaic at the
+    cell's size (the lane rotations of the segmented scan, the 65k-entry
+    scalar prefetch), gathered in the matvec's own panels, its
+    temporaries what ``plan_bytes`` reckons; (min, x) at a smaller
+    size. No sum scatter is in the program."""
+    shp = (chunks, spmv_lib.CHUNK // pc.LANE, pc.LANE)
+    tables = tuple(_sds(one_chip, shp, dt) for dt in (
+        jnp.int32, jnp.int8, jnp.int32, jnp.float32)) + (
+        _sds(one_chip, (chunks,), jnp.int32),)          # chunk -> block
+    static = (G500_NODES, G500_NODES, BLOCK, spmv_lib.LO)
+    compiled = _compile(
+        jax.jit(pc.reduce_apply, static_argnums=(0, 3, 4)), static, tables,
+        _sds(one_chip, (G500_NODES,), jnp.float32), reduce, False)
+    text = compiled.as_text()
+    assert "matrel_spmv_reduce_chunks" in text
+    assert "matrel_spmv_scatter" not in text
+    if chunks != G500_CHUNKS:
+        return
+    per = pc.panel_rows(chunks, spmv_lib.CHUNK)
+    assert 1 < per < chunks
+    assert f"u8[{per * spmv_lib.CHUNK},32]" in text       # a panel's rows
+    assert f"u8[{chunks * spmv_lib.CHUNK},32]" not in text
+    stats = compiled.memory_analysis()
+    reckoned = pc.plan_bytes(chunks, spmv_lib.CHUNK)
+    taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 0.9 * reckoned < taken < 1.05 * reckoned, (reckoned, taken)
+
+
 # The same graph's plan with the hub table build_spmv_plan chooses for it
 # (PR 36, PR 42): 286,720 hubs (2,240 table rows) hold 110.5M of the edges,
 # in chunks of their own whose registers walk the table rows they name
