@@ -304,6 +304,9 @@ def plan_facts(plan, entries: int) -> dict:
     from matrel_tpu.ops import pallas_spmv as pc
     parts = [p for _, p in plan_parts(plan)]
     shapes = [np.asarray(p.src8).shape for p in parts]
+    # an orientation's own plan may have hub chunks (the matvec's; a
+    # k-wide plan never): counted in ``slots``, not in ``chunks``
+    hub_slots = sum(p.hubs.idx.size for p in parts if p.hubs is not None)
     slots = sum(r * c for r, c in shapes)
     per = [pc.wide_panel_rows(r, c) for r, c in shapes]
     dense = getattr(plan, "dense", None)
@@ -314,7 +317,7 @@ def plan_facts(plan, entries: int) -> dict:
         "layout": ("chunks" if parts[0].chunk_block is not None
                    else "blocks"),
         "entries": int(entries) - (0 if dense is None else dense.entries),
-        "slots": int(slots),
+        "slots": int(slots + hub_slots),
         "chunks": int(sum(r for r, _ in shapes)),
         "windowed_chunks": sum(window_rows.values()),
         "window_rows": window_rows,
@@ -323,7 +326,8 @@ def plan_facts(plan, entries: int) -> dict:
         "overflow_edges": sum(0 if p.ov_rows is None
                               else int(p.ov_rows.shape[0]) for p in parts),
         "panels": int(sum(-(-r // n) for (r, _), n in zip(shapes, per))),
-        "plan_bytes": int(pc.TABLE_BYTES_A_SLOT * slots + max(
+        "plan_bytes": int(pc.TABLE_BYTES_A_SLOT * slots
+                          + pc.HUB_BYTES_A_SLOT * hub_slots + max(
             pc.wide_panel_bytes(r, c) for r, c in shapes)
             + (0 if dense is None else dense.slab.nbytes)),
     }
@@ -386,18 +390,26 @@ def semiring_facts(m: "COOMatrix", plan, reduce: str) -> dict:
     say of how a (max | min, ×) product of ``m`` (one entry a cell:
     :meth:`COOMatrix.entry_view`) and a column is answered: ``reduce``
     and ``merge``; ``how`` — ``kernel`` (``plan``: its forward plan, in
-    chunks whose blocks' slots lie in row order, through
+    chunks whose slots lie by row where the scan reads them, through
     ``matrel_spmv_reduce_chunks``: the kernel's engagement counter) or
     ``xla`` (``plan`` None: ``segment_max`` / ``segment_min`` over the
     entries sorted by row — the CPU, a mesh, a refused plan, the blocks
     layout of a small one); ``entries``; of a plan its ``layout``,
-    ``slots``, ``chunks``, ``panels`` (of table rows, the matvec's own)
-    and ``overflow_edges``; ``full_rows`` (rows that hold every column,
+    ``slots`` (the hub chunks' among them), ``chunks`` (those that go
+    through the row gather), ``panels`` (of table rows, the matvec's
+    own) and ``overflow_edges``, and of its hub chunks, whose slots take
+    their value from the hub table in VMEM through
+    ``matrel_spmv_reduce_hubs`` (PR 51; all 0 where the build's rule
+    took no hub: the hub chunks' engagement counters) ``hub_chunks``,
+    ``hub_slots``, ``hub_entry_share`` (the entries in them over
+    ``entries``) and ``hub_walk_rows`` (the table rows the kernel walks
+    a product); ``full_rows`` (rows that hold every column,
     answered on their own off a dense copy of theirs: the only rows
     whose extremum the 0 of a missing cell has no part in); and
     ``hbm_plan_bytes``: the tables, the slots' weights and the largest
-    panel's temporaries (pallas_spmv.plan_bytes), or the sorted entries
-    and one gather over them, and the full rows' copy."""
+    panel's temporaries (pallas_spmv.plan_bytes, a hub slot at its 12
+    B), or the sorted entries and one gather over them, and the full
+    rows' copy."""
     from matrel_tpu.ops import pallas_spmv as pc
     full = m.full_rows()
     facts = {"reduce": reduce, "merge": "mul",
@@ -410,11 +422,17 @@ def semiring_facts(m: "COOMatrix", plan, reduce: str) -> dict:
         facts["hbm_plan_bytes"] = own + (16 + pc._TEMP_BYTES_A_SLOT) * m.nnz
         return facts
     rows, cap = np.asarray(plan.src8).shape
+    hub = plan.hubs
+    hub_slots = 0 if hub is None else int(hub.idx.size)
     facts.update(
-        layout="chunks", slots=int(rows * cap), chunks=int(rows),
+        layout="chunks", slots=int(rows * cap) + hub_slots, chunks=int(rows),
         panels=int(-(-rows // pc.panel_rows(rows, cap))),
         overflow_edges=0,
-        hbm_plan_bytes=own + int(pc.plan_bytes(rows, cap)))
+        hub_chunks=hub_slots // cap, hub_slots=hub_slots,
+        hub_entry_share=(0.0 if hub is None
+                         else round(hub.entries / max(m.nnz, 1), 4)),
+        hub_walk_rows=0 if hub is None else int(hub.rows.sum()),
+        hbm_plan_bytes=own + int(pc.plan_bytes(rows, cap, hub_slots)))
     return facts
 
 
@@ -544,20 +562,25 @@ class COOMatrix:
 
     # ----------------------------------------------------------- plans
     def _build(self, out_ids, in_ids, n_out: int, n_in: int, sel=None,
-               col0: int = 0):
+               col0: int = 0, hubs: bool = False):
         """One compact plan over this matrix's entries (``sel``: a subset
-        of them, sources renumbered from ``col0``): no hub chunks — the
-        k-wide product fetches a hub's whole row like any other."""
+        of them, sources renumbered from ``col0``). ``hubs``: with hub
+        chunks where the build's own rule finds the sources skewed
+        enough (spmv._hub_rows) — an orientation's own plan, which the
+        matvec and the (max | min) reduction read; never a k-wide
+        plan's: that product fetches a hub's whole row like any
+        other."""
         if sel is not None:
             out_ids, in_ids = out_ids[sel], in_ids[sel] - col0
         return spmv_lib.build_spmv_plan(
             out_ids, in_ids, self.vals if sel is None else self.vals[sel],
-            n_rows=n_out, n_cols=n_in, layout=_plan_layout(), hubs=False)
+            n_rows=n_out, n_cols=n_in, layout=_plan_layout(), hubs=hubs)
 
     def _get_plan(self) -> Optional[spmv_lib.EdgeSpMVPlan]:
         if not self._plan_tried:
             self._plan = _counted_build(
-                lambda: self._build(self.rows, self.cols, *self.shape),
+                lambda: self._build(self.rows, self.cols, *self.shape,
+                                    hubs=True),
                 orientation="forward")
             self._plan_tried = True
         return self._plan
@@ -565,7 +588,8 @@ class COOMatrix:
     def _get_plan_t(self) -> Optional[spmv_lib.EdgeSpMVPlan]:
         if not self._plan_t_tried:
             self._plan_t = _counted_build(
-                lambda: self._build(self.cols, self.rows, *self.shape[::-1]),
+                lambda: self._build(self.cols, self.rows, *self.shape[::-1],
+                                    hubs=True),
                 orientation="transposed")
             self._plan_t_tried = True
         return self._plan_t
@@ -584,10 +608,11 @@ class COOMatrix:
 
     def _get_wide_plan(self, transposed: bool = False):
         """The plan the k-wide product A·X (``transposed``: Aᵀ·X) runs:
-        the orientation's own where X's rows make one gather table and
-        no line of the matrix is dense, else a :class:`PanelledPlan`
-        (over ranges of X's rows, beside the dense part), built once;
-        None where the planner refused the matrix."""
+        the orientation's own where X's rows make one gather table, no
+        line of the matrix is dense and that plan has no hub chunks
+        (then one of its own without them), else a
+        :class:`PanelledPlan` (over ranges of X's rows, beside the dense
+        part), built once; None where the planner refused the matrix."""
         n_out, n_in = self.shape[::-1] if transposed else self.shape
         own = self._get_plan_t if transposed else self._get_plan
         if _plan_layout() != "auto":
@@ -595,11 +620,29 @@ class COOMatrix:
         memo = self._wide_t if transposed else self._wide
         if memo:
             return memo[0]
-        panels = spmv_lib.source_panels(n_in)
-        if panels == 1 and self._dense_lines() is None:
-            return own()
         out_ids, in_ids = ((self.cols, self.rows) if transposed
                            else (self.rows, self.cols))
+        panels = spmv_lib.source_panels(n_in)
+        if panels == 1 and self._dense_lines() is None:
+            # the orientation's own where it was built already and has
+            # no hub chunks; else one without them, which IS the own
+            # plan where none was built yet and the rule would take no
+            # hub: one build serves both, whichever asks first
+            tried = self._plan_t_tried if transposed else self._plan_tried
+            mine = own() if tried else None
+            if tried and (mine is None or mine.hubs is None):
+                return mine
+            plan = _counted_build(
+                lambda: self._build(out_ids, in_ids, n_out, n_in),
+                orientation="transposed" if transposed else "forward")
+            if tried or (plan is not None and plan.chunk_block is not None
+                         and spmv_lib.takes_hubs(in_ids, n_in, n_out)):
+                memo.append(plan)
+            elif transposed:
+                self._plan_t, self._plan_t_tried = plan, True
+            else:
+                self._plan, self._plan_tried = plan, True
+            return plan
         # ranges of whole table rows, as even as they come
         width = -(-n_in // (panels * spmv_lib.WIDTH)) * spmv_lib.WIDTH
 

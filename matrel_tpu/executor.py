@@ -880,8 +880,10 @@ class Lowerer:
         ``S[i, j] · x[j]`` over ALL columns — what ``_agg`` over the
         dense ``join_cols`` gives, missing cells' zeros and all — from
         S's entries alone (core.coo.semiring_apply): through the
-        matrix's forward plan and the chunk grid's reduction kernel
-        where :func:`_semiring_dispatch` finds one it reads, else XLA's
+        matrix's forward plan and the chunk grid's reduction kernels
+        (its hub chunks' slots from the hub table in VMEM, the others'
+        through the row gather) where :func:`_semiring_dispatch` finds
+        one they read, else XLA's
         segment reduction over the sorted entries. Neither the (n × m)
         join nor a dense S exists."""
         from matrel_tpu.config import pallas_interpret_mode
@@ -1854,9 +1856,11 @@ def _semiring_dispatch(node: MatExpr, mesh: Mesh,
     """(matrix, plan) a ``semiring`` node is answered from: the leaf's
     matrix with one entry a cell (COOMatrix.entry_view: an extremum,
     unlike a sum, has to know a repeated cell) and its forward SpMV plan
-    where the chunk grid's reduction kernel reads it — one device, the
+    where the chunk grid's reduction kernels read it — one device, the
     compact-table Pallas executor on (config.pallas_enabled), a plan in
-    chunks, without hub chunks, its blocks' slots in row order
+    chunks, with hub chunks where the build's rule took any (PR 51: the
+    plan ``pagerank_edges`` would build of the same entries), in every
+    row of 128 slots those of one destination row side by side
     (spmv.rows_in_order) — else None, and XLA's segment reduction over
     the sorted entries answers. SINGLE source of truth, shared by the
     lowering and the planner's memory reckoning

@@ -48,6 +48,12 @@ Pipeline per matvec (``spmv_compact``):
      permute every ~2.5 cycles whatever is done about it, so the rows
      walked are the kernel's cost: all M for every register until PR 42
      (which held the table at 256 rows), about M a BLOCK since.
+  2c. The (max | min, x) product (``reduce_apply``, PR 50) is steps 1
+     to 2b with another body: a segmented extremum scan along the lanes
+     where the sum stands (``_reduce_tile``), over the same tables —
+     the hub chunks' too since PR 51 (``matrel_spmv_reduce_hubs``: the
+     hub kernel's weights into the reduction's tile), which is why a
+     register's slots lie by destination row (``spmv.rows_in_order``).
   3. XLA: overflow-COO accumulation (blocks layout only; unchanged
      contract).
 
@@ -228,13 +234,15 @@ def _pack_walks(first: np.ndarray, rows: np.ndarray) -> np.ndarray:
     return word.view(np.int32)
 
 
-def _make_hub_scatter_kernel(hi_n: int, lo: int, passes: int, regs: int,
-                             step: int):
+def _make_hub_kernel(regs: int, step: int, fold):
+    """A hub chunk's step: ``fold(tile, off, w)`` is the block's tile
+    with the chunk's slots taken in — the sum's scatter or the (max |
+    min) reduction — their weights ``w`` made here from the hub table."""
     def kernel(cb_ref, walk_ref, idx_ref, off_ref, val_ref, table_ref,
                acc_ref, y_ref):
-        # as the chunk scatter, but the block's tile starts from the
-        # main scatter's sums (``acc``, which the output aliases: a
-        # block with no hub chunk keeps them untouched)
+        # as the chunk kernels, but the block's tile starts from what
+        # the main chunks made of it (``acc``, which the output aliases:
+        # a block with no hub chunk keeps that untouched)
         @pl.when(_first_chunk_of_its_block(cb_ref))
         def _():
             y_ref[...] = acc_ref[...]
@@ -247,7 +255,7 @@ def _make_hub_scatter_kernel(hi_n: int, lo: int, passes: int, regs: int,
                     field & ((1 << _WALK_STEP_BITS) - 1))
 
         w = _hub_weights(idx_ref[0], table_ref, walk, step) * val_ref[0]
-        y_ref[0] += _scatter_tile(off_ref[0], w, hi_n, lo, passes)
+        y_ref[0] = fold(y_ref[0], off_ref[0], w)
 
     return kernel
 
@@ -398,21 +406,17 @@ def _reduce_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
     )
 
 
-@functools.lru_cache(maxsize=32)
-def _hub_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
-                passes: int, m_rows: int, step: int, interpret: bool):
-    """scatter(chunk_block, walks, idx, off, val, table, acc) -> acc + the
-    hub chunks' block sums, (nb, HI', LO): the chunk scatter whose slot
-    weights come from the ``(m_rows, 128)`` hub table in VMEM, a
-    register's from the rows ``walks`` names for it (:func:`_pack_walks`),
-    ``step`` rows a loop trip."""
+def _hub_grid(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
+              m_rows: int, interpret: bool) -> dict:
+    """What the two hub kernels' ``pallas_call``s share: the chunk grid
+    over a plan's hub chunks, call(chunk_block, walks, idx, off, val,
+    table, acc) -> (nb, HI', LO), the ``(m_rows, 128)`` hub table whole
+    in VMEM, ``acc`` aliased to the output."""
     hi_n = block // lo
     cr = chunk // LANE
     slots = pl.BlockSpec((1, cr, LANE), lambda c, cb, wk: (c, 0, 0))
     sums = pl.BlockSpec((1, hi_n, lo), lambda c, cb, wk: (cb[c], 0, 0))
-    return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
-        _make_hub_scatter_kernel(hi_n, lo, passes, cr // 8, step),
-        name="matrel_spmv_scatter_hubs",
+    return dict(
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,                   # chunk_block, walks
             grid=(n_chunks,),
@@ -427,6 +431,43 @@ def _hub_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )
+
+
+@functools.lru_cache(maxsize=32)
+def _hub_runner(n_chunks: int, chunk: int, nb: int, block: int, lo: int,
+                passes: int, m_rows: int, step: int, interpret: bool):
+    """scatter(chunk_block, walks, idx, off, val, table, acc) -> acc + the
+    hub chunks' block sums, (nb, HI', LO): the chunk scatter whose slot
+    weights come from the ``(m_rows, 128)`` hub table in VMEM, a
+    register's from the rows ``walks`` names for it (:func:`_pack_walks`),
+    ``step`` rows a loop trip."""
+    hi_n = block // lo
+    return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel, unported to the registry this round (autotuned via the spmv| table rows)
+        _make_hub_kernel(
+            chunk // LANE // 8, step,
+            lambda y, off, w: y + _scatter_tile(off, w, hi_n, lo, passes)),
+        name="matrel_spmv_scatter_hubs",
+        **_hub_grid(n_chunks, chunk, nb, block, lo, m_rows, interpret))
+
+
+@functools.lru_cache(maxsize=32)
+def _reduce_hub_runner(n_chunks: int, chunk: int, nb: int, block: int,
+                       lo: int, reduce: str, m_rows: int, step: int,
+                       interpret: bool):
+    """reduce(chunk_block, walks, idx, off, val, table, acc) -> the (max |
+    min) of ``acc`` (the main chunks' extrema, :func:`_reduce_runner`)
+    and the hub chunks' slots, (nb, HI', LO): :func:`_hub_runner`'s grid
+    and weights into :func:`_reduce_tile` — each register's slots lie by
+    destination row (``spmv.rows_in_order``), which is all the scan asks
+    and nothing the walk sees."""
+    hi_n = block // lo
+    return pl.pallas_call(  # matlint: disable=ML009 legacy SpMV scatter kernel family, unported to the registry (the hub scatter's grid)
+        _make_hub_kernel(
+            chunk // LANE // 8, step,
+            lambda y, off, w: REDUCES[reduce](
+                y, _reduce_tile(off, w, hi_n, lo, reduce))),
+        name="matrel_spmv_reduce_hubs",
+        **_hub_grid(n_chunks, chunk, nb, block, lo, m_rows, interpret))
 
 
 def compact_tables(plan: spmv_lib.EdgeSpMVPlan):
@@ -559,6 +600,15 @@ def _slot_weights(src8, lane, val, x: jax.Array) -> jax.Array:
                              jnp.zeros(val.shape, jnp.float32))
 
 
+def _hub_table(x: jax.Array, ids: jax.Array) -> jax.Array:
+    """``x[ids]`` as the hub kernels hold it: a row of 128 hubs a row."""
+    table = x.astype(jnp.float32).at[ids].get(
+        mode="promise_in_bounds").reshape(-1, LANE)
+    # up to whole walk steps: rows of zeros, which no slot names
+    return jnp.pad(table, ((0, spmv_lib.hub_table_rows(
+        table.shape[0]) - table.shape[0]), (0, 0)))
+
+
 def compact_apply(plan_static, tables, ov, x: jax.Array,
                   passes: int = 3, interpret: bool = False) -> jax.Array:
     """Traceable body: y = A·x from compact tables. ``plan_static`` is
@@ -576,11 +626,7 @@ def compact_apply(plan_static, tables, ov, x: jax.Array,
                           interpret)(chunk_block, off, w)
         if hub:
             ids, idx, hub_off, hub_val, hub_block, walks = hub
-            table = x.astype(jnp.float32).at[ids].get(
-                mode="promise_in_bounds").reshape(-1, LANE)
-            # up to whole walk steps: rows of zeros, which no slot names
-            table = jnp.pad(table, ((0, spmv_lib.hub_table_rows(
-                table.shape[0]) - table.shape[0]), (0, 0)))
+            table = _hub_table(x, ids)
             y = _hub_runner(idx.shape[0], cr * LANE, nb, block, lo, passes,
                             table.shape[0], spmv_lib.HUB_WALK, interpret)(
                 hub_block, walks, idx, hub_off, hub_val, table, y)
@@ -598,18 +644,29 @@ def reduce_apply(plan_static, tables, x: jax.Array, reduce: str,
                  interpret: bool = False) -> jax.Array:
     """Traceable body: ``y[i] = (max | min)(0, A[i, j] · x[j] over row
     i's entries)`` from the compact tables of a plan in chunks whose
-    blocks' slots lie in row order and that has no hub chunks
-    (``spmv.rows_in_order``): the matvec's gather (:func:`_slot_weights`,
-    in its panels) and the chunk grid's reduction
-    (``matrel_spmv_reduce_chunks``) where the matvec adds. Every product
-    is one float32 multiply and no sum follows it, so ``y`` is what the
-    dense ``A .* x`` would give, bit for bit."""
+    rows of 128 slots hold the slots of one destination row side by side
+    (``spmv.rows_in_order``; five tables, or eleven with hub chunks, as
+    :func:`compact_apply` takes them): the matvec's gather
+    (:func:`_slot_weights`, in its panels) and the chunk grid's
+    reduction (``matrel_spmv_reduce_chunks``) where the matvec adds, and
+    then the hub chunks' slots, their weights off the table ``x[ids]``
+    in VMEM, into the same tiles (``matrel_spmv_reduce_hubs``). Every
+    product is one float32 multiply and no sum follows it, so ``y`` is
+    what the dense ``A .* x`` would give, bit for bit."""
     n_rows, n_cols, block, lo = plan_static
-    src8, lane, off, val, chunk_block = tables
+    src8, lane, off, val, chunk_block, *hub = tables
     rows, cr, _ = src8.shape
+    nb = -(-n_rows // block)
     w = _slot_weights(src8, lane, val, x)
-    y = _reduce_runner(rows, cr * LANE, -(-n_rows // block), block, lo,
-                       reduce, interpret)(chunk_block, off, w)
+    y = _reduce_runner(rows, cr * LANE, nb, block, lo, reduce, interpret)(
+        chunk_block, off, w)
+    if hub:
+        ids, idx, hub_off, hub_val, hub_block, walks = hub
+        table = _hub_table(x, ids)
+        y = _reduce_hub_runner(idx.shape[0], cr * LANE, nb, block, lo,
+                               reduce, table.shape[0], spmv_lib.HUB_WALK,
+                               interpret)(
+            hub_block, walks, idx, hub_off, hub_val, table, y)
     return y.reshape(-1)[:n_rows]
 
 

@@ -45,10 +45,12 @@ reference's plan-per-query model. A plan has one of two layouts:
   every block takes 2.98 and still leaves 362k edges to the scalar tail.
   Only the compact-table Pallas executors (ops/pallas_spmv.py, one
   device: the matvec and, since PR 37, the k-wide product) take it; the
-  expanded tables and ``shard_plan`` say so by name. Without hub chunks
-  a block's slots lie by destination row (PR 38), so a chunk names few
-  rows and the k-wide scatter's one-hot is as tall as the shortest rung
-  of ``WINDOWS`` that holds them (``chunk_windows``; PR 49: a ladder).
+  expanded tables and ``shard_plan`` say so by name. A block's slots
+  lie by destination row (PR 38; beside hub chunks too since PR 51), so
+  a chunk names few rows and the k-wide scatter's one-hot is as tall as
+  the shortest rung of ``WINDOWS`` that holds them (``chunk_windows``;
+  PR 49: a ladder), and the slots of one row lie side by side, which is
+  what the (max | min) reduction's scan reads (``rows_in_order``).
   Where the sources are skewed too (PR 36), the
   edges whose source is among the ``128·M`` of largest out-degree, the *hubs*, lie in
   a second set of chunks (``HubChunks``): a slot there names its source
@@ -59,7 +61,9 @@ reference's plan-per-query model. A plan has one of two layouts:
   1,024 of them names a short run of rows, recorded beside the chunk
   (``hub_walks``), and the kernel walks those rows and not the table: a
   row costs a walk step a block and no longer a permute every hub slot,
-  which is what lets the table grow. On that Graph500 graph 32,768 of
+  which is what lets the table grow. Inside a register the slots lie
+  by destination row (PR 51): the walk and the matvec's sum do not see
+  that order, and the reduction's scan needs it. On that Graph500 graph 32,768 of
   2.4M sources hold 52% of the edges and 286,720 hold 86%; ``_hub_rows``
   chooses M from the degrees and the block count, 0 on a flat graph.
 
@@ -263,8 +267,11 @@ class HubChunks:
                    ``first : first + rows`` a register of the chunk's
                    slots names (:func:`hub_walks`), whole steps of
                    ``HUB_WALK``: what the hub kernel walks for it.
-    A block's real slots lie by table row (``idx // 128``), in input
-    order inside a row, the padding after them."""
+      entries      how many slots are real
+    A block's real slots lie by table row (``idx // 128``) into
+    registers of ``HUB_REG``, inside a register by destination row
+    (``off``), inside a row by table row and then in input order, the
+    padding after them, its ``off`` the last real slot's (PR 51)."""
     ids: np.ndarray
     idx: np.ndarray
     off: np.ndarray
@@ -272,12 +279,14 @@ class HubChunks:
     chunk_block: np.ndarray
     first: np.ndarray
     rows: np.ndarray
+    entries: int
 
     @classmethod
     def of(cls, ids, idx, off, val, chunk_block) -> "HubChunks":
-        """With the walks reckoned from ``idx``."""
+        """With the walks and the count reckoned from ``idx``."""
         first, rows = hub_walks(idx, ids.shape[0])
-        return cls(ids, idx, off, val, chunk_block, first, rows)
+        return cls(ids, idx, off, val, chunk_block, first, rows,
+                   int(np.count_nonzero(idx < ids.shape[0])))
 
 
 @dataclasses.dataclass
@@ -423,8 +432,8 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
     the edges from the sources of largest out-degree go to chunks of
     their own (``plan.hubs``) where :func:`_hub_rows` finds the sources
     skewed enough, a choice made from ``cols`` alone (``hubs=False``:
-    never — a plan that also serves the k-wide product, which fetches a
-    hub's whole row like any other, gains nothing from a second set).
+    never — a plan for the k-wide product, which fetches a hub's whole
+    row like any other, gains nothing from a second set).
     ``"auto"`` (for a caller whose executor takes both: the compact
     Pallas matvec on one device) picks ``chunks`` where that walks
     fewer slots, an overflow edge counted as ``_OVERFLOW_EDGE_SLOTS``,
@@ -513,13 +522,14 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
         return None
 
     # Native counting-sort fill (O(m), no argsort). The chunks layout
-    # without hub chunks lies by row inside a block, slot for slot as
-    # the numpy path lays it (the k-wide scatter's windows read that
-    # order: chunk_windows), and a block's hub slots by table row, slot
-    # for slot too (the hub kernel's walks read that one: hub_walks);
-    # the blocks layout and the main chunks beside hub chunks keep
-    # input order inside a block — the matvec's one-hot contraction is
-    # order-agnostic, so their results match the numpy path
+    # lies by row inside a block, slot for slot as the numpy path lays
+    # it (the k-wide scatter's windows and the reduction's scan read
+    # that order: chunk_windows, rows_in_order), and a block's hub
+    # slots by table row into registers and by row inside a register,
+    # slot for slot too (the hub kernel's walks read the one, hub_walks,
+    # the scan the other); the blocks layout keeps input order inside a
+    # block — the matvec's one-hot contraction is order-agnostic, so its
+    # results match the numpy path
     filled = hub_filled = None
     if hub_ids is not None:
         both = (native.spmv_fill_ragged_hubs(
@@ -536,10 +546,18 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
             at, rest = np.flatnonzero(of_edge >= 0), np.flatnonzero(of_edge < 0)
             at = at[np.argsort(rows[at] // block * (hub_ids.size // HUB_ROW)
                                + of_edge[at] // HUB_ROW, kind="stable")]
+            # and inside each register of its block by destination row
+            blk = rows[at] // block
+            reg = (np.arange(at.size) - (np.cumsum(hub_cnt) - hub_cnt)[blk]
+                   ) // HUB_REG
+            at = at[np.argsort(
+                (blk * (int(hub_cnt.max()) // HUB_REG + 1) + reg) * block
+                + rows[at] % block, kind="stable")]
             idx, _, hub_off, hub_val = _numpy_fill(
                 rows[at], of_edge[at].astype(np.int64),
                 None if vals is None else vals[at], hub_ids.size, block,
-                hub_first, hub_cnt, width=1, in_order=True)[:4]
+                hub_first, hub_cnt, width=1, in_order=True,
+                pad_rows=True)[:4]
             hub_filled = idx, hub_off, hub_val
             rows, cols = rows[rest], cols[rest]
             vals = None if vals is None else vals[rest]
@@ -550,7 +568,8 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
         filled = native.spmv_fill(rows, cols, vals, n_cols, block, nb, cap,
                                   WIDTH, n_ov)
     if filled is None:
-        filled = _numpy_fill(rows, cols, vals, n_cols, block, first, cnt)
+        filled = _numpy_fill(rows, cols, vals, n_cols, block, first, cnt,
+                             pad_rows=layout == "chunks")
     src8, lane, off, val, ov_r64, ov_c64, ov_v = filled
 
     if n_ov:
@@ -636,26 +655,38 @@ def chunk_windows(off: np.ndarray, real: np.ndarray,
 def rows_in_order(plan: "EdgeSpMVPlan") -> bool:
     """Whether the (max | min) reduction over the chunk grid
     (ops/pallas_spmv.reduce_apply) may read this plan: laid out in
-    chunks, no hub chunks, and in every row of 128 slots the real slots
-    first, their ``off`` never falling — so the slots that name one
-    destination row lie side by side there, which is what its segmented
-    scan takes for granted (the matvec's one-hot SUM is order-agnostic
-    and never asked). The chunks fills without hubs lay a block's slots
-    so (PR 38); checked on the tables themselves, once a plan."""
-    if plan.chunk_block is None or plan.hubs is not None:
+    chunks, and in every row of 128 slots — of its own tables and of
+    its hub chunks', where it has any (padding there: ``idx`` =
+    ``len(ids)``) — the real slots first and ``off`` never falling,
+    padding included, so the slots that name one destination row lie
+    side by side there, which is what its segmented scan takes for
+    granted (the matvec's one-hot SUM is order-agnostic and never
+    asked). It asks nothing across rows of 128: a block's main slots lie
+    by row throughout (PR 38), its hub slots by table row into
+    registers of ``HUB_REG`` and by row inside each (PR 51), and either
+    passes; a padded slot's ``off`` is its block's last real one's. The
+    chunks fills lay plans so; checked on the tables themselves, once a
+    plan."""
+    if plan.chunk_block is None:
         return False
     said = getattr(plan, "_rows_in_order", None)
     if said is None:
-        lanes = (-1, 128)
-        off = np.asarray(plan.off).reshape(lanes)
         # a padded slot names the sentinel source ``n_cols``
         real = ~((np.asarray(plan.src8) == plan.n_cols // WIDTH)
-                 & (np.asarray(plan.lane) == plan.n_cols % WIDTH)
-                 ).reshape(lanes)
-        said = bool(np.all(real[:, 1:] <= real[:, :-1]) and np.all(
-            (off[:, 1:] >= off[:, :-1]) | ~real[:, 1:]))
+                 & (np.asarray(plan.lane) == plan.n_cols % WIDTH))
+        said = _runs_side_by_side(np.asarray(plan.off), real)
+        hub = plan.hubs
+        if said and hub is not None:
+            said = _runs_side_by_side(hub.off, hub.idx < hub.ids.shape[0])
         plan._rows_in_order = said
     return said
+
+
+def _runs_side_by_side(off: np.ndarray, real: np.ndarray) -> bool:
+    """:func:`rows_in_order`'s rule over one set of chunk tables."""
+    off, real = off.reshape(-1, 128), real.reshape(-1, 128)
+    return bool(np.all(real[:, 1:] <= real[:, :-1])
+                and np.all(off[:, 1:] >= off[:, :-1]))
 
 
 def window_of(win):
@@ -717,6 +748,14 @@ def _choose_hubs(cols: np.ndarray, n_cols: int, blocks: int):
     return ids, rank
 
 
+def takes_hubs(cols, n_cols: int, n_rows: int, block: int = BLOCK) -> bool:
+    """Whether a plan in chunks of these entries gets hub chunks
+    (``build_spmv_plan(hubs=True)``): the rule's own answer, from the
+    sources' edge counts and the block count alone, without a build."""
+    return _choose_hubs(np.asarray(cols, np.int64), n_cols,
+                        -(-n_rows // block))[0] is not None
+
+
 def hub_walks(idx: np.ndarray, n_hubs: int):
     """(``first``, ``rows``), each (chunks, CHUNK // HUB_REG) int32, of a
     hub chunk table ``idx`` (chunks, CHUNK): the run of table rows,
@@ -728,9 +767,11 @@ def hub_walks(idx: np.ndarray, n_hubs: int):
     register and no other row. A padded slot (``idx`` = ``n_hubs``)
     takes no part; a register of padding alone walks the table's first
     step, as the kernel walks one whatever it is told. Whatever the
-    order of the slots the run covers them; in the order the build lays
-    them (a block's slots by table row) the runs of a block's registers
-    share the table out and overlap in a step at most."""
+    order of the slots the run covers them; as the build lays them (a
+    block's slots by table row into registers; the order inside a
+    register, by destination row since PR 51, is nothing to a run) the
+    runs of a block's registers share the table out and overlap in a
+    step at most."""
     row = idx.reshape(idx.shape[0], -1, HUB_REG) >> 7
     padded = n_hubs >> 7           # one past the table's last row
     low = row.min(axis=2)          # padding is the largest row there is
@@ -750,14 +791,18 @@ def hub_table_rows(rows: int) -> int:
 
 
 def _numpy_fill(rows, cols, vals, n_cols, block, first, cnt,
-                width: int = WIDTH, in_order: bool = False):
+                width: int = WIDTH, in_order: bool = False,
+                pad_rows: bool = False):
     """Pure-numpy plan fill (fallback when the native library is
     unavailable): stable argsort by row (``in_order``: the edges come
     block by block already, in the order their slots shall have), then
     fancy-indexed scatters. Block b owns the flat slots
     ``first[b]:first[b + 1]`` (one row of ``cap`` in the blocks layout,
     its chunks in the other); edges past them are the overflow. A column
-    is a row of ``width`` and a lane."""
+    is a row of ``width`` and a lane. ``pad_rows`` (the chunks layout,
+    where nothing overflows): a padded slot's ``off`` is that of its
+    block's last edge and not 0, so ``off`` never falls along a row of
+    128 (:func:`rows_in_order`)."""
     m = rows.shape[0]
     if vals is None:
         vals = np.ones((m,), np.float32)
@@ -776,6 +821,9 @@ def _numpy_fill(rows, cols, vals, n_cols, block, first, cnt,
     src_pad = np.full(slots, n_cols, np.int64)       # sentinel -> reads 0
     val_pad = np.zeros(slots, np.float32)
     off_pad = np.zeros(slots, np.int64)
+    if pad_rows and m:
+        last = rows_s[np.maximum(starts[1:] - 1, 0)] % block
+        off_pad = np.repeat(np.where(cnt > 0, last, 0), np.diff(first))
     p_main = pos[in_main]
     src_pad[p_main] = cols_s[in_main]
     val_pad[p_main] = vals_s[in_main]

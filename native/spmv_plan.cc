@@ -20,27 +20,77 @@
 // set of ragged tables (rank, off, val) and every other edge to the main
 // ones, in one walk over the edge list: no partitioned copy of it.
 //
-// Slot order within a block. matrel_spmv_fill, and the main tables of
-// matrel_spmv_fill_ragged_hubs, keep input order where the numpy path
-// sorts by row — the matvec's one-hot contraction is order-agnostic, so
-// their contract (tests assert it) is equal spmv RESULTS, not byte-equal
-// layouts. matrel_spmv_fill_ragged (PR 38) lays a block's entries by
-// destination row, stable inside a row, as the numpy path does: EQUAL
-// LAYOUTS, slot for slot (tests assert that too). The k-wide scatter
-// (ops/pallas_spmv.py) reads the order: a chunk of 2,048 slots in row
-// order names few rows, and where they lie within 128 of one another its
-// one-hot is 128 rows tall and not the block's 512. The hub tables of
-// matrel_spmv_fill_ragged_hubs (PR 42) lie by the hub table's row
-// (rank / 128), stable inside a row, as the numpy path lays them: equal
-// layouts again. The hub kernel reads that order: a register of 1,024
-// slots names a short run of table rows and walks those alone. Sentinel
-// convention matches everywhere: src = n_cols, off = 0, val = 0.
+// Slot order within a block. matrel_spmv_fill (the blocks layout) keeps
+// input order where the numpy path sorts by row — the matvec's one-hot
+// contraction is order-agnostic, so its contract (tests assert it) is equal
+// spmv RESULTS, not byte-equal layouts. Both chunks fills lay a block's main
+// slots by destination row, stable inside a row, as the numpy path does
+// (matrel_spmv_fill_ragged since PR 38, the main tables beside hub chunks
+// since PR 51): EQUAL LAYOUTS, slot for slot (tests assert that too). Two
+// kernels read the order (ops/pallas_spmv.py): the k-wide scatter — a chunk
+// of 2,048 slots in row order names few rows, and its one-hot is as tall as
+// they need and not the block's 512 — and the (max | min) reduction, whose
+// segmented scan wants the slots of one destination row side by side in
+// every row of 128. The hub tables of matrel_spmv_fill_ragged_hubs lie by
+// the hub table's row (rank / 128) into vector registers of 1,024 slots
+// (PR 42: a register names a short run of table rows and the hub kernel
+// walks those alone), and INSIDE each register by destination row, stable
+// (PR 51: the walk is indifferent to the order inside a register, the
+// reduction's scan is not), as the numpy path lays them: equal layouts
+// again. Sentinels: src = n_cols (hub rank = n_hubs), val = 0, and in the
+// chunks fills off = the off of the block's last real slot (0 in a block
+// with none; the blocks layout: 0), so that `off` never falls along a row
+// of 128 whatever it holds — a padded slot of off 0 behind real slots of
+// rows 0 and 5 would read to the scan as row 0's run going on.
 
 #include <algorithm>
 #include <cstdint>
 #include <cstring>
 #include <numeric>
 #include <vector>
+
+namespace {
+
+// The slots [p0, p0 + n) of up to four parallel tables (a, b 32-bit, c 8-bit
+// or null, d float) put by a key below `keys`, stable: per-key counts, their
+// prefix sum, one scatter walk from a scratch copy of the run.
+struct RunSort {
+    std::vector<int64_t> at;
+    std::vector<int32_t> t_a, t_b;
+    std::vector<int8_t> t_c;
+    std::vector<float> t_d;
+
+    // key_of_b: the key is table b's value (a destination row); else table
+    // a's value >> 7 (a hub table row)
+    void run(int64_t p0, int64_t n, int64_t keys, bool key_of_b,
+             int32_t* a, int32_t* b, int8_t* c, float* d) {
+        if (n < 2) return;
+        at.assign(keys + 1, 0);
+        t_a.assign(a + p0, a + p0 + n);
+        t_b.assign(b + p0, b + p0 + n);
+        if (c) t_c.assign(c + p0, c + p0 + n);
+        t_d.assign(d + p0, d + p0 + n);
+        const int32_t* k = key_of_b ? t_b.data() : t_a.data();
+        const int shift = key_of_b ? 0 : 7;
+        for (int64_t i = 0; i < n; ++i) at[(k[i] >> shift) + 1]++;
+        for (int64_t r = 0; r < keys; ++r) at[r + 1] += at[r];
+        for (int64_t i = 0; i < n; ++i) {
+            const int64_t p = p0 + at[k[i] >> shift]++;
+            a[p] = t_a[i];
+            b[p] = t_b[i];
+            if (c) c[p] = t_c[i];
+            d[p] = t_d[i];
+        }
+    }
+};
+
+// The padded slots [p0 + n, p1) of a block name the row of its last real
+// slot (see the header: `off` never falls along a row of 128).
+void pad_offs(int32_t* off, int64_t p0, int64_t n, int64_t p1) {
+    if (n > 0) std::fill(off + p0 + n, off + p1, off[p0 + n - 1]);
+}
+
+}  // namespace
 
 extern "C" {
 
@@ -118,12 +168,12 @@ int64_t matrel_spmv_fill(const int64_t* rows, const int64_t* cols,
 
 // The chunks layout's fill: block b owns the flat slots
 // [first[b], first[b+1]) (its chunks, laid one after the other), sized
-// by Python from the counts so that nothing overflows. Same sentinels as
-// matrel_spmv_fill; a block's entries lie by destination row, stable
-// inside a row. Two walks, both O(m): the scatter by block in input order
-// (938 write heads at the Netflix shape, where a head a row would be
-// 480,189 and miss the cache at every write), then a counting sort by
-// `off` inside each block through a scratch copy of its slots.
+// by Python from the counts so that nothing overflows. Sentinels as the
+// header says; a block's entries lie by destination row, stable inside a
+// row. Two walks, both O(m): the scatter by block in input order (938
+// write heads at the Netflix shape, where a head a row would be 480,189
+// and miss the cache at every write), then a counting sort by `off`
+// inside each block through a scratch copy of its slots (RunSort).
 // Returns 0, or -1 on an index out of range or a block past its slots.
 int matrel_spmv_fill_ragged(const int64_t* rows, const int64_t* cols,
                             const float* vals, int64_t m, int64_t n_cols,
@@ -157,29 +207,12 @@ int matrel_spmv_fill_ragged(const int64_t* rows, const int64_t* cols,
         val[p] = vals ? vals[e] : 1.0f;
     }
 
-    // a block's real slots, first[b] .. next[b], by row: per-row counts,
-    // their prefix sum, one scatter walk from the scratch copy
-    std::vector<int64_t> at(block + 1);
-    std::vector<int32_t> t_src, t_off;
-    std::vector<int8_t> t_lane;
-    std::vector<float> t_val;
+    // a block's real slots, first[b] .. next[b], by row
+    RunSort sort;
     for (int64_t b = 0; b < nb; ++b) {
         const int64_t p0 = first[b], n = next[b] - p0;
-        if (n < 2) continue;
-        std::fill(at.begin(), at.end(), 0);
-        for (int64_t i = 0; i < n; ++i) at[off[p0 + i] + 1]++;
-        for (int64_t r = 0; r < block; ++r) at[r + 1] += at[r];
-        t_src.assign(src8 + p0, src8 + p0 + n);
-        t_lane.assign(lane + p0, lane + p0 + n);
-        t_off.assign(off + p0, off + p0 + n);
-        t_val.assign(val + p0, val + p0 + n);
-        for (int64_t i = 0; i < n; ++i) {
-            const int64_t p = p0 + at[t_off[i]]++;
-            src8[p] = t_src[i];
-            lane[p] = t_lane[i];
-            off[p] = t_off[i];
-            val[p] = t_val[i];
-        }
+        sort.run(p0, n, block, true, src8, off, lane, val);
+        pad_offs(off, p0, n, first[b + 1]);
     }
     return 0;
 }
@@ -207,12 +240,14 @@ int matrel_spmv_counts_hubs(const int64_t* rows, const int64_t* cols,
 // source has a rank goes to the hub tables (block b owns their flat slots
 // [hub_first[b], hub_first[b+1]); hub_idx = the rank, n_hubs in padded
 // slots), every other to the main tables, sentinels as
-// matrel_spmv_fill_ragged's, a block's slots in input order. Then every
-// block's hub slots are put by table row (rank / 128) with a counting sort
-// through a scratch copy, as matrel_spmv_fill_ragged sorts by `off`: one
-// more walk over the hub slots, no sort of the edge list. Returns 0, or -1
-// on an index or a rank out of range or a block past its slots in either
-// set.
+// matrel_spmv_fill_ragged's, a block's slots in input order. Then, a block
+// at a time, counting sorts through a scratch copy (RunSort), no sort of
+// the edge list: the main slots by `off`, as matrel_spmv_fill_ragged's;
+// the hub slots by table row (rank / 128), which shares them out among
+// registers of 1,024, and each register's by `off` (512 keys for at most
+// 1,024 slots), its set of slots — and so the walk hub_walks reckons for it
+// — what the sort by table row made it. Returns 0, or -1 on an index or a
+// rank out of range or a block past its slots in either set.
 int matrel_spmv_fill_ragged_hubs(const int64_t* rows, const int64_t* cols,
                                  const float* vals, int64_t m,
                                  int64_t n_cols, int64_t block, int64_t nb,
@@ -262,26 +297,23 @@ int matrel_spmv_fill_ragged_hubs(const int64_t* rows, const int64_t* cols,
         }
     }
 
-    // a block's real hub slots, hub_first[b] .. hub_next[b], by table row
+    // a block's main slots by row, as matrel_spmv_fill_ragged lays them;
+    // its real hub slots, hub_first[b] .. hub_next[b], by table row, and
+    // then every register of 1,024 of them by destination row
     const int64_t table_rows = (static_cast<int64_t>(n_hubs) + 127) / 128;
-    std::vector<int64_t> at(table_rows + 1);
-    std::vector<int32_t> t_idx, t_off;
-    std::vector<float> t_val;
+    const int64_t reg = 1024;
+    RunSort sort;
     for (int64_t b = 0; b < nb; ++b) {
+        const int64_t m0 = first[b], mn = next[b] - m0;
+        sort.run(m0, mn, block, true, src8, off, lane, val);
+        pad_offs(off, m0, mn, first[b + 1]);
         const int64_t p0 = hub_first[b], n = hub_next[b] - p0;
-        if (n < 2) continue;
-        std::fill(at.begin(), at.end(), 0);
-        for (int64_t i = 0; i < n; ++i) at[(hub_idx[p0 + i] >> 7) + 1]++;
-        for (int64_t r = 0; r < table_rows; ++r) at[r + 1] += at[r];
-        t_idx.assign(hub_idx + p0, hub_idx + p0 + n);
-        t_off.assign(hub_off + p0, hub_off + p0 + n);
-        t_val.assign(hub_val + p0, hub_val + p0 + n);
-        for (int64_t i = 0; i < n; ++i) {
-            const int64_t p = p0 + at[t_idx[i] >> 7]++;
-            hub_idx[p] = t_idx[i];
-            hub_off[p] = t_off[i];
-            hub_val[p] = t_val[i];
-        }
+        sort.run(p0, n, table_rows, false, hub_idx, hub_off, nullptr,
+                 hub_val);
+        for (int64_t r0 = 0; r0 < n; r0 += reg)
+            sort.run(p0 + r0, std::min(reg, n - r0), block, true, hub_idx,
+                     hub_off, nullptr, hub_val);
+        pad_offs(hub_off, p0, n, hub_first[b + 1]);
     }
     return 0;
 }

@@ -500,23 +500,26 @@ def test_semiring_round_reduces_in_the_chunk_grid(one_chip, reduce, chunks):
 G500_MAIN_CHUNKS, G500_HUB_CHUNKS, G500_HUBS = 11_179, 56_310, 286_720
 
 
-def _g500_hub_loop(one_chip):
-    from matrel_tpu.workloads import pagerank
-
+def _g500_hub_tables(one_chip):
+    """``compact_tables`` of that plan, as shapes: eleven of them."""
     def chunks(n, *dtypes):
         shp = (n, spmv_lib.CHUNK // pc.LANE, pc.LANE)
         return tuple(_sds(one_chip, shp, dt) for dt in dtypes) + (
             _sds(one_chip, (n,), jnp.int32),)            # chunk -> block
 
-    tables = chunks(G500_MAIN_CHUNKS, jnp.int32, jnp.int8, jnp.int32,
-                    jnp.float32) + (
+    return chunks(G500_MAIN_CHUNKS, jnp.int32, jnp.int8, jnp.int32,
+                  jnp.float32) + (
         _sds(one_chip, (G500_HUBS,), jnp.int32),) + chunks(
         G500_HUB_CHUNKS, jnp.int32, jnp.int32, jnp.float32) + (
         _sds(one_chip, (G500_HUB_CHUNKS,), jnp.int32),)          # walks
+
+
+def _g500_hub_loop(one_chip):
+    from matrel_tpu.workloads import pagerank
     static = (G500_NODES, G500_NODES, BLOCK, spmv_lib.LO)
     loop = pagerank._compact_runner_loop(G500_NODES, 10, 0.85, static, 0, 3,
                                          False)
-    return _compile(loop, tables, (),
+    return _compile(loop, _g500_hub_tables(one_chip), (),
                     _sds(one_chip, (G500_NODES,), jnp.float32))
 
 
@@ -545,6 +548,37 @@ def test_chunked_pagerank_loop_with_a_hub_table(one_chip):
         G500_MAIN_CHUNKS * spmv_lib.CHUNK) - pc.HUB_BYTES_A_SLOT * hub_slots
     taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
     assert 0.9 * reckoned < taken < 1.05 * reckoned, (reckoned, taken)
+
+
+def test_semiring_round_with_a_hub_table(one_chip):
+    """Cell wcc_g500_22_1c's product since PR 51: the (max, x) reduction
+    over the plan cell 6 runs — 11,179 main chunks through the row
+    gather in 2 panels and ``matrel_spmv_reduce_chunks``, 56,310 hub
+    chunks through ``matrel_spmv_reduce_hubs`` (the walk of the (2240,
+    128) table and the segmented scan in one body, compiled by Mosaic
+    at the cell's size) — no sum scatter, and arguments and temporaries
+    what ``plan_bytes`` reckons with 12 B a hub slot."""
+    static = (G500_NODES, G500_NODES, BLOCK, spmv_lib.LO)
+    compiled = _compile(
+        jax.jit(pc.reduce_apply, static_argnums=(0, 3, 4)), static,
+        _g500_hub_tables(one_chip),
+        _sds(one_chip, (G500_NODES,), jnp.float32), "max", False)
+    text = compiled.as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 2
+    assert "matrel_spmv_reduce_chunks" in text
+    assert "matrel_spmv_reduce_hubs" in text
+    assert "matrel_spmv_scatter" not in text
+    per = pc.panel_rows(G500_MAIN_CHUNKS, spmv_lib.CHUNK)
+    assert -(-G500_MAIN_CHUNKS // per) == 2 and per % 64 == 0
+    assert f"u8[{per * spmv_lib.CHUNK},32]" in text
+    assert f"f32[{G500_HUBS // pc.LANE},{pc.LANE}]" in text  # the hub table
+    stats = compiled.memory_analysis()
+    hub_slots = G500_HUB_CHUNKS * spmv_lib.CHUNK
+    reckoned = pc.plan_bytes(G500_MAIN_CHUNKS, spmv_lib.CHUNK, hub_slots)
+    taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 0.9 * reckoned < taken < 1.05 * reckoned, (reckoned, taken)
+    # a quarter less than the plan without hub chunks held (PR 50)
+    assert reckoned < 0.75 * pc.plan_bytes(G500_CHUNKS, spmv_lib.CHUNK)
 
 
 def test_the_most_hub_chunks_the_rule_allows_fit_smem(one_chip):

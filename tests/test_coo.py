@@ -628,6 +628,10 @@ class TestWidePlans:
         # no room for a slab: at this length a line pays from 10 entries
         # on (TestDenseLines gives the dense part room)
         monkeypatch.setattr(coo_lib, "_DENSE_SHARE", 0.0)
+        # and a hub table's row pays from 10,000 entries on: at this
+        # scale 128 flat sources hold 4,000, and the rule's own price
+        # (PR 51: an orientation's own plan asks it) would make each a hub
+        monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 10_000)
         yield
         config_lib._default_config = was
 
@@ -652,14 +656,20 @@ class TestWidePlans:
         monkeypatch.undo()
         assert coo_lib._plan_layout() == "blocks"
 
-    def test_a_matrix_of_skewed_sources_has_no_hub_chunks(self, rng,
-                                                          on_one_chip):
-        """A COOMatrix declines hub chunks whatever its columns' degrees
-        (PR 36), so PR 42's order of hub slots never reaches it: a
-        block's slots lie by destination row (PR 38), five tables go to
-        the device, and the k-wide product lowers to its one kernel."""
+    @pytest.mark.parametrize("first", ["wide", "own"])
+    def test_skewed_sources_hub_chunks_stay_with_the_own_plan(self, rng,
+                                                              on_one_chip,
+                                                              first):
+        """An orientation's own plan — the matvec's, the (max | min)
+        reduction's — has hub chunks where the sources are skewed (PR
+        51: the plan PageRank would build); the k-wide product, which
+        gains nothing from them, runs one of its own without, whichever
+        is asked for first: two builds, and neither twice. Its plan's
+        slots lie by destination row (PR 38), five tables go to the
+        device, and it lowers to its one kernel."""
         import jax
         import jax.numpy as jnp
+        from matrel_tpu.core import coo as coo_lib
         from matrel_tpu.ops import pallas_spmv as pc
         from matrel_tpu.ops import spmv as spmv_lib
         m, shape = 60_000, (2600, 900)
@@ -668,10 +678,21 @@ class TestWidePlans:
                         rng.integers(0, shape[1], m))   # 40 hot columns
         vals = rng.standard_normal(m).astype(np.float32)
         A = COOMatrix.from_edges(rows, cols, vals, shape=shape)
-        plan = A._get_plan()
-        # the same edges through the door PageRank uses do get hubs
-        assert spmv_lib.build_spmv_plan(rows, cols, vals, *shape,
-                                        layout="chunks").hubs is not None
+        builds = coo_lib.plan_builds()
+        if first == "wide":
+            plan = A._get_wide_plan()
+            assert coo_lib.plan_builds() == builds + 1
+            own = A._get_plan()
+        else:
+            own = A._get_plan()
+            assert coo_lib.plan_builds() == builds + 1
+            plan = A._get_wide_plan()
+        assert coo_lib.plan_builds() == builds + 2
+        assert A._get_wide_plan() is plan and A._get_plan() is own
+        assert coo_lib.plan_builds() == builds + 2
+        assert own.hubs is not None and own.hubs.ids.size == 128
+        assert len(pc.compact_tables(own)) == 11
+        assert spmv_lib.rows_in_order(own)
         assert plan.hubs is None and plan.chunk_block is not None
         assert len(pc.compact_tables(plan)) == 5
         real = plan.val != 0
@@ -687,12 +708,28 @@ class TestWidePlans:
         ).lower(lowering_platforms=("tpu",)).as_text()
         assert "matrel_spmm_scatter_chunks" in text
         assert "matrel_spmv_scatter_hubs" not in text
+        # both answer: the matvec off the hub table, the product without
+        x = rng.standard_normal((shape[1], 3)).astype(np.float32)
+        want = A.to_dense() @ x
+        got = np.asarray(A.matmat(jnp.asarray(x)))
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-4)
+        np.testing.assert_allclose(np.asarray(A.matvec(x[:, 0])),
+                                   want[:, 0], rtol=2e-5, atol=2e-4)
 
+    @pytest.mark.parametrize("first", ["wide", "own"])
     def test_one_table_is_one_plan_shared_with_the_matvec(self, rng,
-                                                          on_one_chip):
+                                                          on_one_chip,
+                                                          first):
+        """Flat sources: the rule takes no hub, and one build serves
+        both, whichever asks first."""
+        from matrel_tpu.core import coo as coo_lib
         A = self._skewed(rng)
+        builds = coo_lib.plan_builds()
+        if first == "own":
+            A._get_plan(), A._get_plan_t()
         assert A._get_wide_plan() is A._get_plan()
         assert A._get_wide_plan(transposed=True) is A._get_plan_t()
+        assert coo_lib.plan_builds() == builds + 2
 
     def test_source_panels_where_the_table_is_too_tall(self, rng,
                                                        on_one_chip,

@@ -1209,8 +1209,9 @@ class TestProfilerTier:
 
     def test_all_pallas_call_sites_were_found(self):
         # PR 36: the hub scatter; PR 44: less the routed SpMV's two;
-        # PR 47: the sampled scatter; PR 50: the chunk grid's reduction
-        assert len(_PALLAS_SITES) == 10
+        # PR 47: the sampled scatter; PR 50: the chunk grid's reduction;
+        # PR 51: the hub chunks' reduction
+        assert len(_PALLAS_SITES) == 11
 
 
 class TestAnalyzeEvent:

@@ -268,17 +268,14 @@ def test_native_and_numpy_fills_agree_on_chunks(graphs, monkeypatch, rng,
     ref = spmv_lib.build_spmv_plan(dst, src, vals, v, v, layout="chunks")
     np.testing.assert_array_equal(nat.chunk_block, ref.chunk_block)
     assert nat.src8.shape == ref.src8.shape
-    # slot order within a block: the main chunks beside hub chunks keep
-    # input order (native) and lie row-sorted (numpy): the same slots
-    # are real, and each block holds the same edges, as the same matvec
-    # shows; without hub chunks (PR 38) both lie by row, the same
-    # tables; and the hub chunks lie by table row, stable inside one,
-    # in both (PR 42): the same tables and the same walks
-    np.testing.assert_array_equal((nat.val != 0).sum(1), (ref.val != 0).sum(1))
-    if not hub_rows:
-        for name in ("src8", "lane", "off", "val"):
-            np.testing.assert_array_equal(getattr(nat, name),
-                                          getattr(ref, name), err_msg=name)
+    # slot order within a block: the main chunks lie by row in both,
+    # beside hub chunks too (PR 38; PR 51): the same tables; and the hub
+    # chunks lie by table row into registers (PR 42) and by row inside a
+    # register (PR 51), stable, in both: the same tables and the same
+    # walks
+    for name in ("src8", "lane", "off", "val"):
+        np.testing.assert_array_equal(getattr(nat, name),
+                                      getattr(ref, name), err_msg=name)
     assert (nat.hubs is not None) == (ref.hubs is not None) == bool(hub_rows)
     if hub_rows:        # the library splits the edges in one walk, numpy
         # takes each set out of the list: the same hubs, chunks and slots

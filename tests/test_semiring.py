@@ -6,7 +6,9 @@ query (negative values, empty rows, a full row, skewed degrees, a
 repeated cell), the chunk grid's reduction kernel against XLA's segment
 reduction, weakly connected components through ``session.sql`` +
 ``compute`` against scipy, what ``last_plan()`` says, the cap an
-un-matched join still meets, and the programs it must leave alone."""
+un-matched join still meets, and the programs it must leave alone.
+Since PR 51 also over plans WITH hub chunks (``matrel_spmv_reduce_hubs``:
+the slots of skewed sources take their value from the hub table)."""
 
 import numpy as np
 import pytest
@@ -37,14 +39,36 @@ def _session(config=None):
 def one_chip(monkeypatch):
     """What the chip is to a COOMatrix (tests/test_sampled.py's
     fixture): the compact Pallas executors of one device, interpreted,
-    and plans in chunks whatever their size."""
+    and plans in chunks whatever their size — without hub chunks (at
+    this scale the rule would make every source a hub; the cases that
+    want some ask :func:`_with_hubs`)."""
     cfg = MatrelConfig(pallas_interpret=True, cse_enable=True)
     was = config_lib._default_config
     config_lib.set_default_config(cfg)
     monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")
     monkeypatch.setattr(spmv_lib, "_SMALL_PLAN_SLOTS", 0)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", 0)
     yield cfg
     config_lib._default_config = was
+
+
+HUB_KINDS = ("hubs", "hubs-one-step")
+
+
+def _with_hubs(monkeypatch, kind):
+    """The build's rule as it takes rows at this scale where ``kind`` is
+    one of ``HUB_KINDS``: a hub table of 12 rows walked in steps of 8
+    (two steps tall), or of 2 rows in the code's own step of 64,
+    every row taken that holds a source."""
+    if kind not in HUB_KINDS:
+        return
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES_A_BLOCK", 0.0)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 0)
+    if kind == "hubs":
+        monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", 12)
+        monkeypatch.setattr(spmv_lib, "HUB_WALK", 8)
+    else:
+        monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", 2)
 
 
 # -- the rule -------------------------------------------------------------------
@@ -120,13 +144,60 @@ def test_the_node_refuses_what_it_cannot_mean(rng):
 # -- fused = the dense lowering ------------------------------------------------
 
 
+def _hub_matrix(rng, kind):
+    """(rows, cols, n, m) of a matrix whose SOURCES are skewed, for a
+    plan with hub chunks (``_with_hubs``: the table holds the 1,536 — or
+    256 — columns of most entries, which are the first, two hundred of
+    them heavier still): four blocks of rows. Block 0 holds most
+    entries, from hubs and others alike, so its hub slots fill several
+    registers whose runs of table rows differ, and most of its rows hold
+    entries of both sets; its rows 3 to 40 only hubs reach, row 41 only
+    others. Block 1 holds 300 hub entries (one register of its one hub
+    chunk is all padding) and none other. Block 2 holds no hub entry, so
+    it owns no hub chunk, and block 3 three entries, of rows 0, 0 and 5
+    (its chunk's padding lies behind them in one row of 128 slots)."""
+    hubs, least = (1536, 8) if kind == "hubs" else (256, 40)
+    others = 2_500
+    n, m = 512 * 3 + 90, hubs + others
+    heavy = np.arange(hubs) < 200
+    of_hubs = np.repeat(np.arange(hubs),
+                        least + heavy * rng.integers(5, 30, hubs))
+    of_others = np.repeat(hubs + np.arange(others),
+                          rng.integers(1, 4, others))
+    rng.shuffle(of_hubs), rng.shuffle(of_others)
+    to_hubs = rng.integers(0, 512, of_hubs.size)
+    to_hubs[to_hubs == 41] = 42
+    to_hubs[:300] += 512                                  # block 1
+    to_others = rng.integers(0, 512, of_others.size)
+    to_others[(to_others >= 3) & (to_others <= 40)] = 41
+    to_others[::2] += 1024                                # block 2
+    # column 2 is a hub, the last is none
+    rows = np.concatenate([to_hubs, to_others, [1536, 1536, 1541]])
+    cols = np.concatenate([of_hubs, of_others, [2, m - 1, 7]])
+    return rows, cols, n, m
+
+
 def _matrix(rng, kind, n=700, m=600):
     """Seeded COO matrices as the issue lists them: values of both
     signs, rows with no entry, and by ``kind`` a full row (every
-    column), skewed degrees (a third of the entries in four rows) or a
-    cell listed twice."""
+    column), skewed degrees (a third of the entries in four rows), a
+    cell listed twice, skewed SOURCES (``HUB_KINDS``:
+    :func:`_hub_matrix`) or a block of three entries (PR 51: rows 0 and
+    5 of a block and padding behind them in one row of 128 slots)."""
+    if kind in HUB_KINDS:
+        rows, cols, n, m = _hub_matrix(rng, kind)
+        keys = np.unique(rows.astype(np.int64) * m + cols)
+        order = rng.permutation(keys.size)
+        return COOMatrix.from_edges(
+            (keys // m)[order], (keys % m)[order],
+            rng.normal(size=keys.size).astype(np.float32), shape=(n, m))
     at = rng.choice(n * m, min(9_000, n * m // 4), replace=False)
     rows, cols = at // m, at % m
+    if kind == "short-block":
+        last = rows >= 512
+        rows, cols = rows[~last], cols[~last]
+        rows = np.append(rows, [512, 512, 517])
+        cols = np.append(cols, [3, 90, 11])
     if kind == "skewed":
         rows[:3_000] = rng.integers(0, 4, 3_000) * 97
     empty = rng.choice(n, n // 17, replace=False)
@@ -154,15 +225,42 @@ def _oracle(A: COOMatrix, x, reduce):
     return (prod.max if reduce == "max" else prod.min)(axis=1)
 
 
-KINDS = ["plain", "full-row", "skewed", "repeated-cell"]
+KINDS = ["plain", "full-row", "skewed", "repeated-cell", "short-block",
+         *HUB_KINDS]
+
+
+def _assert_both_sets_busy(plan):
+    """The plan of a ``HUB_KINDS`` matrix is what its docstring says."""
+    hub = plan.hubs
+    real = hub.idx < hub.ids.size
+    assert hub is not None and real.sum() == hub.entries > 4_000
+    assert (plan.val != 0).sum() > 4_000
+    # blocks 0 and 1 own hub chunks, 2 none, 3 one of one real slot
+    assert sorted(set(hub.chunk_block)) == [0, 1, 3]
+    assert (hub.chunk_block == 0).sum() >= 3
+    # where the table is several walk steps tall, runs that differ
+    assert len({tuple(w) for w in zip(hub.first.ravel(), hub.rows.ravel())}
+               ) > (spmv_lib.HUB_WALK < 64)
+    # a register that is all padding, and runs that differ
+    regs = real.reshape(-1, spmv_lib.HUB_REG)
+    assert (~regs.any(axis=1)).any()
+    of_rows = np.asarray(plan.off)[np.asarray(plan.chunk_block) == 0]
+    main_rows = set(of_rows[np.asarray(plan.val)[
+        np.asarray(plan.chunk_block) == 0] != 0])
+    hub_rows = set(hub.off[hub.chunk_block == 0][real[hub.chunk_block == 0]])
+    assert len(main_rows & hub_rows) > 300       # rows of both sets
+    assert set(range(3, 41)) <= hub_rows - main_rows     # hubs alone
+    assert 41 in main_rows - hub_rows
 
 
 @pytest.mark.parametrize("reduce", ["max", "min"])
 @pytest.mark.parametrize("kind", KINDS)
-def test_fused_is_the_dense_lowering(rng, one_chip, kind, reduce):
+def test_fused_is_the_dense_lowering(rng, one_chip, monkeypatch, kind,
+                                     reduce):
     """The same SQL through the rule (fused, the kernel) and with the
     rule batch off (the join materialised, the leaf densified): equal
     to the last bit, for labels of both signs."""
+    _with_hubs(monkeypatch, kind)
     A = _matrix(rng, kind)
     x = rng.normal(size=(A.shape[1], 1)).astype(np.float32)
     sql = f'row{reduce}(joincols(A, t(x), "mul"))'
@@ -181,6 +279,17 @@ def test_fused_is_the_dense_lowering(rng, one_chip, kind, reduce):
             assert rec["full_rows"] == (kind == "full-row")
             assert not said["densified_products"]
             assert said["executors"] == ["pallas_spmv"]
+            assert bool(rec["hub_chunks"]) == (kind in HUB_KINDS)
+            if kind in HUB_KINDS:
+                assert 0.5 < rec["hub_entry_share"] < 0.8
+                assert rec["hub_slots"] == rec["hub_chunks"] * spmv_lib.CHUNK
+                assert rec["slots"] == rec["hub_slots"] \
+                    + rec["chunks"] * spmv_lib.CHUNK
+                assert rec["hub_walk_rows"] >= 2 * rec["hub_chunks"] \
+                    * spmv_lib.HUB_WALK
+            else:
+                assert rec["hub_slots"] == rec["hub_walk_rows"] == 0
+                assert rec["hub_entry_share"] == 0.0
         else:
             assert not said["semiring"] and said["densified_products"]
     np.testing.assert_array_equal(got["fused"], got["dense"])
@@ -197,12 +306,18 @@ def test_fused_is_the_dense_lowering(rng, one_chip, kind, reduce):
 
 @pytest.mark.parametrize("reduce", ["max", "min"])
 @pytest.mark.parametrize("kind", KINDS)
-def test_the_kernel_is_the_xla_fallback(rng, one_chip, kind, reduce):
+def test_the_kernel_is_the_xla_fallback(rng, one_chip, monkeypatch, kind,
+                                        reduce):
     import jax.numpy as jnp
+    _with_hubs(monkeypatch, kind)
     A = _matrix(rng, kind).entry_view()
     x = jnp.asarray(rng.normal(size=A.shape[1]).astype(np.float32))
     plan = A._get_plan()
     assert spmv_lib.rows_in_order(plan)
+    if kind in HUB_KINDS:
+        _assert_both_sets_busy(plan)
+    else:
+        assert plan.hubs is None
     kernel = coo_lib.semiring_apply(A, plan, x, reduce, interpret=True)
     xla = coo_lib.semiring_apply(A, None, x, reduce)
     np.testing.assert_array_equal(np.asarray(kernel), np.asarray(xla))
@@ -236,25 +351,69 @@ def test_on_a_mesh_the_column_is_replicated(rng, mesh8):
     assert s.last_plan()["semiring"][0]["how"] == "xla"
 
 
-@pytest.mark.parametrize("why", ["hub-chunks", "input-order", "blocks"])
-def test_a_plan_the_kernel_cannot_read_is_not_handed_to_it(rng, why):
-    """The segmented scan takes a block's slots in row order for
-    granted; the dispatch asks the tables themselves."""
+@pytest.mark.parametrize("why", ["hub-chunks", "input-order", "blocks",
+                                 "hub-registers-by-table-row",
+                                 "padding-behind-two-rows"])
+def test_a_plan_the_kernel_cannot_read_is_not_handed_to_it(rng, why,
+                                                           monkeypatch):
+    """The segmented scan takes for granted that in a row of 128 slots
+    those of one destination row lie side by side; the dispatch asks the
+    tables themselves, the hub chunks' too."""
     rows = rng.integers(0, 1024, 40_000)
     cols = rng.integers(0, 900, rows.size)
     if why == "blocks":
         plan = spmv_lib.build_spmv_plan(rows, cols, None, 1024, 900)
-    else:
+    elif why in ("input-order", "padding-behind-two-rows"):
         plan = spmv_lib.build_spmv_plan(rows, cols, None, 1024, 900,
                                         layout="chunks", hubs=False)
         assert spmv_lib.rows_in_order(plan)
         del plan._rows_in_order
-        if why == "hub-chunks":
-            plan.hubs = object()
-        else:
-            flat = np.asarray(plan.off).reshape(-1)
+        flat = np.asarray(plan.off).reshape(-1).copy()
+        if why == "input-order":
             flat[[3, 4]] = flat[[4000, 3]]
-            plan.off = flat.reshape(np.asarray(plan.off).shape)
+        else:
+            # what the fills laid until PR 51: a padded slot's ``off`` 0,
+            # here behind real slots of other rows, which the scan would
+            # take for row 0's run going on (and add to it: the one-hot
+            # places every run's end, and this would be a second for
+            # row 0)
+            pad = np.asarray(plan.val).reshape(-1) == 0
+            assert pad.any() and flat[pad].min() > 0
+            flat[pad] = 0
+        plan.off = flat.reshape(np.asarray(plan.off).shape)
+    else:
+        monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", 2)
+        monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES_A_BLOCK", 0.0)
+        monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 0)
+        plan = spmv_lib.build_spmv_plan(rows, cols, None, 1024, 900,
+                                        layout="chunks")
+        hub = plan.hubs
+        assert hub.ids.size == 256 and spmv_lib.rows_in_order(plan)
+        del plan._rows_in_order
+        real = hub.idx < hub.ids.size
+        if why == "hub-chunks":
+            # hub chunks are no reason by themselves (until PR 51 they
+            # were); one slot out of its row's run is
+            flat = hub.off.reshape(-1).copy()
+            flat[[3, 4]] = flat[[900, 3]]
+            assert flat[3] != flat[5]
+            hub.off = flat.reshape(hub.off.shape)
+        else:
+            # a plan file of PRs 42 to 50: a block's hub slots by table
+            # row alone, in input order inside one
+            for b in np.unique(hub.chunk_block):
+                at = hub.chunk_block == b
+                n = int(real[at].sum())
+                order = np.argsort(hub.idx[at].ravel()[:n] >> 7,
+                                   kind="stable")
+                for t in (hub.idx, hub.off, hub.val):
+                    flat = t[at].ravel()
+                    flat[:n] = flat[:n][order]
+                    t[at] = flat.reshape(-1, spmv_lib.CHUNK)
+            # the same registers hold the same slots: the same walks
+            first, walked = spmv_lib.hub_walks(hub.idx, hub.ids.size)
+            np.testing.assert_array_equal(first, hub.first)
+            np.testing.assert_array_equal(walked, hub.rows)
     assert not spmv_lib.rows_in_order(plan)
 
 
@@ -288,9 +447,14 @@ def _scipy_labels(rows, cols, n):
     return count, top[comp]
 
 
-def test_wcc_through_sql_is_scipys_components(rng, one_chip, monkeypatch):
+@pytest.mark.parametrize("kind", ["plain", "hubs-one-step"])
+def test_wcc_through_sql_is_scipys_components(rng, one_chip, monkeypatch,
+                                              kind):
+    """``kind``: without hub chunks, and with the 256 vertices of most
+    edges in a hub table (the cell's plan since PR 51)."""
     # four even blocks: "auto" would keep the blocks layout
     monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "chunks")
+    _with_hubs(monkeypatch, kind)
     rows, cols, n = _graph(rng)
     count, want = _scipy_labels(rows, cols, n)
     assert count > 12                       # the pair, the lone vertices
@@ -308,6 +472,7 @@ def test_wcc_through_sql_is_scipys_components(rng, one_chip, monkeypatch):
         (rec,) = said["semiring"]
         assert rec["how"] == "kernel" and rec["full_rows"] == 0
         assert not said["densified_products"]
+        assert bool(rec["hub_chunks"]) == (kind in HUB_KINDS)
         s.register("Lnew", new)
         changed = s.compute(s.sql("count(Lnew - L)")).to_numpy()[0, 0]
         L = new

@@ -975,6 +975,45 @@ def kronecker_13():
     return g500.directed_in_seed_order(lo, hi, 36) + (v,)
 
 
+def _assert_hub_slots_in_order(hub):
+    """A block's real hub slots lie by table row INTO registers of
+    ``HUB_REG`` (no register names a row below one an earlier register
+    of its block names), inside a register by destination row (PR 51),
+    the padding after them with the last real slot's ``off``."""
+    n = hub.ids.size
+    for b in np.unique(hub.chunk_block):
+        at = hub.chunk_block == b
+        idx, off = hub.idx[at].reshape(-1), hub.off[at].reshape(-1)
+        real = idx < n
+        k = int(real.sum())
+        assert real[:k].all(), "padding lies at the block's end"
+        if k:
+            np.testing.assert_array_equal(off[k:], off[k - 1])
+        regs = [slice(r, min(r + spmv_lib.HUB_REG, k))
+                for r in range(0, k, spmv_lib.HUB_REG)]
+        for before, after in zip(regs, regs[1:]):
+            assert (idx[before] >> 7).max() <= (idx[after] >> 7).min()
+        for reg in regs:
+            assert (np.diff(off[reg]) >= 0).all()
+            # stable: a row's slots by table row
+            key = off[reg].astype(np.int64) * (n >> 7) + (idx[reg] >> 7)
+            assert (np.diff(key) >= 0).all()
+
+
+def _hub_walks_before_pr51(plan):
+    """``hub_walks`` of the layout PRs 42 to 50 built of the same plan:
+    a block's hub slots by table row alone."""
+    hub = plan.hubs
+    idx = hub.idx.copy()
+    for b in np.unique(hub.chunk_block):
+        at = hub.chunk_block == b
+        flat = idx[at].reshape(-1)
+        k = int((flat < hub.ids.size).sum())
+        flat[:k] = flat[:k][np.argsort(flat[:k] >> 7, kind="stable")]
+        idx[at] = flat.reshape(-1, spmv_lib.CHUNK)
+    return spmv_lib.hub_walks(idx, hub.ids.size)
+
+
 def _assert_walks_cover(hub):
     """Every register's recorded run of table rows starts on a tile, is
     whole walk steps (one at the least: a register of padding walks the
@@ -1032,10 +1071,8 @@ def test_skewed_matvec_with_and_without_hubs(kronecker_13, monkeypatch, rng,
         hub_edges, hub_slots = int((hub.val != 0).sum()), hub.val.size
         assert np.isin(src, hub.ids).sum() == hub_edges
         _assert_walks_cover(hub)
-        # a block's real slots lie by table row, the padding after them
-        for b in np.unique(hub.chunk_block):
-            mine = hub.idx[hub.chunk_block == b].reshape(-1)
-            assert (np.diff(mine >> 7) >= 0).all()
+        _assert_hub_slots_in_order(hub)
+        assert spmv_lib.rows_in_order(plan)
         # and so its registers share the table out: they walk little
         # more than the table once a block, not once a register
         blocks = np.unique(hub.chunk_block).size
@@ -1451,13 +1488,23 @@ def _native_or_skip():
     (1300, 64, 2048 * 3),       # a block's entries fill its chunks whole
 ], ids=["hub_block", "two_blocks", "one_chunk", "empty", "no_padding"])
 @pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("hub_rows", [0, 2], ids=["no_hubs", "two_hub_rows"])
 def test_the_ragged_fills_agree_slot_for_slot_in_row_order(rng, monkeypatch,
-                                                          shape, weighted):
-    """The chunks layout without hubs: the library's fill and numpy's lay
-    the same tables, and a block's real slots lie by row, a row's in
-    input order."""
+                                                          shape, weighted,
+                                                          hub_rows):
+    """The chunks layout, without hub chunks and (PR 51) beside them: the
+    library's fill and numpy's lay the same tables, a block's real main
+    slots lie by row, a row's in input order, its hub slots by table row
+    into registers and by row inside one; the reduction may read either
+    build, the walks are what the layout of PRs 42 to 50 gave the same
+    edges, and they still ride in one word a chunk."""
+    from matrel_tpu.ops import pallas_spmv as pc
     native = _native_or_skip()
     n_rows, n_cols, m = shape
+    monkeypatch.setattr(spmv_lib, "_HUB_ROWS_MAX", hub_rows)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES_A_BLOCK", 0.0)
+    monkeypatch.setattr(spmv_lib, "_HUB_ROW_EDGES", 0)
+    monkeypatch.setattr(spmv_lib, "_HUB_MIN_SHARE", 0.0)
     if n_rows == 3000:
         rows, cols, vals = _skewed_entries(rng, n_rows, n_cols, m)
     else:
@@ -1467,24 +1514,46 @@ def test_the_ragged_fills_agree_slot_for_slot_in_row_order(rng, monkeypatch,
         rows = np.repeat(np.arange(3) * 512, 2048) + rng.integers(0, 200, m)
     vals = vals if weighted else None
     nat = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
-                                   layout="chunks", hubs=False)
+                                   layout="chunks")
     monkeypatch.setattr(native, "spmv_counts", lambda *a: None)
     ref = spmv_lib.build_spmv_plan(rows, cols, vals, n_rows, n_cols,
-                                   layout="chunks", hubs=False)
+                                   layout="chunks")
     for name in ("src8", "lane", "off", "val", "chunk_block"):
         np.testing.assert_array_equal(getattr(nat, name), getattr(ref, name),
                                       err_msg=name)
+    assert (nat.hubs is not None) == (ref.hubs is not None) \
+        == bool(hub_rows and m)
+    is_hub = np.zeros(m, bool)
+    if nat.hubs is not None:
+        for name in ("ids", "idx", "off", "val", "chunk_block", "first",
+                     "rows"):
+            np.testing.assert_array_equal(
+                getattr(nat.hubs, name), getattr(ref.hubs, name),
+                err_msg="hubs." + name)
+        assert nat.hubs.entries == ref.hubs.entries
+        _assert_hub_slots_in_order(nat.hubs)
+        _assert_walks_cover(nat.hubs)
+        first, walked = _hub_walks_before_pr51(nat)
+        np.testing.assert_array_equal(nat.hubs.first, first)
+        np.testing.assert_array_equal(nat.hubs.rows, walked)
+        assert pc._pack_walks(first, walked).shape == (
+            nat.hubs.idx.shape[0],)
+        is_hub = np.isin(cols, nat.hubs.ids[:min(n_cols, nat.hubs.ids.size)])
+        assert nat.hubs.entries == is_hub.sum()
+    assert spmv_lib.rows_in_order(nat) and spmv_lib.rows_in_order(ref)
     # by row over the real slots of every block, and stable: the columns
-    # of one row come in the order the entries were given
+    # of one row come in the order the entries were given; a padded
+    # slot names its block's last real row (PR 51)
     src = nat.src8.astype(np.int64) * 8 + nat.lane
     real = src != n_cols
-    assert real.sum() == m
+    assert real.sum() == m - is_hub.sum()
     for b in np.unique(nat.chunk_block):
         at = nat.chunk_block == b
         off, s, r = nat.off[at].ravel(), src[at].ravel(), real[at].ravel()
         assert r[:r.sum()].all(), "padding lies at the block's end"
         assert (np.diff(off[r]) >= 0).all()
-        given = rows // 512 == b
+        np.testing.assert_array_equal(off[~r], off[r][-1] if r.any() else 0)
+        given = (rows // 512 == b) & ~is_hub
         order = np.argsort(rows[given], kind="stable")
         np.testing.assert_array_equal(s[r], cols[given][order])
 
