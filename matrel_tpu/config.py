@@ -1169,8 +1169,12 @@ def configure_compile_cache() -> str:
     cache is ``<checkout>/.jax_cache`` — a fixed path (the path is part
     of the cache key, so a directory that moves never hits). Called by
     MatrelSession.__init__ and by the root scripts before their first
-    compile; idempotent."""
+    compile; idempotent. From the first call on the process also hears
+    what jax says of its compiles and of this cache's hits and misses
+    (obs.trace.hear_jax: the cold ring's ``jit.*`` records)."""
     import jax
+    from matrel_tpu.obs import trace as trace_lib
+    trace_lib.hear_jax()
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = os.path.join(

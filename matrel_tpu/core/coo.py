@@ -80,9 +80,11 @@ def plan_builds() -> int:
 
 
 def _counted_build(build, **said):
-    """``build()`` under its ``matrel.spmm.plan.build`` span, counted."""
+    """``build()`` under its ``matrel.spmm.plan.build`` span (cold: it
+    is in ``cold_spans()`` whether or not a session runs, with what
+    ``build_spmv_plan`` tallies of its parts), counted."""
     global _PLAN_BUILDS
-    with trace_lib.span("spmm.plan.build", **said):
+    with trace_lib.phase("spmm.plan.build", **said):
         plan = build()
     _PLAN_BUILDS += 1
     return plan
@@ -162,18 +164,21 @@ class DenseLines:
         that), so it does unless a cell is listed twice — and then it
         has fewer cells that are not zero than it was given entries."""
         n = ids.size
-        piece = min(_SLAB_PIECE, -(-max(n, 1) // 1024) * 1024)
-        # whole pieces: what is over adds zeros to cell (0, 0)
-        row, col, val = (np.zeros(-(-n // piece) * piece, dt)
-                         for dt in (np.int32, np.int32, np.float32))
-        row[:n], col[:n], val[:n] = others, self.column_of[ids], vals
-        slab = jnp.zeros((length, self.width), self.dtype)
-        for s in range(0, row.size, piece):
-            slab = _slab_add(slab, row[s:s + piece], col[s:s + piece],
-                             val[s:s + piece])
-        if (self.dtype != "float32"
-                and int(jnp.count_nonzero(slab)) != n):
-            return False
+        with trace_lib.phase(
+                "coo.slab.fill", entries=int(n), dtype=self.dtype,
+                bytes=length * self.width * np.dtype(self.dtype).itemsize):
+            piece = min(_SLAB_PIECE, -(-max(n, 1) // 1024) * 1024)
+            # whole pieces: what is over adds zeros to cell (0, 0)
+            row, col, val = (np.zeros(-(-n // piece) * piece, dt)
+                             for dt in (np.int32, np.int32, np.float32))
+            row[:n], col[:n], val[:n] = others, self.column_of[ids], vals
+            slab = jnp.zeros((length, self.width), self.dtype)
+            for s in range(0, row.size, piece):
+                slab = _slab_add(slab, row[s:s + piece], col[s:s + piece],
+                                 val[s:s + piece])
+            if (self.dtype != "float32"
+                    and int(jnp.count_nonzero(slab)) != n):
+                return False
         self.slab = slab
         self.lines_dev = jnp.asarray(self.lines, jnp.int32)
         return True
@@ -515,24 +520,29 @@ class COOMatrix:
     @classmethod
     def from_edges(cls, rows, cols, vals=None,
                    shape: Optional[Tuple[int, int]] = None) -> "COOMatrix":
-        rows = np.asarray(rows, dtype=np.int64).ravel()
-        cols = np.asarray(cols, dtype=np.int64).ravel()
-        if rows.shape != cols.shape:
-            raise ValueError(f"rows/cols length mismatch: "
-                             f"{rows.shape} vs {cols.shape}")
-        if vals is None:
-            vals = np.ones(rows.shape, np.float32)
-        else:
-            vals = np.asarray(vals, dtype=np.float32).ravel()
-            if vals.shape != rows.shape:
-                raise ValueError("vals length must match rows/cols")
-        if shape is None:
-            shape = (int(rows.max()) + 1 if rows.size else 1,
-                     int(cols.max()) + 1 if cols.size else 1)
-        if rows.size and (rows.min() < 0 or rows.max() >= shape[0]
-                          or cols.min() < 0 or cols.max() >= shape[1]):
-            raise ValueError("edge indices out of bounds for shape")
-        return cls(rows=rows, cols=cols, vals=vals, shape=tuple(shape))
+        # cold: the copies to int64 / float32 and the bounds' passes
+        # are seconds at 100M entries, once a matrix
+        with trace_lib.phase("coo.from_edges") as sp:
+            rows = np.asarray(rows, dtype=np.int64).ravel()
+            cols = np.asarray(cols, dtype=np.int64).ravel()
+            if rows.shape != cols.shape:
+                raise ValueError(f"rows/cols length mismatch: "
+                                 f"{rows.shape} vs {cols.shape}")
+            if vals is None:
+                vals = np.ones(rows.shape, np.float32)
+            else:
+                vals = np.asarray(vals, dtype=np.float32).ravel()
+                if vals.shape != rows.shape:
+                    raise ValueError("vals length must match rows/cols")
+            if shape is None:
+                shape = (int(rows.max()) + 1 if rows.size else 1,
+                         int(cols.max()) + 1 if cols.size else 1)
+            if rows.size and (rows.min() < 0 or rows.max() >= shape[0]
+                              or cols.min() < 0 or cols.max() >= shape[1]):
+                raise ValueError("edge indices out of bounds for shape")
+            sp.set(entries=int(rows.size),
+                   bytes=int(rows.nbytes + cols.nbytes + vals.nbytes))
+            return cls(rows=rows, cols=cols, vals=vals, shape=tuple(shape))
 
     @classmethod
     def from_scipy(cls, mat) -> "COOMatrix":
@@ -815,12 +825,16 @@ class COOMatrix:
         plan as in the dense matrix); an extremum over a row's products
         has to (executor._semiring_product)."""
         if not self._coalesced and not self._entry_memo:
-            keys = self.rows * self.shape[1] + self.cols
-            keys.sort()
-            if bool((keys[1:] == keys[:-1]).any()):
-                self._entry_memo.append(self.coalesce())
-            else:
-                self._coalesced = True
+            with trace_lib.phase("coo.entry_view", entries=self.nnz,
+                                 bytes=8 * self.nnz) as sp:
+                keys = self.rows * self.shape[1] + self.cols
+                keys.sort()
+                repeats = bool((keys[1:] == keys[:-1]).any())
+                if repeats:
+                    self._entry_memo.append(self.coalesce())
+                else:
+                    self._coalesced = True
+                sp.set(repeats=repeats)
         return self if self._coalesced else self._entry_memo[0]
 
     def full_rows(self) -> Optional[tuple]:

@@ -536,7 +536,7 @@ class Lowerer:
                     return pc.compact_apply(static, pc.compact_tables(plan),
                                             plan.overflow, X[:, 0],
                                             interpret=interp)[:, None]
-                with trace_lib.span("spmm.plan.upload"):
+                with trace_lib.phase("spmm.plan.upload"):
                     static, part_statics, part_arrays = pc.plan_operands(plan)
                 return pc.compact_matmat_parts(static, part_statics,
                                                part_arrays, X,
@@ -709,7 +709,7 @@ class Lowerer:
             x = ev(dense)
             x = (x.T if flipped else x)[: plan.n_cols, :k]
             # here the plan's tables move to the device, once a process
-            with trace_lib.span("spmm.plan", hit=False, **facts):
+            with trace_lib.phase("spmm.plan", hit=False, **facts):
                 out = self._coo_spmv_stack(plan, x)
             return fin(self._pad_to_node(out.T if flipped else out, node))
         if l.kind == "sparse_leaf":
@@ -866,8 +866,8 @@ class Lowerer:
 
         z = ev(dense)
         z = (z.T if flipped else z)[: plan.n_cols, :k]
-        with trace_lib.span("sampled.plan", hit=False, **facts):
-            with trace_lib.span("spmm.plan.upload"):
+        with trace_lib.phase("sampled.plan", hit=False, **facts):
+            with trace_lib.phase("spmm.plan.upload"):
                 static, part_statics, part_arrays = pc.plan_operands(plan)
             out = pc.sampled_matmat_parts(
                 static, part_statics, part_arrays, z, smp.attrs["op"],
@@ -902,7 +902,7 @@ class Lowerer:
             col = jax.lax.with_sharding_constraint(
                 col, NamedSharding(self.mesh, P()))
         # here the plan's tables move to the device, once a process
-        with trace_lib.span("semiring.plan", hit=False, **facts):
+        with trace_lib.phase("semiring.plan", hit=False, **facts):
             y = semiring_apply(m, plan, col, node.attrs["reduce"],
                                interpret=pallas_interpret_mode(self.config))
         return self._pad_to_node(y[:, None], node)
@@ -1617,8 +1617,8 @@ def compile_exprs(exprs, mesh: Optional[Mesh] = None,
     grid = mesh_lib.mesh_grid_shape(mesh)
     rule_hits: Dict[str, int] = {}
     # phase(): timed ALWAYS (meta needs the durations on the obs-off
-    # path too), emitted as parent-linked spans only when a tracer is
-    # active — the pre-span perf_counter pairs, one mechanism
+    # path too) and recorded ALWAYS in the cold ring (a compile is no
+    # query's warm path), emitted to a tracer only when one is active
     with trace_lib.phase("plan.optimize", roots=len(exprs)) as sp_opt:
         opts = tuple(planner.annotate_strategies(
             rules.optimize(e, cfg, grid=grid, mesh=mesh,

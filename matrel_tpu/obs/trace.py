@@ -6,14 +6,22 @@ bytes, cache outcomes); this module says WHERE THE TIME WENT: one
 dispatch) → fetch, the serve admission path and PageRank's host side,
 emitting parent-linked records that share a ``qid`` per query.
 
-Four cost tiers, strictly ordered:
+Five cost tiers, strictly ordered:
 
 - **Inactive** (no profiler session, ``obs_level="off"``, flight
   recorder off — the bench default): :func:`span` returns a shared
   no-op singleton — no allocation, no clock reads, no stack
-  bookkeeping. ``phase()`` (the executor's compile-phase form) still
-  reads the clock because its durations feed ``plan.meta`` regardless
-  of observability.
+  bookkeeping.
+- **Cold** (always on; nothing turns it off or on): the sites that run
+  only where a plan is MADE — a compile on a plan-cache miss and its
+  phases, a host plan build, an upload, the slab's fill — open their
+  span with :func:`phase`, which always times and always leaves one
+  record in a second bounded ring (:func:`cold_spans`), and while it
+  is open the thread's :func:`span` calls record there too,
+  parent-linked. jax's own trace / lowering / backend-compile /
+  compile-cache events land in the same ring by function name
+  (:func:`hear_jax`). A query answered from a warm plan meets none of
+  them: it stays on the inactive tier's path.
 - **Profiler session** (a ``jax.profiler`` trace is running —
   ``start_trace`` around traffic, or ``start_server`` + a capture; no
   config change): every span is also a
@@ -74,14 +82,17 @@ _trace_annotation = None
 class _State:
     """One thread's tracing state, installed by its open entry span."""
 
-    __slots__ = ("live", "tracer", "profiled", "current", "gc")
+    __slots__ = ("live", "tracer", "profiled", "current", "gc", "cold",
+                 "jit_cache")
 
     def __init__(self):
-        self.live = False       # tracer is not None or profiled
+        self.live = False       # tracer is not None, profiled, or cold
         self.tracer = None      # the entry call's Tracer
         self.profiled = False   # the entry call found a profiler session
         self.current = None     # the innermost open live Span
         self.gc = None          # the open span of a collection under way
+        self.cold = False       # a cold span (phase()) is open
+        self.jit_cache = None   # jax's cache verdict, until its jit.backend
 
 
 class _ThreadState(threading.local):
@@ -113,7 +124,8 @@ def _profiler_on() -> bool:
 class _NoopSpan:
     """The inactive-path singleton: enters/exits without touching the
     clock or the thread's state. ``dur_ms`` stays None — callers that
-    need a duration unconditionally use :func:`phase` instead."""
+    need a duration unconditionally use :func:`timed` (or, on a cold
+    path, :func:`phase`) instead."""
 
     __slots__ = ()
     dur_ms = None
@@ -142,16 +154,18 @@ class Span:
     record of the :func:`profile_spans` ring. A root span draws the
     ``qid`` its descendants carry. An ENTRY span (:func:`entry`) also
     installs its tracer and the profiler's answer for the thread while
-    it is open."""
+    it is open. A COLD span (:func:`phase`) always leaves a record in
+    the :func:`cold_spans` ring, and so does every span opened on the
+    thread while it is open."""
 
     __slots__ = ("name", "attrs", "tracer", "profiled", "span_id",
                  "parent_id", "qid", "t0", "start_ns", "dur_ms",
                  "_annotation", "_profile_name", "_parent", "_outer",
-                 "_st")
+                 "_st", "_cold")
 
     def __init__(self, name: str, tracer: Optional["Tracer"],
                  attrs: dict, profiled: bool, is_entry: bool,
-                 st: _State):
+                 st: _State, cold: bool = False):
         self.name = name
         self.tracer = tracer
         self.profiled = profiled
@@ -162,14 +176,21 @@ class Span:
         self.qid = None
         self.dur_ms = None
         self._outer = () if is_entry else None
+        # a cold span keeps here what the thread's (live, cold) were
+        self._cold = () if cold else None
 
     def __enter__(self):
         tracer, profiled = self.tracer, self.profiled
-        if tracer is not None or profiled:
-            tls = self._st
+        tls = self._st
+        if (tracer is not None or profiled or tls.cold
+                or self._cold is not None):
             if self._outer is not None:
                 self._outer = (tls.live, tls.tracer, tls.profiled)
                 tls.live, tls.tracer, tls.profiled = True, tracer, profiled
+            if self._cold is not None:
+                # span() below records while this is open
+                self._cold = (tls.live, tls.cold)
+                tls.live = tls.cold = True
             parent = self._parent = tls.current
             tls.current = self
             self.span_id = next(_SPAN_SEQ)
@@ -192,16 +213,24 @@ class Span:
             return False
         tls = self._st
         tls.current = self._parent
+        to_cold = tls.cold      # this span's own, or one it lies beneath
+        if self._cold is not None:
+            tls.live, tls.cold = self._cold
         if self._outer is not None:
             tls.live, tls.tracer, tls.profiled = self._outer
         tid = _get_ident()
-        if self.profiled:
+        if self.profiled or to_cold:
             end_ns = _time_ns()
-            self._annotation.__exit__(exc_type, exc, tb)
-            # _RECORD_KEYS' order; profile_spans() makes the dict
-            _ring_append((self._profile_name, self.start_ns, end_ns,
-                          self.span_id, self.parent_id, self.qid, tid,
-                          self.attrs))
+            if self.profiled:
+                self._annotation.__exit__(exc_type, exc, tb)
+                # _RECORD_KEYS' order; profile_spans() makes the dict
+                _ring_append((self._profile_name, self.start_ns, end_ns,
+                              self.span_id, self.parent_id, self.qid, tid,
+                              self.attrs))
+            if to_cold:
+                _cold_append((self.name, self.start_ns, end_ns,
+                              self.span_id, self.parent_id, self.qid, tid,
+                              self.attrs))
         if self.tracer is not None:
             rec = {"name": self.name,
                    "span_id": self.span_id,
@@ -223,7 +252,7 @@ class Span:
     @property
     def live(self) -> bool:
         """Whether the open span records anything: false for the no-op
-        singleton and for a :func:`phase` that only times. A site asks
+        singleton and for a :func:`timed` that only times. A site asks
         before work done for the record's sake (``to_numpy``'s split)."""
         return self.span_id is not None
 
@@ -271,13 +300,59 @@ def span(name: str, **attrs):
     return Span(name, st.tracer, attrs, st.profiled, False, st)
 
 
-def phase(name: str, **attrs) -> Span:
+def timed(name: str, **attrs) -> Span:
     """A span that ALWAYS times (``dur_ms`` readable after exit) and
-    emits only under a live entry span — for the executor's compile
-    phases, whose durations feed ``plan.meta`` regardless of
-    observability (the pre-span behaviour, one mechanism)."""
+    emits only under a live entry span — for a duration that feeds a
+    record regardless of observability on a path every query takes
+    (``query.execute``): never a cold span, so a served query leaves
+    nothing in the :func:`cold_spans` ring."""
     st = _tls.st
     return Span(name, st.tracer, attrs, st.profiled, False, st)
+
+
+def phase(name: str, **attrs) -> Span:
+    """A COLD span: for the sites that run only where a plan is made
+    (the executor's compile phases, a host plan build, an upload, the
+    slab's fill). It ALWAYS times (``dur_ms`` readable after exit: the
+    phases' durations feed ``plan.meta`` regardless of observability),
+    emits to the tracer and the profiler's trace under a live entry
+    span as :func:`span` does, and ALWAYS leaves one record in the
+    :func:`cold_spans` ring; while it is open, :func:`span` on the
+    thread returns recording spans too (children of it in that ring),
+    whether or not a session runs. Costs a :class:`Span`, three clock
+    reads and one tuple append: never put it on a warm query's path."""
+    st = _tls.st
+    return Span(name, st.tracer, attrs, st.profiled, False, st, True)
+
+
+class _Part:
+    """See :func:`part`."""
+
+    __slots__ = ("key", "attrs", "t0")
+
+    def __init__(self, key: str, attrs: dict):
+        self.key = key
+        self.attrs = attrs
+
+    def __enter__(self):
+        self.t0 = _perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.attrs[self.key] = round(
+            self.attrs.get(self.key, 0.0) + _perf_counter() - self.t0, 4)
+        return False
+
+
+def part(key: str):
+    """A scope whose seconds are ADDED to attribute ``key`` of the
+    thread's innermost open span where a cold span is open: a part of a
+    build that is worth a number and no name of its own
+    (``build_spmv_plan``'s ``fill_s`` and ``hub_walks_s`` on its
+    caller's ``*.plan.build`` record; several builds under one record
+    add up). The no-op singleton otherwise."""
+    st = _tls.st
+    return _Part(key, st.current.attrs) if st.cold else _NOOP
 
 
 #: A pause of Python's cyclic collector, as a span of the profiler
@@ -416,6 +491,125 @@ def profile_spans() -> List[dict]:
     ``time.time_ns()``, which is the trace's host clock plus the
     session's start."""
     return [dict(zip(_RECORD_KEYS, r)) for r in _PROFILE_RING.snapshot()]
+
+
+#: The cold tier's records, process-wide. A process's set-up makes 65
+#: to 600 where no Pallas kernel is traced and 1,300 to 7,300 where one
+#: is (the benchmark's cells on a v5e, PR 52): every function jax traces,
+#: lowers, compiles or loads is three or four records, and a kernel's
+#: body is some hundred jitted ``jnp`` calls, each a ``jit.trace`` of
+#: its own inside ``pallas_call``'s. The OLDEST falls out: a process that
+#: goes on making plans or matrices for hours keeps its newest 16,384
+#: records and loses its start-up's first (and a record that outlived
+#: its fallen children then reads a self time that was theirs).
+COLD_RING_CAPACITY = 16384
+_COLD_RING = FlightRecorder(COLD_RING_CAPACITY)
+# as _ring_append: one C call, no lock, so a listener of jax's or a
+# collection's callback may append from wherever it is called
+_cold_append = _COLD_RING._buf.append
+
+
+def cold_spans() -> List[dict]:
+    """Snapshot of the cold tier's ring, oldest first: one record
+    ``{name, start_ns, end_ns, span_id, parent_id, qid, tid, attrs}``
+    (``time.time_ns()``; ``name`` bare, no ``matrel.`` prefix: no
+    profiler's plane is shared here) per :func:`phase` span, per
+    :func:`span` that ended beneath an open one, and per event of
+    jax's that :func:`hear_jax` listens for — whether or not a tracer
+    or a profiler session ran. What a process spent before it could
+    answer its first query, from the inside."""
+    return [dict(zip(_RECORD_KEYS, r)) for r in _COLD_RING.snapshot()]
+
+
+# jax's own account of a compile (jax/_src/dispatch.py, compiler.py,
+# compilation_cache.py): the three time spans carry ``fun_name``, start
+# and end on time.time(); the cache's verdict comes as an event and two
+# durations INSIDE the backend-compile span, before that span is told.
+_JIT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.backend",
+}
+_JIT_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": True,
+    "/jax/compilation_cache/cache_misses": False,
+}
+_JIT_CACHE_SECS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
+_hear_once = itertools.count()
+_jax_heard = False
+
+
+def hear_jax() -> None:
+    """Listen, from now on and once a process however often it is
+    called, to what ``jax.monitoring`` tells of a function's trace,
+    lowering and backend compile (records ``jit.trace``, ``jit.lower``,
+    ``jit.backend`` of the cold ring: jax's start and end,
+    ``fun_name``) and of the persistent compile cache (``jit.cache``:
+    ``hit``, ``retrieval_s``, ``saved_s``, ``fun_name``; zero length, a
+    child of the ``jit.backend`` it fell inside). ``parent_id`` is the
+    thread's innermost open span where a cold span is open, else null.
+    jax calls a listener only where it traces, lowers or compiles,
+    never on a cached call's path. An event name this does not know is
+    ignored: a jax that renames one loses a record, not a query."""
+    global _jax_heard
+    if _jax_heard or next(_hear_once):
+        return      # done, or another thread is about to
+    from jax import monitoring
+    monitoring.register_event_time_span_listener(_on_jax_span)
+    monitoring.register_event_listener(_on_jax_event)
+    monitoring.register_event_duration_secs_listener(_on_jax_secs)
+    _jax_heard = True
+
+
+# The three listeners never raise (jax calls them inside a compile) and
+# take no lock (the rule _on_gc follows): a thread-local read, a dict
+# lookup, a deque append.
+
+def _on_jax_span(event: str, start: float, end: float, **kw) -> None:
+    try:
+        name = _JIT_SPANS.get(event)
+        if name is None:
+            return
+        st = _tls.st
+        parent = st.current if st.cold else None
+        parent_id, qid = ((None, None) if parent is None
+                          else (parent.span_id, parent.qid))
+        span_id, tid = next(_SPAN_SEQ), _get_ident()
+        fun = kw.get("fun_name")
+        _cold_append((name, int(start * 1e9), int(end * 1e9), span_id,
+                      parent_id, qid, tid, {"fun_name": fun}))
+        if name == "jit.backend":
+            cache, st.jit_cache = st.jit_cache, None
+            if cache is not None:
+                at = cache.pop("at_ns")
+                cache["fun_name"] = fun
+                _cold_append(("jit.cache", at, at, next(_SPAN_SEQ),
+                              span_id, qid, tid, cache))
+    except Exception:  # matlint: disable=ML007 never-fail obs sink — jax calls this inside a compile, which a broken record must not fail
+        pass
+
+
+def _on_jax_event(event: str, **kw) -> None:
+    try:
+        hit = _JIT_CACHE_EVENTS.get(event)
+        if hit is not None:
+            _tls.st.jit_cache = {"hit": hit, "at_ns": _time_ns()}
+    except Exception:  # matlint: disable=ML007 never-fail obs sink — as _on_jax_span
+        pass
+
+
+def _on_jax_secs(event: str, secs: float, **kw) -> None:
+    try:
+        key = _JIT_CACHE_SECS.get(event)
+        if key is not None:
+            cache = _tls.st.jit_cache
+            if cache is not None:
+                cache[key] = secs
+    except Exception:  # matlint: disable=ML007 never-fail obs sink — as _on_jax_span
+        pass
 
 
 #: Default flight-recorder artifact name (cwd-relative, like the event

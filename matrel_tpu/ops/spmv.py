@@ -82,6 +82,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from matrel_tpu.obs import trace as trace_lib
+
 WIDTH = 8        # values a table row of the plan layout (src8, lane): the
                  # gather's cost is flat for 8..128 because every such row
                  # is padded to 128 lanes; gather_1d picks its own row
@@ -530,46 +532,51 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
     # the scan the other); the blocks layout keeps input order inside a
     # block — the matvec's one-hot contraction is order-agnostic, so its
     # results match the numpy path
-    filled = hub_filled = None
-    if hub_ids is not None:
-        both = (native.spmv_fill_ragged_hubs(
-            rows, cols, vals, hub_rank, hub_ids.size, block, first,
-            hub_first, WIDTH) if use_native else None)
-        if both is not None:        # both sets in one walk over the edges
-            filled, hub_filled = both
-        else:
-            # the edge list split, each set filled on its own: the hub
-            # set over the table's columns, a value a "row" (width 1:
-            # ``src8`` comes back as the rank, ``len(hub_ids)`` in
-            # padded slots)
-            of_edge = hub_rank[cols]
-            at, rest = np.flatnonzero(of_edge >= 0), np.flatnonzero(of_edge < 0)
-            at = at[np.argsort(rows[at] // block * (hub_ids.size // HUB_ROW)
-                               + of_edge[at] // HUB_ROW, kind="stable")]
-            # and inside each register of its block by destination row
-            blk = rows[at] // block
-            reg = (np.arange(at.size) - (np.cumsum(hub_cnt) - hub_cnt)[blk]
-                   ) // HUB_REG
-            at = at[np.argsort(
-                (blk * (int(hub_cnt.max()) // HUB_REG + 1) + reg) * block
-                + rows[at] % block, kind="stable")]
-            idx, _, hub_off, hub_val = _numpy_fill(
-                rows[at], of_edge[at].astype(np.int64),
-                None if vals is None else vals[at], hub_ids.size, block,
-                hub_first, hub_cnt, width=1, in_order=True,
-                pad_rows=True)[:4]
-            hub_filled = idx, hub_off, hub_val
-            rows, cols = rows[rest], cols[rest]
-            vals = None if vals is None else vals[rest]
-    elif use_native and layout == "chunks":
-        filled = native.spmv_fill_ragged(rows, cols, vals, n_cols, block,
-                                         first, WIDTH)
-    elif use_native:
-        filled = native.spmv_fill(rows, cols, vals, n_cols, block, nb, cap,
-                                  WIDTH, n_ov)
-    if filled is None:
-        filled = _numpy_fill(rows, cols, vals, n_cols, block, first, cnt,
-                             pad_rows=layout == "chunks")
+    # its seconds go on the build's record (a caller's cold
+    # ``*.plan.build`` span) as ``fill_s``
+    with trace_lib.part("fill_s"):
+        filled = hub_filled = None
+        if hub_ids is not None:
+            both = (native.spmv_fill_ragged_hubs(
+                rows, cols, vals, hub_rank, hub_ids.size, block, first,
+                hub_first, WIDTH) if use_native else None)
+            if both is not None:        # both sets in one walk over the edges
+                filled, hub_filled = both
+            else:
+                # the edge list split, each set filled on its own: the hub
+                # set over the table's columns, a value a "row" (width 1:
+                # ``src8`` comes back as the rank, ``len(hub_ids)`` in
+                # padded slots)
+                of_edge = hub_rank[cols]
+                at = np.flatnonzero(of_edge >= 0)
+                rest = np.flatnonzero(of_edge < 0)
+                at = at[np.argsort(
+                    rows[at] // block * (hub_ids.size // HUB_ROW)
+                    + of_edge[at] // HUB_ROW, kind="stable")]
+                # and inside each register of its block by destination row
+                blk = rows[at] // block
+                reg = (np.arange(at.size) - (np.cumsum(hub_cnt) - hub_cnt)[blk]
+                       ) // HUB_REG
+                at = at[np.argsort(
+                    (blk * (int(hub_cnt.max()) // HUB_REG + 1) + reg) * block
+                    + rows[at] % block, kind="stable")]
+                idx, _, hub_off, hub_val = _numpy_fill(
+                    rows[at], of_edge[at].astype(np.int64),
+                    None if vals is None else vals[at], hub_ids.size, block,
+                    hub_first, hub_cnt, width=1, in_order=True,
+                    pad_rows=True)[:4]
+                hub_filled = idx, hub_off, hub_val
+                rows, cols = rows[rest], cols[rest]
+                vals = None if vals is None else vals[rest]
+        elif use_native and layout == "chunks":
+            filled = native.spmv_fill_ragged(rows, cols, vals, n_cols, block,
+                                             first, WIDTH)
+        elif use_native:
+            filled = native.spmv_fill(rows, cols, vals, n_cols, block, nb, cap,
+                                      WIDTH, n_ov)
+        if filled is None:
+            filled = _numpy_fill(rows, cols, vals, n_cols, block, first, cnt,
+                                 pad_rows=layout == "chunks")
     src8, lane, off, val, ov_r64, ov_c64, ov_v = filled
 
     if n_ov:
@@ -583,13 +590,14 @@ def build_spmv_plan(rows, cols, vals=None, n_rows: int = None,
     if hub_ids is not None:
         hub_shape = (hub_slots // CHUNK, CHUNK)
         idx, hub_off, hub_val = hub_filled
-        hubs = HubChunks.of(
-            hub_ids,
-            np.ascontiguousarray(idx, np.int32).reshape(hub_shape),
-            np.ascontiguousarray(hub_off, np.int32).reshape(hub_shape),
-            np.ascontiguousarray(hub_val, np.float32).reshape(hub_shape),
-            np.repeat(np.arange(nb, dtype=np.int32),
-                      np.diff(hub_first) // CHUNK))
+        with trace_lib.part("hub_walks_s"):
+            hubs = HubChunks.of(
+                hub_ids,
+                np.ascontiguousarray(idx, np.int32).reshape(hub_shape),
+                np.ascontiguousarray(hub_off, np.int32).reshape(hub_shape),
+                np.ascontiguousarray(hub_val, np.float32).reshape(hub_shape),
+                np.repeat(np.arange(nb, dtype=np.int32),
+                          np.diff(hub_first) // CHUNK))
 
     # compact tables stay host-side numpy; they move to device (default
     # placement or sharded via shard_plan) at expansion time

@@ -544,7 +544,7 @@ class MatrelSession:
             plan = self._plan_cache.get(key)
             if plan is not None:
                 return plan, True
-            with trace_lib.span("compile") as sp:
+            with trace_lib.phase("compile") as sp:
                 try:
                     plan = build()
                 except Exception as ex:
@@ -1735,9 +1735,10 @@ class MatrelSession:
         (serve/mqo.py): fresh leaves rebound into the cached program,
         and the query record saying so (``cache: "template_hit"``)."""
         first = not getattr(plan, "_obs_executed", False)
-        # phase(): the one timing mechanism — the duration lands in the
-        # query record AND (tracer active here) as an "execute" span
-        with trace_lib.phase("query.execute",
+        # timed(): the one timing mechanism — the duration lands in the
+        # query record AND (tracer active here) as an "execute" span;
+        # never cold: every observed query comes this way
+        with trace_lib.timed("query.execute",
                              cache=cache_label
                              or ("hit" if hit else "miss")) as sp:
             out = self._arbitrated_run(plan, bindings=bindings)

@@ -101,6 +101,7 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
     """
     if impl not in ("auto", "segment", "onehot"):
         raise ValueError(f"unknown impl {impl!r}")
+    trace_lib.hear_jax()    # no session here to have asked; once a process
     with trace_lib.entry("pagerank") as sp:
         _LAST_PLAN.clear()
         out, path = _pagerank_edges(src, dst, n, rounds, alpha, mesh,

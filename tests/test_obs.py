@@ -1629,3 +1629,293 @@ class TestHistorySketchAgreement:
     def test_pctile_empty_is_none(self):
         from matrel_tpu.obs.history import _pctile
         assert _pctile([], 0.5) is None
+
+
+class TestColdTier:
+    """PR 52: the sites that run only where a plan is made leave a
+    record in ``cold_spans()`` with everything else off (no tracer, no
+    flight recorder, no profiler session), jax's own account of each
+    compile lands beside them by function name, and a warm query
+    leaves nothing anywhere."""
+
+    @staticmethod
+    def _mark():
+        """A span id no record made from now on falls under: the ring
+        is the process's and may be full, so its length says nothing."""
+        from matrel_tpu.obs import trace as trace_lib
+        return next(trace_lib._SPAN_SEQ)
+
+    @staticmethod
+    def _since(mark):
+        from matrel_tpu.obs import trace as trace_lib
+        return [r for r in trace_lib.cold_spans() if r["span_id"] > mark]
+
+    @staticmethod
+    def _dark(mesh8):
+        sess = MatrelSession(mesh=mesh8)        # the default config
+        assert sess.config.obs_level == "off"
+        assert sess._tracer is None and sess._flight is None
+        return sess
+
+    def test_a_cold_compile_is_recorded_with_everything_off(
+            self, mesh8, chain3):
+        sess = self._dark(mesh8)
+        mark = self._mark()
+        sess.compute(chain3)
+        recs = self._since(mark)
+        by_name = {r["name"]: r for r in recs}
+        compile_ = by_name["compile"]
+        assert compile_["parent_id"] is None
+        assert compile_["attrs"]["executors"] \
+            == sess.compile(chain3).meta["executors"]
+        for name in ("plan.optimize", "plan.verify", "plan.trace"):
+            r = by_name[name]
+            assert r["parent_id"] == compile_["span_id"]
+            assert r["qid"] == compile_["qid"]
+            assert compile_["start_ns"] <= r["start_ns"] \
+                <= r["end_ns"] <= compile_["end_ns"]
+        # span() beneath an open cold span records too
+        assert by_name["plan.strategy"]["parent_id"] == compile_["span_id"]
+        meta = sess.compile(chain3).meta
+        for key, name in (("optimize_ms", "plan.optimize"),
+                          ("trace_ms", "plan.trace")):
+            r = by_name[name]
+            length_ms = (r["end_ns"] - r["start_ns"]) * 1e-6
+            # one span, two clocks (perf_counter for dur_ms)
+            assert meta[key] == pytest.approx(length_ms, abs=0.5, rel=0.02)
+        # a record's name is bare, and nothing reached the profile ring
+        assert not any(r["name"].startswith("matrel.") for r in recs)
+
+    def test_b_a_warm_query_leaves_nothing(self, mesh8, chain3):
+        from matrel_tpu.obs import trace as trace_lib
+        sess = self._dark(mesh8)
+        want = sess.compute(chain3).to_numpy()      # the cold compile
+        assert trace_lib.entry("compute", None) is trace_lib._NOOP
+        assert trace_lib.span("dispatch") is trace_lib._NOOP
+        mark = self._mark()
+        profiled = len(trace_lib.profile_spans())
+        for _ in range(100):
+            # blocked a round: programs with a collective each, queued
+            # unblocked on 8 virtual devices, starve XLA's thread pool
+            out = sess.compute(chain3).to_numpy()
+        np.testing.assert_array_equal(out, want)
+        assert self._since(mark) == []
+        assert len(trace_lib.profile_spans()) == profiled
+
+    def test_c_a_fresh_jit_is_heard_by_function_name(self, mesh8):
+        import jax
+        import jax.numpy as jnp
+        self._dark(mesh8)       # a session's start listens
+
+        def cold_tier_probe_c(x):
+            return x * 2.0 + 1.0
+
+        fn = jax.jit(cold_tier_probe_c)
+        mark = self._mark()
+        assert float(fn(jnp.ones(3))[0]) == 3.0
+        recs = [r for r in self._since(mark)
+                if "cold_tier_probe_c" in str(r["attrs"].get("fun_name"))]
+        assert sorted(r["name"] for r in recs
+                      if r["name"] != "jit.cache") \
+            == ["jit.backend", "jit.lower", "jit.trace"]
+        for r in recs:
+            assert r["parent_id"] is None or r["name"] == "jit.cache"
+            assert 0 <= r["end_ns"] - r["start_ns"] < 60e9
+        mark = self._mark()
+        fn(jnp.ones(3))
+        assert self._since(mark) == []
+
+    def test_c_a_jit_event_names_the_open_cold_span(self, mesh8):
+        import jax
+        import jax.numpy as jnp
+        from matrel_tpu.obs import trace as trace_lib
+        self._dark(mesh8)
+        mark = self._mark()
+        with trace_lib.phase("coo.slab.fill", entries=3) as sp:
+            jax.jit(lambda x: x - 7.0)(jnp.ones(3))
+        recs = self._since(mark)
+        jits = [r for r in recs if r["name"].startswith("jit.")
+                and r["name"] != "jit.cache"]
+        assert {r["name"] for r in jits} \
+            == {"jit.trace", "jit.lower", "jit.backend"}
+        assert {r["parent_id"] for r in jits} == {sp.span_id}
+        assert recs[-1]["name"] == "coo.slab.fill"
+        assert recs[-1]["attrs"] == {"entries": 3}
+
+    def test_d_query_execute_never_reaches_the_cold_ring(
+            self, mesh8, tmp_path, chain3):
+        sess = _session(mesh8, tmp_path, level="on")
+        mark = self._mark()
+        sess.run(chain3)
+        sess.run(chain3)
+        logged = [e["name"] for e in read_events(sess.config.obs_event_log)
+                  if e["kind"] == "span"]
+        assert logged.count("query.execute") == 2
+        cold = [r["name"] for r in self._since(mark)]
+        assert "compile" in cold and "query.execute" not in cold
+        assert "compute" not in cold and "dispatch" not in cold
+        # and the phases still go to the tracer as they did
+        assert {"compile", "plan.optimize", "plan.trace"} <= set(logged)
+
+    def test_e_the_ring_is_bounded(self):
+        from matrel_tpu.obs import trace as trace_lib
+        assert trace_lib._COLD_RING.capacity \
+            == trace_lib.COLD_RING_CAPACITY == 16384
+        for k in range(trace_lib.COLD_RING_CAPACITY + 10):
+            with trace_lib.phase("plan.verify", k=k):
+                pass
+        recs = trace_lib.cold_spans()
+        assert len(recs) == trace_lib.COLD_RING_CAPACITY
+        # the oldest fell out
+        assert recs[-1]["attrs"] == {"k": trace_lib.COLD_RING_CAPACITY + 9}
+        assert recs[0]["attrs"] == {"k": 10}
+
+    def test_e_a_listener_never_fails_a_compile(self, mesh8, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+        from matrel_tpu.obs import trace as trace_lib
+        self._dark(mesh8)
+        mark = self._mark()
+        # a later jax's renamed events: ignored by all three listeners
+        jax.monitoring.record_event("/jax/compilation_cache/renamed")
+        jax.monitoring.record_event_duration_secs("/jax/core/renamed", 1.5)
+        jax.monitoring.record_event_time_span("/jax/core/renamed", 1.0, 2.0)
+        assert self._since(mark) == []
+
+        def boom(record):
+            raise RuntimeError("a broken ring")
+
+        monkeypatch.setattr(trace_lib, "_cold_append", boom)
+        assert float(jax.jit(lambda x: x * 5.0)(jnp.ones(2))[0]) == 5.0
+        trace_lib._on_jax_event(None)       # nor on nonsense
+        trace_lib._on_jax_secs(None, None)
+        trace_lib._on_jax_span(None, "a", "b", fun_name=3)
+        monkeypatch.undo()
+        assert self._since(mark) == []
+
+    def test_f_two_sessions_hear_each_event_once(self, mesh8):
+        import jax
+        import jax.numpy as jnp
+        from jax._src import monitoring
+        from matrel_tpu.obs import trace as trace_lib
+        self._dark(mesh8)
+        self._dark(mesh8)
+        trace_lib.hear_jax()
+        for listeners, mine in (
+                (monitoring.get_event_time_span_listeners(),
+                 trace_lib._on_jax_span),
+                (monitoring.get_event_listeners(), trace_lib._on_jax_event),
+                (monitoring.get_event_duration_listeners(),
+                 trace_lib._on_jax_secs)):
+            assert listeners.count(mine) == 1
+
+        def cold_tier_probe_f(x):
+            return x + 11.0
+
+        mark = self._mark()
+        jax.jit(cold_tier_probe_f)(jnp.ones(2))
+        assert sorted(
+            r["name"] for r in self._since(mark)
+            if "cold_tier_probe_f" in str(r["attrs"].get("fun_name"))
+            and r["name"] != "jit.cache") \
+            == ["jit.backend", "jit.lower", "jit.trace"]
+
+    def test_f_the_cache_verdict_rides_its_backend_compile(self, mesh8):
+        """jax tells of a hit (an event, then two durations) inside the
+        backend-compile span and of that span at its end: the
+        ``jit.cache`` record is the child of the ``jit.backend`` one."""
+        import jax
+        self._dark(mesh8)
+        mark = self._mark()
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/compile_time_saved_sec", 4.0)
+        jax.monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+        jax.monitoring.record_event_time_span(
+            "/jax/core/compile/backend_compile_duration", 100.0, 100.5,
+            fun_name="jit(loaded)")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+        jax.monitoring.record_event_time_span(
+            "/jax/core/compile/backend_compile_duration", 101.0, 109.0,
+            fun_name="jit(compiled)")
+        jax.monitoring.record_event_time_span(
+            "/jax/core/compile/backend_compile_duration", 110.0, 110.1,
+            fun_name="jit(neither)")
+        loaded, hit, compiled, miss, neither = self._since(mark)
+        assert (loaded["name"], loaded["start_ns"], loaded["end_ns"]) \
+            == ("jit.backend", 100_000_000_000, 100_500_000_000)
+        assert hit["name"] == "jit.cache"
+        assert hit["parent_id"] == loaded["span_id"]
+        assert hit["start_ns"] == hit["end_ns"]
+        assert hit["attrs"] == {"hit": True, "saved_s": 4.0,
+                                "retrieval_s": 0.25,
+                                "fun_name": "jit(loaded)"}
+        assert miss["parent_id"] == compiled["span_id"]
+        assert miss["attrs"] == {"hit": False, "fun_name": "jit(compiled)"}
+        assert neither["attrs"] == {"fun_name": "jit(neither)"}
+
+    def test_g_a_coo_products_first_compile(self, rng, tmp_path,
+                                            monkeypatch):
+        """On one device with the compact executors (interpreted) a COO
+        product's first compile leaves its host build and its upload
+        in the cold ring: under a profiler session the very records
+        the profile tier gets, and with everything off the same names
+        and attribute keys."""
+        import jax
+        from matrel_tpu import config as config_lib
+        from matrel_tpu.core import coo as coo_lib
+        from matrel_tpu.core import mesh as mesh_lib
+        from matrel_tpu.core.coo import COOMatrix
+        from matrel_tpu.obs import trace as trace_lib
+        from matrel_tpu.ops import spmv as spmv_lib
+        cfg = MatrelConfig(pallas_interpret=True)
+        monkeypatch.setattr(config_lib, "_default_config", cfg)
+        monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")
+        monkeypatch.setattr(coo_lib, "_DENSE_SHARE", 0.0)
+        monkeypatch.setattr(spmv_lib, "_SMALL_PLAN_SLOTS", 0)
+        mesh = mesh_lib.make_mesh((1, 1), devices=jax.devices()[:1])
+        sess = MatrelSession(mesh=mesh, config=cfg)
+        dense = BlockMatrix.from_numpy(
+            rng.standard_normal((300, 8)).astype(np.float32), mesh=mesh)
+
+        def product():
+            m = COOMatrix.from_edges(
+                rng.integers(0, 400, 3000), rng.integers(0, 300, 3000),
+                rng.random(3000, dtype=np.float32), shape=(400, 300))
+            return m, m.expr() @ dense.expr()
+
+        names = ("spmm.plan.build", "spmm.plan.upload", "spmm.plan")
+        m, e = product()
+        mark = self._mark()
+        profiled = len(trace_lib.profile_spans())
+        with TestProfilerTier._trace(tmp_path):
+            got = sess.compute(e).to_numpy()
+        np.testing.assert_allclose(
+            got, m.to_dense() @ dense.to_numpy(), rtol=1e-4, atol=1e-4)
+        prof = {r["span_id"]: r
+                for r in trace_lib.profile_spans()[profiled:]}
+        lit = [r for r in self._since(mark) if r["name"] in names]
+        assert {r["name"] for r in lit} == set(names)
+        for r in lit:
+            p = prof[r["span_id"]]
+            assert p["name"] == "matrel." + r["name"]
+            assert {k: p[k] for k in r if k != "name"} \
+                == {k: r[k] for k in r if k != "name"}
+        mark = self._mark()
+        _, e = product()
+        profiled = len(trace_lib.profile_spans())
+        sess.compute(e)
+        assert len(trace_lib.profile_spans()) == profiled
+        dark = [r for r in self._since(mark) if r["name"] in names]
+        by_name = {r["name"]: r for r in dark}
+        assert set(by_name) == set(names)
+        for r in lit:
+            assert set(by_name[r["name"]]["attrs"]) == set(r["attrs"])
+        build = by_name["spmm.plan.build"]["attrs"]
+        assert build["orientation"] == "forward" and build["fill_s"] >= 0
+        assert by_name["spmm.plan"]["attrs"]["hit"] is False
+        # the matrix's own cold site, with the sizes it handled
+        made = next(r for r in self._since(mark)
+                    if r["name"] == "coo.from_edges")
+        assert made["attrs"] == {"entries": 3000, "bytes": 3000 * 20}
