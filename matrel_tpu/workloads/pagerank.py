@@ -84,10 +84,16 @@ def pagerank_edges(src: jax.Array, dst: jax.Array, n: int,
     edges = 80 MB) and may be sharded over the mesh (segment_sum psums
     over ICI). The one-hot and compact paths build their plan on the
     host, once per graph: a later call on the same graph finds it by
-    comparing the edge arrays with the copy the plan keeps (numpy
-    arrays: ~8-15 ms at 10M edges, so an in-place edit is seen), or by
-    identity when it is handed the same ``jax.Array`` objects (no pull
-    to the host). On one device the compact executor takes a skewed
+    comparing the edge arrays, every element, with the copy the plan
+    keeps (so an in-place edit of one edge is seen), or by identity
+    when it is handed the same ``jax.Array`` objects (no pull to the
+    host). Arrays that agree with a cached plan in their first chunk
+    are launched on that plan FIRST and compared in full while the
+    device iterates (~8 ms at 10M edges, ~98 ms at 128M, under runs of
+    0.6 and 1.3 s); ranks launched for a graph that then differs are
+    dropped, never returned, and the call builds the plan of the arrays
+    it was handed (:func:`recognition_counts`). On one device the
+    compact executor takes a skewed
     graph too (PR 33): its plan lies in fixed chunks of slots, a hub
     block owning many (``build_spmv_plan`` ``layout="auto"``: no
     overflow COO, ~1.04 slots an edge on a Graph500 Kronecker graph),
@@ -128,7 +134,8 @@ def last_plan() -> dict:
     (:func:`_plan_attrs`: ``layout``, ``edges``, ``slots``, ``chunks``,
     ``chunk``, ``overflow_edges``, ``row_values``, ``panels``,
     ``plan_bytes``, ``hubs``, ``hub_slots``, ``hub_chunks``,
-    ``hub_walk_rows``, ``hit``; on
+    ``hub_walk_rows``, ``hit``, and ``recognised``: the call's key of
+    :func:`recognition_counts`; on
     a build by the one-device path also
     ``build_s`` and ``upload_s``) of the newest :func:`pagerank_edges` call
     — what its ``matrel.pagerank`` / ``matrel.pagerank.plan`` spans say
@@ -143,6 +150,26 @@ def path_counts() -> dict:
     ``segment`` (gather + segment-sum) — the ``impl`` the
     ``matrel.pagerank`` span carries. A copy."""
     return dict(_PATH_COUNTS)
+
+
+# How each pagerank_edges call that went to the prepared-plan cache knew
+# its graph, process-wide.
+_RECOGNISED = dict.fromkeys(
+    ("confirmed", "discarded", "compared_first", "identity"), 0)
+
+
+def recognition_counts() -> dict:
+    """Calls of :func:`pagerank_edges` by how the prepared-plan cache
+    knew their graph: ``confirmed`` (launched on a cached plan whose
+    first chunks agreed, found equal in full while the device ran:
+    the comparison cost the caller no time), ``discarded`` (launched
+    so, found edited: the launched ranks were dropped unseen and the
+    call went on to the plan of the arrays it was handed),
+    ``compared_first`` (nothing cached agreed in its first chunk: a
+    new graph, built before any launch) and ``identity`` (the very
+    ``jax.Array`` objects a plan was built from: nothing compared).
+    The segment-sum path has no plan and counts nowhere. A copy."""
+    return dict(_RECOGNISED)
 
 
 def _pagerank_edges(src, dst, n, rounds, alpha, mesh, impl, weights,
@@ -328,17 +355,34 @@ def run_pagerank_compact(prepared, rounds: int = 30, alpha: float = 0.85,
 # int32 (weights: float32) copy of the arrays it was built from, taken
 # once, when the plan is cached. Equality, not a digest or a sample: a
 # graph edited in one edge, in place or in a new array, is rebuilt, and
-# an equal graph in another array or index dtype hits. A probe walks the
-# entries of the same (n, sizes, weighted, mesh…) key and compares in
-# chunks, with an early exit and no temporary beyond a chunk whatever the
-# dtype or strides: a repeated 10M-edge call reads 80 MB against 80 MB
-# (7.9 ms on the v5e's host, PERF.md §6 PR 26; no copy, no hash — against
-# ~0.75 s of 30-round compute), and a different graph of the same size
-# fails in its first chunk. A
-# numpy array is mutable and is always compared; a jax.Array is not, so
-# the very object an entry was built from (held weakly) hits by identity
-# with no device→host pull — any other jax.Array is pulled to be
-# compared. Host memory: the copies are 8 B an edge (12 with weights) per
+# an equal graph in another array or index dtype hits. The comparison
+# runs in chunks, with an early exit and no temporary beyond a chunk
+# whatever the dtype or strides: a repeated 10M-edge call reads 80 MB
+# against 80 MB (7.9 ms on the v5e's host, PERF.md §6 PR 26; no copy, no
+# hash), a Graph500 scale-22 call 1.03 GB (98 ms). A numpy array is
+# mutable and is always compared; a jax.Array is not, so the very object
+# an entry was built from (held weakly) hits by identity with no
+# device→host pull — any other jax.Array is pulled to be compared.
+# WHEN it is compared (PR 53; _cached_plan): the comparison needs nothing
+# of the device and, where the graph is the cached one, the device needs
+# nothing of it, so they overlap. A PROBE walks the entries of the same
+# (n, sizes, weighted, mesh…) key and compares the first chunk of each
+# array (~0.1 ms an entry: a different graph of the same size fails
+# here, and nothing is launched for it); the first entry that passes is
+# LAUNCHED at once (jax's dispatch returns in ~1 ms, the device starts
+# its rounds) and the rest of every array is compared on the calling
+# thread meanwhile. Confirmed, the launched ranks are the call's answer,
+# and the comparison cost it max(0, compare − run). Not confirmed (an
+# edit past the first chunk), they are dropped — never returned, counted
+# nowhere but recognition_counts()["discarded"] — and the call goes on
+# as if it had compared first: the later entries in full, before any
+# second launch (one speculation a call), else a build. A dropped run
+# occupies the device for max(0, run − build) longer than the call
+# would have: it executes while the host builds the new plan (16.9 s for
+# the Graph500 plan, 0.9 s for the 1M one, against runs of 1.26 and
+# 0.63 s), and its output is n floats. What a call returns is always the
+# ranks of the arrays it was handed, compared in full.
+# Host memory: the copies are 8 B an edge (12 with weights) per
 # cached graph, beside the ~13 B a slot of compact host tables its plan
 # already keeps; a plan has about a slot an edge or more, so the budget
 # below holds all single-device entries' copies to ~192 MB (288
@@ -357,9 +401,10 @@ _PLAN_CACHE_MAX_BYTES = _PLAN_CACHE_MAX_SLOTS * _EXPANDED_BYTES_A_SLOT
 # the share of a device's memory a compact plan may take while it runs
 # (tables, slot weights and a panel's temporaries: pallas_spmv.plan_bytes)
 _PLAN_SHARE = 0.5
-# elements a comparison step: its temporary (a 64 KB mask) stays in the
-# heap and the cache, a first-chunk miss costs ~0.1 ms, and from 64K
-# elements up the whole compare runs at memory speed
+# elements a comparison step, and what the probe reads of an array: its
+# temporary (a 64 KB mask) stays in the heap and the cache, a
+# first-chunk miss costs ~0.1 ms, and from 64K elements up the whole
+# compare runs at memory speed
 _PROBE_CHUNK = 1 << 16
 
 
@@ -380,15 +425,18 @@ def _host_fetchable(a) -> bool:
     return True
 
 
-def _same_contents(a, kept) -> tuple:
-    """(whether ``a`` holds what ``kept`` holds, the bytes of ``kept``
-    read to find out). Indices compare under numpy's promotion, so an
-    id beyond int32 equals nothing; weights compare as the float32 the
-    plan is built from."""
+def _same_contents(a, kept, start: int = 0, stop: int = None) -> tuple:
+    """(whether ``a`` holds what ``kept`` holds over the elements
+    [``start``, ``stop``), the bytes of ``kept`` read to find out).
+    Indices compare under numpy's promotion, so an id beyond int32
+    equals nothing; weights compare as the float32 the plan is built
+    from."""
     a = np.asarray(a)
     seen = 0
-    for i in range(0, kept.shape[0], _PROBE_CHUNK):
-        ours, theirs = kept[i:i + _PROBE_CHUNK], a[i:i + _PROBE_CHUNK]
+    stop = kept.shape[0] if stop is None else min(stop, kept.shape[0])
+    for i in range(start, stop, _PROBE_CHUNK):
+        j = min(i + _PROBE_CHUNK, stop)
+        ours, theirs = kept[i:j], a[i:j]
         if ours.dtype.kind == "f":
             theirs = theirs.astype(ours.dtype, copy=False)
         seen += ours.nbytes
@@ -397,75 +445,125 @@ def _same_contents(a, kept) -> tuple:
     return True, seen
 
 
-def _recognise(arrays, key):
-    """The entry of ``_PLAN_CACHE`` built from this graph, if any, and
-    how it was known: ``identity`` (every array is the jax.Array the
-    entry was built from), ``compare`` (by content) or ``new``."""
-    with trace_lib.span("pagerank.fingerprint") as sp:
-        examined = 0
-        for entry in _PLAN_CACHE:
-            if entry.key != key:
-                continue
-            how = "identity"
-            for a, kept, ref in zip(arrays, entry.kept, entry.refs):
-                if ref is not None and ref() is a:
-                    continue
-                how = "compare"
-                same, seen = _same_contents(a, kept)
-                examined += seen
-                if not same:
-                    break
-            else:
-                sp.set(bytes=examined, how=how)
-                return entry
-        sp.set(bytes=examined, how="new")
-        return None
+def _known(arrays, entry, start: int = 0, stop: int = None) -> tuple:
+    """(how ``arrays`` are ``entry``'s graph over the elements
+    [``start``, ``stop``): ``identity`` (every array is the jax.Array
+    the entry was built from), ``compare`` (by content), None where
+    one differs; the bytes read)."""
+    how, examined = "identity", 0
+    for a, kept, ref in zip(arrays, entry.kept, entry.refs):
+        if ref is not None and ref() is a:
+            continue
+        how = "compare"
+        same, seen = _same_contents(a, kept, start, stop)
+        examined += seen
+        if not same:
+            return None, examined
+    return how, examined
 
 
-def _cached_plan(src, dst, n: int, weights, tail: tuple, build,
+def _recognise(arrays, entries, stop: int = None) -> tuple:
+    """(the first of ``entries`` whose graph ``arrays`` are in their
+    first ``stop`` elements (None: in all), how it was known — or
+    (None, ``new``) — and the bytes read). ``entries`` is an iterator,
+    left behind the entry returned."""
+    examined = 0
+    for entry in entries:
+        how, seen = _known(arrays, entry, 0, stop)
+        examined += seen
+        if how is not None:
+            return entry, how, examined
+    return None, "new", examined
+
+
+def _cached_plan(src, dst, n: int, weights, tail: tuple, build, launch,
                  compact: bool, devices: int = 1):
-    """The prepared plan of this graph for the caller ``tail`` names:
-    the cached one, or ``build()``'s (None = refused), cached with its
-    cost in per-device bytes (``compact``: the compact tables' price,
-    else the expanded ones', over ``devices``) unless that exceeds the
-    budget."""
+    """``launch(prepared)``, the ranks as the device will have them, on
+    the prepared plan of this graph for the caller ``tail`` names: the
+    cached one, or ``build()``'s (None = refused, and None is what
+    comes back), cached with its cost in per-device bytes (``compact``:
+    the compact tables' price, else the expanded ones', over
+    ``devices``) unless that exceeds the budget. A cached plan that is
+    known by content is launched after the probe and confirmed under
+    the launch (the comment over ``_PLAN_CACHE``)."""
     arrays = tuple(a if isinstance(a, (jax.Array, np.ndarray))
                    else np.asarray(a)
                    for a in (src, dst, weights) if a is not None)
     key = (n, tuple(a.shape[0] for a in arrays[:2]),
            weights is not None) + tail
-    entry = _recognise(arrays, key)
+    entries = iter([e for e in _PLAN_CACHE if e.key == key])
+    entry, how, examined = _recognise(arrays, entries, _PROBE_CHUNK)
+    outcome = "identity" if how == "identity" else "compared_first"
+    under_launch = how == "compare"
+    out = _launch(entry, launch) if under_launch else None
+    with trace_lib.span("pagerank.fingerprint") as sp:
+        if under_launch:
+            # the device iterates; the calling thread has nothing else
+            rest, seen = _known(arrays, entry, _PROBE_CHUNK)
+            examined += seen
+            confirmed = rest is not None
+            outcome = "confirmed" if confirmed else "discarded"
+            sp.set(confirmed=confirmed)
+            if not confirmed:
+                # edited past the probe: those ranks are another
+                # graph's. One speculation a call: the later entries
+                # are compared in full before anything is launched
+                out = None
+                entry, how, seen = _recognise(arrays, entries)
+                examined += seen
+        sp.set(bytes=examined, how=how, under_launch=under_launch)
+    _RECOGNISED[outcome] += 1
+    hit = entry is not None
+    if not hit:
+        entry = _build_entry(arrays, key, build, compact, devices)
+        if entry is None:
+            return None
+        out = launch(entry.prepared)
+    elif out is None:
+        out = _launch(entry, launch)
+    _LAST_PLAN.update(entry.attrs, hit=hit, recognised=outcome)
+    return out
+
+
+def _launch(entry, launch):
+    """The caller's launch on a cached plan, ``matrel.pagerank.plan``
+    saying ``hit`` and the plan's layout ahead of it."""
     with trace_lib.span("pagerank.plan") as sp:
-        hit = entry is not None
-        sp.set(hit=hit)
-        if hit:
-            prepared, attrs = entry.prepared, entry.attrs
-        else:
-            prepared = build()
-            if prepared is None:
-                return None
-            attrs = _plan_attrs(prepared[0], arrays[0].shape[0], compact,
-                                devices)
-            from matrel_tpu.ops.pallas_spmv import resident_bytes
-            hub_slots = attrs["hub_slots"]      # 0 off the compact path
-            own = attrs["slots"] - hub_slots
-            cost = -(-(resident_bytes(own, hub_slots) if compact
-                       else own * _EXPANDED_BYTES_A_SLOT) // devices)
-            if cost <= _PLAN_CACHE_MAX_BYTES:
-                total = sum(e.cost for e in _PLAN_CACHE)
-                while _PLAN_CACHE and total + cost > _PLAN_CACHE_MAX_BYTES:
-                    total -= _PLAN_CACHE.pop(0).cost
-                # the build accepted the ids (all in [0, n)), so int32
-                # holds
-                kept = tuple(np.array(a, dtype=t) for a, t in zip(
-                    arrays, (np.int32, np.int32, np.float32)))
-                refs = tuple(weakref.ref(a) if isinstance(a, jax.Array)
-                             else None for a in arrays)
-                _PLAN_CACHE.append(_CachedPlan(key, kept, refs, prepared,
-                                               cost, attrs))
+        sp.set(hit=True, **entry.attrs)
+    return launch(entry.prepared)
+
+
+def _build_entry(arrays, key, build, compact: bool, devices: int):
+    """``build()``'s plan as a cache entry (None = refused), appended
+    to ``_PLAN_CACHE`` with its copies of ``arrays`` where its cost
+    fits the budget, the oldest entries making room; above it the
+    entry is handed back alone and keeps no copy."""
+    with trace_lib.span("pagerank.plan") as sp:
+        sp.set(hit=False)
+        prepared = build()
+        if prepared is None:
+            return None
+        attrs = _plan_attrs(prepared[0], arrays[0].shape[0], compact,
+                            devices)
         sp.set(**attrs)
-        _LAST_PLAN.update(attrs, hit=hit)
-        return prepared
+        from matrel_tpu.ops.pallas_spmv import resident_bytes
+        hub_slots = attrs["hub_slots"]      # 0 off the compact path
+        own = attrs["slots"] - hub_slots
+        cost = -(-(resident_bytes(own, hub_slots) if compact
+                   else own * _EXPANDED_BYTES_A_SLOT) // devices)
+        if cost > _PLAN_CACHE_MAX_BYTES:
+            return _CachedPlan(key, (), (), prepared, cost, attrs)
+        total = sum(e.cost for e in _PLAN_CACHE)
+        while _PLAN_CACHE and total + cost > _PLAN_CACHE_MAX_BYTES:
+            total -= _PLAN_CACHE.pop(0).cost
+        # the build accepted the ids (all in [0, n)), so int32 holds
+        kept = tuple(np.array(a, dtype=t) for a, t in zip(
+            arrays, (np.int32, np.int32, np.float32)))
+        refs = tuple(weakref.ref(a) if isinstance(a, jax.Array)
+                     else None for a in arrays)
+        entry = _CachedPlan(key, kept, refs, prepared, cost, attrs)
+        _PLAN_CACHE.append(entry)
+        return entry
 
 
 def _plan_attrs(plan, edges: int, compact: bool, devices: int = 1) -> dict:
@@ -549,20 +647,22 @@ def _pagerank_onehot(src, dst, n: int, rounds: int, alpha: float,
                           upload_s=round(placed.dur_ms / 1e3, 3))
         return prepared
 
+    def launch(prepared):
+        if compact:
+            # compact-table Pallas executor: faster and ~17× less HBM
+            # than the expanded tables (BASELINE row 5). passes=3
+            # (default) is f32-faithful like the expanded path; callers
+            # may pass 2 for ranking-grade (~2^-16 per matvec) at
+            # higher speed
+            return run_pagerank_compact(prepared, rounds, alpha,
+                                        passes=passes)
+        return run_pagerank_onehot(prepared, rounds, alpha)
+
     # keyed by the executor: a plan built for the compact one may lie
     # in chunks, which the expanded tables cannot be made from
-    prepared = _cached_plan(src, dst, n, weights,
-                            ("compact",) if compact else (), build, compact)
-    if prepared is None:
-        return None
-    if compact:
-        # compact-table Pallas executor: faster and ~17× less HBM than
-        # the expanded tables (BASELINE row 5). passes=3 (default) is
-        # f32-faithful like the expanded path; callers may pass 2 for
-        # ranking-grade (~2^-16 per matvec) at higher speed
-        return run_pagerank_compact(prepared, rounds, alpha,
-                                    passes=passes)
-    return run_pagerank_onehot(prepared, rounds, alpha)
+    return _cached_plan(src, dst, n, weights,
+                        ("compact",) if compact else (), build, launch,
+                        compact)
 
 
 def _pagerank_compact_sharded(src, dst, n: int, rounds: int, alpha: float,
@@ -586,20 +686,19 @@ def _pagerank_compact_sharded(src, dst, n: int, rounds: int, alpha: float,
         pc.shard_compact_tables(prepared[0], mesh)   # place now
         return prepared
 
-    prepared = _cached_plan(src, dst, n, weights, (mesh, "compact"), build,
-                            True, mesh.size)
-    if prepared is None:
-        return None
-    plan, dangling = prepared
-    from matrel_tpu.config import resolve_interpret
-    interpret = resolve_interpret(interpret)
-    tables = pc.shard_compact_tables(plan, mesh)
-    ov = plan.overflow
-    run = _compact_sharded_loop(
-        int(n), int(rounds), float(alpha),
-        (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO),
-        len(ov), int(passes), bool(interpret), mesh)
-    return _dispatch(run, *tables, jnp.asarray(dangling), *ov)
+    def launch(prepared):
+        plan, dangling = prepared
+        from matrel_tpu.config import resolve_interpret
+        tables = pc.shard_compact_tables(plan, mesh)
+        ov = plan.overflow
+        run = _compact_sharded_loop(
+            int(n), int(rounds), float(alpha),
+            (plan.n_rows, plan.n_cols, plan.block, spmv_lib.LO),
+            len(ov), int(passes), bool(resolve_interpret(interpret)), mesh)
+        return _dispatch(run, *tables, jnp.asarray(dangling), *ov)
+
+    return _cached_plan(src, dst, n, weights, (mesh, "compact"), build,
+                        launch, True, mesh.size)
 
 
 @functools.lru_cache(maxsize=32)
@@ -651,14 +750,15 @@ def _pagerank_onehot_sharded(src, dst, n: int, rounds: int, alpha: float,
 
     # Mesh compares identity-precise: same-shaped meshes over different
     # devices must not share cached (device-committed) plans
-    prepared = _cached_plan(src, dst, n, weights, (mesh,), build, False, p)
-    if prepared is None:
-        return None
-    plan, dangling = prepared
-    run = _onehot_sharded_runner(int(n), int(rounds), float(alpha),
-                                 (plan.n_rows, plan.n_cols, plan.block),
-                                 len(plan.arrays()), mesh)
-    return _dispatch(run, *plan.arrays(), dangling)
+    def launch(prepared):
+        plan, dangling = prepared
+        run = _onehot_sharded_runner(int(n), int(rounds), float(alpha),
+                                     (plan.n_rows, plan.n_cols, plan.block),
+                                     len(plan.arrays()), mesh)
+        return _dispatch(run, *plan.arrays(), dangling)
+
+    return _cached_plan(src, dst, n, weights, (mesh,), build, launch,
+                        False, p)
 
 
 @functools.lru_cache(maxsize=32)

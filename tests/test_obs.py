@@ -1101,6 +1101,7 @@ class TestProfilerTier:
         from matrel_tpu.obs import trace as trace_lib
         from matrel_tpu.workloads import pagerank as pr
         monkeypatch.setattr(pr, "_PLAN_CACHE", [])
+        monkeypatch.setattr(pr, "_PROBE_CHUNK", 512)
         src = rng.integers(0, 300, 2000).astype(np.int32)
         dst = rng.integers(0, 300, 2000).astype(np.int32)
         counts = pr.path_counts()
@@ -1121,18 +1122,23 @@ class TestProfilerTier:
             [r for r in recs if r["qid"] == root["qid"]
              and r is not root] for root in roots)
         # the second call knows its graph by comparing both arrays
-        # with the copies the first call's plan kept
-        for kids, hit, known in ((first, False, {"bytes": 0, "how": "new"}),
-                                 (second, True, {"bytes": 2 * 4 * 2000,
-                                                 "how": "compare"})):
+        # with the copies the first call's plan kept: PR 53: their
+        # first chunks, then the launch, then the rest behind it
+        for kids, hit, known in (
+                (first, False, {"bytes": 0, "how": "new",
+                                "under_launch": False}),
+                (second, True, {"bytes": 2 * 4 * 2000, "how": "compare",
+                                "under_launch": True, "confirmed": True})):
             names = [r["name"] for r in sorted(
                 kids, key=lambda r: r["start_ns"])]
             # PR 33: a build times its host fill and its upload
-            assert names == ["matrel.pagerank.fingerprint",
-                             "matrel.pagerank.plan"] + (
-                [] if hit else ["matrel.pagerank.plan.build",
-                                "matrel.pagerank.plan.upload"]) + [
-                             "matrel.pagerank.dispatch"]
+            assert names == (
+                ["matrel.pagerank.plan", "matrel.pagerank.dispatch",
+                 "matrel.pagerank.fingerprint"] if hit else
+                ["matrel.pagerank.fingerprint", "matrel.pagerank.plan",
+                 "matrel.pagerank.plan.build",
+                 "matrel.pagerank.plan.upload",
+                 "matrel.pagerank.dispatch"])
             by = {r["name"]: r for r in kids}
             said = by["matrel.pagerank.plan"]["attrs"]
             assert said["hit"] is hit

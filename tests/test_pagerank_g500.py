@@ -462,10 +462,11 @@ def test_pagerank_edges_auto_answers_a_skewed_graph_in_chunks(
     again = np.asarray(pr.pagerank_edges(src, dst, v, rounds=10, alpha=0.85))
     hit = pr.last_plan()
     assert hit["hit"] is True and len(pr._PLAN_CACHE) == 1
-    assert {k: hit[k] for k in said if k not in ("hit", "build_s",
-                                                 "upload_s")} \
-        == {k: said[k] for k in said if k not in ("hit", "build_s",
-                                                  "upload_s")}
+    assert (said["recognised"], hit["recognised"]) == ("compared_first",
+                                                       "confirmed")
+    told_once = ("hit", "recognised", "build_s", "upload_s")
+    assert {k: hit[k] for k in said if k not in told_once} \
+        == {k: said[k] for k in said if k not in told_once}
     np.testing.assert_array_equal(again, got.astype(np.float32))
 
 

@@ -123,7 +123,8 @@ class TestEdgePageRank:
 
 def _recognition_cases():
     """name -> (weighted first call, what the second call is handed,
-    how the plan cache must know it). ``g`` is the first call's
+    how the plan cache must know it, the call's key of
+    ``recognition_counts()``). ``g`` is the first call's
     (src, dst, weights-or-None); an ``edit`` mutates it IN PLACE."""
     import jax.numpy as jnp
 
@@ -139,32 +140,41 @@ def _recognition_cases():
         return tuple(None if a is None else jnp.array(a) for a in g)
 
     return {
-        "same_arrays": (False, lambda g: g, "compare"),
+        "same_arrays": (False, lambda g: g, "compare", "confirmed"),
         "equal_in_new_arrays": (
-            False, lambda g: (g[0].copy(), g[1].copy(), None), "compare"),
+            False, lambda g: (g[0].copy(), g[1].copy(), None), "compare",
+            "confirmed"),
         "equal_as_int64": (
             False, lambda g: (g[0].astype(np.int64),
-                              g[1].astype(np.int64), None), "compare"),
+                              g[1].astype(np.int64), None), "compare",
+            "confirmed"),
         "equal_strided_and_list": (
             False, lambda g: (np.repeat(g[0], 2)[::2], g[1].tolist(), None),
-            "compare"),
-        "edit_src_first_chunk": (False, edit(0, "first"), "new"),
-        "edit_src_middle_chunk": (False, edit(0, "middle"), "new"),
-        "edit_dst_last_chunk": (False, edit(1, "last"), "new"),
+            "compare", "confirmed"),
+        # the probe reads the first chunk: nothing is launched for it
+        "edit_src_first_chunk": (False, edit(0, "first"), "new",
+                                 "compared_first"),
+        # past the probe: launched on the cached plan, then found out
+        "edit_src_middle_chunk": (False, edit(0, "middle"), "new",
+                                  "discarded"),
+        "edit_dst_last_chunk": (False, edit(1, "last"), "new", "discarded"),
         "weights_equal_as_float64": (
             True, lambda g: (g[0], g[1], g[2].astype(np.float64)),
-            "compare"),
+            "compare", "confirmed"),
         "weights_added": (
             False, lambda g: (g[0], g[1],
                               (1 + np.arange(len(g[0])) % 3)
-                              .astype(np.float32)), "new"),
-        "weights_dropped": (True, lambda g: (g[0], g[1], None), "new"),
-        "weights_edited_last_chunk": (True, edit(2, "last"), "new"),
-        "jax_same_objects": (True, lambda g: g, "identity"),
-        "jax_equal_in_new_arrays": (True, as_jax, "compare"),
+                              .astype(np.float32)), "new",
+            "compared_first"),
+        "weights_dropped": (True, lambda g: (g[0], g[1], None), "new",
+                            "compared_first"),
+        "weights_edited_last_chunk": (True, edit(2, "last"), "new",
+                                      "discarded"),
+        "jax_same_objects": (True, lambda g: g, "identity", "identity"),
+        "jax_equal_in_new_arrays": (True, as_jax, "compare", "confirmed"),
         "jax_then_numpy": (
             False, lambda g: (np.asarray(g[0]), np.asarray(g[1]), None),
-            "compare"),
+            "compare", "confirmed"),
     }
 
 
@@ -183,7 +193,9 @@ class TestPlanRecognition:
         monkeypatch.setattr(pr, "_PROBE_CHUNK", 64)
 
     def _call(self, g):
-        """(ranks, attrs of the call's spans by name)."""
+        """(ranks, attrs of the call's spans by name: of the newest
+        where a name comes twice; the order of the fingerprint, plan
+        and dispatch records)."""
         from matrel_tpu.obs import trace as trace_lib
         from matrel_tpu.workloads import pagerank as pr
         recs = []
@@ -192,13 +204,17 @@ class TestPlanRecognition:
             r = np.asarray(pr.pagerank_edges(
                 g[0], g[1], self.N, rounds=self.ROUNDS, impl="onehot",
                 weights=g[2]))
-        return r, {rec["name"]: rec.get("attrs", {}) for rec in recs}
+        recs.sort(key=lambda rec: rec["span_id"])
+        return (r, {rec["name"]: rec.get("attrs", {}) for rec in recs},
+                [rec["name"] for rec in recs if rec["name"] in (
+                    "pagerank.fingerprint", "pagerank.plan",
+                    "pagerank.dispatch")])
 
     @pytest.mark.parametrize("case", sorted(_recognition_cases()))
     def test_second_call(self, case, rng):
         import jax.numpy as jnp
         from matrel_tpu.workloads import pagerank as pr
-        weighted, second, how = _recognition_cases()[case]
+        weighted, second, how, outcome = _recognition_cases()[case]
         # five out-edges a node, so that one weight moves the ranks
         g = (rng.permutation(np.arange(self.M, dtype=np.int32) % self.N),
              rng.integers(0, self.N, self.M).astype(np.int32),
@@ -206,20 +222,42 @@ class TestPlanRecognition:
              if weighted else None)
         if case.startswith("jax_"):
             g = tuple(None if a is None else jnp.asarray(a) for a in g)
-        r1, spans = self._call(g)
-        assert spans["pagerank.fingerprint"] == {"bytes": 0, "how": "new"}
+        r1, spans, order = self._call(g)
+        assert spans["pagerank.fingerprint"] == {
+            "bytes": 0, "how": "new", "under_launch": False}
         assert spans["pagerank.plan"]["hit"] is False
+        assert order == ["pagerank.fingerprint", "pagerank.plan",
+                         "pagerank.dispatch"]
         built = spans["pagerank.plan"]
+        counts = pr.recognition_counts()
         g2 = second(g)
-        r2, spans = self._call(g2)
-        assert spans["pagerank.fingerprint"]["how"] == how
+        r2, spans, order = self._call(g2)
+        assert pr.recognition_counts() == {
+            **counts, outcome: counts[outcome] + 1}
+        assert pr.last_plan()["recognised"] == outcome
+        said = spans["pagerank.fingerprint"]
+        assert said["how"] == how
+        # the comparison ran behind a launch, and whether it bore it out
+        under = outcome in ("confirmed", "discarded")
+        assert said["under_launch"] is under
+        assert said.get("confirmed") == (
+            (outcome == "confirmed") if under else None)
+        assert order == {
+            "confirmed": ["pagerank.plan", "pagerank.dispatch",
+                          "pagerank.fingerprint"],
+            "discarded": ["pagerank.plan", "pagerank.dispatch",
+                          "pagerank.fingerprint", "pagerank.plan",
+                          "pagerank.dispatch"],
+        }.get(outcome, ["pagerank.fingerprint", "pagerank.plan",
+                        "pagerank.dispatch"])
         assert spans["pagerank.plan"]["hit"] is (how != "new")
         if how != "new":    # a hit says of the plan what its build said
             assert spans["pagerank.plan"] == {**built, "hit": True}
             assert built["layout"] == "blocks" and built["edges"] == self.M
             assert pr.last_plan() == {**spans["pagerank.plan"],
-                                      "impl": "onehot"}
-        examined = spans["pagerank.fingerprint"]["bytes"]
+                                      "impl": "onehot",
+                                      "recognised": outcome}
+        examined = said["bytes"]
         if how == "identity":
             assert examined == 0
         elif how == "compare":
@@ -234,7 +272,7 @@ class TestPlanRecognition:
         assert len(pr._PLAN_CACHE) == 2
         assert not np.array_equal(r2, r1)
         pr._PLAN_CACHE.clear()
-        fresh, _ = self._call(tuple(
+        fresh, _, _ = self._call(tuple(
             None if a is None else np.array(a) for a in g2))
         np.testing.assert_array_equal(r2, fresh)
 
@@ -256,11 +294,239 @@ class TestPlanRecognition:
             src, dst = np.repeat(src, 2)[::2], np.repeat(dst, 2)[::2]
         tracemalloc.start()
         try:
-            assert pr._recognise((src, dst), key) is pr._PLAN_CACHE[0]
+            found, how, seen = pr._recognise((src, dst),
+                                             iter(pr._PLAN_CACHE))
+            assert found is pr._PLAN_CACHE[0] and how == "compare"
+            assert seen == 2 * 4 * m
             _, peak = tracemalloc.get_traced_memory()
         finally:
             tracemalloc.stop()
         assert peak < 4 * m // 2
+
+
+_EXECUTORS = ("onehot", "compact", "onehot_sharded", "compact_sharded")
+
+
+class TestLaunchThenConfirm:
+    """A cached plan that has to be known by content is launched after
+    the probe and confirmed under the launch (PR 53), in all four
+    executors of the prepared-plan cache: the order, what a refuted
+    launch leaves behind (nothing but a count), and that the programs
+    launched are the ones launched before."""
+
+    N, M, ROUNDS, CHUNK = 100, 500, 3, 64
+
+    @pytest.fixture(params=_EXECUTORS)
+    def call(self, request, monkeypatch, mesh8):
+        """``call(g)`` -> (ranks, what happened, in order): ``launch``
+        for a jitted loop's call, ``build`` for a plan build and
+        (``read``, array length, start, bytes) for a comparison of
+        elements from ``start`` on; the executor under ``call.path``."""
+        from matrel_tpu import config as config_lib
+        from matrel_tpu.workloads import pagerank as pr
+        path = request.param
+        if path.startswith("compact"):
+            monkeypatch.setattr(
+                config_lib, "_default_config",
+                config_lib.MatrelConfig(pallas_interpret=True))
+        monkeypatch.setattr(pr, "_PLAN_CACHE", [])
+        monkeypatch.setattr(pr, "_PROBE_CHUNK", self.CHUNK)
+        events = []
+        dispatch, compare = pr._dispatch, pr._same_contents
+        prepare = pr.prepare_pagerank_onehot
+
+        def spy_dispatch(run, *args):
+            events.append("launch")
+            return dispatch(run, *args)
+
+        def spy_compare(a, kept, start=0, stop=None):
+            same, seen = compare(a, kept, start, stop)
+            events.append(("read", kept.shape[0], start, seen))
+            return same, seen
+
+        def spy_prepare(*args, **kw):
+            events.append("build")
+            return prepare(*args, **kw)
+
+        monkeypatch.setattr(pr, "_dispatch", spy_dispatch)
+        monkeypatch.setattr(pr, "_same_contents", spy_compare)
+        monkeypatch.setattr(pr, "prepare_pagerank_onehot", spy_prepare)
+        mesh = mesh8 if path.endswith("_sharded") else None
+
+        def call(g):
+            del events[:]
+            r = np.asarray(pr.pagerank_edges(
+                g[0], g[1], self.N, rounds=self.ROUNDS, impl="onehot",
+                mesh=mesh))
+            assert pr.last_plan()["impl"] == path
+            return r, list(events)
+
+        call.path = path
+        return call
+
+    def _graph(self, rng):
+        return (rng.permutation(np.arange(self.M, dtype=np.int32) % self.N),
+                rng.integers(0, self.N, self.M).astype(np.int32))
+
+    @staticmethod
+    def _edited(g, where):
+        """A copy of ``g`` with one destination moved to another node."""
+        src, dst = g[0].copy(), g[1].copy()
+        dst[where] = (dst[where] + 1) % 100
+        return src, dst
+
+    def test_an_equal_graph_is_launched_then_compared(self, call, rng):
+        from matrel_tpu.workloads import pagerank as pr
+        g = self._graph(rng)
+        first, events = call(g)
+        assert events == ["build", "launch"]
+        counts, paths = pr.recognition_counts(), pr.path_counts()
+        again, events = call((g[0].copy(), g[1].copy()))
+        at = events.index("launch")
+        # at most one chunk an array before the launch, the rest behind
+        assert events[:at] == [("read", self.M, 0, 4 * self.CHUNK)] * 2
+        assert events[at + 1:] == [
+            ("read", self.M, self.CHUNK, 4 * (self.M - self.CHUNK))] * 2
+        said = pr.last_plan()
+        assert said["hit"] is True and said["recognised"] == "confirmed"
+        assert pr.recognition_counts() == {
+            **counts, "confirmed": counts["confirmed"] + 1}
+        assert pr.path_counts() == {**paths,
+                                    call.path: paths[call.path] + 1}
+        np.testing.assert_array_equal(again, first)
+
+    def test_an_edit_in_the_last_chunk_drops_the_launched_ranks(self, call,
+                                                                rng):
+        from matrel_tpu.workloads import pagerank as pr
+        g = self._graph(rng)
+        first, _ = call(g)
+        counts, paths = pr.recognition_counts(), pr.path_counts()
+        g[1][-1] = (g[1][-1] + 1) % 100     # in place, as a caller would
+        got, events = call(g)
+        assert [e for e in events if isinstance(e, str)] \
+            == ["launch", "build", "launch"]
+        said = pr.last_plan()
+        assert said["hit"] is False and said["recognised"] == "discarded"
+        assert pr.recognition_counts() == {
+            **counts, "discarded": counts["discarded"] + 1}
+        # the dropped run is no call of anything
+        assert pr.path_counts() == {**paths,
+                                    call.path: paths[call.path] + 1}
+        assert len(pr._PLAN_CACHE) == 2
+        assert not np.array_equal(got, first)
+        pr._PLAN_CACHE.clear()
+        fresh, _ = call((g[0].copy(), g[1].copy()))
+        np.testing.assert_array_equal(got, fresh)
+
+    def test_an_edit_in_the_first_chunk_launches_nothing_first(self, call,
+                                                               rng):
+        from matrel_tpu.workloads import pagerank as pr
+        g = self._graph(rng)
+        call(g)
+        counts = pr.recognition_counts()
+        g[0][3] = (g[0][3] + 1) % 100
+        _, events = call(g)
+        assert events == [("read", self.M, 0, 4 * self.CHUNK),
+                          "build", "launch"]
+        assert pr.last_plan()["recognised"] == "compared_first"
+        assert pr.recognition_counts() == {
+            **counts, "compared_first": counts["compared_first"] + 1}
+
+    def test_the_same_jax_arrays_are_launched_uncompared(self, call, rng):
+        import jax.numpy as jnp
+        from matrel_tpu.workloads import pagerank as pr
+        g = tuple(jnp.asarray(a) for a in self._graph(rng))
+        first, _ = call(g)
+        counts = pr.recognition_counts()
+        again, events = call(g)
+        assert events == ["launch"]
+        assert pr.last_plan()["recognised"] == "identity"
+        assert pr.recognition_counts() == {
+            **counts, "identity": counts["identity"] + 1}
+        np.testing.assert_array_equal(again, first)
+
+    @pytest.mark.parametrize("differ", ["in_the_first_chunk",
+                                        "in_the_last_chunk"])
+    def test_the_second_of_two_cached_graphs(self, call, rng, differ):
+        """Two plans under one key, the call's graph the younger one's:
+        the elder fails the probe, or passes it and is launched and
+        dropped — and then nothing more is launched on a guess."""
+        from matrel_tpu.workloads import pagerank as pr
+        elder = self._graph(rng)
+        younger = self._edited(elder,
+                               3 if differ == "in_the_first_chunk" else -1)
+        call(elder)
+        want, _ = call(younger)
+        assert len(pr._PLAN_CACHE) == 2
+        counts = pr.recognition_counts()
+        got, events = call((younger[0].copy(), younger[1].copy()))
+        outcome = ("confirmed" if differ == "in_the_first_chunk"
+                   else "discarded")
+        launches = [i for i, e in enumerate(events) if e == "launch"]
+        assert len(launches) == (1 if outcome == "confirmed" else 2)
+        if outcome == "discarded":
+            # the younger plan: both arrays in full, then its launch
+            assert events[-3:] == [("read", self.M, 0, 4 * self.M)] * 2 \
+                + ["launch"]
+        assert "build" not in events and len(pr._PLAN_CACHE) == 2
+        said = pr.last_plan()
+        assert said["hit"] is True and said["recognised"] == outcome
+        assert pr.recognition_counts() == {**counts,
+                                           outcome: counts[outcome] + 1}
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("runner", _EXECUTORS)
+def test_the_runners_lower_to_the_parents_programs(runner, mesh8):
+    """PR 53 moved WHEN a cached plan's loop is called, not what is
+    called: each executor's jitted loop lowers for the chip to the text
+    the parent commit (0c5b601) lowers it to, by SHA-256 recorded there
+    in this container's jax."""
+    import jax
+    import jax.numpy as jnp
+    from test_semiring import _lowered_hash
+    from matrel_tpu.ops import pallas_spmv as pc
+    from matrel_tpu.ops import spmv as spmv_lib
+    from matrel_tpu.workloads import pagerank as pr
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the recorded texts are jax 0.9.0's")
+    fixed = np.random.default_rng(7)
+    n, m = 1024, 6000
+    src, dst = fixed.integers(0, n, m), fixed.integers(0, n, m)
+    plan, dangling = pr.prepare_pagerank_onehot(src, dst, n)
+    static = (plan.n_rows, plan.n_cols, plan.block)
+    if runner == "onehot":
+        traced = pr._onehot_runner(
+            n, 10, 0.85, static, len(plan.arrays())).trace(
+            plan.arrays(), dangling)
+    elif runner == "compact":
+        traced = pr._compact_runner_loop(
+            n, 10, 0.85, static + (spmv_lib.LO,), len(plan.overflow), 3,
+            False).trace(pc.compact_tables(plan), plan.overflow, dangling)
+    elif runner == "onehot_sharded":
+        plan = spmv_lib.shard_plan(plan, mesh8)
+        traced = pr._onehot_sharded_runner(
+            n, 10, 0.85, static, len(plan.arrays()), mesh8).trace(
+            *plan.arrays(), dangling)
+    else:
+        traced = pr._compact_sharded_loop(
+            n, 10, 0.85, static + (spmv_lib.LO,), len(plan.overflow), 3,
+            False, mesh8).trace(
+            *pc.shard_compact_tables(plan, mesh8), jnp.asarray(dangling),
+            *plan.overflow)
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    kernels = text.count('\\22body\\22')
+    assert kernels == (1 if runner.startswith("compact") else 0)
+    assert _lowered_hash(text, kernels) == {
+        "onehot": "9cc252b40947e510001dc9064841ed15"
+                  "fcefa965ca8868f668f4102107ec4a54",
+        "compact": "ff30e3e87e0000d831681797edb15bd8"
+                   "0dffedf7e5f9c0afeca63a7ea06396a1",
+        "onehot_sharded": "fc9a9a4abb882b1ad19b90824869abe9"
+                          "2ec2a60b41b6a145f6a5ff145294a89d",
+        "compact_sharded": "8b302649cf1e4959a9252105a20141f2"
+                           "e4c032edfa0323581eb17a0c97821b61",
+    }[runner]
 
 
 class TestTriangleCount:
