@@ -84,6 +84,7 @@ PADDING_CONTRACT: Dict[str, Callable] = {
     "rank1": lambda n: CLEAN,         # a + u.vT of clean operands
     "sampled": lambda n: CLEAN,       # 0 op (0-rows x 0-cols): 0 / 0 = 0
     "semiring": lambda n: CLEAN,      # logical rows, zero pad
+    "mmchain": lambda n: CLEAN,       # logical slices in, zero pad out
     "select_value": _select_value_effect,
     "select_index": lambda n: CLEAN,  # where(keep, x, 0) over x == 0
     "select_block": lambda n: CLEAN,

@@ -276,6 +276,8 @@ class Lowerer:
                     "xla", ev(a), ev(b), self.mesh, self.config))
         if k == "semiring":
             return self._semiring_product(node, ev)
+        if k == "mmchain":
+            return self._mmchain(node, ev)
         if k == "transpose":
             return ev(node.children[0]).T
         if k == "matmul":
@@ -906,6 +908,26 @@ class Lowerer:
             y = semiring_apply(m, plan, col, node.attrs["reduce"],
                                interpret=pallas_interpret_mode(self.config))
         return self._pad_to_node(y[:, None], node)
+
+    def _mmchain(self, node: MatExpr, ev) -> Array:
+        """``mmchain(X, v[, w])`` = ``t(X) * (w .* (X * v))`` in ONE pass
+        over X where it lies (ops/mmchain.py: row tiles of the table
+        read from HBM once, both products on the vector unit in
+        float32), as the planner stamped it (planner.mmchain_plan's
+        facts: a node it declined never reaches the lowering, it was
+        written back as its two products)."""
+        from matrel_tpu.config import pallas_interpret_mode
+        from matrel_tpu.ops import mmchain as mmchain_lib
+        facts = node.attrs["mmchain"]
+        x, v, *w = node.children
+        n, k = x.shape
+        self._ran("pallas_mmchain")
+        with trace_lib.phase("mmchain.plan", hit=False, **facts):
+            out = mmchain_lib.mmchain(
+                ev(x), ev(v)[:k, :1], *(ev(c)[:n, :1] for c in w),
+                tile=facts["tile_rows"],
+                interpret=pallas_interpret_mode(self.config))
+        return self._pad_to_node(out, node)
 
     def _long_contraction(self, node: MatExpr, ev) -> Optional[Array]:
         """A product over a LONG float32 contraction (the regression's
@@ -1553,6 +1575,12 @@ def _hbm_meta(opts, mesh, cfg) -> Dict:
     if products:
         meta["hbm_plan_bytes"] = max(p["hbm_plan_bytes"] for p in products)
         meta["products"] = products
+        chains = [rec["mmchain"] for rec in products if "mmchain" in rec]
+        if chains:
+            # planner.mmchain_plan's facts of every fused chain, and of
+            # every chain the planner un-fused (``why_not``): a
+            # ``matrel.mmchain.plan`` span each at every dispatch
+            meta["mmchain"] = chains
         for rec in products:
             with trace_lib.span("plan.strategy", **rec):
                 pass

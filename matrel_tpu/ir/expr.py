@@ -18,6 +18,9 @@ Node set mirrors the reference's logical operators:
   Semiring (an element-sparse leaf times one column under (max, ×) or
   (min, ×): γ_max/min by row over a column join, MatRel's "aggregate
   over a join" whose (sum, mul) case is the matrix product).
+  MMChain (t(X) · (w ∘ (X · v)) over a tall dense table and one column:
+  SystemML's fused mmchain, the product every iterative solver over a
+  tall table turns on; its (n × 1) intermediate is no node).
 
 All shape/sparsity metadata lives on the nodes so the optimizer runs as pure
 Python before any tracing.
@@ -358,6 +361,38 @@ def semiring(reduce: str, s: MatExpr, x: MatExpr) -> MatExpr:
                    {"reduce": reduce, "merge": "mul"})
 
 
+#: The widest ``v`` the rule writes an ``mmchain`` node for: a narrow
+#: right side keeps the chain a pass over the table (the kernel itself
+#: takes one column, planner.mmchain_plan; a wider one un-fuses by name).
+MMCHAIN_NARROW_MAX = COO_NARROW_MAX
+
+
+def mmchain(x: MatExpr, v: MatExpr, w: Optional[MatExpr] = None) -> MatExpr:
+    """``t(X) · (w ∘ (X · v))`` (``w`` None: ``t(X) · (X · v)``) for a
+    dense leaf ``X`` (n × k), ``v`` (k × m) and weights ``w`` (n × 1):
+    SystemML's fused ``mmchain`` (XtXv / XtwXv), the product
+    LinearRegCG, GLM, MLogreg and L2SVM turn on.
+    ``rules.mmchain_product`` rewrites the bracketed chain to this node
+    and the DSL never writes it. Its children are the table and the
+    vectors, so the (n × m) intermediate ``X · v`` is priced and stored
+    by no pass; where one pass over ``X`` answers it
+    (planner.mmchain_plan) the executor reads the table once
+    (ops/mmchain.py), elsewhere the planner writes the two products
+    back (planner.unfused_mmchain) and they lower as they always did."""
+    if x.kind != "leaf":
+        raise ValueError("mmchain: the table must be a dense leaf")
+    n, k = x.shape
+    if v.shape[0] != k:
+        raise ValueError(f"mmchain shape mismatch: {x.shape} against "
+                         f"{v.shape}")
+    if w is not None and w.shape != (n, 1):
+        raise ValueError(f"mmchain: weights {w.shape} are no column of "
+                         f"{n} rows")
+    kids = (x, v) if w is None else (x, v, w)
+    return MatExpr("mmchain", kids, (k, v.shape[1]), None,
+                   {"weighted": w is not None})
+
+
 def scalar_op(op: str, a: MatExpr, s: float) -> MatExpr:
     if op not in SCALAR_OPS:
         raise ValueError(f"unknown scalar op {op}")
@@ -561,6 +596,8 @@ def pretty(e: MatExpr, indent: int = 0, mesh=None,
         extra = f" op={e.attrs['op']} v={e.attrs['value']}"
     elif e.kind == "semiring":
         extra = f" ({e.attrs['reduce']}, {e.attrs['merge']})"
+    elif e.kind == "mmchain":
+        extra = " weighted" if e.attrs["weighted"] else ""
     elif e.kind == "agg":
         extra = f" {e.attrs['agg']}/{e.attrs['axis']}"
     elif e.kind == "matmul" and "strategy" in e.attrs:

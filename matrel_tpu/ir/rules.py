@@ -37,6 +37,10 @@ Rules, mirroring the reference's Catalyst batch:
      (max, ×) / (min, ×) sibling of the matrix product; the (n × m)
      join is never priced or built (a round of label propagation:
      LDBC Graphalytics' WCC).
+  R11 fused chain: t(X)·(X·v) and t(X)·(w ∘ (X·v)) over one dense leaf
+     X and a narrow v → mmchain(X, v[, w]) (SystemML's mmchain: a
+     round of LinearRegCG, GLM, MLogreg, L2SVM). After the chain DP
+     only: written flat, t(X)·X·p is a chain the DP brackets first.
 
 Each rule is a bottom-up tree transform; the batch runs to fixpoint with a
 bound, Catalyst-style.
@@ -49,8 +53,9 @@ from typing import Callable, List, Optional
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.ir import chain as chain_lib
 from matrel_tpu.ir.expr import (
-    COO_NARROW_MAX, SEMIRING_REDUCES, MatExpr, agg, elemwise, matmul,
-    sampled, scalar_op, select_index, semiring, transpose,
+    COO_NARROW_MAX, MMCHAIN_NARROW_MAX, SEMIRING_REDUCES, MatExpr, agg,
+    elemwise, matmul, mmchain, sampled, scalar_op, select_index, semiring,
+    transpose,
 )
 
 Rule = Callable[[MatExpr], Optional[MatExpr]]
@@ -293,6 +298,46 @@ def semiring_product(e: MatExpr) -> Optional[MatExpr]:
     return semiring(e.attrs["agg"], s, x)
 
 
+# -- R11: fused chain --------------------------------------------------------
+
+
+def _same_leaf(a: MatExpr, b: MatExpr) -> bool:
+    """Two dense leaves of one matrix object (the SQL front end makes a
+    fresh leaf a mention; CSE runs after the rules)."""
+    return (a.kind == b.kind == "leaf"
+            and a.attrs["matrix"] is b.attrs["matrix"])
+
+
+def mmchain_product(e: MatExpr) -> Optional[MatExpr]:
+    """t(X)·(X·v) → mmchain(X, v); t(X)·(w ∘ (X·v)) and t(X)·((X·v) ∘ w)
+    → mmchain(X, v, w), for ONE dense leaf X on both sides, ``v`` of at
+    most MMCHAIN_NARROW_MAX columns and ``w`` a column of X's rows. It
+    fires by what it sees; whether one pass over X answers the node is
+    the planner's to say (planner.mmchain_plan: a mesh, a bfloat16
+    table, another ``matmul_precision`` un-fuse it, by name). A Gram
+    (t(X)·X), ``t(X)·y`` over a leaf ``y``, an element-sparse X and the
+    normal equations' ``solve`` are not the pattern."""
+    if e.kind != "matmul":
+        return None
+    xt, right = e.children
+    if xt.kind != "transpose" or xt.children[0].kind != "leaf":
+        return None
+    x = xt.children[0]
+    w = None
+    if right.kind == "elemwise" and right.attrs["op"] == "mul":
+        a, b = right.children
+        if b.kind == "matmul":
+            a, b = b, a
+        if b.shape != (x.shape[0], 1):
+            return None
+        right, w = a, b
+    if (right.kind != "matmul" or not _same_leaf(right.children[0], x)
+            or not 0 < right.children[1].shape[1] <= MMCHAIN_NARROW_MAX
+            or (w is not None and right.shape[1] != 1)):
+        return None
+    return mmchain(x, right.children[1], w)
+
+
 # -- R7: solve fusion --------------------------------------------------------
 
 
@@ -328,12 +373,14 @@ _RULES: List[Rule] = [
     rank1_pushdown,
     sampled_product,
     semiring_product,
+    mmchain_product,
 ]
 # ahead of the chain DP an inverse stays a factor of its chain: fused
 # with its left-associated neighbour first, (XᵀX)⁻¹·Xᵀ·y would reach
-# the DP as the two-factor product solve(XᵀX, Xᵀ)·y
-_RULES_AHEAD_OF_CHAIN_DP: List[Rule] = [r for r in _RULES
-                                        if r is not solve_fusion]
+# the DP as the two-factor product solve(XᵀX, Xᵀ)·y; and a fused chain
+# is a bracketing, which is the DP's to choose before the rule reads it
+_RULES_AHEAD_OF_CHAIN_DP: List[Rule] = [
+    r for r in _RULES if r not in (solve_fusion, mmchain_product)]
 
 _MAX_ITERS = 10
 

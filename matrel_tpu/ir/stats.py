@@ -13,7 +13,8 @@ Estimation model (standard independence assumptions, as in MatFast/MatRel):
   transpose/scalar-mul preserve density; scalar-add densifies; a
   sampled node (S op (A·B), ir/expr.py) has its leaf S's structure; a
   semiring node ((max | min, ×) of a leaf and a column) is one dense
-  column, its (n × m) join never a node.
+  column, its (n × m) join never a node; an mmchain node (t(X)·(w ∘
+  (X·v))) is dense (k × m), its (n × m) intermediate never a node.
 """
 
 from __future__ import annotations
@@ -181,6 +182,14 @@ def integral_abs_bound(node, memo: dict = None):
             if None in (bs, bx):
                 return None
             return bs * bx
+        if k == "mmchain":
+            # sum over n rows of x · (w ·) (sum over k columns of x · v)
+            vals = [walk(c) for c in n.children]
+            if None in vals:
+                return None
+            rows, cols = n.children[0].shape
+            bound = float(rows) * float(cols) * vals[0] * vals[0] * vals[1]
+            return bound * vals[2] if len(vals) == 3 else bound
         if k == "join_index":
             mk = n.attrs.get("merge_kind")
             vals = [walk(c) for c in n.children]
@@ -264,7 +273,7 @@ def infer_integral(node, memo: dict = None) -> bool:
         if k == "sampled":
             return (n.attrs.get("op") == "mul"
                     and all(walk(c) for c in n.children))
-        if k == "semiring":
+        if k in ("semiring", "mmchain"):
             return all(walk(c) for c in n.children)
         if k in ("join_index", "join_rows", "join_cols", "join_value"):
             # structured merges are closed over integers; callables are

@@ -18,7 +18,7 @@ from jax.sharding import Mesh
 
 from matrel_tpu.config import MatrelConfig, default_config
 from matrel_tpu.core import mesh as mesh_lib
-from matrel_tpu.ir.expr import MatExpr
+from matrel_tpu.ir.expr import MatExpr, elemwise, matmul, transpose
 from matrel_tpu.parallel.strategies import (LONG_CONTRACTION, acc_itemsize,
                                             gram_reduce_bytes,
                                             gram_rider_room, gram_tiles,
@@ -736,7 +736,8 @@ def infer_dtype(node: MatExpr, config: Optional[MatrelConfig] = None,
             if "bfloat16" in (np.dtype(da).name, np.dtype(db).name):
                 return np.dtype("float32")
             return _promote(da, db)
-        if k in ("elemwise", "rank1", "join_value", "sampled", "semiring"):
+        if k in ("elemwise", "rank1", "join_value", "sampled", "semiring",
+                 "mmchain"):
             return _promote(*(walk(c) for c in n.children))
         if k == "inverse":
             da = walk(n.children[0])
@@ -1958,6 +1959,95 @@ def long_gram(node: MatExpr, mesh: Mesh,
     return gram
 
 
+def _lies_by_columns(leaf: MatExpr) -> bool:
+    """Does a dense leaf's array lie with its ROWS on the lanes (the
+    chip's default for a tall float32 table, ``major_to_minor=(1, 0)``:
+    PR 31), so that ``x.T`` under ``jit`` is a bitcast and no copy? Off
+    the chip (interpreted kernels) the question has no cost and the
+    answer is yes."""
+    from matrel_tpu.config import on_tpu
+    if not on_tpu():
+        return True
+    try:
+        layout = leaf.attrs["matrix"].data.format.layout
+    except (AttributeError, RuntimeError):      # a described shape
+        return False
+    return tuple(getattr(layout, "major_to_minor", ())) == (1, 0)
+
+
+def mmchain_plan(node: MatExpr, mesh: Mesh,
+                 config: Optional[MatrelConfig] = None,
+                 dtype_memo: Optional[dict] = None) -> dict:
+    """How an ``mmchain`` node ``t(X) * (w .* (X * v))`` runs, from what
+    can be observed (shapes, dtypes, how the table lies, the mesh, the
+    config): the facts ``last_plan()["mmchain"]`` and the
+    ``matrel.mmchain.plan`` spans carry. ``one_read`` true: ONE pass
+    over X in row tiles of ``tile_rows`` (ops/mmchain.py,
+    ``bytes_read`` the table once). False: the two products it was
+    written as, X read twice, and ``why_not`` names what declined — a
+    mesh (the chain on a mesh is the two ``cpmm_rows`` products of PR
+    39), a ``v`` wider than the kernel's one column, a table or vector
+    that is not float32, a ``matmul_precision`` other than "highest"
+    or a precision SLA (both own the products' numerics), a forced
+    strategy, no Pallas executor, a table too wide for two tiles in
+    VMEM or with ragged sublanes, fewer rows than one lane chunk, a
+    table that lies by rows (its transpose would be a second table).
+    Of the dense long-contraction family (:func:`long_in_place`,
+    :func:`long_gram`): the ONE test the stamp (annotate_strategies)
+    and the lowering (executor._mmchain) both go by, so they cannot
+    disagree."""
+    from matrel_tpu.config import pallas_enabled
+    from matrel_tpu.core import padding
+    from matrel_tpu.ops import mmchain as mmchain_lib
+    cfg = config or default_config()
+    x, v = node.children[:2]
+    n, k = x.shape
+    tile = mmchain_lib.tile_rows(n)
+    why = None
+    if mesh.size > 1:
+        why = "mesh"
+    elif cfg.strategy_override != "auto":
+        why = "strategy_override"
+    elif v.shape[1] != 1:
+        why = "v_columns"
+    elif any(infer_dtype(c, cfg, dtype_memo) != np.float32
+             for c in node.children):
+        why = "dtype"
+    elif cfg.matmul_precision != "highest":
+        why = "matmul_precision"
+    elif cfg.precision_sla != "default":
+        why = "precision_sla"
+    elif not pallas_enabled(cfg):
+        why = "pallas_off"
+    elif k % 8 or k > mmchain_lib.COLS_MAX:
+        why = "table_columns"
+    elif not tile:
+        why = "rows"
+    elif (padding.padded_shape(x.shape, mesh) != tuple(x.shape)
+          or not _lies_by_columns(x)):
+        why = "layout"
+    reads = 1 if why is None else 2
+    facts = {"rows": n, "cols": k, "weighted": bool(node.attrs["weighted"]),
+             "tile_rows": tile if why is None else 0,
+             "bytes_read": reads * 4 * n * k, "one_read": why is None}
+    if why is not None:
+        facts["why_not"] = why
+    return facts
+
+
+def unfused_mmchain(node: MatExpr, facts: dict) -> MatExpr:
+    """The two products an ``mmchain`` node was written as, for a plan
+    that :func:`mmchain_plan` declined: ``t(X) * (w .* (X * v))`` as the
+    rule found it, stamped by annotate_strategies and lowered like any
+    other products (the parent's program), with the declined ``facts``
+    on the outer one for ``last_plan()`` and the spans."""
+    x, v, *w = node.children
+    q = matmul(x, v)
+    if w:
+        q = elemwise("mul", w[0], q)
+    return matmul(transpose(x), q).with_attrs(mmchain=facts)
+
+
 def _nodes(root: MatExpr) -> List[MatExpr]:
     """Every node under ``root`` once, in evaluation order."""
     out, seen = [], set()
@@ -2122,6 +2212,11 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
     memo = {} if _dtype_memo is None else _dtype_memo
     lmemo = {} if _layout_memo is None else _layout_memo
     imemo = {} if _integral_memo is None else _integral_memo
+    if e.kind == "mmchain" and "mmchain" not in e.attrs:
+        how = mmchain_plan(e, mesh, config, memo)
+        # declined: the two products it was written as, planned below
+        e = (e.with_attrs(mmchain=how) if how["one_read"]
+             else unfused_mmchain(e, how))
     is_root = _held is None
     if is_root:
         # the root's value is the program's output: its buffer is
@@ -2269,6 +2364,19 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
         e = e.with_attrs(hbm_plan_bytes=int(need), coo_product=how,
                          **({"refused_hbm": (how["chosen"],)}
                             if 0 < limit < need else {}))
+    if e.kind == "mmchain" and "hbm_plan_bytes" not in e.attrs:
+        # one pass over the resident table: what is alive (the table
+        # and the vectors among it), its own column, the kernel's
+        # lanes of partial sums and, of a ragged table, the tail's copy
+        rows, cols = e.children[0].shape
+        tile = e.attrs["mmchain"]["tile_rows"]
+        need = (alive + 4.0 * cols * (2 * 128 + rows % tile)
+                + (0.0 if is_root
+                   else device_bytes(e, mesh, config, memo, lmemo)))
+        limit = mesh_lib.hbm_limit_bytes(mesh, config)
+        e = e.with_attrs(hbm_plan_bytes=int(need),
+                         **({"refused_hbm": ("mmchain",)}
+                            if 0 < limit < need else {}))
     if e.kind in ("join_rows", "join_cols") and "replicate" not in e.attrs:
         e = e.with_attrs(replicate=choose_join_scheme(
             e, mesh, config, layout_memo=lmemo,
@@ -2297,7 +2405,9 @@ def hbm_report(root: MatExpr) -> list:
     on the product carried; on a mesh's product multiplied where its
     operands lie (``chosen`` :data:`OWN_ROWS`), :func:`own_rows_stamps`'
     ``operand_layout``, ``devices``, ``rows_a_device`` and
-    ``reduce_bytes``."""
+    ``reduce_bytes``; on a fused chain (``node`` "mmchain") and on the
+    outer product of one that was un-fused, ``mmchain``:
+    :func:`mmchain_plan`'s facts."""
     out = []
     for n in _nodes(root):
         if "hbm_plan_bytes" in n.attrs:
@@ -2321,7 +2431,8 @@ def hbm_report(root: MatExpr) -> list:
             if "gram_tiles" in n.attrs:
                 out[-1]["gram_tiles"] = list(n.attrs["gram_tiles"])
             for stamp in ("gram_rides", "rides_gram", "operand_layout",
-                          "devices", "rows_a_device", "reduce_bytes"):
+                          "devices", "rows_a_device", "reduce_bytes",
+                          "mmchain"):
                 if stamp in n.attrs:
                     out[-1][stamp] = n.attrs[stamp]
     return out

@@ -519,7 +519,11 @@ class MatrelSession:
         ``matrel.sampled.plan`` span carries), ``semiring`` (one each
         (max | min, ×) product of a coo_leaf and a column,
         core.coo.semiring_facts: what its ``matrel.semiring.plan`` span
-        carries) and ``densified_products``
+        carries), ``mmchain`` (one each chain ``t(X) * (w .* (X * v))``
+        the rule found, planner.mmchain_plan's facts: ``one_read`` true
+        where one pass over X answered it, else ``why_not`` and the two
+        products under ``products``; what its ``matrel.mmchain.plan``
+        span carries) and ``densified_products``
         (one each leaf that was densified, under a product or read as
         an array). Copies; {} before the first dispatch."""
         plan = self._last_plan
@@ -533,6 +537,7 @@ class MatrelSession:
                 "spmm": [dict(r) for r in meta.get("spmm", ())],
                 "sampled": [dict(r) for r in meta.get("sampled", ())],
                 "semiring": [dict(r) for r in meta.get("semiring", ())],
+                "mmchain": [dict(r) for r in meta.get("mmchain", ())],
                 "densified_products": [
                     dict(r) for r in meta.get("densified_products", ())]}
 
@@ -1678,7 +1683,7 @@ class MatrelSession:
             if sp.live:
                 # the SpMV plan each coo_leaf product of this program
                 # runs on: built and uploaded once, answered here
-                for name in ("spmm", "sampled", "semiring"):
+                for name in ("spmm", "sampled", "semiring", "mmchain"):
                     for rec in plan.meta.get(name, ()):
                         with trace_lib.span(name + ".plan", hit=True,
                                             **rec):

@@ -10,6 +10,13 @@ compiler-friendly control flow, no host round-trips).
 ``cg_solve`` takes a dense BlockMatrix / expression; ``cg_solve_linop``
 takes any traceable matvec closure (e.g. a planned SpMV or the
 never-materialised Gram operator v ↦ Aᵀ(Av)).
+
+This is a side function with a loop and a ``jit`` of its own, NOT the
+session's path: conjugate gradient through ``session.sql`` + ``compute``
+(SystemML's LinearRegCG, a statement a line, its ``t(X) * (X * p)`` the
+``mmchain`` node answered in one pass over X) is the benchmark's cell
+``linregcg_10m_1c`` (``benchmarks/configs/systemml_linregcg_10m.py``),
+and the two share no code.
 """
 
 from __future__ import annotations

@@ -1083,3 +1083,109 @@ def test_whole_linreg_reduces_once_and_moves_no_table(linreg_whole_program):
               if " convolution(" in line] for reached in bodies.values()]
     assert sorted(len(c) for c in convs if c) \
         == [len(strategies.gram_blocks(LINREG_K))]
+
+
+# -- the fused chain t(X) * (w .* (X * v)), PR 54 -----------------------------
+#
+# Cell ``linregcg_10m_1c``: the regression cells' table, one chain a
+# round of LinearRegCG. The kernel takes the table as it lies (the long
+# dimension on the lanes): its ``x.T`` is a bitcast and the program
+# holds ONE table.
+
+
+def _mmchain_args(one_chip, major_to_minor, weighted):
+    from jax.experimental.layout import Format, Layout
+    lie = Format(Layout(major_to_minor=major_to_minor), one_chip)
+    args = [_sds(lie, (LINREG_N, LINREG_K), jnp.float32),
+            _sds(one_chip, (LINREG_K, 1), jnp.float32)]
+    if weighted:
+        args.append(_sds(one_chip, (LINREG_N, 1), jnp.float32))
+    return args
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["t(X)(Xv)", "t(X)(w.Xv)"])
+def test_mmchain_reads_one_table_where_it_lies(one_chip, weighted):
+    """``matrel_mmchain`` at the cell's shapes, compiled by Mosaic for
+    the described v5e: the table its only large argument (10.2 GB, under
+    12: no transposed or re-laid copy), temporaries the (1000, 128)
+    lanes of partial sums and ``v``'s broadcast, one kernel."""
+    from matrel_tpu.ops import mmchain as mmchain_lib
+    tile = mmchain_lib.tile_rows(LINREG_N)
+    assert tile == 2048 and LINREG_N % tile == 0        # no ragged tail
+    compiled = _compile(
+        jax.jit(lambda *a: mmchain_lib.mmchain(*a, tile=tile)),
+        *_mmchain_args(one_chip, (1, 0), weighted))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "matrel_mmchain" in text
+    mem = compiled.memory_analysis()
+    taken = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes)
+    table = LINREG_N * LINREG_K * 4
+    assert table <= taken < 12e9
+    assert mem.temp_size_in_bytes < 4 * LINREG_K * 128 * 4
+    written = _arrays_written(text, LINREG_N)
+    assert all(int(np.prod(dims)) == LINREG_N for _, dims in written), \
+        written
+    # the transposed view of the table is a bitcast, not a copy
+    assert re.search(rf"f32\[{LINREG_K},{LINREG_N}\]\S* bitcast\(", text)
+
+
+def test_mmchain_of_a_row_major_table_would_copy_it(one_chip):
+    """Why ``planner.mmchain_plan`` declines a table that lies by rows
+    (``why_not`` layout): the kernel's transpose is then a second table,
+    which the described chip refuses or counts."""
+    from matrel_tpu.ops import mmchain as mmchain_lib
+    try:
+        compiled = jax.jit(lambda *a: mmchain_lib.mmchain(
+            *a, tile=2048)).lower(
+            *_mmchain_args(one_chip, (0, 1), False)).compile()
+    except Exception as e:  # noqa: BLE001 — the refusal is the result
+        assert "RESOURCE_EXHAUSTED" in str(e) or "memory" in str(e).lower()
+        return
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes > 0.9 * LINREG_N * LINREG_K * 4
+
+
+def test_linregcg_chain_statement_as_the_session_plans_it(topo,
+                                                          monkeypatch):
+    """``t(X) * (X * p) + p * lam`` through ``session.sql`` + ``compile``
+    on ONE described v5e: the rule fires, the planner says one read (the
+    test stands in for the chip where the program asks the backend and
+    the array: ``on_tpu``, how the described table lies), the reckoned
+    peak is what the compiled program takes, and nothing N-shaped is
+    written."""
+    from jax.experimental.layout import Format, Layout
+    from matrel_tpu import config as config_lib
+    from matrel_tpu.core.blockmatrix import BlockMatrix
+    from matrel_tpu.parallel import planner
+    from matrel_tpu.session import MatrelSession
+    monkeypatch.setattr(config_lib, "on_tpu", lambda: True)
+    monkeypatch.setattr(planner, "_lies_by_columns", lambda leaf: True)
+    mesh = Mesh(np.asarray(topo.devices[:1], dtype=object).reshape(1, 1),
+                ("x", "y"))
+    whole = NamedSharding(mesh, P(None, None))
+    sess = MatrelSession(mesh=mesh, config=MatrelConfig(cse_enable=True))
+    for name, shape in (("X", (LINREG_N, LINREG_K)), ("p", (LINREG_K, 1)),
+                        ("lam", (1, 1))):
+        sess.register(name, BlockMatrix.from_array(
+            _sds(whole, shape, jnp.float32), shape, mesh, P(None, None)))
+    plan = sess.compile(sess.sql("t(X) * (X * p) + p * lam"))
+    (rec,) = plan.meta["mmchain"]
+    assert rec == {"rows": LINREG_N, "cols": LINREG_K, "weighted": False,
+                   "tile_rows": 2048, "one_read": True,
+                   "bytes_read": LINREG_N * LINREG_K * 4}
+    assert plan.meta["executors"] == ["pallas_mmchain", "xla"]
+    lie = Format(Layout(major_to_minor=(1, 0)), whole)
+    compiled = plan.jitted.lower(*[
+        _sds(lie if leaf.shape[0] == LINREG_N else whole,
+             leaf.attrs["matrix"].shape, jnp.float32)
+        for leaf in plan.leaf_order]).compile()
+    text = compiled.as_text()
+    assert "matrel_mmchain" in text
+    mem = compiled.memory_analysis()
+    taken = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert taken <= plan.meta["hbm_plan_bytes"] * 1.001 < 12e9
+    assert not _arrays_written(text, LINREG_N)
