@@ -942,7 +942,10 @@ class Lowerer:
         product over the same table rides that loop
         (planner.gram_riders) the pair is evaluated once: whichever of
         the two is reached first runs the loop, and the other's product
-        waits in ``_rode``. One device runs the loop as it is; a mesh
+        waits in ``_rode``. One device runs the loop as it is — or,
+        where planner.gram_kernel_plan says ``one_read``, ONE kernel
+        over the table where it lies in place of the loop
+        (:meth:`_gram_kernel`), the rider inside it; a mesh
         (the stamp planner.OWN_ROWS: the tables lie cut over all
         devices along the contraction) runs it a device at a time
         inside one ``shard_map`` and all-reduces the accumulators once
@@ -954,10 +957,14 @@ class Lowerer:
         own = functools.partial(strategies.over_own_rows, self.mesh)
         gram, rider = self._riders.get(node.uid, (None, None))
         if node is gram:
-            out, self._rode[rider.uid] = own(
-                lambda reduce, x, y: strategies.gram_in_panels(
-                    x, 0, self.config, rhs=y, reduce=reduce),
-                (ev(l.children[0]), ev(rider.children[1])), (0, 0))
+            x, y = ev(l.children[0]), ev(rider.children[1])
+            pair = self._gram_kernel(node, x, y)
+            if pair is None:
+                pair = own(
+                    lambda reduce, x, y: strategies.gram_in_panels(
+                        x, 0, self.config, rhs=y, reduce=reduce),
+                    (x, y), (0, 0))
+            out, self._rode[rider.uid] = pair
             return out
         if node is rider:
             ev(gram)
@@ -968,14 +975,36 @@ class Lowerer:
         if found is not None:
             side, base = found
             c = 0 if side == "AtA" else 1
-            return own(lambda reduce, x: strategies.gram_in_panels(
-                x, c, self.config, reduce=reduce), (ev(base),), (c,))
+            x = ev(base)
+            out = self._gram_kernel(node, x)
+            if out is None:
+                out = own(lambda reduce, x: strategies.gram_in_panels(
+                    x, c, self.config, reduce=reduce), (x,), (c,))
+            return out
         (a, ca), (b, cb) = planner.own_rows_operands(node, self.mesh)
         a, b = ev(a), ev(b)
         if a.dtype != jnp.float32 or b.dtype != jnp.float32:
             return None
         return own(lambda reduce, a, b: strategies.dot_in_panels(
             a, ca, b, cb, self.config, reduce=reduce), (a, b), (ca, cb))
+
+    def _gram_kernel(self, node: MatExpr, x: Array, y=None):
+        """A long Gram ``t(x) * x`` (with ``y``: the pair with ``t(x) *
+        y``) as ONE kernel over the table where it lies
+        (strategies.gram_in_tiles), or None where
+        planner.gram_kernel_plan declines it and the loop of panels
+        multiplies it (its ``why_not`` is on the plan's record)."""
+        from matrel_tpu.config import pallas_interpret_mode
+        facts = planner.gram_kernel_plan(node, self.mesh, self.config,
+                                         self._dt_memo)
+        if not facts["one_read"]:
+            return None
+        facts["rider"] = 0 if y is None else y.shape[1]
+        self._ran("pallas_gram")
+        with trace_lib.phase("gram.plan", hit=False, **facts):
+            return strategies.gram_in_tiles(
+                x, self.config, rhs=y, tile=facts["tile_rows"],
+                interpret=pallas_interpret_mode(self.config))
 
     def _stage_root_relay(self, root: MatExpr, out: Array) -> Array:
         """Root output → canonical 2d through the compiled reshard

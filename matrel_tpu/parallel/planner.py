@@ -1876,9 +1876,15 @@ def gram_operand(node: MatExpr) -> Optional[Tuple[str, MatExpr]]:
     """("AtA", X) for the product ``t(X) * X`` and ("AAt", X) for ``X *
     t(X)`` of one evaluated operand, else None. A stamped precision tier
     owns the product's numerics: such a product is no Gram to anyone."""
-    l, r = node.children
     if node.attrs.get("precision_tier") is not None:
         return None
+    return _gram_sides(node)
+
+
+def _gram_sides(node: MatExpr) -> Optional[Tuple[str, MatExpr]]:
+    """:func:`gram_operand` by the operands alone, whatever tier is
+    stamped on the product."""
+    l, r = node.children
     if l.kind == "transpose" and _same_operand(l.children[0], r):
         return "AtA", r
     if r.kind == "transpose" and _same_operand(r.children[0], l):
@@ -1970,9 +1976,80 @@ def _lies_by_columns(leaf: MatExpr) -> bool:
         return True
     try:
         layout = leaf.attrs["matrix"].data.format.layout
-    except (AttributeError, RuntimeError):      # a described shape
-        return False
+    except (AttributeError, KeyError, RuntimeError):
+        return False        # a described shape; a computed operand
     return tuple(getattr(layout, "major_to_minor", ())) == (1, 0)
+
+
+def gram_kernel_plan(node: MatExpr, mesh: Mesh,
+                     config: Optional[MatrelConfig] = None,
+                     dtype_memo: Optional[dict] = None) -> Optional[dict]:
+    """How a LONG Gram (``t(X) * X`` or ``X * t(X)`` over a contraction
+    of LONG_CONTRACTION or more) runs, from what can be observed
+    (shapes, dtypes, how the table lies, the mesh, the config), or None
+    for any other product: the facts ``last_plan()["products"]`` carries
+    as ``gram_kernel`` and the ``matrel.gram.plan`` record of the
+    lowering. ``one_read`` true: ONE ``pallas_call`` over X where it
+    lies (ops/gram_kernel.py: row tiles of ``tile_rows``, the upper
+    triangle in blocks of 128, ``tiles`` of them of those the square
+    holds, ``rider`` columns of a second product inside it:
+    :func:`_stamp_gram_riders` counts them). False: the loop of
+    :func:`long_gram` (strategies.gram_in_panels, four XLA dots a panel)
+    or whatever else the product is lowered as, and ``why_not`` names
+    what declined — a mesh (a device at a time the loop multiplies
+    where the table lies, whatever its layout), a forced strategy, ``X
+    * t(X)``, a table that is not float32, ``matmul_precision`` "high"
+    (ops/gram.py owns that Gram), a precision tier or SLA, no Pallas
+    executor, columns in ragged sublanes, under two blocks of 128 (one
+    tile and nothing to skip) or wider than VMEM holds, fewer rows than
+    one lane chunk, a table that lies by rows (its transpose would be a
+    second table; one device pads nothing). Of the dense long-contraction
+    family (:func:`long_gram`, :func:`mmchain_plan`): the ONE test the
+    stamp (annotate_strategies), the riders' room (:func:`gram_riders`)
+    and the lowering (executor._long_contraction) all go by, so they
+    cannot disagree."""
+    from matrel_tpu.config import pallas_enabled
+    cfg = config or default_config()
+    found = _gram_sides(node)
+    if found is None or node.children[0].shape[1] < LONG_CONTRACTION:
+        return None
+    side, x = found
+    facts = {"one_read": False, "rider": 0, "tile_rows": 0, "tiles": []}
+    why = None
+    if mesh.size > 1:
+        why = "mesh"
+    elif cfg.strategy_override != "auto":
+        why = "strategy_override"
+    elif side != "AtA":
+        why = "contraction"
+    elif infer_dtype(x, cfg, dtype_memo) != np.float32:
+        why = "dtype"
+    elif cfg.matmul_precision == "high":
+        why = "matmul_precision"
+    elif (cfg.precision_sla != "default"
+          or node.attrs.get("precision_tier") is not None):
+        why = "precision_sla"
+    elif not pallas_enabled(cfg):
+        why = "pallas_off"
+    else:
+        # only a process that runs Pallas kernels pays their import
+        # (1.3 s: a mesh's plan and a CPU session never get here)
+        from matrel_tpu.ops import gram_kernel
+        n, k = x.shape
+        tile = gram_kernel.tile_rows(n)
+        if (k % 8 or gram_kernel.blocks(k) < 2
+                or gram_kernel.vmem_bytes(k, tile) > gram_kernel.VMEM_LIMIT):
+            why = "columns"
+        elif not tile:
+            why = "rows"
+        elif not _lies_by_columns(x):
+            why = "layout"
+        else:
+            facts.update(one_read=True, tile_rows=tile,
+                         tiles=list(gram_kernel.tiles(k)))
+    if why is not None:
+        facts["why_not"] = why
+    return facts
 
 
 def mmchain_plan(node: MatExpr, mesh: Mesh,
@@ -2069,13 +2146,16 @@ def gram_riders(root: MatExpr, mesh: Mesh,
                 dtype_memo: Optional[dict] = None
                 ) -> Dict[int, Tuple[MatExpr, MatExpr]]:
     """{uid: (gram, rider)}, under both nodes' uids, of the products of
-    one plan that are lowered as ONE loop over their table
-    (strategies.gram_in_panels ``rhs``): a :func:`long_gram` ``t(X) *
-    X`` and a ``t(X) * B`` over the very same ``X``, float32 and
+    one plan that are lowered as ONE pass over their table
+    (strategies.gram_in_panels / gram_in_tiles ``rhs``): a
+    :func:`long_gram` ``t(X) * X`` and a ``t(X) * B`` over the very
+    same ``X``, float32 and
     multiplied where they lie like it (:func:`in_place_strategy`),
     whose ``B`` fits the lanes the Gram's last block
     column leaves spare in its MXU tile (strategies.gram_rider_room: 24
-    columns at k = 1000, none where k is a multiple of 128) and is not
+    columns at k = 1000, none where k is a multiple of 128; under the
+    kernel the spare rows of its ragged last block,
+    ops/gram_kernel.rider_room: the same 24) and is not
     computed from the Gram. A Gram carries one product, the first in
     evaluation order. Like :func:`long_gram`, the ONE test the stamps
     (``gram_rides`` / ``rides_gram``, annotate_strategies) and the
@@ -2086,9 +2166,17 @@ def gram_riders(root: MatExpr, mesh: Mesh,
     own = in_place_strategy(mesh)
     for gram in nodes:
         found = long_gram(gram, mesh, config, dtype_memo)
-        # the arrays a mesh's lowering sees are the padded ones
-        room = gram_rider_room(padding.padded_shape(gram.shape, mesh)[0])
-        if found is None or found[0] != "AtA" or not room:
+        if found is None or found[0] != "AtA":
+            continue
+        # the arrays a mesh's lowering sees are the padded ones; the
+        # kernel's room is its ragged last block's spare rows
+        pk = padding.padded_shape(gram.shape, mesh)[0]
+        if gram_kernel_plan(gram, mesh, config, dtype_memo)["one_read"]:
+            from matrel_tpu.ops import gram_kernel
+            room = gram_kernel.rider_room(pk)
+        else:
+            room = gram_rider_room(pk)
+        if not room:
             continue
         for rider in nodes:
             l, r = rider.children
@@ -2147,6 +2235,10 @@ def _stamp_gram_riders(root: MatExpr, mesh: Mesh,
                 out = out.with_attrs(**(
                     {"rides_gram": True} if n is rider
                     else {"gram_rides": rider.shape[1]}))
+                how = n.attrs["gram_kernel"] if n is gram else {}
+                if how.get("one_read"):     # the rider is the kernel's
+                    out = out.with_attrs(gram_kernel={
+                        **how, "rider": rider.shape[1]})
                 if "reduce_bytes" in out.attrs:
                     # the pair's ONE all-reduce is the Gram's
                     out = out.with_attrs(reduce_bytes=0 if n is rider
@@ -2306,11 +2398,18 @@ def annotate_strategies(e: MatExpr, mesh: Mesh,
         if strat == OWN_ROWS:
             stamp.update(own_rows_stamps(e, mesh))
         e = e.with_attrs(**stamp)
+        how = gram_kernel_plan(e, mesh, config, memo)
+        if how is not None:
+            # a long Gram: which lowering multiplies it, and why
+            e = e.with_attrs(gram_kernel=how)
         if long_gram(e, mesh, config, memo) is not None:
             # engagement counter of the triangle lowering: block
-            # products a panel (computed, of), for plan.meta and the
+            # products a panel (the loop) or a row tile (the kernel)
+            # multiplies (computed, of), for plan.meta and the
             # plan.strategy spans (hbm_report)
-            e = e.with_attrs(gram_tiles=gram_tiles(e.shape[0]))
+            e = e.with_attrs(gram_tiles=tuple(how["tiles"])
+                             if how["one_read"]
+                             else gram_tiles(e.shape[0]))
         if strat == "spgemm":
             # registry dispatch (ops/kernel_registry.py): stamp WHICH
             # kernel the S×S lowering will run — chosen from the
@@ -2407,7 +2506,10 @@ def hbm_report(root: MatExpr) -> list:
     ``operand_layout``, ``devices``, ``rows_a_device`` and
     ``reduce_bytes``; on a fused chain (``node`` "mmchain") and on the
     outer product of one that was un-fused, ``mmchain``:
-    :func:`mmchain_plan`'s facts."""
+    :func:`mmchain_plan`'s facts; on every long Gram, ``gram_kernel``:
+    :func:`gram_kernel_plan`'s facts (``one_read`` where ONE kernel
+    multiplies it, ``gram_tiles`` then the kernel's tiles; else
+    ``why_not``)."""
     out = []
     for n in _nodes(root):
         if "hbm_plan_bytes" in n.attrs:
@@ -2432,7 +2534,7 @@ def hbm_report(root: MatExpr) -> list:
                 out[-1]["gram_tiles"] = list(n.attrs["gram_tiles"])
             for stamp in ("gram_rides", "rides_gram", "operand_layout",
                           "devices", "rows_a_device", "reduce_bytes",
-                          "mmchain"):
+                          "mmchain", "gram_kernel"):
                 if stamp in n.attrs:
                     out[-1][stamp] = n.attrs[stamp]
     return out

@@ -98,7 +98,9 @@ def _sum_of_panels(length: int, panel, zero):
     ragged tail after it, added on the vector unit in that order."""
     add = functools.partial(jax.tree_util.tree_map, jnp.add)
     whole, tail = divmod(length, ACC_PANEL_ROWS)
-    out = jax.lax.fori_loop(
+    # a loop's body is traced even for no round: a contraction shorter
+    # than one panel (the rows a kernel's tiles leave over) has none
+    out = zero if not whole else jax.lax.fori_loop(
         0, whole,
         lambda i, acc: add(acc, panel(i * ACC_PANEL_ROWS, ACC_PANEL_ROWS)),
         zero)
@@ -149,7 +151,12 @@ def dot_in_panels(a, ca: int, b, cb: int,
 #: us beside its operations at the full square's rate (traced: the four
 #: block columns run at 73, 80, 83 and 87% of the six-pass peak, the
 #: square at 93%; no gap between them), so the fewer dots win what the
-#: finer triangle saves.
+#: finer triangle saves. The finer triangle is ONE kernel's
+#: (:func:`gram_in_tiles`, PR 55): the plans that still take this loop
+#: are those planner.gram_kernel_plan declines by name — a mesh (a
+#: device at a time, cell ``linreg_10m_2x2``), a table that lies by
+#: rows, ``a * t(a)``, k under two blocks of 128 (the NMF cells' ``t(W)
+#: * W``) or in ragged sublanes, no Pallas executor (the CPU).
 GRAM_BLOCK = 256
 
 
@@ -181,7 +188,11 @@ def gram_in_panels(a, ca: int, config: Optional[MatrelConfig] = None,
                    rhs=None, reduce=None):
     """The float32 Gram of ``a`` contracted with itself over its
     dimension ``ca`` (``t(a) * a`` for 0, ``a * t(a)`` for 1), in the
-    panels of :func:`dot_in_panels`, each panel multiplying the upper
+    panels of :func:`dot_in_panels` — the lowering of every long Gram
+    that planner.gram_kernel_plan does not hand to ONE kernel
+    (:func:`gram_in_tiles`: one device, ``t(a) * a``, the table's rows
+    on the lanes), in either layout of the table and on a mesh — each
+    panel multiplying the upper
     block triangle alone: block column j is one dot of the panel's
     first ``end_j`` columns with its block j, with an accumulator of
     its own, and the lower triangle is one mirror of the k x k result
@@ -255,6 +266,34 @@ def gram_in_panels(a, ca: int, config: Optional[MatrelConfig] = None,
     upper = jnp.concatenate(
         [jnp.pad(c, ((0, k - c.shape[0]), (0, 0))) for c in cols], axis=1)
     gram = jnp.where(jnp.tri(k, dtype=bool), upper.T, upper)
+    return gram if rhs is None else (gram, rode)
+
+
+def gram_in_tiles(a, config: Optional[MatrelConfig] = None, rhs=None, *,
+                  tile: int, interpret: bool = False):
+    """:func:`gram_in_panels`' answer for ``t(a) * a`` (and ``t(a) *
+    rhs``) on ONE device over a table whose rows lie on the lanes, as
+    ONE kernel (ops/gram_kernel.py: the upper triangle in blocks of 128
+    a row tile of ``tile``, the table read once, the riders inside it)
+    where planner.gram_kernel_plan says ``one_read``. The rows beyond
+    the last whole tile (fewer than ``tile``) go through
+    :func:`gram_in_panels` and :func:`dot_in_panels` on the tail slice,
+    and the lower triangle is the same ONE mirror: symmetric bit for
+    bit."""
+    from matrel_tpu.ops import gram_kernel
+    n, k = a.shape
+    head = n // tile * tile
+    upper, rode = gram_kernel.gram_upper(
+        a, rhs, tile=tile, precision=_precision(config),
+        interpret=interpret)
+    gram = jnp.where(jnp.tri(k, dtype=bool), upper.T, upper)
+    if head < n:
+        rest = jax.lax.slice_in_dim(a, head, n, axis=0)
+        gram = gram + gram_in_panels(rest, 0, config)
+        if rhs is not None:
+            rode = rode + dot_in_panels(
+                rest, 0, jax.lax.slice_in_dim(rhs, head, n, axis=0), 0,
+                config)
     return gram if rhs is None else (gram, rode)
 
 

@@ -509,7 +509,10 @@ class MatrelSession:
         or a plan template answered the lookup), ``executors``,
         ``hbm_plan_bytes``, ``products`` (the memory reckoning's record
         of each product, solve and materialised transpose:
-        planner.hbm_report, with ``gram_tiles`` / ``gram_rides`` and a
+        planner.hbm_report, with ``gram_tiles`` / ``gram_rides``, a
+        long Gram's ``gram_kernel`` (planner.gram_kernel_plan's facts:
+        ``one_read`` where ONE kernel multiplies it, else ``why_not``)
+        and a
         mesh's ``operand_layout`` / ``devices`` / ``rows_a_device`` /
         ``reduce_bytes`` where they apply), and of its coo_leaf products
         ``spmm`` (one

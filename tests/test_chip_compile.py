@@ -842,24 +842,36 @@ LINREG_LAYOUTS = {"as_the_chip_lays_them": ((1, 0), 1000 * 4 + 4),
 def linreg_program(topo, request):
     """``inv(t(X) * X) * t(X) * y`` over one chip's quarter of the 10M x
     1k table as the session plans and lowers it, compiled for ONE
-    described v5e, the tables in either layout."""
+    described v5e, the tables in either layout. The fixture stands in
+    for the chip where the program asks the backend and the array
+    (``on_tpu``, how a described table lies): as the chip lays them the
+    Gram is ONE kernel over the table (PR 55), row-major it is the loop
+    over panels (``why_not`` layout)."""
     from jax.experimental.layout import Format, Layout
+    from matrel_tpu import config as config_lib
     from matrel_tpu.core.blockmatrix import BlockMatrix
+    from matrel_tpu.parallel import planner
     from matrel_tpu.session import MatrelSession
     major_to_minor, bytes_a_row = LINREG_LAYOUTS[request.param]
     mesh = Mesh(np.asarray(topo.devices[:1], dtype=object).reshape(1, 1),
                 ("x", "y"))
     whole = NamedSharding(mesh, P(None, None))
-    sess = MatrelSession(mesh=mesh)
-    for name, shape in (("X", (LINREG_N, LINREG_K)), ("y", (LINREG_N, 1))):
-        sess.register(name, BlockMatrix.from_array(
-            _sds(whole, shape, jnp.float32), shape, mesh, P(None, None)))
-    plan = sess.compile(sess.sql("inv(t(X) * X) * t(X) * y"))
-    lie = Format(Layout(major_to_minor=major_to_minor), whole)
-    compiled = plan.jitted.lower(*[
-        _sds(lie, leaf.attrs["matrix"].shape, jnp.float32)
-        for leaf in plan.leaf_order]).compile()
-    return plan, compiled, LINREG_N * bytes_a_row
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(config_lib, "on_tpu", lambda: True)
+        patch.setattr(planner, "_lies_by_columns",
+                      lambda leaf: major_to_minor == (1, 0))
+        sess = MatrelSession(mesh=mesh)
+        for name, shape in (("X", (LINREG_N, LINREG_K)),
+                            ("y", (LINREG_N, 1))):
+            sess.register(name, BlockMatrix.from_array(
+                _sds(whole, shape, jnp.float32), shape, mesh, P(None, None)))
+        plan = sess.compile(sess.sql("inv(t(X) * X) * t(X) * y"))
+        lie = Format(Layout(major_to_minor=major_to_minor), whole)
+        compiled = plan.jitted.lower(*[
+            _sds(lie, leaf.attrs["matrix"].shape, jnp.float32)
+            for leaf in plan.leaf_order]).compile()
+    return plan, compiled, LINREG_N * bytes_a_row, \
+        request.param == "as_the_chip_lays_them"
 
 
 #: Operations that hand an array on and write none: the loop over the
@@ -895,14 +907,19 @@ def test_linreg_plan_fits_beside_the_table(linreg_program):
     the k x k Gram, Xᵀy, the solve's copies: megabytes — and arguments
     and temporaries fit the planner's budget. Neither layout makes the
     loop over the contraction's panels copy the table, and there is ONE
-    such loop (PR 34): t(X)·y rides t(X)·X's."""
-    plan, compiled, tables = linreg_program
+    such loop (PR 34): t(X)·y rides t(X)·X's — or, as the chip lays the
+    table, none: ONE kernel reads it (PR 55), its accumulator (4 MB, a
+    tile after the other) in fast memory."""
+    plan, compiled, tables, kernel = linreg_program
     mem = compiled.memory_analysis()
     n, k = LINREG_N, LINREG_K
     assert mem.argument_size_in_bytes == tables
-    loops = [ln for ln in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    loops = [ln for ln in text.splitlines()
              if " while(" in ln and f"f32[{n},{k}]" in ln]
-    assert len(loops) == 1, loops       # t(X)·X and t(X)·y, in panels
+    # t(X)·X and t(X)·y: in panels, or in the kernel's tiles
+    assert len(loops) == (0 if kernel else 1), loops
+    assert text.count('custom_call_target="tpu_custom_call"') == kernel
     # described tables are shapes, reckoned at their logical bytes
     residents, answer = n * k * 4 + n * 4, k * 4
     reckoned = plan.meta["hbm_plan_bytes"] - residents - answer
@@ -920,7 +937,7 @@ def test_linreg_plan_writes_no_n_shaped_array(linreg_program):
     (and the loops over the contraction's panels, which carry them
     through) no array with 2,555,904 in its shape is written that is
     larger than y's column re-laid as a vector (10 MB)."""
-    plan, compiled, _ = linreg_program
+    plan, compiled, _, _ = linreg_program
     assert plan.meta["rule_hits"]["chain_solve"] == 1
     written = _arrays_written(compiled.as_text(), LINREG_N)
     assert all(int(np.prod(dims)) == LINREG_N for _, dims in written), written
@@ -957,9 +974,35 @@ def test_linreg_gram_multiplies_the_triangle_only(linreg_program):
     share of the square a full-square dot of a panel costs (10 of 16
     blocks at 256: 62.5%), each block column one convolution whose
     slices of the table are read in place: no array of a panel's length
-    is written in either layout, beside y's column of it."""
-    _, compiled, _ = linreg_program
+    is written in either layout, beside y's column of it. As the chip
+    lays the table the triangle is the kernel's (PR 55): ONE
+    ``tpu_custom_call`` named ``matrel_gram`` that takes the table's
+    parameter through a bitcast, 36 of 64 tiles of 128 a row tile, no
+    loop and no convolution over the table left, nothing with n or a
+    tile's rows among its dimensions written."""
+    plan, compiled, _, kernel = linreg_program
     text = compiled.as_text()
+    gram = plan.meta["products"][0]
+    if kernel:
+        assert plan.meta["executors"] == ["pallas_gram", "xla"]
+        assert gram["gram_kernel"] == {"one_read": True, "rider": 1,
+                                       "tile_rows": 2048, "tiles": [36, 64]}
+        assert gram["gram_tiles"] == [36, 64]
+        (call,) = [ln for ln in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln]
+        assert "%matrel_gram" in call
+        table = re.search(r"custom-call\(%([\w.\-]+),", call).group(1)
+        assert re.search(
+            rf"%{re.escape(table)} = f32\[{LINREG_K},{LINREG_N}\]\S* "
+            r"bitcast\(%args_[\w.]+\)", text)
+        bodies, lines = _loop_bodies(text)
+        assert not [ln for reached in bodies.values() for name in reached
+                    for ln in lines[name] if " convolution(" in ln]
+        assert not _arrays_written(text, LINREG_N)
+        assert not _arrays_written(text, 2048)
+        return
+    assert gram["gram_kernel"]["why_not"] == "layout"
+    assert gram["gram_tiles"] == [10, 16]
     rows, k = strategies.ACC_PANEL_ROWS, LINREG_K
     bodies, lines = _loop_bodies(text)
     ops = [[2 * rows * int(m) * int(w)
@@ -981,12 +1024,27 @@ def test_linreg_rhs_rides_the_gram(linreg_program):
     multiply-reduce runs over a panel, and beside y's own slice of a
     panel no array of a panel's length is written or staged (a
     ``concatenate`` of the slice and y is fused as well, but stages the
-    232 columns first: ``dynamic-slice f32[8192,232]``)."""
-    plan, compiled, _ = linreg_program
+    232 columns first: ``dynamic-slice f32[8192,232]``). As the chip
+    lays the tables y rides the KERNEL (PR 55): its column is the
+    custom call's second operand, through a bitcast as well (the rows
+    of t(y) fill the ragged last block's spare rows), and no second
+    n-shaped pass — no loop, no multiply-reduce — is left."""
+    plan, compiled, _, kernel = linreg_program
     assert [(p.get("gram_rides"), p.get("rides_gram"))
             for p in plan.meta["products"]] == [
         (1, None), (None, True), (None, None)]
     text = compiled.as_text()
+    if kernel:
+        (call,) = [ln for ln in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln]
+        _, column = re.search(
+            r"custom-call\(%([\w.\-]+), %([\w.\-]+)\)", call).groups()
+        assert re.search(
+            rf"%{re.escape(column)} = f32\[1,{LINREG_N}\]\S* "
+            r"bitcast\(%args_[\w.]+\)", text)
+        assert "multiply_reduce" not in text and " while(" not in "".join(
+            ln for ln in text.splitlines() if f"[{LINREG_N}," in ln)
+        return
     rows, k = strategies.ACC_PANEL_ROWS, LINREG_K
     bodies, lines = _loop_bodies(text)
     (body,) = [reached for reached in bodies.values()

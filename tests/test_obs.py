@@ -1216,8 +1216,9 @@ class TestProfilerTier:
     def test_all_pallas_call_sites_were_found(self):
         # PR 36: the hub scatter; PR 44: less the routed SpMV's two;
         # PR 47: the sampled scatter; PR 50: the chunk grid's reduction;
-        # PR 51: the hub chunks' reduction; PR 54: the fused chain
-        assert len(_PALLAS_SITES) == 12
+        # PR 51: the hub chunks' reduction; PR 54: the fused chain;
+        # PR 55: the Gram's triangle
+        assert len(_PALLAS_SITES) == 13
 
 
 class TestAnalyzeEvent:
