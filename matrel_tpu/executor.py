@@ -1654,6 +1654,87 @@ def _verify_plans(opts, mesh, cfg) -> Optional[List[dict]]:
     return [d.to_dict() for d in diags]
 
 
+# -- rows deltas: the in-place update and the views' patches ------------------
+# (serve/ivm.py runs them; they are programs, so they are emitted here)
+
+_ROWS_PROGRAMS: Dict[tuple, Callable] = {}
+
+
+def rows_update(contiguous: bool) -> Callable:
+    """The program that REPLACES c rows of a dense table where it lies:
+    ``fn(table, new, at) -> (table', old)`` with ``table`` DONATED (its
+    buffer is the output's: at no point do two tables exist), ``new``
+    the c x m replacement rows, ``at`` the first row id (``contiguous``:
+    one run of ids, a dynamic slice) or the c ids (a gather and a
+    scatter), and ``old`` the rows that leave, read out before they are
+    overwritten. ``at`` is an argument: a window that moves compiles
+    once."""
+    key = ("update", contiguous)
+    fn = _ROWS_PROGRAMS.get(key)
+    if fn is None:
+        def update(table, new, at):
+            new = new.astype(table.dtype)
+            if contiguous:
+                old = jax.lax.dynamic_slice_in_dim(table, at, new.shape[0],
+                                                   axis=0)
+                return (jax.lax.dynamic_update_slice_in_dim(
+                    table, new, at, axis=0), old)
+            return table.at[at].set(new), jnp.take(table, at, axis=0)
+
+        fn = _ROWS_PROGRAMS[key] = jax.jit(update, donate_argnums=0)
+    return fn
+
+
+def _two_sum(hi, lo, d):
+    """(hi, lo) + d, the pair carried as an unevaluated sum of two
+    float32 words (Knuth's TwoSum, then one renormalisation): ``hi`` is
+    the view as it is read, ``lo`` what its roundings left over, so
+    that thousands of ``view += small - small`` at a large view lose
+    nothing the next patch cannot see."""
+    s = hi + d
+    b = s - hi
+    lo = lo + ((hi - (s - b)) + (d - b))
+    out = s + lo
+    return out, lo - (out - s)
+
+
+def rows_patch(form: str, contiguous: bool,
+               config: Optional[MatrelConfig] = None) -> Callable:
+    """The program that corrects one view for a rows delta of its table
+    (ir/delta.RowsPatch): ``fn(hi, lo, new, old, partner, at) -> (hi',
+    lo')`` with the view's two words DONATED. The corrections are
+    contractions over the c rows that changed, float32 at the session's
+    ``matmul_precision``, in the panels every long contraction of the
+    program is multiplied in (strategies.gram_in_panels: the upper
+    block triangle, mirrored; strategies.dot_in_panels); the partner's
+    same rows are sliced from its table inside the program. A Gram adds
+    ``t(new) * new`` and takes ``t(old) * old`` away as two corrections
+    of their own, not as their difference: what a slot added when its
+    rows came is what it takes away when they leave, bit for bit."""
+    cfg = config or default_config()
+    key = ("patch", form, contiguous, cfg.matmul_precision)
+    fn = _ROWS_PROGRAMS.get(key)
+    if fn is None:
+        def patch(hi, lo, new, old, partner, at):
+            if form == "gram":
+                hi, lo = _two_sum(hi, lo,
+                                  strategies.gram_in_panels(new, 0, cfg))
+                return _two_sum(hi, lo,
+                                -strategies.gram_in_panels(old, 0, cfg))
+            theirs = (jax.lax.dynamic_slice_in_dim(
+                partner, at, new.shape[0], axis=0) if contiguous
+                else jnp.take(partner, at, axis=0))
+            mine = new - old
+            if form == "left":
+                d = strategies.dot_in_panels(mine, 0, theirs, 0, cfg)
+            else:
+                d = strategies.dot_in_panels(theirs, 0, mine, 0, cfg)
+            return _two_sum(hi, lo, d)
+
+        fn = _ROWS_PROGRAMS[key] = jax.jit(patch, donate_argnums=(0, 1))
+    return fn
+
+
 def compile_exprs(exprs, mesh: Optional[Mesh] = None,
                   config: Optional[MatrelConfig] = None) -> MultiPlan:
     """Compile several expressions into one program with shared leaves."""

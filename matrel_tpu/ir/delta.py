@@ -26,6 +26,16 @@ Delta representations (:class:`MatrixDelta`):
   dense    a same-shaped correction matrix — the fallback form, also
            the materialization every other kind lowers to for
            elementwise contexts.
+  rows     row ids and their REPLACEMENT values (c x m): after the
+           register the table's rows ``ids`` equal the values bit for
+           bit. Not a sum: the correction is ``N - O`` with ``O`` the
+           rows that leave, which only the table holds — so against a
+           dense float32 table on one device the plane reads ``O``
+           out, overwrites the rows IN PLACE (no second table) and
+           patches the views it has a rule for from ``O``, ``N`` and
+           the partner's same rows (:func:`derive_rows_patch`: the
+           regression's ``t(X) * X``, ``t(X) * y``), never from a
+           pass over the table. A sliding window's batch.
 
 Sparse ΔA·B: when the delta's sparse form multiplies a sparse leaf,
 the emitted product is an S×S matmul over two sparse leaves — exactly
@@ -82,11 +92,28 @@ from matrel_tpu.ir.expr import MatExpr
 
 #: Primary-rule vocabulary a patch stamp may carry (MV113 checks
 #: membership; the autotune ``ivm|`` key embeds it).
-DELTA_RULES = ("linear", "rank_k", "rank_k_both", "spgemm", "refine")
+DELTA_RULES = ("linear", "rank_k", "rank_k_both", "spgemm", "refine",
+               "rows")
 
 #: f32/HIGHEST per-product relative error unit — the MV108 bound table's
 #: "f32" row (planner.TIER_EPS); patches compound it per generation.
 _F32_EPS = 2.0 ** -20
+
+#: The most a delta may materialise as ONE host or device array of its
+#: target's shape (a dense correction, one-hot factors, the ``np.zeros``
+#: a dense branch adds into): reckoned from shapes before anything is
+#: allocated, and past it the delta is refused by name
+#: (:class:`DeltaTooLarge`) instead of ending as an out-of-memory kill.
+#: Every table of the toy and test sizes the plane was written at
+#: passes; a 10 GB table's same-shaped correction does not.
+MATERIALIZE_MAX_BYTES = 1 << 30
+
+#: The composed relative error bound (``CacheEntry.err_bound``) past
+#: which a rows-patched view is RE-BASED — recomputed from the table as
+#: it stands, its bound reset to a fresh execution's (serve/ivm.py):
+#: four f32 product units; a patch of c of a view's n rows adds
+#: 2 * 2^-20 * c / n (:func:`rows_patch_bound`).
+ROWS_REBASE_BOUND = 2.0 ** -18
 
 #: Construction counter — the bit-identity test hook (ir/fusion.py's
 #: ``_CONSTRUCTED`` idiom): the default path must never build a delta.
@@ -95,6 +122,28 @@ _CONSTRUCTED = {"count": 0}
 
 class DeltaIneligible(Exception):
     """Internal control flow: the expression has no derivable patch."""
+
+
+class DeltaTooLarge(ValueError):
+    """A delta whose lowering would materialise one host or device
+    array past :data:`MATERIALIZE_MAX_BYTES` (a same-shaped
+    correction of a large table, its one-hot factors): refused by name
+    from the shapes alone, before anything is allocated."""
+
+
+def _refuse_past_budget(what: str, shape, count: int) -> None:
+    """Raise :class:`DeltaTooLarge` where ``count`` float32 arrays of
+    ``shape`` are more than a delta may materialise."""
+    limit = MATERIALIZE_MAX_BYTES
+    need = 4 * count * int(shape[0]) * int(shape[1])
+    if need > limit:
+        raise DeltaTooLarge(
+            f"delta refused before anything was allocated: {what} would "
+            f"materialise {need:,} bytes ({count} float32 array(s) of "
+            f"{int(shape[0])}x{int(shape[1])}), over "
+            f"ir/delta.MATERIALIZE_MAX_BYTES = {limit:,}. A batch of rows of "
+            f"a large table is handed over as kind='rows' (row ids and "
+            f"their replacement values), which touches those rows alone.")
 
 
 # ---------------------------------------------------------------------------
@@ -106,7 +155,11 @@ class DeltaIneligible(Exception):
 class MatrixDelta:
     """One registered update ``ΔA`` for a bound catalog matrix.
 
-    kind: "coo" | "lowrank" | "dense" (see module docstring).
+    kind: "coo" | "lowrank" | "dense" | "rows" (see module docstring;
+      ``rows``: ``rows`` holds the row ids, ``vals`` the c x m
+      replacement values — a host array, or a device array that is
+      not uploaded again — and ``start`` the first id where the ids
+      are one ascending run, else None).
     shape: ΔA's logical shape (== the bound matrix's).
     integral: every delta entry is an exact integer — graph-count
       patches then ride the int paths EXACTLY (err bound 0).
@@ -120,6 +173,7 @@ class MatrixDelta:
     u: Optional[np.ndarray] = None        # (n, c)
     v: Optional[np.ndarray] = None        # (m, c)
     dense: Optional[np.ndarray] = None    # (n, m)
+    start: Optional[int] = None           # rows: first id of one run
     integral: bool = False
     _factors: Optional[tuple] = dataclasses.field(default=None,
                                                   repr=False)
@@ -152,9 +206,16 @@ class MatrixDelta:
         return None
 
     def to_dense_numpy(self) -> np.ndarray:
-        """ΔA as a host array (the shared lowering of every kind)."""
+        """ΔA as a host array (the shared lowering of every additive
+        kind), its bytes reckoned first (:class:`DeltaTooLarge`)."""
         if self.kind == "dense":
             return np.asarray(self.dense, np.float32)
+        if self.kind == "rows":
+            raise DeltaIneligible(
+                "a rows delta replaces rows: its correction needs the "
+                "rows that leave, which only the table holds")
+        _refuse_past_budget(f"the dense form of a {self.kind} delta",
+                            self.shape, 1)
         if self.kind == "lowrank":
             return (np.asarray(self.u, np.float32)
                     @ np.asarray(self.v, np.float32).T)
@@ -168,7 +229,9 @@ class MatrixDelta:
         rebindable thin form — or None when the delta has no cheap
         factorisation (dense kind, or rank above
         ``config.delta_rank_max``: a fat factored product would cost
-        more than it saves)."""
+        more than it saves). A coo delta's one-hot factors are host
+        arrays of (n + m) x c: their bytes are reckoned first
+        (:class:`DeltaTooLarge`)."""
         cfg = config or default_config()
         r = self.rank
         if r is None or r > cfg.delta_rank_max:
@@ -182,6 +245,9 @@ class MatrixDelta:
                 # one scaled one-hot column per edge: U[:, t] =
                 # vals[t]·e_rows[t], V[:, t] = e_cols[t]
                 c = max(r, 1)
+                _refuse_past_budget(
+                    "the one-hot factors of a coo delta",
+                    (self.shape[0] + self.shape[1], c), 1)
                 un = np.zeros((self.shape[0], c), np.float32)
                 vn = np.zeros((self.shape[1], c), np.float32)
                 if r:
@@ -212,7 +278,7 @@ class MatrixDelta:
         products against sparse leaves dispatch the tile-intersection
         SpGEMM (ops/spgemm.py via executor._spgemm_dispatch). None for
         lowrank (no coordinate list to bucket)."""
-        if self.kind == "lowrank":
+        if self.kind in ("lowrank", "rows"):
             return None
         if self._sparse_bm is None or \
                 self._sparse_bm.block_size != block_size:
@@ -240,6 +306,14 @@ class MatrixDelta:
         from matrel_tpu.core.sparse import BlockSparseMatrix
         cfg = config or default_config()
         if isinstance(old, BlockSparseMatrix):
+            if self.kind == "rows":
+                raise TypeError(
+                    "a rows delta replaces rows of a dense BlockMatrix; "
+                    f"{type(old).__name__} tables take coo or dense "
+                    "deltas")
+            _refuse_past_budget(
+                "a delta against a block-sparse table (densified, "
+                "corrected and rebuilt on the host)", self.shape, 2)
             arr = old.to_numpy()
             arr = arr + self.to_dense_numpy().astype(arr.dtype)
             return BlockSparseMatrix.from_numpy(
@@ -249,10 +323,25 @@ class MatrixDelta:
             raise TypeError(
                 f"register_delta target must be a BlockMatrix or "
                 f"BlockSparseMatrix, got {type(old).__name__}")
+        # every branch below rebinds the name to a corrected COPY: a
+        # second table beside the first while the scatter or the sum
+        # runs, reckoned against the device before it is attempted
+        self._refuse_second_table(old, mesh, cfg)
+        if self.kind == "rows":
+            # the copying form of a replacement (a mesh, another dtype:
+            # whatever the in-place program of serve/ivm.py does not
+            # take)
+            data = old.data.at[np.asarray(self.rows)].set(
+                jax.numpy.asarray(self.vals, old.data.dtype))
+            return dataclasses.replace(old, data=data, nnz=None,
+                                       integral=False, int_abs_max=None)
         if self.kind == "coo":
             data = old.data.at[self.rows, self.cols].add(
                 np.asarray(self.vals, old.data.dtype))
         else:
+            _refuse_past_budget(
+                "the padded host copy of a same-shaped correction",
+                old.padded_shape, 1)
             pad = np.zeros(old.padded_shape, np.float32)
             d = self.to_dense_numpy()
             pad[: self.shape[0], : self.shape[1]] = d
@@ -273,11 +362,34 @@ class MatrixDelta:
             old, data=data, nnz=None, integral=integral,
             int_abs_max=amax)
 
+    def _refuse_second_table(self, old, mesh, cfg) -> None:
+        """A delta that is not applied in place rebinds its name to a
+        corrected copy of the table: refused by name where two tables
+        are over the device's limit (planner.PlanMemoryError), as any
+        other plan is."""
+        from matrel_tpu.core import mesh as mesh_lib
+        from matrel_tpu.parallel import planner
+        limit = mesh_lib.hbm_limit_bytes(mesh, cfg)
+        one = planner.device_bytes(E.leaf(old), mesh, cfg)
+        if limit > 0 and 2 * one > limit:
+            raise planner.PlanMemoryError(
+                f"{self.kind} delta refused before anything ran: the "
+                f"table {old.shape[0]}x{old.shape[1]} ({int(one):,} "
+                f"bytes on one device) would be rebound to a corrected "
+                f"copy, and the copy beside it is {int(2 * one):,} "
+                f"bytes, over the limit of {limit:,} bytes. Only "
+                f"kind='rows' against a dense float32 table on ONE "
+                f"device is overwritten in place.")
+
     def signature(self) -> tuple:
         """Patch-plan reuse key: two deltas with equal signatures
         produce structurally identical patch plans, so the plane can
         rebind factor/dense leaves instead of recompiling (constant
-        edge-batch streams hit this every step)."""
+        edge-batch streams hit this every step). A rows delta's is its
+        batch's shape and whether its ids are one run."""
+        if self.kind == "rows":
+            return (self.kind, self.shape, int(self.rows.shape[0]),
+                    self.start is not None)
         return (self.kind, self.shape, self.rank, self.integral)
 
 
@@ -287,7 +399,9 @@ def as_delta(payload, old, kind: str = "auto",
 
     Accepted payloads: a COOMatrix; ``(rows, cols[, vals])`` index
     arrays (kind "coo"); ``(U, V)`` with ``ΔA = U·Vᵀ`` (kind
-    "lowrank"); a same-shaped ndarray/BlockMatrix (kind "dense").
+    "lowrank"); a same-shaped ndarray/BlockMatrix (kind "dense");
+    ``(row_ids, values)`` with ``values`` the c x m rows that REPLACE
+    rows ``row_ids`` (kind "rows"; ids distinct).
     ``kind="auto"`` disambiguates by shape; pass it explicitly when a
     2-tuple could mean either."""
     from matrel_tpu.core.blockmatrix import BlockMatrix
@@ -335,6 +449,26 @@ def as_delta(payload, old, kind: str = "auto",
         return MatrixDelta(kind="dense", shape=shape, dense=arr,
                            integral=integral)
 
+    def _rows(ids, vals):
+        ids = np.asarray(ids, np.int64).ravel()
+        if not hasattr(vals, "shape") or not hasattr(vals, "dtype"):
+            vals = np.asarray(vals, np.float32)
+        if vals.ndim == 1:
+            vals = vals.reshape(-1, 1)
+        if tuple(vals.shape) != (ids.size, shape[1]) or not ids.size:
+            raise ValueError(
+                f"rows delta needs ids (c,) and values (c, {shape[1]}), "
+                f"c >= 1; got {ids.shape}, {tuple(vals.shape)}")
+        if ids.min() < 0 or ids.max() >= shape[0]:
+            raise ValueError(
+                f"rows delta ids out of bounds for {shape}")
+        run = bool(np.all(np.diff(ids) == 1))
+        if not run and np.unique(ids).size != ids.size:
+            raise ValueError(
+                "rows delta ids repeat: a row is replaced once a delta")
+        return MatrixDelta(kind="rows", shape=shape, rows=ids, vals=vals,
+                           start=int(ids[0]) if run else None)
+
     if isinstance(payload, COOMatrix):
         if tuple(payload.shape) != shape:
             raise ValueError(
@@ -348,9 +482,11 @@ def as_delta(payload, old, kind: str = "auto",
         return _lowrank(*payload)
     if kind == "dense":
         return _dense(payload)
+    if kind == "rows":
+        return _rows(*payload)
     if kind != "auto":
         raise ValueError(f"unknown delta kind {kind!r} (expected "
-                         f"'auto'/'coo'/'lowrank'/'dense')")
+                         f"'auto'/'coo'/'lowrank'/'dense'/'rows')")
     if isinstance(payload, (tuple, list)):
         if len(payload) == 3:
             return _coo(*payload)
@@ -361,6 +497,8 @@ def as_delta(payload, old, kind: str = "auto",
                 return _lowrank(a, b)
             if a.ndim == 1 and b.ndim == 1:
                 return _coo(a, b)
+            if a.ndim == 1 and b.ndim == 2 and b.shape[0] == a.shape[0]:
+                return _rows(a, payload[1])
         raise ValueError(
             "ambiguous delta payload — pass kind='coo' or 'lowrank'")
     return _dense(payload)
@@ -806,6 +944,11 @@ def derive_patch(expr: MatExpr, old, new, delta: MatrixDelta,
     sibling cached entries to their ``(old_result, patched_result)``
     BlockMatrices — the delta-propagation substrate."""
     cfg = config or default_config()
+    if delta.kind == "rows":
+        # a replacement has no additive form: its rules are
+        # :func:`derive_rows_patch`'s, run in place by serve/ivm.py;
+        # on this (copying) path every dependent takes the kill
+        return None
     refine = expr.attrs.get("delta_refine")
     est_full = _optimized_flops(expr, mesh, cfg)
     if callable(refine):
@@ -865,6 +1008,72 @@ def derive_patch(expr: MatExpr, old, new, delta: MatrixDelta,
                      err_bound=bound, expr=patched,
                      rebindable=ctx.rebindable,
                      known_keys=tuple(sorted(set(ctx.known_used))))
+
+
+# ---------------------------------------------------------------------------
+# Rows deltas — the sliding window's rules
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class RowsPatch:
+    """How one view follows a ``rows`` delta of its table ``T`` (rows
+    ``O`` leave, rows ``N`` come): the rule table's ``matmul`` lines
+    with ``ΔT`` the scatter of ``N - O`` into those rows, so that every
+    product touches the partner's same c rows and nothing else.
+
+      gram   ``t(T) * T``   Δ = t(N) * N - t(O) * O     ("both")
+      left   ``t(T) * B``   Δ = t(N - O) * B[rows]      ("a only")
+      right  ``t(A) * T``   Δ = t(A[rows]) * (N - O)    ("b only")
+
+    ``partner`` is the other leaf's matrix (None for a Gram), read AS IT
+    STANDS when the patch runs: a tick that replaces rows of X and then
+    of y patches ``t(X) * y`` twice, and the two corrections add up to
+    ``t(N) * n - t(O) * o``."""
+
+    form: str
+    partner: Optional[object] = None
+
+
+def _plain_leaf(n: MatExpr) -> bool:
+    return (n.kind == "leaf" and "result_cache" not in n.attrs
+            and "ivm_role" not in n.attrs)
+
+
+def derive_rows_patch(expr: MatExpr, target) -> Optional[RowsPatch]:
+    """The :class:`RowsPatch` of a cached view under a rows delta of
+    ``target``, or None where no rule applies (the caller kills the
+    entry: the historical answer, never a wrong one). The rules are the
+    long contractions over the table's rows — the sufficient statistics
+    of a regression — whose correction is a contraction over the c rows
+    that changed; a product that keeps the table's rows in its result
+    (``T * B``) changes in c rows of a result that may be as large as
+    the table, and is left to the kill."""
+    if expr.kind != "matmul" or expr.attrs:
+        return None
+    a, b = expr.children
+    if a.kind != "transpose" or a.attrs or not _plain_leaf(a.children[0]) \
+            or not _plain_leaf(b):
+        return None
+    left, right = a.children[0].attrs["matrix"], b.attrs["matrix"]
+    if left is target and right is target:
+        return RowsPatch("gram")
+    if left is target:
+        return RowsPatch("left", right)
+    if right is target:
+        return RowsPatch("right", left)
+    return None
+
+
+def rows_patch_bound(batch_rows: int, table_rows: int) -> float:
+    """What one rows patch adds to its view's composed relative error
+    bound: one f32 product unit for the rows that leave and one for
+    the rows that come, each weighted by the share of the view's rows
+    they are (the view's entries are sums over the table's rows, a
+    patch's over the batch's); the compensated accumulation of the
+    view (its second word: executor.rows_patch) adds 2^-46."""
+    share = float(batch_rows) / float(max(table_rows, 1))
+    return 2.0 * _F32_EPS * min(share, 1.0) + 2.0 ** -46
 
 
 # ---------------------------------------------------------------------------

@@ -2612,6 +2612,48 @@ def refuse_over_limit(roots, mesh: Mesh,
             f"(0 turns the reckoning off).")
 
 
+def rows_delta_plan(table, batch_rows: int, views, partners,
+                    mesh: Mesh,
+                    config: Optional[MatrelConfig] = None) -> dict:
+    """The one-device reckoning of a rows delta applied in place
+    (serve/ivm.py: executor.rows_update, then executor.rows_patch a
+    view): ``table`` (the dense matrix whose rows are replaced, ONE
+    copy: the update donates it), the batch and the rows that leave
+    (two c x m arrays), every view that follows the delta at its two
+    words and the correction it is patched with (a third array of its
+    shape, while its patch runs), and the ``partners`` whose rows the
+    patches read (resident tables, each once). Raises
+    :class:`PlanMemoryError` where that is over the device's limit, by
+    name and before anything is uploaded; returns the record the
+    ``matrel.delta.update`` span and the delta's summary carry."""
+    from matrel_tpu.ir.expr import leaf
+
+    def one(m) -> int:
+        return int(device_bytes(leaf(m), mesh, config))
+
+    itemsize = np.dtype(table.data.dtype).itemsize
+    seen, resident = set(), 0
+    for m in [table, *partners]:
+        if id(m) not in seen:
+            seen.add(id(m))
+            resident += one(m)
+    batch = 2 * int(batch_rows) * int(table.shape[1]) * itemsize
+    kept = sum(3 * one(v) for v in views)
+    need = resident + batch + kept
+    limit = mesh_lib.hbm_limit_bytes(mesh, config)
+    if limit > 0 and need > limit:
+        raise PlanMemoryError(
+            f"rows delta refused before anything was uploaded: replacing "
+            f"{batch_rows:,} rows of the table {table.shape[0]}x"
+            f"{table.shape[1]} in place holds {need:,} bytes on the one "
+            f"device (the table and the tables its views' patches read "
+            f"{resident:,}; the batch and the rows that leave {batch:,}; "
+            f"{len(views)} view(s) at two words and a correction "
+            f"{kept:,}), over the limit of {limit:,} bytes (min of "
+            f"hbm_budget_bytes and the device's bytes_limit).")
+    return {"hbm_plan_bytes": need, "table_bytes": one(table)}
+
+
 def _refuse_on_mesh(roots, mesh: Mesh,
                     config: Optional[MatrelConfig] = None) -> None:
     """:func:`refuse_over_limit` for a mesh: raise where a materialised

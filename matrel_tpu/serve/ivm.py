@@ -25,6 +25,19 @@ The plane owns:
     leaves (CompiledPlan.run(bindings=...)) instead of recompiled:
     constant-batch streams pay one compile per entry, ever.
 
+  * rows deltas IN PLACE (``kind="rows"`` against a dense float32
+    table on one device — :meth:`DeltaPlane._apply_rows`): the rows
+    that leave are read out, the table's buffer is overwritten where
+    it lies (its BlockMatrix, and so every plan and key that names it,
+    stays the object it was), and each view with a rule
+    (ir/delta.derive_rows_patch) is corrected from the rows that left,
+    the rows that came and its partner's same rows — carried as a
+    compensated pair of words, its composed bound kept, and RE-BASED
+    (recomputed from the table) before the bound passes
+    ``ir/delta.ROWS_REBASE_BOUND``. Nothing on that path copies the
+    table or passes over it; anything else a rows delta meets (a mesh,
+    another dtype) takes the copying path below, dependents killed.
+
 Entry mutation happens ONLY through the result cache's patch/apply
 seam (apply_patch / rekey / drop — matlint ML012 pins that).
 
@@ -41,9 +54,11 @@ import itertools
 import logging
 from typing import Dict, Optional, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from matrel_tpu.ir import delta as delta_lib
+from matrel_tpu.obs import trace as trace_lib
 from matrel_tpu.serve.result_cache import CacheEntry, result_nbytes
 
 log = logging.getLogger("matrel_tpu.ivm")
@@ -67,6 +82,20 @@ class PatchProgram:
     err_bound: float
 
 
+@dataclasses.dataclass
+class RowsView:
+    """A view that follows rows deltas in place: its second word (the
+    compensated accumulation's: executor.rows_patch), the bound a fresh
+    execution of it carries (what a re-base resets it to), and its
+    compiled patches (executor.rows_patch) — one a (delta signature,
+    form, partner): ``t(X) * y`` has one for a delta of X and one for a
+    delta of y."""
+
+    lo: object
+    base_bound: float
+    programs: Dict[tuple, object] = dataclasses.field(default_factory=dict)
+
+
 class DeltaPlane:
     """Per-session IVM orchestrator (see module docstring)."""
 
@@ -74,9 +103,12 @@ class DeltaPlane:
         delta_lib._CONSTRUCTED["count"] += 1
         self.sess = session
         self._programs: Dict[int, PatchProgram] = {}
+        # what a rows-patched view carries beside its entry, by ivm_id
+        # (reconciled against the live entries with _programs)
+        self._rows_views: Dict[int, RowsView] = {}
         self._ivm_ids = itertools.count(1)
         self.stats = {"patch_compiles": 0, "patch_reuses": 0,
-                      "measured_overrides": 0}
+                      "measured_overrides": 0, "rebases": 0}
 
     # -- entry point --------------------------------------------------------
 
@@ -85,8 +117,13 @@ class DeltaPlane:
         sess = self.sess
         cfg = sess.config
         mesh = sess.mesh
+        if delta.kind == "rows" and _in_place_table(old, mesh):
+            return self._apply_rows(name, old, delta)
         t0 = _now()
-        new = delta.apply_to(old, mesh, cfg)
+        with trace_lib.span("delta.update", in_place=False,
+                            rows=(int(delta.rows.shape[0])
+                                  if delta.kind == "rows" else None)):
+            new = delta.apply_to(old, mesh, cfg)
         gen_old = sess._delta_gen
         gen = gen_old + 1
         old_prefix = delta_lib.delta_prefix(gen_old)
@@ -151,13 +188,11 @@ class DeltaPlane:
         # orphaned PatchPrograms whose plans pin old-generation device
         # arrays — unbounded over a long session (the ML011 failure
         # class), so they drop the moment their entry is gone
-        live = {e.ivm_id for _k, e in rc.items_snapshot()
-                if e.ivm_id is not None}
-        self._programs = {i: p for i, p in self._programs.items()
-                          if i in live}
+        self._reconcile(rc)
         record = {
             "name": name, "gen": gen, "delta_kind": delta.kind,
             "delta_rank": delta.rank, "delta_nnz": delta.nnz,
+            "in_place": False,
             "examined": len(dependents),
             "patched": counters["patched"],
             "killed": counters["killed"],
@@ -170,6 +205,188 @@ class DeltaPlane:
         }
         sess._emit_delta_event(record)
         return record
+
+    def _reconcile(self, rc) -> None:
+        """Drop the patch programs and second words of entries that
+        are gone (killed, evicted, invalidated by a plain register)."""
+        live = {e.ivm_id for _k, e in rc.items_snapshot()
+                if e.ivm_id is not None}
+        self._programs = {i: p for i, p in self._programs.items()
+                          if i in live}
+        self._rows_views = {i: v for i, v in self._rows_views.items()
+                            if i in live}
+
+    # -- rows, in place -----------------------------------------------------
+
+    def _apply_rows(self, name: str, table,
+                    delta: delta_lib.MatrixDelta) -> dict:
+        """A rows delta against a dense float32 table on one device
+        (module docstring): upload, read out and overwrite in place,
+        then patch, re-base or kill each dependent entry. ``table`` is
+        the catalog's BlockMatrix before AND after: its ``data`` is the
+        donated program's output."""
+        from matrel_tpu import executor as executor_lib
+        from matrel_tpu.core.blockmatrix import BlockMatrix
+        from matrel_tpu.parallel import planner
+        from matrel_tpu.resilience.retry import now as _now
+        sess = self.sess
+        cfg, mesh, rc = sess.config, sess.mesh, sess._result_cache
+        t0 = _now()
+        c = int(delta.rows.shape[0])
+        run = delta.start is not None
+        gen = sess._delta_gen + 1
+        old_prefix = delta_lib.delta_prefix(gen - 1)
+        new_prefix = delta_lib.delta_prefix(gen)
+        keep_stale = sess._brownout is not None
+        deps = frozenset({id(table)})
+        snapshot = rc.items_snapshot()
+        todo = []
+        for key, ent in snapshot:
+            if not ent.dep_ids & deps:
+                continue
+            spec = None
+            if cfg.delta_patch_mode != "off" and ent.expr is not None \
+                    and _in_place_table(ent.result, mesh):
+                spec = delta_lib.derive_rows_patch(ent.expr, table)
+            todo.append((key, ent, spec))
+        planned = planner.rows_delta_plan(
+            table, c, [e.result for _k, e, s in todo if s is not None],
+            [s.partner for _k, _e, s in todo
+             if s is not None and s.partner is not None], mesh, cfg)
+        vals = delta.vals
+        nbytes = 0
+        with trace_lib.span("delta.upload") as sp:
+            if isinstance(vals, np.ndarray):
+                nbytes = int(vals.nbytes)
+                vals = BlockMatrix.from_numpy(vals, mesh=mesh,
+                                              config=cfg).data
+            at = (np.int32(delta.start) if run
+                  else jnp.asarray(delta.rows, jnp.int32))
+            sp.set(bytes=nbytes)
+        with trace_lib.span("delta.update", rows=c, in_place=True,
+                            hbm_plan_bytes=planned["hbm_plan_bytes"]):
+            table.data, left = executor_lib.rows_update(run)(
+                table.data, vals, at)
+        counters = {"patched": 0, "killed": 0, "no_rule": 0,
+                    "rebased": 0, "reused_plans": 0}
+        worst = 0.0
+        for key, ent, spec in todo:
+            new_key = new_prefix + key[len(old_prefix):]
+            bound = None
+            if spec is not None:
+                bound = self._patch_rows(key, new_key, ent, spec, table,
+                                         delta, vals, left, at, gen,
+                                         counters)
+            if bound is None:
+                rc.drop(key, keep_stale=keep_stale,
+                        stale_max=cfg.result_cache_max_entries,
+                        stale_max_bytes=cfg.result_cache_max_bytes)
+                counters["killed"] += 1
+                counters["no_rule"] += spec is None
+            else:
+                worst = max(worst, bound)
+        rekeyed = 0
+        for key, ent in snapshot:
+            if not ent.dep_ids & deps and key.startswith(old_prefix):
+                rekeyed += rc.rekey(key, new_prefix + key[len(old_prefix):])
+        rc.rebuild_stale(
+            lambda k: (new_prefix + k[len(old_prefix):]
+                       if k.startswith(old_prefix) else k), deps)
+        sess._delta_gen = gen
+        self._reconcile(rc)
+        record = {
+            "name": name, "gen": gen, "delta_kind": "rows",
+            "delta_rank": None, "delta_nnz": None,
+            "rows": c, "in_place": True, "upload_bytes": nbytes,
+            "examined": len(todo),
+            "patched": counters["patched"],
+            "killed": counters["killed"],
+            "no_rule": counters["no_rule"],
+            "priced_out": 0,
+            "rebased": counters["rebased"],
+            "table_passes": counters["rebased"],
+            "reused_plans": counters["reused_plans"],
+            "rekeyed": rekeyed,
+            "rules": {"rows": counters["patched"]},
+            "err_bound": worst,
+            "hbm_plan_bytes": planned["hbm_plan_bytes"],
+            "est_saved_flops": 0.0,
+            "ms": round((_now() - t0) * 1e3, 3),
+        }
+        sess._emit_delta_event(record)
+        return record
+
+    def _patch_rows(self, key: str, new_key: str, ent: CacheEntry,
+                    spec, table, delta, new_rows, old_rows, at, gen: int,
+                    counters: dict) -> Optional[float]:
+        """One view under a rows delta: corrected by its compiled patch
+        (``matrel.delta.patch``), or — where the correction would carry
+        its composed bound past ``ROWS_REBASE_BOUND`` — recomputed
+        from the table as it now stands (``matrel.delta.rebase``: the
+        statement's own plan, a pass over the table). Either way the
+        entry's BlockMatrix stays the object it was (a plan over it
+        stays the plan it was) and takes the new array. Returns the
+        entry's bound, or None where it no longer fits the cache (the
+        caller kills it)."""
+        from matrel_tpu import executor as executor_lib
+        sess = self.sess
+        cfg = sess.config
+        view = ent.result
+        ivm_id = ent.ivm_id if ent.ivm_id is not None \
+            else next(self._ivm_ids)
+        state = self._rows_views.get(ivm_id)
+        if state is None:
+            state = self._rows_views[ivm_id] = RowsView(
+                jnp.zeros_like(view.data), float(ent.err_bound))
+        bound = float(ent.err_bound) + delta_lib.rows_patch_bound(
+            int(delta.rows.shape[0]), table.shape[0])
+        if bound > delta_lib.ROWS_REBASE_BOUND:
+            with trace_lib.span("delta.rebase", rule="rows",
+                                form=spec.form, table_pass=True,
+                                err_bound=bound):
+                sla = ent.prec[len("prec:"):-1] if ent.prec else "default"
+                plan = sess._compile_entry(ent.expr, sla=sla)[0]
+                view.data = sess._arbitrated_run(plan).data
+                state.lo = jnp.zeros_like(view.data)
+            bound = state.base_bound
+            counters["rebased"] += 1
+            self.stats["rebases"] += 1
+        else:
+            sig = (delta.signature(), spec.form, id(spec.partner))
+            patch = state.programs.get(sig)
+            reused = patch is not None
+            with trace_lib.span("delta.patch", rule="rows",
+                                form=spec.form, reused=reused,
+                                table_pass=False, err_bound=bound):
+                if not reused:
+                    patch = state.programs[sig] = executor_lib.rows_patch(
+                        spec.form, delta.start is not None, cfg)
+                    self.stats["patch_compiles"] += 1
+                else:
+                    self.stats["patch_reuses"] += 1
+                    counters["reused_plans"] += 1
+                partner = (spec.partner.data if spec.partner is not None
+                           else new_rows)
+                view.data, state.lo = patch(
+                    view.data, state.lo, new_rows, old_rows, partner, at)
+        new_ent = dataclasses.replace(
+            ent,
+            key_hash=hashlib.sha1(new_key.encode()).hexdigest()[:16],
+            nbytes=2 * result_nbytes(view),
+            err_bound=bound,
+            delta_gen=gen,
+            delta_rule="rows",
+            ivm_id=ivm_id)
+        if not sess._result_cache.apply_patch(
+                key, new_key, new_ent, cfg.result_cache_max_bytes,
+                cfg.result_cache_max_entries):
+            self._rows_views.pop(ivm_id, None)
+            return None
+        if sess._prov is not None:
+            sess._prov.stamp_patched(new_ent, gen, "rows",
+                                     bound - float(ent.err_bound))
+        counters["patched"] += 1
+        return bound
 
     # -- one entry ----------------------------------------------------------
 
@@ -359,6 +576,14 @@ class DeltaPlane:
                 self.stats["measured_overrides"] += 1
                 return winner == "patch"
         return est_win
+
+
+def _in_place_table(m, mesh) -> bool:
+    """What the in-place rows programs take: a dense float32
+    BlockMatrix on ONE device."""
+    from matrel_tpu.core.blockmatrix import BlockMatrix
+    return (isinstance(m, BlockMatrix) and mesh.size == 1
+            and np.dtype(m.data.dtype) == np.float32)
 
 
 def spec_shape(spec: delta_lib.PatchSpec) -> tuple:

@@ -12,6 +12,7 @@ analogue).
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import itertools
 import logging
@@ -89,6 +90,10 @@ class MatrelSession:
         # (last_plan())
         self._last_plan = None
         self._last_hit = False
+        # what the result cache answered of the newest statement
+        # (last_plan(): ``views_hit``, ``table_pass``); None while the
+        # cache is off
+        self._last_rc = None
         self._plan_cache_bytes = 0
         self._plan_cache_evicted = 0
         self._event_log = None      # lazily built (obs_level != "off")
@@ -288,7 +293,14 @@ class MatrelSession:
         ``(rows, cols[, vals])`` edge arrays or a COOMatrix (``kind=
         "coo"``), a ``(U, V)`` pair with ``ΔA = U·Vᵀ`` (``kind=
         "lowrank"``), or a same-shaped array (``kind="dense"``);
-        ``kind="auto"`` disambiguates by shape. Each cached entry
+        ``kind="auto"`` disambiguates by shape. ``kind="rows"`` with
+        ``(row_ids, values)`` REPLACES rows: after the call the table's
+        rows ``row_ids`` equal ``values`` bit for bit; against a dense
+        float32 table on one device the rows are overwritten IN PLACE
+        (the registered BlockMatrix stays the object it was and takes
+        the new array: no second table) and the views with a rows rule
+        (``t(X) * X``, ``t(X) * y``) are corrected from the rows that
+        left and the rows that came (docs/IVM.md). Each cached entry
         depending on the old binding is patched in place through the
         delta algebra where a rule applies AND the patch prices below
         recompute (``config.delta_patch_mode``; a measured autotune
@@ -306,12 +318,17 @@ class MatrelSession:
                 f"register_delta: {name!r} is not a bound catalog "
                 f"name — register() it first")
         from matrel_tpu.ir import delta as delta_lib
-        d = delta_lib.as_delta(delta, old, kind, self.config)
-        with self._compile_lock:
-            if self._delta_plane is None:
-                from matrel_tpu.serve.ivm import DeltaPlane
-                self._delta_plane = DeltaPlane(self)
-            out = self._delta_plane.apply(name, old, d)
+        with trace_lib.entry("delta", self._tracer, kind=kind) as sp:
+            d = delta_lib.as_delta(delta, old, kind, self.config)
+            with self._compile_lock:
+                if self._delta_plane is None:
+                    from matrel_tpu.serve.ivm import DeltaPlane
+                    self._delta_plane = DeltaPlane(self)
+                out = self._delta_plane.apply(name, old, d)
+            if sp.live:
+                sp.set(**{k: out.get(k) for k in (
+                    "delta_kind", "in_place", "patched", "killed",
+                    "no_rule", "rebased", "table_passes")})
         if self._fleet is not None:
             # fleet slices hold REPLICAS of the old binding: the delta
             # plane patched the parent's caches in place, but a slice
@@ -528,12 +545,18 @@ class MatrelSession:
         products under ``products``; what its ``matrel.mmchain.plan``
         span carries) and ``densified_products``
         (one each leaf that was densified, under a product or read as
-        an array). Copies; {} before the first dispatch."""
+        an array). With the result cache on, of the newest STATEMENT:
+        ``views_hit`` (the cached results it was answered from: 1 with
+        ``root_hit`` where the statement itself was cached and nothing
+        was dispatched — the plan's records are then the plan's before
+        it) and ``table_pass`` (whether what was dispatched still read
+        a catalog table). Copies; {} before the first dispatch."""
         plan = self._last_plan
         if plan is None:
             return {}
         meta = plan.meta or {}
-        return {"hit": self._last_hit,
+        return {**(self._last_rc or {}),
+                "hit": self._last_hit,
                 "executors": list(meta.get("executors") or ()),
                 "hbm_plan_bytes": meta.get("hbm_plan_bytes"),
                 "products": [dict(r) for r in meta.get("products", ())],
@@ -857,9 +880,42 @@ class MatrelSession:
             node = self._prov.stamp_leaf(node, ent)
         return node
 
+    def _rc_chain(self, e: MatExpr, prefix: str) -> Optional[MatExpr]:
+        """A product chain with its cached SUB-PRODUCTS substituted, or
+        None where it has none. The parser brackets ``inv(t(X) * X) *
+        t(X) * y`` to the left, so ``t(X) * y`` is no subtree of it and
+        the structural walk of :meth:`_rc_substitute` cannot find the
+        view a statement ``t(X) * y`` left in the cache; only the chain
+        DP (after this consult) would bracket it so. The chain's
+        factors are taken as the DP takes them (ir/chain.collect_chain,
+        plain products only), every run of two or more of them — the
+        whole chain is the caller's own probe — is keyed as the
+        statement that would have cached it (left to right) and probed,
+        longest first, and a hit stands in for its factors."""
+        from matrel_tpu.ir import expr as expr_mod
+        factors = _product_factors(e)
+        if len(factors) < 3:
+            return None
+        hit = False
+        width = len(factors) - 1
+        while width >= 2:
+            i = 0
+            while i + width <= len(factors):
+                node = functools.reduce(expr_mod.matmul,
+                                        factors[i:i + width])
+                ent = self._result_cache.probe(prefix + _plan_key(node)[0])
+                if ent is None:
+                    i += 1
+                else:
+                    factors[i:i + width] = [self._rc_leaf(ent)]
+                    hit = True
+            width = min(width, len(factors)) - 1
+        return functools.reduce(expr_mod.matmul, factors) if hit else None
+
     def _rc_substitute(self, e: MatExpr, parts: Optional[list] = None,
                        spans: Optional[dict] = None,
-                       prefix: str = "") -> MatExpr:
+                       prefix: str = "",
+                       _in_chain: bool = False) -> MatExpr:
         """Replace every cached INTERIOR subexpression with its result
         leaf (top-down; a hit stops the descent — everything under it
         is already paid for). The root is the caller's business
@@ -871,6 +927,15 @@ class MatrelSession:
         only ever hit entries computed under the SAME SLA."""
         if not e.children:
             return e
+        plain_product = e.kind == "matmul" and not e.attrs
+        if plain_product and not _in_chain:
+            # the top of a product chain: its cached sub-products
+            # first (a view the parser's bracketing hides), then the
+            # structural walk over what is left
+            chained = self._rc_chain(e, prefix)
+            if chained is not None:
+                return self._rc_substitute(chained, prefix=prefix,
+                                           _in_chain=True)
         if parts is None or spans is None:
             parts, _pins, spans = _plan_key_spans(e)
         new_children = []
@@ -887,7 +952,8 @@ class MatrelSession:
                 new_children.append(self._rc_leaf(ent))
                 changed = True
                 continue
-            nc = self._rc_substitute(c, parts, spans, prefix)
+            nc = self._rc_substitute(c, parts, spans, prefix,
+                                     _in_chain=plain_product)
             changed = changed or (nc is not c)
             new_children.append(nc)
         return e.with_children(tuple(new_children)) if changed else e
@@ -1842,7 +1908,10 @@ class MatrelSession:
             with trace_lib.span("rc.probe") as sp:
                 ent, key, pins, e = self._rc_admit(
                     e, self._rc_key_prefix(sla))
-                sp.set(hit=ent is not None)
+                self._last_rc = _rc_answer(e, ent is not None)
+                sp.set(hit=ent is not None,
+                       views_hit=self._last_rc["views_hit"],
+                       table_pass=self._last_rc["table_pass"])
             if ent is not None:
                 # repeated query: answered from the materialized-result
                 # cache — no optimize, no trace, no device work
@@ -2672,6 +2741,41 @@ def _plan_key_spans(e: MatExpr, leaf_token=None
 
     walk(e)
     return parts, pins, spans
+
+
+def _rc_answer(e: MatExpr, root_hit: bool) -> dict:
+    """``last_plan()``'s account of one result-cache admission: ``e``
+    is the statement as it will run (cached interiors substituted)."""
+    if root_hit:
+        return {"root_hit": True, "views_hit": 1, "table_pass": False}
+    views = tables = 0
+    seen = set()
+
+    def walk(n: MatExpr):
+        nonlocal views, tables
+        if n.uid in seen:
+            return
+        seen.add(n.uid)
+        if not n.children:
+            if "result_cache" in n.attrs:
+                views += 1
+            elif "cse" not in n.attrs:
+                tables += 1
+        for c in n.children:
+            walk(c)
+
+    walk(e)
+    return {"root_hit": False, "views_hit": views,
+            "table_pass": tables > 0}
+
+
+def _product_factors(e: MatExpr) -> List[MatExpr]:
+    """The ordered factors of a tree of plain products (no stamped
+    attribute on any of them): what the chain DP re-associates."""
+    if e.kind == "matmul" and not e.attrs:
+        return (_product_factors(e.children[0])
+                + _product_factors(e.children[1]))
+    return [e]
 
 
 def _plan_key(e: MatExpr) -> Tuple[str, list]:

@@ -1247,3 +1247,76 @@ def test_linregcg_chain_statement_as_the_session_plans_it(topo,
              + mem.output_size_in_bytes)
     assert taken <= plan.meta["hbm_plan_bytes"] * 1.001 < 12e9
     assert not _arrays_written(text, LINREG_N)
+
+
+# -- the sliding window's programs (PR 56) ------------------------------------
+# executor.rows_update and executor.rows_patch at the shapes of cell
+# linreg_window_10m_1c: a batch of 8,192 rows replaces rows of the
+# table where it lies (rows on the lanes, as the chip lays it), and the
+# two views are corrected from the batch, the rows that left and the
+# partner's same rows. ONE table: the update's output IS its argument.
+
+WINDOW_BATCH = 8192
+
+
+def _window_args(one_chip, cols):
+    from jax.experimental.layout import Format, Layout
+    lie = Format(Layout(major_to_minor=(1, 0)), one_chip)
+    return (_sds(lie, (LINREG_N, cols), jnp.float32),
+            _sds(one_chip, (WINDOW_BATCH, cols), jnp.float32),
+            _sds(one_chip, (), jnp.int32))
+
+
+@pytest.mark.parametrize("cols", [LINREG_K, 1], ids=["X", "y"])
+def test_window_update_overwrites_the_table_in_place(one_chip, cols):
+    """The donated table is aliased to the output whole, nothing
+    table-sized is allocated beside it (temporaries: none), and the
+    program holds one table, under 12 GB."""
+    from matrel_tpu import executor
+    compiled = executor.rows_update(True).lower(
+        *_window_args(one_chip, cols)).compile()
+    mem = compiled.memory_analysis()
+    table = LINREG_N * cols * 4
+    assert mem.alias_size_in_bytes == table
+    assert mem.temp_size_in_bytes < WINDOW_BATCH * 1024 * 4
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert table <= held < min(12e9, table + 4 * WINDOW_BATCH * 1024 * 4
+                               + (1 << 20))
+    assert "dynamic-update-slice" in compiled.as_text()
+
+
+@pytest.mark.parametrize("form,view,partner", [
+    ("gram", (LINREG_K, LINREG_K), None),
+    ("left", (LINREG_K, 1), (LINREG_N, 1)),
+    ("right", (LINREG_K, 1), (LINREG_N, LINREG_K))])
+def test_window_patches_touch_a_batch_of_rows(one_chip, form, view,
+                                              partner):
+    """A view's patch at the cell's shapes: its two words donated and
+    aliased, the partner's table an argument that is sliced where it
+    lies (no temporary of its size), the correction a contraction over
+    8,192 rows."""
+    from jax.experimental.layout import Format, Layout
+    from matrel_tpu import executor
+    lie = Format(Layout(major_to_minor=(1, 0)), one_chip)
+    mine = LINREG_K if form != "right" else 1
+    rows = _sds(one_chip, (WINDOW_BATCH, mine), jnp.float32)
+    theirs = rows if partner is None else _sds(lie, partner, jnp.float32)
+    word = _sds(one_chip, view, jnp.float32)
+    compiled = executor.rows_patch(form, True, MatrelConfig()).lower(
+        word, word, rows, rows, theirs, _sds(one_chip, (), jnp.int32)
+    ).compile()
+    mem = compiled.memory_analysis()
+    # both words, as the chip pads them to its tiles
+    assert 2 * view[0] * view[1] * 4 <= mem.alias_size_in_bytes \
+        <= 2 * view[0] * 1024 * 4
+    assert mem.temp_size_in_bytes < 64 << 20
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert held < 12e9
+    if partner is None:
+        # the upper block triangle of each Gram: 4 + 4 dots of 8,192
+        # rows, and the whole program well under a tenth of a refit
+        cycles = sum(int(c) for c in re.findall(
+            r'"estimated_cycles":"(\d+)"', compiled.as_text()))
+        assert 0 < cycles / 1.45e9 < 0.010
