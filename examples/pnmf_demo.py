@@ -74,7 +74,8 @@ def main():
             for rec in said["sampled"]:
                 shown = {f: rec[f] for f in (
                     "orientation", "op", "entries", "dense_entries", "lines",
-                    "panel_rows", "slab_dtype", "shared_gather", "dot",
+                    "lines_by", "panel_rows", "slab_dtype", "shared_gather",
+                    "dot",
                     "hbm_plan_bytes")}
                 print(f"  iteration {it} {name}: sampled {shown}")
             assert said["sampled"] and not said["densified_products"], said

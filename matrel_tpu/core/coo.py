@@ -352,20 +352,24 @@ def sampled_facts(plan, entries: int, shared: bool) -> dict:
     is the factor whose rows its sources name, so one gather serves the
     entry's dot and the scatter): ``entries`` (ALL of them: every one is
     sampled), ``dense_entries`` (those on the slab, whose quotient the
-    MXU makes a panel at a time; 0 where the matrix has no dense part),
-    ``lines``, ``slab_dtype`` and ``panel_rows`` (the slab rows a panel
-    of the quotient takes), ``dot`` ("kernel": the scatter kernel takes
-    the destination's rows off its block tile and makes the entry's dot;
-    no path of this tree gathers them) and of the compact parts, which
-    hold the rest, :func:`plan_facts`' ``layout``, ``slots``,
-    ``chunks``, ``windowed_chunks``, ``window_rows``,
-    ``source_panels``, ``overflow_edges`` and, at this
-    product's own panel size (a slot holds one gathered row more where
-    the gather is not ``shared``), ``panels``; ``hbm_plan_bytes``: the
-    tables, the largest panel's temporaries, the slab once and one
-    panel of its quotient."""
-    from matrel_tpu.ops import pallas_spmv as pc
-    from matrel_tpu.parallel import strategies
+    MXU makes; 0 where the matrix has no dense part),
+    ``lines``, ``slab_dtype``, and who multiplies the dense lines
+    (ops/sampled_lines.plan, which the lowering asks too): ``lines_by``
+    ("kernel": ``matrel_sampled_lines``, the quotient in VMEM alone;
+    "xla": the loop of panels, with ``lines_why_not``; "" without a
+    slab) and ``panel_rows`` (the slab rows a step of it takes: the
+    kernel's row tile, the loop's panel); ``dot`` ("kernel": the scatter
+    kernel takes the destination's rows off its block tile and makes the
+    entry's dot; no path of this tree gathers them) and of the compact
+    parts, which hold the rest, :func:`plan_facts`' ``layout``,
+    ``slots``, ``chunks``, ``windowed_chunks``, ``window_rows``,
+    ``source_panels``, ``overflow_edges`` and, at this product's own
+    panel size (a slot holds one gathered row more where the gather is
+    not ``shared``), ``panels``; ``hbm_plan_bytes``: the tables, the
+    largest panel's temporaries, the slab once and what its product
+    writes beside it — the loop a panel's float32 cells, dot and
+    quotient, the kernel nothing."""
+    from matrel_tpu.ops import pallas_spmv as pc, sampled_lines
     own = plan_facts(plan, entries)
     more = 0 if shared else 1
     shapes = [np.asarray(p.src8).shape for _, p in plan_parts(plan)]
@@ -373,20 +377,23 @@ def sampled_facts(plan, entries: int, shared: bool) -> dict:
     facts = {k: own[k] for k in ("layout", "slots", "chunks",
                                  "windowed_chunks", "window_rows",
                                  "source_panels", "overflow_edges")}
+    lines = {"lines_by": "", "panel_rows": 0}
+    beside = 0
+    if dense is not None:
+        lines = sampled_lines.plan(dense.width, dense.slab.dtype.itemsize)
+        if lines["lines_by"] != "kernel":
+            beside = 3 * 4 * lines["panel_rows"] * dense.width
     facts.update(
         entries=int(entries), dense_entries=own.get("dense_entries", 0),
         lines=own.get("dense_lines", 0),
-        slab_dtype=own.get("dense_dtype", ""),
-        panel_rows=strategies.ACC_PANEL_ROWS if dense is not None else 0,
+        slab_dtype=own.get("dense_dtype", ""), **lines,
         shared_gather=bool(shared), dot="kernel",
         panels=int(sum(-(-r // pc.wide_panel_rows(r, c, more))
                        for r, c in shapes)),
         hbm_plan_bytes=int(
             pc.TABLE_BYTES_A_SLOT * own["slots"]
             + max(pc.wide_panel_bytes(r, c, more) for r, c in shapes)
-            + (0 if dense is None else dense.slab.nbytes
-               # a panel's cells, dot and quotient, float32
-               + 3 * 4 * strategies.ACC_PANEL_ROWS * dense.width)))
+            + (0 if dense is None else dense.slab.nbytes + beside)))
     return facts
 
 

@@ -1350,30 +1350,65 @@ def sampled_values(op: str, s, d):
                      s / jnp.where(d == 0, jnp.ones((), d.dtype), d))
 
 
+def lines_rows(t, lines, width: int):
+    """The rows of ``t`` that a slab's ``lines`` name, in the slab's
+    column order, zero rows up to its ``width``."""
+    return jnp.pad(t.at[lines].get(mode="promise_in_bounds"),
+                   ((0, width - lines.shape[0]), (0, 0)))
+
+
 def _sampled_dense_part(Y, role: str, slab, lines, Z, op: str, of_src,
-                        of_dst):
-    """``Y`` plus the dense lines' share of a sampled product
-    (:func:`sampled_matmat_parts`), a panel of
-    ``strategies.ACC_PANEL_ROWS`` rows of the slab at a time: the panel
-    of the dense product at the slab's cells on the MXU, ``D = P_p ·
-    R[lines]ᵀ`` (``P`` the factor whose rows the slab's rows name, ``R``
-    the one its lines name), the sampled values ``Q = slab_p op D`` where
-    the slab holds an entry (the slab is the STRUCTURE here: its zeros
-    are the cells without one), and ``Q``'s product at once — where the
-    lines are the product's ``"sources"``, ``Y_p += Q · Z[lines]``; where
-    its ``"destinations"``, ``Y[lines] += Qᵀ · Z_p``, summed over the
-    panels on the vector unit. ``Q`` never leaves its panel. Every dot is
-    float32 at ``highest`` whatever the slab's dtype: the quotient is no
-    bfloat16's, so a product costs six MXU passes where the slab's own
-    values cost three."""
+                        of_dst, inner: int, interpret: bool):
+    """``Y`` (destinations, k) plus the dense lines' share of a sampled
+    product (:func:`sampled_matmat_parts`): the dense product at the
+    slab's cells on the MXU, ``D = P · R[lines]ᵀ`` (``P`` the factor
+    whose rows the slab's rows name, ``R`` the one its lines name), the
+    sampled values ``Q = slab op D`` where the slab holds an entry (the
+    slab is the STRUCTURE here: its zeros are the cells without one), and
+    ``Q``'s product at once — where the lines are the product's
+    ``"sources"``, ``Y += Q · Z[lines]``; where its ``"destinations"``,
+    ``Y[lines] += Qᵀ · Z``. Every dot is float32 at ``highest`` whatever
+    the slab's dtype: the quotient is no bfloat16's, so a product costs
+    six MXU passes where the slab's own values cost three. ``Z``,
+    ``of_src`` and ``of_dst`` come 128 lanes wide, zero past ``Z``'s k
+    and the factors' ``inner`` columns.
+
+    Who multiplies is ``sampled_lines.plan``'s to say from the shapes
+    (core.coo.sampled_facts says the same of the plan: ``lines_by``).
+    The kernel ``matrel_sampled_lines`` (ops/sampled_lines.py), a row
+    tile of the slab a grid step: ``D`` and ``Q`` exist only in VMEM;
+    what leaves it is ``Y``'s row tile, written where ``Y`` lies
+    (``"sources"``), or the ``(width, 128)`` sums once at the grid's end
+    (``"destinations"``); the ragged last tile is the kernel's own, its
+    rows past the slab's end masked. Else :func:`_sampled_lines_xla`, the
+    loop of panels it replaces."""
+    from matrel_tpu.ops import sampled_lines
+    k = Y.shape[1]
+    how = sampled_lines.plan(slab.shape[1], slab.dtype.itemsize)
+    if how["lines_by"] != "kernel":
+        return _sampled_lines_xla(Y, role, slab, lines, Z[:, :k], op,
+                                  of_src[:, :inner], of_dst[:, :inner])
+    P, R = (of_dst, of_src) if role == "sources" else (of_src, of_dst)
+    out = sampled_lines.sampled_lines(
+        jnp.pad(Y, ((0, 0), (0, WIDE_COLS - k))), role, slab, lines, Z, op,
+        P, R, tile=how["panel_rows"], interpret=interpret)
+    return out[:, :k]
+
+
+def _sampled_lines_xla(Y, role: str, slab, lines, Z, op: str, of_src,
+                       of_dst):
+    """:func:`_sampled_dense_part` as an XLA loop, a panel of
+    ``strategies.ACC_PANEL_ROWS`` rows of the slab at a time: ``D = P_p ·
+    R[lines]ᵀ`` and ``Q`` are one fusion, ``Q``'s product the next, so
+    the panel's ``(rows, width)`` float32 quotient is written to HBM by
+    the first and read by the second; ``"sources"`` adds into ``Y``'s
+    panel of rows, ``"destinations"`` sums ``Qᵀ · Z_p`` over the panels
+    on the vector unit; the ragged tail is a last, shorter panel. Where
+    the kernel's tiles do not fit, and the tests' second opinion."""
     from matrel_tpu.parallel import strategies
     n, width = lines.shape[0], slab.shape[1]
     along = role == "sources"
     P, R = (of_dst, of_src) if along else (of_src, of_dst)
-
-    def at_lines(t):
-        return jnp.pad(t.at[lines].get(mode="promise_in_bounds"),
-                       ((0, width - n), (0, 0)))
 
     def dot(a, ca, b, cb):
         return jax.lax.dot_general(
@@ -1381,7 +1416,7 @@ def _sampled_dense_part(Y, role: str, slab, lines, Z, op: str, of_src,
             precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
-    RL = at_lines(R)
+    RL = lines_rows(R, lines, width)
 
     def quotient(start, rows):
         cells = jax.lax.dynamic_slice_in_dim(slab, start, rows) \
@@ -1400,7 +1435,7 @@ def _sampled_dense_part(Y, role: str, slab, lines, Z, op: str, of_src,
         return step(whole * per, tail, carry) if tail else carry
 
     if along:
-        ZL = at_lines(Z)
+        ZL = lines_rows(Z, lines, width)
 
         def step(start, rows, Y):
             mine = jax.lax.dynamic_slice_in_dim(Y, start, rows)
@@ -1436,7 +1471,10 @@ def sampled_matmat_parts(plan_static, part_statics, part_arrays,
     and what the executor runs; they are the parts of the scatter's
     contributions, the dot is float32 whatever they say); the dense
     part is :func:`_sampled_dense_part`, float32 at ``highest``
-    whatever ``passes`` says; overflow entries go by the scalar path
+    whatever ``passes`` says: ONE kernel over the slab where its tiles
+    fit VMEM (``matrel_sampled_lines``: the lines' sampled values never
+    leave VMEM), else XLA's loop of panels, which writes a panel of them
+    to HBM between its two dots; overflow entries go by the scalar path
     with the same values. The sampled values are never stored whole."""
     dense = None
     if part_statics and isinstance(part_statics[-1][0], str):
@@ -1476,9 +1514,8 @@ def sampled_matmat_parts(plan_static, part_statics, part_arrays,
     if dense is None:
         return Y
     role, slab, lines = dense
-    inner = of_dst.shape[1]
-    return _sampled_dense_part(Y, role, slab, lines, Zf[:, :k], op,
-                               mine[:, :inner], dst[:n_rows, :inner])
+    return _sampled_dense_part(Y, role, slab, lines, Zf, op, mine,
+                               lanes(of_dst), of_dst.shape[1], interpret)
 
 
 def compact_matmat_apply(plan_static, tables, ov, X: jax.Array,

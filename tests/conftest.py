@@ -112,6 +112,21 @@ def _fresh_session():
 
 
 @pytest.fixture(autouse=True)
+def _fresh_profile_ring():
+    """The profile tier's ring is the process's, and a traced rehearsal
+    of a benchmark cell finds its window in it by the window's length
+    (benchmarks/program_spans.py: the FIRST run of query roots that
+    fits): where a worker ran another cell's traced rehearsal before, it
+    found that one's and dropped its per-layer metrics (PR 57: one of
+    ``tests/test_bench_*.py`` failed so in each of two whole runs, and
+    the parent's tree fails alike when two of them share a process).
+    Every test starts with the ring empty."""
+    from matrel_tpu.obs import trace
+    trace._PROFILE_RING._buf.clear()
+    yield
+
+
+@pytest.fixture(autouse=True)
 def _autotune_table_tmp(tmp_path, monkeypatch):
     """Keep the persisted autotune table out of the repo root and out of
     cross-test state: each test gets a fresh table path + empty cache."""

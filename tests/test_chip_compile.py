@@ -322,7 +322,14 @@ def test_pnmf_sampled_products(one_chip, monkeypatch):
     GNMF's), the slab is read where it lies, and arguments and
     temporaries stay inside what ``sampled_facts`` reckons the plan to
     hold. ``win`` is one int32 a chunk, start and rung of the ladder in
-    one word (PR 49: the operands are PR 47's). Prints what the compile
+    one word (PR 49: the operands are PR 47's). PR 57: the dense lines
+    of both products (the slab 480,189 x 4,224 bfloat16, ``k`` = ``inner``
+    = 128, the lines the W update's sources and the H update's
+    destinations) through ``matrel_sampled_lines`` beside the scatter
+    kernel — no panel of the quotient (``f32[8192,4224]``) is left, no
+    copy or transpose of a factor the size of ``W`` feeds the kernel, and
+    the reckoning holds WITHOUT the loop's 415 MB a panel, as
+    ``sampled_facts`` now reckons it. Prints what the compile
     says of the kernel; its bundles a step are read offline, one body a
     compile (PERF.md section 6, PR 49: 3,418 at a 128-row window, 5,883
     at a 256-row one and 11,983 at the whole block, where the plain
@@ -334,7 +341,9 @@ def test_pnmf_sampled_products(one_chip, monkeypatch):
     lines = _sds(one_chip, (PN_LINES,), jnp.int32)
     run = jax.jit(pc.sampled_matmat_parts,
                   static_argnums=(0, 1, 4, 7, 8))
-    panel = 3 * 4 * strategies.ACC_PANEL_ROWS * PN_LINES
+    from matrel_tpu.ops import sampled_lines
+    assert sampled_lines.plan(PN_LINES, 2) == {"lines_by": "kernel",
+                                               "panel_rows": 512}
 
     def wins(chunks):
         return (_sds(one_chip, (chunks,), jnp.int32),)
@@ -347,7 +356,11 @@ def test_pnmf_sampled_products(one_chip, monkeypatch):
         assert not re.search(rf"= bf16\[{NF_USERS},{PN_LINES}\]\S* "
                              r"(copy|transpose)\(", text)
         assert "matrel_sampled_scatter_chunks" in text
+        assert "matrel_sampled_lines" in text
         assert "matrel_spmm_scatter_chunks" not in text
+        assert f"f32[{strategies.ACC_PANEL_ROWS},{PN_LINES}]" not in text
+        assert not re.search(rf"= f32\[(128,{NF_USERS}|{NF_USERS},128)\]\S* "
+                             r"(copy|transpose)\(", text)
         # the gathers by the slot (the dense lines' own factor rows, a
         # gather of 4,224, are the dense part's and stay)
         tables = _gather_operands(text, rows_over=PN_LINES)
@@ -362,7 +375,7 @@ def test_pnmf_sampled_products(one_chip, monkeypatch):
                                 rf"|f32\[{938 * BLOCK}\D", t)
                        for t in tables), tables
         stats = compiled.memory_analysis()
-        reckoned = (stats.argument_size_in_bytes + panel + 3 * out_bytes
+        reckoned = (stats.argument_size_in_bytes + 3 * out_bytes
                     + pc._wide_slot_bytes(0) * slots_a_panel)
         taken = stats.argument_size_in_bytes + stats.temp_size_in_bytes
         assert taken < 1.05 * reckoned, (reckoned, taken)
@@ -405,6 +418,36 @@ def test_pnmf_sampled_products(one_chip, monkeypatch):
                  4 * NF_MOVIES * NF_RANK, NF_MOVIES)
     assert re.search(rf"f32\[{NF_PANEL_USERS + 8},128\]\{{[^}}]*S\(1\)\}}",
                      text)
+
+
+@pytest.mark.parametrize("role", ["sources", "destinations"])
+@pytest.mark.parametrize("dtype,tile", [("bfloat16", 128), ("float32", 512)])
+def test_the_lines_kernel_fits_vmem_wherever_its_plan_says_so(
+        one_chip, dtype, tile, role):
+    """``sampled_lines.plan`` reckons a step's VMEM from the shapes
+    (``vmem_bytes``); interpret mode cannot say whether Mosaic agrees.
+    The WIDEST slab it still takes at a row tile — 15,232 bfloat16 lines
+    at 128 rows, 4,864 float32 lines at 512 — compiles for the described
+    chip inside ``VMEM_LIMIT`` in both roles."""
+    from matrel_tpu.ops import sampled_lines
+    cell = jnp.dtype(dtype).itemsize
+    width = 128
+    while sampled_lines.vmem_bytes(tile, width + 128, cell) <= \
+            sampled_lines.VMEM_LIMIT:
+        width += 128
+    assert width == {"bfloat16": 15_232, "float32": 4_864}[dtype]
+    assert sampled_lines.plan(width, cell) == {"lines_by": "kernel",
+                                               "panel_rows": tile}
+    rows, others = 3_000, 20_000
+    tall = _sds(one_chip, (rows, 128), jnp.float32)
+    wide = _sds(one_chip, (others, 128), jnp.float32)
+    Z, Y = (wide, tall) if role == "sources" else (tall, wide)
+    text = _compile(
+        jax.jit(lambda Y, slab, lines, Z, P, R: sampled_lines.sampled_lines(
+            Y, role, slab, lines, Z, "div", P, R, tile=tile)),
+        Y, _sds(one_chip, (rows, width), jnp.dtype(dtype)),
+        _sds(one_chip, (width,), jnp.int32), Z, tall, wide).as_text()
+    assert "matrel_sampled_lines" in text
 
 
 def test_compact_spmv_sharded_2x2(mesh_2x2):

@@ -1217,8 +1217,9 @@ class TestProfilerTier:
         # PR 36: the hub scatter; PR 44: less the routed SpMV's two;
         # PR 47: the sampled scatter; PR 50: the chunk grid's reduction;
         # PR 51: the hub chunks' reduction; PR 54: the fused chain;
-        # PR 55: the Gram's triangle
-        assert len(_PALLAS_SITES) == 13
+        # PR 55: the Gram's triangle; PR 57: the sampled product's dense
+        # lines
+        assert len(_PALLAS_SITES) == 14
 
 
 class TestAnalyzeEvent:

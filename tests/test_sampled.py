@@ -177,13 +177,16 @@ def test_both_products_match_float64(rng, one_chip, hot, exact,
     # a panel of table rows each source panel: no split for a window
     assert rec["panels"] == rec["source_panels"]
     if hot:
-        assert rec["lines"] == HOT and rec["panel_rows"] == 8192
+        # the dense lines through matrel_sampled_lines, 512 slab rows a
+        # step (PR 57)
+        assert rec["lines"] == HOT and rec["panel_rows"] == 512
+        assert rec["lines_by"] == "kernel" and "lines_why_not" not in rec
         assert 0 < rec["dense_entries"] < V.nnz
         assert rec["slab_dtype"] == ("bfloat16" if exact
                                      else "float32")
     else:
         assert (rec["lines"], rec["dense_entries"], rec["slab_dtype"],
-                rec["panel_rows"]) == (0, 0, "", 0)
+                rec["panel_rows"], rec["lines_by"]) == (0, 0, "", 0, "")
     assert 0 < rec["hbm_plan_bytes"] <= said["hbm_plan_bytes"]
 
 
@@ -353,6 +356,107 @@ def test_the_sampled_kernel_matches_float64(rng, name, shared, op):
     assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 2e-6
     empty = np.setdiff1d(np.arange(plan.n_rows), rows)
     assert not got[empty].any()
+
+
+def _lines_case(rng, role, dtype, rows, count, width=256, others=400):
+    """A slab of ``rows`` x ``width`` cells, a third of them entries
+    (1 to 5 in bfloat16, no bfloat16's in float32), ``count`` lines of
+    ``others`` and zero columns past them; the factors and the dense
+    side 128 lanes wide, the row of ``R`` that the first line names ZERO
+    (a zero denominator down a whole column of the slab)."""
+    import jax.numpy as jnp
+    cells = rng.integers(1, 6, (rows, width)).astype(np.float32)
+    if dtype == "float32":
+        cells += rng.uniform(0.001, 0.4, cells.shape).astype(np.float32)
+    cells *= rng.random(cells.shape) < 0.35
+    cells[:, count:] = 0.0
+    lines = np.sort(rng.choice(others, count, replace=False)).astype(np.int32)
+    P = rng.uniform(0.1, 1.0, (rows, 128)).astype(np.float32)
+    R = rng.uniform(0.1, 1.0, (others, 128)).astype(np.float32)
+    R[lines[0]] = 0.0
+    n_z, n_y = (others, rows) if role == "sources" else (rows, others)
+    Z = rng.uniform(-1, 1, (n_z, 128)).astype(np.float32)
+    Y = rng.uniform(-1, 1, (n_y, 128)).astype(np.float32)
+    return (jnp.asarray(cells, dtype), jnp.asarray(lines),
+            *(jnp.asarray(a) for a in (P, R, Z, Y)))
+
+
+@pytest.mark.parametrize("count", [256, 200], ids=["whole-groups", "padded"])
+@pytest.mark.parametrize("rows", [256, 300, 70],
+                         ids=["whole-tiles", "ragged-tail", "under-a-tile"])
+@pytest.mark.parametrize("op", ["div", "mul"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("role", ["sources", "destinations"])
+def test_the_lines_kernel_matches_float64_and_the_loop(rng, role, dtype, op,
+                                                       rows, count):
+    """``matrel_sampled_lines`` interpreted at a row tile of 128 (two
+    whole steps; two and a ragged third, whose rows past the slab's end
+    the kernel masks; one step taller than the slab), either role, either
+    slab, ``./`` and ``.*``, the lines a whole number of lane groups or
+    padded to one: against float64 at the 2e-6 of max |want| the file
+    holds a product to, and against XLA's loop of panels, the path it
+    replaces, at the same."""
+    import jax
+    from matrel_tpu.ops import pallas_spmv as pc, sampled_lines
+    slab, lines, P, R, Z, Y = _lines_case(rng, role, dtype, rows, count)
+    got = np.asarray(jax.jit(lambda y: sampled_lines.sampled_lines(
+        y, role, slab, lines, Z, op, P, R, tile=128, interpret=True))(Y))
+    of_src, of_dst = (R, P) if role == "sources" else (P, R)
+    loop = np.asarray(pc._sampled_lines_xla(Y, role, slab, lines, Z, op,
+                                            of_src, of_dst))
+    s, p, r, z = (np.asarray(a).astype(np.float64)
+                  for a in (slab[:, :count], P, R[lines], Z))
+    d = p @ r.T
+    q = s * d if op == "mul" else np.where(
+        (s != 0) & (d != 0), s / np.where(d != 0, d, 1.0), 0.0)
+    want = np.asarray(Y).astype(np.float64)
+    if role == "sources":
+        want = want + q @ z[np.asarray(lines)]
+    else:
+        want[np.asarray(lines)] += q.T @ z
+    assert got.shape == want.shape and np.all(np.isfinite(got))
+    for other in (want, loop):
+        assert np.max(np.abs(got - other)) / np.max(np.abs(want)) < 2e-6
+    if role == "destinations":      # the lines it does not name: untouched
+        rest = np.setdiff1d(np.arange(Y.shape[0]), np.asarray(lines))
+        np.testing.assert_array_equal(got[rest], np.asarray(Y)[rest])
+
+
+def test_who_multiplies_the_lines_is_said_and_the_loop_still_answers(
+        rng, one_chip, monkeypatch):
+    """``sampled_lines.plan`` from the slab's shape alone: the kernel at
+    the tallest row tile that fits ``VMEM_LIMIT`` at the slab's width, a
+    shorter one where that does not, XLA's loop with ``lines_why_not``
+    where none does — and a session whose slab no tile fits says so in
+    ``last_plan()`` and answers the product through the loop, to the
+    same float64."""
+    from matrel_tpu.ops import sampled_lines
+    assert sampled_lines.plan(4_224, 2) == {
+        "lines_by": "kernel", "panel_rows": 512}
+    assert sampled_lines.plan(9_600, 4)["panel_rows"] == 128
+    assert sampled_lines.plan(9_600, 2)["panel_rows"] == 256
+    assert sampled_lines.plan(40_960, 2) == {
+        "lines_by": "xla", "lines_why_not": "vmem", "panel_rows": 8192}
+    V = _ratings(rng, hot=True, exact=True)
+    s = _session(one_chip)
+    w, h, W, H = _factors(rng, s)
+    for name, m in (("V", V), ("W", W), ("H", H)):
+        s.register(name, m)
+    with monkeypatch.context() as tight:
+        tight.setattr(sampled_lines, "VMEM_LIMIT", 1 << 19)
+        got = s.compute(s.sql("(V / (W * H)) * t(H)")).to_numpy()
+    (rec,) = s.last_plan()["sampled"]
+    assert (rec["lines_by"], rec["lines_why_not"], rec["panel_rows"]) == (
+        "xla", "vmem", 8192)
+    kernel = coo_lib.sampled_facts(V._get_wide_plan(), V.nnz, True)
+    assert kernel["lines_by"] == "kernel"
+    # the loop keeps a panel's float32 cells, dot and quotient beside
+    # the slab; the kernel nothing
+    assert rec["hbm_plan_bytes"] - kernel["hbm_plan_bytes"] == \
+        3 * 4 * 8192 * kernel["lines"]
+    Vd = V.to_dense().astype(np.float64)
+    want = (Vd / (w.astype(np.float64) @ h)) @ h.T
+    np.testing.assert_allclose(got, want, rtol=5e-6)
 
 
 @pytest.mark.parametrize("orientation", ["transposed", "forward"])
