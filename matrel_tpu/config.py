@@ -370,12 +370,10 @@ class MatrelConfig:
         (est saved dispatches / HBM bytes), and MV111 verifies every
         stamp. The degradation ladder's rung 3 forces this off so a
         miscompiling fused region cannot survive retry.
-      cse_enable: admission-time multi-query optimization
+      cse_enable: admission-time cross-query CSE
         (matrel_tpu/serve/mqo.py; docs/SERVING.md). Off (the default)
-        is bit-identical to the historical serve plane: no hoist or
-        template object is ever constructed (test-enforced), every
-        cache key keeps its historical format, plan snapshots
-        unchanged. On: (1) cross-query CSE — a MultiPlan batch
+        no hoist is ever chosen (test-enforced) and a batch of one
+        root is indifferent to it. On: a MultiPlan batch
         (``run_many`` / the admission worker's coalesced batches)
         detects interior subplans shared across its queries via the
         structural span keys, computes each exactly once, and feeds
@@ -383,24 +381,19 @@ class MatrelConfig:
         result-cache interior-hit crediting, so ``infer_layout`` /
         ``comm_cost`` price the reuse); hoists happen only at fused-
         region boundaries (non-fusable kinds), so per-query epilogue
-        chains keep fusing instead of being split. (2) plan-template
-        reuse — queries structurally identical modulo dense-leaf
-        bindings hit a template cache keyed on the leaf-ABSTRACTED
-        structural key and rebind their leaves into the already-
-        compiled program, paying zero optimize/trace (the IVM
-        ``ivm_role`` rebinding seam generalized to serve traffic);
-        the ``degr:``/``axisw:``/``prec:`` key-prefix idiom keeps
-        degrade/topology/SLA isolation intact. MV116 verifies the
+        chains keep fusing instead of being split. MV116 verifies the
         stamps; shared results flow into the result cache with
-        transitive dep sets so rebind invalidation cascades.
+        transitive dep sets so rebind invalidation cascades. (Plan
+        templates are no part of it: every session's plan lookup asks
+        them on a plan-cache miss, ``session._plan_lookup``.)
       cse_min_uses: occurrence threshold for hoisting one shared
         interior (>= 2: a "shared" node used once is just the query
         itself). Occurrences are counted across the whole batch,
         within-query duplicates included.
-      cse_template_max: entry bound on the plan-template cache (LRU
-        past it — a template is an affinity hint over the plan cache,
-        never a correctness surface; eviction only costs a
-        recompile).
+      cse_template_max: entry bound on the plan-template cache every
+        session keeps (LRU past it — a template is an affinity hint
+        over the plan cache, never a correctness surface; eviction
+        only costs a recompile).
       delta_patch_mode: how ``session.register_delta`` maintains
         dependent result-cache entries (serve/ivm.py; docs/IVM.md).
         "auto" (the default): patch when a delta rule applies AND the

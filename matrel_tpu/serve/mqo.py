@@ -1,4 +1,5 @@
-"""Admission-time multi-query optimization (docs/SERVING.md).
+"""Multi-query optimization: plan templates and admission-time
+cross-query CSE (docs/SERVING.md).
 
 The serve plane dedups whole-query ROOTS (``run_many``'s structural
 uniq) and catches interior reuse only AFTER a prior query materialized
@@ -12,9 +13,10 @@ to the query stream. Two mechanisms, both driven by the session's ONE
 structural-key walk (``session._plan_key_spans`` — span-slice joins,
 never subtree re-walks):
 
-**Cross-query CSE** (:func:`choose_hoists` / :func:`substitute`): the
-interior subtrees shared by >= ``config.cse_min_uses`` occurrences
-across a batch are hoisted into a compute-once MultiPlan of their own;
+**Cross-query CSE** (:func:`choose_hoists` / :func:`substitute`;
+``config.cse_enable``, off by default): the interior subtrees shared
+by >= ``config.cse_min_uses`` occurrences across a batch are hoisted
+into a compute-once MultiPlan of their own;
 every consumer query re-enters planning with the result substituted as
 an already-laid-out leaf carrying a ``cse`` stamp — the result-cache
 interior-hit shape, so ``infer_layout``/``comm_cost`` credit the reuse
@@ -24,18 +26,21 @@ and ``matmul_decisions`` marks the hoist-fed operands
 already crosses a region edge), so per-consumer epilogue chains keep
 fusing into their own regions instead of being split by the share.
 
-**Plan-template reuse** (:class:`MqoState` + :func:`template_key`):
-queries structurally identical modulo dense-leaf bindings key one
-TEMPLATE on the leaf-abstracted structural key — dense leaves emit a
+**Plan-template reuse** (:class:`MqoState` + :func:`template_key`;
+every session — the plan cache's second key, asked by
+``session._plan_lookup`` on a concrete miss only): queries
+structurally identical modulo dense-leaf bindings key one TEMPLATE on
+the leaf-abstracted structural key — dense leaves emit a
 session-independent token carrying exactly the host metadata planning
 consults (shape, PartitionSpec, dtype, density, integrality bounds),
 so rebinding a new matrix with the same token into the compiled
 program is planning-equivalent by construction; sparse/COO leaves keep
 their identity tokens (their payloads are baked into the compiled
-program as constants — not rebindable). Steady-state dashboard traffic
-rebinds leaves into the cached plan via ``plan.run(bindings=...)`` —
-the IVM ``ivm_role`` rebinding seam (serve/ivm.py) generalized to
-serve traffic — and pays ZERO optimize/trace. The session composes the
+program as constants — not rebindable). An iteration's statements
+over new factors (NMF, CG, WCC) and steady-state dashboard traffic
+rebind leaves into the cached plan via ``plan.run(bindings=...)`` —
+the IVM ``ivm_role`` rebinding seam (serve/ivm.py) generalized — and
+pay ZERO optimize/trace. The session composes the
 ``degr:``/``axisw:``/``prec:`` key prefixes onto every template key,
 so degrade/topology/SLA isolation is inherited, not re-implemented.
 
@@ -44,10 +49,9 @@ Verification: MV116 (analysis/cse_pass.py) statically checks every
 recent hoist-substituted batches UNSHARED (the MV113 patched-entry
 idiom) — :attr:`MqoState.recent` is the bounded ring it replays.
 
-Zero-overhead contract: ``cse_enable = False`` (the default)
-constructs NOTHING from this module — no state, no hoist, no template
-(``_CONSTRUCTED`` is the poisoned-init test hook, the FusedRegion
-discipline) — and every cache key keeps its historical format.
+``cse_enable = False`` (the default) never hoists: ``_CONSTRUCTED``
+counts the hoists chosen, and a query the plan cache answers reaches
+nothing in this module.
 """
 
 from __future__ import annotations
@@ -58,9 +62,9 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-#: Test hook (tests/test_cse.py): with ``cse_enable`` off NOTHING in
-#: this module is ever constructed — the count stays exactly 0 over
-#: the whole default-config suite (the ir/fusion._CONSTRUCTED idiom).
+#: Test hook (tests/test_cse.py): with ``cse_enable`` off no hoist is
+#: ever chosen — the count stays where it was over a default session's
+#: batches (the ir/fusion._CONSTRUCTED idiom).
 _CONSTRUCTED = {"count": 0}
 
 #: Ring depth of :attr:`MqoState.recent` — what MV116's dynamic half
@@ -105,9 +109,6 @@ class TemplateEntry:
     slots: Tuple[Tuple[str, Tuple[int, ...]], ...]
     pins: Tuple
 
-    def __post_init__(self):
-        _CONSTRUCTED["count"] += 1
-
 
 class MqoState:
     """Per-session multi-query-optimization state: the template cache
@@ -117,7 +118,6 @@ class MqoState:
     hoist-substituted executions MV116's dynamic half replays."""
 
     def __init__(self, config):
-        _CONSTRUCTED["count"] += 1
         self.config = config
         self.templates: "OrderedDict[str, TemplateEntry]" = OrderedDict()
         self.cse_hoisted = 0          # lifetime hoisted interiors
@@ -216,8 +216,7 @@ def template_key(e) -> Tuple[str, list, list]:
     keep their identity tokens (payloads are trace constants in the
     compiled program — not rebindable) and are pinned. Interior tokens
     come byte-identical from the session's one structural-walk
-    implementation. Raises ``KeyError`` when the tree is ineligible
-    (the ``_plan_key_spans`` leaf-token contract)."""
+    implementation."""
     from matrel_tpu import session as session_mod
 
     pins: list = []

@@ -844,7 +844,7 @@ def _restore_fleet(session, records) -> int:
 
 
 def _restore_templates(session, keys) -> int:
-    if not keys or not session._cse_on():
+    if not keys:
         return 0
     try:
         return session._mqo_state().seed_templates(keys)

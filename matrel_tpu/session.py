@@ -117,9 +117,8 @@ class MatrelSession:
             self._spill.emit = self._emit_spill_event
             self._result_cache.attach_spill(self._spill)
         # multi-query optimization (serve/mqo.py; docs/SERVING.md):
-        # cross-query CSE + plan templates — None for the default
-        # config (cse_enable off: the structural zero-object contract,
-        # poisoned-init test-enforced; mqo._CONSTRUCTED stays 0)
+        # the plan templates (built on the first plan-cache miss) and
+        # cross-query CSE's hoist state (cse_enable)
         self._mqo = None
         # obs tier 2 (obs/trace.py): the flight-recorder ring is
         # independent of obs_level (always-cheap post-mortem trail);
@@ -473,23 +472,44 @@ class MatrelSession:
     def _compile_entry(self, e: MatExpr, sla: Optional[str] = None,
                        rung: int = 0
                        ) -> Tuple[executor_lib.CompiledPlan, bool, str]:
-        """(plan, cache_hit, key) — the compile path with its cache
-        outcome exposed, so compute() can emit hit/miss events without
-        a second key computation. ``rung`` > 0 compiles a DEGRADED
-        retry attempt (resilience/degrade.py): the config loses the
-        rung's features and the key gains the ``degr:<rung>|`` prefix,
-        so a degraded plan never shares a cache slot with the stamped
-        original (the axisw/prec prefix idiom)."""
+        """(plan, cache_hit, key) of ``e``'s OWN plan — the concrete
+        key only, no template: for the callers that take no bindings
+        (``compile()``, IVM's rebase, ``_replan_warm``). ``rung`` > 0
+        compiles a DEGRADED retry attempt (resilience/degrade.py): the
+        config loses the rung's features and the key gains the
+        ``degr:<rung>|`` prefix, so a degraded plan never shares a cache
+        slot with the stamped original (the axisw/prec prefix idiom)."""
+        return self._plan_lookup(e, sla, rung, rebind=False)[:3]
+
+    def _plan_lookup(self, e: MatExpr, sla: Optional[str] = None,
+                     rung: int = 0, rebind: bool = True):
+        """(plan, hit, key, bindings): which compiled program answers
+        ``e``. The order lives here and nowhere else — the concrete
+        key; on its miss the abstract key (serve/mqo.py: a plan
+        template, the program already compiled for this structure,
+        its dense leaves rebound); else compile, and insert under both.
+        ``bindings`` (dense-leaf uid -> matrix, for ``plan.run``) is
+        None on a concrete hit and on a compile. Both keys compose the
+        SAME isolation prefixes, so a degraded or fast-SLA template
+        can never serve a pristine exact query."""
         sla = sla if sla is not None else self.config.precision_sla
         with trace_lib.span("plan") as sp:
             # fault site "compile" (resilience/faults.py): free when off
             faults_lib.check("compile", self.config)
             key, pins = _plan_key(e)
-            key = (degrade_lib.key_prefix(rung) + self._axisw_prefix()
-                   + self._coeff_prefix() + _prec_prefix(sla) + key)
+            prefix = (degrade_lib.key_prefix(rung) + self._axisw_prefix()
+                      + self._coeff_prefix() + _prec_prefix(sla))
+            key = prefix + key
             plan = self._plan_probe(key, sp)
-        if plan is not None:
-            return plan, True, key
+            if plan is not None:
+                return plan, True, key, None
+            if rebind:
+                abstract = [mqo_lib.template_key(e)]
+                tkey = prefix + abstract[0][0]
+                tpl = self._template_rebind(tkey, abstract, 1, sp)
+                if tpl is not None:
+                    plan, _slots, bindings = tpl
+                    return plan, True, key, bindings
 
         def build():
             plan = executor_lib.compile_expr(
@@ -506,7 +526,9 @@ class MatrelSession:
             return plan
 
         plan, hit = self._plan_build(key, build, rung)
-        return plan, hit, key
+        if rebind and not hit:
+            self._template_record(tkey, abstract, plan)
+        return plan, hit, key, None
 
     def _plan_probe(self, key: str, sp):
         """The plan cache's answer for ``key`` (None: a miss), noted on
@@ -636,14 +658,24 @@ class MatrelSession:
                              ) -> Tuple["executor_lib.MultiPlan", bool,
                                         List[str]]:
         """(multiplan, cache_hit, per-root keys) — the MultiPlan twin
-        of :meth:`_compile_entry`. Compiled MultiPlans participate in
-        the SAME session plan cache (one LRU, one byte budget — their
-        hoisted payloads pin HBM exactly like single plans'), keyed on
-        the SORTED unique root keys plus the axis-weight prefix, so a
-        batch resubmitted in any order (or with duplicate roots) hits
-        instead of recompiling every call. The cached plan remembers
-        its root-key order (``_root_keys``) so callers can map outputs
-        back to their own root order."""
+        of :meth:`_compile_entry`: the batch's OWN plan, no template.
+        Map outputs back to root order through the plan's
+        ``_root_keys``."""
+        return self._plan_lookup_multi(roots, sla, rung, rebind=False)[:3]
+
+    def _plan_lookup_multi(self, roots: List[MatExpr],
+                           sla: Optional[str] = None, rung: int = 0,
+                           rebind: bool = True):
+        """(multiplan, hit, per-root keys, pos, bindings) — the
+        MultiPlan twin of :meth:`_plan_lookup`; ``pos[key]`` is the
+        output that answers the root of that key. Compiled MultiPlans
+        participate in the SAME session plan cache (one LRU, one byte
+        budget — their hoisted payloads pin HBM exactly like single
+        plans'), keyed on the SORTED unique root keys plus the
+        isolation prefixes, so a batch resubmitted in any order (or
+        with duplicate roots) hits instead of recompiling every call.
+        A template pairs roots to its outputs by ABSTRACT key:
+        structurally identical roots are interchangeable programs."""
         sla = sla if sla is not None else self.config.precision_sla
         with trace_lib.span("plan", roots=len(roots)) as sp:
             # fault site "compile": the MultiPlan twin shares the site
@@ -658,12 +690,20 @@ class MatrelSession:
             for k, e in zip(keyed, roots):
                 uniq.setdefault(k, e)
             skeys = sorted(uniq)
-            mkey = ("multi:" + degrade_lib.key_prefix(rung)
-                    + self._axisw_prefix() + self._coeff_prefix()
-                    + _prec_prefix(sla) + "||".join(skeys))
+            prefix = ("multi:" + degrade_lib.key_prefix(rung)
+                      + self._axisw_prefix() + self._coeff_prefix()
+                      + _prec_prefix(sla))
+            mkey = prefix + "||".join(skeys)
             plan = self._plan_probe(mkey, sp)
-        if plan is not None:
-            return plan, True, keyed
+            if plan is None and rebind:
+                abstract = [mqo_lib.template_key(uniq[k]) for k in skeys]
+                tkey = prefix + "||".join(sorted(a[0] for a in abstract))
+                tpl = self._template_rebind(tkey, abstract, len(roots),
+                                            sp)
+                if tpl is not None:
+                    plan, slots, bindings = tpl
+                    return (plan, True, keyed, dict(zip(skeys, slots)),
+                            bindings)
 
         def build():
             plan = executor_lib.compile_exprs(
@@ -673,8 +713,72 @@ class MatrelSession:
             plan._root_keys = tuple(skeys)
             return plan
 
-        plan, hit = self._plan_build(mkey, build, rung)
-        return plan, hit, keyed
+        hit = plan is not None
+        if not hit:
+            plan, hit = self._plan_build(mkey, build, rung)
+            if rebind and not hit:
+                # ``abstract`` is in plan-root order by construction
+                self._template_record(tkey, abstract, plan)
+        return (plan, hit, keyed,
+                {k: j for j, k in enumerate(plan._root_keys)}, None)
+
+    def _template_rebind(self, tkey: str, abstract: list, served: int,
+                         sp):
+        """(plan, the template output answering each root, bindings)
+        where the template under ``tkey`` can answer roots whose
+        ``mqo_lib.template_key`` walks are ``abstract`` by REBINDING
+        its dense leaves — None when there is none, or sound bindings
+        cannot be formed (a shared template leaf facing two distinct
+        matrices — miss, never a guess). Any assignment within an
+        abstract-key group is sound as long as the caller routes each
+        root to its assigned output."""
+        with self._compile_lock:
+            st = self._mqo_state()
+            ent = st.get_template(tkey)
+            if ent is None or not mqo_lib.rebindable(ent):
+                return None
+            pool: dict = {}
+            for s, (ak, _uids) in enumerate(ent.slots):
+                pool.setdefault(ak, []).append(s)
+            slots: list = []
+            bindings: dict = {}
+            for ak, _pins, leaves in abstract:
+                free = pool.get(ak)
+                if not free:
+                    return None
+                slots.append(free.pop(0))
+                uids = ent.slots[slots[-1]][1]
+                if len(uids) != len(leaves):
+                    return None
+                for u, l in zip(uids, leaves):
+                    m = l.attrs["matrix"]
+                    if bindings.setdefault(u, m) is not m:
+                        return None
+            if any(pool.values()):
+                return None     # template has roots this batch lacks
+            st.template_hits += served
+        self._last_hit = True
+        sp.set(hit=True, via="template")
+        return ent.plan, slots, bindings
+
+    def _template_record(self, tkey: str, abstract: list, plan) -> None:
+        """Hold a freshly compiled plan rebindable under its abstract
+        key. Guarded by :func:`mqo_lib.rebindable`: when the optimizer
+        dropped or re-created a dense leaf (fresh uid), the recorded
+        uids and the program's real binding order disagree — a rebind
+        would silently feed stale data, so no template is stored (the
+        only cost is no speedup)."""
+        ent = mqo_lib.TemplateEntry(
+            plan=plan,
+            slots=tuple((ak, tuple(l.uid for l in leaves))
+                        for ak, _pins, leaves in abstract),
+            pins=tuple(p for _ak, pins, _lv in abstract for p in pins))
+        if not mqo_lib.rebindable(ent):
+            return
+        with self._compile_lock:
+            st = self._mqo_state()
+            st.put_template(tkey, ent)
+            st.template_inserts += 1
 
     def _evict_plans(self) -> None:
         """Drop least-recently-used plans past the config bounds. The
@@ -1058,167 +1162,13 @@ class MatrelSession:
     def mqo_info(self) -> dict:
         """``plan_cache_info``-style surface for the multi-query
         optimizer: template count, lifetime template hits/inserts,
-        hoisted-interior counts. All zeros (and no state constructed)
-        with ``cse_enable`` off."""
+        hoisted-interior counts (zero with ``cse_enable`` off). All
+        zeros before the session's first plan-cache miss."""
         if self._mqo is None:
             return {"templates": 0, "template_hits": 0,
                     "template_inserts": 0, "cse_hoisted": 0,
                     "cse_batches": 0}
         return self._mqo.info()
-
-    def _tpl_prefix(self, sla: str, rung: int) -> str:
-        """Template keys compose the SAME isolation prefixes as
-        concrete plan keys (``degr:``/``axisw:``/``coeffv:``/``prec:``
-        — the _compile_entry idiom): a degraded or fast-SLA template
-        can never serve a pristine exact query, because the probes
-        never share a key namespace."""
-        return (degrade_lib.key_prefix(rung) + self._axisw_prefix()
-                + self._coeff_prefix() + _prec_prefix(sla))
-
-    def _template_probe(self, e: MatExpr, sla: str, rung: int):
-        """(plan, concrete key, bindings) when a cached template can
-        serve this query by REBINDING its dense leaves — None when the
-        concrete plan-cache entry exists (that path owns its hit-rate
-        accounting and pays no rebind), the tree is
-        template-ineligible, no template matches, or sound bindings
-        cannot be formed (a shared template leaf facing two distinct
-        matrices — miss, never a guess)."""
-        prefix = self._tpl_prefix(sla, rung)
-        key, _pins = _plan_key(e)
-        ckey = prefix + key
-        with self._compile_lock:
-            if ckey in self._plan_cache:
-                return None
-            try:
-                akey, _tp, leaves = mqo_lib.template_key(e)
-            except KeyError:
-                return None
-            st = self._mqo_state()
-            ent = st.get_template(prefix + akey)
-            if ent is None or not mqo_lib.rebindable(ent):
-                return None
-            (ak0, uids), = ent.slots
-            if ak0 != akey or len(uids) != len(leaves):
-                return None
-            bindings: dict = {}
-            for u, l in zip(uids, leaves):
-                m = l.attrs["matrix"]
-                prev = bindings.get(u)
-                if prev is not None and prev is not m:
-                    return None
-                bindings[u] = m
-            st.template_hits += 1
-            return ent.plan, ckey, bindings
-
-    def _template_insert(self, e: MatExpr, plan, sla: str,
-                         rung: int) -> None:
-        """Record a freshly compiled single plan as a rebindable
-        template. Guarded by :func:`mqo_lib.rebindable`: when the
-        optimizer dropped or re-created a dense leaf (fresh uid), the
-        recorded uids and the program's real binding order disagree —
-        a rebind would silently feed stale data, so no template is
-        stored (the only cost is no speedup)."""
-        try:
-            akey, tp, leaves = mqo_lib.template_key(e)
-        except KeyError:
-            return
-        ent = mqo_lib.TemplateEntry(
-            plan=plan, slots=((akey, tuple(l.uid for l in leaves)),),
-            pins=tuple(tp))
-        if not mqo_lib.rebindable(ent):
-            return
-        with self._compile_lock:
-            st = self._mqo_state()
-            st.put_template(self._tpl_prefix(sla, rung) + akey, ent)
-            st.template_inserts += 1
-
-    def _template_probe_multi(self, roots: List[MatExpr], sla: str,
-                              rung: int):
-        """(plan, per-root concrete keys, pos, bindings) when a cached
-        MultiPlan template matches this batch modulo dense-leaf
-        bindings — the :meth:`_template_probe` twin. Roots pair to
-        template slots by ABSTRACT key (structurally identical roots
-        are interchangeable programs — any assignment within an
-        abstract-key group is sound as long as ``pos`` routes each
-        concrete root to its assigned slot's output)."""
-        prefix = self._tpl_prefix(sla, rung)
-        keyed = []
-        for e in roots:
-            k, _p = _plan_key(e)
-            keyed.append(k)
-        uniq: "OrderedDict[str, MatExpr]" = OrderedDict()
-        for k, e in zip(keyed, roots):
-            uniq.setdefault(k, e)
-        skeys = sorted(uniq)
-        mkey = "multi:" + prefix + "||".join(skeys)
-        with self._compile_lock:
-            if mkey in self._plan_cache:
-                return None
-            try:
-                ab = {}
-                for k in skeys:
-                    ak, _tp, lv = mqo_lib.template_key(uniq[k])
-                    ab[k] = (ak, lv)
-            except KeyError:
-                return None
-            st = self._mqo_state()
-            ent = st.get_template(
-                "multi:" + prefix
-                + "||".join(sorted(ak for ak, _lv in ab.values())))
-            if ent is None or not mqo_lib.rebindable(ent):
-                return None
-            slot_pool: dict = {}
-            for s, (ak, _uids) in enumerate(ent.slots):
-                slot_pool.setdefault(ak, []).append(s)
-            pos: dict = {}
-            bindings: dict = {}
-            for k in skeys:
-                ak, lv = ab[k]
-                pool = slot_pool.get(ak)
-                if not pool:
-                    return None
-                s = pool.pop(0)
-                uids = ent.slots[s][1]
-                if len(uids) != len(lv):
-                    return None
-                for u, l in zip(uids, lv):
-                    m = l.attrs["matrix"]
-                    prev = bindings.get(u)
-                    if prev is not None and prev is not m:
-                        return None
-                    bindings[u] = m
-                pos[k] = s
-            if any(slot_pool.values()):
-                return None     # template has roots this batch lacks
-            st.template_hits += len(roots)
-            return ent.plan, keyed, pos, bindings
-
-    def _template_insert_multi(self, plan, sla: str,
-                               rung: int) -> None:
-        """Record a freshly compiled MultiPlan as a rebindable
-        template. The plan's pinned uniq roots (``_cache_pin``) ARE
-        plan-root order, so slot order matches the program's output
-        order by construction."""
-        roots = plan._cache_pin[0]
-        try:
-            slots = []
-            pins: list = []
-            for e in roots:
-                ak, tp, lv = mqo_lib.template_key(e)
-                slots.append((ak, tuple(l.uid for l in lv)))
-                pins.extend(tp)
-        except KeyError:
-            return
-        ent = mqo_lib.TemplateEntry(plan=plan, slots=tuple(slots),
-                                    pins=tuple(pins))
-        if not mqo_lib.rebindable(ent):
-            return
-        with self._compile_lock:
-            st = self._mqo_state()
-            st.put_template(
-                "multi:" + self._tpl_prefix(sla, rung)
-                + "||".join(sorted(ak for ak, _u in slots)), ent)
-            st.template_inserts += 1
 
     def _cse_hoist_batch(self, pend: list, sla: str, rung: int,
                          rc: bool) -> Tuple[list, int]:
@@ -1249,17 +1199,8 @@ class MatrelSession:
         # steady-state dashboard batch rebinding fresh leaves
         # recompiles nothing at all), one dispatch, one fusion domain
         with trace_lib.span("cse.hoist", shared=len(hoists)):
-            hexprs = [h.expr for h in hoists]
-            bindings = None
-            tpl = self._template_probe_multi(hexprs, sla, rung)
-            if tpl is not None:
-                plan, hkeys, pos, bindings = tpl
-            else:
-                plan, p_hit, hkeys = self._compile_multi_entry(
-                    hexprs, sla=sla, rung=rung)
-                pos = {k: j for j, k in enumerate(plan._root_keys)}
-                if not p_hit:
-                    self._template_insert_multi(plan, sla, rung)
+            plan, _hit, hkeys, pos, bindings = self._plan_lookup_multi(
+                [h.expr for h in hoists], sla, rung)
             faults_lib.check("execute", self.config)
             outs = self._arbitrated_run(plan, bindings=bindings)
         rc_prefix = self._rc_key_prefix(sla)
@@ -1875,7 +1816,7 @@ class MatrelSession:
             return self._compute_resilient(e, rc, sla, pol,
                                            tenant=tenant)
         fast = (not rc and not self._obs_enabled()
-                and self._tracer is None and not self._cse_on())
+                and self._tracer is None)
         # the entry span: executor compile phases and every span below
         # parent-link into this query's trail. One span set for the
         # three ways through compute; with no tracer and no profiler
@@ -1885,13 +1826,11 @@ class MatrelSession:
             if fast:
                 # the production path: zero event assembly, zero extra
                 # device syncs, zero span objects, zero cache-key walks
-                # beyond the plan cache's own (the obs_level="off" /
-                # result_cache_max_bytes=0 / flight-recorder-off /
-                # cse-off contract the benchmark's cells rely on; with cse_enable
-                # a single query must still reach the template
-                # probe/insert seam in _compute_observed)
-                return self._arbitrated_run(
-                    self._compile_entry(e, sla=sla)[0])
+                # beyond the lookup's own (the obs_level="off" /
+                # result_cache_max_bytes=0 / flight-recorder-off
+                # contract nine of the benchmark's cells rely on)
+                plan, _hit, _key, bindings = self._plan_lookup(e, sla)
+                return self._arbitrated_run(plan, bindings=bindings)
             return self._compute_observed(e, rc, sla, tenant=tenant)
 
     def _compute_observed(self, e: MatExpr, rc: bool,
@@ -1926,27 +1865,8 @@ class MatrelSession:
                     self._prov_capture("rc_hit", key, sla, rung=rung,
                                        ent=ent)
                 return ent.result
-        bindings = cache_label = None
-        # plan-template probe (serve/mqo.py): a structurally
-        # identical query modulo dense-leaf bindings rebinds into
-        # the cached template's program — zero optimize/trace
-        tpl = None
-        if self._cse_on():
-            # the lookup of a session with plan templates: a template
-            # answers a query whose dense leaves are new arrays (an
-            # iteration's factors) as the plan cache answers a repeat
-            with trace_lib.span("plan", via="template") as sp:
-                tpl = self._template_probe(e, sla, rung)
-                sp.set(hit=tpl is not None)
-            if tpl is not None:
-                self._last_hit = True
-        if tpl is not None:
-            plan, pkey, bindings = tpl
-            hit, cache_label = True, "template_hit"
-        else:
-            plan, hit, pkey = self._compile_entry(e, sla=sla, rung=rung)
-            if self._cse_on() and not hit:
-                self._template_insert(e, plan, sla, rung)
+        plan, hit, pkey, bindings = self._plan_lookup(e, sla, rung)
+        cache_label = "template_hit" if bindings is not None else None
         # fault site "execute": the host-side dispatch point — the main
         # retryable site (per attempt, unlike the trace-time sites)
         faults_lib.check("execute", self.config)
@@ -2210,19 +2130,9 @@ class MatrelSession:
                 # with cse-stamped leaves
                 pend, cse_hoisted = self._cse_hoist_batch(pend, sla,
                                                           rung, rc)
-            bindings = None
-            tpl = (self._template_probe_multi(
-                [e for _, e in pend], sla, rung)
-                if self._cse_on() else None)
-            if tpl is not None:
-                plan, keys, pos, bindings = tpl
-                plan_hit = tpl_hit = True
-            else:
-                plan, plan_hit, keys = self._compile_multi_entry(
-                    [e for _, e in pend], sla=sla, rung=rung)
-                pos = {k: j for j, k in enumerate(plan._root_keys)}
-                if self._cse_on() and not plan_hit:
-                    self._template_insert_multi(plan, sla, rung)
+            plan, plan_hit, keys, pos, bindings = \
+                self._plan_lookup_multi([e for _, e in pend], sla, rung)
+            tpl_hit = bindings is not None
             # fault site "execute" — per batch attempt (host side)
             faults_lib.check("execute", self.config)
             # the batch's execute span: under obs the sync happens

@@ -1267,7 +1267,7 @@ def test_linregcg_chain_statement_as_the_session_plans_it(topo,
     mesh = Mesh(np.asarray(topo.devices[:1], dtype=object).reshape(1, 1),
                 ("x", "y"))
     whole = NamedSharding(mesh, P(None, None))
-    sess = MatrelSession(mesh=mesh, config=MatrelConfig(cse_enable=True))
+    sess = MatrelSession(mesh=mesh, config=MatrelConfig())
     for name, shape in (("X", (LINREG_N, LINREG_K)), ("p", (LINREG_K, 1)),
                         ("lam", (1, 1))):
         sess.register(name, BlockMatrix.from_array(

@@ -1,12 +1,13 @@
-"""Multi-query optimization at admission (serve/mqo.py + session
-integration): cross-query CSE — shared interiors of one run_many batch
-compute ONCE (dispatch-counted) and feed consumers as cse-stamped
-leaves the planner prices (cse_operands) — and plan-template reuse —
-structurally-identical-modulo-leaves queries rebind into the cached
-program with ZERO optimize/trace (event-verified), isolated by SLA
-prefix and by leaf identity pattern. MV116 proves substitution
-transparent (static stamps + dynamic substituted ≡ unshared), and the
-default config constructs NOTHING from the mqo module (poisoned init)."""
+"""Multi-query optimization (serve/mqo.py + session integration):
+cross-query CSE (``cse_enable``) — shared interiors of one run_many
+batch compute ONCE (dispatch-counted) and feed consumers as cse-stamped
+leaves the planner prices (cse_operands) — and plan-template reuse, on
+every session — structurally-identical-modulo-leaves queries rebind
+into the cached program with ZERO optimize/trace (event-verified),
+isolated by SLA prefix and by leaf identity pattern. MV116 proves
+substitution transparent (static stamps + dynamic substituted ≡
+unshared); the default config never hoists (poisoned init), and a
+query the plan cache answers never reaches the templates."""
 
 import numpy as np
 import pytest
@@ -187,12 +188,52 @@ class TestCrossQueryCSE:
                                        rtol=3e-4, atol=3e-4)
 
 
+@pytest.mark.parametrize("cfg", [{}, CSE], ids=["default", "cse_enable"])
 class TestPlanTemplates:
+    """Templates answer from what the lookup observes — a plan-cache
+    miss whose structure a cached plan shares — whatever ``cse_enable``
+    says."""
+
+    def test_new_leaves_of_a_known_structure_compile_nothing(
+            self, cfg, mesh8, rng, tmp_path):
+        # compute()'s fast path: no obs, no tracer, no result cache
+        import jax
+        from matrel_tpu.obs import trace as trace_lib
+        sess = _sess(mesh8, **cfg)
+        A = _mat(rng, 48, 16, mesh8)
+        B = _mat(rng, 48, 16, mesh8)
+        sess.run(A.expr().t().multiply(A.expr()))
+        assert sess.last_plan()["hit"] is False
+        cold = len(trace_lib.cold_spans())
+        before = len(trace_lib.profile_spans())
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            out = sess.run(B.expr().t().multiply(B.expr()))
+        finally:
+            jax.profiler.stop_trace()
+        bn = B.to_numpy()
+        np.testing.assert_allclose(out.to_numpy(), bn.T @ bn,
+                                   rtol=3e-4, atol=3e-4)
+        assert sess.last_plan()["hit"] is True
+        assert sess.mqo_info()["template_hits"] == 1
+        assert sess.plan_cache_info()["plans"] == 1
+        assert not [r for r in trace_lib.cold_spans()[cold:]
+                    if r["name"] == "compile"]
+        mine = trace_lib.profile_spans()[before:]
+        assert [r["attrs"] for r in mine
+                if r["name"] == "matrel.plan"] == [
+            {"hit": True, "via": "template"}]
+        assert [r["attrs"]["path"] for r in mine
+                if r["name"] == "matrel.compute"] == ["fast"]
+        assert not [r for r in mine if r["name"] == "matrel.compile"]
+
     def test_template_hit_pays_zero_optimize_event_verified(
-            self, mesh8, rng, tmp_path):
+            self, cfg, mesh8, rng, tmp_path):
         from matrel_tpu.obs.events import read_events
         log = str(tmp_path / "events.jsonl")
-        sess = _sess(mesh8, **CSE, obs_level="on", obs_event_log=log)
+        sess = _sess(mesh8, **cfg, obs_level="on", obs_event_log=log)
         A = _mat(rng, 48, 16, mesh8)
         B = _mat(rng, 48, 16, mesh8)
         sess.run(A.expr().t().multiply(A.expr()))
@@ -211,8 +252,9 @@ class TestPlanTemplates:
         assert q[1]["trace_ms"] == 0.0
         assert q[0]["optimize_ms"] > 0.0
 
-    def test_multiplan_template_rebinds_whole_batch(self, mesh8, rng):
-        sess = _sess(mesh8, **CSE)
+    def test_multiplan_template_rebinds_whole_batch(self, cfg, mesh8,
+                                                    rng):
+        sess = _sess(mesh8, **cfg)
         A = _mat(rng, 48, 16, mesh8)
         B = _mat(rng, 48, 16, mesh8)
         sess.run_many(_gram_batch(A, k=3))
@@ -223,11 +265,11 @@ class TestPlanTemplates:
             np.testing.assert_allclose(out.to_numpy(), want,
                                        rtol=3e-4, atol=3e-4)
 
-    def test_identity_pattern_never_aliases(self, mesh8, rng):
+    def test_identity_pattern_never_aliases(self, cfg, mesh8, rng):
         # t(A) @ A dedupes its two leaves into one Gram operand;
         # t(B) @ C cannot — the abstract key's identity classes
         # (#0/#0 vs #0/#1) must keep them apart
-        sess = _sess(mesh8, **CSE)
+        sess = _sess(mesh8, **cfg)
         A = _mat(rng, 32, 32, mesh8)
         B = _mat(rng, 32, 32, mesh8)
         C = _mat(rng, 32, 32, mesh8)
@@ -245,8 +287,8 @@ class TestPlanTemplates:
             out2.to_numpy(), D.to_numpy().T @ D.to_numpy(),
             rtol=3e-4, atol=3e-4)
 
-    def test_sla_prefix_isolates_templates(self, mesh8, rng):
-        sess = _sess(mesh8, **CSE)
+    def test_sla_prefix_isolates_templates(self, cfg, mesh8, rng):
+        sess = _sess(mesh8, **cfg)
         A = _mat(rng, 48, 16, mesh8)
         B = _mat(rng, 48, 16, mesh8)
         sess.run(A.expr().t().multiply(A.expr()))
@@ -254,10 +296,10 @@ class TestPlanTemplates:
         sess.run(B.expr().t().multiply(B.expr()), precision="high")
         assert sess.mqo_info()["template_hits"] == 0
 
-    def test_sparse_leaves_keep_identity_tokens(self, mesh8, rng):
+    def test_sparse_leaves_keep_identity_tokens(self, cfg, mesh8, rng):
         # sparse payloads are trace CONSTANTS in the compiled program —
         # a different sparse matrix must never rebind into the template
-        sess = _sess(mesh8, **CSE)
+        sess = _sess(mesh8, **cfg)
         sp1 = scipy.sparse.random(64, 64, density=0.3, format="csr",
                                   random_state=1, dtype=np.float32)
         sp2 = scipy.sparse.random(64, 64, density=0.3, format="csr",
@@ -313,19 +355,34 @@ class TestMV116:
 
 
 class TestZeroOverheadDefault:
-    def test_default_config_constructs_nothing(self, mesh8, rng):
-        # the poisoned-init proof: cse_enable off (the default) must
-        # never touch serve/mqo.py — no state, no hoist, no template
+    def test_default_config_constructs_nothing(self, mesh8, rng,
+                                               monkeypatch):
+        # the bypass: cse_enable off (the default) never hoists (the
+        # poisoned-init proof), and a query or a batch the plan cache
+        # answers never reaches the templates
         before = mqo_lib._CONSTRUCTED["count"]
         sess = _sess(mesh8)
-        X = _mat(rng, 48, 16, mesh8)
-        outs = sess.run_many(_gram_batch(X, k=4))
-        sess.run(X.expr().t().multiply(X.expr()))
-        assert mqo_lib._CONSTRUCTED["count"] == before
-        assert sess._mqo is None
         assert sess.mqo_info() == {
             "templates": 0, "template_hits": 0, "template_inserts": 0,
             "cse_hoisted": 0, "cse_batches": 0}
+        X = _mat(rng, 48, 16, mesh8)
+        batch = _gram_batch(X, k=4)
+        gram = X.expr().t().multiply(X.expr())
+        sess.run_many(batch)
+        sess.run(gram)
+
+        def poisoned(e):
+            raise AssertionError("a concrete hit walked template_key")
+        monkeypatch.setattr(mqo_lib, "template_key", poisoned)
+        outs = sess.run_many(batch)
+        sess.run(gram)
+        assert sess.last_plan()["hit"] is True
+        assert mqo_lib._CONSTRUCTED["count"] == before
+        assert not sess._mqo.recent
+        info = sess.mqo_info()
+        assert (info["cse_hoisted"], info["cse_batches"],
+                info["template_hits"]) == (0, 0, 0)
+        assert info["templates"] == info["template_inserts"] == 2
         for out, want in zip(outs, _gram_oracles(X, k=4)):
             np.testing.assert_allclose(out.to_numpy(), want,
                                        rtol=3e-4, atol=3e-4)

@@ -461,8 +461,7 @@ def test_the_cells_that_are_there_lower_to_the_parents_programs(
     on_mesh = cell == "linreg_10m_2x2"
     mesh = mesh_square if on_mesh else one_device
     spec = P(("x", "y"), None) if on_mesh else P(None, None)
-    sess = MatrelSession(mesh=mesh, config=MatrelConfig(
-        cse_enable=cell == "linregcg_10m_1c"))
+    sess = MatrelSession(mesh=mesh, config=MatrelConfig())
     shapes = {"relational_small_1c": CATALOG,
               "linregcg_10m_1c": SOLVER}.get(cell, REGRESSION)
     for name, shape in shapes.items():

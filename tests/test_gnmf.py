@@ -55,7 +55,7 @@ def compact_one_device(monkeypatch):
     for a slab (PR 43: a line of 3,000 cells would pay from 11 entries
     on, and every movie would leave the tables these tests are about;
     the dense part's own tests give it room)."""
-    cfg = MatrelConfig(pallas_interpret=True, cse_enable=True)
+    cfg = MatrelConfig(pallas_interpret=True)
     was = config_lib._default_config
     config_lib.set_default_config(cfg)
     monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")

@@ -374,7 +374,7 @@ def parents_program(cell, sql, one_device, mesh_square):
                         {"X": CG["X"], "y": CG["y"]})
     else:
         sess = MatrelSession(mesh=one_device, config=MatrelConfig(
-            pallas_interpret=True, cse_enable=True))
+            pallas_interpret=True))
         tables = _zeros(one_device, P(None, None),
                         CG if cell == "linregcg_10m_1c" else NMF)
         if cell != "linregcg_10m_1c":

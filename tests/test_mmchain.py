@@ -43,7 +43,6 @@ def data():
 
 
 def session_of(mesh, data, spec=P(None, None), **config):
-    config.setdefault("cse_enable", True)
     sess = MatrelSession(mesh=mesh, config=MatrelConfig(**config))
     for name, arr in data.items():
         sess.register(name, BlockMatrix.from_array(

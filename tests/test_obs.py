@@ -408,9 +408,12 @@ class TestSessionEvents:
 
     def test_eviction_counted(self, mesh8, tmp_path, rng):
         sess = _session(mesh8, tmp_path, plan_cache_max_plans=2)
-        for _ in range(4):
+        for i in range(4):
+            # a shape each: a new array of a known shape is no new plan
+            # (a template answers it)
             m = BlockMatrix.from_numpy(
-                rng.standard_normal((8, 8)).astype(np.float32), mesh=mesh8)
+                rng.standard_normal((8, 8 * (i + 1))).astype(np.float32),
+                mesh=mesh8)
             sess.run(m.expr().t())
         recs = read_events(sess.config.obs_event_log,
                            kinds=("query",))

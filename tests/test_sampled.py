@@ -40,7 +40,7 @@ def one_chip(monkeypatch):
     interpreted, plans past the small-plan threshold, a gather table of
     500 rows at the most so that a product over the users runs in three
     source panels (one over the movies in fifteen)."""
-    cfg = MatrelConfig(pallas_interpret=True, cse_enable=True)
+    cfg = MatrelConfig(pallas_interpret=True)
     was = config_lib._default_config
     config_lib.set_default_config(cfg)
     monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")

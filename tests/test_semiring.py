@@ -42,7 +42,7 @@ def one_chip(monkeypatch):
     and plans in chunks whatever their size — without hub chunks (at
     this scale the rule would make every source a hub; the cases that
     want some ask :func:`_with_hubs`)."""
-    cfg = MatrelConfig(pallas_interpret=True, cse_enable=True)
+    cfg = MatrelConfig(pallas_interpret=True)
     was = config_lib._default_config
     config_lib.set_default_config(cfg)
     monkeypatch.setattr(coo_lib, "_plan_layout", lambda: "auto")

@@ -18,20 +18,43 @@ class TestPlanCacheEviction:
     def test_count_bound_evicts_lru(self, mesh8, rng):
         sess = MatrelSession(
             mesh=mesh8, config=MatrelConfig(plan_cache_max_plans=3))
+        # a shape each: a new array of a known shape is no new plan (a
+        # template answers it)
         mats = [BlockMatrix.from_numpy(
-            rng.standard_normal((8, 8)).astype(np.float32), mesh=mesh8)
-            for _ in range(5)]
+            rng.standard_normal((8, 8 * (i + 1))).astype(np.float32),
+            mesh=mesh8) for i in range(5)]
         for m in mats:
             sess.compute(m.expr().t())
         assert sess.plan_cache_info()["plans"] == 3
         keys_before = list(sess._plan_cache)
-        # the OLDEST (mats[0]) was evicted: recomputing it recompiles,
-        # inserting a fresh entry and evicting the current LRU
-        sess.compute(mats[0].expr().t())
+        # the OLDEST (mats[0]) was evicted: compiling it again inserts
+        # a fresh entry and evicts the current LRU
+        sess.compile(mats[0].expr().t())
         keys_after = list(sess._plan_cache)
         assert keys_after[-1] not in keys_before   # new entry appended
         assert keys_before[0] not in keys_after    # LRU evicted
         assert sess.plan_cache_info()["plans"] == 3
+
+    def test_a_template_outlives_its_plans_eviction(self, mesh8, rng):
+        # the NMF cells stand on it: their two updates' plans each count
+        # the shared 4 GB slab against plan_cache_max_bytes and evict
+        # one another, and the templates go on answering
+        sess = MatrelSession(
+            mesh=mesh8, config=MatrelConfig(plan_cache_max_plans=1))
+
+        def t_of(cols):
+            return BlockMatrix.from_numpy(
+                rng.standard_normal((8, cols)).astype(np.float32),
+                mesh=mesh8).expr().t()
+        sess.compute(t_of(8))
+        sess.compute(t_of(16))          # evicts 8's plan
+        assert sess.plan_cache_info()["evicted"] == 1
+        for cols in (8, 16, 8):
+            sess.compute(t_of(cols))
+            assert sess.last_plan()["hit"] is True
+        assert sess.plan_cache_info() == {
+            "plans": 1, "hoisted_bytes": 0, "evicted": 1}
+        assert sess.mqo_info()["template_hits"] == 3
 
     def test_lru_order_on_hit(self, mesh8, rng):
         sess = MatrelSession(

@@ -156,7 +156,7 @@ def build_pool(sess, rng, register=False):
                  S1.to_numpy() @ S2.to_numpy()))
     # dashboard-session class (round 17, serve/mqo.py): a burst of
     # structurally-identical-modulo-leaves queries — the same scaled
-    # Gram shape over DISTINCT small tables. With cse_enable on, the
+    # Gram shape over DISTINCT small tables. The
     # first compiles and inserts a plan template; every sibling
     # rebinds into it (template_hits), so dashboard traffic's compile
     # count plateaus at one — the artifact's mqo assertion.
@@ -449,11 +449,11 @@ def main(slo: bool = False) -> int:
         # on a real TPU, where the MXU turns coalescing into a win.
         serve_max_batch=1,
         plan_cache_max_plans=256,
-        # round 17 (serve/mqo.py): plan-template reuse on — the
-        # dashboard-session pool class (structurally identical modulo
-        # leaves) must plateau its compile count: first variant pays
-        # optimize/trace, every sibling rebinds into the cached
-        # template (mqo.template_hits in the record)
+        # round 17 (serve/mqo.py): cross-query CSE on. Whatever it
+        # says, the dashboard-session pool class (structurally
+        # identical modulo leaves) must plateau its compile count:
+        # first variant pays optimize/trace, every sibling rebinds into
+        # the cached template (mqo.template_hits in the record)
         cse_enable=True,
         brownout_enable=True,
         brownout_window=16,
